@@ -1,0 +1,518 @@
+"""Chip smoke: the query path, once, on the TPU it was written for.
+
+    python chip_smoke.py            # one chip: engine + served phases
+    python chip_smoke.py --chips 4  # four chips: DistributedEngine only
+
+One process, no child that needs the chip. A seeded ``http_events``
+replay (the bench's five-column layout, 32 B/row) goes in through the
+table store's ingest path with device residency on; every answer is
+checked against a plain numpy replay of the same semantics. Progress is
+one JSON object per line; the LAST line is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+and the exit code is 0 only then. Any phase that raises, or a platform
+other than ``tpu``, ends in ``"ok": false`` and a non-zero exit. On the
+CPU the script only rehearses (``JAX_PLATFORMS=cpu python chip_smoke.py
+--rows 65536``: Pallas kernels in interpret mode, never ``ok``).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import os
+import sys
+import time
+import traceback
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+
+WINDOW = 1 << 21  # one window size: one update + one finalize per query
+REHEARSAL_MAX_ROWS = 1 << 20  # what a run without a TPU may be asked for
+
+SERVICES = [f"svc-{i}" for i in range(32)]
+PATHS = [f"/api/v1/ep{i}" for i in range(8)]
+
+#: FLOAT64 aggregates over a dense (dictionary-coded) key domain: the one
+#: shape ``exec/fragment.py`` routes through ``dense_group_fold``. No
+#: shipped script has it on this table (``latency_ns`` is INT64).
+F64_GROUPBY = """
+import px
+df = px.DataFrame(table='http_events')
+df.lat_ms = df.latency_ns / 1000000.0
+df = df.groupby(['service', 'req_path']).agg(
+    n=('lat_ms', px.count),
+    lat_sum=('lat_ms', px.sum),
+    lat_mean=('lat_ms', px.mean),
+    lat_max=('lat_ms', px.max),
+    lat_min=('lat_ms', px.min),
+)
+px.display(df)
+"""
+
+#: Two groups (a BOOLEAN key is a dense domain of 2) x 8192 bins = 16384
+#: slots: inside ``hist_fold``'s gate (``ops/tdigest.py``, G * B <=
+#: 1 << 15, that is G <= 4). An ungrouped aggregate folds into
+#: ``max_groups`` slots and every shipped quantile script groups by a
+#: column with more than four values here, so none reaches the kernel.
+QUANTILE_BY_FAILED = """
+import px
+df = px.DataFrame(table='http_events')
+df.failed = df.resp_status >= 400
+df = df.groupby('failed').agg(
+    lat_q=('latency_ns', px.quantiles),
+    n=('latency_ns', px.count),
+)
+df.p50 = px.pluck_float64(df.lat_q, 'p50')
+df.p99 = px.pluck_float64(df.lat_q, 'p99')
+df = df[['failed', 'p50', 'p99', 'n']]
+px.display(df)
+"""
+
+
+def emit(**kw) -> None:
+    print(json.dumps(kw), flush=True)
+
+
+# -- compile accounting -------------------------------------------------------
+
+
+class CompileMeter:
+    """Counts what JAX compiled (or fetched from the persistent cache),
+    from JAX's own monitoring events: every jit in the process, tracked
+    by the program registry or not."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.programs = 0  # compiled, or fetched from the persistent cache
+        self.fetched = 0  # of those, fetched
+        self.secs = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, secs, **_kw):
+        # The backend-compile timer wraps compile-or-fetch, so a
+        # persistent-cache hit fires both events.
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.programs += 1
+            self.secs += secs
+        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            self.fetched += 1
+
+    def mark(self):
+        return self.programs, self.fetched, self.secs
+
+    def since(self, mark=(0, 0, 0.0)) -> dict:
+        return {
+            "programs": self.programs - mark[0],
+            "from_persistent_cache": self.fetched - mark[1],
+            "compile_secs": round(self.secs - mark[2], 3),
+        }
+
+
+def _registry_snapshot() -> dict:
+    from pixie_tpu.exec.programs import default_program_registry
+
+    return {
+        r.program_id: r.compiles
+        for r in default_program_registry().records()
+    }
+
+
+def _programs_since(snap: dict) -> list:
+    """Registry records compiled since ``snap`` (the tracked fragment
+    programs: label, compile seconds, whether a Pallas kernel is in the
+    executable XLA built)."""
+    from pixie_tpu.exec.programs import default_program_registry
+
+    out = []
+    for r in default_program_registry().records():
+        if r.compiles > snap.get(r.program_id, 0):
+            text = r.compiled.as_text() if r.compiled is not None else ""
+            out.append({
+                "program": f"{r.kind}:{r.label}",
+                "compile_secs": round(r.compile_s_last, 3),
+                "tpu_custom_call": "tpu_custom_call" in text,
+                "kernels": sorted(
+                    k for k in ("dense_group_fold", "hist_fold")
+                    if f"{k}/pallas_call" in text
+                ),
+            })
+    return out
+
+
+# -- the replay and its numpy reference --------------------------------------
+
+
+class Replay:
+    """``rows`` http_events at the bench's layout (``bench._http_replay``):
+    time_ i64, latency_ns i64, resp_status i64, service/req_path as
+    dictionary codes. All of it from ``seed``."""
+
+    def __init__(self, rows: int, seed: int):
+        from pixie_tpu.types.dtypes import DataType
+        from pixie_tpu.types.relation import Relation
+        from pixie_tpu.types.strings import StringDictionary
+
+        rng = np.random.default_rng(seed)
+        self.rows = rows
+        self.rel = Relation([
+            ("time_", DataType.TIME64NS),
+            ("latency_ns", DataType.INT64),
+            ("resp_status", DataType.INT64),
+            ("service", DataType.STRING),
+            ("req_path", DataType.STRING),
+        ])
+        self.dicts = {
+            "service": StringDictionary(SERVICES),
+            "req_path": StringDictionary(PATHS),
+        }
+        statuses = np.array([200, 200, 200, 200, 404, 500])
+        self.svc = rng.integers(0, len(SERVICES), rows).astype(np.int32)
+        self.path = rng.integers(0, len(PATHS), rows).astype(np.int32)
+        self.lat = rng.integers(1_000, 100_000_000, rows)
+        self.status = statuses[rng.integers(0, len(statuses), rows)].astype(
+            np.int64
+        )
+        self.bytes_per_row = 8 + 8 + 8 + 4 + 4
+
+    def push(self, append, rows: int | None = None) -> int:
+        """Append the first ``rows`` rows, a window at a time, through
+        ``append(table, HostBatch)`` (the engine's / agent's ingest)."""
+        from pixie_tpu.types.batch import HostBatch
+
+        n = self.rows if rows is None else min(rows, self.rows)
+        for off in range(0, n, WINDOW):
+            m = min(WINDOW, n - off)
+            s = slice(off, off + m)
+            append("http_events", HostBatch(
+                relation=self.rel,
+                cols={
+                    "time_": (np.arange(off, off + m, dtype=np.int64),),
+                    "latency_ns": (self.lat[s],),
+                    "resp_status": (self.status[s],),
+                    "service": (self.svc[s],),
+                    "req_path": (self.path[s],),
+                },
+                length=m, dicts=self.dicts,
+            ))
+        return n
+
+
+def _by_key(got: dict):
+    """Group-by output keyed service * 64 + req_path, sorted."""
+    key = got["service"].astype(np.int64) * 64 + got["req_path"]
+    order = np.argsort(key)
+    return key[order], order
+
+
+def check_http_stats(rp: Replay, n: int, got: dict) -> None:
+    lat, status = rp.lat[:n], rp.status[:n]
+    ok = status < 400
+    key = rp.svc[:n][ok].astype(np.int64) * 64 + rp.path[:n][ok]
+    uniq, inv = np.unique(key, return_inverse=True)
+    cnt = np.bincount(inv)
+    mean = np.bincount(inv, weights=lat[ok].astype(np.float64)) / cnt
+    mx = np.full(len(uniq), -np.inf)
+    np.maximum.at(mx, inv, lat[ok])
+    gkey, order = _by_key(got)
+    assert np.array_equal(uniq, gkey), "http_stats: group keys differ"
+    assert np.array_equal(got["n"][order], cnt), "http_stats: counts differ"
+    np.testing.assert_allclose(got["lat_mean"][order], mean, rtol=1e-5)
+    np.testing.assert_allclose(got["lat_max"][order], mx)
+
+
+def check_service_stats(rp: Replay, n: int, got: dict) -> None:
+    lat, status, svc = rp.lat[:n], rp.status[:n], rp.svc[:n]
+    seen = set()
+    for s, p50, p99, err, thr in zip(
+        got["service"], got["p50"], got["p99"], got["error_rate"],
+        got["throughput"],
+    ):
+        m = svc == s
+        seen.add(int(s))
+        r50, r99 = np.quantile(lat[m], 0.5), np.quantile(lat[m], 0.99)
+        assert abs(p50 - r50) / r50 < 0.15, f"p50 off: {p50} vs {r50}"
+        assert abs(p99 - r99) / r99 < 0.15, f"p99 off: {p99} vs {r99}"
+        np.testing.assert_allclose(err, np.mean(status[m] >= 400), rtol=1e-4)
+        assert thr == int(m.sum()), "service_stats: throughput differs"
+    assert seen == set(np.unique(svc).tolist()), "service_stats: services differ"
+
+
+def check_f64_groupby(rp: Replay, n: int, got: dict) -> None:
+    # The device holds FLOAT64 planes as f32 (types/dtypes.py) and the
+    # kernel folds in f32: the repo's own tolerance for it is 1e-4
+    # (tests/test_tpu.py).
+    ms = rp.lat[:n].astype(np.float64) / 1e6
+    key = rp.svc[:n].astype(np.int64) * 64 + rp.path[:n]
+    uniq, inv = np.unique(key, return_inverse=True)
+    cnt = np.bincount(inv)
+    total = np.bincount(inv, weights=ms)
+    mx = np.full(len(uniq), -np.inf)
+    mn = np.full(len(uniq), np.inf)
+    np.maximum.at(mx, inv, ms)
+    np.minimum.at(mn, inv, ms)
+    gkey, order = _by_key(got)
+    assert np.array_equal(uniq, gkey), "f64_groupby: group keys differ"
+    assert np.array_equal(got["n"][order], cnt), "f64_groupby: counts differ"
+    np.testing.assert_allclose(got["lat_sum"][order], total, rtol=1e-4)
+    np.testing.assert_allclose(got["lat_mean"][order], total / cnt, rtol=1e-4)
+    np.testing.assert_allclose(got["lat_max"][order], mx, rtol=1e-6)
+    np.testing.assert_allclose(got["lat_min"][order], mn, rtol=1e-6)
+
+
+def check_quantile_by_failed(rp: Replay, n: int, got: dict) -> None:
+    lat, failed = rp.lat[:n], rp.status[:n] >= 400
+    assert sorted(got["failed"].tolist()) == [False, True], got["failed"]
+    for f, p50, p99, cnt in zip(
+        got["failed"], got["p50"], got["p99"], got["n"]
+    ):
+        m = failed == bool(f)
+        assert cnt == int(m.sum()), "quantile_by_failed: counts differ"
+        for name, val, q in (("p50", p50, 0.5), ("p99", p99, 0.99)):
+            ref = np.quantile(lat[m], q)
+            assert abs(val - ref) / ref < 0.15, (
+                f"quantile_by_failed {name} off: {val} vs {ref}"
+            )
+
+
+def engine_queries() -> list:
+    """(name, PxL, check, kernel the program must contain on the chip)."""
+    from pixie_tpu.scripts import load_script
+
+    return [
+        ("px/http_stats", load_script("px/http_stats").pxl,
+         check_http_stats, None),
+        ("px/service_stats", load_script("px/service_stats").pxl,
+         check_service_stats, None),
+        ("inline/f64_groupby", F64_GROUPBY, check_f64_groupby,
+         "dense_group_fold"),
+        ("inline/quantile_by_failed", QUANTILE_BY_FAILED,
+         check_quantile_by_failed, "hist_fold"),
+    ]
+
+
+# -- phases -------------------------------------------------------------------
+
+
+def _resident(table, sharded_over: int = 1) -> dict:
+    """What of ``table`` sits in device memory, by walking the windows a
+    query would scan. With ``sharded_over`` > 1 every plane must have
+    its shards on that many distinct devices."""
+    windows = rows = nbytes = 0
+    devices = set()
+    for win, _lo, _hi in table.device_scan(None, None, window_rows=WINDOW):
+        windows += 1
+        rows += win.n
+        nbytes += win.nbytes
+        for planes in win.cols.values():
+            for p in planes:
+                devs = {sh.device for sh in p.addressable_shards}
+                assert len(devs) == sharded_over, (
+                    f"window at row {win.row0}: shards on {len(devs)} "
+                    f"devices, want {sharded_over}"
+                )
+                devices |= devs
+    return {"windows": windows, "rows": rows, "bytes": nbytes,
+            "devices": sorted(str(d) for d in devices)}
+
+
+def _timed_query(eng, pxl: str):
+    """Seconds to the host readback, and the host result."""
+    t0 = time.perf_counter()
+    out = eng.execute_query(pxl, materialize=False)
+    host = {
+        k: (v.to_host() if hasattr(v, "to_host") else v)
+        for k, v in out.items()
+    }
+    return time.perf_counter() - t0, host
+
+
+def run_queries(eng, rp: Replay, n: int, queries, meter: CompileMeter,
+                on_tpu: bool, phase: str) -> None:
+    """Each query twice (cold, warm), checked; the second run may
+    compile nothing, and on the chip a query that names a kernel must
+    have it in the program XLA built."""
+    for name, pxl, check, kernel in queries:
+        snap, mark = _registry_snapshot(), meter.mark()
+        cold_s, host = _timed_query(eng, pxl)
+        cold = meter.since(mark)
+        programs = _programs_since(snap)
+        check(rp, n, host["output"].to_pydict(decode_strings=False))
+        mark = meter.mark()
+        warm_s, host = _timed_query(eng, pxl)
+        warm = meter.since(mark)
+        check(rp, n, host["output"].to_pydict(decode_strings=False))
+        emit(phase=phase, query=name, rows=n, checked=True,
+             cold_secs=cold_s, warm_secs=warm_s,
+             cold_compile=cold, warm_compile=warm, programs=programs)
+        assert warm["programs"] == 0, (
+            f"{name}: second run compiled {warm['programs']} program(s)"
+        )
+        if kernel and on_tpu:
+            assert any(
+                p["tpu_custom_call"] and kernel in p["kernels"]
+                for p in programs
+            ), f"{name}: no tpu_custom_call for {kernel} in its programs"
+
+
+def phase_engine(rp: Replay, meter: CompileMeter, on_tpu: bool) -> None:
+    from pixie_tpu.exec.engine import Engine
+
+    eng = Engine(window_rows=WINDOW)
+    eng.create_table("http_events")
+    t0 = time.perf_counter()
+    n = rp.push(eng.append_data)
+    table = eng.tables["http_events"]
+    res = _resident(table)
+    emit(phase="engine", step="ingest", rows=n,
+         table_bytes=n * rp.bytes_per_row, secs=time.perf_counter() - t0,
+         table_store_backend=type(table._backend).__name__, resident=res)
+    assert res["rows"] == n, f"resident rows {res['rows']} != {n}"
+    run_queries(eng, rp, n, engine_queries(), meter, on_tpu, "engine")
+
+
+def phase_served(rp: Replay, meter: CompileMeter) -> None:
+    """Broker + one PEM + one Kelvin on an in-process bus; three
+    px/http_stats requests through the broker."""
+    from pixie_tpu.exec.engine import Engine
+    from pixie_tpu.scripts import load_script
+    from pixie_tpu.services import (
+        AgentTracker, KelvinAgent, MessageBus, PEMAgent, QueryBroker,
+    )
+    from pixie_tpu.services.load_tester import broker_executor
+
+    bus = MessageBus()
+    tracker = AgentTracker(bus)
+    pem = PEMAgent(bus, "pem-0", engine=Engine(window_rows=WINDOW)).start()
+    kelvin = KelvinAgent(bus, "kelvin-0").start()
+    try:
+        n = rp.push(pem.append_data, rows=WINDOW)
+        pem._register()  # so the tracker sees the post-ingest schema
+        deadline = time.monotonic() + 30
+        while "http_events" not in tracker.schemas():
+            assert time.monotonic() < deadline, "PEM schema never registered"
+            time.sleep(0.01)
+        emit(phase="served", step="ingest", pem_rows=n,
+             note="the PEM holds one window of the same replay; the "
+                  "engine phase carries the size")
+        execute = broker_executor(QueryBroker(bus, tracker))
+        pxl = load_script("px/http_stats").pxl
+        for i in range(3):
+            mark = meter.mark()
+            t0 = time.perf_counter()
+            res = execute(pxl, 1100.0)
+            secs = time.perf_counter() - t0
+            # The merged result carries its own string dictionary: map
+            # the decoded strings back onto the replay's codes.
+            got = res["tables"]["output"].to_pydict()
+            got["service"] = np.array(
+                [SERVICES.index(s) for s in got["service"]]
+            )
+            got["req_path"] = np.array(
+                [PATHS.index(s) for s in got["req_path"]]
+            )
+            check_http_stats(rp, n, got)
+            emit(phase="served", request=i, query="px/http_stats", rows=n,
+                 groups=len(got["n"]), checked=True, secs=secs,
+                 cache=res.get("cache", ""), compile=meter.since(mark))
+    finally:
+        pem.stop()
+        kelvin.stop()
+        tracker.close()
+        bus.close()
+
+
+def phase_distributed(rp: Replay, meter: CompileMeter, on_tpu: bool,
+                      chips: int) -> None:
+    """``DistributedEngine`` over ``agent_mesh(chips)`` on the same
+    replay: windows resident row-sharded across the chips, the two
+    shipped scripts checked against the same numpy reference."""
+    import jax
+
+    from pixie_tpu.parallel.executor import DistributedEngine
+    from pixie_tpu.parallel.mesh import agent_mesh
+
+    assert len(jax.devices()) >= chips, (
+        f"--chips {chips} needs {chips} devices, have {len(jax.devices())}"
+    )
+    eng = DistributedEngine(window_rows=WINDOW, mesh=agent_mesh(chips))
+    eng.create_table("http_events")
+    t0 = time.perf_counter()
+    n = rp.push(eng.append_data)
+    res = _resident(eng.tables["http_events"], sharded_over=chips)
+    emit(phase="distributed", step="ingest", rows=n, chips=chips,
+         secs=time.perf_counter() - t0, resident=res)
+    assert res["rows"] == n and len(res["devices"]) == chips
+    run_queries(eng, rp, n, engine_queries()[:2], meter, on_tpu,
+                "distributed")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=16 << 20,
+                    help="http_events rows (default 16 Mi = 512 MiB)")
+    ap.add_argument("--seed", type=int, default=22)
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: the DistributedEngine phase and nothing else")
+    args = ap.parse_args(argv)
+
+    from pixie_tpu import native
+    from pixie_tpu.utils.cache import configure_jax_cache
+
+    cache_dir = configure_jax_cache()
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    on_tpu = dev.platform == "tpu"
+    emit(device=device, cache_dir=cache_dir, rows=args.rows, seed=args.seed,
+         window_rows=WINDOW, jax=jax.__version__)
+    if not on_tpu and args.rows > REHEARSAL_MAX_ROWS:
+        emit(error=f"no TPU (platform {dev.platform}); without one only a "
+                   f"rehearsal at --rows <= {REHEARSAL_MAX_ROWS} runs")
+        emit(ok=False, device=device)
+        return 1
+
+    ok = False
+    try:
+        emit(native_rebuilt=native.rebuild_all(), libraries=native.LIBRARIES)
+        from pixie_tpu.config import override_flag
+
+        meter = CompileMeter()
+        rp = Replay(args.rows, args.seed)
+        with contextlib.ExitStack() as flags:
+            if not on_tpu:
+                # A rehearsal of the control flow: 'auto' keeps the
+                # kernels off the CPU, so they are asked for in interpret
+                # mode by name, and the CPU backend's native fold is off
+                # so that the XLA fold the chip runs is what rehearses.
+                # On the chip every flag stays at its default.
+                for name, value in (("pallas_dense_fold", "interpret"),
+                                    ("pallas_tdigest", "interpret"),
+                                    ("cpu_fold_threads", 1)):
+                    flags.enter_context(override_flag(name, value))
+            if args.chips == 4:
+                phase_distributed(rp, meter, on_tpu, 4)
+            else:
+                phase_engine(rp, meter, on_tpu)
+                phase_served(rp, meter)
+        emit(total_compile=meter.since())
+        ok = on_tpu
+        if not ok:
+            emit(error=f"rehearsal on {dev.platform}: not a chip run")
+    except BaseException:
+        traceback.print_exc()
+        emit(error=traceback.format_exc(limit=3).splitlines()[-1])
+    emit(ok=ok, device=device)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -33,6 +33,39 @@ def _build(name: str) -> str:
     return out
 
 
+#: The shared objects ``load`` serves (table store ring, CPU segmented
+#: fold, host hash join).
+LIBRARIES = ("table_ring", "seg_fold", "hash_join")
+
+
+def rebuild_all() -> bool:
+    """Rebuild every library from its ``.cc``, discarding what is on disk.
+
+    For entry points that must not run on a stale or half-built object
+    (``chip_smoke.py``): call before the first ``load``. Returns False
+    when there is no ``g++`` (callers then run on the numpy backends);
+    a compile error raises.
+    """
+    import shutil
+
+    if shutil.which("g++") is None:
+        return False
+    with _LOCK:
+        _LIBS.clear()
+        for name in LIBRARIES:
+            out = os.path.join(_DIR, f"lib{name}.so")
+            if os.path.exists(out):
+                os.remove(out)
+            try:
+                _build(name)
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    f"native build of {name} failed:\n"
+                    f"{e.stderr.decode(errors='replace')}"
+                ) from None
+    return True
+
+
 def build_executable(name: str) -> str | None:
     """Build native/<name>.cc as a standalone binary (the client CLI
     path, vs ``load``'s shared-object path). Returns the binary path or

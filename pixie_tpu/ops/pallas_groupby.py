@@ -28,6 +28,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+
+def row_chunk(n: int, cap: int) -> int | None:
+    """Row block (<= cap) Mosaic accepts for a 1-D 32-bit [n] operand.
+
+    XLA tiles such an operand T(1024) (the whole array below that), and
+    the kernel's block has to agree with it: a multiple of 1024 that
+    divides n, or all n rows as one block. None when there is neither —
+    the caller keeps that window on its XLA path.
+    """
+    for c in range(cap - cap % 1024, 0, -1024):
+        if n % c == 0:
+            return c
+    return n if n <= cap else None
+
+
+def fold_row_chunk(n: int, g: int) -> int | None:
+    """``dense_group_fold``'s row block for [n] rows and g (padded) groups.
+
+    The [chunk, g] one-hot and masked max/min temporaries stay at 4 MiB
+    each, but for the 1024-row floor the tiling sets: 8 MiB at g = 2048,
+    which still compiles under the kernel's 16 MiB scoped-vmem limit.
+    """
+    return row_chunk(n, 2048 if g <= 512 else 1024)
+
+
 def _fold_kernel(slot_ref, val_ref, cnt_ref, sum_ref, max_ref, aux_ref,
                  *, g: int, want_min: bool):
     """One grid step: fold a [C]-row chunk into the [G] accumulators.
@@ -63,15 +88,17 @@ def _fold_kernel(slot_ref, val_ref, cnt_ref, sum_ref, max_ref, aux_ref,
     # they feed only VPU reductions, never the matmul, so a group whose
     # values are all +inf (f32 overflow of a huge f64) still reports the
     # true extremum the XLA scatter path would.
+    # The lhs is written [1, C], never a bare [C] vector: Mosaic's dot
+    # lowering needs a non-contracting lhs dimension.
     cnt_ref[:] += jnp.sum(onehot, axis=0)
-    sum_ref[:] += jnp.where(jnp.isfinite(vals), vals, 0.0) @ onehot
+    sum_ref[:] += (jnp.where(jnp.isfinite(vals), vals, 0.0)[None, :] @ onehot)[0]
     masked_hi = jnp.where(onehot > 0, vals[:, None], -jnp.inf)  # [C, G] VPU
     max_ref[:] = jnp.maximum(max_ref[:], jnp.max(masked_hi, axis=0))
     if want_min:
         masked_lo = jnp.where(onehot > 0, vals[:, None], jnp.inf)
         aux_ref[:] = jnp.minimum(aux_ref[:], jnp.min(masked_lo, axis=0))
     else:
-        aux_ref[:] += (vals == -jnp.inf).astype(jnp.float32) @ onehot
+        aux_ref[:] += ((vals == -jnp.inf).astype(jnp.float32)[None, :] @ onehot)[0]
 
 
 @functools.partial(
@@ -82,9 +109,9 @@ def dense_group_fold(slots, values, g: int, chunk: int = 2048,
     """(count, sum, max, min | None) f32[g] over packed slot ids.
 
     ``slots`` i32[n] in [0, g) for live rows, >= g for masked rows;
-    ``values`` f32[n]. n must be a multiple of ``chunk`` (the engine's
-    capacity bucketing guarantees powers of two); g should be a multiple
-    of 128 for lane alignment (pad and slice at the caller).
+    ``values`` f32[n]. ``chunk`` comes from ``fold_row_chunk`` (a multiple
+    of 1024 dividing n, or n itself); g should be a multiple of 128 for
+    lane alignment (pad and slice at the caller).
     ``want_min=False`` skips the min reduce (the 4th return is None) —
     queries without a min aggregate don't pay its VPU pass.
     """
@@ -98,13 +125,10 @@ def dense_group_fold(slots, values, g: int, chunk: int = 2048,
             pl.BlockSpec((chunk,), lambda i: (i,)),
         ],
         # Accumulators: every grid step maps to the SAME [g] block, so
-        # they live in VMEM across the whole pass (init at step 0).
-        out_specs=[
-            pl.BlockSpec((g,), lambda i: (0,)),
-            pl.BlockSpec((g,), lambda i: (0,)),
-            pl.BlockSpec((g,), lambda i: (0,)),
-            pl.BlockSpec((g,), lambda i: (0,)),
-        ],
+        # they live in VMEM across the whole pass (init at step 0). The
+        # block index is an explicit int32: the package runs with x64 on,
+        # where a Python 0 traces as i64 and Mosaic refuses the index map.
+        out_specs=[pl.BlockSpec((g,), lambda i: (jnp.int32(0),))] * 4,
         out_shape=[
             jax.ShapeDtypeStruct((g,), jnp.float32),
             jax.ShapeDtypeStruct((g,), jnp.float32),
@@ -112,6 +136,7 @@ def dense_group_fold(slots, values, g: int, chunk: int = 2048,
             jax.ShapeDtypeStruct((g,), jnp.float32),
         ],
         interpret=interpret,
+        name="dense_group_fold",
     )(slots.astype(jnp.int32), values.astype(jnp.float32))
     cnt, s, m, aux = out
     # Restore per-group non-finite sums from the max/aux evidence (the

@@ -46,7 +46,8 @@ def _hist_kernel(bin_ref, val_ref, w_ref, mw_ref, *, tile: int):
         == jax.lax.broadcasted_iota(jnp.int32, (bins.shape[0], tile), 1)
     ).astype(jnp.float32)
     w_ref[:] += jnp.sum(onehot, axis=0)
-    mw_ref[:] += vals @ onehot
+    # [1, C] lhs: Mosaic's dot lowering needs a non-contracting lhs dim.
+    mw_ref[:] += (vals[None, :] @ onehot)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "chunk", "interpret"))
@@ -77,5 +78,6 @@ def hist_fold(bins, values, n_slots: int, chunk: int = 2048,
             jax.ShapeDtypeStruct((pad,), jnp.float32),
         ],
         interpret=interpret,
+        name="hist_fold",
     )(bins.astype(jnp.int32), values.astype(jnp.float32))
     return w[:n_slots], mw[:n_slots]

@@ -45,6 +45,23 @@ def _needs_blocking(x, force: bool) -> bool:
     return n * np.dtype(x.dtype).itemsize > _FLAT_MAX_BYTES
 
 
+def _totals_scan(totals, op, identity):
+    """Inclusive scan of the [c] chunk totals in log2(c) shifted
+    combines (Hillis-Steele), exact for integers. Not ``jnp.cumsum``:
+    inside a ``while`` body (the engine's scan-fold program) XLA:TPU
+    gives even this tiny 64-bit reduce-window a scoped-vmem stack past
+    the 16 MiB limit, and the program fails to compile."""
+    (c,) = totals.shape
+    k = 1
+    while k < c:
+        shifted = jnp.concatenate(
+            [jnp.full(k, identity, totals.dtype), totals[:-k]]
+        )
+        totals = op(totals, shifted)
+        k *= 2
+    return totals
+
+
 def blocked_cumsum(x: jnp.ndarray, force: bool = False) -> jnp.ndarray:
     """Inclusive 1-D cumsum, exact for integers, safe to compile on TPU
     at any length. Equals ``jnp.cumsum(x)`` elementwise for integer
@@ -58,12 +75,9 @@ def blocked_cumsum(x: jnp.ndarray, force: bool = False) -> jnp.ndarray:
     pad = c * _CHUNK - n
     x2 = jnp.pad(x, (0, pad)).reshape(c, _CHUNK)
     within = jnp.cumsum(x2, axis=1)
-    # Exclusive prefix of the chunk totals: a length-c scan (c = n/8192),
-    # small enough for the flat lowering.
-    totals = within[:, -1]
-    prefix = jnp.concatenate(
-        [jnp.zeros(1, x.dtype), jnp.cumsum(totals)[:-1]]
-    )
+    # Exclusive prefix of the chunk totals: a length-c scan (c = n/8192).
+    totals = _totals_scan(within[:, -1], jnp.add, 0)
+    prefix = jnp.concatenate([jnp.zeros(1, x.dtype), totals[:-1]])
     return (within + prefix[:, None]).reshape(-1)[:n]
 
 
@@ -86,8 +100,6 @@ def blocked_cummax(x: jnp.ndarray, force: bool = False) -> jnp.ndarray:
     pad = c * _CHUNK - n
     x2 = jnp.pad(x, (0, pad), constant_values=lowest).reshape(c, _CHUNK)
     within = jax.lax.cummax(x2, axis=1)
-    totals = within[:, -1]
-    prefix = jnp.concatenate(
-        [jnp.full(1, lowest, x.dtype), jax.lax.cummax(totals)[:-1]]
-    )
+    totals = _totals_scan(within[:, -1], jnp.maximum, lowest)
+    prefix = jnp.concatenate([jnp.full(1, lowest, x.dtype), totals[:-1]])
     return jnp.maximum(within, prefix[:, None]).reshape(-1)[:n]

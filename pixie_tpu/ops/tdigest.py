@@ -124,23 +124,26 @@ def batch_to_digest(values, group_ids, mask, num_groups: int, k: int = DEFAULT_K
     from ..config import get_flag
 
     n_slots = num_groups * b
+    n = values.shape[0]
     mode = get_flag("pallas_tdigest")
-    use_pallas = (
+    chunk = None
+    if (
         mode in ("auto", "interpret")
         and (mode == "interpret" or jax.default_backend() == "tpu")
         and n_slots <= (1 << 15)  # MXU dense sweep beats scatters here
-        and values.shape[0] >= 128
-    )
-    if use_pallas:
+        and n >= 128
+    ):
+        # Imported only here: pulling in Pallas costs seconds, which a
+        # process that never runs the kernel must not pay mid-query.
+        from .pallas_groupby import row_chunk
+
+        chunk = row_chunk(n, 2048)  # None: no block the tiling accepts
+    if chunk is not None:
         # Pallas kernel: both histograms in one VMEM-resident sweep
         # (pallas_tdigest.py); trash rows get an id past the kernel's
         # padded slot range so they match no tile column.
         from .pallas_tdigest import hist_fold, _TILE
 
-        n = values.shape[0]
-        chunk = min(2048, n)
-        while n % chunk:
-            chunk //= 2
         pad = -(-n_slots // _TILE) * _TILE
         flat = jnp.where(mask & (gids < num_groups), gids * b + bins, pad)
         w_f, mw_f = hist_fold(

@@ -962,7 +962,7 @@ class TestDeadlineFault:
 class TestLoadUnderFaults:
     def test_load_tester_reports_failure_rates(self, cluster):
         """Satellite: the load tester, driven into injected faults,
-        reports failure rate + error taxonomy (and partial counts)."""
+        reports failure rate + error breakdown (and partial counts)."""
         from pixie_tpu.services.load_tester import (
             broker_executor,
             run_load,
@@ -984,10 +984,10 @@ class TestLoadUnderFaults:
         d = report.to_dict()
         assert d["queries"] == 6
         assert d["failure_rate"] == report.errors / 6
-        assert d["partials"] + d["errors"] >= 0  # taxonomy present
+        assert d["partials"] + d["errors"] >= 0  # breakdown present
         assert isinstance(d["errors_by_type"], dict)
         # With require_complete, dropped dispatches become ERRORS the
-        # report must taxonomize.
+        # report must break down.
         inj2 = FaultInjector(seed=SEED)
         inj2.drop("agent.pem-2.execute")
         bus.fault_injector = inj2

@@ -163,3 +163,52 @@ px.display(out)
         s = np.asarray(s)
         assert s[0] == -np.inf
         assert s[1] == 64.0
+
+
+class TestRowChunk:
+    """ops/pallas_groupby.row_chunk: the row block both kernels' call
+    sites pick — a multiple of 1024 dividing n, or all n rows (what the
+    chip's tiling of a 1-D 32-bit operand accepts; the compiles against
+    it are in tests/test_tpu_compile.py)."""
+
+    @pytest.mark.parametrize("n,cap,want", [
+        (1 << 21, 2048, 2048),
+        (1 << 21, 1024, 1024),
+        (1024, 2048, 1024),
+        (3072, 2048, 1024),
+        (128, 2048, 128),     # below the tile: the whole array
+        (1536, 2048, 1536),   # not a 1024 multiple, fits one block
+        (1536, 1024, None),   # no block the tiling accepts: XLA path
+        (5000, 2048, None),
+    ])
+    def test_block(self, n, cap, want):
+        from pixie_tpu.ops.pallas_groupby import row_chunk
+
+        assert row_chunk(n, cap) == want
+
+    def test_odd_window_stays_on_xla_segment_sum(self):
+        """A window with no valid block keeps batch_to_digest on the
+        scatter path even when the kernel is asked for by name."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from pixie_tpu.config import override_flag
+        from pixie_tpu.ops import pallas_tdigest
+        from pixie_tpu.ops.tdigest import batch_to_digest, digest_quantile
+
+        n = 5000
+        rng = np.random.default_rng(0)
+        vals = jnp.asarray(rng.lognormal(3.0, 1.0, n).astype(np.float32))
+        gids = jnp.zeros(n, jnp.int32)
+        mask = jnp.ones(n, bool)
+        called = []
+        orig = pallas_tdigest.hist_fold
+        pallas_tdigest.hist_fold = lambda *a, **k: called.append(1) or orig(*a, **k)
+        try:
+            with override_flag("pallas_tdigest", "interpret"):
+                q = digest_quantile(batch_to_digest(vals, gids, mask, 1), (0.5,))
+        finally:
+            pallas_tdigest.hist_fold = orig
+        assert not called
+        ref = np.quantile(np.asarray(vals), 0.5)
+        assert abs(float(q[0, 0]) - ref) / ref < 0.05

@@ -122,3 +122,49 @@ class TestELFReader:
         p.write_bytes(b"not an elf")
         with _pytest.raises(ELFError):
             ELFReader(str(p))
+
+
+class TestJaxCacheDir:
+    """utils/cache.py: the caller's JAX_COMPILATION_CACHE_DIR is the
+    cache; unset, it is <checkout>/.jax_cache — never a path built from
+    /tmp, a pid, a time or the host."""
+
+    def test_env_wins(self):
+        from pixie_tpu.utils.cache import jax_cache_dir
+
+        assert jax_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/some/dir"}) == "/some/dir"
+
+    def test_default_is_in_the_checkout(self):
+        import os
+
+        from pixie_tpu.utils.cache import jax_cache_dir
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax_cache_dir({}) == os.path.join(repo, ".jax_cache")
+        assert jax_cache_dir({"JAX_COMPILATION_CACHE_DIR": ""}) == jax_cache_dir({})
+
+    def test_configure_sets_this_process(self):
+        """configure_jax_cache() goes through jax.config (the env var is
+        read once, at jax's import) and names no other directory."""
+        import jax
+
+        from pixie_tpu.utils.cache import configure_jax_cache, jax_cache_dir
+
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            assert configure_jax_cache() == jax_cache_dir()
+            assert jax.config.jax_compilation_cache_dir == jax_cache_dir()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+
+    @pytest.mark.parametrize("base", [{}, {"JAX_COMPILATION_CACHE_DIR": "/x/y"}])
+    def test_cpu_env_for_children(self, base):
+        from pixie_tpu.utils.cache import cpu_env, jax_cache_dir
+
+        env = cpu_env(n_devices=4, base={**base, "XLA_FLAGS": "--foo "
+                      "--xla_force_host_platform_device_count=8"})
+        assert env["JAX_PLATFORMS"] == "cpu"
+        assert env["JAX_COMPILATION_CACHE_DIR"] == jax_cache_dir(base)
+        assert env["XLA_FLAGS"].split() == [
+            "--foo", "--xla_force_host_platform_device_count=4"
+        ]
