@@ -323,8 +323,9 @@ define_flag(
 )
 define_flag(
     "trace_window_sample", 64,
-    "Record one per-window stage/compute/stall interval span every N "
-    "windows per fragment (1 = every window, 0 = no window spans). "
+    "window.stage / window.stall spans: every interval of a stage up "
+    "to N, then every Nth (1 = every window, 0 = no window spans; the "
+    "device.dispatch / device.wait spans are never sampled). "
     "Timestamps only — never forces device sync.",
 )
 define_flag(

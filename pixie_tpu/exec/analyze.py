@@ -8,12 +8,12 @@ the unit of execution is a compiled *fragment* (a whole Map/Filter/Agg
 chain), so stats attach per fragment with a per-stage wall-time
 breakdown of the TPU streaming pipeline:
 
-- ``read``     host slab -> host window (cursor read)
 - ``stage``    host -> device transfer + padding (zero when the window
                was already device-resident)
-- ``compute``  device program (update/fold), measured to completion
-- ``finalize`` agg finalize program
-- ``materialize`` device -> host copy + host batch assembly
+- ``compute``  device program (update/fold): to completion under
+               ``analyze``; without it, what enqueueing costs the host
+- ``finalize`` agg finalize program (likewise)
+- ``materialize`` host batch assembly, once the bytes are on the host
 - ``stall``    consumer time blocked waiting on the window-prefetch
                pipeline (pipeline_depth > 1); high stall with low stage
                time means the device, not staging, is the bottleneck
@@ -32,6 +32,7 @@ thread, so FragmentStats.add is lock-protected.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -68,7 +69,10 @@ class FragmentStats:
     )
 
     def add(self, stage: str, seconds: float, rows: int = 0,
-            nbytes: int = 0) -> None:
+            nbytes: int = 0, start_ns: int = 0, end_ns: int = 0) -> None:
+        """``start_ns``/``end_ns`` are the interval's two ends on
+        ``time.perf_counter_ns()``, stamped where it ran; a traced
+        fragment (``trace.py``) makes a span of them."""
         with self._lock:
             s = self.stages.setdefault(stage, StageStat())
             s.seconds += seconds
@@ -78,6 +82,16 @@ class FragmentStats:
 
     def timed(self, stage: str, rows: int = 0, nbytes: int = 0):
         return _Timer(self, stage, rows, nbytes)
+
+    def subspan(self, name: str, **attrs):
+        """A named span under this fragment; nothing without a trace."""
+        return contextlib.nullcontext()
+
+    def dispatch(self, program: str, stage: str = "compute",
+                 windows: int = 1):
+        """Around ONE program's enqueue: the ``stage`` timer, and on a
+        traced fragment a ``device.dispatch`` span as well."""
+        return self.timed(stage)
 
     def to_dict(self) -> dict:
         # Snapshot under the lock: /debug/queryz renders IN-FLIGHT
@@ -108,13 +122,14 @@ class _Timer:
         self.nbytes = nbytes
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
         self.stats.add(
-            self.stage, time.perf_counter() - self.t0, self.rows,
-            self.nbytes,
+            self.stage, (t1 - self.t0) / 1e9, self.rows, self.nbytes,
+            start_ns=self.t0, end_ns=t1,
         )
 
 

@@ -20,6 +20,10 @@ from ..types.strings import NULL_ID, StringDictionary
 from .fragment import ColumnMeta, compile_fragment_cached as compile_fragment
 from .plan import AggOp
 from .stream import (
+    _device_wait,
+    _dispatch,
+    _fetch_result,
+    _timed,
     QueryError,
     _double_agg_groups,
     _Stream,
@@ -175,14 +179,21 @@ def bridge_payload(engine, res):
                 else None
             )
             state = engine._fold_agg_state(res, frag, stats)
-            if not bool(np.asarray(state["overflow"])):
+            # The fragment's sync: the fold has run when its overflow
+            # flag is on the host; then the state that ships, a copy a
+            # leaf (as ever: see stream._fetch_result).
+            with _device_wait(stats):
+                overflowed = bool(np.asarray(state["overflow"]))
+                if not overflowed:
+                    state = jax.tree_util.tree_map(np.asarray, state)
+            if not overflowed:
                 break
             res = _double_agg_groups(res)  # rebucket before shipping
         return AggStatePayload(
             chain=tuple(res.chain),
             input_relation=res.relation,
             input_dicts=dict(res.dicts),
-            state=jax.tree_util.tree_map(np.asarray, state),
+            state=state,
             dense_domains=frag.dense_domains,
             dense_offsets=frag.dense_offsets,
             dense_strides=frag.dense_strides,
@@ -221,6 +232,10 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
     from ..types.dtypes import device_dtypes
 
     p0 = pending.payloads[0]
+    # The merge-and-finalize half records onto the query's trace spine
+    # as a fragment of its own: its programs and its wait are spans.
+    qstats = getattr(engine, "_query_stats", None)
+    stats = qstats.new_fragment(p0.chain) if qstats is not None else None
     # The merge fragment is compiled WITHOUT dense mode: agents encode
     # against their own dictionaries, so dense slot spaces are not
     # comparable across payloads — expand each dense state to explicit
@@ -348,9 +363,13 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
         padded = [jax.tree_util.tree_map(pad, s, init) for s in states]
         acc = padded[0]
         for s in padded[1:]:
-            acc = merge(acc, s)
-        cols, valid, overflow = frag.finalize(acc)
-        if not bool(overflow):
+            with _dispatch(stats, frag.merge_states):
+                acc = merge(acc, s)
+        with _dispatch(stats, frag.finalize, "finalize"):
+            cols, valid, overflow = frag.finalize(acc)
+        with _device_wait(stats):
+            overflowed = bool(overflow)
+        if not overflowed:
             break
         from ..config import get_flag
 
@@ -380,4 +399,7 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
         )
         for m in frag.out_meta
     ]
-    return _to_host_batch(meta, cols, np.asarray(valid))
+    with _device_wait(stats):
+        cols, valid = _fetch_result(meta, cols, valid)
+    with _timed(stats, "materialize"):
+        return _to_host_batch(meta, cols, valid)

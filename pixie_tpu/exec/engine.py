@@ -79,8 +79,11 @@ from .stream import (  # noqa: F401  (re-exported)
     _chain_out_relation,
     _col,
     _concat_host,
+    _device_wait,
+    _dispatch,
     _double_agg_groups,
     _empty_host_batch,
+    _fetch_result,
     _Stream,
     _stream_col_stats,
     _timed,
@@ -132,7 +135,13 @@ class DeviceResult:
         eng, stream, frag = self._engine, self._stream, self._frag
         cols, valid, overflow = self._cols, self._valid, self._overflow
         stats = self._stats
-        while bool(overflow):
+        while True:
+            # The aggregate's first sync: the finalize program has run
+            # when its overflow flag is on the host.
+            with _device_wait(stats):
+                overflowed = bool(overflow)
+            if not overflowed:
+                break
             # NOTE: the rebucket re-folds the source table AS IT IS NOW —
             # rows appended between execute and to_host are included,
             # unlike the no-overflow snapshot. Callers needing snapshot
@@ -152,11 +161,13 @@ class DeviceResult:
                 stats = self._qstats.new_fragment(stream.chain)
                 stats.ops = stats.ops + ("rebucket",)
             state = eng._fold_agg_state(stream, frag, stats)
-            with _timed(stats, "finalize"):
+            with _dispatch(stats, frag.finalize, "finalize"):
                 cols, valid, overflow = frag.finalize(state)
                 _block_if(stats, (cols, valid, overflow))
+        with _device_wait(stats):
+            cols, valid = _fetch_result(frag.out_meta, cols, valid)
         with _timed(stats, "materialize"):
-            out = _to_host_batch(frag.out_meta, cols, np.asarray(valid))
+            out = _to_host_batch(frag.out_meta, cols, valid)
         if stats is not None:
             stats.rows_out = out.length
         self._host = _apply_limit(out, frag.limit)
@@ -615,9 +626,10 @@ class Engine:
             )
         status, error = "ok", ""
         try:
-            return self._execute_plan_scoped(
-                plan, bridge_inputs, analyze, materialize, cancel, trace
-            )
+            with trace.annotation():
+                return self._execute_plan_scoped(
+                    plan, bridge_inputs, analyze, materialize, cancel, trace
+                )
         except QueryCancelled as e:
             status, error = "cancelled", str(e)
             raise
@@ -964,14 +976,21 @@ class Engine:
             if not pend_cols:
                 return state
             if len(pend_cols) == 1:
-                state = agg_step(state, pend_cols[0], (pend_lo[0], pend_hi[0]))
+                with _dispatch(stats, agg_step):
+                    state = agg_step(
+                        state, pend_cols[0], (pend_lo[0], pend_hi[0])
+                    )
+                    _block_if(stats, state)
             else:
-                state = frag.update_all(
-                    state, tuple(pend_cols),
-                    # Host int lists, not device buffers — no sync.
-                    np.asarray(pend_lo, dtype=np.int32),  # pxlint: disable=host-sync-hot-path
-                    np.asarray(pend_hi, dtype=np.int32),  # pxlint: disable=host-sync-hot-path
-                )
+                with _dispatch(stats, frag.update_all,
+                               windows=len(pend_cols)):
+                    state = frag.update_all(
+                        state, tuple(pend_cols),
+                        # Host int lists, not device buffers — no sync.
+                        np.asarray(pend_lo, dtype=np.int32),  # pxlint: disable=host-sync-hot-path
+                        np.asarray(pend_hi, dtype=np.int32),  # pxlint: disable=host-sync-hot-path
+                    )
+                    _block_if(stats, state)
             pend_cols.clear()
             pend_lo.clear()
             pend_hi.clear()
@@ -988,26 +1007,25 @@ class Engine:
                         or _window_shapes(cols) == _window_shapes(pend_cols[0])
                     )
                 )
-                with _timed(stats, "compute"):
-                    if batchable:
-                        pend_cols.append(cols)
-                        pend_lo.append(valid[0])
-                        pend_hi.append(valid[1])
-                        if len(pend_cols) >= chunk_w:
-                            state = flush_pending(state)
-                    else:
+                # A resident window joins the pending run (no program
+                # runs for it yet); each enqueue is one device.dispatch.
+                if batchable:
+                    pend_cols.append(cols)
+                    pend_lo.append(valid[0])
+                    pend_hi.append(valid[1])
+                    if len(pend_cols) >= chunk_w:
                         state = flush_pending(state)
+                else:
+                    state = flush_pending(state)
+                    with _dispatch(stats, agg_step):
                         state = agg_step(state, cols, valid)
-                    _block_if(stats, state)
+                        _block_if(stats, state)
                 if stats is not None:
                     stats.windows += 1
         finally:
             pipe.close()
             self._note_pipeline(pipe)
-        with _timed(stats, "compute"):
-            state = flush_pending(state)
-            _block_if(stats, state)
-        return state
+        return flush_pending(state)
 
     def _fold_agg_state_native(self, stream: "_Stream", frag, stats=None):
         """Fold via the native multi-core segmented-fold kernel.
@@ -1418,7 +1436,7 @@ class Engine:
 
         if frag.is_agg:
             state = self._fold_agg_state(stream, frag, stats)
-            with _timed(stats, "finalize"):
+            with _dispatch(stats, frag.finalize, "finalize"):
                 cols, valid, overflow = frag.finalize(state)
                 _block_if(stats, (cols, valid, overflow))
             return DeviceResult(
@@ -1432,15 +1450,17 @@ class Engine:
         pipe = self._window_pipeline(stream, stats)
         try:
             for cols, valid in pipe:
-                with _timed(stats, "compute"):
+                with _dispatch(stats, rows_step):
                     out_cols, out_valid = rows_step(cols, valid)
                     _block_if(stats, (out_cols, out_valid))
                 if stats is not None:
                     stats.windows += 1
-                with _timed(stats, "materialize"):
-                    piece = _to_host_batch(
-                        frag.out_meta, out_cols, np.asarray(out_valid)
+                with _device_wait(stats):
+                    out_cols, out_valid = _fetch_result(
+                        frag.out_meta, out_cols, out_valid
                     )
+                with _timed(stats, "materialize"):
+                    piece = _to_host_batch(frag.out_meta, out_cols, out_valid)
                 pieces.append(piece)
                 total += piece.length
                 if frag.limit is not None and total >= frag.limit:

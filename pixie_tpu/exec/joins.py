@@ -650,7 +650,7 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
 
     from ..config import get_flag
     from .pipeline import WindowPipeline
-    from .stream import _block_if, _timed
+    from .stream import _block_if, _device_wait, _dispatch, _timed
 
     if decision is None:
         decision = JoinDecision(strategy="sorted", window_rows=window_rows)
@@ -822,25 +822,27 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
     try:
         run = probe_fn(capacity)
         for off, pk_dev, pv_dev in pipe:
-            with _timed(stats, "compute"):
-                while True:
-                    # The per-window readback is the driver's consume
-                    # step: compacting each window host-side bounds
-                    # memory to one window's capacity, and the overflow
-                    # flag rides in the same batch (no extra sync, no
-                    # per-window bool(overflow) readback).
+            while True:
+                with _dispatch(stats, run):
+                    out = run(pk_dev, pv_dev)
+                # The per-window readback is the driver's consume
+                # step: compacting each window host-side bounds
+                # memory to one window's capacity, and the overflow
+                # flag rides in the same batch (no extra sync, no
+                # per-window bool(overflow) readback).
+                with _device_wait(stats):
                     p_idx, p_take, b_idx, b_take, out_valid, overflow = (
-                        np.asarray(a) for a in run(pk_dev, pv_dev)  # pxlint: disable=host-sync-hot-path
+                        np.asarray(a) for a in out  # pxlint: disable=host-sync-hot-path
                     )
-                    if not bool(overflow):
-                        break
-                    # Estimate/learned rung was wrong: double, recompile
-                    # (counted — the bench gate wants this at zero), and
-                    # keep the larger capacity for every later window.
-                    capacity *= 2
-                    counter.inc()
-                    decision.retries += 1
-                    run = probe_fn(capacity)
+                if not bool(overflow):
+                    break
+                # Estimate/learned rung was wrong: double, recompile
+                # (counted — the bench gate wants this at zero), and
+                # keep the larger capacity for every later window.
+                capacity *= 2
+                counter.inc()
+                decision.retries += 1
+                run = probe_fn(capacity)
             if stats is not None:
                 stats.windows += 1
             compact(off, p_idx, p_take, b_idx, b_take, out_valid)

@@ -32,8 +32,8 @@ Instrumentation: the pipeline tracks ``windows``, ``stage_secs``
 waiting for a window). The per-window stall intervals also land in the
 query's fragment stats (stage ``"stall"``) — always on since the trace
 spine (``trace.py``) passes stats for every query, feeding the
-``pixie_window_stage_seconds{stage="stall"}`` histogram and sampled
-``window.stall`` spans; engines accumulate per-query and lifetime
+``pixie_window_stage_seconds{stage="stall"}`` histogram and
+``window.stall`` spans (both ends stamped here, where it stalls); engines accumulate per-query and lifetime
 totals for bench.py's overlap report and the observability gauges.
 """
 
@@ -206,7 +206,7 @@ class WindowPipeline:
         try:
             while True:
                 self._check_cancel()
-                t0 = time.perf_counter()
+                t0 = time.perf_counter_ns()
                 # Samples landing while we block on the producer are
                 # wait-for-staging, not compute: flag them "stall" so
                 # the flame separates starvation from real host work.
@@ -215,10 +215,11 @@ class WindowPipeline:
                     kind, val = self._get()
                 finally:
                     threadmap.restore(tm)
-                dt = time.perf_counter() - t0
+                t1 = time.perf_counter_ns()
+                dt = (t1 - t0) / 1e9
                 self.stall_secs += dt
                 if self._stats is not None:
-                    self._stats.add("stall", dt)
+                    self._stats.add("stall", dt, start_ns=t0, end_ns=t1)
                 if kind == "done":
                     return
                 if kind == "error":

@@ -233,6 +233,13 @@ class TrackedProgram:
         self._label = label
         self._pins = pins
 
+    @property
+    def kind(self) -> str:
+        """The program's name in the registry (``fragment_update``,
+        ``join_probe_sorted``...): what a ``device.dispatch`` span
+        carries as ``program``."""
+        return self._kind
+
     def __call__(self, *args):
         reg = self._registry
         # Profiler phase bracket: samples landing while the program
@@ -768,12 +775,15 @@ class DeviceMemoryMonitor:
         self._stop.clear()
 
         def run():
+            from .trace import background
+
             while not self._stop.wait(period):
-                peak = self._in_use()
-                with self._lock:
-                    for token in self._open:
-                        if peak > token["peak"]:
-                            token["peak"] = peak
+                with background.turn("device_memory.poll"):
+                    peak = self._in_use()
+                    with self._lock:
+                        for token in self._open:
+                            if peak > token["peak"]:
+                                token["peak"] = peak
 
         self._thread = threading.Thread(
             target=run, name="device-memory-poll", daemon=True

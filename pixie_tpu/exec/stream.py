@@ -12,6 +12,7 @@ one fused XLA fragment instead of a node-per-op push loop.
 
 from __future__ import annotations
 
+import contextlib
 import json
 from dataclasses import dataclass, field
 from typing import Optional
@@ -139,14 +140,35 @@ def _window_shapes(cols) -> tuple:
     )
 
 
+#: What the stage helpers return without stats (reusable, reentrant).
+_NO_STATS = contextlib.nullcontext()
+
+
 def _timed(stats, stage: str, rows: int = 0, nbytes: int = 0):
     """Stage timer context (no-op without stats) — keeps the analyze and
     plain execution paths one code path."""
     if stats is None:
-        import contextlib
-
-        return contextlib.nullcontext()
+        return _NO_STATS
     return stats.timed(stage, rows, nbytes)
+
+
+def _dispatch(stats, fn, stage: str = "compute", windows: int = 1):
+    """Around ONE program's enqueue call: the stage timer plus, on a
+    traced fragment, a ``device.dispatch`` span named for the program as
+    ``ProgramRegistry`` names its kind (no-op without stats)."""
+    if stats is None:
+        return _NO_STATS
+    program = getattr(fn, "kind", None) or getattr(fn, "__name__", "program")
+    return stats.dispatch(program, stage, windows)
+
+
+def _device_wait(stats):
+    """Around the sync the path has anyway — the host asks for a result
+    until its bytes are on the host: a ``device.wait`` span on a traced
+    fragment (no-op without stats). Never adds a sync of its own."""
+    if stats is None:
+        return _NO_STATS
+    return stats.subspan("device.wait")
 
 
 def _block_if(stats, x) -> None:
@@ -162,6 +184,21 @@ def _block_if(stats, x) -> None:
 
 
 # -- host-batch assembly ------------------------------------------------------
+def _fetch_result(meta_list, cols, valid):
+    """A result's validity and planes to the host — what a
+    ``device.wait`` span is put around. One copy a plane, the planes
+    ``_to_host_batch`` reads and in its order, exactly the copies the
+    path made when they were interleaved with the assembly (one batched
+    ``jax.device_get`` is fewer round trips: PERF.md, PR 25, left to a
+    ``perf_opt`` issue). Returns (host cols, host valid)."""
+    valid = np.asarray(valid)
+    host: dict = {}
+    for m in meta_list:
+        n = 1 if m.struct_fields is not None else len(host_dtypes(m.dtype))
+        host[m.name] = tuple(np.asarray(p) for p in cols[m.name][:n])
+    return host, valid
+
+
 def _to_host_batch(meta_list, cols, valid) -> HostBatch:
     idx = np.nonzero(valid)[0]
     out_cols: dict = {}

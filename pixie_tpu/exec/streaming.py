@@ -35,7 +35,10 @@ from .engine import (
     Engine,
     QueryCancelled,
     QueryError,
+    _device_wait,
+    _dispatch,
     _double_agg_groups,
+    _fetch_result,
     _stream_col_stats,
     _Stream,
     _timed,
@@ -373,7 +376,7 @@ class StreamingQuery:
             for cols, valid, (wm_key, wm_hi) in pipe:
                 self._check_cancel()
                 if cols is not None:
-                    with _timed(st, "compute"):
+                    with _dispatch(st, frag.update):
                         self._state = frag.update(self._state, cols, valid)
                     w_rows = int(valid[1] - valid[0])
                     rows += w_rows
@@ -458,12 +461,14 @@ class StreamingQuery:
                     # is nothing to emit — just advance the watermark.
                     self._wm[wm_key] = wm_hi
                     continue
-                with _timed(st, "compute"):
+                with _dispatch(st, frag.update):
                     out_cols, out_valid = frag.update(cols, valid)
-                with _timed(st, "materialize"):
-                    hb = _to_host_batch(
-                        frag.out_meta, out_cols, np.asarray(out_valid)
+                with _device_wait(st):
+                    out_cols, out_valid = _fetch_result(
+                        frag.out_meta, out_cols, out_valid
                     )
+                with _timed(st, "materialize"):
+                    hb = _to_host_batch(frag.out_meta, out_cols, out_valid)
                 if st is not None:
                     st.windows += 1
                     st.rows_in += int(valid[1] - valid[0])
@@ -537,12 +542,14 @@ class StreamingQuery:
                     # the watermark; no rows survive to ship.
                     self._wm[wm_key] = wm_hi
                     continue
-                with _timed(st, "compute"):
+                with _dispatch(st, frag.update):
                     out_cols, out_valid = frag.update(cols, valid)
-                with _timed(st, "materialize"):
-                    hb = _to_host_batch(
-                        frag.out_meta, out_cols, np.asarray(out_valid)
+                with _device_wait(st):
+                    out_cols, out_valid = _fetch_result(
+                        frag.out_meta, out_cols, out_valid
                     )
+                with _timed(st, "materialize"):
+                    hb = _to_host_batch(frag.out_meta, out_cols, out_valid)
                 rows += int(valid[1] - valid[0])
                 if st is not None:
                     st.windows += 1

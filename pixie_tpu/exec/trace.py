@@ -10,37 +10,54 @@ boundaries, never ``block_until_ready`` — kept in a bounded ring buffer
 and optionally pushed over the engine's own OTLP path (dogfooding
 ``exec/otel.py``'s span dicts through ``OTLPHttpExporter``).
 
+**One clock.** Every span, on every thread of the process, takes both
+its ends from ``time.perf_counter_ns()`` (``clock_ns``), where the work
+runs; nothing is back-dated from a duration. ``start_unix_nano`` /
+``end_unix_nano`` (OTLP, ``/debug/queryz``) are reckoned from one
+wall-clock anchor taken once a process. Entering a span also enters a
+``jax.profiler.TraceAnnotation`` of the same name (``qid`` attached),
+so a profiler session's ``.xplane.pb`` holds the program's spans on the
+host threads' lines, on the device operations' own clock; with no
+session on that is a flag test.
+
 Span hierarchy (one trace per ``Engine.execute_plan`` /
-``StreamingQuery`` lifetime):
+``StreamingQuery`` lifetime; the broker's and the agents' stamps are in
+``services/query_broker.py`` and ``services/agent.py``):
 
 - ``query``               root; status/script-hash/row-count attributes
 - ``compile``             parse + PxL compile + plan (execute_query path)
 - ``fragment``            one per compiled fragment actually executed
   (Map/Filter/Agg chain, join driver, rebucket attempt); attributes
   carry windows, rows in/out and the per-stage second totals
-- ``window.<stage>``      sampled per-window stage/compute/stall
-  intervals (every ``trace_window_sample``-th interval per stage),
-  children of their fragment span
+- ``device.dispatch``     one per program enqueued: the host's enqueue
+  call (attributes ``program`` as ``ProgramRegistry`` names its kind,
+  ``windows``); child of its fragment
+- ``device.wait``         the host asks for a result until the bytes are
+  on the host, at the sync the path has anyway
+- ``window.stage`` / ``window.stall`` / ``materialize``  windows that
+  are staged, the consumer blocked on the prefetch pipe, host batch
+  assembly after the wait. Every interval of a stage up to
+  ``trace_window_sample``, then every that-many-th.
 
 The stats spine is shared with ``analyze`` (``analyze.py``): a trace
 owns a ``QueryStats`` whose fragments the engine fills exactly as
 before; ``analyze=True`` just flips ``sync=True`` on that object, so
-analyze is a *detail level* of the same trace, not a separate path.
+analyze is a *detail level* of the same trace, not a separate path
+(a ``device.dispatch`` then lasts until the program has run).
 
-Because compute stamps are taken without fencing the device, a window's
-``compute`` interval measures **dispatch** time (host-side cost of
-enqueueing the program) and ``stall`` measures where the query thread
-actually waited — which is exactly the signal sketch/telemetry-driven
-optimization wants (arXiv:2102.02440, arXiv:2506.20010): where does
-wall-clock go, without perturbing it.
+**Background work** is not a query trace: each turn of a periodic task
+(heartbeats, sweeps, telemetry folds, the memory poller, collections of
+the garbage collector) is one entry of the process-wide ``background``
+ring, on the same clock, so it can be laid over a late request. It
+reaches no listener, no ``__queries__`` row and no query counter.
 
 Consumers:
 
-- ``Tracer.recent()`` / ``in_flight()`` — served by
-  ``ObservabilityServer`` as ``/debug/queryz``
+- ``Tracer.recent()`` / ``in_flight()`` and ``background.entries()`` —
+  served by ``ObservabilityServer`` as ``/debug/queryz``
 - Prometheus histograms on the shared ``MetricsRegistry``
-  (``pixie_query_duration_seconds``, ``pixie_window_stage_seconds``,
-  ``pixie_pipeline_stall_seconds``) — ``/metrics``
+  (``pixie_query_duration_seconds``, ``pixie_window_stage_seconds``)
+  — ``/metrics``
 - the slow-query log (``slow_query_threshold_ms`` flag): offending
   queries dump their full trace to the ``pixie_tpu.slow_query`` logger
 - OTLP/HTTP push of finished traces when ``trace_export_url`` is set
@@ -50,6 +67,7 @@ Consumers:
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import json
 import logging
@@ -61,7 +79,7 @@ from dataclasses import asdict, dataclass, field
 
 from ..config import get_flag
 from . import tracectx
-from .analyze import FragmentStats, QueryStats, StageStat
+from .analyze import FragmentStats, QueryStats, StageStat, _Timer
 
 logger = logging.getLogger("pixie_tpu.slow_query")
 
@@ -83,12 +101,48 @@ BYTES_BUCKETS = (
     1 << 10, 1 << 14, 1 << 18, 1 << 22, 1 << 26, 1 << 30, 1 << 34,
 )
 
-#: Millisecond buckets (per-query device dispatch time).
+#: Millisecond buckets (per-query device time).
 MS_BUCKETS = (0.1, 1.0, 10.0, 100.0, 1_000.0, 10_000.0, 100_000.0)
+
+#: Stages whose timed intervals become spans, and the span's name.
+#: ``compute`` and ``finalize`` are not here: a program's enqueue is a
+#: ``device.dispatch`` span, stamped by ``TracedFragment.dispatch``.
+STAGE_SPANS = {
+    "stage": "window.stage", "stall": "window.stall",
+    "materialize": "materialize",
+}
+
+#: THE clock: both ends of every span and of every background entry,
+#: on every thread. It is the clock of ``time.perf_counter()``, which
+#: callers (the benchmark's driver) time requests with.
+clock_ns = time.perf_counter_ns
+
+#: Wall-clock anchor, taken once a process: unix ns = anchor + clock.
+_UNIX_ANCHOR_NS = time.time_ns() - clock_ns()
+
+
+def unix_ns(ns: int) -> int:
+    """A ``clock_ns`` reading as unix nanoseconds (0 stays 0: unset)."""
+    return _UNIX_ANCHOR_NS + ns if ns else 0
 
 
 def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
+
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, qid: str = ""):
+    """A ``jax.profiler.TraceAnnotation`` (a context manager): the
+    benchmark's ``harness.mark`` for the program's own spans. Entering
+    it is a flag test unless a profiler session is on."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, qid=qid) if qid else _TraceAnnotation(name)
 
 
 @dataclass
@@ -102,8 +156,9 @@ class QueryResourceUsage:
       (0 for device-cache-resident windows — those were staged at
       append time; the gap between rows_in and bytes_staged IS the
       cache-hit signal)
-    - ``device_ms``     host-side dispatch time of device programs
-      (compute + finalize stage seconds; dispatch, not fenced runtime)
+    - ``device_ms``     the time the query had work on the device or
+      was waiting for it: each fragment's first ``device.dispatch``
+      start to its last ``device.wait`` end, summed over fragments
     - ``compile_ms``    the compile span (parse + PxL + plan + verify)
     - ``stall_ms``      query-thread time blocked on the prefetch pipe
     - ``wire_bytes``    bridge payload bytes this query SHIPPED
@@ -182,9 +237,17 @@ class Span:
     trace_id: str
     span_id: str = field(default_factory=lambda: _new_id(8))
     parent_id: str = ""
-    start_unix_nano: int = 0
-    end_unix_nano: int = 0
+    start_ns: int = 0  # both on ``clock_ns``; 0 = not stamped yet
+    end_ns: int = 0
     attributes: dict = field(default_factory=dict)
+
+    @property
+    def start_unix_nano(self) -> int:
+        return unix_ns(self.start_ns)
+
+    @property
+    def end_unix_nano(self) -> int:
+        return unix_ns(self.end_ns)
 
     def to_otlp(self) -> dict:
         from .otel import _attr_kvs
@@ -203,38 +266,86 @@ class Span:
 
 
 class _SpanCtx:
-    """Context manager stamping a span's start/end around a block."""
+    """Context manager stamping a span's start/end around a block (and
+    the profiler's annotation of the same name)."""
 
-    def __init__(self, trace: "QueryTrace", name: str, parent: Span | None):
+    def __init__(self, trace: "QueryTrace", name: str, parent: Span | None,
+                 attrs: dict | None = None):
+        self.trace = trace
         self.span = trace._new_span(name, parent)
+        if attrs:
+            self.span.attributes.update(attrs)
 
     def __enter__(self) -> Span:
-        self.span.start_unix_nano = time.time_ns()
+        self._ann = _annotation(self.span.name, self.trace.qid)
+        self._ann.__enter__()
+        self.span.start_ns = clock_ns()
         return self.span
 
     def __exit__(self, exc_type, exc, tb):
-        self.span.end_unix_nano = time.time_ns()
+        self.span.end_ns = clock_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         if exc is not None:
             self.span.attributes["error"] = f"{type(exc).__name__}: {exc}"
 
 
+class _FragmentSpanCtx(_SpanCtx):
+    """A span under a fragment's span. ``device.*`` spans move the
+    fragment's device interval; one with a ``stage`` also feeds that
+    stage's timer (a program's enqueue is both)."""
+
+    def __init__(self, frag: "TracedFragment", name: str,
+                 attrs: dict | None = None, stage: str | None = None):
+        super().__init__(frag.trace, name, frag.span, attrs)
+        self.frag = frag
+        self.stage = stage
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        sp = self.span
+        if self.stage is not None:
+            self.frag.add(self.stage, (sp.end_ns - sp.start_ns) / 1e9,
+                          start_ns=sp.start_ns, end_ns=sp.end_ns)
+        if sp.name.startswith("device."):
+            self.frag._note_device(sp.start_ns, sp.end_ns)
+
+
+class _AnnotatedTimer(_Timer):
+    def __init__(self, stats, stage, rows, nbytes, name):
+        super().__init__(stats, stage, rows, nbytes)
+        self.name = name
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, self.stats.trace.qid)
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
+
+
 class TracedFragment(FragmentStats):
-    """FragmentStats that additionally owns a ``fragment`` span and
-    records sampled per-window stage-interval spans + stage histograms.
-    ``add`` runs on both the query thread (compute/stall) and the
-    prefetch thread (stage) — the inherited lock covers both."""
+    """FragmentStats that additionally owns a ``fragment`` span, the
+    spans under it, and the stage histograms. ``add`` runs on both the
+    query thread (compute/stall) and the prefetch thread (stage) — the
+    inherited lock covers both."""
 
     def __init__(self, ops: tuple, trace: "QueryTrace", sync: bool):
         super().__init__(ops=ops, sync=sync)
         self.trace = trace
         self.span = trace._new_span("fragment", trace.root)
-        self.span.start_unix_nano = time.time_ns()
+        self.span.start_ns = clock_ns()
         self.span.attributes["ops"] = ",".join(ops) or "(join)"
-        self.last_activity_ns = self.span.start_unix_nano
+        self.last_activity_ns = self.span.start_ns
+        # [first device.dispatch start, last device.* end]: the time
+        # this fragment had work on the device or waited for it.
+        self.device_first_ns = 0
+        self.device_last_ns = 0
 
     def add(self, stage: str, seconds: float, rows: int = 0,
-            nbytes: int = 0) -> None:
-        now_ns = time.time_ns()
+            nbytes: int = 0, start_ns: int = 0, end_ns: int = 0) -> None:
+        now_ns = end_ns or clock_ns()
         with self._lock:
             s = self.stages.setdefault(stage, StageStat())
             s.seconds += seconds
@@ -242,30 +353,63 @@ class TracedFragment(FragmentStats):
             s.count += 1
             s.nbytes += int(nbytes)
             count = s.count
-            self.last_activity_ns = now_ns
+            self.last_activity_ns = max(self.last_activity_ns, now_ns)
         tracer = self.trace.tracer
         if tracer is not None:
             tracer._observe_stage(stage, seconds)
+        name = STAGE_SPANS.get(stage)
         k = self.trace.window_sample
-        if k and (count - 1) % k == 0:
+        # A span needs its two stamps: a bare duration (the cold tier's
+        # decode meter) is a stage total only.
+        if (name and start_ns and end_ns and k
+                and (count <= k or (count - 1) % k == 0)):
             attrs = {"interval": count - 1}
             if rows:
                 attrs["rows"] = int(rows)
             self.trace._add_span(Span(
-                name=f"window.{stage}",
+                name=name,
                 trace_id=self.trace.trace_id,
                 parent_id=self.span.span_id,
-                start_unix_nano=now_ns - int(seconds * 1e9),
-                end_unix_nano=now_ns,
+                start_ns=start_ns,
+                end_ns=end_ns,
                 attributes=attrs,
             ))
+
+    def timed(self, stage: str, rows: int = 0, nbytes: int = 0):
+        """The stage timer; a stage that becomes a span is also the
+        profiler's annotation of that name while it runs."""
+        name = STAGE_SPANS.get(stage)
+        if name is None:
+            return super().timed(stage, rows, nbytes)
+        return _AnnotatedTimer(self, stage, rows, nbytes, name)
+
+    def subspan(self, name: str, **attrs) -> _FragmentSpanCtx:
+        return _FragmentSpanCtx(self, name, attrs)
+
+    def dispatch(self, program: str, stage: str = "compute",
+                 windows: int = 1) -> _FragmentSpanCtx:
+        return _FragmentSpanCtx(
+            self, "device.dispatch",
+            {"program": program, "windows": int(windows)}, stage=stage,
+        )
+
+    def _note_device(self, start_ns: int, end_ns: int) -> None:
+        with self._lock:
+            if not self.device_first_ns or start_ns < self.device_first_ns:
+                self.device_first_ns = start_ns
+            self.device_last_ns = max(self.device_last_ns, end_ns)
+            self.last_activity_ns = max(self.last_activity_ns, end_ns)
+
+    def device_ms(self) -> float:
+        with self._lock:
+            return (self.device_last_ns - self.device_first_ns) / 1e6
 
     def finish(self, end_ns: int) -> None:
         """Seal the fragment span (trace end): end timestamp = last
         host-side activity, attributes = the final counters."""
         with self._lock:
-            self.span.end_unix_nano = min(
-                max(self.last_activity_ns, self.span.start_unix_nano), end_ns
+            self.span.end_ns = min(
+                max(self.last_activity_ns, self.span.start_ns), end_ns
             ) or end_ns
             self.span.attributes.update({
                 "windows": self.windows,
@@ -299,6 +443,8 @@ class QueryTrace:
     def __init__(self, tracer: "Tracer | None", script: str = "",
                  analyze: bool = False, kind: str = "query",
                  parent_ctx: dict | None = None):
+        self.start_ns = clock_ns()
+        self.end_ns = 0
         self.tracer = tracer
         # A valid parent context (a broker dispatch span, carried in the
         # bus envelope — see tracectx.py) makes this trace PART of the
@@ -330,9 +476,6 @@ class QueryTrace:
         self.cache = ""
         self.status = "running"
         self.error = ""
-        self.start_unix_nano = time.time_ns()
-        self.end_unix_nano = 0
-        self._t0 = time.perf_counter()
         self.duration_s = 0.0
         self.window_sample = int(get_flag("trace_window_sample"))
         self.pipeline: dict | None = None  # engine.last_pipeline snapshot
@@ -349,11 +492,24 @@ class QueryTrace:
         self.dropped_spans = 0
         self._lock = threading.Lock()
         self.root = Span(
-            "query", self.trace_id, start_unix_nano=self.start_unix_nano,
+            "query", self.trace_id, start_ns=self.start_ns,
             parent_id=self.parent_ctx["span_id"] if self.parent_ctx else "",
         )
         self.spans: list[Span] = [self.root]
         self.stats = TraceStats(self, sync=analyze)
+
+    @property
+    def start_unix_nano(self) -> int:
+        return unix_ns(self.start_ns)
+
+    @property
+    def end_unix_nano(self) -> int:
+        return unix_ns(self.end_ns)
+
+    def annotation(self):
+        """The profiler's annotation of the trace's root while it runs
+        on the calling thread: ``query:<kind>``."""
+        return _annotation(f"query:{self.kind}", self.qid)
 
     def ctx(self, span: "Span | None" = None) -> dict:
         """The propagation envelope for children of ``span`` (default:
@@ -397,9 +553,25 @@ class QueryTrace:
                 return
             self.spans.append(span)
 
-    def span(self, name: str, parent: Span | None = None) -> _SpanCtx:
+    def span(self, name: str, parent: Span | None = None,
+             **attrs) -> _SpanCtx:
         """``with trace.span("compile"): ...`` — stamps start/end."""
-        return _SpanCtx(self, name, parent if parent is not None else self.root)
+        return _SpanCtx(
+            self, name, parent if parent is not None else self.root, attrs
+        )
+
+    def add_span(self, name: str, start_ns: int, end_ns: int,
+                 parent: Span | None = None, **attrs) -> Span:
+        """A span whose two ends were stamped (``clock_ns``) where they
+        happened but not around one block: a wait that begins in one
+        handler and ends in another."""
+        sp = Span(
+            name, self.trace_id,
+            parent_id=(parent if parent is not None else self.root).span_id,
+            start_ns=start_ns, end_ns=end_ns, attributes=attrs,
+        )
+        self._add_span(sp)
+        return sp
 
     # -- derived views -------------------------------------------------------
     @property
@@ -417,10 +589,10 @@ class QueryTrace:
     def _finalize(self, status: str, error: str) -> None:
         self.status = status
         self.error = error
-        self.end_unix_nano = time.time_ns()
-        self.duration_s = time.perf_counter() - self._t0
+        self.end_ns = clock_ns()
+        self.duration_s = (self.end_ns - self.start_ns) / 1e9
         self.stats.total_seconds = self.duration_s
-        self.root.end_unix_nano = self.end_unix_nano
+        self.root.end_ns = self.end_ns
         self._finalize_usage()
         self.root.attributes.update({
             "status": status,
@@ -438,13 +610,9 @@ class QueryTrace:
             self.root.attributes["agent_id"] = self.agent_id
         if error:
             self.root.attributes["error"] = error
-        if self.pipeline:
-            self.root.attributes["pipeline_stall_seconds"] = round(
-                self.pipeline.get("stall_secs", 0.0), 6
-            )
         for f in self.stats.fragments:
             if isinstance(f, TracedFragment):
-                f.finish(self.end_unix_nano)
+                f.finish(self.end_ns)
 
     def _finalize_usage(self) -> None:
         """Derive the resource record from the stats spine + spans.
@@ -460,10 +628,8 @@ class QueryTrace:
                 stages = {k: (v.seconds, v.nbytes, v.count)
                           for k, v in f.stages.items()}
             u.bytes_staged += stages.get("stage", (0.0, 0, 0))[1]
-            u.device_ms += (
-                stages.get("compute", (0.0, 0, 0))[0]
-                + stages.get("finalize", (0.0, 0, 0))[0]
-            ) * 1e3
+            if isinstance(f, TracedFragment):
+                u.device_ms += f.device_ms()
             u.stall_ms += stages.get("stall", (0.0, 0, 0))[0] * 1e3
             # Cold-tier stage adds: "decode" seconds ride the stage
             # timeline (producer thread); "skip" counts windows a zone
@@ -473,9 +639,9 @@ class QueryTrace:
         compile_span = next(
             (s for s in self.spans if s.name == "compile"), None
         )
-        if compile_span is not None and compile_span.end_unix_nano:
+        if compile_span is not None and compile_span.end_ns:
             u.compile_ms += (
-                compile_span.end_unix_nano - compile_span.start_unix_nano
+                compile_span.end_ns - compile_span.start_ns
             ) / 1e6
 
     def to_dict(self) -> dict:
@@ -488,8 +654,8 @@ class QueryTrace:
             "status": self.status,
             "start_unix_nano": self.start_unix_nano,
             "duration_ms": round(
-                (self.duration_s if self.end_unix_nano
-                 else time.perf_counter() - self._t0) * 1e3, 3
+                (self.duration_s if self.end_ns
+                 else (clock_ns() - self.start_ns) / 1e9) * 1e3, 3
             ),
             "rows_in": self.rows_in,
             "rows_out": self.rows_out,
@@ -614,10 +780,6 @@ class Tracer:
                     "sync)",
                     buckets=STAGE_BUCKETS,
                 ),
-                "stall": reg.histogram(
-                    "pixie_pipeline_stall_seconds",
-                    "Per-query total window-pipeline stall",
-                ),
                 "slow": reg.counter(
                     "pixie_slow_queries_total",
                     "Queries over slow_query_threshold_ms",
@@ -639,8 +801,9 @@ class Tracer:
                 ),
                 "device_ms": reg.histogram(
                     "pixie_query_device_ms",
-                    "Per-query device program dispatch milliseconds "
-                    "(compute + finalize stages; host-side, unfenced)",
+                    "Per-query milliseconds with work on the device or "
+                    "waiting for it (first device.dispatch start to last "
+                    "device.wait end, summed over fragments)",
                     buckets=MS_BUCKETS,
                 ),
                 "wire_bytes": reg.histogram(
@@ -711,8 +874,6 @@ class Tracer:
         m["bytes_staged"].observe(u.bytes_staged)
         m["device_ms"].observe(u.device_ms)
         m["wire_bytes"].observe(u.wire_bytes)
-        if trace.pipeline:
-            m["stall"].observe(trace.pipeline.get("stall_secs", 0.0))
         self._slow_query_check(trace, m)
         self._export(trace, m)
         self._notify(trace)
@@ -763,7 +924,7 @@ class Tracer:
     def in_flight(self) -> list:
         with self._lock:
             traces = sorted(
-                self._inflight.values(), key=lambda t: t.start_unix_nano
+                self._inflight.values(), key=lambda t: t.start_ns
             )
         return [t.to_dict() for t in traces]
 
@@ -786,6 +947,77 @@ class Tracer:
         """Most recently finished trace (None if the ring is empty)."""
         with self._lock:
             return self._ring[-1] if self._ring else None
+
+
+class BackgroundRing:
+    """What the process did besides queries: one entry a turn of each
+    periodic task, ``(name, thread, start_ns, end_ns)`` on ``clock_ns``,
+    newest last, bounded. Not query traces: no listener, no
+    ``__queries__`` row, no counter sees them."""
+
+    def __init__(self, size: int = 8192):
+        self._ring: deque = deque(maxlen=size)
+        self._gc_t0 = 0
+        self._gc_watched = False
+
+    def record(self, name: str, start_ns: int, end_ns: int) -> None:
+        # deque.append is atomic: no lock on any task's path.
+        self._ring.append(
+            (name, threading.current_thread().name, start_ns, end_ns)
+        )
+
+    def turn(self, name: str) -> "_Turn":
+        """``with background.turn("heartbeat"): ...``"""
+        return _Turn(self, name)
+
+    def entries(self, since_ns: int = 0) -> list:
+        """Entries that ended at or after ``since_ns``, as dicts."""
+        return [
+            {"name": n, "thread": t, "start_ns": a, "end_ns": b}
+            for n, t, a, b in list(self._ring) if b >= since_ns
+        ]
+
+    def watch_gc(self, min_ms: float = 1.0) -> None:
+        """Record every garbage collection of ``min_ms`` or more
+        (``gc.callbacks``). Idempotent; the served stack's agents and
+        broker call it when they start."""
+        if self._gc_watched:
+            return
+        self._gc_watched = True
+        min_ns = int(min_ms * 1e6)
+
+        def on_gc(phase, info):
+            if phase == "start":
+                self._gc_t0 = clock_ns()
+            elif self._gc_t0:
+                t1 = clock_ns()
+                if t1 - self._gc_t0 >= min_ns:
+                    self.record(
+                        f"gc.gen{info.get('generation')}", self._gc_t0, t1
+                    )
+                self._gc_t0 = 0
+
+        self._gc_callback = on_gc  # so a test can take it off again
+        gc.callbacks.append(on_gc)
+
+
+class _Turn:
+    def __init__(self, ring: BackgroundRing, name: str):
+        self.ring, self.name = ring, name
+
+    def __enter__(self):
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0 = clock_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.ring.record(self.name, self.t0, clock_ns())
+        self._ann.__exit__(*exc)
+
+
+#: The process-wide ring (``/debug/queryz`` serves it beside the traces).
+background = BackgroundRing()
 
 
 def plan_script(plan) -> str:

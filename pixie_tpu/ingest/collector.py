@@ -16,6 +16,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ..exec.trace import background
 from .core import DataTable, SourceConnector
 
 
@@ -80,7 +81,10 @@ class Collector:
             for c in connectors:
                 if c.sampling_freq.expired(now):
                     try:
-                        c.transfer_data(self, self._data_tables)
+                        # One entry of the background ring a tick (the
+                        # CPU sampler's among them, when it is on).
+                        with background.turn(f"collector.{c.name}"):
+                            c.transfer_data(self, self._data_tables)
                         self.stats["transfer_calls"] += 1
                     except Exception as e:
                         self.errors.append((c.name, repr(e)))
@@ -92,7 +96,8 @@ class Collector:
                     dt = self._data_tables[name]
                     if (push_due or dt.over_threshold()) and dt.pending_rows:
                         try:
-                            self._push(dt)
+                            with background.turn("collector.push"):
+                                self._push(dt)
                         except Exception as e:  # push must not kill the loop
                             self.errors.append((dt.name, repr(e)))
             if once:

@@ -290,6 +290,9 @@ class DistributedEngine(Engine):
                 fn = self._dist_step(frag, isinstance(valid, tuple), True)
                 return fn(state, cols, side, valid)
 
+            # What a device.dispatch span names the program (the mesh
+            # steps are plain jits, not ProgramRegistry records).
+            agg_step.kind = "mesh_agg_step"
             return init_state, agg_step, None
 
         def rows_step(cols, valid):
@@ -297,4 +300,5 @@ class DistributedEngine(Engine):
             fn = self._dist_step(frag, isinstance(valid, tuple), False)
             return fn(cols, side, valid)
 
+        rows_step.kind = "mesh_rows_step"
         return None, None, rows_step

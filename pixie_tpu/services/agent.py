@@ -20,7 +20,7 @@ from ..exec import tracectx
 from ..exec.engine import Engine, QueryError
 from ..exec.pipeline import DeadlineEvent
 from ..exec.stream import QueryCancelled
-from ..exec.trace import plan_script
+from ..exec.trace import background, clock_ns, plan_script
 from .msgbus import MessageBus
 from .tracker import TOPIC_HEARTBEAT, TOPIC_REGISTER
 
@@ -150,6 +150,7 @@ class Agent:
                 bus=self.bus,
             )
         self._register()
+        background.watch_gc()
         self._hb_thread = threading.Thread(target=self._heartbeat_loop, daemon=True)
         self._hb_thread.start()
         # The ingest loop (Stirling::RunAsThread): drains connector
@@ -197,50 +198,60 @@ class Agent:
 
     def _heartbeat_loop(self):
         while not self._stop.wait(self.heartbeat_interval_s):
-            # ONE freshness sweep per heartbeat, shared by the storage-
-            # tier fold and the envelope: the fold is forced (a row per
-            # table per heartbeat, the reference's stats-on-every-
-            # heartbeat shape) so a STOPPED ingest still advances fold
-            # time past its frozen watermark — px/ingest_lag's signal.
-            # Ring-bounded; the per-trace fold stays change-cursored so
-            # query load can't multiply rows.
-            fresh = self.engine.table_store.freshness()
-            tel = getattr(self, "telemetry", None)
-            if tel is not None:
-                try:
-                    tel.table_stats.fold(force=True, snapshot=fresh)
-                except Exception:
-                    pass  # telemetry must never kill the heartbeat loop
-            hb = {
-                "agent_id": self.agent_id,
-                "schemas": self._schemas(),
-                "table_stats": self._table_stats(freshness=fresh),
-            }
-            # Profiling tier: ship this agent's cumulative folded-stack
-            # summary (top-N, counts monotonic) for the tracker's
-            # cluster merge — /debug/pprof and `px profile` read the
-            # merged view. Filtered by agent_id so co-resident agents
-            # in one process don't double-ship each other's samples.
-            try:
-                from ..ingest.profiler import profile_summary
+            # One entry a turn in the background ring (exec/trace.py),
+            # and one each for the parts that can be long: what a late
+            # request is laid over.
+            with background.turn("heartbeat"):
+                self._heartbeat_turn()
 
-                prof = profile_summary(agent_id=self.agent_id)
-                if prof:
-                    hb["profile"] = prof
+    def _heartbeat_turn(self):
+        # ONE freshness sweep per heartbeat, shared by the storage-
+        # tier fold and the envelope: the fold is forced (a row per
+        # table per heartbeat, the reference's stats-on-every-
+        # heartbeat shape) so a STOPPED ingest still advances fold
+        # time past its frozen watermark — px/ingest_lag's signal.
+        # Ring-bounded; the per-trace fold stays change-cursored so
+        # query load can't multiply rows.
+        with background.turn("heartbeat.freshness"):
+            fresh = self.engine.table_store.freshness()
+        tel = getattr(self, "telemetry", None)
+        if tel is not None:
+            try:
+                with background.turn("heartbeat.tables_fold"):
+                    tel.table_stats.fold(force=True, snapshot=fresh)
             except Exception:
-                pass  # profiling must never kill the heartbeat loop
-            # Transport tier: fold this agent's bus counters into
-            # __bus__ (heartbeat cadence ONLY — see BusStatsCollector)
-            # and ship the same summary for the tracker's cluster merge.
-            if tel is not None:
-                try:
+                pass  # telemetry must never kill the heartbeat loop
+        hb = {
+            "agent_id": self.agent_id,
+            "schemas": self._schemas(),
+            "table_stats": self._table_stats(freshness=fresh),
+        }
+        # Profiling tier: ship this agent's cumulative folded-stack
+        # summary (top-N, counts monotonic) for the tracker's
+        # cluster merge — /debug/pprof and `px profile` read the
+        # merged view. Filtered by agent_id so co-resident agents
+        # in one process don't double-ship each other's samples.
+        try:
+            from ..ingest.profiler import profile_summary
+
+            prof = profile_summary(agent_id=self.agent_id)
+            if prof:
+                hb["profile"] = prof
+        except Exception:
+            pass  # profiling must never kill the heartbeat loop
+        # Transport tier: fold this agent's bus counters into
+        # __bus__ (heartbeat cadence ONLY — see BusStatsCollector)
+        # and ship the same summary for the tracker's cluster merge.
+        if tel is not None:
+            try:
+                with background.turn("heartbeat.bus_fold"):
                     tel.bus_stats.fold(force=True)
-                except Exception:
-                    pass  # telemetry must never kill the heartbeat loop
-            bus_rows = self._bus_summary()
-            if bus_rows:
-                hb["bus"] = bus_rows
-            self.bus.publish(TOPIC_HEARTBEAT, hb)
+            except Exception:
+                pass  # telemetry must never kill the heartbeat loop
+        bus_rows = self._bus_summary()
+        if bus_rows:
+            hb["bus"] = bus_rows
+        self.bus.publish(TOPIC_HEARTBEAT, hb)
 
     def _schemas(self) -> dict:
         # Snapshot: heartbeat thread vs concurrent table creation
@@ -521,32 +532,39 @@ class Agent:
             if qid in self._cancelled:
                 return  # cancelled during execution: results are dropped
         merge_agent = msg.get("merge_agent")
-        for key, val in outputs.items():
-            if isinstance(key, tuple) and key[0] == "bridge":
-                self.bus.publish(
-                    f"agent.{merge_agent}.bridge",
-                    {
-                        "qid": qid,
-                        "bridge_id": key[1],
-                        "from_agent": self.agent_id,
-                        "payload": val,
-                    },
-                )
-            else:  # whole plan executed locally (no split)
-                self.bus.publish(
-                    f"query.{qid}.results",
-                    {"table": key, "batch": val, "agent": self.agent_id},
-                )
-        self.bus.publish(
-            f"query.{qid}.agent_done",
-            {
-                "agent": self.agent_id,
-                "exec_time_s": elapsed,
-                # Per-agent resource attribution (QueryResourceUsage):
-                # execute_plan ended the trace, so usage is final here.
-                "usage": trace.usage.to_dict(),
-            },
-        )
+        # ``publish`` lies AFTER the trace's root: execute_plan ended
+        # the trace (usage must be final for agent_done, and the
+        # tracer's listeners — the telemetry fold — ran there), so this
+        # span is kept on the trace object (queryz, readers) but was
+        # not in what the root's end exported.
+        with trace.span("publish", outside_root="after"):
+            for key, val in outputs.items():
+                if isinstance(key, tuple) and key[0] == "bridge":
+                    self.bus.publish(
+                        f"agent.{merge_agent}.bridge",
+                        {
+                            "qid": qid,
+                            "bridge_id": key[1],
+                            "from_agent": self.agent_id,
+                            "payload": val,
+                        },
+                    )
+                else:  # whole plan executed locally (no split)
+                    self.bus.publish(
+                        f"query.{qid}.results",
+                        {"table": key, "batch": val, "agent": self.agent_id},
+                    )
+            self.bus.publish(
+                f"query.{qid}.agent_done",
+                {
+                    "agent": self.agent_id,
+                    "exec_time_s": elapsed,
+                    # Per-agent resource attribution
+                    # (QueryResourceUsage): execute_plan ended the
+                    # trace, so usage is final here.
+                    "usage": trace.usage.to_dict(),
+                },
+            )
 
     @staticmethod
     def _new_pending_merge() -> dict:
@@ -557,9 +575,11 @@ class Agent:
         # completes the bridge set (a different dispatcher thread whose
         # ambient context is some data agent's fragment), so the
         # install-time context is stored, not inherited.
+        # "installed_ns": when the merge plan landed (clock_ns): the
+        # start of the merge trace's ``merge.wait`` span.
         return {"plan": None, "expect": None, "got": {}, "got_keys": set(),
                 "keep": None, "trace_ctx": None, "deadline": None,
-                "tenant": ""}
+                "tenant": "", "installed_ns": 0}
 
     def _on_merge(self, msg):
         """Install a merge fragment; runs once all bridge payloads land."""
@@ -586,6 +606,7 @@ class Agent:
                     parked if pm["keep"] is None else (pm["keep"] & parked)
                 )
             pm["plan"] = msg["plan"]
+            pm["installed_ns"] = clock_ns()
             pm["trace_ctx"] = tracectx.extract(msg) or tracectx.current()
             pm["deadline"] = msg.get("deadline_unix_s")
             pm["tenant"] = str(msg.get("tenant") or "")
@@ -694,6 +715,12 @@ class Agent:
         trace.qid = qid
         trace.agent_id = self.agent_id
         trace.tenant = pm["tenant"]
+        # ``merge.wait`` lies BEFORE the trace's root (the trace is the
+        # merge's own work, as it always was): merge installed until
+        # the last bridge payload is in, both ends stamped where they
+        # happened.
+        trace.add_span("merge.wait", pm["installed_ns"], trace.start_ns,
+                       outside_root="before")
         # The merge respects the query deadline AND query.cancel:
         # folding states for a client the broker already answered is
         # dead work — the same window-boundary abort as data fragments.
@@ -728,21 +755,22 @@ class Agent:
         finally:
             with self._lock:
                 self._running.pop(qid, None)
-        for name, batch in outputs.items():
+        with trace.span("publish", outside_root="after"):
+            for name, batch in outputs.items():
+                self.bus.publish(
+                    f"query.{qid}.results",
+                    {"table": name, "batch": batch, "agent": self.agent_id},
+                )
+            # Merge-tier attribution rides a role-tagged agent_done (the
+            # forwarder files it under merge_stats, keeping agent_stats
+            # == data agents for existing consumers). BEFORE eos, so the
+            # wait loop never needs its post-eos grace budget for it.
             self.bus.publish(
-                f"query.{qid}.results",
-                {"table": name, "batch": batch, "agent": self.agent_id},
+                f"query.{qid}.agent_done",
+                {"agent": self.agent_id, "exec_time_s": elapsed,
+                 "role": "merge", "usage": trace.usage.to_dict()},
             )
-        # Merge-tier attribution rides a role-tagged agent_done (the
-        # forwarder files it under merge_stats, keeping agent_stats ==
-        # data agents for existing consumers). BEFORE eos, so the wait
-        # loop never needs its post-eos grace budget for it.
-        self.bus.publish(
-            f"query.{qid}.agent_done",
-            {"agent": self.agent_id, "exec_time_s": elapsed,
-             "role": "merge", "usage": trace.usage.to_dict()},
-        )
-        self.bus.publish(f"query.{qid}.results", {"eos": True})
+            self.bus.publish(f"query.{qid}.results", {"eos": True})
 
 
     # -- live queries (StreamResults analog) ---------------------------------

@@ -10,9 +10,9 @@ text exposition follows the Prometheus format so standard scrapers work.
 Metric kinds: ``counter`` (monotonic), ``gauge``, and ``histogram``
 (fixed buckets; cumulative ``_bucket{le=...}`` + ``_sum``/``_count``
 exposition, prometheus-cpp Histogram analog). The query-lifecycle
-tracer (``exec/trace.py``) records ``pixie_query_duration_seconds``,
-``pixie_window_stage_seconds`` and ``pixie_pipeline_stall_seconds``
-histograms here; ``/debug/queryz`` lists its in-flight + recent traces.
+tracer (``exec/trace.py``) records ``pixie_query_duration_seconds``
+and ``pixie_window_stage_seconds`` histograms here; ``/debug/queryz``
+lists its in-flight + recent traces and the background ring.
 """
 
 from __future__ import annotations
@@ -433,10 +433,15 @@ class ObservabilityServer:
         if path == "/debug/queryz":
             if self.tracer is None:
                 return (404, "text/plain", "no tracer wired\n")
+            from ..exec.trace import background
+
             body = json.dumps(
                 {
                     "in_flight": self.tracer.in_flight(),
                     "recent": self.tracer.recent(),
+                    # What the process did besides queries (heartbeats,
+                    # sweeps, folds, collections), on the spans' clock.
+                    "background": background.entries(),
                 },
                 indent=1,
                 default=str,
@@ -660,9 +665,5 @@ def engine_collector(engine):
                 "pixie_pipeline_stage_seconds_total",
                 "Prefetch-thread seconds spent staging windows",
             ).set(round(pt["stage_secs"], 6))
-            reg.gauge(
-                "pixie_pipeline_stall_seconds_total",
-                "Query-thread seconds stalled waiting for a window",
-            ).set(round(pt["stall_secs"], 6))
 
     return collect

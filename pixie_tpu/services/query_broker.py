@@ -22,6 +22,7 @@ from ..planner import CompilerState, compile_mutations, compile_pxl
 from ..planner.distributed import DistributedPlanner
 from ..planner.distributed.coordinator import PlanningError
 from ..udf.registry import Registry, default_registry
+from ..exec.trace import background
 from .msgbus import MessageBus
 from .tracker import AgentTracker
 
@@ -241,20 +242,21 @@ class _Admission:
 
     def admit(self, qid: str, predicted: dict | None,
               tenant: str | None = None, priority: int = 0,
-              deadline: float | None = None) -> None:
+              deadline: float | None = None) -> bool:
         """Admit/queue/reject ``qid``. ``tenant`` is resolved through
         the registered set; ``deadline`` is an absolute
         ``time.monotonic()`` instant (the query's own deadline — a
-        waiter past it is shed, never dispatched)."""
+        waiter past it is shed, never dispatched). Returns whether the
+        query had to queue before it was admitted."""
         from ..config import get_flag
         from .tenancy import resolve_tenant, tenant_shares
 
         budget = float(get_flag("admission_bytes_budget_mb")) * (1 << 20)
         if budget <= 0:
-            return
+            return False
         pred = (predicted or {}).get("bytes_staged_hi")
         if pred is None:
-            return  # unknown cost: admit (never falsely reject)
+            return False  # unknown cost: admit (never falsely reject)
         pred = int(pred)
         tenant = resolve_tenant(tenant)
         share = tenant_shares(budget).get(tenant, budget)
@@ -283,7 +285,7 @@ class _Admission:
             self._waiters.append(w)
             self._schedule_locked(budget)
             if w["admitted"]:
-                return
+                return False
         self._count("queued", tenant)
         while True:
             with self._cond:
@@ -293,7 +295,7 @@ class _Admission:
                 # directly through their events).
                 self._schedule_locked(budget)
                 if w["admitted"]:
-                    return
+                    return True
                 if w["cancelled"]:
                     # cancel() already removed us and rescheduled.
                     verdict = "cancelled"
@@ -521,6 +523,17 @@ class QueryResultForwarder:
         merge_stats: dict = {}  # merge-tier attribution (role="merge")
         eos = False
         grace_deadline = None
+        # ``await`` on the query's trace, cut at eos into its two
+        # children: ``await.results`` (dispatches acked, fragments,
+        # merge, rows) and ``await.stats`` (eos until every agent's
+        # stats are in: what the post-eos grace budget is spent on).
+        tr = st.get("trace")
+        open_spans = []  # innermost last
+        if tr is not None:
+            open_spans.append(tr.span("await"))
+            await_sp = open_spans[0].__enter__()
+            open_spans.append(tr.span("await.results", parent=await_sp))
+            open_spans[1].__enter__()
         # Inactivity watchdog: only QUERY-RELEVANT activity pushes the
         # deadline out — unrelated cluster churn (another query's agent
         # expiring) must not postpone a hung query's timeout forever.
@@ -650,11 +663,19 @@ class QueryResultForwarder:
                     else:
                         stats[msg["agent"]] = entry
                 elif msg.get("eos"):
+                    if not eos and open_spans:
+                        open_spans.pop().__exit__(None, None, None)
+                        open_spans.append(
+                            tr.span("await.stats", parent=await_sp)
+                        )
+                        open_spans[1].__enter__()
                     eos = True
                 elif "table" in msg:
                     outputs[msg["table"]] = msg["batch"]
                 watchdog = time.monotonic() + timeout_s
         finally:
+            while open_spans:
+                open_spans.pop().__exit__(None, None, None)
             self._deregister(qid)
 
     @staticmethod
@@ -1423,7 +1444,8 @@ class QueryBroker:
             if "pxtrace" in query:
                 cache_status = rc.BYPASS
             else:
-                cluster_stats = self.tracker.table_stats()
+                with trace.span("snapshot"):
+                    cluster_stats = self.tracker.table_stats()
 
                 def _cluster_wm(t, _stats=cluster_stats):
                     fresh = _stats.get(t, {}).get("freshness") or {}
@@ -1443,15 +1465,18 @@ class QueryBroker:
                     return result
                 cache_status = status
         trace.cache = cache_status
-        compiler_state = CompilerState(
-            schemas=self.tracker.schemas(),
-            registry=self.registry,
-            now_ns=now_ns,
-            max_output_rows=max_output_rows,
+        with trace.span("snapshot"):
+            schemas = self.tracker.schemas()
             # Cluster-wide ingest-sketch summary (agents ship it with
             # heartbeats): seeds the planner's NDV sizing AND pxbound's
             # predicted query cost — the admission-control signal.
-            table_stats=self.tracker.table_stats(),
+            table_stats = self.tracker.table_stats()
+        compiler_state = CompilerState(
+            schemas=schemas,
+            registry=self.registry,
+            now_ns=now_ns,
+            max_output_rows=max_output_rows,
+            table_stats=table_stats,
         )
         mutation_states = None
         # Cheap gate: the mutation pass re-executes the script, so skip it
@@ -1492,7 +1517,8 @@ class QueryBroker:
                 max_output_rows=max_output_rows,
                 table_stats=self.tracker.table_stats(),
             )
-        state = self.tracker.distributed_state()  # fresh per query
+        with trace.span("snapshot"):
+            state = self.tracker.distributed_state()  # fresh per query
         with trace.span("compile"):
             compiled = compile_pxl(query, compiler_state)
         if mutations and not compiled.outputs and not compiled.n_exports:
@@ -1502,14 +1528,48 @@ class QueryBroker:
                 "agent_stats": {},
                 "qid": None,
             }
-        try:
-            dplan = self.planner.plan(
-                compiled.plan, state,
-                schemas=compiler_state.schemas,
-                table_stats=compiler_state.table_stats,
+        from ..analysis.bounds import merged_cost
+        from ..config import get_flag
+
+        with trace.span("plan"):
+            try:
+                dplan = self.planner.plan(
+                    compiled.plan, state,
+                    schemas=compiler_state.schemas,
+                    table_stats=compiler_state.table_stats,
+                )
+            except PlanningError as e:
+                raise QueryError(str(e)) from e
+            # Predicted cost (pxbound): the logical plan's resource
+            # envelope + the split's bridge wire bound. Stamped on the
+            # broker trace (predicted-vs-observed in `px debug
+            # queries`), attached to every dispatch, and the admission
+            # decision's input.
+            predicted = merged_cost(
+                getattr(compiled.plan, "resource_report", None),
+                getattr(dplan, "resource_report", None),
             )
-        except PlanningError as e:
-            raise QueryError(str(e)) from e
+            # Calibration (admission_observed_floor): floor the
+            # plan-time prediction at this script hash's OBSERVED
+            # staged-byte history — a sketch-less unknown becomes the
+            # observed bytes (admitted against reality instead of
+            # accounted at zero), and a prediction below past
+            # observations is raised to them. The floored dict flows
+            # everywhere predicted_cost does: the trace (`px debug
+            # queries` pred + pred/obs columns), every dispatch, the
+            # client result, and the admission decision below. Gated on
+            # admission actually being ON: with no budget the floor
+            # would only replace the auditable pxbound prediction (and
+            # blank the pred/obs calibration ratio) without anyone
+            # consuming it.
+            if (
+                get_flag("admission_observed_floor")
+                and float(get_flag("admission_bytes_budget_mb")) > 0
+            ):
+                predicted = self.observed_costs.floor_predicted(
+                    predicted, trace.script_hash
+                )
+            trace.predicted = predicted
 
         qid = uuid.uuid4().hex[:12]
         trace.qid = qid
@@ -1517,37 +1577,6 @@ class QueryBroker:
         if not dplan.kelvin_agent_ids:
             raise QueryError("no live agent available to run the query")
         merge_agent = dplan.kelvin_agent_ids[0]
-
-        # Predicted cost (pxbound): the logical plan's resource envelope
-        # + the split's bridge wire bound. Stamped on the broker trace
-        # (predicted-vs-observed in `px debug queries`), attached to
-        # every dispatch, and the admission decision's input.
-        from ..analysis.bounds import merged_cost
-        from ..config import get_flag
-
-        predicted = merged_cost(
-            getattr(compiled.plan, "resource_report", None),
-            getattr(dplan, "resource_report", None),
-        )
-        # Calibration (admission_observed_floor): floor the plan-time
-        # prediction at this script hash's OBSERVED staged-byte history
-        # — a sketch-less unknown becomes the observed bytes (admitted
-        # against reality instead of accounted at zero), and a
-        # prediction below past observations is raised to them. The
-        # floored dict flows everywhere predicted_cost does: the trace
-        # (`px debug queries` pred + pred/obs columns), every dispatch,
-        # the client result, and the admission decision below. Gated on
-        # admission actually being ON: with no budget the floor would
-        # only replace the auditable pxbound prediction (and blank the
-        # pred/obs calibration ratio) without anyone consuming it.
-        if (
-            get_flag("admission_observed_floor")
-            and float(get_flag("admission_bytes_budget_mb")) > 0
-        ):
-            predicted = self.observed_costs.floor_predicted(
-                predicted, trace.script_hash
-            )
-        trace.predicted = predicted
 
         # LaunchQuery: merge fragment first (so the router can accept
         # early bridge chunks), then the per-agent data fragments —
@@ -1597,31 +1626,34 @@ class QueryBroker:
         # tenant's share (released in the finally below) or raises
         # without recording; a queued query whose deadline lapses is
         # shed here with zero agent work.
-        self.admission.admit(
-            qid, predicted, tenant=tenant, priority=priority,
-            deadline=deadline_mono,
-        )
-        try:
-            # Verify BEFORE registering the query: a failing check must
-            # not leak the forwarder's subscriptions/dispatcher threads
-            # (they are only released through wait()'s deregister).
-            self._check_dispatch_sets(dplan, dispatches, merge_agent)
-            self.forwarder.register_query(
-                qid, data_agents, merge_agent=merge_agent,
-                require_complete=require_complete, trace=trace,
+        with trace.span("admit") as sp:
+            sp.attributes["queued"] = self.admission.admit(
+                qid, predicted, tenant=tenant, priority=priority,
+                deadline=deadline_mono,
             )
-            # Replication (broker HA): the admission grant + dispatch
-            # expectations, enough for a standby to reconcile and
-            # resolve this query if this broker dies mid-flight.
-            self._log_state("inflight", {
-                "qid": qid, "tenant": tenant,
-                "expected": list(data_agents),
-                "merge_agent": merge_agent,
-                "reply_to": reply_to or "",
-                "require_complete": bool(require_complete),
-                "predicted": predicted,
-                "deadline_unix_s": deadline_unix,
-            })
+        try:
+            with trace.span("register"):
+                # Verify BEFORE registering the query: a failing check
+                # must not leak the forwarder's subscriptions/dispatcher
+                # threads (they are only released through wait()'s
+                # deregister).
+                self._check_dispatch_sets(dplan, dispatches, merge_agent)
+                self.forwarder.register_query(
+                    qid, data_agents, merge_agent=merge_agent,
+                    require_complete=require_complete, trace=trace,
+                )
+                # Replication (broker HA): the admission grant + dispatch
+                # expectations, enough for a standby to reconcile and
+                # resolve this query if this broker dies mid-flight.
+                self._log_state("inflight", {
+                    "qid": qid, "tenant": tenant,
+                    "expected": list(data_agents),
+                    "merge_agent": merge_agent,
+                    "reply_to": reply_to or "",
+                    "require_complete": bool(require_complete),
+                    "predicted": predicted,
+                    "deadline_unix_s": deadline_unix,
+                })
             with trace.span("dispatch") as sp:
                 sp.attributes.update({
                     "data_agents": ",".join(data_agents),
@@ -1647,60 +1679,61 @@ class QueryBroker:
             # admission budget the moment it finishes or fails.
             self.admission.release(qid)
             self._log_state("release", {"qid": qid})
-        result["qid"] = qid
-        result["distributed_plan"] = dplan
-        result["predicted_cost"] = predicted
-        result["tenant"] = tenant
-        # Fold per-agent resource records into the broker's trace: the
-        # distributed query's cost with per-agent attribution (served by
-        # broker.debug_queries / `px debug queries` / /debug/queryz).
-        # Built locally and assigned ONCE: the trace is already visible
-        # to concurrent debug surfaces (to_dict iterates agent_usage),
-        # so in-place insertion would race their snapshot.
-        agent_usage = {}
-        for aid, entry in {**result.get("agent_stats", {}),
-                           **result.get("merge_stats", {})}.items():
-            u = entry.get("usage")
-            if isinstance(u, dict):
-                agent_usage[aid] = dict(u)
-                trace.usage.merge(u)
-        trace.agent_usage = agent_usage
-        # Result staleness (storage tier): the worst scanned-table
-        # watermark lag any agent reported — how stale this answer is,
-        # the validity predicate a result cache would check.
-        result["freshness_lag_ms"] = round(
-            trace.usage.freshness_lag_ms, 3
-        )
-        # Prime the result cache. Never a partial/interrupted result (a
-        # degraded answer must not masquerade as a complete one on the
-        # next repeat) and never a mutation script. The watermark
-        # snapshot is the PRE-dispatch compiler_state one —
-        # conservative: ingest that landed mid-execution makes the
-        # stored watermark older than reality, so the next lookup sees
-        # the advance and re-validates instead of over-trusting.
-        if (
-            self.result_cache.enabled()
-            and cache_status != rc.BYPASS
-            and not result.get("partial")
-            and not result.get("interrupted")
-        ):
-            def _snap_wm(t, _stats=compiler_state.table_stats):
-                fresh = (_stats or {}).get(t, {}).get("freshness") or {}
-                wm = fresh.get("watermark")
-                return None if wm is None or int(wm) < 0 else int(wm)
-
-            cached = {
-                k: v for k, v in result.items() if k != "distributed_plan"
-            }
-            cache_status = self.result_cache.store(
-                query, compiler_state.now_ns, max_output_rows,
-                compiled.plan, cached, _snap_wm,
+        with trace.span("finish"):
+            result["qid"] = qid
+            result["distributed_plan"] = dplan
+            result["predicted_cost"] = predicted
+            result["tenant"] = tenant
+            # Fold per-agent resource records into the broker's trace: the
+            # distributed query's cost with per-agent attribution (served by
+            # broker.debug_queries / `px debug queries` / /debug/queryz).
+            # Built locally and assigned ONCE: the trace is already visible
+            # to concurrent debug surfaces (to_dict iterates agent_usage),
+            # so in-place insertion would race their snapshot.
+            agent_usage = {}
+            for aid, entry in {**result.get("agent_stats", {}),
+                               **result.get("merge_stats", {})}.items():
+                u = entry.get("usage")
+                if isinstance(u, dict):
+                    agent_usage[aid] = dict(u)
+                    trace.usage.merge(u)
+            trace.agent_usage = agent_usage
+            # Result staleness (storage tier): the worst scanned-table
+            # watermark lag any agent reported — how stale this answer is,
+            # the validity predicate a result cache would check.
+            result["freshness_lag_ms"] = round(
+                trace.usage.freshness_lag_ms, 3
             )
-            trace.cache = cache_status
-        if cache_status:
-            result["cache"] = cache_status
-        if mutation_states is not None:
-            result["mutations"] = mutation_states
+            # Prime the result cache. Never a partial/interrupted result (a
+            # degraded answer must not masquerade as a complete one on the
+            # next repeat) and never a mutation script. The watermark
+            # snapshot is the PRE-dispatch compiler_state one —
+            # conservative: ingest that landed mid-execution makes the
+            # stored watermark older than reality, so the next lookup sees
+            # the advance and re-validates instead of over-trusting.
+            if (
+                self.result_cache.enabled()
+                and cache_status != rc.BYPASS
+                and not result.get("partial")
+                and not result.get("interrupted")
+            ):
+                def _snap_wm(t, _stats=compiler_state.table_stats):
+                    fresh = (_stats or {}).get(t, {}).get("freshness") or {}
+                    wm = fresh.get("watermark")
+                    return None if wm is None or int(wm) < 0 else int(wm)
+
+                cached = {
+                    k: v for k, v in result.items() if k != "distributed_plan"
+                }
+                cache_status = self.result_cache.store(
+                    query, compiler_state.now_ns, max_output_rows,
+                    compiled.plan, cached, _snap_wm,
+                )
+                trace.cache = cache_status
+            if cache_status:
+                result["cache"] = cache_status
+            if mutation_states is not None:
+                result["mutations"] = mutation_states
         return result
 
     def execute_script_streaming(

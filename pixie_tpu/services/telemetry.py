@@ -321,9 +321,15 @@ class TelemetryCollector:
     # -- the fold (Tracer listener) ------------------------------------------
     def on_trace(self, trace) -> None:
         # Tracer._notify already contains exceptions, but count them
-        # here too so a schema drift is visible, not silent.
+        # here too so a schema drift is visible, not silent. The fold is
+        # one entry of the background ring: it runs on the thread that
+        # ended the trace, after the trace's root — between an agent's
+        # fragment and its publish.
+        from ..exec.trace import background
+
         try:
-            self._fold(trace)
+            with background.turn("telemetry.fold"):
+                self._fold(trace)
         except Exception:
             with self._lock:
                 self.fold_errors += 1

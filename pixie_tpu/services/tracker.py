@@ -196,8 +196,11 @@ class AgentTracker:
 
     # -- expiry --------------------------------------------------------------
     def _expiry_loop(self):
+        from ..exec.trace import background
+
         while not self._stop.wait(self.check_interval_s):
-            self.expire_silent()
+            with background.turn("tracker.sweep"):
+                self.expire_silent()
 
     def expire_silent(self) -> list[str]:
         now = time.monotonic()

@@ -472,12 +472,17 @@ class TestClusterBusFold:
 
     def test_tracker_merges_heartbeat_summaries(self, cluster):
         bus, tracker, pems, kelvin, broker = cluster
-        # Register summaries arrive first; wait until a HEARTBEAT-borne
-        # row (heartbeats ride the bus themselves) reached the merge.
-        assert _wait(lambda: any(
-            r["topic_class"] == "agent.heartbeat"
-            for r in tracker.bus_stats()["merged"]
-        ) and len(tracker.bus_stats()["agents"]) == 3)
+        # Register summaries arrive first; wait until HEARTBEAT-borne
+        # rows (heartbeats ride the bus themselves) reached the merge.
+        # An agent snapshots the bus BEFORE it publishes its heartbeat,
+        # so the first heartbeat-borne row reads msgs=1 (the second
+        # agent's view of the first's heartbeat): wait for the count
+        # the assertion below wants, not for the first row.
+        assert _wait(lambda: sum(
+            r["msgs"] for r in tracker.bus_stats()["merged"]
+            if r["topic_class"] == "agent.heartbeat"
+            and r["direction"] == "pub"
+        ) >= 3 and len(tracker.bus_stats()["agents"]) == 3)
         t = tracker.bus_stats()
         assert set(t["agents"]) == {"pem-0", "pem-1", "kelvin-0"}
         merged = {

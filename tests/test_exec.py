@@ -540,7 +540,8 @@ px.display(df)
         frags = [hit[0] for hit in _FRAGMENT_CACHE.values()]
         dense = [fr for fr in frags if fr.is_agg and fr.dense_domains]
         assert dense, "expected the agg fragment to compile dense"
-        assert dense[0].dense_domains == (8, 4)  # 7 svcs, 3 paths (+NULL)
+        # (the cache is the process's: other files' fragments may be in it)
+        assert (8, 4) in [fr.dense_domains for fr in dense]  # 7 svcs, 3 paths (+NULL)
 
     def test_deferred_device_result(self, engine):
         from pixie_tpu.exec.engine import DeviceResult

@@ -1143,9 +1143,15 @@ class TestQuarantineCooldownRecovery:
 
     def _mk_flappy_cluster(self):
         bus = MessageBus()
+        # quarantine_s: a quarantined agent stays registered and is
+        # plannable again the instant the cooldown lapses, so the
+        # window has to outlast the test's own steps between the flap
+        # and the quarantine-era query's planning (a register round
+        # trip, then a request over the netbus): 0.4 s lapsed first on
+        # a loaded box and the query found all three shards.
         tracker = AgentTracker(
             bus, expiry_s=60.0, check_interval_s=60.0,
-            flap_threshold=2, flap_window_s=60.0, quarantine_s=0.4,
+            flap_threshold=2, flap_window_s=60.0, quarantine_s=1.5,
         )
         pems = [
             PEMAgent(bus, f"pem-{i}", **FAST).start() for i in range(3)

@@ -445,4 +445,9 @@ class TestInstrumentation:
         assert "pixie_pipeline_depth 2" in body
         assert "pixie_pipeline_windows_total" in body
         assert "pixie_pipeline_stage_seconds_total" in body
-        assert "pixie_pipeline_stall_seconds_total" in body
+        # The stall is recorded once: the tracer's per-interval
+        # histogram (its _sum is the lifetime total the gauge repeated).
+        assert "pixie_pipeline_stall_seconds" not in body
+        assert 'pixie_window_stage_seconds_count{stage="stall"}' in (
+            eng.tracer.registry.render()
+        )

@@ -81,18 +81,36 @@ class TestTraceSpine:
 
     def test_window_spans_sampled(self):
         eng = _mk_engine()
-        with config.override_flag("trace_window_sample", 1):
+        # cpu_fold_threads=1: the XLA fold the chip runs, not the CPU
+        # backend's native kernel.
+        with config.override_flag("trace_window_sample", 1), \
+                config.override_flag("cpu_fold_threads", 1):
             eng.execute_query(AGG_Q)
         tr = eng.tracer.last()
         wspans = [s for s in tr.spans if s.name.startswith("window.")]
-        assert {s.name for s in wspans} >= {"window.compute"}
+        assert {s.name for s in wspans} >= {"window.stall"}
+        # A program's enqueue is a device.dispatch span, never sampled:
+        # one a window here (the CPU backend folds window by window),
+        # then the finalize; the result's readback is the device.wait.
+        dispatches = [s for s in tr.spans if s.name == "device.dispatch"]
+        assert [d.attributes["program"] for d in dispatches] == (
+            ["fragment_update"] * tr.windows + ["fragment_finalize"]
+        )
+        # ... in two parts: the overflow flag (the finalize has run),
+        # then the result's planes.
+        waits = [s for s in tr.spans if s.name == "device.wait"]
+        assert len(waits) == 2
+        assert waits[0].start_ns >= dispatches[-1].end_ns
         frag_ids = {s.span_id for s in tr.spans if s.name == "fragment"}
-        assert all(s.parent_id in frag_ids for s in wspans)
-        # sample=0 disables window spans entirely.
-        with config.override_flag("trace_window_sample", 0):
+        assert all(s.parent_id in frag_ids
+                   for s in wspans + dispatches + waits)
+        # sample=0 disables window spans entirely (not the programs').
+        with config.override_flag("trace_window_sample", 0), \
+                config.override_flag("cpu_fold_threads", 1):
             eng.execute_query(AGG_Q)
         tr0 = eng.tracer.last()
         assert not [s for s in tr0.spans if s.name.startswith("window.")]
+        assert [s for s in tr0.spans if s.name == "device.dispatch"]
 
     def test_analyze_is_a_detail_level_of_the_trace(self):
         eng = _mk_engine()
