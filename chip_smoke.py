@@ -1,7 +1,7 @@
 """Chip smoke: the query path, once, on the TPU it was written for.
 
-    python chip_smoke.py            # one chip: engine + served phases
-    python chip_smoke.py --chips 4  # four chips: DistributedEngine only
+    python chip_smoke.py            # one chip: kernel + engine + served phases
+    python chip_smoke.py --chips 4  # four chips: kernel + DistributedEngine only
 
 One process, no child that needs the chip. A seeded ``http_events``
 replay (the bench's five-column layout, 32 B/row) goes in through the
@@ -38,9 +38,10 @@ REHEARSAL_MAX_ROWS = 1 << 20  # what a run without a TPU may be asked for
 SERVICES = [f"svc-{i}" for i in range(32)]
 PATHS = [f"/api/v1/ep{i}" for i in range(8)]
 
-#: FLOAT64 aggregates over a dense (dictionary-coded) key domain: the one
-#: shape ``exec/fragment.py`` routes through ``dense_group_fold``. No
-#: shipped script has it on this table (``latency_ns`` is INT64).
+#: FLOAT64 aggregates over a dense (dictionary-coded) key domain: the
+#: shape ``exec/fragment.py`` routes through the f32 ``dense_group_fold``.
+#: The shipped scripts' INT64 / BOOLEAN aggregates take the exact
+#: ``dense_group_fold_int`` (``latency_ns`` is INT64).
 F64_GROUPBY = """
 import px
 df = px.DataFrame(table='http_events')
@@ -139,7 +140,8 @@ def _programs_since(snap: dict) -> list:
                 "compile_secs": round(r.compile_s_last, 3),
                 "tpu_custom_call": "tpu_custom_call" in text,
                 "kernels": sorted(
-                    k for k in ("dense_group_fold", "hist_fold")
+                    k for k in ("dense_group_fold", "dense_group_fold_int",
+                              "hist_fold")
                     if f"{k}/pallas_call" in text
                 ),
             })
@@ -287,9 +289,9 @@ def engine_queries() -> list:
 
     return [
         ("px/http_stats", load_script("px/http_stats").pxl,
-         check_http_stats, None),
+         check_http_stats, "dense_group_fold_int"),
         ("px/service_stats", load_script("px/service_stats").pxl,
-         check_service_stats, None),
+         check_service_stats, "dense_group_fold_int"),
         ("inline/f64_groupby", F64_GROUPBY, check_f64_groupby,
          "dense_group_fold"),
         ("inline/quantile_by_failed", QUANTILE_BY_FAILED,
@@ -333,11 +335,21 @@ def _timed_query(eng, pxl: str):
     return time.perf_counter() - t0, host
 
 
+def _fold_routes(eng) -> list:
+    """The ``fold`` attributes of the last query's device.dispatch spans:
+    how its window-fold programs said they fold."""
+    return sorted({
+        sp.attributes["fold"] for sp in eng.tracer.last().spans
+        if sp.name == "device.dispatch" and "fold" in sp.attributes
+    })
+
+
 def run_queries(eng, rp: Replay, n: int, queries, meter: CompileMeter,
-                on_tpu: bool, phase: str) -> None:
+                on_tpu: bool, phase: str, tracked: bool = True) -> None:
     """Each query twice (cold, warm), checked; the second run may
     compile nothing, and on the chip a query that names a kernel must
-    have it in the program XLA built."""
+    say so on its fold's spans and (``tracked``: the mesh steps are plain
+    jits, not registry records) have it in the program XLA built."""
     for name, pxl, check, kernel in queries:
         snap, mark = _registry_snapshot(), meter.mark()
         cold_s, host = _timed_query(eng, pxl)
@@ -348,17 +360,89 @@ def run_queries(eng, rp: Replay, n: int, queries, meter: CompileMeter,
         warm_s, host = _timed_query(eng, pxl)
         warm = meter.since(mark)
         check(rp, n, host["output"].to_pydict(decode_strings=False))
+        folds = _fold_routes(eng)
         emit(phase=phase, query=name, rows=n, checked=True,
-             cold_secs=cold_s, warm_secs=warm_s,
+             cold_secs=cold_s, warm_secs=warm_s, fold=folds,
              cold_compile=cold, warm_compile=warm, programs=programs)
         assert warm["programs"] == 0, (
             f"{name}: second run compiled {warm['programs']} program(s)"
         )
-        if kernel and on_tpu:
+        if kernel == "dense_group_fold_int" and on_tpu:
+            assert folds and all("pallas_int" in f for f in folds), (
+                f"{name}: fold spans say {folds}, not pallas_int"
+            )
+        if kernel and on_tpu and tracked:
             assert any(
                 p["tpu_custom_call"] and kernel in p["kernels"]
                 for p in programs
             ), f"{name}: no tpu_custom_call for {kernel} in its programs"
+
+
+def phase_int_kernel(seed: int, rows: int, on_tpu: bool, chips: int) -> None:
+    """``dense_group_fold_int`` at the benchmark cells' shapes against
+    numpy: a window of ``rows`` rows over px/http_stats' 2,048 slots
+    (count, sum and max of an INT64) and px/service_stats' 32 (count and
+    sum of a BOOLEAN); on four chips a quarter of the window a shard
+    under ``shard_map``, as the mesh step runs it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from pixie_tpu.ops.pallas_groupby import (
+        dense_group_fold_int, int_fold_blocks, int_fold_groups,
+    )
+    from pixie_tpu.parallel.mesh import agent_mesh, row_sharding
+
+    rng = np.random.default_rng(seed)
+    lat = np.exp(rng.normal(15, 1.2, rows)).astype(np.int64)
+    lat[:3] = [np.iinfo(np.int64).max, np.iinfo(np.int64).min, -1]
+    failed = rng.random(rows) < 0.08
+    mesh = agent_mesh(chips) if chips > 1 else None
+    for g, sums, exts in ((2048, (lat,), (lat,)), (32, (failed,), ())):
+        g_pad = int_fold_groups(g)
+        chunk, g_block = int_fold_blocks(rows // chips, g_pad)
+        slots = rng.integers(0, g, rows).astype(np.int32)
+        slots[::9] = g_pad  # masked rows
+
+        def fold(slots, sums, exts):
+            cnt, s, e = dense_group_fold_int(
+                slots, sums, exts, g=g_pad, chunk=chunk, g_block=g_block,
+                ext_max=(True,) * len(exts), interpret=not on_tpu,
+            )
+            # A leading shard axis, so four chips' partials come back
+            # side by side and numpy merges them.
+            return jax.tree_util.tree_map(lambda x: x[None, :g], (cnt, s, e))
+
+        args = (slots, sums, exts)
+        if mesh is not None:
+            axes = mesh.axis_names
+            fold = jax.shard_map(fold, mesh=mesh, in_specs=P(axes),
+                                 out_specs=P(axes), check_vma=False)
+            args = jax.device_put(args, row_sharding(mesh))
+        t0 = time.perf_counter()
+        compiled = jax.jit(fold).lower(*args).compile()
+        compile_s = time.perf_counter() - t0
+        assert not on_tpu or "tpu_custom_call" in compiled.as_text()
+        cnt, s, e = jax.block_until_ready(compiled(*args))
+        t0 = time.perf_counter()
+        jax.block_until_ready(compiled(*args))
+        warm_s = time.perf_counter() - t0
+        live = slots < g
+        np.testing.assert_array_equal(
+            np.asarray(cnt).sum(0), np.bincount(slots[live], minlength=g)
+        )
+        for got, v in zip(s, sums):
+            want = np.zeros(g, np.int64)
+            np.add.at(want, slots[live], v[live].astype(np.int64))
+            np.testing.assert_array_equal(np.asarray(got).sum(0), want)
+        for got, v in zip(e, exts):
+            want = np.full(g, np.iinfo(np.int64).min)
+            np.maximum.at(want, slots[live], v[live])
+            np.testing.assert_array_equal(np.asarray(got).max(0), want)
+        emit(phase="int_kernel", rows=rows, groups=g, chips=chips,
+             rows_a_shard=rows // chips, blocks=[chunk, g_block],
+             checked=True, compile_secs=round(compile_s, 3),
+             warm_secs=round(warm_s, 5))
 
 
 def phase_engine(rp: Replay, meter: CompileMeter, on_tpu: bool) -> None:
@@ -377,7 +461,7 @@ def phase_engine(rp: Replay, meter: CompileMeter, on_tpu: bool) -> None:
     run_queries(eng, rp, n, engine_queries(), meter, on_tpu, "engine")
 
 
-def phase_served(rp: Replay, meter: CompileMeter) -> None:
+def phase_served(rp: Replay, meter: CompileMeter, on_tpu: bool) -> None:
     """Broker + one PEM + one Kelvin on an in-process bus; three
     px/http_stats requests through the broker."""
     from pixie_tpu.exec.engine import Engine
@@ -403,6 +487,7 @@ def phase_served(rp: Replay, meter: CompileMeter) -> None:
                   "engine phase carries the size")
         execute = broker_executor(QueryBroker(bus, tracker))
         pxl = load_script("px/http_stats").pxl
+        snap = _registry_snapshot()
         for i in range(3):
             mark = meter.mark()
             t0 = time.perf_counter()
@@ -421,6 +506,16 @@ def phase_served(rp: Replay, meter: CompileMeter) -> None:
             emit(phase="served", request=i, query="px/http_stats", rows=n,
                  groups=len(got["n"]), checked=True, secs=secs,
                  cache=res.get("cache", ""), compile=meter.since(mark))
+        # The shipped script, served, reaches the integer kernel: its
+        # fold program holds the kernel's tpu_custom_call.
+        folds = [p for p in _programs_since(snap)
+                 if p["program"].startswith("fragment_update")]
+        emit(phase="served", fold_programs=folds)
+        if on_tpu:
+            assert any(
+                p["tpu_custom_call"] and "dense_group_fold_int" in p["kernels"]
+                for p in folds
+            ), f"px/http_stats: no dense_group_fold_int in {folds}"
     finally:
         pem.stop()
         kelvin.stop()
@@ -450,7 +545,7 @@ def phase_distributed(rp: Replay, meter: CompileMeter, on_tpu: bool,
          secs=time.perf_counter() - t0, resident=res)
     assert res["rows"] == n and len(res["devices"]) == chips
     run_queries(eng, rp, n, engine_queries()[:2], meter, on_tpu,
-                "distributed")
+                "distributed", tracked=False)
 
 
 def main(argv=None) -> int:
@@ -498,11 +593,13 @@ def main(argv=None) -> int:
                                     ("pallas_tdigest", "interpret"),
                                     ("cpu_fold_threads", 1)):
                     flags.enter_context(override_flag(name, value))
+            phase_int_kernel(args.seed, min(args.rows, WINDOW), on_tpu,
+                             args.chips)
             if args.chips == 4:
                 phase_distributed(rp, meter, on_tpu, 4)
             else:
                 phase_engine(rp, meter, on_tpu)
-                phase_served(rp, meter)
+                phase_served(rp, meter, on_tpu)
         emit(total_compile=meter.since())
         ok = on_tpu
         if not ok:

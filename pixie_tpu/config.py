@@ -200,8 +200,12 @@ define_flag("agent_heartbeat_s", 5.0, "Agent heartbeat period (seconds).")
 define_flag("agent_expiry_s", 60.0, "Tracker agent expiry after silence.")
 define_flag(
     "pallas_dense_fold", "auto",
-    "Pallas MXU dense-fold kernel routing: 'auto' (TPU backend only), "
-    "'interpret' (any backend, interpreter mode — tests), 'off'.",
+    "Pallas MXU dense-fold kernel routing, per aggregate of a dense "
+    "group-by: count/sum/mean/max/min over INT64, BOOLEAN and TIME64NS "
+    "take the exact limb kernel (up to INT_FOLD_MAX_GROUPS slots), over "
+    "FLOAT64 the f32 kernel (up to 2,048); everything else stays on XLA. "
+    "'auto' (TPU backend only), 'interpret' (any backend, interpreter "
+    "mode — tests), 'off' (every aggregate on XLA).",
 )
 define_flag(
     "pallas_tdigest", "auto",

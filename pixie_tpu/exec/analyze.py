@@ -62,6 +62,11 @@ class FragmentStats:
     # wall-clock boundaries only, never force a sync.
     sync: bool = True
     stages: dict = field(default_factory=dict)  # {stage: StageStat}
+    # How an aggregating fragment's window fold runs
+    # (``CompiledFragment.fold``, set by the fold loop); "" for a fragment
+    # that folds nothing. On a traced fragment every ``compute`` dispatch
+    # carries it.
+    fold: str = ""
     # Staging runs on the prefetch thread concurrently with compute on
     # the query thread (pipeline.py), so stage accumulation is locked.
     _lock: threading.Lock = field(
@@ -102,7 +107,7 @@ class FragmentStats:
                 k: (v.seconds, v.rows, v.count, v.nbytes)
                 for k, v in self.stages.items()
             }
-        return {
+        out = {
             "ops": list(self.ops),
             "windows": self.windows,
             "rows_in": self.rows_in,
@@ -113,6 +118,9 @@ class FragmentStats:
                 for k, (s, r, c, b) in stages.items()
             },
         }
+        if self.fold:
+            out["fold"] = self.fold
+        return out
 
 
 class _Timer:

@@ -959,6 +959,8 @@ class Engine:
             if state is not None:
                 return state
         state = init_state()
+        if stats is not None:
+            stats.fold = frag.fold  # onto its device.dispatch spans
         # Scan-folding trades W dispatches for one; on the CPU backend
         # dispatches are cheap and the jnp.stack of window planes is a
         # pure memory-bandwidth loss.

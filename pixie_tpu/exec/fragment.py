@@ -20,6 +20,7 @@ multi-device partial-agg merge uses.
 from __future__ import annotations
 
 import threading
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -104,6 +105,12 @@ class CompiledFragment:
     # Per-key value stride (1 except binned/affine integer keys, where
     # slot codes count stride steps: value = code * stride + offset).
     dense_strides: tuple = ()
+    # How the window fold runs, decided at compile time (agg only):
+    # ``pallas_int`` / ``pallas_f32`` (ops/pallas_groupby.py), ``xla``, or
+    # ``mixed:<route>=<aggregates>,...`` when the AggOp's aggregates take
+    # different routes. Stamped on the fold programs' device.dispatch
+    # spans and on the fragment's /debug/queryz entry.
+    fold: str = ""
 
 
 _FRAGMENT_CACHE: dict = {}
@@ -731,87 +738,182 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         dense_group_ids_hash if impl == "hash" else dense_group_ids
     )
 
-    # Pallas dense fold (TPU): count/sum/mean/max over FLOAT64 planes
-    # route through the hand-scheduled MXU kernel — one-hot contractions
-    # with VMEM-resident [G] accumulators replace per-UDA HBM scatters
-    # (ops/pallas_groupby.py). 'auto' engages on the TPU backend;
-    # 'interpret' runs the kernel in interpreter mode on any backend
-    # (the equivalence tests); 'off' disables.
+    # Pallas dense fold: on a dense domain count/sum/mean/max/min route
+    # PER AGGREGATE through the hand-scheduled kernels of
+    # ops/pallas_groupby.py — one-hot contractions with VMEM-resident
+    # [G] accumulators instead of per-UDA sorts, gathers and scatters.
+    # INT64 / BOOLEAN / TIME64NS arguments take the exact integer kernel
+    # (limb contractions, carries stay i64), FLOAT64 arguments the f32
+    # kernel; anything else (``quantiles``...) keeps its ``uda.update``
+    # and vetoes nothing. Decided here from what the code observes: the
+    # domain, the argument types, G against each kernel's limit, the
+    # backend; the row block is the window's to decide (window_state).
+    # 'auto' engages on the TPU backend; 'interpret' runs the kernels in
+    # interpreter mode on any backend (the equivalence tests); 'off'
+    # disables.
     _pallas_mode = get_flag("pallas_dense_fold")
-    pallas_fold = (
+    _pallas_on = (
         dense_domains is not None
         and _pallas_mode in ("auto", "interpret")
         and (_pallas_mode == "interpret" or jax.default_backend() == "tpu")
-        and g <= 2048  # [chunk, G] one-hot must fit VMEM
-        and all(
-            ae.uda_name == "count"
-            or (
-                ae.uda_name in ("sum", "mean", "max", "min")
-                and len(arg_bound) == 1
-                and casts[0][1] == DataType.FLOAT64
-            )
-            for ae, _uda, arg_bound, casts in aggs_bound
+    )
+    g_pad = -(-g // 128) * 128  # f32 kernel's lane alignment
+    _f32_ok = _pallas_on and g <= 2048  # its [chunk, G] one-hot must fit VMEM
+    _int_ok = False
+    if _pallas_on:
+        from ..ops.pallas_groupby import (
+            INT_FOLD_MAX_GROUPS,
+            dense_group_fold,
+            dense_group_fold_int,
+            fold_row_chunk,
+            int_fold_blocks,
+            int_fold_groups,
+        )
+
+        g_int = int_fold_groups(g)  # integer kernel's lanes and group blocks
+        # Above the measured cross-over the one-hot (rows x G) loses to
+        # the sort: those domains keep the XLA fold.
+        _int_ok = g_int <= INT_FOLD_MAX_GROUPS
+
+    def _fold_route(ae, arg_bound, casts):
+        if ae.uda_name in ("sum", "mean", "max", "min") and len(arg_bound) == 1:
+            want = casts[0][1]
+            if want == DataType.FLOAT64:
+                return "pallas_f32" if _f32_ok else "xla"
+            if want in (DataType.INT64, DataType.TIME64NS) or (
+                want == DataType.BOOLEAN and ae.uda_name in ("sum", "mean")
+            ):
+                return "pallas_int" if _int_ok else "xla"
+        return "xla"
+
+    routes = {
+        ae.out_name: _fold_route(ae, arg_bound, casts)
+        for ae, _uda, arg_bound, casts in aggs_bound
+        if ae.uda_name != "count"
+    }
+    # A count reads no argument: it rides the kernel that runs anyway
+    # (the integer one's count is i32-exact, so it is preferred).
+    _kernels = set(routes.values()) - {"xla"}
+    _count_route = (
+        "pallas_int" if _int_ok and _kernels != {"pallas_f32"}
+        else "pallas_f32" if "pallas_f32" in _kernels
+        else "xla"
+    )
+    for ae, _uda, _b, _c in aggs_bound:
+        if ae.uda_name == "count":
+            routes[ae.out_name] = _count_route
+    # What the fold does, for the device.dispatch span and /debug/queryz.
+    _tally = Counter(routes.values())
+    fold = (
+        next(iter(_tally), "xla") if len(_tally) <= 1
+        else "mixed:" + ",".join(
+            f"{r}={_tally[r]}"
+            for r in ("pallas_int", "pallas_f32", "xla") if r in _tally
         )
     )
 
-    g_pad = -(-g // 128) * 128  # kernel lane alignment
-
-    def _pallas_window_carries(gids, cols, valid, chunk):
-        """Per-agg carries via dense_group_fold over ``chunk``-row blocks;
-        returns (carries, valid_w)."""
-        from ..ops.pallas_groupby import dense_group_fold
-
-        interpret = _pallas_mode == "interpret"
+    def _pallas_window_carries(gids, cols, valid):
+        """Carries of the aggregates the kernels take on this window, as
+        ``uda.update`` of a fresh state would give them, and the window's
+        per-slot row count (None when no kernel ran: a window with no row
+        block the chip's tiling accepts stays whole on the XLA fold)."""
         n = valid.shape[0]
-        # Trash rows must match NO kernel column, incl. the pad range.
-        gids_p = jnp.where(gids >= g, jnp.int32(g_pad), gids)
-        # One kernel pass per distinct ARG EXPRESSION (sum+mean+max over
-        # the same column share a single sweep — the kernel returns all
-        # three statistics anyway).
-        folds: dict = {}
+        interpret = _pallas_mode == "interpret"
+        by_route = {"pallas_int": [], "pallas_f32": []}
+        for ab in aggs_bound:
+            if ab[0].uda_name != "count" and routes[ab[0].out_name] != "xla":
+                by_route[routes[ab[0].out_name]].append(ab)
+        f32_chunk = (
+            fold_row_chunk(n, g_pad)
+            if by_route["pallas_f32"] or _count_route == "pallas_f32" else None
+        )
+        int_blocks = (
+            int_fold_blocks(n, g_int)
+            if by_route["pallas_int"] or _count_route == "pallas_int" else None
+        )
 
-        need_min = any(ae.uda_name == "min" for ae, _u, _b, _c in aggs_bound)
-
-        def fold_for(a):
-            cnt, s, mx, mn = dense_group_fold(
-                gids_p, a, g_pad, chunk=chunk, interpret=interpret,
-                want_min=need_min,
-            )
-            return cnt[:g], s[:g], mx[:g], mn[:g] if mn is not None else None
+        def arg_plane(ae, arg_bound, casts):
+            a = apply_cast(arg_bound[0].fn(cols), *casts[0])
+            return jnp.broadcast_to(a, valid.shape)
 
         carries_w = {}
-        cnt_shared = None
-        for ae, uda, arg_bound, casts in aggs_bound:
-            if ae.uda_name == "count":
-                continue
-            fkey = (_struct_key(ae.args), casts[0])
-            if fkey not in folds:
-                a = apply_cast(arg_bound[0].fn(cols), *casts[0])
-                folds[fkey] = fold_for(jnp.broadcast_to(a, valid.shape))
-            cnt, s, mx, mn = folds[fkey]
-            cnt_shared = cnt
-            init_leaf = uda.init(g)
-            if ae.uda_name == "sum":
-                carries_w[ae.out_name] = s.astype(init_leaf.dtype)
-            elif ae.uda_name == "mean":
-                carries_w[ae.out_name] = (
-                    s.astype(init_leaf[0].dtype),
-                    cnt.astype(init_leaf[1].dtype),
-                )
-            else:  # max/min: empty slots keep the UDA's neutral fill
-                ext = mx if ae.uda_name == "max" else mn
-                carries_w[ae.out_name] = jnp.where(
-                    cnt > 0, ext.astype(init_leaf.dtype), init_leaf
-                )
-        if cnt_shared is None:
-            # count-only aggregation: one kernel pass over a zero column.
-            cnt_shared = fold_for(jnp.zeros(n, dtype=jnp.float32))[0]
-        for ae, uda, _b, _c in aggs_bound:
-            if ae.uda_name == "count":
-                carries_w[ae.out_name] = cnt_shared.astype(
-                    uda.init(g).dtype
-                )
-        return carries_w, cnt_shared > 0
+        cnt = None
+        if f32_chunk is not None:
+            # Trash rows must match NO kernel column, incl. the pad range.
+            gids_p = jnp.where(gids >= g, jnp.int32(g_pad), gids)
+            need_min = any(
+                ae.uda_name == "min" for ae, _u, _b, _c in by_route["pallas_f32"]
+            )
+            # One kernel pass per distinct ARG EXPRESSION (sum+mean+max
+            # over the same column share a single sweep — the kernel
+            # returns all three statistics anyway).
+            folds: dict = {}
+            for ae, uda, arg_bound, casts in by_route["pallas_f32"]:
+                fkey = (_struct_key(ae.args), casts[0])
+                if fkey not in folds:
+                    c, s, mx, mn = dense_group_fold(
+                        gids_p, arg_plane(ae, arg_bound, casts), g_pad,
+                        chunk=f32_chunk, interpret=interpret,
+                        want_min=need_min,
+                    )
+                    folds[fkey] = (
+                        c[:g], s[:g], mx[:g], mn[:g] if mn is not None else None
+                    )
+                cnt, s, mx, mn = folds[fkey]
+                init_leaf = uda.init(g)
+                if ae.uda_name == "sum":
+                    carries_w[ae.out_name] = s.astype(init_leaf.dtype)
+                elif ae.uda_name == "mean":
+                    carries_w[ae.out_name] = (
+                        s.astype(init_leaf[0].dtype),
+                        cnt.astype(init_leaf[1].dtype),
+                    )
+                else:  # max/min: empty slots keep the UDA's neutral fill
+                    ext = mx if ae.uda_name == "max" else mn
+                    carries_w[ae.out_name] = jnp.where(
+                        cnt > 0, ext.astype(init_leaf.dtype), init_leaf
+                    )
+        if int_blocks is not None and (by_route["pallas_int"] or cnt is None):
+            # ONE kernel call for the AggOp: every distinct argument's
+            # sum and extreme, and the count, off the same one-hot.
+            # (insertion-ordered: key -> position among the kernel's
+            # sum / extreme arguments).
+            sum_at, ext_at, planes = {}, {}, {}
+            for ae, _uda, arg_bound, casts in by_route["pallas_int"]:
+                fkey = (_struct_key(ae.args), casts[0])
+                if fkey not in planes:
+                    planes[fkey] = arg_plane(ae, arg_bound, casts)
+                if ae.uda_name in ("sum", "mean"):
+                    sum_at.setdefault(fkey, len(sum_at))
+                else:
+                    ext_at.setdefault((fkey, ae.uda_name == "max"), len(ext_at))
+            cnt, sums, exts = dense_group_fold_int(
+                jnp.where(gids >= g, jnp.int32(g_int), gids),
+                tuple(planes[k] for k in sum_at),
+                tuple(planes[k] for k, _mx in ext_at),
+                g=g_int, chunk=int_blocks[0], g_block=int_blocks[1],
+                ext_max=tuple(mx for _k, mx in ext_at),
+                interpret=interpret,
+            )
+            cnt = cnt[:g]
+            for ae, uda, _b, casts in by_route["pallas_int"]:
+                fkey = (_struct_key(ae.args), casts[0])
+                if ae.uda_name == "sum":
+                    carries_w[ae.out_name] = sums[sum_at[fkey]][:g]
+                elif ae.uda_name == "mean":
+                    carries_w[ae.out_name] = (
+                        sums[sum_at[fkey]][:g],
+                        cnt.astype(uda.init(g)[1].dtype),
+                    )
+                else:  # empty slots already read the UDA's neutral fill
+                    carries_w[ae.out_name] = exts[
+                        ext_at[fkey, ae.uda_name == "max"]
+                    ][:g]
+        if cnt is not None:
+            for ae, uda, _b, _c in aggs_bound:
+                if ae.uda_name == "count":
+                    carries_w[ae.out_name] = cnt.astype(uda.init(g).dtype)
+        return carries_w, cnt
 
     def window_state(cols, valid):
         """Fold one window of rows into a fresh [G]-slot group state.
@@ -820,6 +922,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         (the device-resident-window form)."""
         valid = _range_valid(cols, valid)
         cols, valid = apply_pre(cols, valid)
+        carries_w = {}
         if dense_domains is not None:
             gids, oob = dense_slot_ids(cols, valid)
             keys_w = ()
@@ -828,29 +931,17 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
             # domains overflow only when a row's key escapes the
             # compile-time bounds (oob flags it for the rebucket retry).
             n_w = jnp.where(oob, g + 1, 0).astype(jnp.int32)
-            # A window with no row block the chip's tiling accepts
-            # (fold_row_chunk -> None) stays on the XLA fold below.
-            chunk = None
-            if pallas_fold:
-                from ..ops.pallas_groupby import fold_row_chunk
-
-                chunk = fold_row_chunk(valid.shape[0], g_pad)
-            if chunk is not None:
-                carries_w, valid_w = _pallas_window_carries(
-                    gids, cols, valid, chunk
-                )
-                return {
-                    "keys": (),
-                    "valid": valid_w,
-                    "carries": carries_w,
-                    "overflow": n_w > g,
-                }
+            if _f32_ok or _int_ok:
+                carries_w, cnt_w = _pallas_window_carries(gids, cols, valid)
+                if cnt_w is not None:
+                    valid_w = cnt_w > 0
         else:
             key_planes = [cols[c][i] for c, i in key_plane_index]
             gids, keys_w, valid_w, n_w = window_group_ids(key_planes, valid, g)
 
-        carries_w = {}
         for ae, uda, arg_bound, casts in aggs_bound:
+            if ae.out_name in carries_w:
+                continue
             args = [
                 apply_cast(b.fn(cols), have, want)
                 for b, (have, want) in zip(arg_bound, casts)
@@ -1143,6 +1234,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         dense_domains=dense_domains or (),
         dense_offsets=dense_offsets or (),
         dense_strides=dense_strides or (),
+        fold=fold,
     )
 
 

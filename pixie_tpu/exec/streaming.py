@@ -371,6 +371,8 @@ class StreamingQuery:
                     self._fold_lo[id(t)] = max(pos, t.first_row_id())
         folded = False
         st = self._tstats
+        if st is not None:
+            st.fold = frag.fold
         pipe = self._pipelined_windows()
         try:
             for cols, valid, (wm_key, wm_hi) in pipe:

@@ -31,7 +31,9 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   carry windows, rows in/out and the per-stage second totals
 - ``device.dispatch``     one per program enqueued: the host's enqueue
   call (attributes ``program`` as ``ProgramRegistry`` names its kind,
-  ``windows``); child of its fragment
+  ``windows``, and on a window-fold program ``fold``: ``pallas_int``,
+  ``pallas_f32``, ``xla`` or ``mixed:...``, as ``CompiledFragment.fold``
+  decided at compile time); child of its fragment
 - ``device.wait``         the host asks for a result until the bytes are
   on the host, at the sync the path has anyway
 - ``window.stage`` / ``window.stall`` / ``materialize``  windows that
@@ -388,10 +390,10 @@ class TracedFragment(FragmentStats):
 
     def dispatch(self, program: str, stage: str = "compute",
                  windows: int = 1) -> _FragmentSpanCtx:
-        return _FragmentSpanCtx(
-            self, "device.dispatch",
-            {"program": program, "windows": int(windows)}, stage=stage,
-        )
+        attrs = {"program": program, "windows": int(windows)}
+        if self.fold and stage == "compute":  # a window-fold program
+            attrs["fold"] = self.fold
+        return _FragmentSpanCtx(self, "device.dispatch", attrs, stage=stage)
 
     def _note_device(self, start_ns: int, end_ns: int) -> None:
         with self._lock:

@@ -97,17 +97,28 @@ def register(reg):
     # Float carries are f64 even though column planes are f32: [G]-sized,
     # sort-free accumulators keep billions-row sums exact without tripping
     # the f64-sort compile blowup (see types/dtypes.py).
-    # 64-bit INTEGER segment reductions avoid XLA scatter: a 64-bit
-    # scatter-add on a 2M-row window costs ~125ms real on the TPU (vs
-    # ~15ms for i32) — the sort-based form (argsort group ids once,
-    # cumsum, boundary gathers) is ~2x cheaper per agg, and the shared
-    # argsort/searchsorted CSE away across the aggs of one fused window
-    # program. 32-bit-and-smaller dtypes keep the plain scatter (cheaper
-    # than a sort), and so do floats (prefix-difference sums cancel).
+    # 64-bit INTEGER segment reductions avoid XLA's 64-bit scatter on the
+    # TPU: they take the sort-based form (argsort group ids once, cumsum,
+    # boundary gathers), whose shared argsort/searchsorted CSE away
+    # across the aggs of one fused window program. On the v5e that form
+    # costs 134-158 ms a 2^21-row window for count + mean + max of one
+    # INT64 column, about the same whatever the group count
+    # (tools/fold_sweep.py; PERF.md section 6, my chip run, PR 26). It is
+    # what is LEFT for 64-bit integers: on a dense key domain of up to
+    # INT_FOLD_MAX_GROUPS slots exec/fragment.py routes count / sum /
+    # mean / max / min to the one-hot limb kernel instead
+    # (ops/pallas_groupby.py dense_group_fold_int: 11.6 ms the same
+    # window at 2,048 slots), so these functions now serve non-dense
+    # group-bys, domains above the cross-over, and windows with no row
+    # block the kernel's tiling accepts. 32-bit-and-smaller dtypes keep
+    # the plain scatter (cheaper than a sort), and so do floats
+    # (prefix-difference sums cancel).
 
     def _sorted_segments() -> bool:
-        """TPU only: XLA's TPU sort is fast (~10ms/2M) while 64-bit
-        scatters cost ~125ms; on CPU the trade inverts hard (argsort 2M
+        """TPU only: XLA's TPU sort is fast (the sorts are 5-6 % of the
+        sort-based fold's device time, ledger PR 25; the window-long
+        gathers behind them are the rest) while a 64-bit scatter was
+        measured no better; on CPU the trade inverts hard (argsort 2M
         ~660ms vs scatter-add ~8ms). Trace-time check — executables are
         per-backend."""
         return jax.default_backend() == "tpu"
@@ -214,8 +225,8 @@ def register(reg):
         doc="Arithmetic mean of the group (sum/count carry; merges exactly).",
     )
     # Direct integer/bool overloads: EXACT i64 sums (the FLOAT64 path
-    # rides f32 device planes) via the shared sort-based reduction — no
-    # 64-bit-float scatter (~125ms per 2M-row window on the chip).
+    # rides f32 device planes) via the shared sort-based reduction here,
+    # or the limb kernel on a dense domain (see above) — never f32.
     reg.uda(
         "mean",
         (INT64,),

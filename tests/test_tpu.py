@@ -206,6 +206,84 @@ def test_pallas_dense_group_fold_on_tpu(tpu):
     print(f"pallas dense fold 1M rows: {dt * 1e3:.1f} ms")
 
 
+# The benchmark cells' shapes: a 2^21-row window over px/http_stats'
+# 2,048 slots and over px/service_stats' 32.
+@pytest.mark.parametrize("g", [2048, 32])
+def test_pallas_int_fold_on_tpu(tpu, g):
+    """The exact integer kernel, compiled for the chip, equals numpy's
+    int64 sums, counts and extremes (wraparound and both ends of the
+    range included)."""
+    import jax
+
+    import pixie_tpu  # noqa: F401  (x64 on)
+    from pixie_tpu.ops.pallas_groupby import (
+        dense_group_fold_int, int_fold_blocks, int_fold_groups,
+    )
+
+    rng = np.random.default_rng(g)
+    n = 1 << 21
+    g_pad = int_fold_groups(g)
+    chunk, g_block = int_fold_blocks(n, g_pad)
+    slots = rng.integers(0, g, n).astype(np.int32)
+    slots[::5] = g_pad  # masked rows
+    i64 = np.iinfo(np.int64)
+    v = rng.integers(i64.min, i64.max, n, dtype=np.int64, endpoint=True)
+    v[:2] = [i64.min, i64.max]
+    b = rng.random(n) < 0.1
+    fold = jax.jit(lambda s, v, b: dense_group_fold_int(
+        s, (v, b), (v, v), g=g_pad, chunk=chunk, g_block=g_block,
+        ext_max=(True, False)))
+    jax.block_until_ready(fold(slots, v, b))
+    t0 = time.perf_counter()
+    cnt, (s_v, s_b), (mx, mn) = jax.block_until_ready(fold(slots, v, b))
+    dt = time.perf_counter() - t0
+    live = slots < g
+    np.testing.assert_array_equal(
+        np.asarray(cnt)[:g], np.bincount(slots[live], minlength=g))
+    for got, arg in ((s_v, v), (s_b, b)):
+        want = np.zeros(g, np.int64)
+        np.add.at(want, slots[live], arg[live].astype(np.int64))
+        np.testing.assert_array_equal(np.asarray(got)[:g], want)
+    for got, ufunc, fill in ((mx, np.maximum, i64.min), (mn, np.minimum, i64.max)):
+        want = np.full(g, fill)
+        ufunc.at(want, slots[live], v[live])
+        np.testing.assert_array_equal(np.asarray(got)[:g], want)
+    print(f"pallas int fold 2M rows x {g} slots: {dt * 1e3:.1f} ms")
+
+
+@pytest.mark.parametrize("script", ["px/http_stats", "px/service_stats"])
+def test_shipped_scripts_reach_the_int_kernel_on_tpu(tpu, script):
+    """At the default flags ('auto') the shipped scripts' fold programs
+    hold the integer kernel, say so on their spans, and answer as the
+    XLA fold ('off') does on the same chip, bit for bit."""
+    from pixie_tpu.config import override_flag
+    from pixie_tpu.exec.engine import Engine
+    from pixie_tpu.ingest.replay import gen_http_events
+    from pixie_tpu.scripts import load_script
+
+    eng = Engine(window_rows=1 << 19)
+    for chunk in gen_http_events(1 << 20, seed=5):
+        eng.append_data("http_events", chunk)
+    pxl = load_script(script).pxl
+
+    def run(mode):
+        with override_flag("pallas_dense_fold", mode):
+            out = eng.execute_query(pxl)["output"].to_pydict()
+        folds = {sp.attributes.get("fold") for sp in eng.tracer.last().spans
+                 if sp.name == "device.dispatch"
+                 and sp.attributes["program"] != "fragment_finalize"}
+        keys = [k for k in ("service", "req_path") if k in out]
+        order = np.lexsort([np.asarray(out[k]) for k in keys])
+        return {k: np.asarray(v)[order] for k, v in out.items()}, folds
+
+    on, folds = run("auto")
+    off, off_folds = run("off")
+    assert off_folds == {"xla"}
+    assert len(folds) == 1 and "pallas_int" in next(iter(folds))
+    for k in on:
+        np.testing.assert_array_equal(on[k], off[k], err_msg=k)
+
+
 def test_dense_domain_groupby_on_tpu(tpu):
     """String-keyed group-by compiles dense (packed codes as slots) and
     matches numpy on hardware."""

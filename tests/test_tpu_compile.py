@@ -65,7 +65,7 @@ def _compile(fn, *shapes, **static):
     return compiled.as_text()
 
 
-# g = 2048 is the top of the call site's gate (fragment.py ``g <= 2048``).
+# g = 2048 is the top of the f32 kernel's gate (fragment.py ``g <= 2048``).
 @pytest.mark.parametrize("want_min", [False, True])
 @pytest.mark.parametrize("g", [128, 512, 2048])
 def test_dense_group_fold_at_bench_window(one_chip, g, want_min):
@@ -91,6 +91,91 @@ def test_dense_group_fold_at_smallest_window(one_chip):
         _rows(MIN_CAPACITY, jnp.int32, one_chip),
         _rows(MIN_CAPACITY, jnp.float32, one_chip),
         g=2048, chunk=fold_row_chunk(MIN_CAPACITY, 2048), want_min=True,
+    )
+    assert "tpu_custom_call" in text
+
+
+def _int_fold_text(sharding, n, g, sums, exts, wrap=None):
+    """Compile ``dense_group_fold_int`` at the engine's blocking for
+    [n] rows and g groups; ``sums`` / ``exts`` are the arguments' dtypes
+    (every extreme a max and a min: both fills, both compares)."""
+    from pixie_tpu.ops.pallas_groupby import (
+        dense_group_fold_int,
+        int_fold_blocks,
+        int_fold_groups,
+    )
+
+    g_pad = int_fold_groups(g)
+    chunk, g_block = int_fold_blocks(n, g_pad)
+
+    def fold(slots, sum_args, ext_args):
+        return dense_group_fold_int(
+            slots, sum_args, ext_args, g=g_pad, chunk=chunk,
+            g_block=g_block, ext_max=(True, False)[: len(exts)],
+        )
+
+    total = n * 4 if wrap else n  # ``wrap`` shards the rows over 2x2
+    return _compile(
+        jax.jit(wrap(fold) if wrap else fold),
+        _rows(total, jnp.int32, sharding),
+        tuple(_rows(total, dt, sharding) for dt in sums),
+        tuple(_rows(total, dt, sharding) for dt in exts),
+    )
+
+
+# The cells' AggOps (PERF.md section 4): px/http_stats is count, mean and
+# max of one INT64 column over 2,048 slots; px/service_stats' integer
+# part is count and mean of one BOOLEAN over 32. Then one slot above a
+# group block, the top of the gate, and min beside max.
+@pytest.mark.parametrize("g,sums,exts", [
+    (2048, (jnp.int64,), (jnp.int64,)),
+    (32, (jnp.bool_,), ()),
+    (2049, (jnp.int64,), (jnp.int64, jnp.int64)),
+    ("max", (jnp.int64, jnp.bool_), (jnp.int64, jnp.int64)),
+])
+def test_dense_group_fold_int_at_bench_window(one_chip, g, sums, exts):
+    import pixie_tpu  # noqa: F401  (x64 on: int64 stays int64)
+    from pixie_tpu.ops.pallas_groupby import INT_FOLD_MAX_GROUPS
+
+    g = INT_FOLD_MAX_GROUPS if g == "max" else g
+    assert "tpu_custom_call" in _int_fold_text(one_chip, WINDOW, g, sums, exts)
+
+
+def test_dense_group_fold_int_at_smallest_window(one_chip):
+    import pixie_tpu  # noqa: F401
+    from pixie_tpu.types.batch import MIN_CAPACITY
+
+    text = _int_fold_text(
+        one_chip, MIN_CAPACITY, 2048, (jnp.int64,), (jnp.int64,)
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_dense_group_fold_int_under_shard_map(topo):
+    """The mesh step (``parallel/executor.py``) runs the window fold
+    inside ``shard_map`` over the 2x2 mesh: 2^19 rows a shard of the
+    four-chip cell's 2^21-row windows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import pixie_tpu  # noqa: F401
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("kelvin", "agents"))
+    axes = mesh.axis_names
+
+    def wrap(fold):
+        def step(slots, sum_args, ext_args):
+            cnt, sums, exts = fold(slots, sum_args, ext_args)
+            return jax.lax.psum((cnt, sums, exts), axes)
+
+        return jax.shard_map(
+            step, mesh=mesh, in_specs=(P(axes), P(axes), P(axes)),
+            out_specs=P(), check_vma=False,
+        )
+
+    text = _int_fold_text(
+        NamedSharding(mesh, P(axes)), WINDOW // 4, 2048,
+        (jnp.int64,), (jnp.int64,), wrap=wrap,
     )
     assert "tpu_custom_call" in text
 
