@@ -1,6 +1,6 @@
 """Chip smoke: the query path, once, on the TPU it was written for.
 
-    python chip_smoke.py            # one chip: kernel + engine + served phases
+    python chip_smoke.py            # one chip: kernel, engine, served, skew
     python chip_smoke.py --chips 4  # four chips: kernel + DistributedEngine only
 
 One process, no child that needs the chip. A seeded ``http_events``
@@ -34,6 +34,7 @@ sys.path.insert(0, REPO)
 
 WINDOW = 1 << 21  # one window size: one update + one finalize per query
 REHEARSAL_MAX_ROWS = 1 << 20  # what a run without a TPU may be asked for
+SKEW_ROWS = 1 << 23  # the non-dense phase: four windows at ten columns
 
 SERVICES = [f"svc-{i}" for i in range(32)]
 PATHS = [f"/api/v1/ep{i}" for i in range(8)]
@@ -548,6 +549,86 @@ def phase_distributed(rp: Replay, meter: CompileMeter, on_tpu: bool,
                 "distributed", tracked=False)
 
 
+def _fold_groups(eng) -> list:
+    """(group, slots) of the last query's fold dispatches: how its rows
+    found their groups and at what capacity."""
+    return sorted({
+        (sp.attributes["group"], sp.attributes["slots"])
+        for sp in eng.tracer.last().spans
+        if sp.name == "device.dispatch" and "group" in sp.attributes
+    })
+
+
+def phase_skew(seed: int, rows: int, meter: CompileMeter,
+               on_tpu: bool) -> None:
+    """The non-dense group-by: configuration ``http_full_1chip``'s data
+    (Zipf keys, 65,536 request paths owned by 32 services, ten columns)
+    at ``rows`` rows through ``Engine``, both shipped scripts against
+    the benchmark's plain numpy reference. ``service`` x ``req_path``
+    has no dense domain, so px/http_stats takes the sort route with a
+    keyed state: its first run reads a sketch of the joint key and folds
+    at the capacity that gives, not at the planner's bound (the product
+    of the columns' NDVs); the second compiles nothing."""
+    from benchmark.builders import served_http_skew
+    from benchmark.reference import px_http_stats, px_service_stats
+    from pixie_tpu.exec.engine import Engine
+    from pixie_tpu.scripts import load_script
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "http_full_1chip.json")) as f:
+        cfg = json.load(f)
+    t0 = time.perf_counter()
+    data = served_http_skew.make_data(cfg, seed, rows)
+    eng = Engine(window_rows=WINDOW)
+    for hb in served_http_skew.batches(data, WINDOW):
+        eng.append_data("http_events", hb)
+    res = _resident(eng.tables["http_events"])
+    emit(phase="skew", step="ingest", rows=rows,
+         secs=time.perf_counter() - t0, resident=res)
+    assert res["rows"] == rows, f"resident rows {res['rows']} != {rows}"
+    for name, ref in (("px/http_stats", px_http_stats),
+                      ("px/service_stats", px_service_stats)):
+        want = ref.answer(data, None)
+        pxl = load_script(name).pxl
+        # The reference's own limits, but for the quantiles: those are set
+        # at the benchmark cell's size; here the smoke's 15 % stands.
+        limits = {k: 0.15 if k.endswith(("p50_relerr", "p99_relerr")) else v
+                  for k, v in ref.LIMITS.items()}
+        for run in ("first", "warm"):
+            mark = meter.mark()
+            t0 = time.perf_counter()
+            # Every group: the default cut is 10,000 rows a table.
+            got = eng.execute_query(pxl, max_output_rows=1 << 17)
+            secs = time.perf_counter() - t0
+            rows_got = ref.rows(got["output"].to_pydict())
+            if "lat_mean" in rows_got:
+                # A bare Engine hands back the f64 quotient; the served
+                # path rounds it once into an f32 plane, which is what
+                # the reference's limits are for.
+                rows_got["lat_mean"] = rows_got["lat_mean"].astype(
+                    np.float32
+                ).astype(np.float64)
+            numbers = ref.numbers(rows_got, want)
+            over = sorted(k for k, v in numbers.items() if v > limits[k])
+            compiled = meter.since(mark)
+            probes = [dict(sp.attributes) for sp in eng.tracer.last().spans
+                      if sp.name == "group_probe"]
+            emit(phase="skew", query=name, run=run, rows=rows, secs=secs,
+                 groups=len(want["key"]), fold=_fold_routes(eng),
+                 group=_fold_groups(eng), group_probe=probes,
+                 compile=compiled, numbers=numbers)
+            assert not over, f"{name} ({run}): over its limit: {over}"
+        assert compiled["programs"] == 0, (
+            f"{name}: second run compiled {compiled['programs']} program(s)"
+        )
+        if name == "px/http_stats":
+            assert all(
+                g != "dense" and slots < 4 * len(want["key"])
+                for g, slots in _fold_groups(eng)
+            ), (f"{name}: expected the non-dense route at the sketched "
+                f"capacity, got {_fold_groups(eng)}")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=16 << 20,
@@ -600,6 +681,8 @@ def main(argv=None) -> int:
             else:
                 phase_engine(rp, meter, on_tpu)
                 phase_served(rp, meter, on_tpu)
+                phase_skew(args.seed, min(args.rows, SKEW_ROWS), meter,
+                           on_tpu)
         emit(total_compile=meter.since())
         ok = on_tpu
         if not ok:

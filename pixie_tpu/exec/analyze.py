@@ -67,6 +67,11 @@ class FragmentStats:
     # that folds nothing. On a traced fragment every ``compute`` dispatch
     # carries it.
     fold: str = ""
+    # How its rows find their group (``CompiledFragment.group``: ``dense``
+    # / ``sorted`` / ``hashed``) and the capacity the fold was compiled at
+    # (``slots``); beside ``fold`` wherever that goes.
+    group: str = ""
+    slots: int = 0
     # Staging runs on the prefetch thread concurrently with compute on
     # the query thread (pipeline.py), so stage accumulation is locked.
     _lock: threading.Lock = field(
@@ -120,6 +125,8 @@ class FragmentStats:
         }
         if self.fold:
             out["fold"] = self.fold
+        if self.group:
+            out["group"], out["slots"] = self.group, self.slots
         return out
 
 

@@ -18,15 +18,21 @@ from ..types.batch import HostBatch, bucket_capacity
 from ..types.dtypes import DataType
 from ..types.strings import NULL_ID, StringDictionary
 from .fragment import ColumnMeta, compile_fragment_cached as compile_fragment
+from .joins import learned_capacity
 from .plan import AggOp
 from .stream import (
     _device_wait,
     _dispatch,
     _fetch_result,
     _timed,
+    _NO_STATS,
     QueryError,
+    _agg_capacity_key,
     _double_agg_groups,
+    _rebucket,
+    _remember_climb,
     _Stream,
+    _with_agg_groups,
     _stream_col_stats,
     _to_host_batch,
 )
@@ -169,26 +175,36 @@ def bridge_payload(engine, res):
         # like any other fragment (rows/windows/stage/compute feed the
         # per-agent QueryResourceUsage attribution).
         qstats = getattr(engine, "_query_stats", None)
+        res, frag = engine._sized_agg_fragment(res)
+        climbed = False
+        retry = _NO_STATS  # a ``rebucket`` span from the 2nd attempt on
         while True:
-            frag = compile_fragment(
-                res.chain, res.relation, res.dicts, engine.registry,
-                col_stats=_stream_col_stats(res),
-            )
-            stats = (
-                qstats.new_fragment(res.chain) if qstats is not None
-                else None
-            )
-            state = engine._fold_agg_state(res, frag, stats)
-            # The fragment's sync: the fold has run when its overflow
-            # flag is on the host; then the state that ships, a copy a
-            # leaf (as ever: see stream._fetch_result).
-            with _device_wait(stats):
-                overflowed = bool(np.asarray(state["overflow"]))
-                if not overflowed:
-                    state = jax.tree_util.tree_map(np.asarray, state)
+            with retry:
+                if frag is None:
+                    frag = compile_fragment(
+                        res.chain, res.relation, res.dicts, engine.registry,
+                        col_stats=_stream_col_stats(res),
+                    )
+                stats = (
+                    qstats.new_fragment(res.chain) if qstats is not None
+                    else None
+                )
+                state = engine._fold_agg_state(res, frag, stats)
+                # The fragment's sync: the fold has run when its overflow
+                # flag is on the host; then the state that ships, a copy
+                # a leaf (as ever: see stream._fetch_result).
+                with _device_wait(stats):
+                    overflowed = bool(np.asarray(state["overflow"]))
+                    if not overflowed:
+                        state = jax.tree_util.tree_map(np.asarray, state)
             if not overflowed:
                 break
             res = _double_agg_groups(res)  # rebucket before shipping
+            climbed = True
+            retry = _rebucket(stats, frag.slots, frag.slots * 2, "pem")
+            frag = None
+        if climbed:
+            _remember_climb(engine, res.chain, res.source, "pem", frag)
         return AggStatePayload(
             chain=tuple(res.chain),
             input_relation=res.relation,
@@ -223,11 +239,6 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
     canonical dictionary (the reference ships raw strings over GRPC,
     so alignment is implicit there; here ids must be reconciled).
     """
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
     from .fragment import _bind_pre_stage, _split_chain
     from ..types.dtypes import device_dtypes
 
@@ -270,10 +281,11 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
         if isinstance(op, AggOp)
     )
     g = max([g] + [len(p.state["valid"]) for p in pending.payloads])
-    chain = [
-        dataclasses.replace(op, max_groups=g) if isinstance(op, AggOp) else op
-        for op in p0.chain
-    ]
+    # ... or the rung an earlier merge of this chain settled on: the
+    # union of the agents' groups is the Kelvin's to observe.
+    cap_key = _agg_capacity_key(p0.chain, "(bridge)", "kelvin")
+    g = max(g, learned_capacity(engine, cap_key) or 0)
+    chain = _with_agg_groups(p0.chain, g)
     frag = compile_fragment(
         chain, p0.input_relation, dict(p0.input_dicts), engine.registry,
         allow_dense=False,
@@ -347,28 +359,14 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
                 "agent failed to rebucket"
             )
         states.append({**p.state, "keys": tuple(keys)})
+    climbed = False
+    retry = _NO_STATS  # a ``rebucket`` span from the 2nd attempt on
     while True:
         # Pad smaller states into g neutral slots, fold-merge, and on
         # merged-distinct overflow double g and retry from the (still
         # intact) original states.
-        init = frag.init_state()
-
-        def pad(a, i):
-            a = jnp.asarray(a)
-            if a.ndim == 0 or a.shape[0] >= i.shape[0]:
-                return a
-            return jnp.concatenate([a, i[a.shape[0]:]])
-
-        merge = jax.jit(frag.merge_states)
-        padded = [jax.tree_util.tree_map(pad, s, init) for s in states]
-        acc = padded[0]
-        for s in padded[1:]:
-            with _dispatch(stats, frag.merge_states):
-                acc = merge(acc, s)
-        with _dispatch(stats, frag.finalize, "finalize"):
-            cols, valid, overflow = frag.finalize(acc)
-        with _device_wait(stats):
-            overflowed = bool(overflow)
+        with retry:
+            cols, valid, overflowed = _merge_padded(frag, states, stats)
         if not overflowed:
             break
         from ..config import get_flag
@@ -380,13 +378,10 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
                 f"{get_flag('max_groups_limit')} cap refused "
                 "(PIXIE_TPU_MAX_GROUPS_LIMIT)"
             )
+        retry = _rebucket(stats, g, g * 2, "kelvin")
         g *= 2
-        chain = [
-            dataclasses.replace(op, max_groups=g)
-            if isinstance(op, AggOp)
-            else op
-            for op in chain
-        ]
+        climbed = True
+        chain = _with_agg_groups(chain, g)
         frag = compile_fragment(
             chain, p0.input_relation, dict(p0.input_dicts), engine.registry,
             allow_dense=False,  # states carry explicit key planes
@@ -401,5 +396,35 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
     ]
     with _device_wait(stats):
         cols, valid = _fetch_result(meta, cols, valid)
+    if climbed:
+        _remember_climb(engine, p0.chain, "(bridge)", "kelvin", frag)
     with _timed(stats, "materialize"):
         return _to_host_batch(meta, cols, valid)
+
+
+def _merge_padded(frag, states, stats):
+    """One attempt of the Kelvin's merge at ``frag``'s capacity: smaller
+    states padded into its neutral slots and folded through its
+    associative merge. Returns (finalized cols, valid, overflowed)."""
+    import jax
+    import jax.numpy as jnp
+
+    init = frag.init_state()
+
+    def pad(a, i):
+        a = jnp.asarray(a)
+        if a.ndim == 0 or a.shape[0] >= i.shape[0]:
+            return a
+        return jnp.concatenate([a, i[a.shape[0]:]])
+
+    merge = jax.jit(frag.merge_states)
+    padded = [jax.tree_util.tree_map(pad, s, init) for s in states]
+    acc = padded[0]
+    for s in padded[1:]:
+        with _dispatch(stats, frag.merge_states):
+            acc = merge(acc, s)
+    with _dispatch(stats, frag.finalize, "finalize"):
+        cols, valid, overflow = frag.finalize(acc)
+    with _device_wait(stats):
+        overflowed = bool(overflow)
+    return cols, valid, overflowed

@@ -81,7 +81,14 @@ from .stream import (  # noqa: F401  (re-exported)
     _concat_host,
     _device_wait,
     _dispatch,
+    _agg_capacity_key,
     _double_agg_groups,
+    _rebucket,
+    _subspan,
+    _probed_capacity,
+    _PROBE_MIN_SLOTS,
+    _remember_climb,
+    _stream_with_groups,
     _empty_host_batch,
     _fetch_result,
     _Stream,
@@ -135,6 +142,7 @@ class DeviceResult:
         eng, stream, frag = self._engine, self._stream, self._frag
         cols, valid, overflow = self._cols, self._valid, self._overflow
         stats = self._stats
+        climbed = False
         while True:
             # The aggregate's first sync: the finalize program has run
             # when its overflow flag is on the host.
@@ -151,21 +159,26 @@ class DeviceResult:
             # recovery the device join uses on output overflow; Carnot's
             # hash map grows instead, ``agg_node.cc``).
             stream = _double_agg_groups(stream)
-            frag = compile_fragment(
-                stream.chain, stream.relation, stream.dicts, eng.registry,
-                col_stats=_stream_col_stats(stream),
-            )
-            if self._qstats is not None:
-                # Fresh per-attempt stats: rows/windows stay per-attempt
-                # and the attempt is marked (analyze fidelity).
-                stats = self._qstats.new_fragment(stream.chain)
-                stats.ops = stats.ops + ("rebucket",)
-            state = eng._fold_agg_state(stream, frag, stats)
-            with _dispatch(stats, frag.finalize, "finalize"):
-                cols, valid, overflow = frag.finalize(state)
-                _block_if(stats, (cols, valid, overflow))
+            climbed = True
+            with _rebucket(stats, frag.slots, frag.slots * 2, "pem"):
+                frag = compile_fragment(
+                    stream.chain, stream.relation, stream.dicts, eng.registry,
+                    col_stats=_stream_col_stats(stream),
+                )
+                if self._qstats is not None:
+                    # Fresh per-attempt stats: rows/windows stay
+                    # per-attempt and the attempt is marked (analyze
+                    # fidelity).
+                    stats = self._qstats.new_fragment(stream.chain)
+                    stats.ops = stats.ops + ("rebucket",)
+                state = eng._fold_agg_state(stream, frag, stats)
+                with _dispatch(stats, frag.finalize, "finalize"):
+                    cols, valid, overflow = frag.finalize(state)
+                    _block_if(stats, (cols, valid, overflow))
         with _device_wait(stats):
             cols, valid = _fetch_result(frag.out_meta, cols, valid)
+        if climbed:
+            _remember_climb(eng, stream.chain, stream.source, "pem", frag)
         with _timed(stats, "materialize"):
             out = _to_host_batch(frag.out_meta, cols, valid)
         if stats is not None:
@@ -960,7 +973,10 @@ class Engine:
                 return state
         state = init_state()
         if stats is not None:
-            stats.fold = frag.fold  # onto its device.dispatch spans
+            # Onto its device.dispatch spans.
+            stats.fold, stats.group, stats.slots = (
+                frag.fold, frag.group, frag.slots
+            )
         # Scan-folding trades W dispatches for one; on the CPU backend
         # dispatches are cheap and the jnp.stack of window planes is a
         # pure memory-bandwidth loss.
@@ -1262,6 +1278,10 @@ class Engine:
     # TPU scan-fold window batching (update_all); DistributedEngine turns
     # it off for the same reason — update_all is not a distributed step.
     scan_fold = True
+    # The joint-key sketch before a keyed aggregate's first fold
+    # (_sized_agg_fragment): a plain jit a window, which DistributedEngine
+    # turns off too (its windows are row-sharded over the mesh).
+    probe_group_keys = True
 
     def _window_capacity(self, length: int) -> int:
         return max(bucket_capacity(self.window_rows), bucket_capacity(length))
@@ -1349,6 +1369,7 @@ class Engine:
     def _staged_windows_inner(self, stream: "_Stream", stats=None):
         from ..config import get_flag
         from ..table_store.coldstore import take_decode_meter
+        from ..table_store.device_cache import take_restage_meter
         from .zoneskip import chain_pruner
 
         use_cache = (
@@ -1382,9 +1403,14 @@ class Engine:
                     # locked fragment stats, the only query-scoped
                     # object reachable from the producer thread.
                     dsec, dbytes = take_decode_meter()
+                    # ... and so did the staging of a window the device
+                    # cache missed.
+                    rsec, rbytes = take_restage_meter()
                     if stats is not None:
                         if dsec or dbytes:
                             stats.add("decode", dsec, nbytes=dbytes)
+                        if rbytes:
+                            stats.add("restage", rsec, nbytes=rbytes)
                         stats.rows_in += hi - lo
                     # (lo, hi) scalar pair, not a mask: the fragment
                     # builds the iota mask INSIDE its program — no
@@ -1422,6 +1448,76 @@ class Engine:
             return dr.to_host()
         return dr
 
+    def _sized_agg_fragment(self, stream: "_Stream"):
+        """(stream, fragment) of ``stream`` compiled at the capacity to
+        fold it at (``exec/stream.py``, "the capacity of a keyed
+        aggregate"): the one remembered for this chain and these tables;
+        else, for a keyed aggregate whose plan asks for many slots, the
+        one a sketch of the joint key over the windows in range gives,
+        which is then remembered; else the plan's."""
+        from .joins import learned_capacity, remember_capacity
+
+        def compiled(st):
+            return compile_fragment(
+                st.chain, st.relation, st.dicts, self.registry,
+                col_stats=_stream_col_stats(st),
+            )
+
+        frag = compiled(stream)
+        if not frag.is_agg or frag.group == "dense":
+            return stream, frag  # no capacity to choose
+        key = _agg_capacity_key(stream.chain, stream.source, "pem")
+        known = learned_capacity(self, key)
+        if known is not None and known != frag.slots:
+            stream = _stream_with_groups(stream, known)
+            frag = compiled(stream)
+        elif (
+            known is None and key is not None and self.probe_group_keys
+            and frag.group_sketch is not None
+            and frag.slots >= _PROBE_MIN_SLOTS
+        ):
+            cap = _probed_capacity(
+                self._sketch_agg_groups(stream, frag), frag.slots
+            )
+            remember_capacity(self, key, cap)
+            if cap != frag.slots:
+                stream = _stream_with_groups(stream, cap)
+                frag = compiled(stream)
+        return stream, frag
+
+    def _sketch_agg_groups(self, stream: "_Stream", frag) -> int:
+        """The estimated distinct count of ``stream``'s joint group key:
+        its windows through ``frag.group_sketch``, one program a window,
+        one register row read back. A fragment of its own on the trace
+        (``group_probe``), with a span of that name around the pass."""
+        import jax
+
+        from ..ops.hll import hll_estimate_np
+
+        qstats = getattr(self, "_query_stats", None)
+        stats = None
+        if qstats is not None:
+            stats = qstats.new_fragment(stream.chain)
+            stats.ops = stats.ops + ("group_probe",)
+        with _subspan(stats, "group_probe", slots=frag.slots) as sp:
+            registers = frag.init_sketch()
+            pipe = self._window_pipeline(stream, stats)
+            try:
+                for cols, valid in pipe:
+                    with _dispatch(stats, frag.group_sketch):
+                        registers = frag.group_sketch(registers, cols, valid)
+                    if stats is not None:
+                        stats.windows += 1
+            finally:
+                pipe.close()
+                self._note_pipeline(pipe)
+            with _device_wait(stats):
+                registers = jax.device_get(registers)
+            estimate = hll_estimate_np(registers[0])
+            if sp is not None:
+                sp.attributes["estimate"] = estimate
+        return estimate
+
     def _run_fragment(self, stream: "_Stream", frag=None):
         """Run a stream's fragment; agg chains return a DeviceResult
         (device-resident, no host readback — callers decide when the
@@ -1429,10 +1525,7 @@ class Engine:
         domain metadata from a probe compile pass that fragment in so
         the run cannot recompile against racing stats."""
         if frag is None:
-            frag = compile_fragment(
-                stream.chain, stream.relation, stream.dicts, self.registry,
-                col_stats=_stream_col_stats(stream),
-            )
+            stream, frag = self._sized_agg_fragment(stream)
         qstats = getattr(self, "_query_stats", None)
         stats = qstats.new_fragment(stream.chain) if qstats is not None else None
 

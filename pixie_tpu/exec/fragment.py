@@ -28,7 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from ..config import get_flag
+from ..ops import hll
 from ..ops.groupby import (
+    _to_bits,
     dense_group_ids,
     dense_group_ids_hash,
     regroup_pair,
@@ -111,6 +113,20 @@ class CompiledFragment:
     # different routes. Stamped on the fold programs' device.dispatch
     # spans and on the fragment's /debug/queryz entry.
     fold: str = ""
+    # How rows find their group (agg only): ``dense`` (the packed key code
+    # is the slot), ``sorted`` or ``hashed`` (``ops/groupby.py``, a keyed
+    # state merged by regroup + scatter), and the capacity g the programs
+    # were compiled at. Beside ``fold`` on the same spans and entry.
+    group: str = ""
+    slots: int = 0
+    # A keyed fold's probe (agg only, None on a dense domain): jitted
+    # (registers, cols, valid) -> registers, the window's rows folded
+    # into ONE HyperLogLog row over the JOINT group key (``ops/hll.py``),
+    # after the same pre-stage the fold runs. ``init_sketch()`` gives the
+    # empty row. The engine reads it where no capacity is remembered yet
+    # (``Engine._sized_agg_fragment``).
+    group_sketch: object = None
+    init_sketch: object = None
 
 
 _FRAGMENT_CACHE: dict = {}
@@ -590,6 +606,10 @@ def _pure_select_map(pre):
     return mapping if mapping is not None else {}
 
 
+#: Registers of a keyed fold's joint-key sketch: 2^12, ~1.6 % error.
+_SKETCH_P = 12
+
+
 def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                  allow_dense=True, col_stats=None, pre_ops=()):
     g = agg.max_groups
@@ -937,7 +957,10 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                     valid_w = cnt_w > 0
         else:
             key_planes = [cols[c][i] for c, i in key_plane_index]
-            gids, keys_w, valid_w, n_w = window_group_ids(key_planes, valid, g)
+            with jax.named_scope("group_ids"):
+                gids, keys_w, valid_w, n_w = window_group_ids(
+                    key_planes, valid, g
+                )
 
         for ae, uda, arg_bound, casts in aggs_bound:
             if ae.out_name in carries_w:
@@ -992,18 +1015,20 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 "carries": carries,
                 "overflow": sa["overflow"] | sb["overflow"],
             }
-        ids_a, ids_b, m_keys, m_valid, n_tot = regroup_pair(
-            sa["keys"], sa["valid"], sb["keys"], sb["valid"], g
-        )
+        with jax.named_scope("regroup"):
+            ids_a, ids_b, m_keys, m_valid, n_tot = regroup_pair(
+                sa["keys"], sa["valid"], sb["keys"], sb["valid"], g
+            )
         carries = {}
         for ae, uda, _, _ in aggs_bound:
             neutral = uda.init(g)
-            ca = scatter_carry(
-                sa["carries"][ae.out_name], ids_a, sa["valid"], g, neutral
-            )
-            cb = scatter_carry(
-                sb["carries"][ae.out_name], ids_b, sb["valid"], g, neutral
-            )
+            with jax.named_scope("scatter_carry"):
+                ca = scatter_carry(
+                    sa["carries"][ae.out_name], ids_a, sa["valid"], g, neutral
+                )
+                cb = scatter_carry(
+                    sb["carries"][ae.out_name], ids_b, sb["valid"], g, neutral
+                )
             carries[ae.out_name] = uda.merge(ca, cb)
         overflow = sa["overflow"] | sb["overflow"] | (n_tot > g)
         return {
@@ -1049,6 +1074,22 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
 
         out, _ = jax.lax.scan(body, state, (stacked, los, his))
         return out
+
+    group_sketch = None
+    if dense_domains is None and group_cols:
+        @jax.jit
+        def group_sketch(registers, cols, valid):
+            valid = _range_valid(cols, valid)
+            cols, valid = apply_pre(cols, valid)
+            joint = None  # one u64 a row, a bijective mix a key plane
+            for c, i in key_plane_index:
+                bits = _to_bits(cols[c][i]).astype(jnp.uint64)
+                joint = bits if joint is None else hll._splitmix64(joint) ^ bits
+            with jax.named_scope("group_sketch"):
+                return hll.hll_update(
+                    registers, jnp.zeros(valid.shape, jnp.int32), valid,
+                    jnp.broadcast_to(joint, valid.shape), p=_SKETCH_P,
+                )
 
     # Output relation: group cols then agg outputs (struct sketches keep a
     # [G, k] plane; they are host-materialized and opaque to post ops).
@@ -1235,6 +1276,15 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         dense_offsets=dense_offsets or (),
         dense_strides=dense_strides or (),
         fold=fold,
+        group=(
+            "dense" if dense_domains is not None
+            else "hashed" if impl == "hash" else "sorted"
+        ),
+        slots=g,
+        group_sketch=group_sketch,
+        init_sketch=(
+            (lambda: hll.hll_init(1, _SKETCH_P)) if group_sketch else None
+        ),
     )
 
 

@@ -101,32 +101,131 @@ def _col(name):
 
 def _double_agg_groups(stream: "_Stream") -> "_Stream":
     """Return the stream with its AggOp's max_groups doubled (rebucket)."""
-    import dataclasses
-
     from ..config import get_flag
 
-    limit = get_flag("max_groups_limit")
-    chain = []
-    doubled = False
-    for op in stream.chain:
-        if isinstance(op, AggOp) and not doubled:
-            g2 = op.max_groups * 2
-            if g2 > limit:
-                raise QueryError(
-                    f"group-by overflow at max_groups={op.max_groups}; "
-                    f"rebucketing past the {limit} cap refused "
-                    "(PIXIE_TPU_MAX_GROUPS_LIMIT)"
-                )
-            chain.append(dataclasses.replace(op, max_groups=g2))
-            doubled = True
-        else:
-            chain.append(op)
-    if not doubled:
-        raise AssertionError("no AggOp in overflowing chain")
-    return _Stream(
-        stream.relation, stream.dicts, chain, stream.source,
-        stream.source_op, dict(stream.side),  # keep lookup-join side tables
+    g = next(
+        (op.max_groups for op in stream.chain if isinstance(op, AggOp)), None
     )
+    if g is None:
+        raise AssertionError("no AggOp in overflowing chain")
+    limit = get_flag("max_groups_limit")
+    if g * 2 > limit:
+        raise QueryError(
+            f"group-by overflow at max_groups={g}; "
+            f"rebucketing past the {limit} cap refused "
+            "(PIXIE_TPU_MAX_GROUPS_LIMIT)"
+        )
+    return _stream_with_groups(stream, g * 2)
+
+
+# -- the capacity of a keyed aggregate ---------------------------------------
+# A keyed (non-dense) aggregate is compiled at a capacity g. The planner
+# sizes g from the product of the group columns' NDVs, which is a bound,
+# not an estimate (a request path belongs to one service: 65,536 live
+# groups under a product of 2^21), and without sketches it is AggOp's
+# default, climbed from by doubling with one whole re-fold and one
+# compile a rung. At 2^22 slots a window's fold takes the chip 5 s where
+# 2^17 take 0.46 s (PERF.md section 6). What the engine can observe and
+# a plan cannot:
+#
+# - before the first fold of a chain, the JOINT key's distinct count:
+#   one HyperLogLog row over the windows in range
+#   (``CompiledFragment.group_sketch``, ``Engine._sized_agg_fragment``),
+#   a pass that neither sorts nor keeps a keyed state. Asked for only
+#   where the plan's capacity is large enough for a wrong one to hurt,
+#   and taken only where it is worth a program (a sort-route fold takes
+#   the chip's compiler most of a minute): at an eighth of the plan's or
+#   less;
+# - after a fold that overflowed, the rung its climb ended on.
+#
+# Either is remembered per (chain, source tables) on the engine, beside
+# the learned join capacities and under their LRU
+# (``joins.remember_capacity``), and the next compile of the same chain
+# starts there: the sketch is read once, the ladder is climbed once. A
+# remembered capacity never shrinks (two time ranges of one script share
+# a chain, and flapping between their sizes would compile each time); a
+# fold that overflows it climbs on and remembers the rung it settles on.
+# A fold that fits is no observation: what it returns has been through
+# the script's filters. Dense folds ignore g and are never recorded.
+#
+# The four numbers below come from one chip comparison (2^22 slots
+# against 2^17, above); where between 8 Ki and 4 Mi slots a probe pass
+# and a compile pay for themselves has not been swept
+# (docs/EXECUTOR.md).
+
+#: Head-room over the distinct keys a probe estimated.
+_CAPACITY_SLACK = 1.25
+#: Floor of a probed capacity: small states cost nothing to keep.
+_CAPACITY_FLOOR = 1024
+#: The planner's capacity is given up for a probed one from this ratio
+#: on.
+_CAPACITY_SHRINK = 8
+#: ... so a plan's capacity under this many slots is never probed.
+_PROBE_MIN_SLOTS = _CAPACITY_FLOOR * _CAPACITY_SHRINK
+
+
+def _agg_capacity_key(chain, source, where: str):
+    """Key of a chain's remembered capacity, or None when the chain does
+    not hash: its ops with the AggOp's capacity taken out (frozen
+    dataclasses: equal structure, equal key; a few microseconds, where
+    the fragment cache's canonical form costs sixty), the tables it
+    folds (a plan does not tell two tables apart) and the tier (``pem``:
+    rows folded; ``kelvin``: states merged). Two chains that differ only
+    in literals Python holds equal (1, 1.0, True) share a key: they
+    share a hint, never an answer."""
+    key = (
+        "agg", where,
+        tuple(_with_agg_groups(chain, 0)),
+        tuple(getattr(t, "name", "") for t in
+              (source if isinstance(source, list) else [source])),
+    )
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _with_agg_groups(chain, g: int) -> list:
+    import dataclasses
+
+    return [
+        dataclasses.replace(op, max_groups=g) if isinstance(op, AggOp) else op
+        for op in chain
+    ]
+
+
+def _stream_with_groups(stream: "_Stream", g: int) -> "_Stream":
+    return _Stream(
+        stream.relation, stream.dicts, _with_agg_groups(stream.chain, g),
+        stream.source, stream.source_op,
+        dict(stream.side),  # keep lookup-join side tables
+    )
+
+
+def _probed_capacity(estimate: int, planned: int) -> int:
+    """The capacity to fold at where a probe counted ``estimate``
+    distinct keys under a plan of ``planned`` slots: the next power of
+    two over them with head-room where that is far enough under the
+    plan's to be worth a program; the plan's otherwise."""
+    want = max(int(estimate * _CAPACITY_SLACK) + 1, _CAPACITY_FLOOR)
+    cap = 1 << (want - 1).bit_length()
+    return cap if cap * _CAPACITY_SHRINK <= planned else planned
+
+
+def _remember_climb(engine, chain, source, where: str, frag) -> None:
+    """Record the rung a keyed fold's climb settled on."""
+    from .joins import learned_capacity, remember_capacity
+
+    key = _agg_capacity_key(chain, source, where)
+    if key is not None and frag.slots > (learned_capacity(engine, key) or 0):
+        remember_capacity(engine, key, frag.slots)
+
+
+def _rebucket(stats, frm: int, to: int, where: str):
+    """Around one re-fold after a capacity overflow: a ``rebucket`` span
+    on a traced fragment (no-op without stats)."""
+    return _subspan(stats, "rebucket", **{"from": frm, "to": to, "where": where})
 
 
 def _window_shapes(cols) -> tuple:
@@ -162,13 +261,19 @@ def _dispatch(stats, fn, stage: str = "compute", windows: int = 1):
     return stats.dispatch(program, stage, windows)
 
 
+def _subspan(stats, name: str, **attrs):
+    """A named child span of a traced fragment (no-op without stats;
+    ``as`` then binds None)."""
+    if stats is None:
+        return _NO_STATS
+    return stats.subspan(name, **attrs)
+
+
 def _device_wait(stats):
     """Around the sync the path has anyway — the host asks for a result
     until its bytes are on the host: a ``device.wait`` span on a traced
     fragment (no-op without stats). Never adds a sync of its own."""
-    if stats is None:
-        return _NO_STATS
-    return stats.subspan("device.wait")
+    return _subspan(stats, "device.wait")
 
 
 def _block_if(stats, x) -> None:

@@ -31,6 +31,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..table_store.coldstore import take_decode_meter
+from ..table_store.device_cache import take_restage_meter
 from .engine import (
     Engine,
     QueryCancelled,
@@ -280,6 +281,9 @@ class StreamingQuery:
                 dsec, dbytes = take_decode_meter()
                 if self._tstats is not None and (dsec or dbytes):
                     self._tstats.add("decode", dsec, nbytes=dbytes)
+                rsec, rbytes = take_restage_meter()
+                if self._tstats is not None and rbytes:
+                    self._tstats.add("restage", rsec, nbytes=rbytes)
                 last_hi = hi
                 yield win.cols, (
                     np.int32(lo - win.row0), np.int32(hi - win.row0)
@@ -372,7 +376,7 @@ class StreamingQuery:
         folded = False
         st = self._tstats
         if st is not None:
-            st.fold = frag.fold
+            st.fold, st.group, st.slots = frag.fold, frag.group, frag.slots
         pipe = self._pipelined_windows()
         try:
             for cols, valid, (wm_key, wm_hi) in pipe:

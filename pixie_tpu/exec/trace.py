@@ -33,7 +33,16 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   call (attributes ``program`` as ``ProgramRegistry`` names its kind,
   ``windows``, and on a window-fold program ``fold``: ``pallas_int``,
   ``pallas_f32``, ``xla`` or ``mixed:...``, as ``CompiledFragment.fold``
-  decided at compile time); child of its fragment
+  decided at compile time, with ``group``: ``dense`` / ``sorted`` /
+  ``hashed`` and ``slots``: the capacity g it was compiled at); child
+  of its fragment
+- ``rebucket``            one per re-fold after a group-capacity overflow
+  (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
+  rows, ``kelvin`` the merge of states): the compile at twice the slots
+  and the whole fold again; child of the attempt that overflowed
+- ``group_probe``         the joint-key sketch before a keyed aggregate's
+  first fold of a chain (attributes ``slots``: the plan's capacity,
+  ``estimate``: distinct joint keys), in a fragment of its own
 - ``device.wait``         the host asks for a result until the bytes are
   on the host, at the sync the path has anyway
 - ``window.stage`` / ``window.stall`` / ``materialize``  windows that
@@ -158,6 +167,11 @@ class QueryResourceUsage:
       (0 for device-cache-resident windows — those were staged at
       append time; the gap between rows_in and bytes_staged IS the
       cache-hit signal)
+    - ``bytes_restaged`` device bytes of table windows the device cache
+      did not hold (LRU evicted them, or the tail grew) and the scan
+      staged again (``Table.device_scan``; padded planes, every column).
+      A counter of its own: admission's observed floor and pxbound's
+      check read ``bytes_staged`` alone
     - ``device_ms``     the time the query had work on the device or
       was waiting for it: each fragment's first ``device.dispatch``
       start to its last ``device.wait`` end, summed over fragments
@@ -168,6 +182,9 @@ class QueryResourceUsage:
       ingress is the sum over its producers)
     - ``retries``       dispatch retries (broker) + join-capacity
       overflow retries (engine)
+    - ``rebuckets``     re-folds of an aggregate after a group-capacity
+      overflow (``rebucket`` spans: the PEM's fold or the Kelvin's merge
+      compiled again at twice the slots)
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -185,11 +202,13 @@ class QueryResourceUsage:
     rows_out: int = 0
     windows: int = 0
     bytes_staged: int = 0
+    bytes_restaged: int = 0
     device_ms: float = 0.0
     compile_ms: float = 0.0
     stall_ms: float = 0.0
     wire_bytes: int = 0
     retries: int = 0
+    rebuckets: int = 0
     skipped_windows: int = 0
     device_peak_bytes: int = 0
     freshness_lag_ms: float = 0.0
@@ -211,8 +230,9 @@ class QueryResourceUsage:
         aggregation; accepts the dict form that crossed the bus)."""
         d = other if isinstance(other, dict) else asdict(other)
         for k in (
-            "rows_in", "rows_out", "windows", "bytes_staged", "wire_bytes",
-            "retries", "skipped_windows",
+            "rows_in", "rows_out", "windows", "bytes_staged",
+            "bytes_restaged", "wire_bytes", "retries", "rebuckets",
+            "skipped_windows",
         ):
             setattr(self, k, getattr(self, k) + int(d.get(k, 0)))
         for k in ("device_ms", "compile_ms", "stall_ms", "decode_ms"):
@@ -393,6 +413,8 @@ class TracedFragment(FragmentStats):
         attrs = {"program": program, "windows": int(windows)}
         if self.fold and stage == "compute":  # a window-fold program
             attrs["fold"] = self.fold
+            if self.group:
+                attrs["group"], attrs["slots"] = self.group, int(self.slots)
         return _FragmentSpanCtx(self, "device.dispatch", attrs, stage=stage)
 
     def _note_device(self, start_ns: int, end_ns: int) -> None:
@@ -630,6 +652,7 @@ class QueryTrace:
                 stages = {k: (v.seconds, v.nbytes, v.count)
                           for k, v in f.stages.items()}
             u.bytes_staged += stages.get("stage", (0.0, 0, 0))[1]
+            u.bytes_restaged += stages.get("restage", (0.0, 0, 0))[1]
             if isinstance(f, TracedFragment):
                 u.device_ms += f.device_ms()
             u.stall_ms += stages.get("stall", (0.0, 0, 0))[0] * 1e3
@@ -638,6 +661,7 @@ class QueryTrace:
             # map pruned before stage/decode (one add() per window).
             u.decode_ms += stages.get("decode", (0.0, 0, 0))[0] * 1e3
             u.skipped_windows += stages.get("skip", (0.0, 0, 0))[2]
+        u.rebuckets += sum(1 for s in self.spans if s.name == "rebucket")
         compile_span = next(
             (s for s in self.spans if s.name == "compile"), None
         )

@@ -148,9 +148,10 @@ class DistributedEngine(Engine):
     # Folding happens INSIDE shard_map over the mesh; neither the
     # single-device CPU thread-parallel fold nor the TPU scan-fold
     # batching (update_all — a single-logical-device jit) may bypass
-    # the distributed steps.
+    # the distributed steps; nor may the joint-key sketch's plain jit.
     cpu_parallel_fold = False
     scan_fold = False
+    probe_group_keys = False
 
     def __init__(self, registry=None, window_rows: int | None = None,
                  mesh: Mesh | None = None, n_agents: int | None = None,

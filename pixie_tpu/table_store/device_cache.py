@@ -69,6 +69,33 @@ def _enforce_global_budget(newest: tuple) -> None:
         victim[1]._evict(victim[2])
 
 
+# -- thread-local restage meter ------------------------------------------------
+# A window a scan found missing (LRU evicted it, or the tail grew) is
+# staged again inside ``Table.device_scan``, on the scanning thread (the
+# pipeline's producer when prefetching). The staging generators take the
+# meter after each window and charge it to the query's ``restage`` stage
+# (``QueryResourceUsage.bytes_restaged``), as the cold tier's decode
+# meter is. It is a counter of its own: ``bytes_staged`` feeds
+# admission's observed floor and pxbound's observed-against-predicted
+# check, which predict unpadded rows of the scan's columns.
+
+_RESTAGED = threading.local()
+
+
+def take_restage_meter() -> tuple[float, int]:
+    """Return and reset this thread's (seconds, device bytes) of windows
+    ``Table.device_scan`` staged on a cache miss since the last take."""
+    out = (getattr(_RESTAGED, "secs", 0.0), getattr(_RESTAGED, "nbytes", 0))
+    _RESTAGED.secs = 0.0
+    _RESTAGED.nbytes = 0
+    return out
+
+
+def note_restage(secs: float, nbytes: int) -> None:
+    _RESTAGED.secs = getattr(_RESTAGED, "secs", 0.0) + secs
+    _RESTAGED.nbytes = getattr(_RESTAGED, "nbytes", 0) + nbytes
+
+
 @dataclass
 class DeviceWindow:
     """One staged window: device column planes + occupancy info.

@@ -805,7 +805,9 @@ class Table:
         (exec/zoneskip.py) runs BEFORE the cache probe/stage, so a
         skipped window is never decoded or transferred.
         """
-        from .device_cache import DeviceWindowCache, stage_window
+        from .device_cache import (
+            DeviceWindowCache, note_restage, stage_window,
+        )
 
         if self._backend is None:
             return
@@ -851,9 +853,11 @@ class Table:
                     continue
             win = self._device_cache.get((w, k, first, n))
             if win is None:
+                t0 = time.perf_counter()
                 win = stage_window(self, k, w)
                 if win is None:
                     continue
+                note_restage(time.perf_counter() - t0, win.nbytes)
                 self._device_cache.put((w, k, win.row0, win.n), win)
             lo, hi = max(start_row, win.row0), min(stop_row, win.row0 + win.n)
             if hi > lo:

@@ -151,6 +151,27 @@ class TestEngineResidency:
         assert calls == []
 
 
+    def test_a_missed_window_is_charged_to_bytes_restaged(self, monkeypatch):
+        """A window the cache lost is staged again by the scan and lands
+        in ``usage.bytes_restaged`` (device bytes, padded planes), not
+        in ``bytes_staged``, which admission and pxbound read."""
+        monkeypatch.setenv("PIXIE_TPU_WINDOW_ROWS", str(W))
+        e = _mk_engine(3 * W)  # exact multiple: no tail
+        cache = e.tables["events"]._device_cache
+        resident = cache.nbytes
+        assert len(cache) == 3 and resident > 0
+        e.execute_query(QUERY)
+        u = e.tracer.last().usage
+        assert (u.bytes_staged, u.bytes_restaged) == (0, 0)
+        cache.clear()
+        e.execute_query(QUERY)
+        u = e.tracer.last().usage
+        assert (u.bytes_staged, u.bytes_restaged) == (0, resident)
+        assert cache.nbytes == resident
+        e.execute_query(QUERY)
+        assert e.tracer.last().usage.bytes_restaged == 0
+
+
 class TestAnalyze:
     def test_stats_recorded(self, monkeypatch):
         monkeypatch.setenv("PIXIE_TPU_WINDOW_ROWS", str(W))

@@ -255,3 +255,82 @@ def test_blocked_scan_inside_a_loop_at_bench_window(one_chip, scan_fn):
     fold = jax.jit(lambda ws: jax.lax.scan(body, jnp.int64(0), ws)[0])
     _compile(fold, jax.ShapeDtypeStruct((2, WINDOW), jnp.int64,
                                         sharding=one_chip))
+
+
+def _http_stats_keyed_fragment(slots):
+    """px/http_stats' aggregate over its two dictionary keys, whose
+    domains (33 x 65,537 codes) pass ``dense_domain_limit``: the keyed
+    route, compiled at ``slots``, as configuration ``http_full_1chip``
+    folds it. ``groupby_impl`` is the TPU's (``sort``), whatever the
+    backend the test runs on."""
+    import pixie_tpu  # noqa: F401
+    from pixie_tpu.config import override_flag
+    from pixie_tpu.exec.fragment import compile_fragment
+    from pixie_tpu.exec.plan import AggExpr, AggOp, ColumnRef
+    from pixie_tpu.types.dtypes import DataType
+    from pixie_tpu.types.relation import Relation
+    from pixie_tpu.types.strings import StringDictionary
+    from pixie_tpu.udf.registry import default_registry
+
+    rel = Relation([("latency_ns", DataType.INT64),
+                    ("service", DataType.STRING),
+                    ("req_path", DataType.STRING)])
+    dicts = {"service": StringDictionary(f"s{i}" for i in range(32)),
+             "req_path": StringDictionary(f"p{i}" for i in range(65_536))}
+    lat = (ColumnRef("latency_ns"),)
+    with override_flag("groupby_impl", "sort"):
+        frag = compile_fragment(
+            [AggOp(("service", "req_path"),
+                   (AggExpr("n", "count", lat), AggExpr("lat_mean", "mean", lat),
+                    AggExpr("lat_max", "max", lat)),
+                   max_groups=slots)],
+            rel, dicts, default_registry(),
+        )
+    assert frag.group == "sorted" and frag.slots == slots
+    return frag
+
+
+def _window_cols(one_chip):
+    cols = {"latency_ns": (_rows(WINDOW, jnp.int64, one_chip),),
+            "service": (_rows(WINDOW, jnp.int32, one_chip),),
+            "req_path": (_rows(WINDOW, jnp.int32, one_chip),)}
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return cols, (scalar, scalar)
+
+
+def _on(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def test_joint_key_sketch_at_bench_window(one_chip):
+    """The probe a keyed aggregate runs before its first fold
+    (``CompiledFragment.group_sketch``: px/http_stats' two dictionary
+    keys mixed into one u64 a row, a 2^21-row ``segment_max`` into 2^12
+    registers), at the benchmark's window."""
+    frag = _http_stats_keyed_fragment(1 << 17)
+    _compile(frag.group_sketch,
+             _on(jax.eval_shape(frag.init_sketch), one_chip),
+             *_window_cols(one_chip))
+
+
+# The window fold takes this compiler over half a minute: opt-in here
+# (``-m slow``), and ``chip_smoke.py``'s ``skew`` phase runs it on the chip.
+@pytest.mark.parametrize("program", [
+    "merge_states", "finalize", pytest.param("update", marks=pytest.mark.slow),
+])
+def test_keyed_state_programs_at_the_full_cells_capacity(one_chip, program):
+    """The sort-route programs at the state ``http_full_1chip`` settles
+    on, 2^17 slots: the Kelvin's regrouping merge of two keyed states
+    (a 2^18-element sort and a scatter a carry leaf), its finalize, and
+    the 2^21-row window fold (scoped vmem is what would refuse them)."""
+    frag = _http_stats_keyed_fragment(1 << 17)
+    state = _on(jax.eval_shape(frag.init_state), one_chip)
+    if program == "merge_states":
+        _compile(jax.jit(frag.merge_states), state, state)
+    elif program == "finalize":
+        _compile(frag.finalize, state)
+    else:
+        _compile(frag.update, state, *_window_cols(one_chip))
