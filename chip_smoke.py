@@ -566,9 +566,11 @@ def phase_skew(seed: int, rows: int, meter: CompileMeter,
     at ``rows`` rows through ``Engine``, both shipped scripts against
     the benchmark's plain numpy reference. ``service`` x ``req_path``
     has no dense domain, so px/http_stats takes the sort route with a
-    keyed state: its first run reads a sketch of the joint key and folds
-    at the capacity that gives, not at the planner's bound (the product
-    of the columns' NDVs); the second compiles nothing."""
+    keyed state, and since its aggregates are exact integer statistics
+    the rows ride the sort (``fold`` = ``sorted_int``; px/service_stats
+    stays dense): its first run reads a sketch of the joint key and
+    folds at the capacity that gives, not at the planner's bound (the
+    product of the columns' NDVs); the second compiles nothing."""
     from benchmark.builders import served_http_skew
     from benchmark.reference import px_http_stats, px_service_stats
     from pixie_tpu.exec.engine import Engine
@@ -621,12 +623,21 @@ def phase_skew(seed: int, rows: int, meter: CompileMeter,
         assert compiled["programs"] == 0, (
             f"{name}: second run compiled {compiled['programs']} program(s)"
         )
+        folds = _fold_routes(eng)
         if name == "px/http_stats":
             assert all(
                 g != "dense" and slots < 4 * len(want["key"])
                 for g, slots in _fold_groups(eng)
             ), (f"{name}: expected the non-dense route at the sketched "
                 f"capacity, got {_fold_groups(eng)}")
+            # count / mean / max of an INT64 by a keyed state: the rows
+            # ride the sort (the CPU's 'auto' hashes, and keeps the id form).
+            assert not on_tpu or folds == ["sorted_int"], (
+                f"{name}: fold spans say {folds}, not sorted_int")
+        else:
+            assert "sorted_int" not in folds and all(
+                g == "dense" for g, _slots in _fold_groups(eng)
+            ), f"{name}: expected a dense route, got {folds} {_fold_groups(eng)}"
 
 
 def main(argv=None) -> int:

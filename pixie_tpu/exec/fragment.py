@@ -19,6 +19,7 @@ multi-device partial-agg merge uses.
 
 from __future__ import annotations
 
+import math
 import threading
 from collections import Counter
 from dataclasses import dataclass, field
@@ -33,8 +34,11 @@ from ..ops.groupby import (
     _to_bits,
     dense_group_ids,
     dense_group_ids_hash,
+    join_u32,
     regroup_pair,
     scatter_carry,
+    sorted_group_fold,
+    split_u32,
 )
 from ..types.dtypes import DataType, device_dtypes, pad_values
 from ..types.relation import Relation
@@ -108,15 +112,18 @@ class CompiledFragment:
     # slot codes count stride steps: value = code * stride + offset).
     dense_strides: tuple = ()
     # How the window fold runs, decided at compile time (agg only):
-    # ``pallas_int`` / ``pallas_f32`` (ops/pallas_groupby.py), ``xla``, or
+    # ``pallas_int`` / ``pallas_f32`` (ops/pallas_groupby.py), ``xla``,
     # ``mixed:<route>=<aggregates>,...`` when the AggOp's aggregates take
-    # different routes. Stamped on the fold programs' device.dispatch
-    # spans and on the fragment's /debug/queryz entry.
+    # different routes, or ``sorted_int`` (a keyed group-by of exact
+    # integer statistics: the rows ride the sort, ops/groupby.py
+    # ``sorted_group_fold``). Stamped on the fold programs'
+    # device.dispatch spans and on the fragment's /debug/queryz entry.
     fold: str = ""
     # How rows find their group (agg only): ``dense`` (the packed key code
     # is the slot), ``sorted`` or ``hashed`` (``ops/groupby.py``, a keyed
-    # state merged by regroup + scatter), and the capacity g the programs
-    # were compiled at. Beside ``fold`` on the same spans and entry.
+    # state: merged by the sorted fold itself under ``sorted_int``, else
+    # by regroup + scatter), and the capacity g the programs were
+    # compiled at. Beside ``fold`` on the same spans and entry.
     group: str = ""
     slots: int = 0
     # A keyed fold's probe (agg only, None on a dense domain): jitted
@@ -504,6 +511,13 @@ def compile_fragment(ops, input_relation, input_dicts, registry: Registry,
     )
 
 
+def _dict_code(p, dom):
+    """A dictionary-id or boolean key plane as its code in [0, dom):
+    NULL_ID (-1) takes the last sub-slot (``unpack_dense_slots`` is the
+    inverse)."""
+    return jnp.clip(jnp.where(p < 0, dom - 1, p).astype(jnp.int32), 0, dom - 1)
+
+
 def unpack_dense_slots(iota, doms, col_types, xp, offsets=None, strides=None):
     """Dense slot indices -> per-group-col key planes.
 
@@ -630,6 +644,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
     dense_domains = None
     dense_offsets = None
     dense_strides = None
+    doms = None
     if allow_dense and agg.group_cols:
         doms = _static_key_domains(
             rel1, dicts1, list(agg.group_cols), col_stats
@@ -717,9 +732,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 oob = out if oob is None else (oob | out)
                 code = jnp.clip(raw, 0, dom - 1).astype(jnp.int32)
             else:
-                code = jnp.clip(
-                    jnp.where(p < 0, dom - 1, p).astype(jnp.int32), 0, dom - 1
-                )
+                code = _dict_code(p, dom)
             slot = code if slot is None else slot * jnp.int32(dom) + code
         if oob is None:
             oob_any = jnp.zeros((), dtype=jnp.bool_)
@@ -795,15 +808,23 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         # the sort: those domains keep the XLA fold.
         _int_ok = g_int <= INT_FOLD_MAX_GROUPS
 
-    def _fold_route(ae, arg_bound, casts):
+    def _int_agg(ae, arg_bound, casts):
+        """An exact integer statistic: sum / mean / max / min of one
+        INT64 / TIME64NS argument, sum / mean of a BOOLEAN one (the set
+        both integer folds take: the dense kernel and the keyed sort)."""
         if ae.uda_name in ("sum", "mean", "max", "min") and len(arg_bound) == 1:
             want = casts[0][1]
-            if want == DataType.FLOAT64:
-                return "pallas_f32" if _f32_ok else "xla"
-            if want in (DataType.INT64, DataType.TIME64NS) or (
+            return want in (DataType.INT64, DataType.TIME64NS) or (
                 want == DataType.BOOLEAN and ae.uda_name in ("sum", "mean")
-            ):
-                return "pallas_int" if _int_ok else "xla"
+            )
+        return False
+
+    def _fold_route(ae, arg_bound, casts):
+        if _int_agg(ae, arg_bound, casts):
+            return "pallas_int" if _int_ok else "xla"
+        if (ae.uda_name in ("sum", "mean", "max", "min") and len(arg_bound) == 1
+                and casts[0][1] == DataType.FLOAT64):
+            return "pallas_f32" if _f32_ok else "xla"
         return "xla"
 
     routes = {
@@ -831,6 +852,117 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
             for r in ("pallas_int", "pallas_f32", "xla") if r in _tally
         )
     )
+
+    # Keyed integer fold: a NON-dense key whose aggregates are all in the
+    # integer set folds by sorting the rows themselves, keys, values and
+    # carries riding one ``lax.sort`` (ops/groupby.py sorted_group_fold):
+    # no argsort, no window-long gather or scatter, and the same function
+    # is the window fold and the merge of two keyed states. Chosen from
+    # what the code observes: the key has no dense domain, every
+    # aggregate is exact-integer, the sort impl (the TPU's under 'auto').
+    # Anything else keeps group ids in row order (``window_group_ids`` +
+    # ``uda.update``), which a ``quantiles`` or a FLOAT64 sum needs.
+    _sorted_int = (
+        dense_domains is None and bool(group_cols) and impl == "sort"
+        and all(
+            ae.uda_name == "count" or _int_agg(ae, arg_bound, casts)
+            for ae, _uda, arg_bound, casts in aggs_bound
+        )
+    )
+    # Key planes packed into ONE word where the columns' domains show
+    # they fit (dictionary ids and booleans: exact, as the dense route
+    # trusts them; 33 x 65,537 codes are 22 bits), the top bit left for
+    # "not valid". The Kelvin's fragment (allow_dense=False) merges ids
+    # remapped into a dictionary it was not compiled against: it packs
+    # nothing and sorts the planes as they are.
+    pack_doms = None
+    if _sorted_int:
+        fold = "sorted_int"
+        if (
+            doms is not None
+            and all(rel1.col_type(c) not in _INT_KEY_TYPES for c in group_cols)
+            and math.prod(d for d, _off, _st in doms) < (1 << 31) - 1
+        ):
+            pack_doms = tuple(d for d, _off, _st in doms)
+    key_dtypes = [
+        device_dtypes(rel1.col_type(c))[i] for c, i in key_plane_index
+    ]
+    # Unpacked, a leading dictionary id still spares the flag operand:
+    # ids are >= NULL_ID (-1), so id + 1 never reads 0xFFFFFFFF.
+    _lead_id = (
+        _sorted_int and pack_doms is None
+        and rel1.col_type(group_cols[0]) == DataType.STRING
+    )
+
+    def _key_words(planes):
+        """[n] key planes (``state['keys']`` order) -> u32 sort words."""
+        if pack_doms is None:
+            words = [w for p in planes for w in split_u32(_to_bits(p))]
+            if _lead_id:
+                words[0] = words[0] + jnp.uint32(1)
+            return words
+        code = None
+        for p, dom in zip(planes, pack_doms):
+            c = _dict_code(p, dom)
+            code = c if code is None else code * jnp.int32(dom) + c
+        return [code.astype(jnp.uint32)]
+
+    def _key_planes(words):
+        """Inverse of ``_key_words`` on the [g] slots of a folded state."""
+        if pack_doms is not None:
+            return unpack_dense_slots(
+                words[0].astype(jnp.int32), pack_doms,
+                [rel1.col_type(c) for c, _i in key_plane_index], jnp,
+            )
+        if _lead_id:
+            words = [words[0] - jnp.uint32(1)] + list(words[1:])
+        planes, at = [], 0
+        for dt in key_dtypes:
+            k = 2 if jnp.dtype(dt).itemsize == 8 else 1
+            planes.append(join_u32(words[at:at + k], dt))
+            at += k
+        return planes
+
+    def _sorted_state(key_planes, valid, leaves):
+        """``sorted_group_fold`` over N partial groups. ``leaves`` maps an
+        aggregate's out_name to its statistic planes in carry order, each
+        ("sum" | "max" | "min" | "rows", int64[N] or None): the new [g]
+        state. ``rows`` is a window's count (every valid row counts one)."""
+        sums, maxes = [], []
+        for kinds in leaves.values():
+            for kind, v in kinds:
+                if kind == "sum" and not any(v is s for s in sums):
+                    sums.append(v)
+                elif kind in ("max", "min"):
+                    v = v if kind == "max" else ~v
+                    maxes.append(v)
+        with jax.named_scope("sorted_fold"):
+            words, valid_g, rows, sums_g, maxes_g, n_groups = sorted_group_fold(
+                _key_words(key_planes), valid, sums, maxes, g,
+                folded_flag=pack_doms is not None or _lead_id,
+            )
+        carries = {}
+        n_max = 0
+        for ae, uda, _b, _c in aggs_bound:
+            init = uda.init(g)
+            init_leaves = init if isinstance(init, tuple) else (init,)
+            out = []
+            for (kind, v), init_leaf in zip(leaves[ae.out_name], init_leaves):
+                if kind == "rows":
+                    leaf = rows
+                elif kind == "sum":
+                    leaf = sums_g[next(i for i, s in enumerate(sums) if s is v)]
+                else:
+                    leaf = maxes_g[n_max] if kind == "max" else ~maxes_g[n_max]
+                    n_max += 1
+                out.append(leaf.astype(init_leaf.dtype))
+            carries[ae.out_name] = tuple(out) if isinstance(init, tuple) else out[0]
+        return {
+            "keys": tuple(_key_planes(words)),
+            "valid": valid_g,
+            "carries": carries,
+            "overflow": n_groups > g,
+        }
 
     def _pallas_window_carries(gids, cols, valid):
         """Carries of the aggregates the kernels take on this window, as
@@ -942,6 +1074,26 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         (the device-resident-window form)."""
         valid = _range_valid(cols, valid)
         cols, valid = apply_pre(cols, valid)
+        if _sorted_int:
+            planes = {}  # one plane a distinct argument expression
+            leaves = {}
+            for ae, _uda, arg_bound, casts in aggs_bound:
+                if ae.uda_name == "count":
+                    leaves[ae.out_name] = (("rows", None),)
+                    continue
+                fkey = (_struct_key(ae.args), casts[0])
+                if fkey not in planes:
+                    a = apply_cast(arg_bound[0].fn(cols), *casts[0])
+                    planes[fkey] = jnp.broadcast_to(
+                        a, valid.shape).astype(jnp.int64)
+                v = planes[fkey]
+                leaves[ae.out_name] = (
+                    (("sum", v), ("rows", None)) if ae.uda_name == "mean"
+                    else ((ae.uda_name, v),)
+                )
+            return _sorted_state(
+                [cols[c][i] for c, i in key_plane_index], valid, leaves
+            )
         carries_w = {}
         if dense_domains is not None:
             gids, oob = dense_slot_ids(cols, valid)
@@ -1015,6 +1167,31 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 "carries": carries,
                 "overflow": sa["overflow"] | sb["overflow"],
             }
+        if _sorted_int:
+            # Slots are partial groups like rows are: one concatenation,
+            # one fold. Neither side need be key-sorted (the Kelvin's
+            # arrive remapped into its canonical dictionary).
+            def cat(a, b):
+                return jnp.concatenate([jnp.asarray(a), jnp.asarray(b)])
+
+            leaves = {}
+            for ae, uda, _b, _c in aggs_bound:
+                ca, cb = sa["carries"][ae.out_name], sb["carries"][ae.out_name]
+                if ae.uda_name == "mean":
+                    leaves[ae.out_name] = (
+                        ("sum", cat(ca[0], cb[0])), ("sum", cat(ca[1], cb[1])),
+                    )
+                else:
+                    kind = ae.uda_name if ae.uda_name in ("max", "min") else "sum"
+                    leaves[ae.out_name] = ((kind, cat(ca, cb)),)
+            merged = _sorted_state(
+                [cat(a, b) for a, b in zip(sa["keys"], sb["keys"])],
+                cat(sa["valid"], sb["valid"]), leaves,
+            )
+            merged["overflow"] = (
+                merged["overflow"] | sa["overflow"] | sb["overflow"]
+            )
+            return merged
         with jax.named_scope("regroup"):
             ids_a, ids_b, m_keys, m_valid, n_tot = regroup_pair(
                 sa["keys"], sa["valid"], sb["keys"], sb["valid"], g

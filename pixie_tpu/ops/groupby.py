@@ -1,25 +1,40 @@
-"""Group-by machinery: exact dense group ids with static shapes.
+"""Group-by machinery: exact groups with static shapes.
 
 Reference parity: Carnot's BlockingAggNode builds an absl flat_hash_map
 keyed by RowTuple (``src/carnot/exec/agg_node.h:66``,
-``src/carnot/exec/row_tuple.h``). Two exact device strategies:
+``src/carnot/exec/row_tuple.h``). Three exact device strategies for a key
+with no dense domain (``exec/fragment.py`` picks; a dense domain needs
+none of them, the packed key code is the slot):
 
-- ``dense_group_ids`` — **multi-key lexicographic sort + first-occurrence
-  cumsum**: no hashing at all; used for small inputs (regrouping two [G]
-  states) where sort cost is negligible.
+- ``sorted_group_fold`` — **the rows ride the sort** (PR 29): keys, values
+  and carries are operands of ``lax.sort``, so groups come out adjacent
+  with their data; sums are a cumsum differenced at the group ends, an
+  extreme is a group's last row, one row a group is compacted into the G
+  slots. No row ever gets a group id, so nothing is gathered or scattered
+  at window length. It is both the window fold and the merge of two [G]
+  states, for aggregates that are exact integer statistics (count / sum /
+  mean / max / min of INT64, TIME64NS, BOOLEAN): what ``px/http_stats``
+  runs on the TPU when its keys have no dense domain.
+- ``dense_group_ids`` — **multi-key lexicographic argsort +
+  first-occurrence cumsum**, the ids scattered back to row order: no
+  hashing at all. The id form, for aggregates that need a row's group id
+  in row order (``quantiles``, FLOAT64 sums, ``any``): their
+  ``uda.update`` takes the ids. Also regroups two [G] states
+  (``regroup_pair``) for those aggregates and under ``hashed``.
 - ``dense_group_ids_hash`` — **bounded-probe open-addressing insert on
   device**: rows claim slots in a 2G-slot table via scatter-min rounds,
   then slot ranks give dense ids. Exact (full keys are compared, the hash
   only picks probe order); O(rounds * n) elementwise work instead of
-  O(key_planes) full-window stable sorts — the per-window fast path.
-  Probe exhaustion reports overflow, which the engine's rebucketing
-  doubles away (Carnot's growing hash map, ``agg_node.cc``).
+  O(key_planes) full-window stable sorts — the per-window path on the
+  CPU, where XLA's sort is ~90x its scatter. Probe exhaustion reports
+  overflow, which the engine's rebucketing doubles away (Carnot's growing
+  hash map, ``agg_node.cc``).
 
-Plus the regroup layer: align two group states (different slot orders,
-e.g. accumulated-state x new-window, or per-device partials) onto a shared
-dense id space so UDA carries can be merged slot-wise. This is the TPU
-replacement for Carnot's partial-agg-serialize -> GRPC -> finalize-agg
-pipeline (``planner/distributed/splitter/partial_op_mgr``).
+Plus the regroup layer of the id form: align two group states (different
+slot orders, e.g. accumulated-state x new-window, or per-device partials)
+onto a shared dense id space so UDA carries can be merged slot-wise. This
+is the TPU replacement for Carnot's partial-agg-serialize -> GRPC ->
+finalize-agg pipeline (``planner/distributed/splitter/partial_op_mgr``).
 """
 
 from __future__ import annotations
@@ -27,7 +42,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .scan import blocked_cumsum
+from .scan import _CHUNK, blocked_cumsum
 
 
 def _sortable(plane):
@@ -296,3 +311,221 @@ def scatter_carry(carry, ids, valid, capacity: int, init_carry):
         carry,
         init_carry,
     )
+
+
+# -- the keyed fold as a payload-carrying sort ---------------------------------
+#
+# ``dense_group_ids`` sorts an INDEX (argsort) and fetches every plane
+# through it: a window-long gather a plane, a window-long scatter for the
+# ids, and again for each UDA. On the TPU the sorts are cheap and those
+# gathers are not (ledger, PR 28: ``sort`` 0.38 s of 7.55 s busy, the
+# fusion around them 7.01 s). ``sorted_group_fold`` lets the rows ride the
+# sort instead: ``lax.sort`` takes several operands, the leading ones as
+# keys, so after the sort every plane is in group order and what is left
+# is elementwise work, scans, and moving one row a group to the front
+# (``_front``). No argsort-and-fetch, no window-long gather or scatter.
+#
+# What shapes the sorts is the TPU compiler's time, not the chip's: a
+# 1-D sort of k u32 operands compiles in 3 / 8 / 13 / 38 / 86 s at k = 1 /
+# 2 / 3 / 6 / 10 (a described v5e, PR 29; more with several keys, more
+# again with 64-bit operands), the same at 2^18 rows as at 2^21, while a
+# BATCHED two-operand sort ([P, N] along N, the key row repeated) compiles
+# in 5 s whatever P (and runs in 3 ms at 2^18 rows, but 19-30 ms at 2^21).
+# So only the keys ride the first sort, and payload planes follow under a
+# unique key (``_batched_sort``, ``_front``).
+
+_U32_MAX = 0xFFFFFFFF
+_I32_MAX = 0x7FFFFFFF
+_SIGN = 0x80000000
+
+
+def split_u32(bits):
+    """A u32 / u64 bit plane as one / two u32 planes, high word first."""
+    if bits.dtype == jnp.uint32:
+        return [bits]
+    return [(bits >> jnp.uint64(32)).astype(jnp.uint32),
+            bits.astype(jnp.uint32)]
+
+
+def join_u32(words, dtype):
+    """Inverse of ``_to_bits`` + ``split_u32`` for a key plane of ``dtype``."""
+    if len(words) == 1:
+        return _from_bits(words[0], dtype)
+    hi, lo = words
+    bits = (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
+    return _from_bits(bits, dtype)
+
+
+def _i64_words(v):
+    """INT64 as [hi, lo] u32 whose unsigned lexicographic order is the
+    signed order of ``v`` (the sign bit flipped)."""
+    u = jax.lax.bitcast_convert_type(v, jnp.uint64)
+    hi = (u >> jnp.uint64(32)).astype(jnp.uint32) ^ jnp.uint32(_SIGN)
+    return [hi, u.astype(jnp.uint32)]
+
+
+def _i64_from_words(hi, lo):
+    u = ((hi ^ jnp.uint32(_SIGN)).astype(jnp.uint64) << jnp.uint64(32)) | (
+        lo.astype(jnp.uint64)
+    )
+    return jax.lax.bitcast_convert_type(u, jnp.int64)
+
+
+def _batched_sort(key, words):
+    """u32[N] ``words`` in ascending order of the unique int32 ``key``:
+    one two-operand sort along the rows of [P, N], the key row repeated.
+    Returns (the sorted key, the sorted words)."""
+    stacked = jnp.stack(words)
+    s_key, out = jax.lax.sort(
+        [jnp.broadcast_to(key[None, :], stacked.shape), stacked],
+        dimension=1, is_stable=False, num_keys=1,
+    )
+    return s_key[0], list(out)
+
+
+def _front(pos, planes, g):
+    """The rows whose ``pos`` (unique int32, INT32_MAX where a row is not
+    wanted) is smallest, in its order, as g slots: (pos[g], planes[g]);
+    ``planes`` are u32 or int64. Slots past the wanted rows hold junk.
+
+    A long window into few slots (N >= 4 g) sorts ``pos`` alone and
+    fetches g rows a plane: on the v5e a one-operand 2^21-row sort is 2.2
+    ms and a g-long gather 1.9-2.6 ms at g = 2^17, where the batched sort
+    of five planes is 24 ms (tools/fold_sweep.py --micro, PR
+    29). Otherwise (a merge of two states, N = 2 g) the planes ride one
+    batched sort: 3 ms at 2^18 rows.
+    """
+    n = pos.shape[0]
+    if n >= 4 * g:
+        pos_g = jnp.sort(pos)[:g]
+        at = jnp.minimum(pos_g, n - 1)
+        return pos_g, [p[at] for p in planes]
+    words = [w for p in planes
+             for w in (_i64_words(p) if p.dtype == jnp.int64 else [p])]
+    pos_s, words = _batched_sort(pos, words)
+    if n >= g:
+        pos_g, words = pos_s[:g], [w[:g] for w in words]
+    else:
+        pos_g = jnp.pad(pos_s, (0, g - n), constant_values=_I32_MAX)
+        words = [jnp.pad(w, (0, g - n)) for w in words]
+    out = []
+    for p in planes:
+        if p.dtype == jnp.int64:
+            out.append(_i64_from_words(words[0], words[1]))
+            words = words[2:]
+        else:
+            out.append(words[0])
+            words = words[1:]
+    return pos_g, out
+
+
+def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
+                      folded_flag: bool = False):
+    """Fold N partial groups into ``max_groups`` slots by sorting the rows.
+
+    A row is a partial group: a window's row (count 1, sum = max = its
+    value) or a slot of an accumulated state (its carries), so the one
+    function is the window fold and the associative merge of two states,
+    whatever order their slots are in.
+
+    Args:
+      keys: list of u32[N] key bit planes (``_to_bits`` + ``split_u32``,
+        or one packed code); equal keys are one group.
+      valid: bool[N].
+      sums: list of int64[N] planes to add up a group (wrapping, exact).
+      maxes: list of int64[N] planes to take the greatest of a group (a
+        minimum is the maximum of ``~v``). The first rides the sort as
+        its last key, and a plane that is also (``is``) in ``sums`` is
+        carried once; each further maximum costs a sort of its own.
+      max_groups: static slot count g.
+      folded_flag: the caller guarantees ``keys[0]`` of a valid row is
+        never 0xFFFFFFFF, so "not valid" needs no operand of its own.
+
+    Returns (keys[g], valid[g], rows[g], sums[g], maxes[g], n_groups):
+    slot k is the k-th group in key order; ``rows`` (int32) is its count
+    of valid rows; empty slots read zero sums and INT64_MIN maxes;
+    n_groups may exceed g (the caller's overflow).
+    """
+    g = max_groups
+    n = valid.shape[0]
+    u32 = jnp.uint32
+    iota = jnp.arange(n, dtype=jnp.int32)
+    if folded_flag:
+        lead = [jnp.where(valid, keys[0], u32(_U32_MAX))] + list(keys[1:])
+    else:
+        lead = [(~valid).astype(u32)] + list(keys)
+    n_lead = len(lead)
+    primary = maxes[0] if maxes else None
+    ride = [s for s in sums if s is not primary]
+    operands = lead + (_i64_words(primary) if primary is not None else [])
+    n_keys = len(operands)
+    # Only keys (and, when planes must follow, the row index) ride the
+    # many-key sort; a group's rows may come out in any order.
+    out = jax.lax.sort(operands + ([iota] if ride else []), dimension=0,
+                       is_stable=False, num_keys=n_keys)
+    s_lead = list(out[:n_lead])
+    s_valid = (s_lead[0] != u32(_U32_MAX)) if folded_flag else (s_lead[0] == 0)
+    s_primary = (
+        _i64_from_words(*out[n_lead:n_keys]) if primary is not None else None
+    )
+    if ride:
+        # Where each row went: the inverse of the order it came out in.
+        dest = jax.lax.sort([out[n_keys], iota], dimension=0,
+                            is_stable=False, num_keys=1)[1]
+        rode = _batched_sort(dest, [
+            w for s in ride for w in _i64_words(jnp.where(valid, s, 0))
+        ])[1]
+    sorted_sums = []
+    for s in sums:
+        if s is primary:
+            v = jnp.where(s_valid, s_primary, 0)
+        else:
+            v, rode = _i64_from_words(rode[0], rode[1]), rode[2:]
+        sorted_sums.append(v)
+
+    # Neighbour compares: a row starts a group when a key plane differs
+    # from the row before; it ends one when the next row starts one or is
+    # not valid (invalid rows sort last).
+    one = jnp.ones(1, dtype=jnp.bool_)
+    differs = jnp.zeros(n - 1, dtype=jnp.bool_)
+    for p in s_lead:
+        differs = differs | (p[1:] != p[:-1])
+    starts = jnp.concatenate([one, differs])
+    is_last = s_valid & jnp.concatenate([differs | ~s_valid[1:], one])
+    n_groups = jnp.sum((s_valid & starts).astype(jnp.int32))
+
+    # One row a group to the front: the position of a group's last row is
+    # a unique ascending key, every other row sorts behind; the planes
+    # follow. The position doubles as the count (rows before it).
+    pos = jnp.where(is_last, iota, jnp.int32(_I32_MAX))
+    pay = s_lead + ([s_primary] if primary is not None else [])
+    # Always the blocked scan past one chunk: a flat 2^18-row INT64 cumsum
+    # takes the TPU compiler 17 s, and 119 s inside the fold's scan loop
+    # (a described v5e, PR 29), where the blocked one takes 2 s.
+    pay = pay + [blocked_cumsum(v, force=n > _CHUNK) for v in sorted_sums]
+    pos_g, packed = _front(pos, pay, g)
+    slot_valid = jnp.arange(g, dtype=jnp.int32) < jnp.minimum(n_groups, g)
+    rows = pos_g - jnp.concatenate([jnp.full(1, -1, jnp.int32), pos_g[:-1]])
+    rows = jnp.where(slot_valid, rows, 0)
+    keys_g = packed[:n_lead] if folded_flag else packed[1:n_lead]
+    at = n_lead
+    i64_min = jnp.iinfo(jnp.int64).min
+    maxes_g = []
+    if primary is not None:
+        maxes_g.append(jnp.where(slot_valid, packed[at], i64_min))
+        at += 1
+    sums_g = []
+    for cs in packed[at:]:
+        # Inclusive prefix at a group's end less the one at the end of
+        # the group before: wrap-around differences are exact.
+        tot = cs - jnp.concatenate([jnp.zeros(1, jnp.int64), cs[:-1]])
+        sums_g.append(jnp.where(slot_valid, tot, 0))
+    # Every further maximum: the same keys sort to the same sequence, so
+    # ``is_last`` marks the same rows; only the order inside a group
+    # changes.
+    for extra in maxes[1:]:
+        words = jax.lax.sort(lead + _i64_words(extra), dimension=0,
+                             is_stable=False, num_keys=n_lead + 2)[n_lead:]
+        (mx,) = _front(pos, [_i64_from_words(*words)], g)[1]
+        maxes_g.append(jnp.where(slot_valid, mx, i64_min))
+    return list(keys_g), slot_valid, rows, sums_g, maxes_g, n_groups

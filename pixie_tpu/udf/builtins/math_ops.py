@@ -103,13 +103,19 @@ def register(reg):
     # across the aggs of one fused window program. On the v5e that form
     # costs 134-158 ms a 2^21-row window for count + mean + max of one
     # INT64 column, about the same whatever the group count
-    # (tools/fold_sweep.py; PERF.md section 6, my chip run, PR 26). It is
-    # what is LEFT for 64-bit integers: on a dense key domain of up to
-    # INT_FOLD_MAX_GROUPS slots exec/fragment.py routes count / sum /
-    # mean / max / min to the one-hot limb kernel instead
-    # (ops/pallas_groupby.py dense_group_fold_int: 11.6 ms the same
-    # window at 2,048 slots), so these functions now serve non-dense
-    # group-bys, domains above the cross-over, and windows with no row
+    # (tools/fold_sweep.py; PERF.md section 6, my chip run, PR 26): the
+    # window-long gathers behind the argsort, 19-34 ms each, are what it
+    # pays for (PR 29). Neither of the engine's own integer folds comes
+    # here any more: on a dense key domain of up to INT_FOLD_MAX_GROUPS
+    # slots exec/fragment.py routes count / sum / mean / max / min to the
+    # one-hot limb kernel (ops/pallas_groupby.py dense_group_fold_int),
+    # and on a key with NO dense domain, when every aggregate of the
+    # AggOp is such an integer statistic, to the payload-carrying sort
+    # (ops/groupby.py sorted_group_fold), which gives no row a group id.
+    # What is LEFT for these functions' 64-bit integer branch: an AggOp
+    # that mixes integer aggregates with one that needs group ids in row
+    # order (a ``quantiles``, a FLOAT64 sum) by a non-dense key, dense
+    # domains above the kernel's cross-over, and windows with no row
     # block the kernel's tiling accepts. 32-bit-and-smaller dtypes keep
     # the plain scatter (cheaper than a sort), and so do floats
     # (prefix-difference sums cancel).

@@ -243,8 +243,10 @@ def cluster(request, data):
             pem.append_data("http_events", hb)
         pem._register()
     deadline = time.time() + 10
-    while "http_events" not in tracker.schemas():
-        assert time.time() < deadline, "no schema reached the tracker"
+    # Every PEM's re-registration, not the first one's: until it lands a
+    # PEM is registered with no table and is planned around.
+    while len(tracker.distributed_state().pems_with_table("http_events")) < k:
+        assert time.time() < deadline, "a PEM's schema did not reach the tracker"
         time.sleep(0.01)
     yield QueryBroker(bus, tracker), pems, kelvin
     for a in pems + [kelvin]:

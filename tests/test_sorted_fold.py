@@ -1,0 +1,507 @@
+"""The keyed fold as a payload-carrying sort (``ops/groupby.py``
+``sorted_group_fold`` and the route ``exec/fragment.py`` gives it: a
+non-dense key, every aggregate an exact integer statistic, the sort
+impl). On the CPU through ``groupby_impl=sort`` and by calling the
+function: against numpy over one to three key planes, masked rows, empty
+windows, wrapping sums and negative extremes; against the id form
+(``dense_group_ids`` + ``uda.update`` + ``regroup_pair`` +
+``scatter_carry``) on the same input; over one, three and seven windows;
+over a capacity the groups overflow; and through broker, PEMs with
+dictionaries of their own and the Kelvin, whose merge sees keys in no
+order."""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import pixie_tpu  # noqa: F401  (x64 on)
+from pixie_tpu.config import override_flag
+from pixie_tpu.exec.engine import Engine
+from pixie_tpu.exec.fragment import compile_fragment
+from pixie_tpu.exec.plan import AggExpr, AggOp, ColumnRef
+from pixie_tpu.ops.groupby import (
+    _to_bits, join_u32, sorted_group_fold, split_u32,
+)
+from pixie_tpu.types.dtypes import DataType
+from pixie_tpu.types.relation import Relation
+from pixie_tpu.types.strings import NULL_ID, StringDictionary
+from pixie_tpu.udf.registry import default_registry
+
+I64 = np.iinfo(np.int64)
+
+
+# -- the function, against numpy ------------------------------------------------
+
+def _key_planes(kind, n, rng, distinct):
+    """One to three key planes of ``distinct``-ish values each."""
+    ids = rng.integers(NULL_ID, max(distinct - 1, 0), n).astype(np.int32)
+    planes = [ids]
+    if kind in ("ids_int64", "ids_int64_float"):
+        planes.append(
+            rng.integers(-2, 2, n).astype(np.int64) * (1 << 40) + 7
+        )
+    if kind == "ids_int64_float":
+        planes.append(rng.choice(
+            np.array([np.nan, -0.0, 0.0, 1.5, -np.inf], np.float32), n
+        ))
+    return planes
+
+
+def _canonical(planes, i):
+    """Row i's key as python values that compare the way groups do:
+    bit-identical NaNs are one key, -0.0 is 0.0."""
+    out = []
+    for p in planes:
+        v = p[i]
+        if p.dtype.kind == "f":
+            out.append("nan" if np.isnan(v) else float(v) + 0.0)
+        else:
+            out.append(int(v))
+    return tuple(out)
+
+
+SHAPES = ["random_masked", "empty_window", "one_group", "own_group",
+          "wrapping_sums", "negative_extremes"]
+
+
+def _case(kind, shape, seed):
+    rng = np.random.default_rng(seed)
+    n = 257
+    planes = _key_planes(kind, n, rng, distinct=1 if shape == "one_group" else 6)
+    valid = rng.random(n) < 0.8
+    a = rng.integers(-(1 << 40), 1 << 40, n).astype(np.int64)
+    b = rng.integers(-50, 50, n).astype(np.int64)
+    if shape == "empty_window":
+        valid[:] = False
+    elif shape == "one_group":
+        planes = [np.full_like(p, p[0]) for p in planes]
+    elif shape == "own_group":
+        planes[0] = np.arange(n, dtype=np.int32) - 1  # NULL_ID among them
+    elif shape == "wrapping_sums":
+        a = rng.choice(np.array([I64.max, I64.max - 1, I64.min], np.int64), n)
+    elif shape == "negative_extremes":
+        a = -np.abs(a) - 1
+        b = rng.choice(np.array([I64.min, I64.min + 1, -1], np.int64), n)
+    return planes, valid, a, b
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+@pytest.mark.parametrize("kind", ["ids", "ids_int64", "ids_int64_float"])
+def test_fold_equals_numpy(kind, shape):
+    planes, valid, a, b = _case(kind, shape, seed=len(kind) * 31 + len(shape))
+    g = 512
+    words, widths = [], []
+    for p in planes:
+        w = split_u32(_to_bits(jnp.asarray(p)))
+        words += w
+        widths.append(len(w))
+    ja, jb = jnp.asarray(a), jnp.asarray(b)
+    # sum(a), sum(b); max(a) (a sum plane too), min(b) as max(~b), max(b).
+    keys_g, valid_g, rows, sums, maxes, n_groups = jax.jit(
+        lambda words, valid, ja, jb: sorted_group_fold(
+            words, valid, [ja, jb], [ja, ~jb, jb], g)
+    )(words, jnp.asarray(valid), ja, jb)
+    want = {}
+    for i in np.flatnonzero(valid):
+        want.setdefault(_canonical(planes, i), []).append(i)
+    assert int(n_groups) == len(want)
+    valid_g = np.asarray(valid_g)
+    assert valid_g.sum() == len(want) and valid_g[: len(want)].all()
+    got_planes, at = [], 0
+    for p, k in zip(planes, widths):
+        got_planes.append(np.asarray(join_u32(keys_g[at:at + k], p.dtype)))
+        at += k
+    rows, sums, maxes = jax.device_get((rows, sums, maxes))
+    seen = set()
+    for s in np.flatnonzero(valid_g):
+        key = _canonical(got_planes, s)
+        idx = want[key]
+        seen.add(key)
+        assert rows[s] == len(idx)
+        with np.errstate(over="ignore"):
+            assert sums[0][s] == a[idx].sum() and sums[1][s] == b[idx].sum()
+        assert maxes[0][s] == a[idx].max()
+        assert ~maxes[1][s] == b[idx].min()
+        assert maxes[2][s] == b[idx].max()
+    assert len(seen) == len(want)
+    empty = ~valid_g
+    assert (rows[empty] == 0).all() and (sums[0][empty] == 0).all()
+    assert (maxes[0][empty] == I64.min).all()
+
+
+@pytest.mark.parametrize("rows_valid", ["some", "none"])
+def test_a_packed_code_needs_no_flag_operand(rows_valid):
+    """``folded_flag``: a first key word that is never 0xFFFFFFFF on a
+    valid row carries "not valid" itself."""
+    rng = np.random.default_rng(5)
+    n, g = 300, 64
+    code = rng.integers(0, 40, n).astype(np.uint32)
+    valid = (rng.random(n) < 0.7) & (rows_valid == "some")
+    v = rng.integers(-9, 9, n).astype(np.int64)
+    (codes,), valid_g, rows, (s,), (mx,), n_groups = sorted_group_fold(
+        [jnp.asarray(code)], jnp.asarray(valid), [jnp.asarray(v)],
+        [jnp.asarray(v)], g, folded_flag=True,
+    )
+    live = np.unique(code[valid])
+    assert int(n_groups) == len(live)
+    k = len(live)
+    assert np.array_equal(np.asarray(codes)[:k], live)
+    assert np.array_equal(
+        np.asarray(rows)[:k], [np.sum(valid & (code == c)) for c in live])
+    assert np.array_equal(
+        np.asarray(s)[:k], [v[valid & (code == c)].sum() for c in live])
+    assert np.array_equal(
+        np.asarray(mx)[:k], [v[valid & (code == c)].max() for c in live])
+    assert not np.asarray(valid_g)[k:].any()
+
+
+def test_more_groups_than_slots_is_reported():
+    """n_groups counts every group; the first g in key order stand."""
+    n, g = 100, 16
+    code = np.arange(n, dtype=np.uint32)[::-1].copy()
+    v = np.arange(n, dtype=np.int64)
+    (codes,), valid_g, rows, (s,), _mx, n_groups = sorted_group_fold(
+        [jnp.asarray(code)], jnp.ones(n, jnp.bool_), [jnp.asarray(v)], [], g,
+        folded_flag=True,
+    )
+    assert int(n_groups) == n > g
+    assert np.asarray(valid_g).all()
+    assert np.array_equal(np.asarray(codes), np.arange(g))
+    assert np.array_equal(np.asarray(s), n - 1 - np.arange(g))
+    assert (np.asarray(rows) == 1).all()
+
+
+# -- the route, against the id form ---------------------------------------------
+
+REL = Relation([
+    ("lat", DataType.INT64), ("bytes", DataType.INT64),
+    ("t", DataType.TIME64NS), ("err", DataType.BOOLEAN),
+    ("ratio", DataType.FLOAT64),
+    ("svc", DataType.STRING), ("path", DataType.STRING),
+    ("shard", DataType.INT64),
+])
+DICTS = {"svc": StringDictionary(f"s{i}" for i in range(20)),
+         "path": StringDictionary(f"p{i}" for i in range(70_000))}
+AGG_SETS = {
+    "count_mean_max": (("n", "count", "lat"), ("m", "mean", "lat"),
+                       ("mx", "max", "lat")),
+    "sum_min_boolmean": (("s", "sum", "lat"), ("mn", "min", "lat"),
+                         ("e", "mean", "err"), ("es", "sum", "err")),
+    "two_extremes_two_sums": (("a", "max", "lat"), ("b", "min", "bytes"),
+                              ("c", "sum", "bytes"), ("d", "sum", "lat"),
+                              ("e", "max", "bytes")),
+    "time_extremes": (("first", "min", "t"), ("last", "max", "t"),
+                      ("n", "count", "t")),
+}
+KEY_SETS = {"strings": ("svc", "path"), "string_int": ("svc", "shard")}
+N_ROWS, WINDOW = 6_000, 1_024
+
+
+def _table(seed=11):
+    rng = np.random.default_rng(seed)
+    n = N_ROWS
+    return {
+        "lat": rng.integers(-(1 << 45), 1 << 45, n).astype(np.int64),
+        "bytes": rng.integers(0, 1 << 20, n).astype(np.int64),
+        "t": rng.integers(1 << 60, (1 << 60) + 10_000, n).astype(np.int64),
+        "err": rng.random(n) < 0.3,
+        "ratio": rng.random(n).astype(np.float32),
+        "svc": rng.integers(NULL_ID, 5, n).astype(np.int32),
+        "path": rng.integers(0, 70_000, n).astype(np.int32) % 97 * 700,
+        "shard": rng.integers(-3, 3, n).astype(np.int64) * (1 << 33),
+    }
+
+
+def _frag(keys, aggs, g, impl="sort", extra=(), allow_dense=True):
+    aggs = tuple(AggExpr(o, u, (ColumnRef(c),)) for o, u, c in aggs + extra)
+    with override_flag("groupby_impl", impl):
+        return compile_fragment(
+            [AggOp(tuple(keys), aggs, max_groups=g)], REL, DICTS,
+            default_registry(), allow_dense=allow_dense,
+        )
+
+
+def _windows(table, n_windows):
+    """The table cut into ``n_windows`` windows of one capacity, the
+    last padded (its tail masked by the row range)."""
+    per = -(-N_ROWS // n_windows)
+    cap = -(-per // 8) * 8
+    out = []
+    for w in range(n_windows):
+        lo, hi = w * per, min((w + 1) * per, N_ROWS)
+        cols = {}
+        for c, v in table.items():
+            plane = np.zeros(cap, v.dtype)
+            plane[: hi - lo] = v[lo:hi]
+            cols[c] = (jnp.asarray(plane),)
+        out.append((cols, hi - lo))
+    return out
+
+
+def _fold(frag, table, n_windows=3, scan=False):
+    state = frag.init_state()
+    wins = _windows(table, n_windows)
+    if scan:
+        state = frag.update_all(
+            state, tuple(c for c, _n in wins),
+            jnp.zeros(len(wins), jnp.int32),
+            jnp.asarray([n for _c, n in wins], jnp.int32),
+        )
+    else:
+        for cols, rows in wins:
+            state = frag.update(state, cols, (jnp.int32(0), jnp.int32(rows)))
+    cols, valid, overflow = jax.device_get(frag.finalize(state))
+    return cols, valid, bool(overflow)
+
+
+def _by_key(cols, valid, keys, outs):
+    """{key tuple: tuple of outputs} of a finalized state's live slots."""
+    live = np.flatnonzero(valid)
+    ks = list(zip(*(np.asarray(cols[k][0])[live].tolist() for k in keys)))
+    assert len(set(ks)) == len(ks)
+    vs = zip(*(np.asarray(cols[o][0])[live].tolist() for o in outs))
+    return dict(zip(ks, vs))
+
+
+def _numpy_answer(table, keys, aggs):
+    groups = {}
+    for i in range(N_ROWS):
+        groups.setdefault(tuple(table[k][i].item() for k in keys), []).append(i)
+    fn = {"count": lambda v: len(v), "sum": lambda v: int(v.astype(np.int64).sum()),
+          "mean": lambda v: float(int(v.astype(np.int64).sum())) / len(v),
+          "max": lambda v: int(v.max()), "min": lambda v: int(v.min())}
+    return {k: tuple(fn[u](table[c][idx]) for _o, u, c in aggs)
+            for k, idx in groups.items()}
+
+
+@pytest.mark.parametrize("keys", list(KEY_SETS))
+@pytest.mark.parametrize("aggs", list(AGG_SETS))
+def test_route_equals_the_id_form_and_numpy(aggs, keys):
+    """The same windows through the sorted fold and through the id form
+    (a FLOAT64 sum beside the same aggregates keeps the whole AggOp on
+    ``dense_group_ids`` + ``uda.update`` + ``regroup_pair`` +
+    ``scatter_carry``): equal after ``finalize``, and numpy's."""
+    table = _table()
+    g = 2_048
+    outs = [o for o, _u, _c in AGG_SETS[aggs]]
+    new = _frag(KEY_SETS[keys], AGG_SETS[aggs], g)
+    old = _frag(KEY_SETS[keys], AGG_SETS[aggs], g,
+                extra=(("f", "sum", "ratio"),))
+    assert (new.fold, new.group) == ("sorted_int", "sorted")
+    assert (old.fold, old.group) == ("xla", "sorted")
+    got = _by_key(*_fold(new, table)[:2], KEY_SETS[keys], outs)
+    was = _by_key(*_fold(old, table)[:2], KEY_SETS[keys], outs)
+    assert got == was
+    assert got == _numpy_answer(table, KEY_SETS[keys], AGG_SETS[aggs])
+
+
+@pytest.mark.parametrize("aggs,keys,impl,allow_dense,fold", [
+    ("count_mean_max", ("svc", "path"), "sort", True, "sorted_int"),
+    ("count_mean_max", ("svc", "path"), "sort", False, "sorted_int"),
+    ("count_mean_max", ("svc", "path"), "hash", True, "xla"),
+    ("count_mean_max", ("svc",), "sort", True, "xla"),  # a dense domain
+    ("count_mean_max", (), "sort", True, "xla"),  # no key at all
+])
+def test_what_chooses_the_route(aggs, keys, impl, allow_dense, fold):
+    frag = _frag(keys, AGG_SETS[aggs], 256, impl=impl, allow_dense=allow_dense)
+    assert frag.fold == fold
+    if fold == "sorted_int":
+        assert frag.group == "sorted"
+
+
+@pytest.mark.parametrize("uda,col", [("quantiles", "lat"), ("sum", "ratio"),
+                                     ("max", "ratio")])
+def test_an_aggregate_that_needs_row_ids_keeps_the_id_form(uda, col):
+    frag = _frag(("svc", "path"), AGG_SETS["count_mean_max"], 256,
+                 extra=(("x", uda, col),))
+    assert frag.fold == "xla" and frag.group == "sorted"
+
+
+@pytest.mark.parametrize("allow_dense", [True, False],
+                         ids=["packed_code", "key_planes"])
+@pytest.mark.parametrize("n_windows,scan", [(1, False), (3, True), (7, False)])
+def test_any_cut_into_windows_gives_one_answer(n_windows, scan, allow_dense):
+    """Associativity: one, three (one ``update_all`` scan) and seven
+    windows, with the keys packed into one word (the PEM's fragment) and
+    as planes (the Kelvin's, ``allow_dense=False``)."""
+    table = _table(seed=3)
+    aggs = AGG_SETS["count_mean_max"]
+    frag = _frag(("svc", "path"), aggs, 1_024, allow_dense=allow_dense)
+    cols, valid, overflow = _fold(frag, table, n_windows, scan)
+    assert not overflow
+    got = _by_key(cols, valid, ("svc", "path"), ("n", "m", "mx"))
+    assert got == _numpy_answer(table, ("svc", "path"), aggs)
+
+
+def test_a_merge_takes_its_sides_in_any_order():
+    """Two states whose slots are shuffled (as the Kelvin's arrive after
+    the remap into its own dictionary) merge to what the table holds."""
+    table = _table(seed=4)
+    aggs = AGG_SETS["count_mean_max"]
+    frag = _frag(("svc", "path"), aggs, 1_024, allow_dense=False)
+    rng = np.random.default_rng(0)
+    halves = []
+    for cols, rows in _windows(table, 2):
+        st = frag.update(frag.init_state(), cols, (jnp.int32(0), jnp.int32(rows)))
+        perm = rng.permutation(1_024)
+        halves.append(jax.tree_util.tree_map(
+            lambda a: a[perm] if a.ndim else a, st))
+    merged = jax.jit(frag.merge_states)(halves[1], halves[0])
+    cols, valid, overflow = jax.device_get(frag.finalize(merged))
+    assert not bool(overflow)
+    got = _by_key(cols, valid, ("svc", "path"), ("n", "m", "mx"))
+    assert got == _numpy_answer(table, ("svc", "path"), aggs)
+
+
+@pytest.mark.parametrize("g", [64, 512])
+def test_overflow_is_raised_by_the_window_and_by_the_merge(g):
+    """582 live groups: a window alone overflows 64 slots; at 512 each
+    of seven windows (~450 groups) fits and the merged state does not."""
+    table = _table(seed=3)
+    frag = _frag(("svc", "path"), AGG_SETS["count_mean_max"], g)
+    wins = _windows(table, 7)
+    first = frag.window_state(wins[0][0], (jnp.int32(0), jnp.int32(wins[0][1])))
+    assert bool(first["overflow"]) == (g == 64)
+    assert _fold(frag, table, 7)[2]
+
+
+# -- the engine and the served path ---------------------------------------------
+
+PXL = """import px
+df = px.DataFrame(table='events')
+df = df.groupby(['svc', 'path']).agg(
+    n=('lat', px.count), m=('lat', px.mean), mx=('lat', px.max),
+    mn=('lat', px.min), s=('size', px.sum))
+px.display(df)
+"""
+
+
+def _events(seed, n, svc_names, n_paths):
+    rng = np.random.default_rng(seed)
+    return {
+        "time_": np.arange(n, dtype=np.int64),
+        "lat": rng.integers(-(1 << 40), 1 << 40, n).astype(np.int64),
+        "size": rng.integers(0, 1 << 16, n).astype(np.int64),
+        "svc": [svc_names[i] for i in rng.integers(0, len(svc_names), n)],
+        "path": [f"/api/{i}" for i in rng.integers(0, n_paths, n)],
+    }
+
+
+def _events_answer(parts):
+    groups = {}
+    for d in parts:
+        for s, p, lat, size in zip(d["svc"], d["path"], d["lat"], d["size"]):
+            groups.setdefault((s, p), []).append((int(lat), int(size)))
+    out = {}
+    for k, rows in groups.items():
+        lat = [r[0] for r in rows]
+        out[k] = (len(rows), sum(lat) / len(rows), max(lat), min(lat),
+                  sum(r[1] for r in rows))
+    return out
+
+
+def _rows_by_key(table):
+    d = table.to_pydict()
+    got = {}
+    for s, p, n, m, mx, mn, sz in zip(d["svc"], d["path"], d["n"], d["m"],
+                                      d["mx"], d["mn"], d["s"]):
+        assert (s, p) not in got
+        got[s, p] = (int(n), float(m), int(mx), int(mn), int(sz))
+    return got
+
+
+def _assert_rows(got, want):
+    assert got.keys() == want.keys(), (
+        len(got), len(want), sorted(want.keys() - got.keys())[:5],
+        sorted(got.keys() - want.keys())[:5])
+    for k, (n, m, mx, mn, sz) in want.items():
+        gn, gm, gmx, gmn, gsz = got[k]
+        assert (gn, gmx, gmn, gsz) == (n, mx, mn, sz)
+        assert gm == pytest.approx(m, rel=2e-6, abs=1e-3)
+
+
+@pytest.mark.parametrize("start", [64, 1 << 14], ids=["overflows", "fits"])
+def test_the_engine_refolds_after_an_overflow(start):
+    """From 64 slots the fold reports overflow and the engine climbs to a
+    capacity that holds the ~4,850 groups; the answer is numpy's either
+    way, and every fold dispatch says ``sorted_int``."""
+    from pixie_tpu.planner import CompilerState, compile_pxl
+
+    data = _events(7, 5_000, [f"svc-{i}" for i in range(40)], 2_000)
+    with override_flag("groupby_impl", "sort"), \
+            override_flag("dense_domain_limit", 1_024):
+        eng = Engine(window_rows=1_024)
+        eng.append_data("events", data)
+        state = CompilerState(
+            schemas={n: t.relation for n, t in eng.tables.items()},
+            registry=eng.registry, now_ns=0, max_output_rows=1 << 17,
+            max_groups=start,
+        )
+        out = eng.execute_plan(compile_pxl(PXL, state).plan)
+    trace = eng.tracer.last()
+    _assert_rows(_rows_by_key(out["output"]), _events_answer([data]))
+    folds = [s for s in trace.spans
+             if s.name == "device.dispatch" and "fold" in s.attributes]
+    assert folds and {s.attributes["fold"] for s in folds} == {"sorted_int"}
+    assert {s.attributes["group"] for s in folds} == {"sorted"}
+    assert (trace.usage.rebuckets > 0) == (start == 64)
+
+
+@pytest.fixture(params=[2, 3], ids=["two_pems", "three_pems"])
+def cluster(request):
+    """Broker, Kelvin and PEMs whose tables were appended as python
+    strings: every PEM has dictionaries of its own, in its own order,
+    over service sets that overlap in part."""
+    from pixie_tpu.services import (
+        AgentTracker, KelvinAgent, MessageBus, PEMAgent, QueryBroker,
+    )
+
+    k = request.param
+    bus = MessageBus()
+    tracker = AgentTracker(bus, expiry_s=60.0, check_interval_s=60.0)
+    pems = [
+        PEMAgent(bus, f"pem-{i}", heartbeat_interval_s=0.05,
+                 engine=Engine(window_rows=1_024)).start()
+        for i in range(k)
+    ]
+    kelvin = KelvinAgent(bus, "kelvin-0", heartbeat_interval_s=0.05).start()
+    parts = []
+    for i, pem in enumerate(pems):
+        names = [f"svc-{(7 * i + j) % 50}" for j in range(30)][::-1 if i % 2 else 1]
+        parts.append(_events(100 + i, 3_000 + 500 * i, names, 1_500))
+        pem.append_data("events", parts[-1])
+        pem._register()
+    deadline = time.time() + 10
+    # Every PEM's re-registration, not the first one's: until it lands a
+    # PEM is registered with no table and is planned around.
+    while len(tracker.distributed_state().pems_with_table("events")) < k:
+        assert time.time() < deadline, "a PEM's schema did not reach the tracker"
+        time.sleep(0.01)
+    yield QueryBroker(bus, tracker), pems, kelvin, parts
+    for a in pems + [kelvin]:
+        a.stop()
+    tracker.close()
+    bus.close()
+
+
+def test_pems_with_dictionaries_of_their_own_through_the_kelvin(cluster):
+    """The Kelvin remaps every PEM's ids into its canonical dictionary,
+    which reorders keys: its merge is handed states in no key order, and
+    the answer is still numpy's over all the parts."""
+    broker, pems, kelvin, parts = cluster
+    with override_flag("groupby_impl", "sort"), \
+            override_flag("dense_domain_limit", 1_024):
+        for _ in range(2):
+            res = broker.execute_script(PXL, timeout_s=180,
+                                        max_output_rows=1 << 17)
+            assert not res.get("partial")
+            _assert_rows(_rows_by_key(res["tables"]["output"]),
+                         _events_answer(parts))
+    for pem in pems:
+        frag = next(t for t in pem.engine.tracer.recent()
+                    if t["kind"] == "fragment")
+        assert {f["fold"] for f in frag["fragments"] if "fold" in f} == {
+            "sorted_int"}

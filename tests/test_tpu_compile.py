@@ -257,12 +257,15 @@ def test_blocked_scan_inside_a_loop_at_bench_window(one_chip, scan_fn):
                                         sharding=one_chip))
 
 
-def _http_stats_keyed_fragment(slots):
+def _http_stats_keyed_fragment(slots, allow_dense=True):
     """px/http_stats' aggregate over its two dictionary keys, whose
     domains (33 x 65,537 codes) pass ``dense_domain_limit``: the keyed
     route, compiled at ``slots``, as configuration ``http_full_1chip``
-    folds it. ``groupby_impl`` is the TPU's (``sort``), whatever the
-    backend the test runs on."""
+    folds it: every aggregate an exact integer statistic, so the rows
+    ride the sort (``fold`` = ``sorted_int``), the two keys packed into
+    one word; ``allow_dense=False`` is the Kelvin's fragment, which
+    sorts the key planes as they are. ``groupby_impl`` is the TPU's
+    (``sort``), whatever the backend the test runs on."""
     import pixie_tpu  # noqa: F401
     from pixie_tpu.config import override_flag
     from pixie_tpu.exec.fragment import compile_fragment
@@ -284,9 +287,9 @@ def _http_stats_keyed_fragment(slots):
                    (AggExpr("n", "count", lat), AggExpr("lat_mean", "mean", lat),
                     AggExpr("lat_max", "max", lat)),
                    max_groups=slots)],
-            rel, dicts, default_registry(),
+            rel, dicts, default_registry(), allow_dense=allow_dense,
         )
-    assert frag.group == "sorted" and frag.slots == slots
+    assert (frag.group, frag.fold, frag.slots) == ("sorted", "sorted_int", slots)
     return frag
 
 
@@ -316,21 +319,64 @@ def test_joint_key_sketch_at_bench_window(one_chip):
              *_window_cols(one_chip))
 
 
-# The window fold takes this compiler over half a minute: opt-in here
-# (``-m slow``), and ``chip_smoke.py``'s ``skew`` phase runs it on the chip.
+# Compile seconds here, for the described v5e (PR 29): merge_states 33-41,
+# finalize 0.6, update 34, update_all (three windows) 35, the Kelvin's
+# merge_states 50, the four-chip step ~70. What takes over half a minute
+# is opt-in (``-m slow``); ``chip_smoke.py``'s ``skew`` phase runs the
+# window fold on the chip.
 @pytest.mark.parametrize("program", [
-    "merge_states", "finalize", pytest.param("update", marks=pytest.mark.slow),
+    "merge_states", "finalize",
+    pytest.param("update", marks=pytest.mark.slow),
+    pytest.param("update_all", marks=pytest.mark.slow),
+    pytest.param("kelvin_merge_states", marks=pytest.mark.slow),
 ])
 def test_keyed_state_programs_at_the_full_cells_capacity(one_chip, program):
-    """The sort-route programs at the state ``http_full_1chip`` settles
-    on, 2^17 slots: the Kelvin's regrouping merge of two keyed states
-    (a 2^18-element sort and a scatter a carry leaf), its finalize, and
-    the 2^21-row window fold (scoped vmem is what would refuse them)."""
-    frag = _http_stats_keyed_fragment(1 << 17)
+    """The sorted-fold programs at the state ``http_full_1chip`` settles
+    on, 2^17 slots: the merge of two keyed states (a 2^18-row sort, the
+    carries riding a batched one), with the keys packed (the PEM's
+    fragment) and as planes (the Kelvin's), its finalize, and the
+    2^21-row window fold alone and as the three-window scan the cell
+    dispatches. No window-long scatter is left in any of them."""
+    frag = _http_stats_keyed_fragment(
+        1 << 17, allow_dense=program != "kelvin_merge_states")
     state = _on(jax.eval_shape(frag.init_state), one_chip)
-    if program == "merge_states":
-        _compile(jax.jit(frag.merge_states), state, state)
+    if program.endswith("merge_states"):
+        text = _compile(jax.jit(frag.merge_states), state, state)
     elif program == "finalize":
-        _compile(frag.finalize, state)
+        text = _compile(frag.finalize, state)
+    elif program == "update":
+        text = _compile(frag.update, state, *_window_cols(one_chip))
     else:
-        _compile(frag.update, state, *_window_cols(one_chip))
+        cols, _range = _window_cols(one_chip)
+        bounds = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
+        text = _compile(frag.update_all, state, (cols,) * 3, bounds, bounds)
+    assert " scatter(" not in text
+    if program != "finalize":
+        assert " sort(" in text
+
+
+@pytest.mark.slow
+def test_keyed_fold_step_under_shard_map(topo):
+    """``DistributedEngine``'s step for a keyed chain on the four chips
+    (``parallel/executor.py`` ``distributed_agg_step`` over
+    ``agent_mesh(4)``): the sorted window fold of a 2^19-row shard, the
+    all-gathered partial states merged pairwise by the same function,
+    then into the accumulated state. No cell reaches it (the four-chip
+    cell's keys are dense); this is what says it would compile."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pixie_tpu.parallel.executor import distributed_agg_step
+    from pixie_tpu.parallel.mesh import agent_mesh
+
+    mesh = agent_mesh(4, devices=topo.devices)
+    frag = _http_stats_keyed_fragment(1 << 17)
+    everywhere = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(mesh.axis_names))
+    cols = {"latency_ns": (_rows(WINDOW, jnp.int64, rows),),
+            "service": (_rows(WINDOW, jnp.int32, rows),),
+            "req_path": (_rows(WINDOW, jnp.int32, rows),)}
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=everywhere)
+    step = distributed_agg_step(frag, mesh, range_valid=True)
+    text = _compile(step, _on(jax.eval_shape(frag.init_state), everywhere),
+                    cols, {}, (scalar, scalar))
+    assert "all-gather" in text and " scatter(" not in text

@@ -1,7 +1,11 @@
-"""Where the one-hot integer fold and the sort-based fold cross over.
+"""Where the one-hot integer fold and the sort-based fold cross over,
+and (``--keyed``) what the keyed fold costs, old route against new.
 
     python tools/fold_sweep.py                 # on a chip: the sweep
+    python tools/fold_sweep.py --keyed         # on a chip: the keyed fold
+    python tools/fold_sweep.py --micro         # on a chip: sorts, gathers, scans
     JAX_PLATFORMS=cpu python tools/fold_sweep.py --rows 4096 --groups 128
+    JAX_PLATFORMS=cpu python tools/fold_sweep.py --keyed --rows 4096 --groups 256
 
 Times one window's fold of ``px/http_stats``' aggregates (``count``,
 ``mean`` and ``max`` of one INT64 column) two ways over a range of group
@@ -13,6 +17,15 @@ the chip; the run it was set from is cited in PERF.md (PR 26). One JSON
 object a line; both forms are checked against each other bit for bit.
 On the CPU it only rehearses (kernel in interpret mode, no time means
 anything).
+
+``--keyed``: one window of ``px/http_stats`` over its two dictionary keys
+with NO dense domain (33 x 65,537 codes), folded into a keyed state of g
+slots and merged into an accumulated one, two ways: the id form
+(``ops/groupby.py`` ``dense_group_ids``, the UDAs' ``update``,
+``regroup_pair`` + ``scatter_carry``: an argsort and a window-long gather
+or scatter a step) and ``sorted_group_fold`` (the rows ride the sort).
+The pieces are timed apart, and the two states are compared bit for bit.
+PERF.md section 6 (PR 29) holds the chip's output.
 """
 
 from __future__ import annotations
@@ -39,16 +52,204 @@ def _time(fn, *args, reps: int) -> float:
     return sorted(out)[len(out) // 2]
 
 
+_DOMS = (33, 65_537)  # px/http_stats' key columns in `http_full_1chip`
+
+
+def keyed_sweep(args) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import pixie_tpu  # noqa: F401  (x64 on)
+    from pixie_tpu.ops import groupby as gb
+    from pixie_tpu.ops.scan import blocked_cumsum
+    from pixie_tpu.types.dtypes import DataType
+    from pixie_tpu.udf.registry import default_registry
+
+    dev = jax.devices()[0]
+    print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
+                      "rows": args.rows, "keyed": True}), flush=True)
+    reg = default_registry()
+    udas = [reg.get_uda("count", [DataType.FLOAT64]),
+            reg.get_uda("mean", [DataType.INT64]),
+            reg.get_uda("max", [DataType.INT64])]
+    rng = np.random.default_rng(args.seed)
+    n = args.rows
+    lat = jnp.asarray(np.exp(rng.normal(15, 1.2, n)).astype(np.int64))
+    valid = jnp.asarray(rng.random(n) < 0.92)
+
+    def code_of(svc, path):
+        return (svc * jnp.int32(_DOMS[1]) + path).astype(jnp.uint32)
+
+    for g in args.groups or [1 << 13, 1 << 15, 1 << 17, 1 << 19]:
+        live = max(1, g // 2)  # groups a window holds: half the slots
+        c = rng.integers(0, live, (2, n))
+        svc = [jnp.asarray((x % 32).astype(np.int32)) for x in c]
+        path = [jnp.asarray((x // 32).astype(np.int32)) for x in c]
+
+        # -- the id form: what window_state / merge_states ran until PR 29
+        def old_window(svc, path, valid, lat):
+            gids, keys, kvalid, n_w = gb.dense_group_ids([svc, path], valid, g)
+            carries = tuple(u.update(u.init(g), gids, valid, lat) for u in udas)
+            return keys, kvalid, carries, n_w > g
+
+        def old_merge(sa, sb):
+            ids_a, ids_b, keys, kvalid, n_tot = gb.regroup_pair(
+                sa[0], sa[1], sb[0], sb[1], g)
+            carries = tuple(
+                u.merge(gb.scatter_carry(ca, ids_a, sa[1], g, u.init(g)),
+                        gb.scatter_carry(cb, ids_b, sb[1], g, u.init(g)))
+                for u, ca, cb in zip(udas, sa[2], sb[2]))
+            return keys, kvalid, carries, sa[3] | sb[3] | (n_tot > g)
+
+        # -- the rows ride the sort
+        def new_window(svc, path, valid, lat):
+            (code,), kvalid, rows, (s,), (mx,), n_w = gb.sorted_group_fold(
+                [code_of(svc, path)], valid, [lat], [lat], g, folded_flag=True)
+            rows = rows.astype(jnp.int64)
+            return code, kvalid, (rows, (s, rows), mx), n_w > g
+
+        def new_merge(sa, sb):
+            cat = lambda a, b: jnp.concatenate([a, b])
+            (code,), kvalid, _r, (cn, s, cm), (mx,), n_tot = gb.sorted_group_fold(
+                [cat(sa[0], sb[0])], cat(sa[1], sb[1]),
+                [cat(sa[2][0], sb[2][0]), cat(sa[2][1][0], sb[2][1][0]),
+                 cat(sa[2][1][1], sb[2][1][1])],
+                [cat(sa[2][2], sb[2][2])], g, folded_flag=True)
+            return code, kvalid, (cn, (s, cm), mx), sa[3] | sb[3] | (n_tot > g)
+
+        def by_code(state, packed):
+            """{code: (n, sum, count, max)} of a state's live slots."""
+            keys, kvalid, (cn, (s, cm), mx), over = jax.device_get(state)
+            assert not over
+            code = keys if packed else np.asarray(
+                keys[0].astype(np.int64) * _DOMS[1] + keys[1])
+            live_ = np.flatnonzero(kvalid)
+            order = live_[np.argsort(np.asarray(code)[live_])]
+            return tuple(np.asarray(a)[order] for a in (code, cn, s, cm, mx))
+
+        line = {"groups": g, "live": live}
+        states = {}
+        for name, win, mrg in (("old", old_window, old_merge),
+                               ("new", new_window, new_merge)):
+            win_j, mrg_j = jax.jit(win), jax.jit(mrg)
+            w0 = (svc[0], path[0], valid, lat)
+            w1 = (svc[1], path[1], valid, lat)
+            st0, st1 = jax.block_until_ready((win_j(*w0), win_j(*w1)))
+            states[name] = by_code(mrg_j(st0, st1), name == "new")
+            line[f"{name}_window_ms"] = round(_time(win_j, *w0, reps=args.reps), 3)
+            line[f"{name}_merge_ms"] = round(
+                _time(mrg_j, st0, st1, reps=args.reps), 3)
+        for a, b in zip(states["old"], states["new"]):
+            np.testing.assert_array_equal(a, b)
+        line["equal"] = True
+
+        # -- the pieces, apart
+        code = code_of(svc[0], path[0])
+        hi, lo = gb._i64_words(lat)
+        gids = jnp.asarray(rng.integers(0, g, n).astype(np.int32))
+
+        def scans(code, valid, v):
+            differs = code[1:] != code[:-1]
+            last = valid & jnp.concatenate([differs, jnp.ones(1, jnp.bool_)])
+            return last, blocked_cumsum(jnp.where(valid, v, 0))
+
+        pieces = {
+            "main_sort": (lambda c, h, l: jax.lax.sort(
+                [c, h, l], dimension=0, is_stable=False, num_keys=3),
+                (code, hi, lo)),
+            "scans": (scans, (jnp.sort(code), valid, lat)),
+            "compaction": (lambda k, *p: gb._front(k, list(p), g),
+                           (jnp.asarray(rng.permutation(n).astype(np.int32)),
+                            code, lat, lat)),
+            "old_group_ids": (lambda s, p, m: gb.dense_group_ids([s, p], m, g),
+                              (svc[0], path[0], valid)),
+            "old_uda_updates": (lambda gi, m, v: tuple(
+                u.update(u.init(g), gi, m, v) for u in udas),
+                (gids, valid, lat)),
+        }
+        for name, (fn, a) in pieces.items():
+            line[f"{name}_ms"] = round(_time(jax.jit(fn), *a, reps=args.reps), 3)
+        print(json.dumps(line), flush=True)
+    return 0
+
+
+def micro_sweep(args) -> int:
+    """What the keyed fold is built from, one primitive a line, at the
+    window's and the merge's lengths: sorts by operand and key count, the
+    batched sort, gathers, a scatter, the scans (``first_s`` is the first
+    call: compile, or the cache)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import pixie_tpu  # noqa: F401  (x64 on)
+    from pixie_tpu.ops.scan import blocked_cumsum
+
+    dev = jax.devices()[0]
+    print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
+                      "micro": True}), flush=True)
+    rng = np.random.default_rng(args.seed)
+    g = 1 << 17
+
+    def bench(name, fn, *a):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*a))
+        first = time.perf_counter() - t0
+        print(json.dumps({"name": name, "first_s": round(first, 2),
+                          "ms": round(_time(fn, *a, reps=args.reps), 3)}),
+              flush=True)
+
+    for n in (args.rows, args.rows >> 3):
+        u = [jnp.asarray(rng.integers(0, 1 << 32, n, dtype=np.uint64)
+                         .astype(np.uint32)) for _ in range(8)]
+        code = jnp.asarray(rng.integers(0, 1 << 16, n).astype(np.uint32))
+        i64 = jnp.asarray(rng.integers(-1 << 62, 1 << 62, n).astype(np.int64))
+        perm = jnp.asarray(rng.permutation(n).astype(np.int32))
+        for ops, keys in ((1, 1), (2, 1), (3, 1), (3, 3), (4, 3), (6, 1)):
+            bench(f"sort n={n} operands={ops} keys={keys}", jax.jit(
+                lambda *o, k=keys: jax.lax.sort(
+                    list(o), dimension=0, is_stable=False, num_keys=k)),
+                code, *u[:ops - 1])
+        for planes in (1, 4, 8):
+            bench(f"batched_sort n={n} planes={planes}", jax.jit(
+                lambda k, p: jax.lax.sort(
+                    [jnp.broadcast_to(k[None, :], p.shape), p], dimension=1,
+                    is_stable=False, num_keys=1)[1]),
+                perm, jnp.stack(u[:planes]))
+        take = jax.jit(lambda a, i: a[i])
+        for name, a in (("u32", u[0]), ("i64", i64)):
+            bench(f"gather_{name} n={n} full", take, a, perm)
+            bench(f"gather_{name} n={n} {min(g, n)}-long", take, a, perm[:g])
+        bench(f"scatter_i32 n={n}", jax.jit(
+            lambda a, i: jnp.zeros(a.shape, jnp.int32).at[i].set(a)), perm, perm)
+        bench(f"blocked_cumsum_i64 n={n}", jax.jit(
+            lambda a: blocked_cumsum(a, force=True)), i64)
+        bench(f"searchsorted {min(g, n)} edges into n={n}", jax.jit(
+            lambda a, q: jnp.searchsorted(a, q, side="right")),
+            jnp.sort(perm), perm[:g])
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=1 << 21)
-    ap.add_argument("--groups", type=int, nargs="*",
-                    default=[32, 2048, 4096, 8192, 16384, 32768])
+    ap.add_argument("--keyed", action="store_true",
+                    help="the keyed fold: the id form against the payload sort")
+    ap.add_argument("--micro", action="store_true",
+                    help="the primitives the keyed fold is built from")
+    ap.add_argument("--groups", type=int, nargs="*", default=None)
     ap.add_argument("--blocks", nargs="*", default=[],
                     help="extra kernel blockings chunk,g_block")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=26)
     args = ap.parse_args(argv)
+    if args.micro:
+        return micro_sweep(args)
+    if args.keyed:
+        return keyed_sweep(args)
+    if args.groups is None:
+        args.groups = [32, 2048, 4096, 8192, 16384, 32768]
 
     import jax
     import jax.numpy as jnp
