@@ -670,21 +670,20 @@ def main(argv=None) -> int:
     ok = False
     try:
         emit(native_rebuilt=native.rebuild_all(), libraries=native.LIBRARIES)
-        from pixie_tpu.config import override_flag
+        from unittest import mock
+
+        from pixie_tpu.ops import routes
 
         meter = CompileMeter()
         rp = Replay(args.rows, args.seed)
-        with contextlib.ExitStack() as flags:
+        with contextlib.ExitStack() as rehearsal:
             if not on_tpu:
-                # A rehearsal of the control flow: 'auto' keeps the
-                # kernels off the CPU, so they are asked for in interpret
-                # mode by name, and the CPU backend's native fold is off
-                # so that the XLA fold the chip runs is what rehearses.
-                # On the chip every flag stays at its default.
-                for name, value in (("pallas_dense_fold", "interpret"),
-                                    ("pallas_tdigest", "interpret"),
-                                    ("cpu_fold_threads", 1)):
-                    flags.enter_context(override_flag(name, value))
+                # A rehearsal of the control flow: the chip's routes on
+                # this backend (sorts, the kernels interpreted, the scan
+                # fold, no native fold), by substituting the one function
+                # every route choice asks. On the chip nothing is set.
+                rehearsal.enter_context(mock.patch.object(
+                    routes, "routes_platform", lambda: "tpu"))
             phase_int_kernel(args.seed, min(args.rows, WINDOW), on_tpu,
                              args.chips)
             if args.chips == 4:

@@ -129,16 +129,8 @@ def all_flags() -> dict:
 # -- engine/table tunables ---------------------------------------------------
 define_flag("window_rows", 1 << 17,
             "Rows per streamed device window (engine + device residency).")
-define_flag("max_groups", 4096,
-            "Initial group-by capacity; overflow doubles it and re-runs.")
 define_flag("max_groups_limit", 1 << 22,
             "Hard cap for group-by rebucketing growth.")
-define_flag("groupby_impl", "auto",
-            "Per-window group-id algorithm for keys WITHOUT a static dense "
-            "domain: 'auto' picks per backend (sort on TPU, hash on CPU), "
-            "'sort' forces the multi-key stable sort (data-independent "
-            "runtime; XLA TPU sorts are fast), 'hash' forces the bounded-"
-            "probe device table (scatter-heavy; fast on CPU).")
 define_flag("dense_domain_limit", 1 << 20,
             "Group-bys whose key columns all have statically-known domains "
             "(dictionary-encoded strings, booleans) with product <= this "
@@ -198,20 +190,6 @@ define_flag("device_join_min_rows", 1 << 15,
             "Combined row count above which joins route to the device kernel.")
 define_flag("agent_heartbeat_s", 5.0, "Agent heartbeat period (seconds).")
 define_flag("agent_expiry_s", 60.0, "Tracker agent expiry after silence.")
-define_flag(
-    "pallas_dense_fold", "auto",
-    "Pallas MXU dense-fold kernel routing, per aggregate of a dense "
-    "group-by: count/sum/mean/max/min over INT64, BOOLEAN and TIME64NS "
-    "take the exact limb kernel (up to INT_FOLD_MAX_GROUPS slots), over "
-    "FLOAT64 the f32 kernel (up to 2,048); everything else stays on XLA. "
-    "'auto' (TPU backend only), 'interpret' (any backend, interpreter "
-    "mode — tests), 'off' (every aggregate on XLA).",
-)
-define_flag(
-    "pallas_tdigest", "auto",
-    "Pallas t-digest histogram kernel routing: 'auto' (TPU backend, "
-    "small slot counts), 'interpret' (tests), 'off'.",
-)
 define_flag(
     "cpu_fold_threads", 0,
     "CPU-backend parallel window fold: thread count (0 = auto from cores, "

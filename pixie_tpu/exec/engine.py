@@ -955,12 +955,12 @@ class Engine:
         one dispatch per window."""
         from ..config import get_flag
 
-        import jax
-
         init_state, agg_step, _ = self._compile_steps(frag)
+        # Native fold, scan program or per-window loop: from the platform
+        # the fragment's routes were decided for (``FoldPlan.platform``).
         if (
             self.cpu_parallel_fold
-            and jax.default_backend() == "cpu"
+            and frag.plan.platform == "cpu"
             and frag.native_fold is not None
             and get_flag("cpu_fold_threads") != 1
         ):
@@ -985,7 +985,7 @@ class Engine:
         chunk_w = (
             get_flag("fold_scan_windows")
             if frag.update_all and self.scan_fold
-            and jax.default_backend() == "tpu"
+            and frag.plan.platform == "tpu"
             else 0
         )
         pend_cols, pend_lo, pend_hi = [], [], []

@@ -19,17 +19,16 @@ multi-device partial-agg merge uses.
 
 from __future__ import annotations
 
-import math
 import threading
-from collections import Counter
-from dataclasses import dataclass, field
-from typing import Optional
+from dataclasses import dataclass
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..config import get_flag
 from ..ops import hll
+from ..ops import routes as _routes
 from ..ops.groupby import (
     _to_bits,
     dense_group_ids,
@@ -44,7 +43,8 @@ from ..types.dtypes import DataType, device_dtypes, pad_values
 from ..types.relation import Relation
 from ..udf.registry import Registry
 from ..udf.udf import UDADef, apply_cast
-from .expr import BindError, BoundExpr, bind_expr
+from .expr import BindError, bind_expr
+from .fold_plan import INT_KEY_TYPES, FoldPlan, plan_fold
 from .plan import (
     AggOp,
     ColumnRef,
@@ -55,9 +55,6 @@ from .plan import (
     LookupJoinOp,
     MapOp,
 )
-
-# Integer-typed key columns that qualify for stats-derived dense domains.
-_INT_KEY_TYPES = (DataType.INT64, DataType.TIME64NS)
 
 
 @dataclass
@@ -111,19 +108,15 @@ class CompiledFragment:
     # Per-key value stride (1 except binned/affine integer keys, where
     # slot codes count stride steps: value = code * stride + offset).
     dense_strides: tuple = ()
-    # How the window fold runs, decided at compile time (agg only):
-    # ``pallas_int`` / ``pallas_f32`` (ops/pallas_groupby.py), ``xla``,
-    # ``mixed:<route>=<aggregates>,...`` when the AggOp's aggregates take
-    # different routes, or ``sorted_int`` (a keyed group-by of exact
-    # integer statistics: the rows ride the sort, ops/groupby.py
-    # ``sorted_group_fold``). Stamped on the fold programs'
-    # device.dispatch spans and on the fragment's /debug/queryz entry.
+    # Which fold the programs run, decided once at compile time (agg
+    # only; ``fold_plan.py``), and three of its fields under the names the
+    # fold programs' device.dispatch spans and the fragment's
+    # /debug/queryz entry carry: ``fold`` = the aggregates' route
+    # (``pallas_int`` / ``pallas_f32`` / ``xla`` / ``mixed:<route>=<n>,...``
+    # / ``sorted_int``), ``group`` = the layout (``dense`` / ``sorted`` /
+    # ``hashed``), ``slots`` = the capacity g.
+    plan: Optional[FoldPlan] = None
     fold: str = ""
-    # How rows find their group (agg only): ``dense`` (the packed key code
-    # is the slot), ``sorted`` or ``hashed`` (``ops/groupby.py``, a keyed
-    # state: merged by the sorted fold itself under ``sorted_int``, else
-    # by regroup + scatter), and the capacity g the programs were
-    # compiled at. Beside ``fold`` on the same spans and entry.
     group: str = ""
     slots: int = 0
     # A keyed fold's probe (agg only, None on a dense domain): jitted
@@ -201,8 +194,6 @@ def compile_fragment_cached(ops, input_relation, input_dicts, registry,
     the merge/limit XLA programs) once per run. Unhashable chains (not
     produced by the planner today) fall back to uncached compilation.
     """
-    from ..config import get_flag
-
     try:
         key = (
             _struct_key(tuple(ops)),
@@ -211,9 +202,7 @@ def compile_fragment_cached(ops, input_relation, input_dicts, registry,
                 (n, d.content_key()) for n, d in input_dicts.items()
             )),
             id(registry),
-            get_flag("groupby_impl"),
-            get_flag("pallas_dense_fold"),
-            get_flag("pallas_tdigest"),
+            _routes.routes_platform(),
             get_flag("dense_domain_limit") if allow_dense else -1,
             get_flag("int_dense_domain_limit") if allow_dense else -1,
             _stats_cache_key(ops, col_stats),
@@ -540,7 +529,7 @@ def unpack_dense_slots(iota, doms, col_types, xp, offsets=None, strides=None):
         code = (iota // pack) % dom
         if dt == DataType.BOOLEAN:
             planes.append(code.astype(np.bool_))
-        elif dt in _INT_KEY_TYPES:
+        elif dt in INT_KEY_TYPES:
             planes.append((code * st + off).astype(np.int64))
         else:  # STRING: last sub-slot decodes back to NULL_ID (-1)
             planes.append(
@@ -624,345 +613,112 @@ def _pure_select_map(pre):
 _SKETCH_P = 12
 
 
-def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
-                 allow_dense=True, col_stats=None, pre_ops=()):
-    g = agg.max_groups
-    for c in agg.group_cols:
-        if not rel1.has_column(c):
-            raise BindError(f"group column {c!r} not in {rel1}")
+class _Fold(NamedTuple):
+    """What differs between the three folds (``fold_plan.py``), built by
+    ``_dense_fold`` / ``_sorted_fold`` / ``_id_fold``; they share no state
+    format beyond the {keys, valid, carries, overflow} pytree."""
 
-    # Static dense key domain: when every group column's device code has a
-    # statically-known small domain, the PACKED CODE is the group id —
-    # no per-window sort or hash, and state merges are slot-aligned
-    # (regroup-free), the shape XLA/TPU executes best. Carnot has no
-    # analog (its RowTuple hash map is domain-oblivious,
-    # ``src/carnot/exec/agg_node.h:66``); this is the TPU-first design.
-    # Integer keys qualify through the table store's append-time min/max
-    # stats (a bincount-class scatter replaces hash probing); they get a
-    # larger domain budget because a single int column can't suffer the
-    # multi-key packing blowup the base limit protects against.
-    dense_domains = None
-    dense_offsets = None
-    dense_strides = None
-    doms = None
-    if allow_dense and agg.group_cols:
-        doms = _static_key_domains(
-            rel1, dicts1, list(agg.group_cols), col_stats
-        )
-        if doms is not None:
-            total = 1
-            for d, _off, _st in doms:
-                total *= d
-            has_int = any(off or rel1.col_type(c) in _INT_KEY_TYPES
-                          for (_d, off, _st), c in zip(doms, agg.group_cols))
-            # The larger int budget is justified only for a SINGLE int
-            # key (no multi-key packing blowup); mixed/multi-key domains
-            # stay under the base limit.
-            limit_slots = (
-                get_flag("int_dense_domain_limit")
-                if has_int and len(agg.group_cols) == 1
-                else get_flag("dense_domain_limit")
-            )
-            if total <= limit_slots:
-                dense_domains = tuple(d for d, _off, _st in doms)
-                dense_offsets = tuple(off for _d, off, _st in doms)
-                dense_strides = tuple(st for _d, _off, st in doms)
-                g = total
+    init_keys: object  # () -> ``state["keys"]`` of an empty state
+    window: object  # (cols, valid), pre-stage applied -> the window's state
+    merge: object  # (state_a, state_b) -> merged state
+    key_planes: object  # state -> [g] key planes, ``key_plane_index`` order
 
-    # Bind aggregate input expressions and resolve UDAs.
-    aggs_bound = []  # (AggExpr, UDADef, [BoundExpr], [cast pairs])
-    for ae in agg.aggs:
-        arg_bound = [bind_expr(a, rel1, dicts1, registry) for a in ae.args]
-        uda: UDADef = registry.get_uda(ae.uda_name, [b.dtype for b in arg_bound])
-        casts = list(zip([b.dtype for b in arg_bound], uda.arg_types))
-        aggs_bound.append((ae, uda, arg_bound, casts))
 
-    group_cols = list(agg.group_cols)
-    key_plane_index = []  # (col, plane_i) per key plane
-    for c in group_cols:
-        for i in range(len(device_dtypes(rel1.col_type(c)))):
-            key_plane_index.append((c, i))
+def _dense_slot_ids(plan, rel1, key_plane_index, cols, valid):
+    """Packed key code per row + out-of-domain flag.
 
-    def init_state():
-        if dense_domains is not None:
-            keys = ()  # implicit: slot index IS the packed key
-        else:
-            keys = tuple(
-                jnp.full(
-                    g,
-                    pad_values(rel1.col_type(c))[i],
-                    dtype=device_dtypes(rel1.col_type(c))[i],
-                )
-                for c, i in key_plane_index
-            )
-        carries = {ae.out_name: uda.init(g) for ae, uda, _, _ in aggs_bound}
-        return {
-            "keys": keys,
-            "valid": jnp.zeros(g, dtype=jnp.bool_),
-            "carries": carries,
-            "overflow": jnp.zeros((), dtype=jnp.bool_),
-        }
-
-    def dense_slot_ids(cols, valid):
-        """Packed key code per row + out-of-domain flag.
-
-        slot = sum(code_i * stride_i); NULL_ID (-1) string codes land in
-        each column's last sub-slot and masked rows in the trash slot g.
-        Stats-derived integer codes are offset to zero base; a row whose
-        value escaped the compile-time [min, max] (an append racing the
-        query) goes to the trash slot and raises ``oob`` so the engine's
-        rebucket retry recompiles against fresh stats.
-        """
-        slot = None
-        oob = None
-        for (c, _i), dom, off, st in zip(
-            key_plane_index, dense_domains, dense_offsets, dense_strides
-        ):
-            p = cols[c][0]
-            if rel1.col_type(c) in _INT_KEY_TYPES:
-                raw = p - off
-                if st > 1:
-                    # Strided domain (binned time keys): the slot is the
-                    # step index; off-grid values (appends racing the
-                    # stats) are out-of-domain, not silently misbinned.
-                    out = (raw < 0) | (raw >= dom * st) | (raw % st != 0)
-                    raw = raw // st
-                else:
-                    out = (raw < 0) | (raw >= dom)
-                oob = out if oob is None else (oob | out)
-                code = jnp.clip(raw, 0, dom - 1).astype(jnp.int32)
+    slot = sum(code_i * stride_i); NULL_ID (-1) string codes land in
+    each column's last sub-slot and masked rows in the trash slot g.
+    Stats-derived integer codes are offset to zero base; a row whose
+    value escaped the compile-time [min, max] (an append racing the
+    query) goes to the trash slot and raises ``oob`` so the engine's
+    rebucket retry recompiles against fresh stats.
+    """
+    slot = None
+    oob = None
+    for (c, _i), dom, off, st in zip(
+        key_plane_index, plan.domains, plan.offsets, plan.strides
+    ):
+        p = cols[c][0]
+        if rel1.col_type(c) in INT_KEY_TYPES:
+            raw = p - off
+            if st > 1:
+                # Strided domain (binned time keys): the slot is the
+                # step index; off-grid values (appends racing the
+                # stats) are out-of-domain, not silently misbinned.
+                out = (raw < 0) | (raw >= dom * st) | (raw % st != 0)
+                raw = raw // st
             else:
-                code = _dict_code(p, dom)
-            slot = code if slot is None else slot * jnp.int32(dom) + code
-        if oob is None:
-            oob_any = jnp.zeros((), dtype=jnp.bool_)
-            keep = valid
+                out = (raw < 0) | (raw >= dom)
+            oob = out if oob is None else (oob | out)
+            code = jnp.clip(raw, 0, dom - 1).astype(jnp.int32)
         else:
-            oob = oob & valid
-            oob_any = jnp.any(oob)
-            keep = valid & ~oob
-        # ONE select to the trash slot (several chained wheres over [n]
-        # i64 planes cost real memory bandwidth at window scale).
-        return jnp.where(keep, slot, g).astype(jnp.int32), oob_any
+            code = _dict_code(p, dom)
+        slot = code if slot is None else slot * jnp.int32(dom) + code
+    if oob is None:
+        oob_any = jnp.zeros((), dtype=jnp.bool_)
+        keep = valid
+    else:
+        oob = oob & valid
+        oob_any = jnp.any(oob)
+        keep = valid & ~oob
+    # ONE select to the trash slot (several chained wheres over [n]
+    # i64 planes cost real memory bandwidth at window scale).
+    return jnp.where(keep, slot, plan.slots).astype(jnp.int32), oob_any
 
-    def dense_key_planes():
-        """Reconstruct the [g] key planes from the slot index (traced)."""
-        return unpack_dense_slots(
-            jnp.arange(g, dtype=jnp.int64),
-            dense_domains,
-            [rel1.col_type(c) for c, _i in key_plane_index],
-            jnp,
-            offsets=dense_offsets,
-            strides=dense_strides,
-        )
 
-    # NOTE: merge_states materializes neutral carries by calling uda.init(g)
-    # DURING tracing (never precompute them eagerly here): no concrete
-    # jax Array may be captured as a jit-closure constant.
+def _uda_window_carries(aggs_bound, g, gids, cols, valid, carries_w):
+    """``uda.update`` of a fresh carry, for every aggregate that
+    ``carries_w`` does not hold yet (the XLA route)."""
+    for ae, uda, arg_bound, casts in aggs_bound:
+        if ae.out_name in carries_w:
+            continue
+        args = [
+            apply_cast(b.fn(cols), have, want)
+            for b, (have, want) in zip(arg_bound, casts)
+        ]
+        args = [jnp.broadcast_to(a, valid.shape) for a in args]
+        carries_w[ae.out_name] = uda.update(uda.init(g), gids, valid, *args)
+    return carries_w
 
-    # Per-window group ids for NON-dense keys: backend-matched by
-    # default — XLA's TPU sort is fast while its CPU sort is ~90x slower
-    # than scatter, so 'auto' sorts on TPU and hashes on CPU. The small
-    # [2G] regroup merges below always sort.
-    impl = get_flag("groupby_impl")
-    if impl == "auto":
-        impl = "sort" if jax.default_backend() == "tpu" else "hash"
-    window_group_ids = (
-        dense_group_ids_hash if impl == "hash" else dense_group_ids
-    )
 
-    # Pallas dense fold: on a dense domain count/sum/mean/max/min route
-    # PER AGGREGATE through the hand-scheduled kernels of
-    # ops/pallas_groupby.py — one-hot contractions with VMEM-resident
-    # [G] accumulators instead of per-UDA sorts, gathers and scatters.
-    # INT64 / BOOLEAN / TIME64NS arguments take the exact integer kernel
-    # (limb contractions, carries stay i64), FLOAT64 arguments the f32
-    # kernel; anything else (``quantiles``...) keeps its ``uda.update``
-    # and vetoes nothing. Decided here from what the code observes: the
-    # domain, the argument types, G against each kernel's limit, the
-    # backend; the row block is the window's to decide (window_state).
-    # 'auto' engages on the TPU backend; 'interpret' runs the kernels in
-    # interpreter mode on any backend (the equivalence tests); 'off'
-    # disables.
-    _pallas_mode = get_flag("pallas_dense_fold")
-    _pallas_on = (
-        dense_domains is not None
-        and _pallas_mode in ("auto", "interpret")
-        and (_pallas_mode == "interpret" or jax.default_backend() == "tpu")
-    )
+def _dense_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
+    """Static dense key domain: when every group column's device code has
+    a statically-known small domain, the PACKED CODE is the group id —
+    no per-window sort or hash, and state merges are slot-aligned
+    (regroup-free), the shape XLA/TPU executes best. Carnot has no
+    analog (its RowTuple hash map is domain-oblivious,
+    ``src/carnot/exec/agg_node.h:66``); this is the TPU-first design.
+    Integer keys qualify through the table store's append-time min/max
+    stats (a bincount-class scatter replaces hash probing); they get a
+    larger domain budget because a single int column can't suffer the
+    multi-key packing blowup the base limit protects against.
+
+    count/sum/mean/max/min route PER AGGREGATE (``plan.routes``) through
+    the hand-scheduled kernels of ops/pallas_groupby.py — one-hot
+    contractions with VMEM-resident [G] accumulators instead of per-UDA
+    sorts, gathers and scatters. INT64 / BOOLEAN / TIME64NS arguments
+    take the exact integer kernel (limb contractions, carries stay i64),
+    FLOAT64 arguments the f32 kernel; anything else (``quantiles``...)
+    keeps its ``uda.update`` and vetoes nothing. The row block is the
+    window's to decide."""
+    g = plan.slots
+    routes = dict(plan.routes)
+    count_route = plan.count_route
     g_pad = -(-g // 128) * 128  # f32 kernel's lane alignment
-    _f32_ok = _pallas_on and g <= 2048  # its [chunk, G] one-hot must fit VMEM
-    _int_ok = False
-    if _pallas_on:
+    g_int = _routes.int_fold_groups(g)  # integer kernel's lanes and group blocks
+    kernels = (set(routes.values()) | {count_route}) - {"xla"}
+    if kernels:
+        # Imported only here: pulling in Pallas costs a second, which a
+        # process that never runs the kernels must not pay mid-query.
         from ..ops.pallas_groupby import (
-            INT_FOLD_MAX_GROUPS,
             dense_group_fold,
             dense_group_fold_int,
             fold_row_chunk,
             int_fold_blocks,
-            int_fold_groups,
         )
 
-        g_int = int_fold_groups(g)  # integer kernel's lanes and group blocks
-        # Above the measured cross-over the one-hot (rows x G) loses to
-        # the sort: those domains keep the XLA fold.
-        _int_ok = g_int <= INT_FOLD_MAX_GROUPS
-
-    def _int_agg(ae, arg_bound, casts):
-        """An exact integer statistic: sum / mean / max / min of one
-        INT64 / TIME64NS argument, sum / mean of a BOOLEAN one (the set
-        both integer folds take: the dense kernel and the keyed sort)."""
-        if ae.uda_name in ("sum", "mean", "max", "min") and len(arg_bound) == 1:
-            want = casts[0][1]
-            return want in (DataType.INT64, DataType.TIME64NS) or (
-                want == DataType.BOOLEAN and ae.uda_name in ("sum", "mean")
-            )
-        return False
-
-    def _fold_route(ae, arg_bound, casts):
-        if _int_agg(ae, arg_bound, casts):
-            return "pallas_int" if _int_ok else "xla"
-        if (ae.uda_name in ("sum", "mean", "max", "min") and len(arg_bound) == 1
-                and casts[0][1] == DataType.FLOAT64):
-            return "pallas_f32" if _f32_ok else "xla"
-        return "xla"
-
-    routes = {
-        ae.out_name: _fold_route(ae, arg_bound, casts)
-        for ae, _uda, arg_bound, casts in aggs_bound
-        if ae.uda_name != "count"
-    }
-    # A count reads no argument: it rides the kernel that runs anyway
-    # (the integer one's count is i32-exact, so it is preferred).
-    _kernels = set(routes.values()) - {"xla"}
-    _count_route = (
-        "pallas_int" if _int_ok and _kernels != {"pallas_f32"}
-        else "pallas_f32" if "pallas_f32" in _kernels
-        else "xla"
-    )
-    for ae, _uda, _b, _c in aggs_bound:
-        if ae.uda_name == "count":
-            routes[ae.out_name] = _count_route
-    # What the fold does, for the device.dispatch span and /debug/queryz.
-    _tally = Counter(routes.values())
-    fold = (
-        next(iter(_tally), "xla") if len(_tally) <= 1
-        else "mixed:" + ",".join(
-            f"{r}={_tally[r]}"
-            for r in ("pallas_int", "pallas_f32", "xla") if r in _tally
-        )
-    )
-
-    # Keyed integer fold: a NON-dense key whose aggregates are all in the
-    # integer set folds by sorting the rows themselves, keys, values and
-    # carries riding one ``lax.sort`` (ops/groupby.py sorted_group_fold):
-    # no argsort, no window-long gather or scatter, and the same function
-    # is the window fold and the merge of two keyed states. Chosen from
-    # what the code observes: the key has no dense domain, every
-    # aggregate is exact-integer, the sort impl (the TPU's under 'auto').
-    # Anything else keeps group ids in row order (``window_group_ids`` +
-    # ``uda.update``), which a ``quantiles`` or a FLOAT64 sum needs.
-    _sorted_int = (
-        dense_domains is None and bool(group_cols) and impl == "sort"
-        and all(
-            ae.uda_name == "count" or _int_agg(ae, arg_bound, casts)
-            for ae, _uda, arg_bound, casts in aggs_bound
-        )
-    )
-    # Key planes packed into ONE word where the columns' domains show
-    # they fit (dictionary ids and booleans: exact, as the dense route
-    # trusts them; 33 x 65,537 codes are 22 bits), the top bit left for
-    # "not valid". The Kelvin's fragment (allow_dense=False) merges ids
-    # remapped into a dictionary it was not compiled against: it packs
-    # nothing and sorts the planes as they are.
-    pack_doms = None
-    if _sorted_int:
-        fold = "sorted_int"
-        if (
-            doms is not None
-            and all(rel1.col_type(c) not in _INT_KEY_TYPES for c in group_cols)
-            and math.prod(d for d, _off, _st in doms) < (1 << 31) - 1
-        ):
-            pack_doms = tuple(d for d, _off, _st in doms)
-    key_dtypes = [
-        device_dtypes(rel1.col_type(c))[i] for c, i in key_plane_index
-    ]
-    # Unpacked, a leading dictionary id still spares the flag operand:
-    # ids are >= NULL_ID (-1), so id + 1 never reads 0xFFFFFFFF.
-    _lead_id = (
-        _sorted_int and pack_doms is None
-        and rel1.col_type(group_cols[0]) == DataType.STRING
-    )
-
-    def _key_words(planes):
-        """[n] key planes (``state['keys']`` order) -> u32 sort words."""
-        if pack_doms is None:
-            words = [w for p in planes for w in split_u32(_to_bits(p))]
-            if _lead_id:
-                words[0] = words[0] + jnp.uint32(1)
-            return words
-        code = None
-        for p, dom in zip(planes, pack_doms):
-            c = _dict_code(p, dom)
-            code = c if code is None else code * jnp.int32(dom) + c
-        return [code.astype(jnp.uint32)]
-
-    def _key_planes(words):
-        """Inverse of ``_key_words`` on the [g] slots of a folded state."""
-        if pack_doms is not None:
-            return unpack_dense_slots(
-                words[0].astype(jnp.int32), pack_doms,
-                [rel1.col_type(c) for c, _i in key_plane_index], jnp,
-            )
-        if _lead_id:
-            words = [words[0] - jnp.uint32(1)] + list(words[1:])
-        planes, at = [], 0
-        for dt in key_dtypes:
-            k = 2 if jnp.dtype(dt).itemsize == 8 else 1
-            planes.append(join_u32(words[at:at + k], dt))
-            at += k
-        return planes
-
-    def _sorted_state(key_planes, valid, leaves):
-        """``sorted_group_fold`` over N partial groups. ``leaves`` maps an
-        aggregate's out_name to its statistic planes in carry order, each
-        ("sum" | "max" | "min" | "rows", int64[N] or None): the new [g]
-        state. ``rows`` is a window's count (every valid row counts one)."""
-        sums, maxes = [], []
-        for kinds in leaves.values():
-            for kind, v in kinds:
-                if kind == "sum" and not any(v is s for s in sums):
-                    sums.append(v)
-                elif kind in ("max", "min"):
-                    v = v if kind == "max" else ~v
-                    maxes.append(v)
-        with jax.named_scope("sorted_fold"):
-            words, valid_g, rows, sums_g, maxes_g, n_groups = sorted_group_fold(
-                _key_words(key_planes), valid, sums, maxes, g,
-                folded_flag=pack_doms is not None or _lead_id,
-            )
-        carries = {}
-        n_max = 0
-        for ae, uda, _b, _c in aggs_bound:
-            init = uda.init(g)
-            init_leaves = init if isinstance(init, tuple) else (init,)
-            out = []
-            for (kind, v), init_leaf in zip(leaves[ae.out_name], init_leaves):
-                if kind == "rows":
-                    leaf = rows
-                elif kind == "sum":
-                    leaf = sums_g[next(i for i, s in enumerate(sums) if s is v)]
-                else:
-                    leaf = maxes_g[n_max] if kind == "max" else ~maxes_g[n_max]
-                    n_max += 1
-                out.append(leaf.astype(init_leaf.dtype))
-            carries[ae.out_name] = tuple(out) if isinstance(init, tuple) else out[0]
-        return {
-            "keys": tuple(_key_planes(words)),
-            "valid": valid_g,
-            "carries": carries,
-            "overflow": n_groups > g,
-        }
+        interpret = _routes.kernels_interpreted()
 
     def _pallas_window_carries(gids, cols, valid):
         """Carries of the aggregates the kernels take on this window, as
@@ -970,18 +726,17 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         per-slot row count (None when no kernel ran: a window with no row
         block the chip's tiling accepts stays whole on the XLA fold)."""
         n = valid.shape[0]
-        interpret = _pallas_mode == "interpret"
         by_route = {"pallas_int": [], "pallas_f32": []}
         for ab in aggs_bound:
             if ab[0].uda_name != "count" and routes[ab[0].out_name] != "xla":
                 by_route[routes[ab[0].out_name]].append(ab)
         f32_chunk = (
             fold_row_chunk(n, g_pad)
-            if by_route["pallas_f32"] or _count_route == "pallas_f32" else None
+            if by_route["pallas_f32"] or count_route == "pallas_f32" else None
         )
         int_blocks = (
             int_fold_blocks(n, g_int)
-            if by_route["pallas_int"] or _count_route == "pallas_int" else None
+            if by_route["pallas_int"] or count_route == "pallas_int" else None
         )
 
         def arg_plane(ae, arg_bound, casts):
@@ -1067,66 +822,23 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                     carries_w[ae.out_name] = cnt.astype(uda.init(g).dtype)
         return carries_w, cnt
 
-    def window_state(cols, valid):
-        """Fold one window of rows into a fresh [G]-slot group state.
-
-        ``valid`` is a bool[n] mask or a (lo, hi) row-range scalar pair
-        (the device-resident-window form)."""
-        valid = _range_valid(cols, valid)
-        cols, valid = apply_pre(cols, valid)
-        if _sorted_int:
-            planes = {}  # one plane a distinct argument expression
-            leaves = {}
-            for ae, _uda, arg_bound, casts in aggs_bound:
-                if ae.uda_name == "count":
-                    leaves[ae.out_name] = (("rows", None),)
-                    continue
-                fkey = (_struct_key(ae.args), casts[0])
-                if fkey not in planes:
-                    a = apply_cast(arg_bound[0].fn(cols), *casts[0])
-                    planes[fkey] = jnp.broadcast_to(
-                        a, valid.shape).astype(jnp.int64)
-                v = planes[fkey]
-                leaves[ae.out_name] = (
-                    (("sum", v), ("rows", None)) if ae.uda_name == "mean"
-                    else ((ae.uda_name, v),)
-                )
-            return _sorted_state(
-                [cols[c][i] for c, i in key_plane_index], valid, leaves
-            )
+    def window(cols, valid):
+        gids, oob = _dense_slot_ids(plan, rel1, key_plane_index, cols, valid)
+        # Dense slots cannot overflow by count; stats-derived integer
+        # domains overflow only when a row's key escapes the
+        # compile-time bounds (oob flags it for the rebucket retry).
+        n_w = jnp.where(oob, g + 1, 0).astype(jnp.int32)
         carries_w = {}
-        if dense_domains is not None:
-            gids, oob = dense_slot_ids(cols, valid)
-            keys_w = ()
-            valid_w = None  # filled below (count carries give it free)
-            # Dense slots cannot overflow by count; stats-derived integer
-            # domains overflow only when a row's key escapes the
-            # compile-time bounds (oob flags it for the rebucket retry).
-            n_w = jnp.where(oob, g + 1, 0).astype(jnp.int32)
-            if _f32_ok or _int_ok:
-                carries_w, cnt_w = _pallas_window_carries(gids, cols, valid)
-                if cnt_w is not None:
-                    valid_w = cnt_w > 0
-        else:
-            key_planes = [cols[c][i] for c, i in key_plane_index]
-            with jax.named_scope("group_ids"):
-                gids, keys_w, valid_w, n_w = window_group_ids(
-                    key_planes, valid, g
-                )
-
-        for ae, uda, arg_bound, casts in aggs_bound:
-            if ae.out_name in carries_w:
-                continue
-            args = [
-                apply_cast(b.fn(cols), have, want)
-                for b, (have, want) in zip(arg_bound, casts)
-            ]
-            args = [jnp.broadcast_to(a, valid.shape) for a in args]
-            carries_w[ae.out_name] = uda.update(uda.init(g), gids, valid, *args)
+        valid_w = None  # a kernel's count, or a count carry, gives it free
+        if kernels:
+            carries_w, cnt_w = _pallas_window_carries(gids, cols, valid)
+            if cnt_w is not None:
+                valid_w = cnt_w > 0
+        _uda_window_carries(aggs_bound, g, gids, cols, valid, carries_w)
         if valid_w is None:
-            # Dense mode: a count aggregate's fresh carry already says
-            # which slots saw rows — reuse it instead of paying a third
-            # scatter pass over the window.
+            # A count aggregate's fresh carry already says which slots
+            # saw rows — reuse it instead of paying a third scatter pass
+            # over the window.
             cnt_name = next(
                 (ae.out_name for ae, uda, _b, _c in aggs_bound
                  if ae.uda_name == "count"),
@@ -1139,65 +851,224 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                     jnp.zeros(g + 1, dtype=jnp.bool_).at[gids].set(True)[:g]
                 )
         return {
-            "keys": tuple(keys_w),
+            "keys": (),  # implicit: slot index IS the packed key
             "valid": valid_w,
             "carries": carries_w,
             "overflow": n_w > g,
         }
 
-    def merge_states(sa, sb):
-        """Associative merge of two group states (slot orders may differ).
+    def merge(sa, sb):
+        """Slot-for-slot — no regroup sort at all."""
+        carries = {
+            ae.out_name: uda.merge(
+                sa["carries"][ae.out_name], sb["carries"][ae.out_name]
+            )
+            for ae, uda, _, _ in aggs_bound
+        }
+        return {
+            "keys": (),
+            "valid": sa["valid"] | sb["valid"],
+            "carries": carries,
+            "overflow": sa["overflow"] | sb["overflow"],
+        }
 
-        This single function is both the window accumulator and the
-        distributed finalize: per-device partial states gathered over the
-        mesh merge through it, replacing Carnot's UDA Serialize -> GRPC ->
-        finalize-agg pipeline (``planner/distributed/splitter/partial_op_mgr``).
-        Dense-domain states merge slot-for-slot — no regroup sort at all.
-        """
-        if dense_domains is not None:
-            carries = {
-                ae.out_name: uda.merge(
-                    sa["carries"][ae.out_name], sb["carries"][ae.out_name]
-                )
-                for ae, uda, _, _ in aggs_bound
-            }
-            return {
-                "keys": (),
-                "valid": sa["valid"] | sb["valid"],
-                "carries": carries,
-                "overflow": sa["overflow"] | sb["overflow"],
-            }
-        if _sorted_int:
-            # Slots are partial groups like rows are: one concatenation,
-            # one fold. Neither side need be key-sorted (the Kelvin's
-            # arrive remapped into its canonical dictionary).
-            def cat(a, b):
-                return jnp.concatenate([jnp.asarray(a), jnp.asarray(b)])
+    def key_planes(state):
+        """Reconstruct the [g] key planes from the slot index (traced)."""
+        return unpack_dense_slots(
+            jnp.arange(g, dtype=jnp.int64),
+            plan.domains,
+            [rel1.col_type(c) for c, _i in key_plane_index],
+            jnp,
+            offsets=plan.offsets,
+            strides=plan.strides,
+        )
 
-            leaves = {}
-            for ae, uda, _b, _c in aggs_bound:
-                ca, cb = sa["carries"][ae.out_name], sb["carries"][ae.out_name]
-                if ae.uda_name == "mean":
-                    leaves[ae.out_name] = (
-                        ("sum", cat(ca[0], cb[0])), ("sum", cat(ca[1], cb[1])),
-                    )
+    return _Fold(lambda: (), window, merge, key_planes)
+
+
+def _keyed_init_keys(g, rel1, key_plane_index):
+    """An empty keyed state's key planes: [g] of each plane's pad value."""
+    return lambda: tuple(
+        jnp.full(
+            g,
+            pad_values(rel1.col_type(c))[i],
+            dtype=device_dtypes(rel1.col_type(c))[i],
+        )
+        for c, i in key_plane_index
+    )
+
+
+def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
+    """Keyed integer fold: a NON-dense key whose aggregates are all in the
+    integer set folds by sorting the rows themselves, keys, values and
+    carries riding one ``lax.sort`` (ops/groupby.py sorted_group_fold):
+    no argsort, no window-long gather or scatter, and the same function
+    is the window fold and the merge of two keyed states."""
+    g = plan.slots
+    pack_doms = plan.pack_doms
+    key_dtypes = [
+        device_dtypes(rel1.col_type(c))[i] for c, i in key_plane_index
+    ]
+
+    def _key_words(planes):
+        """[n] key planes (``state['keys']`` order) -> u32 sort words."""
+        if pack_doms is None:
+            words = [w for p in planes for w in split_u32(_to_bits(p))]
+            if plan.lead_id:
+                words[0] = words[0] + jnp.uint32(1)
+            return words
+        code = None
+        for p, dom in zip(planes, pack_doms):
+            c = _dict_code(p, dom)
+            code = c if code is None else code * jnp.int32(dom) + c
+        return [code.astype(jnp.uint32)]
+
+    def _key_planes(words):
+        """Inverse of ``_key_words`` on the [g] slots of a folded state."""
+        if pack_doms is not None:
+            return unpack_dense_slots(
+                words[0].astype(jnp.int32), pack_doms,
+                [rel1.col_type(c) for c, _i in key_plane_index], jnp,
+            )
+        if plan.lead_id:
+            words = [words[0] - jnp.uint32(1)] + list(words[1:])
+        planes, at = [], 0
+        for dt in key_dtypes:
+            k = 2 if jnp.dtype(dt).itemsize == 8 else 1
+            planes.append(join_u32(words[at:at + k], dt))
+            at += k
+        return planes
+
+    def _sorted_state(key_planes, valid, leaves):
+        """``sorted_group_fold`` over N partial groups. ``leaves`` maps an
+        aggregate's out_name to its statistic planes in carry order, each
+        ("sum" | "max" | "min" | "rows", int64[N] or None): the new [g]
+        state. ``rows`` is a window's count (every valid row counts one)."""
+        sums, maxes = [], []
+        for kinds in leaves.values():
+            for kind, v in kinds:
+                if kind == "sum" and not any(v is s for s in sums):
+                    sums.append(v)
+                elif kind in ("max", "min"):
+                    v = v if kind == "max" else ~v
+                    maxes.append(v)
+        with jax.named_scope("sorted_fold"):
+            words, valid_g, rows, sums_g, maxes_g, n_groups = sorted_group_fold(
+                _key_words(key_planes), valid, sums, maxes, g,
+                folded_flag=pack_doms is not None or plan.lead_id,
+            )
+        carries = {}
+        n_max = 0
+        for ae, uda, _b, _c in aggs_bound:
+            init = uda.init(g)
+            init_leaves = init if isinstance(init, tuple) else (init,)
+            out = []
+            for (kind, v), init_leaf in zip(leaves[ae.out_name], init_leaves):
+                if kind == "rows":
+                    leaf = rows
+                elif kind == "sum":
+                    leaf = sums_g[next(i for i, s in enumerate(sums) if s is v)]
                 else:
-                    kind = ae.uda_name if ae.uda_name in ("max", "min") else "sum"
-                    leaves[ae.out_name] = ((kind, cat(ca, cb)),)
-            merged = _sorted_state(
-                [cat(a, b) for a, b in zip(sa["keys"], sb["keys"])],
-                cat(sa["valid"], sb["valid"]), leaves,
+                    leaf = maxes_g[n_max] if kind == "max" else ~maxes_g[n_max]
+                    n_max += 1
+                out.append(leaf.astype(init_leaf.dtype))
+            carries[ae.out_name] = tuple(out) if isinstance(init, tuple) else out[0]
+        return {
+            "keys": tuple(_key_planes(words)),
+            "valid": valid_g,
+            "carries": carries,
+            "overflow": n_groups > g,
+        }
+
+    def window(cols, valid):
+        planes = {}  # one plane a distinct argument expression
+        leaves = {}
+        for ae, _uda, arg_bound, casts in aggs_bound:
+            if ae.uda_name == "count":
+                leaves[ae.out_name] = (("rows", None),)
+                continue
+            fkey = (_struct_key(ae.args), casts[0])
+            if fkey not in planes:
+                a = apply_cast(arg_bound[0].fn(cols), *casts[0])
+                planes[fkey] = jnp.broadcast_to(
+                    a, valid.shape).astype(jnp.int64)
+            v = planes[fkey]
+            leaves[ae.out_name] = (
+                (("sum", v), ("rows", None)) if ae.uda_name == "mean"
+                else ((ae.uda_name, v),)
             )
-            merged["overflow"] = (
-                merged["overflow"] | sa["overflow"] | sb["overflow"]
+        return _sorted_state(
+            [cols[c][i] for c, i in key_plane_index], valid, leaves
+        )
+
+    def merge(sa, sb):
+        # Slots are partial groups like rows are: one concatenation,
+        # one fold. Neither side need be key-sorted (the Kelvin's
+        # arrive remapped into its canonical dictionary).
+        def cat(a, b):
+            return jnp.concatenate([jnp.asarray(a), jnp.asarray(b)])
+
+        leaves = {}
+        for ae, uda, _b, _c in aggs_bound:
+            ca, cb = sa["carries"][ae.out_name], sb["carries"][ae.out_name]
+            if ae.uda_name == "mean":
+                leaves[ae.out_name] = (
+                    ("sum", cat(ca[0], cb[0])), ("sum", cat(ca[1], cb[1])),
+                )
+            else:
+                kind = ae.uda_name if ae.uda_name in ("max", "min") else "sum"
+                leaves[ae.out_name] = ((kind, cat(ca, cb)),)
+        merged = _sorted_state(
+            [cat(a, b) for a, b in zip(sa["keys"], sb["keys"])],
+            cat(sa["valid"], sb["valid"]), leaves,
+        )
+        merged["overflow"] = (
+            merged["overflow"] | sa["overflow"] | sb["overflow"]
+        )
+        return merged
+
+    return _Fold(
+        _keyed_init_keys(g, rel1, key_plane_index), window, merge,
+        lambda state: state["keys"],
+    )
+
+
+def _id_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
+    """Keyed fold with group ids in row order (what a ``quantiles`` or a
+    FLOAT64 sum needs): per-window ids by the platform's own algorithm —
+    XLA's TPU sort is fast while its CPU sort is ~90x slower than
+    scatter, so the TPU sorts and the CPU hashes — then ``uda.update``.
+    The small [2G] regroup merges always sort."""
+    g = plan.slots
+    window_group_ids = (
+        dense_group_ids_hash if plan.layout == "hashed" else dense_group_ids
+    )
+
+    def window(cols, valid):
+        key_planes = [cols[c][i] for c, i in key_plane_index]
+        with jax.named_scope("group_ids"):
+            gids, keys_w, valid_w, n_w = window_group_ids(
+                key_planes, valid, g
             )
-            return merged
+        return {
+            "keys": tuple(keys_w),
+            "valid": valid_w,
+            "carries": _uda_window_carries(
+                aggs_bound, g, gids, cols, valid, {}
+            ),
+            "overflow": n_w > g,
+        }
+
+    def merge(sa, sb):
         with jax.named_scope("regroup"):
             ids_a, ids_b, m_keys, m_valid, n_tot = regroup_pair(
                 sa["keys"], sa["valid"], sb["keys"], sb["valid"], g
             )
         carries = {}
         for ae, uda, _, _ in aggs_bound:
+            # Neutral carries materialize DURING tracing (never precompute
+            # them eagerly): no concrete jax Array may be captured as a
+            # jit-closure constant.
             neutral = uda.init(g)
             with jax.named_scope("scatter_carry"):
                 ca = scatter_carry(
@@ -1214,6 +1085,84 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
             "carries": carries,
             "overflow": overflow,
         }
+
+    return _Fold(
+        _keyed_init_keys(g, rel1, key_plane_index), window, merge,
+        lambda state: state["keys"],
+    )
+
+
+def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
+                 allow_dense=True, col_stats=None, pre_ops=()):
+    for c in agg.group_cols:
+        if not rel1.has_column(c):
+            raise BindError(f"group column {c!r} not in {rel1}")
+
+    # Bind aggregate input expressions and resolve UDAs.
+    aggs_bound = []  # (AggExpr, UDADef, [BoundExpr], [cast pairs])
+    for ae in agg.aggs:
+        arg_bound = [bind_expr(a, rel1, dicts1, registry) for a in ae.args]
+        uda: UDADef = registry.get_uda(ae.uda_name, [b.dtype for b in arg_bound])
+        casts = list(zip([b.dtype for b in arg_bound], uda.arg_types))
+        aggs_bound.append((ae, uda, arg_bound, casts))
+
+    group_cols = list(agg.group_cols)
+    key_plane_index = []  # (col, plane_i) per key plane
+    for c in group_cols:
+        for i in range(len(device_dtypes(rel1.col_type(c)))):
+            key_plane_index.append((c, i))
+
+    # The one decision (fold_plan.py), and the one fold it names.
+    plan = plan_fold(
+        tuple((c, rel1.col_type(c)) for c in group_cols),
+        _static_key_domains(rel1, dicts1, group_cols, col_stats),
+        tuple(
+            (ae.out_name, ae.uda_name, tuple(want for _have, want in casts))
+            for ae, _uda, _b, casts in aggs_bound
+        ),
+        max_groups=agg.max_groups,
+        allow_dense=allow_dense,
+        dense_limit=get_flag("dense_domain_limit"),
+        int_dense_limit=get_flag("int_dense_domain_limit"),
+        platform=_routes.routes_platform(),
+    )
+    g = plan.slots
+    if plan.layout == "dense":
+        build = _dense_fold
+    elif plan.payload_sort:
+        build = _sorted_fold
+    else:
+        build = _id_fold
+    fold = build(plan, aggs_bound, rel1, key_plane_index)
+
+    def init_state():
+        keys = fold.init_keys()
+        carries = {ae.out_name: uda.init(g) for ae, uda, _, _ in aggs_bound}
+        return {
+            "keys": keys,
+            "valid": jnp.zeros(g, dtype=jnp.bool_),
+            "carries": carries,
+            "overflow": jnp.zeros((), dtype=jnp.bool_),
+        }
+
+    def window_state(cols, valid):
+        """Fold one window of rows into a fresh [G]-slot group state.
+
+        ``valid`` is a bool[n] mask or a (lo, hi) row-range scalar pair
+        (the device-resident-window form)."""
+        valid = _range_valid(cols, valid)
+        cols, valid = apply_pre(cols, valid)
+        return fold.window(cols, valid)
+
+    def merge_states(sa, sb):
+        """Associative merge of two group states (slot orders may differ).
+
+        This single function is both the window accumulator and the
+        distributed finalize: per-device partial states gathered over the
+        mesh merge through it, replacing Carnot's UDA Serialize -> GRPC ->
+        finalize-agg pipeline (``planner/distributed/splitter/partial_op_mgr``).
+        """
+        return fold.merge(sa, sb)
 
     @jax.jit
     def update(state, cols, valid):
@@ -1253,7 +1202,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         return out
 
     group_sketch = None
-    if dense_domains is None and group_cols:
+    if plan.layout != "dense" and group_cols:
         @jax.jit
         def group_sketch(registers, cols, valid):
             valid = _range_valid(cols, valid)
@@ -1319,17 +1268,12 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
     @jax.jit
     def finalize(state):
         cols = {}
-        if dense_domains is not None:
-            for c, plane in zip(group_cols, dense_key_planes()):
-                cols[c] = (plane,)
-        else:
-            for c, _ in zip(group_cols, range(len(group_cols))):
-                planes = tuple(
-                    kp
-                    for kp, (kc, _i) in zip(state["keys"], key_plane_index)
-                    if kc == c
-                )
-                cols[c] = planes
+        key_planes = fold.key_planes(state)
+        for c in group_cols:
+            cols[c] = tuple(
+                kp for kp, (kc, _i) in zip(key_planes, key_plane_index)
+                if kc == c
+            )
         for ae, uda, _, _ in aggs_bound:
             out = uda.finalize(state["carries"][ae.out_name])
             cols[ae.out_name] = (out,)
@@ -1356,7 +1300,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
     # multi-core kernel; XLA keeps the elementwise pre-stage + slot-id
     # packing (engine._fold_agg_state_native).
     native_fold = None
-    if dense_domains is not None and all(
+    if plan.layout == "dense" and all(
         (
             ae.uda_name in ("count", "sum", "mean", "min", "max")
             or ae.uda_name == "quantiles"
@@ -1368,7 +1312,9 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         def fold_inputs(cols, valid):
             valid = _range_valid(cols, valid)
             cols2, valid2 = apply_pre(cols, valid)
-            gids, oob = dense_slot_ids(cols2, valid2)
+            gids, oob = _dense_slot_ids(
+                plan, rel1, key_plane_index, cols2, valid2
+            )
             args = []
             for ae, _uda, arg_bound, casts in aggs_bound:
                 if ae.uda_name == "count":
@@ -1390,7 +1336,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
 
             key_specs, key_srcs = [], []
             for (c, pi), dom, off, st in zip(
-                key_plane_index, dense_domains, dense_offsets, dense_strides
+                key_plane_index, plan.domains, plan.offsets, plan.strides
             ):
                 dt = rel1.col_type(c)
                 src = _src(c)
@@ -1449,14 +1395,12 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         key_plane_index=tuple(key_plane_index),
         group_relation=rel1,
         string_carry_sources=tuple(string_carry_sources),
-        dense_domains=dense_domains or (),
-        dense_offsets=dense_offsets or (),
-        dense_strides=dense_strides or (),
-        fold=fold,
-        group=(
-            "dense" if dense_domains is not None
-            else "hashed" if impl == "hash" else "sorted"
-        ),
+        dense_domains=plan.domains,
+        dense_offsets=plan.offsets,
+        dense_strides=plan.strides,
+        plan=plan,
+        fold=plan.fold,
+        group=plan.layout,
         slots=g,
         group_sketch=group_sketch,
         init_sketch=(

@@ -38,6 +38,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .routes import (  # noqa: F401  (the kernels' limits, kept by routes.py)
+    INT_FOLD_GROUP_BLOCK,
+    INT_FOLD_MAX_GROUPS,
+    int_fold_groups,
+)
+
 
 def row_chunk(n: int, cap: int) -> int | None:
     """Row block (<= cap) Mosaic accepts for a 1-D 32-bit [n] operand.
@@ -170,37 +176,13 @@ def dense_group_fold(slots, values, g: int, chunk: int = 2048,
 
 
 # -- exact integer fold -------------------------------------------------------
-#: Largest (padded) group count the integer fold is routed to; above it a
-#: window keeps the sort-based XLA fold (``udf/builtins/math_ops.py``).
-#: The one-hot costs rows x G while the sort costs about the same
-#: whatever G is, so there is a cross-over, and it is measured
-#: (``tools/fold_sweep.py``, one 2^21-row window of px/http_stats'
-#: count + mean + max on a TPU v5e; my chip run, PR 26, PERF.md section
-#: 6), kernel ms against sort-based ms: 32 slots 5.1 / 157.9; 2,048
-#: 11.6 / 134.5; 4,096 21.6 / 135.2; 8,192 41.4 / 136.6; 16,384 80.7 /
-#: 139.7; 24,576 120.1 / 142.2; 32,768 159.1 / 145.1. The kernel is
-#: 2.2 ms + 4.8 ms a 1,024-slot group block, so the lines cross near
-#: 30 Ki slots; the gate is the largest measured size that still wins.
-INT_FOLD_MAX_GROUPS = 24576
 #: Rows one call may fold: a limb's column sum (255 a row) has to stay
 #: inside the i32 accumulator, 255 * 2^23 < 2^31.
 INT_FOLD_MAX_ROWS = 1 << 23
-#: Columns of the one-hot a grid step builds (the G axis of the grid).
-#: Same run, 2,048 slots, [2048 rows, block] a step: 128 columns 59.8 ms
-#: (1,024-row steps), 256 29.9, 512 15.9, 1,024 11.6, 2,048 11.7.
-INT_FOLD_GROUP_BLOCK = 1024
 
 _LIMB_BITS = 8
 _I32_MIN = -(1 << 31)
 _I32_MAX = (1 << 31) - 1
-
-
-def int_fold_groups(g: int) -> int:
-    """g padded for ``dense_group_fold_int``: to whole 128-lane tiles,
-    and above one group block to whole blocks (so a dictionary one entry
-    larger than a block costs one more block, not the kernel)."""
-    step = 128 if g <= INT_FOLD_GROUP_BLOCK else INT_FOLD_GROUP_BLOCK
-    return -(-g // step) * step
 
 
 def int_fold_blocks(n: int, g_pad: int) -> tuple[int, int] | None:

@@ -1,0 +1,65 @@
+"""What a fold's route is chosen from besides the AggOp itself: the
+platform whose routes run, and the kernels' measured size limits.
+
+Kernel-free (no Pallas import): ``exec/fold_plan.py`` decides from these,
+``ops/pallas_groupby.py`` / ``ops/tdigest.py`` / ``udf/builtins/math_ops.py``
+obey the same values, so the decision and the kernels cannot disagree.
+"""
+
+from __future__ import annotations
+
+import jax
+
+
+def _backend() -> str:
+    """The backend underneath (the fold's one read of it)."""
+    return jax.default_backend()
+
+
+def routes_platform() -> str:
+    """Which platform's fold routes run: ``tpu`` sorts, contracts one-hots
+    in Pallas kernels and scan-folds windows in one program; ``cpu``
+    hashes, scatters and hands dense folds to the native library (XLA's
+    CPU sort is ~90x slower than its scatter, the inverse of the TPU).
+    Every route choice asks THIS function, and the fragment cache keys
+    on its value, so a test or ``chip_smoke.py``'s rehearsal reaches the
+    chip's routes on the CPU by substituting it alone."""
+    return _backend()
+
+
+def kernels_interpreted() -> bool:
+    """A Pallas kernel a route asks for is interpreted exactly when the
+    backend underneath is not a TPU (derived, never set)."""
+    return _backend() != "tpu"
+
+
+#: Largest (padded) group count the integer fold is routed to; above it a
+#: window keeps the sort-based XLA fold (``udf/builtins/math_ops.py``).
+#: The one-hot costs rows x G while the sort costs about the same
+#: whatever G is, so there is a cross-over, and it is measured
+#: (``tools/fold_sweep.py``, one 2^21-row window of px/http_stats'
+#: count + mean + max on a TPU v5e; my chip run, PR 26, PERF.md section
+#: 6), kernel ms against sort-based ms: 32 slots 5.1 / 157.9; 2,048
+#: 11.6 / 134.5; 4,096 21.6 / 135.2; 8,192 41.4 / 136.6; 16,384 80.7 /
+#: 139.7; 24,576 120.1 / 142.2; 32,768 159.1 / 145.1. The kernel is
+#: 2.2 ms + 4.8 ms a 1,024-slot group block, so the lines cross near
+#: 30 Ki slots; the gate is the largest measured size that still wins.
+INT_FOLD_MAX_GROUPS = 24576
+#: Columns of the one-hot a grid step builds (the G axis of the grid).
+#: Same run, 2,048 slots, [2048 rows, block] a step: 128 columns 59.8 ms
+#: (1,024-row steps), 256 29.9, 512 15.9, 1,024 11.6, 2,048 11.7.
+INT_FOLD_GROUP_BLOCK = 1024
+#: Largest group count the f32 kernel takes: its [chunk, G] one-hot must
+#: fit VMEM.
+F32_FOLD_MAX_GROUPS = 2048
+#: Largest groups x bins the quantile histogram kernel takes: its dense
+#: MXU sweep beats the two scatters up to here.
+HIST_FOLD_MAX_SLOTS = 1 << 15
+
+
+def int_fold_groups(g: int) -> int:
+    """g padded for ``dense_group_fold_int``: to whole 128-lane tiles,
+    and above one group block to whole blocks (so a dictionary one entry
+    larger than a block costs one more block, not the kernel)."""
+    step = 128 if g <= INT_FOLD_GROUP_BLOCK else INT_FOLD_GROUP_BLOCK
+    return -(-g // step) * step

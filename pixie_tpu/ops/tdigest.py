@@ -25,6 +25,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import routes
+
 DEFAULT_K = 128
 _BIG = jnp.inf
 
@@ -121,16 +123,12 @@ def batch_to_digest(values, group_ids, mask, num_groups: int, k: int = DEFAULT_K
     vb = jnp.where(values < 0, ~vb, vb | jnp.uint32(0x80000000))
     bins = (vb >> shift).astype(jnp.int32)
 
-    from ..config import get_flag
-
     n_slots = num_groups * b
     n = values.shape[0]
-    mode = get_flag("pallas_tdigest")
     chunk = None
     if (
-        mode in ("auto", "interpret")
-        and (mode == "interpret" or jax.default_backend() == "tpu")
-        and n_slots <= (1 << 15)  # MXU dense sweep beats scatters here
+        routes.routes_platform() == "tpu"
+        and n_slots <= routes.HIST_FOLD_MAX_SLOTS
         and n >= 128
     ):
         # Imported only here: pulling in Pallas costs seconds, which a
@@ -148,7 +146,7 @@ def batch_to_digest(values, group_ids, mask, num_groups: int, k: int = DEFAULT_K
         flat = jnp.where(mask & (gids < num_groups), gids * b + bins, pad)
         w_f, mw_f = hist_fold(
             flat, jnp.where(mask, values, 0.0), n_slots, chunk=chunk,
-            interpret=(mode == "interpret"),
+            interpret=routes.kernels_interpreted(),
         )
         w = w_f.reshape(num_groups, b)
         mw = mw_f.reshape(num_groups, b)

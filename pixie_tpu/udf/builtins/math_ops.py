@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops import routes
 from ...ops.scan import blocked_cumsum
 from ..udf import BOOLEAN, FLOAT64, INT64, STRING, TIME64NS
 
@@ -127,7 +128,7 @@ def register(reg):
         measured no better; on CPU the trade inverts hard (argsort 2M
         ~660ms vs scatter-add ~8ms). Trace-time check — executables are
         per-backend."""
-        return jax.default_backend() == "tpu"
+        return routes.routes_platform() == "tpu"
 
     def _seg_order(gids, mask, g):
         """(order, sorted_gids, ends): rows sorted by group id, invalid

@@ -32,7 +32,36 @@ from pixie_tpu.utils.cache import jax_cache_dir  # noqa: E402
 os.environ["JAX_COMPILATION_CACHE_DIR"] = jax_cache_dir()
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
+import contextlib  # noqa: E402
+import time  # noqa: E402
+from unittest import mock  # noqa: E402
+
 import pytest  # noqa: E402
+
+
+@contextlib.contextmanager
+def routes_of(platform):
+    """The fold routes of ``platform`` (``tpu`` / ``cpu``) on whatever
+    backend the tests run on, by substituting the one function every
+    route choice asks (``ops/routes.py``): under ``tpu`` here, rows sort,
+    the Pallas kernels run interpreted (the backend underneath is not a
+    TPU) and windows scan-fold; under ``cpu`` on a chip, the CPU's XLA
+    routes run there. Process-wide, like a flag: agents' threads see it."""
+    from pixie_tpu.ops import routes
+
+    with mock.patch.object(routes, "routes_platform", lambda: platform):
+        yield
+
+
+def wait_until(cond, what, ceiling_s=120.0):
+    """Poll ``cond`` until it holds: for a test that waits on an EVENT of
+    the system (an agent registered, a cancel delivered, a cache dropped)
+    and not on the clock. The ceiling is generous because a loaded box
+    is slow, not wrong; a healthy run never comes near it."""
+    deadline = time.monotonic() + ceiling_s
+    while not cond():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.01)
 
 # Runtime lock-order validation (pxlock's dynamic half): with
 # PIXIE_TPU_LOCKDEP=1 (./run_tests.sh --locks), every lock created from

@@ -195,14 +195,14 @@ class TestAnalyze:
 
 class TestConfig:
     def test_env_and_override(self, monkeypatch):
-        monkeypatch.setenv("PIXIE_TPU_MAX_GROUPS", "512")
-        assert config.get_flag("max_groups") == 512
-        config.set_flag("max_groups", 1024)
-        assert config.get_flag("max_groups") == 1024
-        config.clear_flag("max_groups")
-        assert config.get_flag("max_groups") == 512
-        monkeypatch.delenv("PIXIE_TPU_MAX_GROUPS")
-        assert config.get_flag("max_groups") == 4096
+        monkeypatch.setenv("PIXIE_TPU_FOLD_SCAN_WINDOWS", "8")
+        assert config.get_flag("fold_scan_windows") == 8
+        config.set_flag("fold_scan_windows", 4)
+        assert config.get_flag("fold_scan_windows") == 4
+        config.clear_flag("fold_scan_windows")
+        assert config.get_flag("fold_scan_windows") == 8
+        monkeypatch.delenv("PIXIE_TPU_FOLD_SCAN_WINDOWS")
+        assert config.get_flag("fold_scan_windows") == 16
 
     def test_bool_parse(self, monkeypatch):
         monkeypatch.setenv("PIXIE_TPU_DEVICE_RESIDENCY", "false")

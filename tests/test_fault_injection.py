@@ -16,6 +16,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import wait_until
 from pixie_tpu.config import override_flag
 from pixie_tpu.exec.engine import QueryError
 from pixie_tpu.services import (
@@ -1092,7 +1093,7 @@ class TestQuarantineCooldownRecovery:
     the agent must land back in the dispatch set and the result cache
     must not serve the quarantine-era (2-shard) answer."""
 
-    def _lifecycle(self, execute, bus, tracker, pems):
+    def _lifecycle(self, execute, bus, tracker, pems, broker):
         # Healthy: all 3 data shards answer, and the repeat is a hit.
         res = execute()
         assert set(res["agent_stats"]) == {"pem-0", "pem-1", "pem-2"}
@@ -1107,33 +1108,37 @@ class TestQuarantineCooldownRecovery:
                 {"agent_id": "pem-2", "processes_data": True,
                  "schemas": pems[2]._schemas()},
             )
-            deadline = time.time() + 5
-            while (
-                time.time() < deadline
-                and "pem-2" not in tracker.agent_ids()
-            ):
-                time.sleep(0.01)
+            wait_until(lambda: "pem-2" in tracker.agent_ids(),
+                             "the flapped agent never registered again")
         assert tracker.is_quarantined("pem-2")
         res = execute()
+        assert tracker.is_quarantined("pem-2"), (
+            "the cooldown lapsed before the quarantine-era query ran")
         assert set(res["agent_stats"]) == {"pem-0", "pem-1"}
         assert _total_n(res) == _count_truth(pems, [0, 1])
         # Cooldown passes; the agent re-registers and is dispatchable.
-        deadline = time.time() + 5
-        while time.time() < deadline and tracker.is_quarantined("pem-2"):
-            time.sleep(0.02)
-        assert not tracker.is_quarantined("pem-2")
+        wait_until(lambda: not tracker.is_quarantined("pem-2"),
+                         "the quarantine never lapsed")
         bus.publish(
             "agent.register",
             {"agent_id": "pem-2", "processes_data": True,
              "schemas": pems[2]._schemas()},
         )
-        deadline = time.time() + 5
-        while time.time() < deadline and (
-            "pem-2" not in [
+        # The registration reaches the tracker and the broker on the
+        # bus's own threads: the agent is back once the tracker plans it
+        # again AND the broker has dropped what it cached without it
+        # (its ``agent.register`` handler clears the result cache).
+        wait_until(
+            lambda: "pem-2" in [
                 a.agent_id for a in tracker.distributed_state().agents
-            ]
-        ):
-            time.sleep(0.02)
+            ],
+            "the released agent never came back into the plan",
+        )
+        wait_until(
+            lambda: not broker.result_cache.cachez()["entries"],
+            "the broker kept its quarantine-era cache after the "
+            "re-registration",
+        )
         res = execute()
         assert set(res["agent_stats"]) == {"pem-0", "pem-1", "pem-2"}
         assert _total_n(res) == want_all, (
@@ -1148,10 +1153,12 @@ class TestQuarantineCooldownRecovery:
         # window has to outlast the test's own steps between the flap
         # and the quarantine-era query's planning (a register round
         # trip, then a request over the netbus): 0.4 s lapsed first on
-        # a loaded box and the query found all three shards.
+        # a loaded box and the query found all three shards. The
+        # lifecycle then WAITS for the lapse, so the window costs the
+        # test its length and no more.
         tracker = AgentTracker(
             bus, expiry_s=60.0, check_interval_s=60.0,
-            flap_threshold=2, flap_window_s=60.0, quarantine_s=1.5,
+            flap_threshold=2, flap_window_s=60.0, quarantine_s=3.0,
         )
         pems = [
             PEMAgent(bus, f"pem-{i}", **FAST).start() for i in range(3)
@@ -1167,12 +1174,11 @@ class TestQuarantineCooldownRecovery:
                 "service": [f"svc-{(i + j) % 3}" for j in range(n)],
             })
             pem._register()
-        deadline = time.time() + 5
-        while time.time() < deadline and (
-            len(tracker.agent_ids()) < 4
-            or "http_events" not in tracker.schemas()
-        ):
-            time.sleep(0.01)
+        wait_until(
+            lambda: len(tracker.agent_ids()) == 4
+            and "http_events" in tracker.schemas(),
+            "the cluster never registered",
+        )
         broker = QueryBroker(bus, tracker)
         return bus, tracker, pems, kelvin, broker
 
@@ -1190,7 +1196,7 @@ class TestQuarantineCooldownRecovery:
                 return broker.execute_script(AGG_Q, timeout_s=20.0)
 
             with override_flag("result_cache_mb", 64):
-                self._lifecycle(execute, bus, tracker, pems)
+                self._lifecycle(execute, bus, tracker, pems, broker)
         finally:
             self._teardown(bus, tracker, pems, kelvin, broker)
 
@@ -1212,7 +1218,7 @@ class TestQuarantineCooldownRecovery:
                 return res
 
             with override_flag("result_cache_mb", 64):
-                self._lifecycle(execute, bus, tracker, pems)
+                self._lifecycle(execute, bus, tracker, pems, broker)
         finally:
             rb.close()
             server.close()

@@ -1,0 +1,228 @@
+"""The group-by's one decision (``exec/fold_plan.py`` ``plan_fold``): a
+table of AggOps asked "which fold?" on both platforms, with nothing traced
+or compiled, and the fragment cache keyed on the platform asked."""
+
+import subprocess
+import sys
+
+import pytest
+
+from conftest import routes_of
+from pixie_tpu.exec.fold_plan import FoldPlan, plan_fold
+from pixie_tpu.ops.routes import (
+    F32_FOLD_MAX_GROUPS, INT_FOLD_MAX_GROUPS, int_fold_groups,
+)
+from pixie_tpu.types.dtypes import DataType as D
+
+I64, F64, BOOL, T64 = (D.INT64,), (D.FLOAT64,), (D.BOOLEAN,), (D.TIME64NS,)
+SVC, PATH = ("service", D.STRING), ("req_path", D.STRING)
+# px/http_stats' and px/service_stats' aggregates.
+HTTP = (("n", "count", I64), ("lat_mean", "mean", I64), ("lat_max", "max", I64))
+SERVICE = (("err", "mean", BOOL), ("n", "count", I64),
+           ("p50", "_quantile_p50", F64), ("p99", "_quantile_p99", F64))
+DENSE_2145 = [(33, 0, 1), (65, 0, 1)]  # the dense cells' dictionaries
+KEYED = [(33, 0, 1), (65_537, 0, 1)]  # http_full_1chip's: over the limit
+
+
+def _plan(group_cols, domains, aggs, platform, max_groups=4096,
+          allow_dense=True) -> FoldPlan:
+    return plan_fold(
+        tuple(group_cols), domains, tuple(aggs), max_groups=max_groups,
+        allow_dense=allow_dense, dense_limit=1 << 20,
+        int_dense_limit=1 << 23, platform=platform,
+    )
+
+
+def _routes(plan):
+    return tuple(r for _out, r in plan.routes)
+
+
+# (id, group cols, domains, aggregates,
+#  tpu: (layout, slots, routes, count_route, fold),
+#  cpu: (layout, routes, fold)): on the CPU nothing takes a kernel or the
+#  payload sort, whatever the AggOp.
+DENSE_CASES = [
+    ("http_stats_2145", (SVC, PATH), DENSE_2145, HTTP,
+     ("dense", 2145, ("pallas_int",) * 3, "pallas_int", "pallas_int")),
+    ("service_stats_33", (SVC,), [(33, 0, 1)], SERVICE,
+     ("dense", 33, ("pallas_int", "pallas_int", "xla", "xla"), "pallas_int",
+      "mixed:pallas_int=2,xla=2")),
+    ("at_the_cross_over", (PATH,), [(INT_FOLD_MAX_GROUPS, 0, 1)], HTTP,
+     ("dense", INT_FOLD_MAX_GROUPS, ("pallas_int",) * 3, "pallas_int",
+      "pallas_int")),
+    ("over_the_cross_over", (PATH,), [(INT_FOLD_MAX_GROUPS + 1, 0, 1)], HTTP,
+     ("dense", INT_FOLD_MAX_GROUPS + 1, ("xla",) * 3, "xla", "xla")),
+    ("integer_alone", (SVC,), [(33, 0, 1)], (("s", "sum", I64),),
+     ("dense", 33, ("pallas_int",), "pallas_int", "pallas_int")),
+    ("time_extremes", (SVC,), [(33, 0, 1)],
+     (("first", "min", T64), ("last", "max", T64)),
+     ("dense", 33, ("pallas_int",) * 2, "pallas_int", "pallas_int")),
+    ("boolean_sum_not_max", (SVC,), [(33, 0, 1)],
+     (("e", "sum", BOOL), ("any", "max", BOOL)),
+     ("dense", 33, ("pallas_int", "xla"), "pallas_int",
+      "mixed:pallas_int=1,xla=1")),
+    # A FLOAT64 argument alone: the count rides the f32 kernel with it.
+    ("float_alone", (SVC,), [(33, 0, 1)],
+     (("s", "sum", F64), ("n", "count", F64)),
+     ("dense", 33, ("pallas_f32",) * 2, "pallas_f32", "pallas_f32")),
+    ("float_at_2048", (PATH,), [(F32_FOLD_MAX_GROUPS, 0, 1)],
+     (("mx", "max", F64),),
+     ("dense", F32_FOLD_MAX_GROUPS, ("pallas_f32",), "pallas_f32",
+      "pallas_f32")),
+    # Above the f32 kernel's limit the sum stays on XLA; the count still
+    # rides the integer kernel, which runs for it alone.
+    ("float_over_2048", (SVC, PATH), DENSE_2145,
+     (("s", "sum", F64), ("n", "count", F64)),
+     ("dense", 2145, ("xla", "pallas_int"), "pallas_int",
+      "mixed:pallas_int=1,xla=1")),
+    ("integer_and_float", (SVC,), [(33, 0, 1)],
+     (("si", "sum", I64), ("sf", "mean", F64), ("n", "count", I64)),
+     ("dense", 33, ("pallas_int", "pallas_f32", "pallas_int"), "pallas_int",
+      "mixed:pallas_int=2,pallas_f32=1")),
+    # A ``quantiles`` alone vetoes nothing: the slots' row count (the
+    # state's ``valid``) still comes from the integer kernel.
+    ("quantiles_alone", (SVC,), [(33, 0, 1)], (("q", "quantiles", F64),),
+     ("dense", 33, ("xla",), "pallas_int", "xla")),
+    ("two_arguments_stay_on_xla", (SVC,), [(33, 0, 1)],
+     (("c", "sum", (D.INT64, D.INT64)),),
+     ("dense", 33, ("xla",), "pallas_int", "xla")),
+    # One integer key has the larger budget; beside a second key it has not.
+    ("single_int_key", (("shard", D.INT64),), [(1 << 22, -7, 1)], HTTP,
+     ("dense", 1 << 22, ("xla",) * 3, "xla", "xla")),
+]
+
+
+@pytest.mark.parametrize(
+    "group_cols,domains,aggs,tpu", [c[1:] for c in DENSE_CASES],
+    ids=[c[0] for c in DENSE_CASES])
+def test_a_dense_domain(group_cols, domains, aggs, tpu):
+    layout, slots, routes, count_route, fold = tpu
+    plan = _plan(group_cols, domains, aggs, "tpu")
+    assert (plan.layout, plan.slots, _routes(plan), plan.count_route,
+            plan.fold) == (layout, slots, routes, count_route, fold)
+    assert plan.domains == tuple(d for d, _o, _s in domains)
+    assert not plan.payload_sort and plan.pack_doms is None
+    cpu = _plan(group_cols, domains, aggs, "cpu")
+    assert (cpu.layout, cpu.slots, cpu.count_route, cpu.fold) == (
+        "dense", slots, "xla", "xla")
+    assert set(_routes(cpu)) == {"xla"}
+    assert (cpu.domains, cpu.offsets, cpu.strides) == (
+        plan.domains, plan.offsets, plan.strides)
+    assert [out for out, _r in plan.routes] == [a[0] for a in aggs]
+
+
+def test_a_dense_domains_offsets_and_strides_pass_through():
+    plan = _plan((("minute", T64),), [(60, 1_000, 60_000_000_000)], HTTP,
+                 "tpu")
+    assert (plan.domains, plan.offsets, plan.strides) == (
+        (60,), (1_000,), (60_000_000_000,))
+
+
+def test_the_integer_kernels_padding_decides_the_cross_over():
+    """The gate is on the PADDED group count: one group over a block
+    boundary costs a block, and the last block under the limit counts."""
+    g = INT_FOLD_MAX_GROUPS - 1_023  # pads to the limit itself
+    assert int_fold_groups(g) == INT_FOLD_MAX_GROUPS
+    assert _plan((PATH,), [(g, 0, 1)], HTTP, "tpu").fold == "pallas_int"
+
+
+# (id, group cols, domains, aggregates, extra arguments,
+#  tpu: (payload_sort, pack_doms, lead_id, fold), cpu's fold is ``xla``).
+KEYED_CASES = [
+    ("http_stats_packed", (SVC, PATH), KEYED, HTTP, {"max_groups": 1 << 17},
+     (True, (33, 65_537), False, "sorted_int")),
+    # The Kelvin's fragment trusts no domain: planes as they are, the
+    # leading dictionary id sparing the flag operand.
+    ("kelvin_unpacked_lead_id", (SVC, PATH), KEYED, HTTP,
+     {"allow_dense": False}, (True, None, True, "sorted_int")),
+    ("kelvin_small_domains_stay_keyed", (SVC, PATH), DENSE_2145, HTTP,
+     {"allow_dense": False}, (True, None, True, "sorted_int")),
+    ("unknown_domain", (SVC, PATH), None, HTTP, {},
+     (True, None, True, "sorted_int")),
+    ("an_integer_key_does_not_pack", (SVC, ("shard", D.INT64)),
+     [(33, 0, 1), (1 << 21, 0, 1)], HTTP, {},
+     (True, None, True, "sorted_int")),
+    ("integer_key_first_no_lead_id", (("shard", D.INT64), SVC), None, HTTP,
+     {}, (True, None, False, "sorted_int")),
+    ("too_wide_for_one_word", (PATH, ("peer", D.STRING)),
+     [(65_537, 0, 1), (65_537, 0, 1)], HTTP, {},
+     (True, None, True, "sorted_int")),
+    ("two_keys_have_the_base_budget", (("shard", D.INT64), ("ok", D.BOOLEAN)),
+     [(1 << 20, 0, 1), (2, 0, 1)], HTTP, {},
+     (True, None, False, "sorted_int")),
+    ("count_alone", (SVC, PATH), KEYED, (("n", "count", I64),), {},
+     (True, (33, 65_537), False, "sorted_int")),
+    # An aggregate that needs a row's group id keeps the id form.
+    ("with_a_quantiles", (SVC, PATH), KEYED,
+     HTTP + (("q", "quantiles", F64),), {}, (False, None, False, "xla")),
+    ("with_a_float_sum", (SVC, PATH), KEYED, HTTP + (("s", "sum", F64),), {},
+     (False, None, False, "xla")),
+    ("with_a_boolean_max", (SVC, PATH), KEYED, (("any", "max", BOOL),), {},
+     (False, None, False, "xla")),
+    ("no_key_at_all", (), None, HTTP, {}, (False, None, False, "xla")),
+]
+
+
+@pytest.mark.parametrize(
+    "group_cols,domains,aggs,kw,tpu", [c[1:] for c in KEYED_CASES],
+    ids=[c[0] for c in KEYED_CASES])
+def test_a_keyed_state(group_cols, domains, aggs, kw, tpu):
+    payload_sort, pack_doms, lead_id, fold = tpu
+    plan = _plan(group_cols, domains, aggs, "tpu", **kw)
+    assert (plan.layout, plan.slots) == ("sorted", kw.get("max_groups", 4096))
+    assert (plan.payload_sort, plan.pack_doms, plan.lead_id, plan.fold) == tpu
+    assert set(_routes(plan)) == {"sorted_int" if payload_sort else "xla"}
+    assert plan.count_route == "xla" and plan.domains == ()
+    cpu = _plan(group_cols, domains, aggs, "cpu", **kw)
+    assert (cpu.layout, cpu.slots, cpu.fold) == ("hashed", plan.slots, "xla")
+    assert not cpu.payload_sort and cpu.pack_doms is None and not cpu.lead_id
+    assert set(_routes(cpu)) == {"xla"}
+
+
+def test_the_record_is_frozen_and_names_its_platform():
+    plan = _plan((SVC,), [(33, 0, 1)], HTTP, "tpu")
+    assert plan.platform == "tpu"
+    with pytest.raises(AttributeError):
+        plan.layout = "hashed"
+
+
+def test_the_fragment_cache_key_separates_the_platforms():
+    """One chain compiled under each platform's routes: two fragments,
+    each stamped with its platform; asked again, each is found."""
+    import pixie_tpu  # noqa: F401
+    from pixie_tpu.exec.fragment import compile_fragment_cached
+    from pixie_tpu.exec.plan import AggExpr, AggOp, ColumnRef
+    from pixie_tpu.types.relation import Relation
+    from pixie_tpu.types.strings import StringDictionary
+    from pixie_tpu.udf.registry import default_registry
+
+    rel = Relation([("latency_ns", D.INT64), ("service", D.STRING)])
+    dicts = {"service": StringDictionary(f"s{i}" for i in range(32))}
+    lat = (ColumnRef("latency_ns"),)
+    ops = [AggOp(("service",), (AggExpr("n", "count", lat),
+                                AggExpr("m", "mean", lat)))]
+
+    def compiled(platform):
+        with routes_of(platform):
+            return compile_fragment_cached(ops, rel, dicts, default_registry())
+
+    cpu, tpu = compiled("cpu"), compiled("tpu")
+    assert cpu is not tpu
+    assert (cpu.plan.platform, cpu.fold) == ("cpu", "xla")
+    assert (tpu.plan.platform, tpu.fold) == ("tpu", "pallas_int")
+    assert (tpu.group, tpu.slots) == (tpu.plan.layout, tpu.plan.slots) == (
+        "dense", 33)
+    assert compiled("cpu") is cpu and compiled("tpu") is tpu
+
+
+def test_asking_imports_no_kernel():
+    code = (
+        "import sys; import pixie_tpu.exec.fold_plan as fp;"
+        "from pixie_tpu.types.dtypes import DataType as D;"
+        "p = fp.plan_fold((('s', D.STRING),), [(33, 0, 1)],"
+        " (('n', 'count', (D.INT64,)),), max_groups=64, allow_dense=True,"
+        " dense_limit=1 << 20, int_dense_limit=1 << 23, platform='tpu');"
+        "assert p.fold == 'pallas_int', p;"
+        "bad = [m for m in sys.modules if 'pallas' in m]; assert not bad, bad"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)

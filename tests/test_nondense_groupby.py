@@ -6,8 +6,8 @@ codes) is over ``dense_domain_limit`` at any row count, so
 ``scatter_carry`` with a keyed state. Both shipped scripts, through a
 bare ``Engine`` and through broker, PEMs and Kelvin, against the
 benchmark's plain numpy reference: exact keys, counts and ``lat_max``,
-``lat_mean`` one f32 rounding from exact; over both group-id
-algorithms, over a starting capacity below and above the live groups
+``lat_mean`` one f32 rounding from exact; over both platforms' routes
+(the TPU's sorts, the CPU's hash table), over a starting capacity below and above the live groups
 (the ladder is climbed once and remembered), and over one and three
 PEM partial states merged by the Kelvin."""
 
@@ -19,8 +19,9 @@ import numpy as np
 import pytest
 
 from benchmark.builders import served_http_skew
+from conftest import routes_of
 from benchmark.reference import px_http_stats, px_service_stats
-from pixie_tpu.config import get_flag, override_flag
+from pixie_tpu.config import get_flag
 from pixie_tpu.exec.engine import Engine
 from pixie_tpu.scripts import load_script
 
@@ -96,26 +97,26 @@ def test_the_key_domain_is_over_the_dense_limit(data):
     assert doms[0] * doms[1] > get_flag("dense_domain_limit")
 
 
-@pytest.mark.parametrize("impl", ["sort", "hash"])
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
 @pytest.mark.parametrize("script", list(SCRIPTS))
-def test_engine_equals_the_reference(data, answers, script, impl):
-    with override_flag("groupby_impl", impl):
+def test_engine_equals_the_reference(data, answers, script, platform):
+    with routes_of(platform):
         eng = _engine(data)
         out = eng.execute_query(SCRIPTS[script][0],
                                 max_output_rows=EVERY_GROUP)
     _check(script, out["output"].to_pydict(), answers[script], served=False)
     groups = {s.attributes["group"] for s in _fold_spans(eng.tracer.last())}
     if script == "http_stats":
-        assert groups == {"sorted" if impl == "sort" else "hashed"}
+        assert groups == {"sorted" if platform == "tpu" else "hashed"}
         assert len(answers[script]["key"]) > 10_000
     else:
         assert groups <= {"dense"}  # (the CPU's native fold has no spans)
 
 
-@pytest.mark.parametrize("impl", ["sort", "hash"])
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
 @pytest.mark.parametrize("start", [1024, 1 << 18], ids=["below", "above"])
 def test_the_ladder_is_climbed_once_and_remembered(data, answers, start,
-                                                   impl):
+                                                   platform):
     """From a capacity below the live groups the fold doubles until it
     fits (a ``rebucket`` span a rung, counted in ``usage.rebuckets``);
     from one far above, it folds there once. Either way the answer is
@@ -124,7 +125,7 @@ def test_the_ladder_is_climbed_once_and_remembered(data, answers, start,
     from pixie_tpu.planner import CompilerState, compile_pxl
 
     live = len(answers["http_stats"]["key"])
-    with override_flag("groupby_impl", impl):
+    with routes_of(platform):
         eng = _engine(data)
 
         def run():
@@ -255,14 +256,15 @@ def cluster(request, data):
     bus.close()
 
 
-@pytest.mark.parametrize("impl", ["sort", "hash"])
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
 @pytest.mark.parametrize("script", list(SCRIPTS))
-def test_the_served_path_equals_the_reference(cluster, answers, script, impl):
+def test_the_served_path_equals_the_reference(cluster, answers, script,
+                                              platform):
     """The PEMs' partial states, keyed, merged by the Kelvin's regroup:
     the parts add up to the whole, twice (the second request starts
     from what the first remembered)."""
     broker, pems, kelvin = cluster
-    with override_flag("groupby_impl", impl):
+    with routes_of(platform):
         for _ in range(2):
             res = broker.execute_script(
                 SCRIPTS[script][0], timeout_s=120,
@@ -272,7 +274,7 @@ def test_the_served_path_equals_the_reference(cluster, answers, script, impl):
             _check(script, res["tables"]["output"].to_pydict(),
                    answers[script], served=True)
     if script == "http_stats":
-        want = "sorted" if impl == "sort" else "hashed"
+        want = "sorted" if platform == "tpu" else "hashed"
         for pem in pems:
             frag = next(t for t in pem.engine.tracer.recent()
                         if t["kind"] == "fragment")

@@ -239,17 +239,14 @@ px.display(out)
         return eng
 
     def test_pallas_engine_path_matches_xla(self):
-        from pixie_tpu.config import set_flag
+        from conftest import routes_of
+        from pixie_tpu.config import override_flag
 
         eng = self._engine()
-        set_flag("cpu_fold_threads", 1)  # isolate the XLA/Pallas paths
-        try:
+        with override_flag("cpu_fold_threads", 1):  # the XLA fold, not the native one
             xla = eng.execute_query(self.QUERY)["output"].to_pydict()
-            set_flag("pallas_dense_fold", "interpret")
+        with routes_of("tpu"):
             pallas = eng.execute_query(self.QUERY)["output"].to_pydict()
-        finally:
-            set_flag("pallas_dense_fold", "auto")
-            set_flag("cpu_fold_threads", 0)
         ox = np.argsort(xla["svc"])
         op = np.argsort(pallas["svc"])
         assert list(np.array(xla["svc"])[ox]) == list(np.array(pallas["svc"])[op])
@@ -260,21 +257,18 @@ px.display(out)
         np.testing.assert_allclose(xla["mx"][ox], pallas["mx"][op], rtol=1e-6)
 
     def test_tdigest_pallas_quantiles_close(self):
-        from pixie_tpu.config import set_flag
+        from conftest import routes_of
+        from pixie_tpu.config import override_flag
 
         eng = self._engine()
         q = ("import px\ndf = px.DataFrame(table='t')\n"
              "out = df.groupby('svc').agg(p=('v', px.quantiles))\n"
              "out.p50 = px.pluck_float64(out.p, 'p50')\n"
              "out = out[['svc', 'p50']]\npx.display(out)")
-        set_flag("cpu_fold_threads", 1)
-        try:
+        with override_flag("cpu_fold_threads", 1):
             xla = eng.execute_query(q)["output"].to_pydict()
-            set_flag("pallas_tdigest", "interpret")
+        with routes_of("tpu"):
             pal = eng.execute_query(q)["output"].to_pydict()
-        finally:
-            set_flag("pallas_tdigest", "auto")
-            set_flag("cpu_fold_threads", 0)
         ox, op = np.argsort(xla["svc"]), np.argsort(pal["svc"])
         np.testing.assert_allclose(xla["p50"][ox], pal["p50"][op], rtol=0.05)
 
@@ -353,12 +347,13 @@ px.display(out)
         return eng
 
     @staticmethod
-    def _run(eng, pxl, mode):
+    def _run(eng, pxl, platform):
         """(sorted output columns, the fold programs' ``fold`` span
         attributes, the /debug/queryz fragment entries' ``fold``)."""
+        from conftest import routes_of
         from pixie_tpu.config import override_flag
 
-        with override_flag("pallas_dense_fold", mode), \
+        with routes_of(platform), \
                 override_flag("cpu_fold_threads", 1):  # not the native fold
             out = eng.execute_query(pxl)["output"].to_pydict()
         trace = eng.tracer.last()
@@ -387,8 +382,8 @@ px.display(out)
         from pixie_tpu.scripts import load_script
 
         pxl = load_script(script).pxl
-        off, off_spans, off_queryz = self._run(eng, pxl, "off")
-        on, spans, queryz = self._run(eng, pxl, "interpret")
+        off, off_spans, off_queryz = self._run(eng, pxl, "cpu")
+        on, spans, queryz = self._run(eng, pxl, "tpu")
         self._assert_bit_equal(off, on)
         assert off_spans == off_queryz == {"xla"}
         assert spans == queryz == {fold}
@@ -406,9 +401,9 @@ px.display(out)
             return real(slots, sum_args, ext_args, **kw)
 
         monkeypatch.setattr(pallas_groupby, "dense_group_fold_int", spy)
-        off, _, _ = self._run(eng, self.MIXED, "off")
+        off, _, _ = self._run(eng, self.MIXED, "cpu")
         assert not calls
-        on, spans, _ = self._run(eng, self.MIXED, "interpret")
+        on, spans, _ = self._run(eng, self.MIXED, "tpu")
         self._assert_bit_equal(off, on)
         assert spans == {"mixed:pallas_int=2,xla=1"}
         # Traced once (the three windows share one program): one call,
@@ -418,14 +413,14 @@ px.display(out)
     def test_integer_sum_min_max_equal_xla(self, eng):
         """Negative INT64 values, TIME64NS extremes, several arguments,
         min beside max: every carry bit-equal to the XLA fold's."""
-        off, _, _ = self._run(eng, self.INT_STATS, "off")
-        on, spans, _ = self._run(eng, self.INT_STATS, "interpret")
+        off, _, _ = self._run(eng, self.INT_STATS, "cpu")
+        on, spans, _ = self._run(eng, self.INT_STATS, "tpu")
         self._assert_bit_equal(off, on)
         assert spans == {"pallas_int"}
         assert (on["lo"] < 0).any() and on["s"].dtype == np.int64
 
     def test_float64_arguments_keep_the_f32_kernel(self, eng):
-        _out, spans, queryz = self._run(eng, self.F64, "interpret")
+        _out, spans, queryz = self._run(eng, self.F64, "tpu")
         assert spans == queryz == {"pallas_f32"}
 
     def test_above_the_crossover_stays_on_xla(self, monkeypatch):
@@ -461,8 +456,8 @@ px.display(out)
             if ndv > INT_FOLD_MAX_GROUPS:
                 monkeypatch.setattr(
                     pallas_groupby, "dense_group_fold_int", refuse)
-            off, _, _ = self._run(eng, q, "off")
-            on, spans, _ = self._run(eng, q, "interpret")
+            off, _, _ = self._run(eng, q, "cpu")
+            on, spans, _ = self._run(eng, q, "tpu")
             self._assert_bit_equal(off, on)
             results[ndv] = spans
         assert results[INT_FOLD_MAX_GROUPS - 1] == {"pallas_int"}
@@ -496,7 +491,7 @@ class TestRowChunk:
         import jax.numpy as jnp
         import numpy as np
 
-        from pixie_tpu.config import override_flag
+        from conftest import routes_of
         from pixie_tpu.ops import pallas_tdigest
         from pixie_tpu.ops.tdigest import batch_to_digest, digest_quantile
 
@@ -509,7 +504,7 @@ class TestRowChunk:
         orig = pallas_tdigest.hist_fold
         pallas_tdigest.hist_fold = lambda *a, **k: called.append(1) or orig(*a, **k)
         try:
-            with override_flag("pallas_tdigest", "interpret"):
+            with routes_of("tpu"):
                 q = digest_quantile(batch_to_digest(vals, gids, mask, 1), (0.5,))
         finally:
             pallas_tdigest.hist_fold = orig

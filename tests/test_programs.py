@@ -17,6 +17,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import wait_until
 from pixie_tpu.config import override_flag
 from pixie_tpu.exec.engine import Engine
 from pixie_tpu.exec.programs import (
@@ -564,9 +565,10 @@ class TestObservedFloor:
                     "service": [f"s-{i % 3}" for i in range(n)],
                 })
                 pem._register()
-                deadline = time.time() + 5
-                while time.time() < deadline and not tracker.schemas():
-                    time.sleep(0.01)
+                # The table's schema, not any schema: the agents' first
+                # registration carries their telemetry tables alone.
+                wait_until(lambda: "http_events" in tracker.schemas(),
+                           "the PEM's table never reached the tracker")
                 broker = QueryBroker(bus, tracker)
                 q = (
                     "import px\n"
@@ -818,9 +820,11 @@ class TestMergeTierIdentity:
                     "service": [f"svc-{i % 3}" for i in range(n)],
                 })
                 pem._register()
-            deadline = time.time() + 5
-            while time.time() < deadline and not tracker.schemas():
-                time.sleep(0.01)
+            wait_until(
+                lambda: len(tracker.distributed_state().pems_with_table(
+                    "http_events")) == len(pems),
+                "a PEM's table never reached the tracker",
+            )
             broker = QueryBroker(bus, tracker)
             # String group keys force dictionary-bearing bridge payloads
             # through the merge agent — the exact path that recompiled.

@@ -1,7 +1,7 @@
 """The keyed fold as a payload-carrying sort (``ops/groupby.py``
 ``sorted_group_fold`` and the route ``exec/fragment.py`` gives it: a
-non-dense key, every aggregate an exact integer statistic, the sort
-impl). On the CPU through ``groupby_impl=sort`` and by calling the
+non-dense key, every aggregate an exact integer statistic, the TPU's
+routes). On the CPU under ``routes_of("tpu")`` and by calling the
 function: against numpy over one to three key planes, masked rows, empty
 windows, wrapping sums and negative extremes; against the id form
 (``dense_group_ids`` + ``uda.update`` + ``regroup_pair`` +
@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import pixie_tpu  # noqa: F401  (x64 on)
+from conftest import routes_of
 from pixie_tpu.config import override_flag
 from pixie_tpu.exec.engine import Engine
 from pixie_tpu.exec.fragment import compile_fragment
@@ -215,9 +216,9 @@ def _table(seed=11):
     }
 
 
-def _frag(keys, aggs, g, impl="sort", extra=(), allow_dense=True):
+def _frag(keys, aggs, g, platform="tpu", extra=(), allow_dense=True):
     aggs = tuple(AggExpr(o, u, (ColumnRef(c),)) for o, u, c in aggs + extra)
-    with override_flag("groupby_impl", impl):
+    with routes_of(platform):
         return compile_fragment(
             [AggOp(tuple(keys), aggs, max_groups=g)], REL, DICTS,
             default_registry(), allow_dense=allow_dense,
@@ -298,15 +299,17 @@ def test_route_equals_the_id_form_and_numpy(aggs, keys):
     assert got == _numpy_answer(table, KEY_SETS[keys], AGG_SETS[aggs])
 
 
-@pytest.mark.parametrize("aggs,keys,impl,allow_dense,fold", [
-    ("count_mean_max", ("svc", "path"), "sort", True, "sorted_int"),
-    ("count_mean_max", ("svc", "path"), "sort", False, "sorted_int"),
-    ("count_mean_max", ("svc", "path"), "hash", True, "xla"),
-    ("count_mean_max", ("svc",), "sort", True, "xla"),  # a dense domain
-    ("count_mean_max", (), "sort", True, "xla"),  # no key at all
+@pytest.mark.parametrize("aggs,keys,platform,allow_dense,fold", [
+    ("count_mean_max", ("svc", "path"), "tpu", True, "sorted_int"),
+    ("count_mean_max", ("svc", "path"), "tpu", False, "sorted_int"),
+    ("count_mean_max", ("svc", "path"), "cpu", True, "xla"),
+    # A dense domain: the dense fold, each aggregate on its own route.
+    ("count_mean_max", ("svc",), "tpu", True, "pallas_int"),
+    ("count_mean_max", (), "tpu", True, "xla"),  # no key at all
 ])
-def test_what_chooses_the_route(aggs, keys, impl, allow_dense, fold):
-    frag = _frag(keys, AGG_SETS[aggs], 256, impl=impl, allow_dense=allow_dense)
+def test_what_chooses_the_route(aggs, keys, platform, allow_dense, fold):
+    frag = _frag(keys, AGG_SETS[aggs], 256, platform=platform,
+                 allow_dense=allow_dense)
     assert frag.fold == fold
     if fold == "sorted_int":
         assert frag.group == "sorted"
@@ -431,7 +434,7 @@ def test_the_engine_refolds_after_an_overflow(start):
     from pixie_tpu.planner import CompilerState, compile_pxl
 
     data = _events(7, 5_000, [f"svc-{i}" for i in range(40)], 2_000)
-    with override_flag("groupby_impl", "sort"), \
+    with routes_of("tpu"), \
             override_flag("dense_domain_limit", 1_024):
         eng = Engine(window_rows=1_024)
         eng.append_data("events", data)
@@ -492,7 +495,7 @@ def test_pems_with_dictionaries_of_their_own_through_the_kelvin(cluster):
     which reorders keys: its merge is handed states in no key order, and
     the answer is still numpy's over all the parts."""
     broker, pems, kelvin, parts = cluster
-    with override_flag("groupby_impl", "sort"), \
+    with routes_of("tpu"), \
             override_flag("dense_domain_limit", 1_024):
         for _ in range(2):
             res = broker.execute_script(PXL, timeout_s=180,

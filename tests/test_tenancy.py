@@ -22,6 +22,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import wait_until
 from pixie_tpu.config import override_flag
 from pixie_tpu.services import (
     AgentTracker,
@@ -452,6 +453,11 @@ def cluster():
     bus.close()
 
 
+#: Ceiling on any one wait for an event (a fragment staged, a thread
+#: joined), as ``conftest.wait_until``'s: a healthy run never comes near it.
+WAIT_S = 120.0
+
+
 def _predicted_bytes(broker, query):
     """Plan-time predicted staged bytes for one warm run of ``query``
     (admission off)."""
@@ -703,42 +709,44 @@ class TestTenantEndToEnd:
 
     def test_cancel_query_returns_partial_cancelled(self, cluster):
         bus, tracker, pems, kelvin, broker = cluster
-        # Slow the pipeline so the query is mid-flight when cancelled.
-        delay = {"s": 0.15}
+        # Hold every PEM at its first staged window until the cancel is
+        # in: the query is then mid-flight by construction (dispatched,
+        # so registered with the forwarder; no result can beat the
+        # interrupt), however slow the box is.
+        mid_flight, cancelled = threading.Event(), threading.Event()
         originals = []
         for p in pems:
             eng = p.engine
             orig = eng._staged_windows
             originals.append((eng, orig))
 
-            def slow(stream, stats=None, _orig=orig):
+            def held(stream, stats=None, _orig=orig):
                 for w in _orig(stream, stats):
-                    time.sleep(delay["s"])
+                    mid_flight.set()
+                    cancelled.wait(WAIT_S)
                     yield w
 
-            eng._staged_windows = slow
+            eng._staged_windows = held
         out = {}
 
         def run():
             try:
-                out["res"] = broker.execute_script(VICTIM_Q, timeout_s=30)
+                out["res"] = broker.execute_script(VICTIM_Q, timeout_s=WAIT_S)
             except Exception as e:  # noqa: BLE001 - recorded for assert
                 out["err"] = e
 
         t = threading.Thread(target=run)
         t.start()
         try:
-            qid = None
-            deadline = time.time() + 5
-            while time.time() < deadline and qid is None:
-                inflight = broker.tracer.in_flight()
-                qid = next(
-                    (q.get("qid") for q in inflight if q.get("qid")), None
-                )
-                time.sleep(0.01)
-            assert qid, "query never became visible in-flight"
+            assert mid_flight.wait(WAIT_S), (
+                f"no PEM ever staged a window: {out.get('err')}")
+            qid = next(
+                (q.get("qid") for q in broker.tracer.in_flight()
+                 if q.get("qid")), None,
+            )
+            assert qid, "a dispatched query is not visible in-flight"
             assert broker.cancel_query(qid) is True
-            t.join(10.0)
+            t.join(WAIT_S)
             assert not t.is_alive()
             res = out.get("res")
             assert res is not None, f"cancel errored: {out.get('err')}"
@@ -746,10 +754,10 @@ class TestTenantEndToEnd:
             assert res["interrupted"] == "cancelled"
             assert set(res["missing_reasons"].values()) == {"cancelled"}
         finally:
-            delay["s"] = 0.0
+            cancelled.set()
             for eng, orig in originals:
                 eng._staged_windows = orig
-            t.join(10.0)
+            t.join(WAIT_S)
         # cancel of an unknown qid is a clean no-op.
         assert broker.cancel_query("nonexistent") is False
 
@@ -762,31 +770,40 @@ class TestTenantEndToEnd:
         eng = kelvin.engine
         orig, wr = eng._staged_windows, eng.window_rows
         windows = {"n": 0}
-        in_merge = threading.Event()
+        # The merge parks at its first window until the gate opens (open
+        # from the start for the reference run), so the cancel reaches a
+        # merge that is running and has windows left, on any box.
+        in_merge, gate = threading.Event(), threading.Event()
 
-        def slow(stream, stats=None, _orig=orig):
+        def counted(stream, stats=None, _orig=orig):
             for w in _orig(stream, stats):
                 windows["n"] += 1
                 in_merge.set()
-                time.sleep(0.15)
+                gate.wait(WAIT_S)
                 yield w
 
-        eng._staged_windows = slow
+        eng._staged_windows = counted
         eng.window_rows = 1
         out = {}
 
         def run(key):
             try:
-                out[key] = broker.execute_script(VICTIM_Q, timeout_s=30)
+                out[key] = broker.execute_script(VICTIM_Q, timeout_s=WAIT_S)
             except Exception as e:  # noqa: BLE001 - recorded for assert
                 out[key + "_err"] = e
 
-        # Uncancelled reference run: how many slowed windows a full
-        # merge folds (the data tier is untouched, so every window
-        # counted here is merge-tier work).
+        def merge_state(qid):
+            """(the Kelvin holds the cancel, its merge is still running)"""
+            with kelvin._lock:
+                return qid in kelvin._cancelled, qid in kelvin._running
+
+        # Uncancelled reference run: how many windows a full merge folds
+        # (the data tier is untouched, so every window counted here is
+        # merge-tier work).
+        gate.set()
         t = threading.Thread(target=run, args=("full",))
         t.start()
-        t.join(30.0)
+        t.join(WAIT_S)
         try:
             assert not t.is_alive() and "full" in out, out.get("full_err")
             full_windows = windows["n"]
@@ -794,25 +811,26 @@ class TestTenantEndToEnd:
 
             windows["n"] = 0
             in_merge.clear()
+            gate.clear()
             t = threading.Thread(target=run, args=("cancelled",))
             t.start()
-            assert in_merge.wait(15.0), "merge never started"
-            qid = None
-            deadline = time.time() + 5
-            while time.time() < deadline and qid is None:
-                qid = next(
-                    (q.get("qid") for q in broker.tracer.in_flight()
-                     if q.get("qid")), None,
-                )
-                time.sleep(0.01)
-            assert qid, "query never became visible in-flight"
+            assert in_merge.wait(WAIT_S), "merge never started"
+            qid = next(
+                (q.get("qid") for q in broker.tracer.in_flight()
+                 if q.get("qid")), None,
+            )
+            assert qid, "a merging query is not visible in-flight"
             assert broker.cancel_query(qid) is True
-            t.join(10.0)
+            t.join(WAIT_S)
             assert not t.is_alive()
-            # The merge must actually STOP: give a (buggy)
-            # run-to-completion merge time to fold its remaining
-            # windows, then check it didn't.
-            time.sleep(full_windows * 0.15 + 0.5)
+            # The merge must actually STOP: once the Kelvin holds the
+            # cancel, let the parked merge go on, wait until it has ended
+            # one way or the other, and see that it folded less than all.
+            wait_until(lambda: merge_state(qid)[0],
+                        "the cancel never reached the Kelvin")
+            gate.set()
+            wait_until(lambda: not merge_state(qid)[1],
+                        "the cancelled merge never ended")
             assert windows["n"] < full_windows, (
                 f"merge folded all {windows['n']} windows after cancel"
             )
@@ -821,9 +839,10 @@ class TestTenantEndToEnd:
             assert res["partial"] is True
             assert res["interrupted"] == "cancelled"
         finally:
+            gate.set()
             eng._staged_windows = orig
             eng.window_rows = wr
-            t.join(10.0)
+            t.join(WAIT_S)
 
 
 class TestLoadTesterKwargs:

@@ -253,9 +253,10 @@ def test_pallas_int_fold_on_tpu(tpu, g):
 
 @pytest.mark.parametrize("script", ["px/http_stats", "px/service_stats"])
 def test_shipped_scripts_reach_the_int_kernel_on_tpu(tpu, script):
-    """At the default flags ('auto') the shipped scripts' fold programs
-    hold the integer kernel, say so on their spans, and answer as the
-    XLA fold ('off') does on the same chip, bit for bit."""
+    """As the chip routes them the shipped scripts' fold programs hold
+    the integer kernel, say so on their spans, and answer as the XLA fold
+    (the CPU's routes, run here) does on the same chip, bit for bit."""
+    from conftest import routes_of
     from pixie_tpu.config import override_flag
     from pixie_tpu.exec.engine import Engine
     from pixie_tpu.ingest.replay import gen_http_events
@@ -266,8 +267,9 @@ def test_shipped_scripts_reach_the_int_kernel_on_tpu(tpu, script):
         eng.append_data("http_events", chunk)
     pxl = load_script(script).pxl
 
-    def run(mode):
-        with override_flag("pallas_dense_fold", mode):
+    def run(platform):
+        with routes_of(platform), \
+                override_flag("cpu_fold_threads", 1):  # not the native fold
             out = eng.execute_query(pxl)["output"].to_pydict()
         folds = {sp.attributes.get("fold") for sp in eng.tracer.last().spans
                  if sp.name == "device.dispatch"
@@ -276,8 +278,8 @@ def test_shipped_scripts_reach_the_int_kernel_on_tpu(tpu, script):
         order = np.lexsort([np.asarray(out[k]) for k in keys])
         return {k: np.asarray(v)[order] for k, v in out.items()}, folds
 
-    on, folds = run("auto")
-    off, off_folds = run("off")
+    on, folds = run("tpu")
+    off, off_folds = run("cpu")
     assert off_folds == {"xla"}
     assert len(folds) == 1 and "pallas_int" in next(iter(folds))
     for k in on:
@@ -301,9 +303,10 @@ def test_dense_domain_groupby_on_tpu(tpu):
 
 def test_pallas_engine_fold_matches_xla_on_tpu(tpu):
     """r5: the production agg path routes FLOAT64 dense folds through
-    the Pallas kernel on TPU ('auto'); results must match the XLA fold
-    on the same chip (VERDICT r5 item 2 hardware equivalence)."""
-    from pixie_tpu.config import set_flag
+    the Pallas kernel on TPU; results must match the XLA fold (the CPU's
+    routes) on the same chip (VERDICT r5 item 2 hardware equivalence)."""
+    from conftest import routes_of
+    from pixie_tpu.config import override_flag
     from pixie_tpu.exec.engine import Engine
     from pixie_tpu.types.batch import HostBatch
     from pixie_tpu.types.dtypes import DataType
@@ -321,9 +324,9 @@ def test_pallas_engine_fold_matches_xla_on_tpu(tpu):
          "out = df.groupby('svc').agg(n=('v', px.count), s=('v', px.sum),"
          " mx=('v', px.max))\npx.display(out)")
 
-    def run(mode):
-        set_flag("pallas_dense_fold", mode)
-        try:
+    def run(platform):
+        with routes_of(platform), \
+                override_flag("cpu_fold_threads", 1):  # not the native fold
             eng = Engine(window_rows=1 << 15)
             eng.append_data("t", HostBatch(relation=rel, cols={
                 "time_": (np.arange(n, dtype=np.int64),),
@@ -333,13 +336,11 @@ def test_pallas_engine_fold_matches_xla_on_tpu(tpu):
             t0 = time.perf_counter()
             out = eng.execute_query(q)["output"].to_pydict()
             return out, time.perf_counter() - t0
-        finally:
-            set_flag("pallas_dense_fold", "auto")
 
     rng_codes = rng.integers(0, len(svcs), n).astype(np.int32)
     vals = rng.random(n) * 1000
-    pallas, dt_p = run("auto")  # TPU backend: auto engages the kernel
-    xla, dt_x = run("off")
+    pallas, dt_p = run("tpu")
+    xla, dt_x = run("cpu")
     op, ox = np.argsort(pallas["svc"]), np.argsort(xla["svc"])
     assert list(np.array(pallas["svc"])[op]) == list(np.array(xla["svc"])[ox])
     np.testing.assert_array_equal(pallas["n"][op], xla["n"][ox])
@@ -351,7 +352,7 @@ def test_pallas_engine_fold_matches_xla_on_tpu(tpu):
 def test_pallas_tdigest_hist_on_tpu(tpu):
     """The t-digest histogram kernel matches the XLA segment-sum path on
     the chip (within sketch tolerance)."""
-    from pixie_tpu.config import set_flag
+    from conftest import routes_of
     from pixie_tpu.ops.tdigest import batch_to_digest, digest_quantile
     import jax.numpy as jnp
 
@@ -361,11 +362,7 @@ def test_pallas_tdigest_hist_on_tpu(tpu):
     gids = jnp.asarray(rng.integers(0, g, n).astype(np.int32))
     mask = jnp.ones(n, dtype=bool)
 
-    set_flag("pallas_tdigest", "auto")
     pal = digest_quantile(batch_to_digest(vals, gids, mask, g), (0.5, 0.99))
-    set_flag("pallas_tdigest", "off")
-    try:
+    with routes_of("cpu"):
         ref = digest_quantile(batch_to_digest(vals, gids, mask, g), (0.5, 0.99))
-    finally:
-        set_flag("pallas_tdigest", "auto")
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref), rtol=0.05)

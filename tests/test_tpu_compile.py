@@ -264,10 +264,10 @@ def _http_stats_keyed_fragment(slots, allow_dense=True):
     folds it: every aggregate an exact integer statistic, so the rows
     ride the sort (``fold`` = ``sorted_int``), the two keys packed into
     one word; ``allow_dense=False`` is the Kelvin's fragment, which
-    sorts the key planes as they are. ``groupby_impl`` is the TPU's
-    (``sort``), whatever the backend the test runs on."""
+    sorts the key planes as they are. The routes are the TPU's,
+    whatever the backend the test runs on."""
     import pixie_tpu  # noqa: F401
-    from pixie_tpu.config import override_flag
+    from conftest import routes_of
     from pixie_tpu.exec.fragment import compile_fragment
     from pixie_tpu.exec.plan import AggExpr, AggOp, ColumnRef
     from pixie_tpu.types.dtypes import DataType
@@ -281,7 +281,7 @@ def _http_stats_keyed_fragment(slots, allow_dense=True):
     dicts = {"service": StringDictionary(f"s{i}" for i in range(32)),
              "req_path": StringDictionary(f"p{i}" for i in range(65_536))}
     lat = (ColumnRef("latency_ns"),)
-    with override_flag("groupby_impl", "sort"):
+    with routes_of("tpu"):
         frag = compile_fragment(
             [AggOp(("service", "req_path"),
                    (AggExpr("n", "count", lat), AggExpr("lat_mean", "mean", lat),
