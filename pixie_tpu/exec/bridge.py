@@ -15,15 +15,15 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..types.batch import HostBatch, bucket_capacity
-from ..types.dtypes import DataType
+from ..types.dtypes import DataType, device_dtypes
 from ..types.strings import NULL_ID, StringDictionary
 from .fragment import ColumnMeta, compile_fragment_cached as compile_fragment
 from .joins import learned_capacity
 from .plan import AggOp
 from .stream import (
+    _apply_limit,
     _device_wait,
     _dispatch,
-    _fetch_result,
     _timed,
     _NO_STATS,
     QueryError,
@@ -78,73 +78,60 @@ class _PendingAggBridge:
     payloads: list  # list[AggStatePayload]
 
 
-def _expand_dense_payload(p, group_rel, key_plane_index):
-    """Expand a dense-domain AggStatePayload to explicit key planes.
+def _live_slots(state):
+    """(idx, live, cap) of a shipped state: its live groups' count, the
+    bucket that holds them, and the slots to take so that they sit at
+    the front of ``cap`` slots (None: the state is that small already).
 
-    Dense states carry no keys (slot index IS the packed key); the merge
-    tier reconstructs them with the same unpack arithmetic the producing
-    fragment's finalize uses, so the generic realign/merge path applies.
-    """
-    import dataclasses
+    A dense state is domain-sized (up to ``dense_domain_limit`` slots)
+    and a keyed one as large as the PEM's fold, however few groups are
+    live; the merge must not inherit that capacity. Live slots compact
+    to the front, padded to a power-of-two bucket with one of the
+    state's own invalid slots (they hold uda-neutral carries by
+    construction), so the merge program's shapes stay bucketed."""
+    valid = np.asarray(state["valid"])
+    g = len(valid)
+    live = int(np.count_nonzero(valid))
+    cap = bucket_capacity(max(live, 1))
+    if cap >= g:
+        return None, live, g
+    idx = np.nonzero(valid)[0]
+    fill = int(np.nonzero(~valid)[0][0])
+    idx = np.concatenate([idx, np.full(cap - live, fill, dtype=idx.dtype)])
+    return idx, live, cap
+
+
+def _explicit_state(p, idx, key_types):
+    """A payload's state at the slots ``idx`` (``_live_slots``), with
+    explicit key planes. Dense states carry no keys (slot index IS the
+    packed key); the merge tier reconstructs them with the same unpack
+    arithmetic the producing fragment's finalize uses, for the slots it
+    keeps, so the generic realign/merge path applies."""
+    import jax
 
     from .fragment import unpack_dense_slots
 
-    doms = getattr(p, "dense_domains", ())
-    if not doms:
-        return p
-    gd = len(p.state["valid"])
-    keys = unpack_dense_slots(
-        np.arange(gd, dtype=np.int64),
-        doms,
-        [group_rel.col_type(c) for c, _i in key_plane_index],
-        np,
-        offsets=getattr(p, "dense_offsets", ()),
-        strides=getattr(p, "dense_strides", ()),
-    )
-    return dataclasses.replace(
-        p, state={**p.state, "keys": tuple(keys)}, dense_domains=(),
-        dense_offsets=(), dense_strides=(),
-    )
-
-
-def _compact_payload(p):
-    """Shrink an expanded dense-domain payload to its live slots.
-
-    A dense state is domain-sized (up to ``dense_domain_limit`` slots)
-    however few groups are live; merging every payload at that capacity
-    is a large avoidable cost for small aggregates. Live slots compact to
-    the front (padded to a power-of-two bucket with neutral invalid
-    slots, so merge-fragment compiles stay shape-bucketed).
-    """
-    import dataclasses
-
-    import jax
-
-    valid = np.asarray(p.state["valid"])
-    g = len(valid)
-    live = int(valid.sum())
-    cap = bucket_capacity(max(live, 1))
-    if cap >= g:
-        return p
-    idx = np.nonzero(valid)[0]
-    if len(idx) < cap:
-        # Invalid slots hold uda-neutral carries by construction, so any
-        # one of them is safe padding.
-        fill = int(np.nonzero(~valid)[0][0])
-        idx = np.concatenate(
-            [idx, np.full(cap - len(idx), fill, dtype=np.int64)]
-        )
+    state = p.state
+    g = len(state["valid"])
 
     def take(leaf):
         a = np.asarray(leaf)
-        return a[idx] if a.ndim and a.shape[0] == g else a
+        return a[idx] if idx is not None and a.ndim and a.shape[0] == g else a
 
-    return dataclasses.replace(p, state={
-        "keys": tuple(take(k) for k in p.state["keys"]),
-        "valid": valid[idx],
-        "carries": jax.tree_util.tree_map(take, p.state["carries"]),
-        "overflow": p.state["overflow"],
-    })
+    if p.dense_domains:
+        slots = np.arange(g, dtype=np.int64) if idx is None else idx
+        keys = tuple(unpack_dense_slots(
+            slots.astype(np.int64, copy=False), p.dense_domains, key_types,
+            np, offsets=p.dense_offsets, strides=p.dense_strides,
+        ))
+    else:
+        keys = tuple(take(k) for k in state["keys"])
+    return {
+        "keys": keys,
+        "valid": take(state["valid"]),
+        "carries": jax.tree_util.tree_map(take, state["carries"]),
+        "overflow": np.asarray(state["overflow"]),
+    }
 
 
 def payload_nbytes(p) -> int:
@@ -230,8 +217,262 @@ def bind_bridge(payloads):
     raise QueryError("mixed payload kinds on one bridge")
 
 
-def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
-    """Merge shipped partial-agg states and finalize.
+# -- the Kelvin's merge -------------------------------------------------------
+# Per request the merge tier does only what depends on the values the
+# PEMs sent: which slots are live, one upload of the compacted states,
+# one program, one fetch. What depends on the plan and the dictionaries
+# alone is prepared once and remembered by content (``_PreparedMerge``,
+# an LRU on the engine): the canonical dictionaries and each payload's
+# remap into them, the merge fragment, the plan's ops after the finalize
+# node bound against the canonical dictionaries, and the one program
+# that pads, remaps, folds, finalizes and applies them. One path for
+# every number of payloads and every layout; what it does follows what
+# it observes: equal ``content_key``s, no remap; live counts, the
+# capacity; k payloads, a fold of k - 1 merges.
+
+#: Prepared merges an engine keeps (a Kelvin serves a handful of
+#: scripts; a record pins a fragment, a program and its dictionaries).
+_PREPARED_MAX = 64
+
+
+@dataclass(frozen=True)
+class _PreparedMerge:
+    """What a merge of one chain's payloads needs that the values in
+    them do not change. Keyed by the chain, the ops after the finalize
+    node, the capacity, and a payload's relation, state shapes, dense
+    layout and dictionaries' ``content_key``s, in payload order: a
+    dictionary that grows has a new key (it is append-only), the record
+    misses and is built again, and the old one ages out."""
+
+    frag: object  # the merge fragment (explicit keys), at the capacity
+    program: object  # (states, remaps) -> (planes, valid, overflow, live)
+    # A payload's remaps into the canonical dictionaries, {key plane:
+    # int32 ids on the device}; a remap that is the identity is left out
+    # (always so where the payloads' dictionaries are equal).
+    remaps: tuple
+    meta: tuple  # ColumnMeta of the answer; canonical dictionaries
+    limit: object  # the closing LimitOp's n, or None
+    key_types: tuple  # the group columns' types, a key plane
+
+
+def _dict_keys(p) -> tuple:
+    """A payload's input relation and dictionaries, by content."""
+    return (p.input_relation.items_tuple(), tuple(sorted(
+        (n, d.content_key()) for n, d in p.input_dicts.items()
+    )))
+
+
+def _prepared_merge(engine, payloads, tail, sigs, slots: int, chain_key):
+    """(record, "hit" | "miss") for these payloads at ``slots``;
+    ``chain_key`` is the chain's capacity key (its ops with the AggOp's
+    capacity taken out: ``_agg_capacity_key``)."""
+    from ..ops.routes import routes_platform
+
+    key = (
+        chain_key, tuple(tail), slots, routes_platform(),
+        tuple(
+            (_dict_keys(p), sig, p.dense_domains, p.dense_offsets,
+             p.dense_strides)
+            for p, sig in zip(payloads, sigs)
+        ),
+    )
+    try:
+        if chain_key is None:
+            raise TypeError
+        hash(key)
+    except TypeError:  # a chain that does not hash is prepared each time
+        return _prepare_merge(engine, payloads, tail, slots, None), "miss"
+    cache, lock = engine._prepared_merges, engine._prepared_merges_lock
+    with lock:
+        rec = cache.get(key)
+        if rec is not None:
+            cache.move_to_end(key)
+            return rec, "hit"
+    # Built outside the lock (merges of different queries run
+    # concurrently on one Kelvin, and a build compiles); the loser of a
+    # duplicate miss adopts the winner's record.
+    rec = _prepare_merge(engine, payloads, tail, slots, key)
+    with lock:
+        raced = cache.get(key)
+        if raced is not None:
+            return raced, "miss"
+        cache[key] = rec
+        while len(cache) > _PREPARED_MAX:
+            cache.popitem(last=False)
+    return rec, "miss"
+
+
+def _prepare_merge(engine, payloads, tail, slots: int, key) -> _PreparedMerge:
+    import jax
+
+    from .fragment import _bind_post_stage, _bind_pre_stage, _split_chain
+    from .programs import default_program_registry
+
+    p0 = payloads[0]
+    pre = _split_chain(list(p0.chain))[0]
+    post, _agg, _post, limit = _split_chain(list(tail))
+    # The merge fragment is compiled WITHOUT dense mode: agents encode
+    # against their own dictionaries, so dense slot spaces are not
+    # comparable across payloads; states arrive here with explicit key
+    # planes (``_explicit_state``) and realign through the keyed path.
+    frag = compile_fragment(
+        _with_agg_groups(p0.chain, slots), p0.input_relation,
+        dict(p0.input_dicts), engine.registry, allow_dense=False,
+    )
+    group_rel = frag.group_relation
+    # String ids inside a CARRY (not a group key) cannot be realigned
+    # after the fact; refuse unless every agent encoded from equal
+    # dictionaries (keys only are realigned here: the reference ships
+    # raw strings over GRPC instead).
+    for out_name, src_cols in frag.string_carry_sources:
+        for c in src_cols:
+            if len({
+                d.content_key() if d is not None else None
+                for d in (p.input_dicts.get(c) for p in payloads)
+            }) > 1:
+                raise QueryError(
+                    f"aggregate {out_name!r} carries string ids "
+                    f"of column {c!r} across agents whose "
+                    "dictionaries disagree; results would be "
+                    "garbage. Share one dictionary or aggregate "
+                    "after merge."
+                )
+    # Per-agent post-pre-stage dictionaries of the group columns (bound
+    # once a distinct set of input dictionaries).
+    bound: dict = {}
+    agent_dicts = []
+    for p in payloads:
+        k = _dict_keys(p)
+        if k not in bound:
+            _, rel1, dicts1 = _bind_pre_stage(
+                pre, p.input_relation, dict(p.input_dicts), engine.registry
+            )
+            if tuple(rel1.items()) != tuple(group_rel.items()):
+                raise QueryError(
+                    f"bridge schema mismatch: {rel1} vs {group_rel}"
+                )
+            bound[k] = dicts1
+        agent_dicts.append(bound[k])
+    # The canonical dictionary of each string group column, and each
+    # payload's remap into it. Where every payload's dictionary has the
+    # same content the canonical dictionary IS the first one, an object
+    # that lives as long as this record: its digest is computed once and
+    # every cache key downstream that holds it hits without hashing.
+    canonical: dict[str, StringDictionary] = {}
+    remaps = [dict() for _ in payloads]
+    for pi, (c, i) in enumerate(frag.key_plane_index):
+        if group_rel.col_type(c) != DataType.STRING or i != 0:
+            continue
+        srcs = [dicts1.get(c) for dicts1 in agent_dicts]
+        if srcs[0] is None:
+            continue
+        if len({d.content_key() for d in srcs}) == 1:
+            canonical[c] = srcs[0]
+            continue
+        dst = canonical[c] = StringDictionary()
+        for remap, src in zip(remaps, srcs):
+            ids = np.fromiter(
+                (dst.get_or_add(s) for s in src.strings),
+                dtype=np.int32, count=len(src),
+            )
+            if len(ids) and np.array_equal(ids, np.arange(len(ids))):
+                continue  # the first payload's, and any prefix of it
+            # Padded to a bucket with the null id, so that a dictionary
+            # that grows inside its bucket asks for no new program; an
+            # empty dictionary (an agent with no rows) maps all to null.
+            padded = np.full(bucket_capacity(len(ids)), NULL_ID, np.int32)
+            padded[:len(ids)] = ids
+            remap[pi] = jax.device_put(padded)
+    apply_tail, meta, _rel = _bind_post_stage(
+        post,
+        [
+            ColumnMeta(m.name, m.dtype, dict=canonical[m.name])
+            if m.name in canonical else m
+            for m in frag.out_meta
+        ],
+        engine.registry,
+    )
+    program = _merge_program(frag, apply_tail, meta)
+    if key is not None:
+        program = default_program_registry().wrap(
+            program, "merge_finalize", (key, "merge_finalize"),
+            ",".join(type(o).__name__ for o in (*p0.chain, *tail)),
+            pins=(tuple(canonical.values()), engine.registry),
+        )
+    return _PreparedMerge(
+        frag=frag, program=program, remaps=tuple(remaps),
+        meta=tuple(meta), limit=limit,
+        key_types=tuple(
+            group_rel.col_type(c) for c, _i in frag.key_plane_index
+        ),
+    )
+
+
+def _merge_program(frag, apply_tail, meta):
+    """The one program of a prepared merge: each state as it arrived
+    (compacted, explicit keys) padded into ``frag``'s neutral slots,
+    string key ids remapped where there is a remap, the k - 1 merges
+    folded, the finalize, the plan's ops after it. Returns the planes
+    the host batch reads, their validity, the overflow flag and the
+    union's live groups."""
+    import jax
+    import jax.numpy as jnp
+
+    def pad(a, i):
+        a = jnp.asarray(a, i.dtype)
+        if a.ndim == 0 or a.shape[0] >= i.shape[0]:
+            return a
+        return jnp.concatenate([a, i[a.shape[0]:]])
+
+    def merge_finalize(states, remaps):
+        init = frag.init_state()
+        padded = []
+        for s, remap in zip(states, remaps):
+            keys = list(s["keys"])
+            for pi, table in remap.items():
+                ids = keys[pi]
+                keys[pi] = jnp.where(
+                    ids >= 0, table[jnp.clip(ids, 0, table.shape[0] - 1)],
+                    NULL_ID,
+                ).astype(jnp.int32)
+            padded.append(jax.tree_util.tree_map(
+                pad, {**s, "keys": tuple(keys)}, init
+            ))
+        # The fold of the k - 1 merges as a scan over the stacked
+        # states: one merge body in the program whatever k is (a keyed
+        # merge takes the chip's compiler most of a minute), and an
+        # empty scan for one payload.
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+        acc, _ = jax.lax.scan(
+            lambda acc, s: (frag.merge_states(acc, s), None),
+            jax.tree_util.tree_map(lambda x: x[0], stacked),
+            jax.tree_util.tree_map(lambda x: x[1:], stacked),
+        )
+        live = jnp.sum(acc["valid"], dtype=jnp.int32)
+        cols, valid, overflow = frag.finalize_state(acc)
+        cols, valid = apply_tail(cols, valid)
+        # The planes the host batch reads, each in its column's device
+        # dtype, as a batch staged for a fragment of the plan's ops has
+        # them (a FLOAT64 plane is f32 on the device: the mean is
+        # rounded once, into its result plane).
+        planes = {
+            m.name: (cols[m.name][0],) if m.struct_fields is not None
+            else tuple(
+                p.astype(dt)
+                for p, dt in zip(cols[m.name], device_dtypes(m.dtype))
+            )
+            for m in meta
+        }
+        return planes, valid, overflow, live
+
+    return jax.jit(merge_finalize)
+
+
+def merge_agg_bridge(engine, pending: _PendingAggBridge,
+                     tail=()) -> HostBatch:
+    """Merge shipped partial-agg states, finalize, and apply ``tail``:
+    the plan's Map / Filter ops and closing Limit after the finalize
+    node.
 
     The agent-mode replacement for the on-mesh collective: states from
     k agents fold through the fragment's associative merge, after the
@@ -239,118 +480,22 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
     canonical dictionary (the reference ships raw strings over GRPC,
     so alignment is implicit there; here ids must be reconciled).
     """
-    from .fragment import _bind_pre_stage, _split_chain
-    from ..types.dtypes import device_dtypes
+    import jax
 
-    p0 = pending.payloads[0]
-    # The merge-and-finalize half records onto the query's trace spine
-    # as a fragment of its own: its programs and its wait are spans.
+    from ..config import get_flag
+    from .programs import shape_signature
+    from .joins import remember_capacity
+
+    payloads = pending.payloads
+    p0 = payloads[0]
+    # The merge records onto the query's trace spine as ONE fragment:
+    # one program's dispatch, one wait.
     qstats = getattr(engine, "_query_stats", None)
-    stats = qstats.new_fragment(p0.chain) if qstats is not None else None
-    # The merge fragment is compiled WITHOUT dense mode: agents encode
-    # against their own dictionaries, so dense slot spaces are not
-    # comparable across payloads — expand each dense state to explicit
-    # key planes (then compact to live slots: a dense state is
-    # domain-sized regardless of how few groups are live, and the
-    # merge must not inherit that capacity) and realign through the
-    # generic (sort-space) path. The group relation / key planes come
-    # from binding the pre-stage directly — no compile needed before
-    # the payload sizes are known.
-    pre0, agg0, _post0, _limit0 = _split_chain(list(p0.chain))
-    _, rel1, _ = _bind_pre_stage(
-        pre0, p0.input_relation, dict(p0.input_dicts), engine.registry
+    stats = (
+        qstats.new_fragment((*p0.chain, *tail)) if qstats is not None
+        else None
     )
-    key_plane_index = tuple(
-        (c, i)
-        for c in agg0.group_cols
-        for i in range(len(device_dtypes(rel1.col_type(c))))
-    )
-    group_rel = rel1
-    pending = _PendingAggBridge(payloads=[
-        _compact_payload(_expand_dense_payload(p, rel1, key_plane_index))
-        for p in pending.payloads
-    ])
-    p0 = pending.payloads[0]
-    # Merge at the largest payload capacity (smaller states pad with
-    # neutral slots below); overflow rebucketing grows it if the
-    # union of live groups spills.
-    g = max(
-        op.max_groups
-        for p in pending.payloads
-        for op in p.chain
-        if isinstance(op, AggOp)
-    )
-    g = max([g] + [len(p.state["valid"]) for p in pending.payloads])
-    # ... or the rung an earlier merge of this chain settled on: the
-    # union of the agents' groups is the Kelvin's to observe.
-    cap_key = _agg_capacity_key(p0.chain, "(bridge)", "kelvin")
-    g = max(g, learned_capacity(engine, cap_key) or 0)
-    chain = _with_agg_groups(p0.chain, g)
-    frag = compile_fragment(
-        chain, p0.input_relation, dict(p0.input_dicts), engine.registry,
-        allow_dense=False,
-    )
-    if frag.string_carry_sources and len(pending.payloads) > 1:
-        # String ids inside a CARRY (not a group key) cannot be
-        # realigned after the fact; reject unless every agent encoded
-        # from the very same dictionary objects (keys only are realigned
-        # here — reference ships raw strings over GRPC instead).
-        for out_name, src_cols in frag.string_carry_sources:
-            for c in src_cols:
-                d0 = pending.payloads[0].input_dicts.get(c)
-                s0 = list(d0.strings) if d0 is not None else None
-                for p in pending.payloads[1:]:
-                    d = p.input_dicts.get(c)
-                    same = (
-                        d is d0
-                        or (d is not None and s0 is not None
-                            and list(d.strings) == s0)
-                    )
-                    if not same:
-                        raise QueryError(
-                            f"aggregate {out_name!r} carries string ids "
-                            f"of column {c!r} across agents whose "
-                            "dictionaries disagree; results would be "
-                            "garbage. Share one dictionary or aggregate "
-                            "after merge."
-                        )
-    # Per-agent post-pre-stage dictionaries for the group columns.
-    per_agent_dicts = []
-    for p in pending.payloads:
-        _, rel1_a, dicts1 = _bind_pre_stage(
-            pre0, p.input_relation, dict(p.input_dicts), engine.registry
-        )
-        if tuple(rel1_a.items()) != tuple(group_rel.items()):
-            raise QueryError(
-                f"bridge schema mismatch: {rel1_a} vs {group_rel}"
-            )
-        per_agent_dicts.append(dicts1)
-    # Canonical dictionary + id remap per string group column.
-    canonical: dict[str, StringDictionary] = {}
-    states = []
-    for p, dicts1 in zip(pending.payloads, per_agent_dicts):
-        keys = list(p.state["keys"])
-        for pi, (c, i) in enumerate(key_plane_index):
-            if group_rel.col_type(c) != DataType.STRING or i != 0:
-                continue
-            src = dicts1.get(c)
-            if src is None:
-                continue
-            dst = canonical.setdefault(c, StringDictionary())
-            remap = np.fromiter(
-                (dst.get_or_add(s) for s in src.strings),
-                dtype=np.int32,
-                count=len(src),
-            )
-            ids = np.asarray(keys[pi])
-            if len(remap) == 0:
-                # Empty dictionary (agent had no rows): every slot is
-                # already the null id — nothing to remap.
-                keys[pi] = np.full_like(ids, NULL_ID, dtype=np.int32)
-            else:
-                keys[pi] = np.where(
-                    ids >= 0, remap[np.clip(ids, 0, None)], NULL_ID
-                ).astype(np.int32)
+    for p in payloads:
         if bool(np.asarray(p.state["overflow"])):
             # Lost groups at the source cannot be recovered here; the
             # producing agent rebuckets before shipping (bridge_payload).
@@ -358,19 +503,43 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
                 "bridge payload arrived with group overflow; producing "
                 "agent failed to rebucket"
             )
-        states.append({**p.state, "keys": tuple(keys)})
+    parts = [_live_slots(p.state) for p in payloads]
+    sigs = [
+        (shape_signature(p.state), cap)
+        for p, (_idx, _live, cap) in zip(payloads, parts)
+    ]
+    # The capacity: the bucket of what the payloads hold live (their
+    # union cannot spill it), or the bucket an earlier merge of this
+    # chain saw the union fit where that is smaller: the union of the
+    # agents' groups is the Kelvin's to observe. A union that outgrows
+    # a remembered bucket overflows, doubles and refolds.
+    cap_key = _agg_capacity_key(p0.chain, "(bridge)", "kelvin")
+    known = learned_capacity(engine, cap_key)
+    g = bucket_capacity(sum(live for _idx, live, _cap in parts))
+    if known is not None and known < g:
+        g = max(known, max(cap for _idx, _live, cap in parts))
     climbed = False
     retry = _NO_STATS  # a ``rebucket`` span from the 2nd attempt on
     while True:
-        # Pad smaller states into g neutral slots, fold-merge, and on
-        # merged-distinct overflow double g and retry from the (still
-        # intact) original states.
+        # The merge is one program: its one boundary a cancel can stop
+        # it at is before an attempt's dispatch.
+        engine._check_cancel()
         with retry:
-            cols, valid, overflowed = _merge_padded(frag, states, stats)
+            rec, prepared = _prepared_merge(
+                engine, payloads, tail, sigs, g, cap_key
+            )
+            states = [
+                _explicit_state(p, idx, rec.key_types)
+                for p, (idx, _live, _cap) in zip(payloads, parts)
+            ]
+            with _dispatch(stats, rec.program, "finalize") as span:
+                if span is not None:
+                    span.attributes.update(prepared=prepared, slots=g)
+                out = rec.program(jax.device_put(states), rec.remaps)
+            with _device_wait(stats):
+                cols, valid, overflowed, live = jax.device_get(out)
         if not overflowed:
             break
-        from ..config import get_flag
-
         if g * 2 > get_flag("max_groups_limit"):
             raise QueryError(
                 f"group-by overflow merging bridge states at "
@@ -381,50 +550,14 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge) -> HostBatch:
         retry = _rebucket(stats, g, g * 2, "kelvin")
         g *= 2
         climbed = True
-        chain = _with_agg_groups(chain, g)
-        frag = compile_fragment(
-            chain, p0.input_relation, dict(p0.input_dicts), engine.registry,
-            allow_dense=False,  # states carry explicit key planes
-        )
-    meta = [
-        (
-            ColumnMeta(m.name, m.dtype, dict=canonical[m.name])
-            if m.name in canonical
-            else m
-        )
-        for m in frag.out_meta
-    ]
-    with _device_wait(stats):
-        cols, valid = _fetch_result(meta, cols, valid)
+    held = bucket_capacity(int(live))
     if climbed:
-        _remember_climb(engine, p0.chain, "(bridge)", "kelvin", frag)
+        _remember_climb(engine, p0.chain, "(bridge)", "kelvin", rec.frag)
+    elif held > (known or 0):
+        remember_capacity(engine, cap_key, held)
     with _timed(stats, "materialize"):
-        return _to_host_batch(meta, cols, valid)
-
-
-def _merge_padded(frag, states, stats):
-    """One attempt of the Kelvin's merge at ``frag``'s capacity: smaller
-    states padded into its neutral slots and folded through its
-    associative merge. Returns (finalized cols, valid, overflowed)."""
-    import jax
-    import jax.numpy as jnp
-
-    init = frag.init_state()
-
-    def pad(a, i):
-        a = jnp.asarray(a)
-        if a.ndim == 0 or a.shape[0] >= i.shape[0]:
-            return a
-        return jnp.concatenate([a, i[a.shape[0]:]])
-
-    merge = jax.jit(frag.merge_states)
-    padded = [jax.tree_util.tree_map(pad, s, init) for s in states]
-    acc = padded[0]
-    for s in padded[1:]:
-        with _dispatch(stats, frag.merge_states):
-            acc = merge(acc, s)
-    with _dispatch(stats, frag.finalize, "finalize"):
-        cols, valid, overflow = frag.finalize(acc)
-    with _device_wait(stats):
-        overflowed = bool(overflow)
-    return cols, valid, overflowed
+        out = _apply_limit(_to_host_batch(rec.meta, cols, valid), rec.limit)
+    if stats is not None:
+        stats.rows_in = sum(n for _idx, n, _cap in parts)
+        stats.rows_out = out.length
+    return out

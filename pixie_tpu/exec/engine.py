@@ -24,6 +24,7 @@ for compatibility.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 import time
@@ -37,8 +38,6 @@ from .bridge import (  # noqa: F401  (re-exported)
     AggStatePayload,
     RowsPayload,
     _PendingAggBridge,
-    _compact_payload,
-    _expand_dense_payload,
     bind_bridge,
     bridge_payload,
     merge_agg_bridge,
@@ -191,6 +190,24 @@ class DeviceResult:
         return self.to_host().to_pydict(**kw)
 
 
+def _agg_tail(plan: Plan, nid: int) -> tuple:
+    """(node ids, last id) of the Map / Filter ops and the closing Limit
+    that follow node ``nid`` and nothing else: the rest of its fragment,
+    up to the next blocking op or sink (a limit terminates a fragment;
+    a node read twice, or one that reads two, ends it)."""
+    tail, last = [], nid
+    while True:
+        readers = [n for n in plan.nodes.values() if last in n.inputs]
+        if len(readers) != 1 or not isinstance(
+            readers[0].op, (MapOp, FilterOp, LimitOp)
+        ):
+            return tail, last
+        last = readers[0].id
+        tail.append(last)
+        if isinstance(readers[0].op, LimitOp):
+            return tail, last
+
+
 class _QueryScratch:
     """Per-query execution state, one instance per in-flight
     ``execute_plan`` (thread-local on the engine). This is what used to
@@ -278,6 +295,15 @@ class Engine:
         # identity, so a shared cache would cross-seed engines running
         # the same script over different data.
         self._join_capacity_cache: dict = {}
+        # Prepared merges (exec/bridge.py ``_PreparedMerge``): what the
+        # Kelvin's merge of a chain's payloads needs beyond the values
+        # in them, remembered by content under a bounded LRU. Merges of
+        # different queries run concurrently on one Kelvin: the lock
+        # guards lookup and insert, never a build.
+        self._prepared_merges: collections.OrderedDict = (
+            collections.OrderedDict()
+        )
+        self._prepared_merges_lock = threading.Lock()
         # Self-telemetry (services/telemetry.py TelemetryCollector):
         # when attached, finished traces fold into __queries__/__spans__
         # tables and observed per-script cardinalities feed back into
@@ -751,7 +777,10 @@ class Engine:
                 results[nid] = r
             return r
 
+        absorbed: set = set()  # nodes a merge's program has run already
         for nid in plan.topo_order():
+            if nid in absorbed:
+                continue
             node = plan.nodes[nid]
             op = node.op
             if isinstance(op, MemorySourceOp):
@@ -786,7 +815,14 @@ class Engine:
                         raise QueryError(
                             "agg bridge must feed its finalize AggOp"
                         )
-                    results[nid] = merge_agg_bridge(self, upstream)
+                    # ... and with it the plan's ops up to the next
+                    # blocking op or sink: they run in the merge's one
+                    # program, not as a fragment over its rows.
+                    tail, last = _agg_tail(plan, nid)
+                    absorbed.update(tail)
+                    results[last] = merge_agg_bridge(
+                        self, upstream, [plan.nodes[t].op for t in tail]
+                    )
                     continue
                 st = self._as_stream(upstream)
                 if st.chain and isinstance(st.chain[-1], LimitOp):

@@ -81,6 +81,7 @@ class CompiledFragment:
     # partial-agg path, ``pixie_tpu.parallel``):
     window_state: object = None  # (cols, valid) -> per-window group state
     merge_states: object = None  # (state_a, state_b) -> merged state
+    finalize_state: object = None  # ``finalize`` before jit (the merge tier)
     # Dense fragments whose aggregates are all count/sum/mean/min/max
     # expose the native-fold seam: {"inputs_jit": (cols, valid) ->
     # (gids, per-agg args, oob), "plan": ((out_name, uda_name, init),...)}.
@@ -1092,6 +1093,50 @@ def _id_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     )
 
 
+def _bind_post_stage(post, out_meta, registry):
+    """Bind the Map / Filter ops that follow an aggregate against the
+    non-struct view of its output (``out_meta``: group columns, then
+    aggregates). Returns (apply, final_meta, relation): ``apply(cols,
+    valid)`` takes the aggregate's finalized planes through the ops.
+
+    Struct planes never flow through device post-ops (the planner fuses
+    pluck(quantiles(...)) into _quantile_* UDAs instead). Post filters
+    keep all columns, so struct columns survive them; a post MapOp is a
+    full projection and cannot reference struct columns (binding against
+    the non-struct view raises). Shared by a fragment's own finalize and
+    the Kelvin's merge program (``exec/bridge.py``), which binds the
+    plan's ops after the finalize node against the canonical
+    dictionaries of the merged keys."""
+    struct_cols = {m.name for m in out_meta if m.struct_fields is not None}
+    post_rel = Relation(
+        [(m.name, m.dtype) for m in out_meta if m.name not in struct_cols]
+    )
+    post_dicts = {m.name: m.dict for m in out_meta if m.dict is not None}
+    apply_post, post_rel_out, post_dicts_out = _bind_pre_stage(
+        post, post_rel, post_dicts, registry
+    )
+    if post:
+        final_meta = [
+            ColumnMeta(n, post_rel_out.col_type(n), dict=post_dicts_out.get(n))
+            for n in post_rel_out.column_names
+        ]
+        if not any(isinstance(op, MapOp) for op in post):
+            final_meta += [m for m in out_meta if m.struct_fields is not None]
+        out_rel = post_rel_out
+    else:
+        final_meta = out_meta
+        out_rel = Relation([(m.name, m.dtype) for m in out_meta])
+
+    def apply(cols, valid):
+        device_cols = {n: p for n, p in cols.items() if n not in struct_cols}
+        device_cols, valid = apply_post(device_cols, valid)
+        for s in struct_cols:
+            device_cols[s] = cols[s]
+        return device_cols, valid
+
+    return apply, final_meta, out_rel
+
+
 def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                  allow_dense=True, col_stats=None, pre_ops=()):
     for c in agg.group_cols:
@@ -1217,18 +1262,14 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                     jnp.broadcast_to(joint, valid.shape), p=_SKETCH_P,
                 )
 
-    # Output relation: group cols then agg outputs (struct sketches keep a
-    # [G, k] plane; they are host-materialized and opaque to post ops).
-    out_items = [(c, rel1.col_type(c)) for c in group_cols]
+    # Output: group cols then agg outputs (struct sketches keep a [G, k]
+    # plane; they are host-materialized and opaque to post ops).
     out_meta = [
         ColumnMeta(name=c, dtype=rel1.col_type(c), dict=dicts1.get(c))
         for c in group_cols
     ]
-    struct_cols = set()
     for ae, uda, arg_bound, _ in aggs_bound:
-        out_items.append((ae.out_name, uda.return_type))
         if uda.struct_fields:
-            struct_cols.add(ae.out_name)
             out_meta.append(
                 ColumnMeta(
                     name=ae.out_name, dtype=uda.return_type,
@@ -1240,32 +1281,9 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 uda.return_type == DataType.STRING and arg_bound
             ) else None
             out_meta.append(ColumnMeta(name=ae.out_name, dtype=uda.return_type, dict=d))
-    out_rel = Relation(out_items)
 
-    # Bind post-agg ops against the non-struct view of the output.
-    post_rel = Relation([(n, t) for n, t in out_items if n not in struct_cols])
-    post_dicts = {m.name: m.dict for m in out_meta if m.dict is not None}
-    apply_post, post_rel_out, post_dicts_out = _bind_pre_stage(
-        post, post_rel, post_dicts, registry
-    )
-    # Struct planes never flow through device post-ops (the planner fuses
-    # pluck(quantiles(...)) into _quantile_* UDAs instead). Post filters
-    # keep all columns, so struct columns survive them; a post MapOp is a
-    # full projection and cannot reference struct columns (binding against
-    # post_rel, which excludes them, raises).
-    post_has_map = any(isinstance(op, MapOp) for op in post)
-    if post:
-        final_meta = [
-            ColumnMeta(n, post_rel_out.col_type(n), dict=post_dicts_out.get(n))
-            for n in post_rel_out.column_names
-        ]
-        if not post_has_map:
-            final_meta += [m for m in out_meta if m.struct_fields is not None]
-        out_rel = post_rel_out
-    else:
-        final_meta = out_meta
+    apply_post, final_meta, out_rel = _bind_post_stage(post, out_meta, registry)
 
-    @jax.jit
     def finalize(state):
         cols = {}
         key_planes = fold.key_planes(state)
@@ -1277,11 +1295,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         for ae, uda, _, _ in aggs_bound:
             out = uda.finalize(state["carries"][ae.out_name])
             cols[ae.out_name] = (out,)
-        valid = state["valid"]
-        device_cols = {n: p for n, p in cols.items() if n not in struct_cols}
-        device_cols, valid = apply_post(device_cols, valid)
-        for s in struct_cols:
-            device_cols[s] = cols[s]
+        device_cols, valid = apply_post(cols, state["valid"])
         return device_cols, valid, state["overflow"]
 
     string_carry_sources = []
@@ -1385,7 +1399,8 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         is_agg=True,
         update=update,
         update_all=update_all,
-        finalize=finalize,
+        finalize=jax.jit(finalize),
+        finalize_state=finalize,
         init_state=init_state,
         limit=limit,
         window_state=window_state,

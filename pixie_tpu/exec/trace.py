@@ -185,6 +185,12 @@ class QueryResourceUsage:
     - ``rebuckets``     re-folds of an aggregate after a group-capacity
       overflow (``rebucket`` spans: the PEM's fold or the Kelvin's merge
       compiled again at twice the slots)
+    - ``merge_prepared_hits`` / ``merge_prepared_misses`` the Kelvin's
+      merges that found what they need beyond the payloads' values
+      (canonical dictionaries, remaps, fragment, program) remembered by
+      content, or built it (``exec/bridge.py`` ``_PreparedMerge``; the
+      ``prepared`` attr of a ``merge_finalize`` dispatch). A warm script
+      reads one hit a request
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -209,6 +215,8 @@ class QueryResourceUsage:
     wire_bytes: int = 0
     retries: int = 0
     rebuckets: int = 0
+    merge_prepared_hits: int = 0
+    merge_prepared_misses: int = 0
     skipped_windows: int = 0
     device_peak_bytes: int = 0
     freshness_lag_ms: float = 0.0
@@ -232,6 +240,7 @@ class QueryResourceUsage:
         for k in (
             "rows_in", "rows_out", "windows", "bytes_staged",
             "bytes_restaged", "wire_bytes", "retries", "rebuckets",
+            "merge_prepared_hits", "merge_prepared_misses",
             "skipped_windows",
         ):
             setattr(self, k, getattr(self, k) + int(d.get(k, 0)))
@@ -661,7 +670,13 @@ class QueryTrace:
             # map pruned before stage/decode (one add() per window).
             u.decode_ms += stages.get("decode", (0.0, 0, 0))[0] * 1e3
             u.skipped_windows += stages.get("skip", (0.0, 0, 0))[2]
-        u.rebuckets += sum(1 for s in self.spans if s.name == "rebucket")
+        for s in self.spans:
+            if s.name == "rebucket":
+                u.rebuckets += 1
+            elif s.attributes.get("prepared") == "hit":
+                u.merge_prepared_hits += 1
+            elif s.attributes.get("prepared") == "miss":
+                u.merge_prepared_misses += 1
         compile_span = next(
             (s for s in self.spans if s.name == "compile"), None
         )

@@ -1,0 +1,450 @@
+"""The Kelvin's merge (``exec/bridge.py`` ``merge_agg_bridge``) against a
+plain numpy union-and-reduce: k = 1, 2, 4 payloads x equal, overlapping
+and disjoint dictionaries x a dense PEM state, a keyed one, a digest
+carry. Every benchmark cell has ONE PEM, so that the remap is the
+identity and the fold empty there: what a cluster of PEMs, each with its
+own dictionaries, needs of the merge is held here. Each case also holds
+what the merge prepares once (``_PreparedMerge``): the first request
+misses, the second hits, calls ``StringDictionary.get_or_add`` never and
+compiles nothing.
+"""
+
+import contextlib
+import threading
+
+import numpy as np
+import pytest
+
+from pixie_tpu.exec.engine import Engine, QueryError
+from pixie_tpu.exec.plan import (
+    AggExpr, AggOp, ColumnRef as C, LimitOp, MapOp, MemorySourceOp, Plan,
+    ResultSinkOp,
+)
+from pixie_tpu.planner.distributed.splitter import Splitter
+from pixie_tpu.types.strings import StringDictionary
+
+KS = (1, 2, 4)
+DICTS = ("equal", "overlapping", "disjoint")
+LAYOUTS = ("dense", "keyed", "digest")
+ROWS = 400
+#: Integer keys too far apart for a dense domain: a keyed PEM state.
+CODES = np.array([3, 10**6 + 1, 10**9 + 7, 10**11 + 3, 10**12 + 9])
+
+_compiles = [0]
+
+
+def _on_compile(event, _secs, **_kw):
+    if event == "/jax/core/compile/backend_compile_duration":
+        _compiles[0] += 1
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_meter():
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    yield
+
+
+@contextlib.contextmanager
+def _counted_get_or_add():
+    """Calls of ``StringDictionary.get_or_add`` while the block runs."""
+    calls = [0]
+    real = StringDictionary.get_or_add
+
+    def counted(d, s):
+        calls[0] += 1
+        return real(d, s)
+
+    StringDictionary.get_or_add = counted
+    try:
+        yield calls
+    finally:
+        StringDictionary.get_or_add = real
+
+
+def _names(a: int, dicts: str) -> list:
+    """Agent ``a``'s services, in the order its dictionary learns them."""
+    if dicts == "equal":
+        return [f"svc-{i}" for i in range(12)]
+    if dicts == "overlapping":
+        return [f"svc-{i}" for i in range(3 * a, 3 * a + 6)]
+    return [f"a{a}-svc-{i}" for i in range(6)]
+
+
+def _rows(a: int, dicts: str, seed: int = 0, rows: int = ROWS) -> dict:
+    rng = np.random.default_rng(1000 * seed + a)
+    names = _names(a, dicts)
+    # Every name once, in order, so that equal name lists give equal
+    # dictionaries; then a skewed draw.
+    svc = names + [names[i] for i in
+                   rng.zipf(1.5, rows - len(names)) % len(names)]
+    return {
+        "time_": np.arange(rows, dtype=np.int64),
+        "svc": svc,
+        "code": CODES[rng.integers(0, len(CODES), rows)],
+        "lat": rng.integers(1, 10**9, rows).astype(np.int64),
+    }
+
+
+def _agent(rows: dict) -> Engine:
+    eng = Engine(window_rows=1 << 10)
+    eng.append_data("t", rows)
+    return eng
+
+
+def _split(layout: str, limit: int = 10_000):
+    keys = ("svc", "code") if layout == "keyed" else ("svc",)
+    if layout == "digest":
+        aggs = (AggExpr("n", "count", (C("lat"),)),
+                AggExpr("p50", "_quantile_p50", (C("lat"),)))
+    else:
+        aggs = (AggExpr("n", "count", (C("lat"),)),
+                AggExpr("total", "sum", (C("lat"),)),
+                AggExpr("worst", "max", (C("lat"),)))
+    p = Plan()
+    src = p.add(MemorySourceOp(table="t"))
+    agg = p.add(AggOp(keys, aggs), [src])
+    # The plan's ops after the finalize node ride the merge's program.
+    out = p.add(MapOp(exprs=tuple(
+        [("service", C("svc"))] + [(k, C(k)) for k in keys[1:]]
+        + [(a.out_name, C(a.out_name)) for a in aggs]
+    )), [agg])
+    lim = p.add(LimitOp(n=limit), [out])
+    p.add(ResultSinkOp("output"), [lim])
+    return Splitter().split(p)
+
+
+def _payloads(split, agents):
+    return [e.execute_plan(split.before_blocking)[("bridge", 0)]
+            for e in agents]
+
+
+def _merge(kelvin, split, payloads) -> tuple:
+    """(rows by key, the merge's trace)."""
+    out = kelvin.execute_plan(
+        split.after_blocking, bridge_inputs={0: payloads}
+    )["output"].to_pydict()
+    cols = [c for c in out if c not in ("service", "code")]
+    keys = zip(out["service"], out["code"]) if "code" in out else out["service"]
+    got = {k: tuple(out[c][i] for c in cols) for i, k in enumerate(keys)}
+    assert len(got) == len(out["service"])  # a group appears once
+    return got, kelvin.tracer.last()
+
+
+def _reference(all_rows: list, layout: str) -> dict:
+    """The union of the agents' rows, reduced group by group in numpy."""
+    groups: dict = {}
+    for rows in all_rows:
+        for i, s in enumerate(rows["svc"]):
+            k = (s, int(rows["code"][i])) if layout == "keyed" else s
+            groups.setdefault(k, []).append(int(rows["lat"][i]))
+    if layout == "digest":
+        return {k: (len(v), float(np.median(v))) for k, v in groups.items()}
+    return {k: (len(v), sum(v), max(v)) for k, v in groups.items()}
+
+
+def _assert_equal(got: dict, want: dict, layout: str) -> None:
+    assert set(got) == set(want)
+    for k, w in want.items():
+        g = got[k]
+        if layout == "digest":
+            assert g[0] == w[0]
+            # A digest of a few rows interpolates between neighbours.
+            assert abs(g[1] - w[1]) <= 0.35 * w[1] or w[0] < 8, (k, g, w)
+        else:
+            assert tuple(int(x) for x in g) == w, k
+
+
+def _dispatches(trace) -> list:
+    return [s for s in trace.spans if s.name == "device.dispatch"]
+
+
+def _assert_prepared(trace, want: str, rebuckets: int = 0) -> None:
+    """One ``merge_finalize`` an attempt, the last one ``want``; one
+    ``device.wait`` each; nothing staged; the usage counters agree."""
+    spans = _dispatches(trace)
+    assert [s.attributes["program"] for s in spans] == (
+        ["merge_finalize"] * (1 + rebuckets)
+    )
+    assert spans[-1].attributes["prepared"] == want
+    assert spans[-1].attributes["slots"] >= 1024
+    names = [s.name for s in trace.spans]
+    assert names.count("device.wait") == 1 + rebuckets
+    assert "window.stage" not in names
+    assert names.count("rebucket") == rebuckets == trace.usage.rebuckets
+    u = trace.usage
+    assert u.merge_prepared_hits + u.merge_prepared_misses == 1 + rebuckets
+    assert (u.merge_prepared_hits if want == "hit"
+            else u.merge_prepared_misses) >= 1
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("dicts", DICTS)
+@pytest.mark.parametrize("k", KS)
+def test_merge_equals_union_and_reduce(k, dicts, layout):
+    split = _split(layout)
+    all_rows = [_rows(a, dicts) for a in range(k)]
+    payloads = _payloads(split, [_agent(r) for r in all_rows])
+    assert bool(payloads[0].dense_domains) == (layout != "keyed")
+    want = _reference(all_rows, layout)
+    kelvin = Engine()
+    got, trace = _merge(kelvin, split, payloads)
+    _assert_equal(got, want, layout)
+    _assert_prepared(trace, "miss")
+    # The second identical request: everything but the values is
+    # remembered.
+    before = _compiles[0]
+    with _counted_get_or_add() as calls:
+        got, trace = _merge(kelvin, split, payloads)
+    _assert_equal(got, want, layout)
+    _assert_prepared(trace, "hit")
+    assert calls[0] == 0
+    assert _compiles[0] == before
+    assert len(kelvin._prepared_merges) == 1
+
+
+def test_equal_dictionaries_are_not_remapped_and_others_are():
+    """Equal ``content_key``s: the canonical dictionary IS the first
+    payload's and no remap is applied; disjoint ones: every payload but
+    the first carries one."""
+    split = _split("dense")
+    for dicts, remapped in (("equal", [False, False]),
+                            ("disjoint", [False, True])):
+        agents = [_agent(_rows(a, dicts)) for a in range(2)]
+        payloads = _payloads(split, agents)
+        kelvin = Engine()
+        _merge(kelvin, split, payloads)
+        (rec,) = kelvin._prepared_merges.values()
+        assert [bool(r) for r in rec.remaps] == remapped
+        canon = next(m.dict for m in rec.meta if m.name == "service")
+        first = payloads[0].input_dicts["svc"]
+        assert (canon is first) == (dicts == "equal")
+
+
+def test_payloads_from_the_wire_hit_without_a_dictionary_loop():
+    """Decoded payloads bring fresh dictionary objects every request:
+    the record is found by content, and the answer's dictionary is the
+    record's own object."""
+    from pixie_tpu.services.wire import decode, encode
+
+    split = _split("dense")
+    all_rows = [_rows(a, "overlapping") for a in range(2)]
+    sent = [encode(p) for p in
+            _payloads(split, [_agent(r) for r in all_rows])]
+    kelvin = Engine()
+    want = _reference(all_rows, "dense")
+    dicts = []
+    for expect in ("miss", "hit", "hit"):
+        payloads = [decode(b) for b in sent]
+        with _counted_get_or_add() as calls:
+            out = kelvin.execute_plan(
+                split.after_blocking, bridge_inputs={0: payloads}
+            )["output"]
+        _assert_prepared(kelvin.tracer.last(), expect)
+        assert (calls[0] == 0) == (expect == "hit")
+        dicts.append(out.dicts["service"])
+        got = dict(zip(out.to_pydict()["service"],
+                       out.to_pydict()["n"].tolist()))
+        assert got == {k: v[0] for k, v in want.items()}
+    assert dicts[0] is dicts[1] is dicts[2]
+
+
+def test_a_dictionary_that_grows_misses_once():
+    split = _split("dense")
+    rows = _rows(0, "equal")
+    agent = _agent(rows)
+    kelvin = Engine()
+    _merge(kelvin, split, _payloads(split, [agent]))
+    _got, trace = _merge(kelvin, split, _payloads(split, [agent]))
+    _assert_prepared(trace, "hit")
+    more = {"time_": np.arange(ROWS, ROWS + 3, dtype=np.int64),
+            "svc": ["svc-new"] * 3, "code": CODES[:3],
+            "lat": np.array([5, 6, 7], dtype=np.int64)}
+    agent.append_data("t", more)
+    got, trace = _merge(kelvin, split, _payloads(split, [agent]))
+    _assert_prepared(trace, "miss")
+    assert got["svc-new"] == (3, 18, 7)
+    _assert_equal(got, _reference([rows, more], "dense"), "dense")
+    _got, trace = _merge(kelvin, split, _payloads(split, [agent]))
+    _assert_prepared(trace, "hit")
+
+
+def test_an_empty_payload_merges_as_nothing():
+    split = _split("dense")
+    rows = _rows(0, "equal")
+    empty = {"time_": np.empty(0, np.int64), "svc": np.empty(0, dtype=str),
+             "code": np.empty(0, np.int64), "lat": np.empty(0, np.int64)}
+    payloads = _payloads(split, [_agent(rows), _agent(empty)])
+    assert not payloads[1].state["valid"].any()
+    kelvin = Engine()
+    got, trace = _merge(kelvin, split, payloads)
+    _assert_equal(got, _reference([rows], "dense"), "dense")
+    _assert_prepared(trace, "miss")
+    got, trace = _merge(kelvin, split, payloads)
+    _assert_equal(got, _reference([rows], "dense"), "dense")
+    _assert_prepared(trace, "hit")
+
+
+def _wide(a: int, shared: bool) -> dict:
+    """1,000 services an agent: the same ones, or its own."""
+    names = [f"{'s' if shared else f'a{a}'}-{i}" for i in range(1000)]
+    return {"time_": np.arange(1000, dtype=np.int64), "svc": names,
+            "code": np.zeros(1000, np.int64),
+            "lat": np.arange(1, 1001, dtype=np.int64) * (a + 1)}
+
+
+def test_a_union_that_overflows_the_remembered_capacity_refolds_once():
+    """Two agents with the same 1,000 groups: the merge runs at the
+    bucket of what they hold (2,048) and remembers the bucket the union
+    fit (1,024). The same chain over disjoint groups then starts there,
+    spills, doubles once (one ``rebucket`` span) and is right; the third
+    request starts at what the climb settled on."""
+    split = _split("dense")
+    kelvin = Engine()
+    same = [_wide(a, True) for a in range(2)]
+    got, trace = _merge(kelvin, split, _payloads(split, map(_agent, same)))
+    _assert_equal(got, _reference(same, "dense"), "dense")
+    assert _dispatches(trace)[0].attributes["slots"] == 2048
+    apart = [_wide(a, False) for a in range(2)]
+    payloads = _payloads(split, map(_agent, apart))
+    got, trace = _merge(kelvin, split, payloads)
+    _assert_equal(got, _reference(apart, "dense"), "dense")
+    _assert_prepared(trace, "miss", rebuckets=1)
+    assert [s.attributes["slots"] for s in _dispatches(trace)] == [1024, 2048]
+    got, trace = _merge(kelvin, split, payloads)
+    _assert_equal(got, _reference(apart, "dense"), "dense")
+    _assert_prepared(trace, "hit")
+    assert _dispatches(trace)[0].attributes["slots"] == 2048
+
+
+def test_a_string_carry_over_disagreeing_dictionaries_is_refused():
+    p = Plan()
+    src = p.add(MemorySourceOp(table="t"))
+    agg = p.add(AggOp(("code",), (AggExpr("some", "any", (C("svc"),)),)),
+                [src])
+    p.add(ResultSinkOp("output"), [agg])
+    split = Splitter().split(p)
+    kelvin = Engine()
+    payloads = _payloads(
+        split, [_agent(_rows(a, "disjoint")) for a in range(2)]
+    )
+    for _ in range(2):  # nothing of a refused merge is remembered
+        with pytest.raises(QueryError, match="string ids"):
+            kelvin.execute_plan(
+                split.after_blocking, bridge_inputs={0: payloads}
+            )
+    assert len(kelvin._prepared_merges) == 0
+    # ... and the same carry over equal dictionaries merges.
+    payloads = _payloads(
+        split, [_agent(_rows(a, "equal")) for a in range(2)]
+    )
+    out = kelvin.execute_plan(
+        split.after_blocking, bridge_inputs={0: payloads}
+    )["output"].to_pydict()
+    assert set(out["some"]) <= set(_names(0, "equal"))
+
+
+def test_an_overflowed_payload_is_refused():
+    split = _split("dense")
+    (payload,) = _payloads(split, [_agent(_rows(0, "equal"))])
+    payload.state["overflow"] = np.asarray(True)
+    with pytest.raises(QueryError, match="group overflow"):
+        Engine().execute_plan(
+            split.after_blocking, bridge_inputs={0: [payload]}
+        )
+
+
+def test_the_limit_cuts_the_same_rows_in_the_same_order():
+    """The closing Limit is applied after the finalize, to the rows in
+    slot order, as the fragment over the merged rows applied it."""
+    rows = _rows(0, "equal")
+    (payload,) = _payloads(_split("dense"), [_agent(rows)])
+    kelvin = Engine()
+    every = kelvin.execute_plan(
+        _split("dense").after_blocking, bridge_inputs={0: [payload]}
+    )["output"].to_pydict()
+    cut = kelvin.execute_plan(
+        _split("dense", limit=5).after_blocking,
+        bridge_inputs={0: [payload]},
+    )["output"].to_pydict()
+    assert len(every["service"]) == 12
+    assert list(cut["service"]) == list(every["service"][:5])
+    assert list(cut["total"]) == list(every["total"][:5])
+
+
+def test_two_chains_merge_concurrently_on_one_kelvin():
+    kelvin = Engine()
+    jobs = []
+    for layout in ("dense", "keyed"):
+        split = _split(layout)
+        all_rows = [_rows(a, "overlapping", seed=7) for a in range(2)]
+        jobs.append((layout, split,
+                     _payloads(split, [_agent(r) for r in all_rows]),
+                     _reference(all_rows, layout)))
+    start = threading.Barrier(len(jobs))
+    errors = []
+
+    def run(layout, split, payloads, want):
+        try:
+            start.wait(timeout=60)
+            for _ in range(4):
+                got, _trace = _merge(kelvin, split, payloads)
+                _assert_equal(got, want, layout)
+        except BaseException as e:  # surfaced on the main thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=j) for j in jobs]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+    assert not errors, errors
+    assert len(kelvin._prepared_merges) == 2
+
+
+def _span_readers():
+    """``tests/benchmark/test_span_readers.py``'s rehearsed window and
+    reader (that directory is the benchmark's: read, not edited)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "benchmark",
+                        "test_span_readers.py")
+    spec = importlib.util.spec_from_file_location("_span_readers", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_served_refresh_span_shape():
+    """A served refresh of ``http_pem_1chip.dash_recent``, rehearsed: the
+    Kelvin's trace of each request holds exactly one ``device.dispatch``
+    (``merge_finalize``, ``prepared`` = ``hit``) and one ``device.wait``
+    and stages nothing; ``device_dispatches`` reads 4 a refresh (a PEM
+    fold and a merge a script); and head + device interval + tail still
+    make up the broker's root, with the parts inside what holds them."""
+    readers = _span_readers()
+    ctx = readers._window(0.05)
+    ctx["window"]["refreshes"] = ctx["window"]["refreshes"][:1]
+    (refresh,) = ctx["window"]["refreshes"]
+    merges = {t.qid: t for t in ctx["spans"]["kelvin"] if t.kind == "merge"}
+    for rec in refresh:
+        trace = merges[rec["qid"]]
+        _assert_prepared(trace, "hit")
+        assert trace.usage.merge_prepared_hits == 1
+    read = readers._read
+    assert read("device_dispatches", ctx) == 2 * (1 + 1)
+    roots = {t.qid: t for t in ctx["spans"]["broker"]}
+    root_ms = sum((roots[r["qid"]].end_ns - roots[r["qid"]].start_ns) / 1e6
+                  for r in refresh)
+    head, tail, interval = (read(m, ctx) for m in (
+        "head_ms", "tail_ms", "device_interval_ms"
+    ))
+    assert min(head, tail, interval) > 0
+    assert head + tail + interval == pytest.approx(root_ms, abs=1e-6)
+    client_ms = sum((r["t1"] - r["t0"]) * 1e3 for r in refresh)
+    assert root_ms < client_ms < root_ms + 25
+    assert read("device_wait_ms", ctx) + read("dispatch_ms", ctx) <= interval
+    assert read("merge_ms", ctx) < tail
+    assert read("broker_self_ms", ctx) < head + tail

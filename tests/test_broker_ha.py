@@ -139,8 +139,9 @@ class TestElection:
         assert r1.statusz()["replay_lag"] == 0
         assert s0["role"] == "leader" and s1["role"] == "standby"
         assert s1["leader"] == "broker-0"
-        # Released on completion — once the release event has folded.
-        assert r1.statusz()["mirror_inflight"] == 0
+        # Released on completion — once the release event has folded
+        # (the leader may publish it after the sequence read above).
+        assert _wait_for(lambda: r1.statusz()["mirror_inflight"] == 0)
         assert s0["lease_age_s"] < 1.0
 
     def test_leader_resolution_topic(self, ha_cluster):

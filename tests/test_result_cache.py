@@ -620,8 +620,13 @@ def cluster():
             "service": [f"svc-{(i + j) % 3}" for j in range(n)],
         })
         pem._register()
-    deadline = time.time() + 5
-    while time.time() < deadline and len(tracker.schemas()) < 1:
+    # Every PEM, not the first to register (the telemetry tables make
+    # ``schemas()`` non-empty at once): a plan made before the last one
+    # is known merges a part of the rows.
+    deadline = time.time() + 30
+    while time.time() < deadline and len(
+        tracker.distributed_state().pems_with_table("http_events")
+    ) < len(pems):
         time.sleep(0.01)
     broker = QueryBroker(bus, tracker)
     yield bus, tracker, pems, kelvin, broker

@@ -764,26 +764,28 @@ class TestTenantEndToEnd:
     def test_cancel_mid_merge_stops_the_merge(self, cluster):
         """query.cancel reaches a RUNNING merge fragment, not just the
         data tier: the kelvin registers its merge's cancel event under
-        the qid, so `px cancel` aborts the fold at a window boundary
-        instead of computing the whole merge as dead work."""
+        the qid, so `px cancel` aborts the merge at its boundary (since
+        PR 31 the merge is one program: before its dispatch) instead of
+        computing it as dead work."""
+        from pixie_tpu.exec import engine as engine_mod
+
         bus, tracker, pems, kelvin, broker = cluster
-        eng = kelvin.engine
-        orig, wr = eng._staged_windows, eng.window_rows
-        windows = {"n": 0}
-        # The merge parks at its first window until the gate opens (open
-        # from the start for the reference run), so the cancel reaches a
-        # merge that is running and has windows left, on any box.
+        merges = []
+        kelvin.engine.tracer.add_listener(
+            lambda t: merges.append(t) if t.kind == "merge" else None
+        )
+        real = engine_mod.merge_agg_bridge
+        # The merge parks at its start until the gate opens (open from
+        # the start for the reference run), so the cancel reaches a merge
+        # that is running and has its program left to run, on any box.
         in_merge, gate = threading.Event(), threading.Event()
 
-        def counted(stream, stats=None, _orig=orig):
-            for w in _orig(stream, stats):
-                windows["n"] += 1
-                in_merge.set()
-                gate.wait(WAIT_S)
-                yield w
+        def parked(*a, **k):
+            in_merge.set()
+            gate.wait(WAIT_S)
+            return real(*a, **k)
 
-        eng._staged_windows = counted
-        eng.window_rows = 1
+        engine_mod.merge_agg_bridge = parked
         out = {}
 
         def run(key):
@@ -797,19 +799,23 @@ class TestTenantEndToEnd:
             with kelvin._lock:
                 return qid in kelvin._cancelled, qid in kelvin._running
 
-        # Uncancelled reference run: how many windows a full merge folds
-        # (the data tier is untouched, so every window counted here is
-        # merge-tier work).
+        def dispatched(qid):
+            """Programs the Kelvin's merge of ``qid`` enqueued."""
+            wait_until(lambda: any(t.qid == qid for t in merges),
+                       "the merge's trace never finished")
+            trace = next(t for t in merges if t.qid == qid)
+            return [s.attributes["program"] for s in trace.spans
+                    if s.name == "device.dispatch"]
+
+        # Uncancelled reference run: what a full merge enqueues.
         gate.set()
         t = threading.Thread(target=run, args=("full",))
         t.start()
         t.join(WAIT_S)
         try:
             assert not t.is_alive() and "full" in out, out.get("full_err")
-            full_windows = windows["n"]
-            assert full_windows > 2, "merge never windowed; test moot"
+            assert dispatched(out["full"]["qid"]) == ["merge_finalize"]
 
-            windows["n"] = 0
             in_merge.clear()
             gate.clear()
             t = threading.Thread(target=run, args=("cancelled",))
@@ -825,23 +831,20 @@ class TestTenantEndToEnd:
             assert not t.is_alive()
             # The merge must actually STOP: once the Kelvin holds the
             # cancel, let the parked merge go on, wait until it has ended
-            # one way or the other, and see that it folded less than all.
+            # one way or the other, and see that it enqueued nothing.
             wait_until(lambda: merge_state(qid)[0],
                         "the cancel never reached the Kelvin")
             gate.set()
             wait_until(lambda: not merge_state(qid)[1],
                         "the cancelled merge never ended")
-            assert windows["n"] < full_windows, (
-                f"merge folded all {windows['n']} windows after cancel"
-            )
+            assert dispatched(qid) == [], "the merge ran after the cancel"
             res = out.get("cancelled")
             assert res is not None, f"err: {out.get('cancelled_err')}"
             assert res["partial"] is True
             assert res["interrupted"] == "cancelled"
         finally:
             gate.set()
-            eng._staged_windows = orig
-            eng.window_rows = wr
+            engine_mod.merge_agg_bridge = real
             t.join(WAIT_S)
 
 

@@ -135,7 +135,7 @@ def test_agents_traces_lie_inside_the_brokers_root(served):
 
 @pytest.mark.parametrize("who,programs", [
     ("pem", ["fragment_update"]),
-    ("kelvin", ["fragment_finalize", "fragment_update"]),
+    ("kelvin", ["merge_finalize"]),
 ])
 def test_device_spans_and_device_ms(served, who, programs):
     tr = _last(served, who)
@@ -143,8 +143,12 @@ def test_device_spans_and_device_ms(served, who, programs):
     waits = [s for s in tr.spans if s.name == "device.wait"]
     assert [d.attributes["program"] for d in dispatches] == programs
     assert all(d.attributes["windows"] == 1 for d in dispatches)
-    # An aggregate waits for its overflow flag, then for its planes.
-    assert len(waits) == {"pem": 1, "kelvin": 3}[who]
+    # The PEM waits for its state, the Kelvin's merge once for its one
+    # program's planes, validity and overflow flag.
+    assert len(waits) == {"pem": 1, "kelvin": 1}[who]
+    if who == "kelvin":
+        assert dispatches[0].attributes["prepared"] == "hit"
+        assert not [s for s in tr.spans if s.name == "window.stage"]
     frag_ids = {s.span_id for s in tr.spans if s.name == "fragment"}
     assert {s.parent_id for s in dispatches + waits} == frag_ids
     assert not [s for s in tr.spans if s.name == "window.compute"]
@@ -159,14 +163,29 @@ def test_device_spans_and_device_ms(served, who, programs):
     assert 0 < tr.usage.device_ms <= tr.duration_s * 1e3
 
 
-def test_window_spans_are_stamped_where_they_run(served):
-    tr = _last(served, "kelvin")
+def test_window_spans_are_stamped_where_they_run():
+    """A window staged on the prefetch thread is a span with that
+    thread's two stamps, and the fragment's stage timer is their sum
+    (the served path's windows are resident and the Kelvin stages
+    nothing: a bare engine without residency does)."""
+    from pixie_tpu.exec import Engine
+
+    with config.override_flag("device_residency", False):
+        eng = Engine(window_rows=1 << 10)
+        n = 1 << 10
+        eng.append_data("t", {"time_": np.arange(n, dtype=np.int64),
+                              "k": np.arange(n) % 5, "v": np.arange(n)})
+        eng.execute_query(
+            "import px\ndf = px.DataFrame(table='t')\n"
+            "df = df.groupby('k').agg(n=('v', px.count))\npx.display(df)\n"
+        )
+    tr = eng.tracer.last()
     stage = next(s for s in tr.spans if s.name == "window.stage")
     frag = next(s for s in tr.spans if s.span_id == stage.parent_id)
     assert frag.attributes["stage_seconds"] == pytest.approx(
         (stage.end_ns - stage.start_ns) / 1e9, abs=1e-5
     )
-    assert [s.name for s in tr.spans].count("materialize") == 2
+    assert [s.name for s in tr.spans].count("materialize") == 1
 
 
 def test_every_interval_of_a_short_query_is_a_span():
@@ -230,7 +249,7 @@ def test_a_profiler_session_holds_the_spans_as_annotations(served, tmp_path):
     span = next(s for s in tr.spans if s.name == "device.wait")
     # (the annotation is entered just before the first stamp and left
     # just after the second)
-    assert len(waits) == 1 + 3  # the PEM's, the Kelvin's
+    assert len(waits) == 1 + 1  # the PEM's, the Kelvin's
     assert any(0 <= w.duration_ns - (span.end_ns - span.start_ns) < 2e6
                for w in waits)
 

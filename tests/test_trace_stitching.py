@@ -70,8 +70,13 @@ def cluster():
         })
     for pem in pems:
         pem._register()
-    deadline = time.time() + 5
-    while time.time() < deadline and len(tracker.schemas()) < 1:
+    # Every PEM, not the first to register (the telemetry tables make
+    # ``schemas()`` non-empty at once): a plan made before the last one
+    # is known merges a part of the rows.
+    deadline = time.time() + 30
+    while time.time() < deadline and len(
+        tracker.distributed_state().pems_with_table("http_events")
+    ) < len(pems):
         time.sleep(0.01)
     broker = QueryBroker(bus, tracker)
     yield bus, tracker, pems, kelvin, broker
@@ -366,8 +371,12 @@ class TestAckDedup:
                 "service": ["svc-a"] * 500,
             })
             pem._register()
-        deadline = time.time() + 5
-        while time.time() < deadline and len(tracker.schemas()) < 1:
+        # Both PEMs, not the first to register: a plan made before the
+        # second is known merges 500 rows, not 1,000.
+        deadline = time.time() + 30
+        while time.time() < deadline and len(
+            tracker.distributed_state().pems_with_table("http_events")
+        ) < 2:
             time.sleep(0.01)
         broker = QueryBroker(bus, tracker)
         try:

@@ -11,6 +11,12 @@ chip would see them (the backend answers ``tpu``, the devices are a
 described ``v5e:2x2``'s) and lowers ``update``, ``update_all`` (three
 windows), ``merge_states`` and ``finalize`` at the benchmark's 2^21-row
 window; a keyed chain at the 131,072 slots ``http_full_1chip`` settles on.
+It also prepares the Kelvin's merge of each script as a request does
+(``exec/bridge.py`` ``_prepare_merge``, from the payloads the served run
+shipped: the three configurations have two sets of dictionaries, the
+four-chip one serves the one-chip one's) and lowers AND compiles its one
+program, ``merge_finalize``, at the capacity the cell's live groups give
+(2,048 / 1,024 / 65,536 / 1,024 slots), printing the compile's seconds.
 
     JAX_PLATFORMS=cpu python tools/fold_hlo.py --out DIR
 
@@ -50,7 +56,14 @@ def _capture(batches):
         AgentTracker, KelvinAgent, MessageBus, PEMAgent, QueryBroker,
     )
 
+    from pixie_tpu.exec import bridge
+
     seen, real = [], fragment.compile_fragment
+    merges, real_prepare = [], bridge._prepare_merge
+
+    def spy_prepare(engine, payloads, tail, slots, key):
+        merges.append((list(payloads), list(tail)))
+        return real_prepare(engine, payloads, tail, slots, key)
 
     def spy(ops, relation, dicts, registry, allow_dense=True, col_stats=None):
         frag = real(ops, relation, dicts, registry, allow_dense,
@@ -61,6 +74,7 @@ def _capture(batches):
         return frag
 
     fragment.compile_fragment = spy
+    bridge._prepare_merge = spy_prepare
     fragment._FRAGMENT_CACHE.clear()
     bus = MessageBus()
     tracker = AgentTracker(bus, expiry_s=60.0, check_interval_s=60.0)
@@ -79,11 +93,12 @@ def _capture(batches):
             assert not res.get("partial"), script
     finally:
         fragment.compile_fragment = real
+        bridge._prepare_merge = real_prepare
         pem.stop()
         kelvin.stop()
         tracker.close()
         bus.close()
-    return seen
+    return seen, merges
 
 
 def _wait_for_table(tracker):
@@ -123,6 +138,17 @@ def _without_kernel_locations(text):
     return re.sub(
         r'backend_config = "(\{\\22custom_call_config.*?\})"', digest, text
     )
+
+
+def _record(lines, out_dir, name, program, fold, text, **more):
+    """One program's line (printed, kept) and its text (written)."""
+    lines.append({"case": name, "program": program, "fold": fold,
+                  "sha256": hashlib.sha256(text.encode()).hexdigest()[:16],
+                  "bytes": len(text), **more})
+    print(json.dumps(lines[-1]), flush=True)
+    if out_dir:
+        with open(os.path.join(out_dir, f"{name}.{program}.txt"), "w") as f:
+            f.write(text)
 
 
 def _lower(case, captured, topo_device, out_dir, lines):
@@ -172,15 +198,55 @@ def _lower(case, captured, topo_device, out_dir, lines):
             programs["update_all"] = lambda: frag.update_all.lower(
                 state, (cols,) * 3, bounds, bounds)
         for program, lower in sorted(programs.items()):
-            text = _without_kernel_locations(lower().as_text())
-            digest = hashlib.sha256(text.encode()).hexdigest()[:16]
-            lines.append({"case": name, "program": program, "fold": frag.fold,
-                          "sha256": digest, "bytes": len(text)})
-            print(json.dumps(lines[-1]), flush=True)
-            if out_dir:
-                with open(os.path.join(out_dir, f"{name}.{program}.txt"),
-                          "w") as f:
-                    f.write(text)
+            _record(lines, out_dir, name, program, frag.fold,
+                    _without_kernel_locations(lower().as_text()))
+
+
+def _lower_merges(case, merges, topo_device, out_dir, lines):
+    """The Kelvin's ``merge_finalize`` of each script: the payloads the
+    served run shipped, their states compacted as a request compacts
+    them, at the capacity the cell's live groups give (a keyed chain:
+    ``http_full_1chip``'s 63 k groups in a 65,536-slot bucket). Lowered
+    and COMPILED for the described device: the line carries the
+    seconds."""
+    import time
+    import types
+
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from pixie_tpu.exec import bridge
+    from pixie_tpu.types.batch import bucket_capacity
+    from pixie_tpu.udf.registry import default_registry
+
+    chip = SingleDeviceSharding(topo_device)
+    engine = types.SimpleNamespace(registry=default_registry())
+    for payloads, tail in merges:
+        slots = [bridge._live_slots(p.state) for p in payloads]
+        caps = [cap for _idx, _live, cap in slots]
+        if case.startswith("keyed") and not payloads[0].dense_domains:
+            caps = [KEYED_SLOTS // 2] * len(payloads)
+        g = bucket_capacity(sum(caps))
+        rec = bridge._prepare_merge(engine, payloads, tail, g, None)
+        name = (f"{case}.kelvin.{_agg_label(payloads[0].chain)}"
+                f".k{len(payloads)}.{rec.frag.group}{g}")
+        if any(line["case"] == name for line in lines):
+            continue
+        states = [
+            jax.tree_util.tree_map(
+                lambda a, have=have, cap=cap: jax.ShapeDtypeStruct(
+                    (cap,) + a.shape[1:] if a.ndim and a.shape[0] == have
+                    else a.shape, a.dtype, sharding=chip),
+                bridge._explicit_state(p, idx, rec.key_types),
+            )
+            for p, (idx, _live, have), cap in zip(payloads, slots, caps)
+        ]
+        lowered = rec.program.lower(states, rec.remaps)
+        t0 = time.perf_counter()
+        lowered.compile()
+        _record(lines, out_dir, name, "merge_finalize", rec.frag.fold,
+                _without_kernel_locations(lowered.as_text()),
+                compile_s=round(time.perf_counter() - t0, 2))
 
 
 def main():
@@ -218,8 +284,9 @@ def main():
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     lines = []
-    for case, seen in captured.items():
+    for case, (seen, merges) in captured.items():
         _lower(case, seen, topo.devices[0], args.out, lines)
+        _lower_merges(case, merges, topo.devices[0], args.out, lines)
     return 0
 
 
