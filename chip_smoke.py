@@ -1,6 +1,6 @@
 """Chip smoke: the query path, once, on the TPU it was written for.
 
-    python chip_smoke.py            # one chip: kernel, engine, served, skew
+    python chip_smoke.py            # one chip: kernel, engine, served, skew, flow
     python chip_smoke.py --chips 4  # four chips: kernel + DistributedEngine only
 
 One process, no child that needs the chip. A seeded ``http_events``
@@ -35,6 +35,7 @@ sys.path.insert(0, REPO)
 WINDOW = 1 << 21  # one window size: one update + one finalize per query
 REHEARSAL_MAX_ROWS = 1 << 20  # what a run without a TPU may be asked for
 SKEW_ROWS = 1 << 23  # the non-dense phase: four windows at ten columns
+FLOW_ROWS = 1 << 22  # the join phase: two windows at conn_stats' fifteen
 
 SERVICES = [f"svc-{i}" for i in range(32)]
 PATHS = [f"/api/v1/ep{i}" for i in range(8)]
@@ -640,6 +641,64 @@ def phase_skew(seed: int, rows: int, meter: CompileMeter,
             ), f"{name}: expected a dense route, got {folds} {_fold_groups(eng)}"
 
 
+def phase_flow(seed: int, rows: int, meter: CompileMeter,
+               on_tpu: bool) -> None:
+    """The join: configuration ``conn_flow_1chip``'s ``conn_stats``
+    (4,096 pods x 8,192 addresses, Zipf keys, fifteen columns) at
+    ``rows`` rows through ``Engine``, the bundled px/net_flow_graph
+    against the benchmark's plain numpy reference, every number exact.
+    Two keyed group-bys ride the sort, their rows join on the address
+    strings of two dictionaries (past ``DEVICE_JOIN_MIN_ROWS`` the chip's
+    bulk route: the single-shot device kernel, by ``routes_platform``,
+    so a rehearsal takes it too), and the join's rows are aggregated
+    again; the second run compiles nothing."""
+    from benchmark.builders import served_conn
+    from benchmark.reference import px_net_flow_graph as ref
+    from pixie_tpu.exec.engine import Engine
+    from pixie_tpu.exec.joins import DEVICE_JOIN_MIN_ROWS
+    from pixie_tpu.scripts import load_script
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "conn_flow_1chip.json")) as f:
+        cfg = json.load(f)
+    t0 = time.perf_counter()
+    data = served_conn.make_data(cfg, seed, rows)
+    eng = Engine(window_rows=WINDOW)
+    for hb in served_conn.batches(data, WINDOW):
+        eng.append_data("conn_stats", hb)
+    res = _resident(eng.tables["conn_stats"])
+    emit(phase="flow", step="ingest", rows=rows,
+         secs=time.perf_counter() - t0, resident=res)
+    assert res["rows"] == rows, f"resident rows {res['rows']} != {rows}"
+    want = ref.answer(data, None)
+    pxl = load_script("px/net_flow_graph").pxl
+    for run in ("first", "warm"):
+        mark = meter.mark()
+        t0 = time.perf_counter()
+        # Every edge: the default cut is 10,000 rows a table.
+        got = eng.execute_query(pxl, max_output_rows=1 << 17)
+        secs = time.perf_counter() - t0
+        numbers = ref.numbers(ref.rows(got["output"].to_pydict()), want)
+        compiled = meter.since(mark)
+        joins = [dict(sp.attributes) for sp in eng.tracer.last().spans
+                 if sp.name == "join"]
+        emit(phase="flow", query="px/net_flow_graph", run=run, rows=rows,
+             secs=secs, edges=len(want["key"]), fold=_fold_routes(eng),
+             group=_fold_groups(eng), join=joins, compile=compiled,
+             numbers=numbers)
+        over = sorted(k for k, v in numbers.items() if v > ref.LIMITS[k])
+        assert not over, f"px/net_flow_graph ({run}): over its limit: {over}"
+    assert compiled["programs"] == 0, (
+        f"px/net_flow_graph: second run compiled {compiled['programs']} "
+        "program(s)")
+    (join,) = joins
+    bulk = join["build_rows"] + join["probe_rows"] >= DEVICE_JOIN_MIN_ROWS
+    assert join["where"] == ("device" if bulk else "host"), (
+        f"the join ran on the {join['where']}: {join}")
+    assert not on_tpu or _fold_routes(eng) == ["sorted_int"], (
+        f"fold spans say {_fold_routes(eng)}, not sorted_int")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=16 << 20,
@@ -692,6 +751,8 @@ def main(argv=None) -> int:
                 phase_engine(rp, meter, on_tpu)
                 phase_served(rp, meter, on_tpu)
                 phase_skew(args.seed, min(args.rows, SKEW_ROWS), meter,
+                           on_tpu)
+                phase_flow(args.seed, min(args.rows, FLOW_ROWS), meter,
                            on_tpu)
         emit(total_compile=meter.since())
         ok = on_tpu

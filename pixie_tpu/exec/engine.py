@@ -45,10 +45,11 @@ from .bridge import (  # noqa: F401  (re-exported)
 from . import threadmap
 from .fragment import compile_fragment_cached as compile_fragment
 from .pipeline import WindowPipeline
-from .trace import Tracer, plan_script
+from .trace import Tracer, clock_ns, plan_script
 from .joins import (  # noqa: F401  (re-exported)
     _join_dispatch,
     _union_host,
+    traced_join_dispatch,
     try_fused_join,
 )
 # NOTE: DEVICE_JOIN_MIN_ROWS deliberately NOT re-exported — patching a
@@ -86,6 +87,7 @@ from .stream import (  # noqa: F401  (re-exported)
     _subspan,
     _probed_capacity,
     _PROBE_MIN_SLOTS,
+    _rows_in_hand,
     _remember_climb,
     _stream_with_groups,
     _empty_host_batch,
@@ -839,6 +841,7 @@ class Engine:
                     st = self._as_stream(self._materialize(st))
                 results[nid] = st.extend(op)
             elif isinstance(op, JoinOp):
+                t_join = clock_ns()
                 fused = try_fused_join(self, nid, node, results, consumers)
                 if fused is not None:
                     from .joins import JoinDecision
@@ -848,6 +851,16 @@ class Engine:
                         reason="dense-domain N:1 in-fragment lookup",
                     )
                     results[nid] = fused
+                    qstats = self._query_stats
+                    if qstats is not None:
+                        # The build alone: the probe rows flow through
+                        # the fragment the lookup fused into, uncounted.
+                        qstats.trace.add_span(
+                            "join", t_join, clock_ns(), strategy="fused",
+                            where="device", how=op.how,
+                            build_rows=int(fused.chain[-1].dom),
+                            probe_rows=0, rows_out=0,
+                        )
                 else:
                     from .joins import stream_join_stats
 
@@ -872,7 +885,7 @@ class Engine:
                         report.join_capacity.get(nid)
                         if report is not None else None
                     )
-                    results[nid] = _join_dispatch(
+                    results[nid] = traced_join_dispatch(
                         left, right, op, self,
                         left_stats=lstats, right_stats=rstats,
                         cap_key=(self._plan_fingerprint(plan), nid),
@@ -1352,9 +1365,17 @@ class Engine:
         runs on a prefetch thread while the caller computes window N
         (``pipeline_depth`` windows in flight; 1 = serial, no thread).
         Callers MUST wrap iteration in try/finally close() — that is the
-        no-leaked-threads / no-use-after-cancel contract."""
+        no-leaked-threads / no-use-after-cancel contract.
+
+        A batch in hand that fits one window (a join's rows on their way
+        to a re-aggregation) is staged serially: there is no window N+1
+        to stage ahead of, and the thread's start and hand-over cost its
+        one ``window.stage`` 2.8 or 6.0 ms on the chip, in phases seconds
+        long, where the serial 2.2 never moves (PERF.md section 6, PR 32)."""
+        one_window = 0 < _rows_in_hand(stream) <= self.window_rows
         return WindowPipeline(
-            self._staged_windows(stream, stats), self.pipeline_depth,
+            self._staged_windows(stream, stats),
+            1 if one_window else self.pipeline_depth,
             cancel=getattr(self, "_cancel", None), stats=stats,
         )
 
@@ -1488,9 +1509,10 @@ class Engine:
         """(stream, fragment) of ``stream`` compiled at the capacity to
         fold it at (``exec/stream.py``, "the capacity of a keyed
         aggregate"): the one remembered for this chain and these tables;
-        else, for a keyed aggregate whose plan asks for many slots, the
-        one a sketch of the joint key over the windows in range gives,
-        which is then remembered; else the plan's."""
+        else, for a keyed aggregate whose plan asks for many slots, or
+        for fewer than the rows in hand it is about to fold, the one a
+        sketch of the joint key over the windows in range gives, which
+        is then remembered; else the plan's."""
         from .joins import learned_capacity, remember_capacity
 
         def compiled(st):
@@ -1510,7 +1532,8 @@ class Engine:
         elif (
             known is None and key is not None and self.probe_group_keys
             and frag.group_sketch is not None
-            and frag.slots >= _PROBE_MIN_SLOTS
+            and (frag.slots >= _PROBE_MIN_SLOTS
+                 or _rows_in_hand(stream) > frag.slots)
         ):
             cap = _probed_capacity(
                 self._sketch_agg_groups(stream, frag), frag.slots

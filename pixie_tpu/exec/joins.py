@@ -168,6 +168,22 @@ def _scan_side_stats(keys: np.ndarray) -> JoinSideStats:
     )
 
 
+def _in_hand_build_stats(stats: JoinSideStats | None,
+                         build_planes: list) -> JoinSideStats | None:
+    """The single-shot join's stats of its build side: the ingest
+    sketch's where one covers it; else, where the side is one integer
+    key plane (a merged aggregate's rows, string codes aligned to one
+    dictionary), a count of the keys in hand. Without it the output is
+    sized from the plan's BOUNDS (a keyed aggregate's 2^22 slots for
+    57 k live groups: 4 Mi output slots, a kernel and six device-to-host
+    copies of that length); the plan's capacity stays the ceiling."""
+    if stats is not None and stats.ndv:
+        return stats
+    if len(build_planes) != 1 or build_planes[0].dtype.kind not in "iu":
+        return stats
+    return _scan_side_stats(build_planes[0])
+
+
 # -- learned capacity + retry accounting -------------------------------------
 # (mode, plan-hash, node) -> final (post-overflow) output capacity of a
 # join node, stored on ``Engine._join_capacity_cache``: a repeated query
@@ -306,15 +322,14 @@ def choose_join_strategy(left: HostBatch, right: HostBatch, op: JoinOp,
     device runs) always get a device kernel. See docs/JOINS.md for the
     matrix.
     """
-    import jax
-
     from ..config import get_flag
+    from ..ops import routes
 
     forced = str(get_flag("join_strategy"))
     window_rows = int(get_flag("join_probe_window_rows"))
     radix_bits = int(get_flag("join_radix_bits"))
     zone_skip = bool(get_flag("join_zone_skip"))
-    tpu = jax.default_backend() == "tpu"
+    tpu = routes.routes_platform() == "tpu"
 
     if not device_only and op.how in ("inner", "left") and (
         forced == "host" or (forced == "auto" and not tpu)
@@ -365,6 +380,36 @@ def choose_join_strategy(left: HostBatch, right: HostBatch, op: JoinOp,
         window_rows=window_rows, zone_skip=zone_skip,
         reason="forced" if forced != "auto" else "auto",
     )
+
+
+#: Strategies whose build and probe run in a device program (a ``join``
+#: span's ``where``); the others run on the host.
+DEVICE_STRATEGIES = frozenset({"fused", "single", "sorted", "radix"})
+
+
+def traced_join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
+                         engine, **kw) -> HostBatch:
+    """``_join_dispatch`` inside a ``join`` span on the query's trace:
+    the dictionaries' alignment, the strategy's build and probe and the
+    output rows' assembly, with what the ``JoinDecision`` chose
+    (``strategy``, ``where``: ``host`` / ``device``), ``how`` and the
+    rows of both sides and of the output. ``QueryTrace._finalize_usage``
+    counts them into ``usage.join_rows_in`` / ``join_rows_out``."""
+    qstats = getattr(engine, "_query_stats", None)
+    if qstats is None:
+        return _join_dispatch(left, right, op, engine, **kw)
+    with qstats.trace.span("join", how=op.how) as sp:
+        out = _join_dispatch(left, right, op, engine, **kw)
+        decision = engine.last_join_decision
+        build, probe = (left, right) if decision.swap else (right, left)
+        sp.attributes.update(
+            strategy=decision.strategy,
+            where="device" if decision.strategy in DEVICE_STRATEGIES
+            else "host",
+            build_rows=build.length, probe_rows=probe.length,
+            rows_out=out.length,
+        )
+    return out
 
 
 def _join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
@@ -539,8 +584,10 @@ def _join_key_planes(hb, cols, remaps):
 
 
 @functools.lru_cache(maxsize=64)
-def _device_join_cache(n_build, n_probe, dtypes, capacity, how):
-    """One jitted kernel per (bucketed shapes, key dtypes, capacity, how).
+def _device_join_cache(n_build, n_probe, dtypes, capacity, how, platform):
+    """One jitted kernel per (bucketed shapes, key dtypes, capacity, how,
+    the platform whose routes run: ``device_join`` takes its key ids by
+    the sort or by the table from it, ``ops/routes.py``).
     Tracked in the program registry (exec/programs.py): the lru key
     params fully determine the traced program, so they ARE the program
     key — compile wall-time, XLA cost/memory analysis and hit counts
@@ -555,7 +602,7 @@ def _device_join_cache(n_build, n_probe, dtypes, capacity, how):
     )
     return default_program_registry().wrap(
         fn, "join_single_shot",
-        ("join", "single", n_build, n_probe, dtypes, capacity, how),
+        ("join", "single", n_build, n_probe, dtypes, capacity, how, platform),
         f"single nb={n_build} np={n_probe} cap={capacity} {how}",
     )
 
@@ -898,6 +945,7 @@ def _join_device(left: HostBatch, right: HostBatch, op: JoinOp,
     on overflow (counted), gather columns host-side. Large windowable
     probes route to the windowed drivers instead."""
     from ..config import get_flag
+    from ..ops import routes
 
     if decision is None or decision.strategy == "host_hash":
         decision = choose_join_strategy(
@@ -963,6 +1011,7 @@ def _join_device(left: HostBatch, right: HostBatch, op: JoinOp,
     cap_key = None if cap_key is None else ("single", cap_key)
     capacity = learned_capacity(engine, cap_key)
     if capacity is None:
+        right_stats = _in_hand_build_stats(right_stats, build_planes)
         if right_stats is not None and right_stats.ndv:
             import dataclasses
 
@@ -990,6 +1039,10 @@ def _join_device(left: HostBatch, right: HostBatch, op: JoinOp,
             capacity = min(
                 capacity, bucket_capacity(max(left.length, 1) * right.length)
             )
+            if right_stats.origin == "scan" and planned_capacity:
+                capacity = min(
+                    capacity, bucket_capacity(max(int(planned_capacity), 1))
+                )
         elif planned_capacity:
             # pxbound's plan-time estimate (analysis/bounds.py): sized
             # from bounds run-time sketches cannot see — a post-
@@ -1004,7 +1057,8 @@ def _join_device(left: HostBatch, right: HostBatch, op: JoinOp,
     counter = _retry_counter(engine)
     while True:
         fn = _device_join_cache(
-            nb, np_, tuple(str(p.dtype) for p in bk), capacity, op.how
+            nb, np_, tuple(str(p.dtype) for p in bk), capacity, op.how,
+            routes.routes_platform(),
         )
         p_idx, p_take, b_idx, b_take, out_valid, overflow = (
             np.asarray(a) for a in fn(bk, bv, pk, pv)

@@ -136,6 +136,10 @@ def _double_agg_groups(stream: "_Stream") -> "_Stream":
 #   and taken only where it is worth a program (a sort-route fold takes
 #   the chip's compiler most of a minute): at an eighth of the plan's or
 #   less;
+#   likewise where the plan's capacity is small but the rows are in hand
+#   (a join's output) and outnumber it: without sketches the plan's is
+#   AggOp's default, and 45 k edges climb from 4,096 slots by four
+#   compiles of the sort route;
 # - after a fold that overflowed, the rung its climb ended on.
 #
 # Either is remembered per (chain, source tables) on the engine, beside
@@ -207,10 +211,21 @@ def _probed_capacity(estimate: int, planned: int) -> int:
     """The capacity to fold at where a probe counted ``estimate``
     distinct keys under a plan of ``planned`` slots: the next power of
     two over them with head-room where that is far enough under the
-    plan's to be worth a program; the plan's otherwise."""
-    want = max(int(estimate * _CAPACITY_SLACK) + 1, _CAPACITY_FLOOR)
-    cap = 1 << (want - 1).bit_length()
+    plan's to be worth a program, or over the plan's (which would
+    overflow and climb there a rung and a compile at a time); the
+    plan's otherwise."""
+    want = int(estimate * _CAPACITY_SLACK) + 1
+    cap = 1 << (max(want, _CAPACITY_FLOOR) - 1).bit_length()
+    if want > planned:
+        return cap
     return cap if cap * _CAPACITY_SHRINK <= planned else planned
+
+
+def _rows_in_hand(stream: "_Stream") -> int:
+    """The rows of a stream whose source is a materialized batch (a
+    join's output, a merged aggregate): known before any fold, where a
+    table's rows in range are not."""
+    return stream.source.length if isinstance(stream.source, HostBatch) else 0
 
 
 def _remember_climb(engine, chain, source, where: str, frag) -> None:

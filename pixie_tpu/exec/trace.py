@@ -43,6 +43,11 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
 - ``group_probe``         the joint-key sketch before a keyed aggregate's
   first fold of a chain (attributes ``slots``: the plan's capacity,
   ``estimate``: distinct joint keys), in a fragment of its own
+- ``join``                one per ``JoinOp``, child of the root: the
+  dictionaries' alignment, build and probe, the output rows' assembly
+  (attributes ``strategy``: the ``JoinDecision``'s, ``where``: ``host``
+  / ``device``, ``how``, ``build_rows``, ``probe_rows``, ``rows_out``;
+  a fused lookup join's span covers its build alone)
 - ``device.wait``         the host asks for a result until the bytes are
   on the host, at the sync the path has anyway
 - ``window.stage`` / ``window.stall`` / ``materialize``  windows that
@@ -191,6 +196,9 @@ class QueryResourceUsage:
       content, or built it (``exec/bridge.py`` ``_PreparedMerge``; the
       ``prepared`` attr of a ``merge_finalize`` dispatch). A warm script
       reads one hit a request
+    - ``join_rows_in`` / ``join_rows_out`` rows the query's joins took
+      in (build + probe) and gave out: the ``join`` spans' ``build_rows``
+      + ``probe_rows`` and ``rows_out`` (a span around every ``JoinOp``)
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -217,6 +225,8 @@ class QueryResourceUsage:
     rebuckets: int = 0
     merge_prepared_hits: int = 0
     merge_prepared_misses: int = 0
+    join_rows_in: int = 0
+    join_rows_out: int = 0
     skipped_windows: int = 0
     device_peak_bytes: int = 0
     freshness_lag_ms: float = 0.0
@@ -241,7 +251,7 @@ class QueryResourceUsage:
             "rows_in", "rows_out", "windows", "bytes_staged",
             "bytes_restaged", "wire_bytes", "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
-            "skipped_windows",
+            "join_rows_in", "join_rows_out", "skipped_windows",
         ):
             setattr(self, k, getattr(self, k) + int(d.get(k, 0)))
         for k in ("device_ms", "compile_ms", "stall_ms", "decode_ms"):
@@ -673,6 +683,12 @@ class QueryTrace:
         for s in self.spans:
             if s.name == "rebucket":
                 u.rebuckets += 1
+            elif s.name == "join":
+                a = s.attributes
+                u.join_rows_in += (
+                    a.get("build_rows", 0) + a.get("probe_rows", 0)
+                )
+                u.join_rows_out += a.get("rows_out", 0)
             elif s.attributes.get("prepared") == "hit":
                 u.merge_prepared_hits += 1
             elif s.attributes.get("prepared") == "miss":

@@ -31,6 +31,7 @@ import numpy as np
 
 from .groupby import dense_group_ids, dense_group_ids_hash
 from .hashtable import _mix64, _mix64_j
+from . import routes
 from .scan import blocked_cumsum
 
 
@@ -272,14 +273,21 @@ def device_join(
     # the kernel's hot spot. Distinct keys <= b+n by construction, so a
     # reported overflow can only mean probe exhaustion (pathological
     # clustering); lax.cond falls back to the exact sort path then.
+    # On the TPU's routes, up to ``JOIN_SORT_IDS_MAX_ROWS``, the sort
+    # alone: the table's rounds end when the data lets them, so the
+    # kernel's time moved from seed to seed (``ops/routes.py``).
     cat_keys = [jnp.concatenate([bk, pk]) for bk, pk in zip(build_keys, probe_keys)]
     cat_valid = jnp.concatenate([build_valid, probe_valid])
-    ids_h, _, _, ng_h = dense_group_ids_hash(cat_keys, cat_valid, b + n)
-    ids = jax.lax.cond(
-        ng_h > b + n,
-        lambda: dense_group_ids(cat_keys, cat_valid, b + n)[0],
-        lambda: ids_h,
-    )
+    if (routes.routes_platform() == "tpu"
+            and b + n <= routes.JOIN_SORT_IDS_MAX_ROWS):
+        ids = dense_group_ids(cat_keys, cat_valid, b + n)[0]
+    else:
+        ids_h, _, _, ng_h = dense_group_ids_hash(cat_keys, cat_valid, b + n)
+        ids = jax.lax.cond(
+            ng_h > b + n,
+            lambda: dense_group_ids(cat_keys, cat_valid, b + n)[0],
+            lambda: ids_h,
+        )
     kb = jnp.where(build_valid, ids[:b], b + n)
     kp = jnp.where(probe_valid, ids[b:], b + n + 1)
 

@@ -57,6 +57,25 @@ F32_FOLD_MAX_GROUPS = 2048
 HIST_FOLD_MAX_SLOTS = 1 << 15
 
 
+#: Largest build + probe rows whose shared key-id space ``ops/join.py``
+#: ``device_join`` takes by SORTING on the TPU's routes; above it, and on
+#: the CPU's, the open-addressing table. The table's rounds are a
+#: ``while_loop`` that ends when every row is placed, so the kernel's time
+#: follows the data: px/net_flow_graph's join (4,096 + 65,536 rows, a
+#: 262,144-slot table) places all but 3-4 rows in three rounds and takes
+#: a fourth for them on 52 of 60 seeds, three on 8, at 3.55 ms a round
+#: (scatters: kernel wait 32.3 against 28.75 ms; my chip run, PR 32,
+#: PERF.md section 6), a step of 2.4 % of the refresh from seed to seed.
+#: The sort is the fold's own rule on this platform (``exec/fold_plan.py``:
+#: rows sort on the TPU, hash on the CPU) and its time does not follow
+#: the data. The limit is one window's rows, the size PR 29's pieces were
+#: measured at (a 2^21-row ``lax.sort`` 2.15-3.55 ms, a full scatter 10.5,
+#: a full gather 18.8); larger single-shot joins keep the table until
+#: they are measured. Read after it (same runs): the kernel ~30 -> 19.4 ms
+#: a refresh, ``refresh_p50_ms`` 148.4 -> 136.2 on every seed.
+JOIN_SORT_IDS_MAX_ROWS = 1 << 21
+
+
 def int_fold_groups(g: int) -> int:
     """g padded for ``dense_group_fold_int``: to whole 128-lane tiles,
     and above one group block to whole blocks (so a dictionary one entry

@@ -469,6 +469,9 @@ class Table:
         self.max_bytes = max_bytes
         self.compacted_rows = compacted_rows
         self._backend = None
+        # A table made without a relation adopts its first batch's: the
+        # lock makes that one step (see append).
+        self._adopt_lock = threading.Lock()
         self._plane_layout: list[tuple[str, int]] = []  # native order
         # Device residency (HBM as cold store): staged windows + watermark
         # of rows already staged at append time (device_cache.py). The
@@ -543,7 +546,7 @@ class Table:
         lib = load_native("table_ring")
         ring_max = -1 if tiered else self.max_bytes
         args = (dts, has_time, self.compacted_rows, ring_max)
-        self._backend = (
+        backend = (
             _NativeBackend(lib, *args) if lib is not None else _PyBackend(*args)
         )
         if tiered:
@@ -553,6 +556,8 @@ class Table:
         for cname, dt in self.relation.items():
             if dt == DataType.STRING:
                 self.dicts.setdefault(cname, StringDictionary())
+        # Last: a set backend is what tells append the table is ready.
+        self._backend = backend
 
     # -- write path ----------------------------------------------------------
     def append(self, data, time_cols: Iterable[str] = (TIME_COLUMN,)) -> HostBatch:
@@ -567,9 +572,14 @@ class Table:
                 dicts=self.dicts,
             )
         )
-        if not len(self.relation):
-            self.relation = hb.relation
-            self._init_backend()
+        if self._backend is None:
+            # Two first appends at once (a collector's loop thread and a
+            # caller's flush) must not find the relation set before the
+            # backend is.
+            with self._adopt_lock:
+                if self._backend is None:
+                    self.relation = hb.relation
+                    self._init_backend()
         if hb.length == 0:
             return hb
         cols = dict(hb.cols)  # never mutate the caller's batch

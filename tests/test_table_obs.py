@@ -729,7 +729,14 @@ class TestProfilerWiring:
             for conn in coll._connectors:
                 conn.transfer_data(coll, coll._data_tables)
             coll.flush()
-            t = store.get_table("stack_traces.beta")
+            # The loop thread may hold the sweep's records between
+            # taking them and landing them: give its append a moment.
+            deadline = time.time() + 5
+            while time.time() < deadline:
+                t = store.get_table("stack_traces.beta")
+                if t is not None and t.num_rows > 0:
+                    break
+                time.sleep(0.01)
             assert t is not None and t.num_rows > 0
         finally:
             coll.stop()

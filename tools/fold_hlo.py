@@ -17,6 +17,12 @@ shipped: the three configurations have two sets of dictionaries, the
 four-chip one serves the one-chip one's) and lowers AND compiles its one
 program, ``merge_finalize``, at the capacity the cell's live groups give
 (2,048 / 1,024 / 65,536 / 1,024 slots), printing the compile's seconds.
+The ``flow`` case is ``px/net_flow_graph`` over ``conn_flow_1chip``'s
+``conn_stats``: every program a cold run of its cell compiles for the
+chip, lowered AND compiled at the cell's shapes (``FLOW``): the PEM's two
+keyed folds of one window and their joint-key sketches, the Kelvin's two
+keyed ``merge_finalize``, the single-shot device join, and the
+re-aggregation's fold and finalize of the join's rows.
 
     JAX_PLATFORMS=cpu python tools/fold_hlo.py --out DIR
 
@@ -44,11 +50,24 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 WINDOW = 1 << 21
 KEYED_SLOTS = 1 << 17
 ROWS, SMALL_WINDOW = 1 << 14, 1 << 12
+#: The ``flow`` case's shapes, by chain label: (the fold's capacity, the
+#: Kelvin's merge bucket, the fold's window), as
+#: ``conn_flow_1chip.flow_recent`` settles on them (56.7 k live pairs,
+#: 4,096 pods, 45.4 k edges). The third chain is the Kelvin's
+#: re-aggregation of the join's rows: no merge, a window of their bucket.
+FLOW = {
+    "sum+sum_by_src_pod_remote_addr": (1 << 17, 1 << 16, WINDOW),
+    "_by_src_addr_src_pod": (1 << 13, 1 << 12, WINDOW),  # its count is pruned
+    "sum+sum_by_src_pod_src_pod_dst": (1 << 16, None, 1 << 16),
+}
+#: The single-shot join's (build, probe, output) buckets.
+FLOW_JOIN = (1 << 12, 1 << 16, 1 << 17)
 
 
-def _capture(batches):
+def _capture(batches, table="http_events",
+             scripts=("px/http_stats", "px/service_stats")):
     """Every (who, ops, relation, dicts, allow_dense, col_stats) of an
-    aggregate fragment compiled while the two scripts are served."""
+    aggregate fragment compiled while the scripts are served."""
     from pixie_tpu.exec import fragment
     from pixie_tpu.exec.engine import Engine
     from pixie_tpu.scripts import load_script
@@ -83,11 +102,11 @@ def _capture(batches):
     kelvin = KelvinAgent(bus, "kelvin-0", heartbeat_interval_s=0.05).start()
     try:
         for hb in batches:
-            pem.append_data("http_events", hb)
+            pem.append_data(table, hb)
         pem._register()
-        _wait_for_table(tracker)
+        _wait_for_table(tracker, table)
         broker = QueryBroker(bus, tracker)
-        for script in ("px/http_stats", "px/service_stats"):
+        for script in scripts:
             res = broker.execute_script(load_script(script).pxl,
                                         timeout_s=300, max_output_rows=1 << 17)
             assert not res.get("partial"), script
@@ -101,11 +120,11 @@ def _capture(batches):
     return seen, merges
 
 
-def _wait_for_table(tracker):
+def _wait_for_table(tracker, table):
     import time
 
     deadline = time.time() + 30
-    while not tracker.distributed_state().pems_with_table("http_events"):
+    while not tracker.distributed_state().pems_with_table(table):
         assert time.time() < deadline, "the PEM's schema did not reach the tracker"
         time.sleep(0.01)
 
@@ -170,36 +189,90 @@ def _lower(case, captured, topo_device, out_dir, lines):
         )
 
     for ops, relation, dicts, allow_dense, col_stats in captured:
+        window, flow = WINDOW, case.startswith("flow")
         if case.startswith("keyed"):
-            ops = [dataclasses.replace(op, max_groups=KEYED_SLOTS)
+            slots = KEYED_SLOTS
+        elif flow:
+            if not allow_dense:
+                continue  # the Kelvin's merge of a chain: ``_lower_merges``
+            slots, merged_at, window = FLOW[_agg_label(ops)]
+        else:
+            slots = None
+        if slots is not None:
+            ops = [dataclasses.replace(op, max_groups=slots)
                    if isinstance(op, AggOp) else op for op in ops]
         frag = compile_fragment(ops, relation, dicts, default_registry(),
                                 allow_dense, col_stats=col_stats)
-        who = "pem" if allow_dense else "kelvin"
+        # The flow case's unmerged chain is the Kelvin's re-aggregation.
+        on_kelvin = not allow_dense or (flow and merged_at is None)
+        who = "kelvin" if on_kelvin else "pem"
         name = f"{case}.{who}.{_agg_label(ops)}.{frag.group}{frag.slots}"
         if any(line["case"] == name for line in lines):
             continue  # compiled twice (the probe, then the capacity)
         state = on(jax.eval_shape(frag.init_state))
         cols = {
-            c: tuple(jax.ShapeDtypeStruct((WINDOW,), dt, sharding=chip)
+            c: tuple(jax.ShapeDtypeStruct((window,), dt, sharding=chip)
                      for dt in device_dtypes(t))
             for c, t in relation.items()
         }
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
         bounds = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=chip)
+        valid = jax.ShapeDtypeStruct((window,), jnp.bool_, sharding=chip)
         programs = {
             "merge_states": lambda: jax.jit(frag.merge_states).lower(
                 state, state),
             "finalize": lambda: frag.finalize.lower(state),
+            "update": lambda: frag.update.lower(
+                state, cols, (scalar, scalar)),
+            "update_all": lambda: frag.update_all.lower(
+                state, (cols,) * 3, bounds, bounds),
+            "group_sketch": lambda: jax.jit(frag.group_sketch).lower(
+                on(jax.eval_shape(frag.init_sketch)), cols, valid),
         }
-        if allow_dense:  # the Kelvin folds no window: it merges and finalizes
-            programs["update"] = lambda: frag.update.lower(
-                state, cols, (scalar, scalar))
-            programs["update_all"] = lambda: frag.update_all.lower(
-                state, (cols,) * 3, bounds, bounds)
-        for program, lower in sorted(programs.items()):
+        if flow:  # what the cell runs: one window in range a chain
+            wanted = ("update", "group_sketch" if who == "pem" else "finalize")
+        elif allow_dense:
+            wanted = ("finalize", "merge_states", "update", "update_all")
+        else:  # the Kelvin folds no window: it merges and finalizes
+            wanted = ("finalize", "merge_states")
+        for program in sorted(wanted):
+            lowered = programs[program]()
+            more = {"compile_s": _compile_s(lowered)} if flow else {}
             _record(lines, out_dir, name, program, frag.fold,
-                    _without_kernel_locations(lower().as_text()))
+                    _without_kernel_locations(lowered.as_text()), **more)
+
+
+def _compile_s(lowered) -> float:
+    import time
+
+    t0 = time.perf_counter()
+    lowered.compile()
+    return round(time.perf_counter() - t0, 2)
+
+
+def _lower_join(case, topo_device, out_dir, lines):
+    """The single-shot device join at the ``flow`` case's buckets: the
+    merged ``addrs`` build, the merged ``flows`` probe, int32 string
+    codes aligned to one dictionary."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pixie_tpu.ops.join import device_join
+
+    chip = SingleDeviceSharding(topo_device)
+    nb, npr, cap = FLOW_JOIN
+
+    def plane(n, dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=chip)
+
+    lowered = jax.jit(
+        lambda bk, bv, pk, pv: device_join(bk, bv, pk, pv, cap, "inner")
+    ).lower([plane(nb, jnp.int32)], plane(nb, jnp.bool_),
+            [plane(npr, jnp.int32)], plane(npr, jnp.bool_))
+    _record(lines, out_dir, f"{case}.kelvin.join.nb{nb}.np{npr}.cap{cap}",
+            "join_single_shot", "-", lowered.as_text(),
+            compile_s=_compile_s(lowered))
 
 
 def _lower_merges(case, merges, topo_device, out_dir, lines):
@@ -209,7 +282,6 @@ def _lower_merges(case, merges, topo_device, out_dir, lines):
     ``http_full_1chip``'s 63 k groups in a 65,536-slot bucket). Lowered
     and COMPILED for the described device: the line carries the
     seconds."""
-    import time
     import types
 
     import jax
@@ -226,6 +298,8 @@ def _lower_merges(case, merges, topo_device, out_dir, lines):
         caps = [cap for _idx, _live, cap in slots]
         if case.startswith("keyed") and not payloads[0].dense_domains:
             caps = [KEYED_SLOTS // 2] * len(payloads)
+        elif case.startswith("flow"):
+            caps = [FLOW[_agg_label(payloads[0].chain)][1]] * len(payloads)
         g = bucket_capacity(sum(caps))
         rec = bridge._prepare_merge(engine, payloads, tail, g, None)
         name = (f"{case}.kelvin.{_agg_label(payloads[0].chain)}"
@@ -242,16 +316,16 @@ def _lower_merges(case, merges, topo_device, out_dir, lines):
             for p, (idx, _live, have), cap in zip(payloads, slots, caps)
         ]
         lowered = rec.program.lower(states, rec.remaps)
-        t0 = time.perf_counter()
-        lowered.compile()
         _record(lines, out_dir, name, "merge_finalize", rec.frag.fold,
                 _without_kernel_locations(lowered.as_text()),
-                compile_s=round(time.perf_counter() - t0, 2))
+                compile_s=_compile_s(lowered))
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--out", default=None, help="directory for the texts")
+    ap.add_argument("--cases", default="dense,keyed,flow",
+                    help="comma-separated, of dense, keyed, flow")
     args = ap.parse_args()
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -259,18 +333,32 @@ def main():
     import jax
 
     import pixie_tpu  # noqa: F401
-    from benchmark.builders import served_http_skew
+    from benchmark.builders import served_conn, served_http_skew
     from pixie_tpu.ingest.replay import gen_http_events
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "http_full_1chip.json")) as f:
-        cfg = json.load(f)
-    skew = served_http_skew.make_data(cfg, 3_000_000_019, ROWS)
-    captured = {
-        "dense": _capture(list(gen_http_events(ROWS, seed=3))),
-        "keyed": _capture(list(
-            served_http_skew.batches(skew, SMALL_WINDOW, 0, ROWS))),
+    def config(name):
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               f"{name}.json")) as f:
+            return json.load(f)
+
+    def keyed():
+        skew = served_http_skew.make_data(
+            config("http_full_1chip"), 3_000_000_019, ROWS)
+        return _capture(list(
+            served_http_skew.batches(skew, SMALL_WINDOW, 0, ROWS)))
+
+    def flow():
+        conn = served_conn.make_data(
+            config("conn_flow_1chip"), 3_000_000_019, ROWS)
+        return _capture(
+            list(served_conn.batches(conn, SMALL_WINDOW, 0, ROWS)),
+            table="conn_stats", scripts=("px/net_flow_graph",))
+
+    cases = {
+        "dense": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
+        "keyed": keyed, "flow": flow,
     }
+    captured = {case: cases[case]() for case in args.cases.split(",")}
 
     # From here on the code sees the chip: the one read of the backend
     # (ops/routes.py) answers "tpu", and every shape sits on a described
@@ -287,6 +375,8 @@ def main():
     for case, (seen, merges) in captured.items():
         _lower(case, seen, topo.devices[0], args.out, lines)
         _lower_merges(case, merges, topo.devices[0], args.out, lines)
+        if case == "flow":
+            _lower_join(case, topo.devices[0], args.out, lines)
     return 0
 
 

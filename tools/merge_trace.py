@@ -11,7 +11,8 @@ refreshes of the cell's traffic and prints, a script: the median length
 of the Kelvin's merge trace (root to root) and of each of its spans,
 then the LAST refresh's trace as a table (offset from the root's start,
 length, attributes; the rows between two spans are host work with no
-span of its own). One more refresh then counts, inside the Kelvin's
+span of its own), and the PEM's fragment trace of the same request
+after it. One more refresh then counts, inside the Kelvin's
 ``execute_plan`` and inside its ``merge_agg_bridge`` alone, the calls of
 ``StringDictionary.get_or_add`` and the strings ``content_key`` hashed,
 and the programs JAX compiled: a warm merge is expected to make none of
@@ -32,12 +33,23 @@ import threading
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-_SHOWN = ("program", "windows", "slots", "prepared", "ops", "from", "to")
+_SHOWN = ("program", "windows", "slots", "prepared", "ops", "from", "to",
+          "fold", "rows", "strategy", "where", "build_rows", "probe_rows",
+          "rows_out")
 
 
-def _merge_traces(spans: dict, qids: list) -> list:
-    by_qid = {t.qid: t for t in spans["kelvin"] if t.kind == "merge"}
+def _merge_traces(spans: dict, qids: list, tracer: str = "kelvin",
+                  kind: str = "merge") -> list:
+    by_qid = {t.qid: t for t in spans[tracer] if t.kind == kind}
     return [by_qid[q] for q in qids if q in by_qid]
+
+
+def _print_table(rows: list) -> None:
+    for row in rows:
+        attrs = {k: v for k, v in row.items()
+                 if k not in ("span", "at_ms", "ms")}
+        print(f"  {row['span']:<16} +{row['at_ms']:>9.3f} "
+              f"{row['ms']:>9.3f} ms  {attrs or ''}")
 
 
 def _ms(span) -> float:
@@ -211,11 +223,17 @@ def main(argv=None) -> int:
                 print(f"== {req['label']}: merge trace, root to root, "
                       f"p50 of {len(traces)} = "
                       f"{out['scripts'][req['label']]['merge_ms_p50']} ms")
-                for row in out["scripts"][req["label"]]["last"]:
-                    attrs = {k: v for k, v in row.items()
-                             if k not in ("span", "at_ms", "ms")}
-                    print(f"  {row['span']:<16} +{row['at_ms']:>9.3f} "
-                          f"{row['ms']:>9.3f} ms  {attrs or ''}")
+                _print_table(out["scripts"][req["label"]]["last"])
+                # The same request on the PEM, for what the Kelvin's
+                # parts cost beside the folds.
+                pem = _merge_traces(spans, [q[i] for q in qids],
+                                    "pem", "fragment")
+                out["scripts"][req["label"]]["pem_ms_p50"] = round(
+                    statistics.median(_ms(t.root) for t in pem), 3)
+                out["scripts"][req["label"]]["pem_last"] = _table(pem[-1])
+                print(f"== {req['label']}: the PEM's fragment trace, p50 = "
+                      f"{out['scripts'][req['label']]['pem_ms_p50']} ms")
+                _print_table(out["scripts"][req["label"]]["pem_last"])
             counter = _KelvinCounter(stack.kelvin.engine)
             before = meter.programs
             try:
