@@ -58,11 +58,10 @@ df = df.groupby(['service', 'req_path']).agg(
 px.display(df)
 """
 
-#: Two groups (a BOOLEAN key is a dense domain of 2) x 8192 bins = 16384
-#: slots: inside ``hist_fold``'s gate (``ops/tdigest.py``, G * B <=
-#: 1 << 15, that is G <= 4). An ungrouped aggregate folds into
-#: ``max_groups`` slots and every shipped quantile script groups by a
-#: column with more than four values here, so none reaches the kernel.
+#: Two groups (a BOOLEAN key is a dense domain of 2): the window digest
+#: of a ``quantiles`` aggregate by sorting the rows (``ops/tdigest.py``),
+#: at the other end of the group counts from px/service_stats' 33; its
+#: program must hold the sorted reduction's kernel.
 QUANTILE_BY_FAILED = """
 import px
 df = px.DataFrame(table='http_events')
@@ -143,7 +142,7 @@ def _programs_since(snap: dict) -> list:
                 "tpu_custom_call": "tpu_custom_call" in text,
                 "kernels": sorted(
                     k for k in ("dense_group_fold", "dense_group_fold_int",
-                              "hist_fold")
+                              "sorted_centroid_fold")
                     if f"{k}/pallas_call" in text
                 ),
             })
@@ -297,7 +296,7 @@ def engine_queries() -> list:
         ("inline/f64_groupby", F64_GROUPBY, check_f64_groupby,
          "dense_group_fold"),
         ("inline/quantile_by_failed", QUANTILE_BY_FAILED,
-         check_quantile_by_failed, "hist_fold"),
+         check_quantile_by_failed, "sorted_centroid_fold"),
     ]
 
 

@@ -35,8 +35,10 @@ from dataclasses import dataclass
 from typing import Optional
 
 from ..ops.routes import (
+    DIGEST_K,
     F32_FOLD_MAX_GROUPS,
     INT_FOLD_MAX_GROUPS,
+    digest_route,
     int_fold_groups,
 )
 from ..types.dtypes import DataType
@@ -59,7 +61,10 @@ class FoldPlan:
     slots: int
     #: ((out_name, route), ...) in the AggOp's order; a route is
     #: ``pallas_int`` / ``pallas_f32`` / ``xla`` on a dense layout or
-    #: under group ids, ``sorted_int`` under the payload-carrying sort.
+    #: under group ids, ``sorted_digest`` for a ``quantiles`` aggregate
+    #: whose window digest is built by sorting the rows
+    #: (``ops/tdigest.py``), ``sorted_int`` under the payload-carrying
+    #: sort.
     routes: tuple
     #: The kernel a dense window's per-slot row count rides (a ``count``
     #: aggregate's route, and where the state's ``valid`` comes from
@@ -82,6 +87,12 @@ class FoldPlan:
     #: Unpacked, a leading dictionary id still spares the flag operand:
     #: ids are >= NULL_ID (-1), so id + 1 never reads 0xFFFFFFFF.
     lead_id: bool = False
+
+
+def _digest(uda_name: str) -> bool:
+    """A t-digest aggregate (``udf/builtins/math_sketches.py``):
+    ``quantiles`` or one of the planner's ``_quantile_pXX``."""
+    return uda_name == "quantiles" or uda_name.startswith("_quantile_")
 
 
 def _int_stat(uda_name: str, arg_types: tuple) -> bool:
@@ -140,6 +151,10 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
         if (uda_name in _STATS and len(arg_types) == 1
                 and arg_types[0] == DataType.FLOAT64):
             return "pallas_f32" if f32_ok else "xla"
+        if _digest(uda_name):
+            # Dense or under group ids alike: ``uda.update`` takes the ids
+            # and asks the same function (``ops/tdigest.py``).
+            return digest_route(platform, g * DIGEST_K)
         return "xla"
 
     routes = {
@@ -147,7 +162,7 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
     }
     # A count reads no argument: it rides the kernel that runs anyway
     # (the integer one's count is i32-exact, so it is preferred).
-    kernels = set(routes.values()) - {"xla"}
+    kernels = set(routes.values()) - {"xla", "sorted_digest"}
     count_route = (
         "pallas_int" if int_ok and kernels != {"pallas_f32"}
         else "pallas_f32" if "pallas_f32" in kernels
@@ -188,7 +203,8 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
         else next(iter(tally), "xla") if len(tally) <= 1
         else "mixed:" + ",".join(
             f"{r}={tally[r]}"
-            for r in ("pallas_int", "pallas_f32", "xla") if r in tally
+            for r in ("pallas_int", "pallas_f32", "sorted_digest", "xla")
+            if r in tally
         )
     )
     return FoldPlan(

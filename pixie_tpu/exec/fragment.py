@@ -113,9 +113,9 @@ class CompiledFragment:
     # only; ``fold_plan.py``), and three of its fields under the names the
     # fold programs' device.dispatch spans and the fragment's
     # /debug/queryz entry carry: ``fold`` = the aggregates' route
-    # (``pallas_int`` / ``pallas_f32`` / ``xla`` / ``mixed:<route>=<n>,...``
-    # / ``sorted_int``), ``group`` = the layout (``dense`` / ``sorted`` /
-    # ``hashed``), ``slots`` = the capacity g.
+    # (``pallas_int`` / ``pallas_f32`` / ``sorted_digest`` / ``xla`` /
+    # ``mixed:<route>=<n>,...`` / ``sorted_int``), ``group`` = the layout
+    # (``dense`` / ``sorted`` / ``hashed``), ``slots`` = the capacity g.
     plan: Optional[FoldPlan] = None
     fold: str = ""
     group: str = ""
@@ -708,7 +708,9 @@ def _dense_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     count_route = plan.count_route
     g_pad = -(-g // 128) * 128  # f32 kernel's lane alignment
     g_int = _routes.int_fold_groups(g)  # integer kernel's lanes and group blocks
-    kernels = (set(routes.values()) | {count_route}) - {"xla"}
+    kernels = (set(routes.values()) | {count_route}) & {
+        "pallas_int", "pallas_f32"
+    }
     if kernels:
         # Imported only here: pulling in Pallas costs a second, which a
         # process that never runs the kernels must not pay mid-query.
@@ -729,7 +731,7 @@ def _dense_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
         n = valid.shape[0]
         by_route = {"pallas_int": [], "pallas_f32": []}
         for ab in aggs_bound:
-            if ab[0].uda_name != "count" and routes[ab[0].out_name] != "xla":
+            if ab[0].uda_name != "count" and routes[ab[0].out_name] in by_route:
                 by_route[routes[ab[0].out_name]].append(ab)
         f32_chunk = (
             fold_row_chunk(n, g_pad)

@@ -32,7 +32,8 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
 - ``device.dispatch``     one per program enqueued: the host's enqueue
   call (attributes ``program`` as ``ProgramRegistry`` names its kind,
   ``windows``, and on a window-fold program ``fold``: ``pallas_int``,
-  ``pallas_f32``, ``xla`` or ``mixed:...``, as ``CompiledFragment.fold``
+  ``pallas_f32``, ``sorted_digest``, ``xla``, ``mixed:...`` or
+  ``sorted_int``, as ``CompiledFragment.fold``
   decided at compile time, with ``group``: ``dense`` / ``sorted`` /
   ``hashed`` and ``slots``: the capacity g it was compiled at); child
   of its fragment

@@ -52,9 +52,21 @@ INT_FOLD_GROUP_BLOCK = 1024
 #: Largest group count the f32 kernel takes: its [chunk, G] one-hot must
 #: fit VMEM.
 F32_FOLD_MAX_GROUPS = 2048
-#: Largest groups x bins the quantile histogram kernel takes: its dense
-#: MXU sweep beats the two scatters up to here.
-HIST_FOLD_MAX_SLOTS = 1 << 15
+#: Centroids a group's t-digest keeps (``ops/tdigest.py``).
+DIGEST_K = 128
+#: Largest groups x centroids whose window digest is built by SORTING the
+#: rows (``ops/tdigest.py`` ``_sorted_batch_to_digest``): the reduction's
+#: two f32 accumulators stay in VMEM (2 x 4 MiB here). Above it, and on
+#: the CPU, rows scatter into the [G, B] histogram.
+SORTED_DIGEST_MAX_SLOTS = 1 << 20
+
+
+def digest_route(platform: str, slots: int) -> str:
+    """The route of a ``quantiles`` aggregate's window digest over
+    ``slots`` = groups x centroids: ``sorted_digest`` or ``xla``."""
+    if platform == "tpu" and slots <= SORTED_DIGEST_MAX_SLOTS:
+        return "sorted_digest"
+    return "xla"
 
 
 #: Largest build + probe rows whose shared key-id space ``ops/join.py``

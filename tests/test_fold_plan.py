@@ -10,7 +10,8 @@ import pytest
 from conftest import routes_of
 from pixie_tpu.exec.fold_plan import FoldPlan, plan_fold
 from pixie_tpu.ops.routes import (
-    F32_FOLD_MAX_GROUPS, INT_FOLD_MAX_GROUPS, int_fold_groups,
+    DIGEST_K, F32_FOLD_MAX_GROUPS, INT_FOLD_MAX_GROUPS,
+    SORTED_DIGEST_MAX_SLOTS, int_fold_groups,
 )
 from pixie_tpu.types.dtypes import DataType as D
 
@@ -45,8 +46,9 @@ DENSE_CASES = [
     ("http_stats_2145", (SVC, PATH), DENSE_2145, HTTP,
      ("dense", 2145, ("pallas_int",) * 3, "pallas_int", "pallas_int")),
     ("service_stats_33", (SVC,), [(33, 0, 1)], SERVICE,
-     ("dense", 33, ("pallas_int", "pallas_int", "xla", "xla"), "pallas_int",
-      "mixed:pallas_int=2,xla=2")),
+     ("dense", 33,
+      ("pallas_int", "pallas_int", "sorted_digest", "sorted_digest"),
+      "pallas_int", "mixed:pallas_int=2,sorted_digest=2")),
     ("at_the_cross_over", (PATH,), [(INT_FOLD_MAX_GROUPS, 0, 1)], HTTP,
      ("dense", INT_FOLD_MAX_GROUPS, ("pallas_int",) * 3, "pallas_int",
       "pallas_int")),
@@ -80,9 +82,22 @@ DENSE_CASES = [
      ("dense", 33, ("pallas_int", "pallas_f32", "pallas_int"), "pallas_int",
       "mixed:pallas_int=2,pallas_f32=1")),
     # A ``quantiles`` alone vetoes nothing: the slots' row count (the
-    # state's ``valid``) still comes from the integer kernel.
+    # state's ``valid``) still comes from the integer kernel. Its window
+    # digest is built by sorting the rows (``ops/tdigest.py``) while the
+    # groups' centroids fit the reduction's accumulators, by scatters
+    # above that.
     ("quantiles_alone", (SVC,), [(33, 0, 1)], (("q", "quantiles", F64),),
-     ("dense", 33, ("xla",), "pallas_int", "xla")),
+     ("dense", 33, ("sorted_digest",), "pallas_int", "sorted_digest")),
+    ("quantiles_at_the_accumulators_limit", (PATH,),
+     [(SORTED_DIGEST_MAX_SLOTS // DIGEST_K, 0, 1)],
+     (("q", "_quantile_p99", F64),),
+     ("dense", SORTED_DIGEST_MAX_SLOTS // DIGEST_K, ("sorted_digest",),
+      "pallas_int", "sorted_digest")),
+    ("quantiles_over_the_accumulators_limit", (PATH,),
+     [(SORTED_DIGEST_MAX_SLOTS // DIGEST_K + 1, 0, 1)],
+     (("q", "_quantile_p99", F64),),
+     ("dense", SORTED_DIGEST_MAX_SLOTS // DIGEST_K + 1, ("xla",),
+      "pallas_int", "xla")),
     ("two_arguments_stay_on_xla", (SVC,), [(33, 0, 1)],
      (("c", "sum", (D.INT64, D.INT64)),),
      ("dense", 33, ("xla",), "pallas_int", "xla")),
@@ -152,9 +167,14 @@ KEYED_CASES = [
      (True, None, False, "sorted_int")),
     ("count_alone", (SVC, PATH), KEYED, (("n", "count", I64),), {},
      (True, (33, 65_537), False, "sorted_int")),
-    # An aggregate that needs a row's group id keeps the id form.
+    # An aggregate that needs a row's group id keeps the id form; under
+    # the ids a ``quantiles`` sorts its rows while its centroids fit.
     ("with_a_quantiles", (SVC, PATH), KEYED,
-     HTTP + (("q", "quantiles", F64),), {}, (False, None, False, "xla")),
+     HTTP + (("q", "quantiles", F64),), {},
+     (False, None, False, "mixed:sorted_digest=1,xla=3")),
+    ("with_a_quantiles_at_the_cells_slots", (SVC, PATH), KEYED,
+     HTTP + (("q", "quantiles", F64),), {"max_groups": 1 << 17},
+     (False, None, False, "xla")),
     ("with_a_float_sum", (SVC, PATH), KEYED, HTTP + (("s", "sum", F64),), {},
      (False, None, False, "xla")),
     ("with_a_boolean_max", (SVC, PATH), KEYED, (("any", "max", BOOL),), {},
@@ -171,7 +191,8 @@ def test_a_keyed_state(group_cols, domains, aggs, kw, tpu):
     plan = _plan(group_cols, domains, aggs, "tpu", **kw)
     assert (plan.layout, plan.slots) == ("sorted", kw.get("max_groups", 4096))
     assert (plan.payload_sort, plan.pack_doms, plan.lead_id, plan.fold) == tpu
-    assert set(_routes(plan)) == {"sorted_int" if payload_sort else "xla"}
+    assert set(_routes(plan)) - {"sorted_digest"} == {
+        "sorted_int" if payload_sort else "xla"}
     assert plan.count_route == "xla" and plan.domains == ()
     cpu = _plan(group_cols, domains, aggs, "cpu", **kw)
     assert (cpu.layout, cpu.slots, cpu.fold) == ("hashed", plan.slots, "xla")

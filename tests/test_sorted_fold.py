@@ -315,12 +315,16 @@ def test_what_chooses_the_route(aggs, keys, platform, allow_dense, fold):
         assert frag.group == "sorted"
 
 
-@pytest.mark.parametrize("uda,col", [("quantiles", "lat"), ("sum", "ratio"),
-                                     ("max", "ratio")])
-def test_an_aggregate_that_needs_row_ids_keeps_the_id_form(uda, col):
+@pytest.mark.parametrize("uda,col,fold", [
+    # Under the ids a ``quantiles`` sorts its rows by (id, bin).
+    ("quantiles", "lat", "mixed:sorted_digest=1,xla=3"),
+    ("sum", "ratio", "xla"), ("max", "ratio", "xla"),
+])
+def test_an_aggregate_that_needs_row_ids_keeps_the_id_form(uda, col, fold):
     frag = _frag(("svc", "path"), AGG_SETS["count_mean_max"], 256,
                  extra=(("x", uda, col),))
-    assert frag.fold == "xla" and frag.group == "sorted"
+    assert frag.fold == fold and frag.group == "sorted"
+    assert not frag.plan.payload_sort
 
 
 @pytest.mark.parametrize("allow_dense", [True, False],

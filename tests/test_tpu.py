@@ -349,9 +349,10 @@ def test_pallas_engine_fold_matches_xla_on_tpu(tpu):
     print(f"pallas engine fold: {dt_p*1e3:.0f} ms vs xla {dt_x*1e3:.0f} ms")
 
 
-def test_pallas_tdigest_hist_on_tpu(tpu):
-    """The t-digest histogram kernel matches the XLA segment-sum path on
-    the chip (within sketch tolerance)."""
+def test_sorted_digest_on_tpu(tpu):
+    """The window digest by sorting the rows (the chip's route) matches
+    the scatter route on the chip: same bins, same centroids, f32
+    summation order apart."""
     from conftest import routes_of
     from pixie_tpu.ops.tdigest import batch_to_digest, digest_quantile
     import jax.numpy as jnp
@@ -365,4 +366,4 @@ def test_pallas_tdigest_hist_on_tpu(tpu):
     pal = digest_quantile(batch_to_digest(vals, gids, mask, g), (0.5, 0.99))
     with routes_of("cpu"):
         ref = digest_quantile(batch_to_digest(vals, gids, mask, g), (0.5, 0.99))
-    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref), rtol=0.05)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref), rtol=1e-3)

@@ -180,34 +180,121 @@ def test_dense_group_fold_int_under_shard_map(topo):
     assert "tpu_custom_call" in text
 
 
-# 8192 and 1 << 15 slots: G = 1 and 4 groups at B = 8192 bins, the whole
-# range ``ops/tdigest.py`` admits to the kernel.
-@pytest.mark.parametrize("n_slots", [8192, 1 << 15])
-def test_hist_fold_at_bench_window(one_chip, n_slots):
-    from pixie_tpu.ops.pallas_groupby import row_chunk
-    from pixie_tpu.ops.pallas_tdigest import hist_fold
+# The sorted digest's reduction (``ops/tdigest.py``'s route on the TPU):
+# px/service_stats' 33 x 128 centroid slots at the benchmark's window and
+# at the four-chip cell's 2^19-row shard, the smallest block, and the
+# route's slot limit (two 4 MiB accumulators resident in VMEM).
+@pytest.mark.parametrize("rows,n_slots", [
+    (WINDOW, 33 * 128), (WINDOW // 4, 33 * 128), (1024, 128),
+    (WINDOW, 1 << 20),
+])
+def test_sorted_centroid_fold_at_bench_window(one_chip, rows, n_slots):
+    from pixie_tpu.ops.pallas_tdigest import sorted_centroid_fold
+    from pixie_tpu.ops.routes import SORTED_DIGEST_MAX_SLOTS
 
+    assert n_slots <= SORTED_DIGEST_MAX_SLOTS
     text = _compile(
-        hist_fold,
-        _rows(WINDOW, jnp.int32, one_chip),
-        _rows(WINDOW, jnp.float32, one_chip),
-        n_slots=n_slots, chunk=row_chunk(WINDOW, 2048),
+        sorted_centroid_fold,
+        _rows(rows, jnp.int32, one_chip),
+        _rows(rows, jnp.float32, one_chip),
+        n_slots=n_slots,
     )
     assert "tpu_custom_call" in text
 
 
-def test_hist_fold_at_smallest_window(one_chip):
-    """128 rows, the floor of ``batch_to_digest``'s gate: one block."""
-    from pixie_tpu.ops.pallas_groupby import row_chunk
-    from pixie_tpu.ops.pallas_tdigest import hist_fold
+def _service_stats_fragment():
+    """px/service_stats' aggregate over its one dictionary key (33 dense
+    slots) as the chip compiles it: the count and the mean in the integer
+    kernel, the two quantiles' window digest by sorting the rows. The
+    backend underneath answers ``tpu`` while it is compiled and lowered,
+    so the kernels go through Mosaic, uninterpreted."""
+    import pixie_tpu  # noqa: F401
+    from pixie_tpu.exec.fragment import compile_fragment
+    from pixie_tpu.exec.plan import AggExpr, AggOp, ColumnRef
+    from pixie_tpu.types.dtypes import DataType
+    from pixie_tpu.types.relation import Relation
+    from pixie_tpu.types.strings import StringDictionary
+    from pixie_tpu.udf.registry import default_registry
 
-    text = _compile(
-        hist_fold,
-        _rows(128, jnp.int32, one_chip),
-        _rows(128, jnp.float32, one_chip),
-        n_slots=8192, chunk=row_chunk(128, 2048),
+    rel = Relation([("latency_ns", DataType.INT64),
+                    ("failure", DataType.BOOLEAN),
+                    ("service", DataType.STRING)])
+    dicts = {"service": StringDictionary(f"s{i}" for i in range(32))}
+    lat = (ColumnRef("latency_ns"),)
+    frag = compile_fragment(
+        [AggOp(("service",),
+               (AggExpr("p50", "_quantile_p50", lat),
+                AggExpr("p99", "_quantile_p99", lat),
+                AggExpr("error_rate", "mean", (ColumnRef("failure"),)),
+                AggExpr("throughput", "count", lat)))],
+        rel, dicts, default_registry(),
     )
-    assert "tpu_custom_call" in text
+    assert (frag.group, frag.fold, frag.slots) == (
+        "dense", "mixed:pallas_int=2,sorted_digest=2", 33)
+    return frag
+
+
+# Compile seconds here, for the described v5e (PR 33): 12 each (25 / 29 /
+# 15 while the position scans were flat ``lax.cummax``: they are blocked).
+@pytest.mark.parametrize("program", ["update", "update_all", "mesh_agg_step"])
+def test_sorted_digest_programs_at_bench_window(topo, one_chip, program):
+    """The programs of px/service_stats' fold that the dashboard cells
+    dispatch, with the window digest on the sorted route: one 2^21-row
+    window, the three-window scan, and ``DistributedEngine``'s step over
+    the four chips (2^19 rows a chip, inside ``shard_map``). One sort of
+    the rows (the two quantiles share it), the reduction's kernel, and no
+    scatter of a window's rows: what scatters are left are the digests'
+    [33, 256]-centroid compress."""
+    from unittest import mock
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pixie_tpu.exec import fragment
+    from pixie_tpu.ops import routes
+    from pixie_tpu.parallel.executor import distributed_agg_step
+    from pixie_tpu.parallel.mesh import agent_mesh
+
+    def cols_on(sharding):
+        return {"latency_ns": (_rows(WINDOW, jnp.int64, sharding),),
+                "failure": (_rows(WINDOW, jnp.bool_, sharding),),
+                "service": (_rows(WINDOW, jnp.int32, sharding),)}
+
+    with mock.patch.object(routes, "_backend", lambda: "tpu"):
+        fragment._FRAGMENT_CACHE.clear()
+        try:
+            frag = _service_stats_fragment()
+            state = jax.eval_shape(frag.init_state)
+            if program == "mesh_agg_step":
+                mesh = agent_mesh(4, devices=topo.devices)
+                everywhere = NamedSharding(mesh, P())
+                scalar = jax.ShapeDtypeStruct((), jnp.int32,
+                                              sharding=everywhere)
+                text = _compile(
+                    distributed_agg_step(frag, mesh, range_valid=True),
+                    _on(state, everywhere),
+                    cols_on(NamedSharding(mesh, P(mesh.axis_names))),
+                    {}, (scalar, scalar))
+                assert "all-gather" in text
+            else:
+                scalar = jax.ShapeDtypeStruct((), jnp.int32,
+                                              sharding=one_chip)
+                cols = cols_on(one_chip)
+                if program == "update":
+                    text = _compile(frag.update, _on(state, one_chip), cols,
+                                    (scalar, scalar))
+                else:
+                    bounds = jax.ShapeDtypeStruct((3,), jnp.int32,
+                                                  sharding=one_chip)
+                    text = _compile(frag.update_all, _on(state, one_chip),
+                                    (cols,) * 3, bounds, bounds)
+        finally:
+            fragment._FRAGMENT_CACHE.clear()
+    rows = WINDOW // 4 if program == "mesh_agg_step" else WINDOW
+    assert "sorted_centroid_fold" in text and "dense_group_fold_int" in text
+    assert f"u32[{rows}]" in text and " sort(" in text
+    for line in text.splitlines():
+        if " scatter(" in line:
+            assert f"[{rows}]" not in line, line
 
 
 def test_row_chunk_refuses_what_the_tiling_refuses(one_chip):

@@ -22,7 +22,12 @@ The ``flow`` case is ``px/net_flow_graph`` over ``conn_flow_1chip``'s
 chip, lowered AND compiled at the cell's shapes (``FLOW``): the PEM's two
 keyed folds of one window and their joint-key sketches, the Kelvin's two
 keyed ``merge_finalize``, the single-shot device join, and the
-re-aggregation's fold and finalize of the join's rows.
+re-aggregation's fold and finalize of the join's rows. The ``digest``
+case (PR 33) is ``px/service_stats``' fold, the one chain whose
+``quantiles`` sort their rows (``ops/tdigest.py``): ``update`` and
+``update_all`` at the 2^21-row window on one chip, and the four-chip
+cell's ``shard_map`` step over the described 2x2's four devices (2^19 rows
+a chip), each lowered AND compiled.
 
     JAX_PLATFORMS=cpu python tools/fold_hlo.py --out DIR
 
@@ -190,6 +195,9 @@ def _lower(case, captured, topo_device, out_dir, lines):
 
     for ops, relation, dicts, allow_dense, col_stats in captured:
         window, flow = WINDOW, case.startswith("flow")
+        digest = case == "digest"
+        if digest and not (allow_dense and _folds_quantiles(ops)):
+            continue
         if case.startswith("keyed"):
             slots = KEYED_SLOTS
         elif flow:
@@ -231,15 +239,61 @@ def _lower(case, captured, topo_device, out_dir, lines):
         }
         if flow:  # what the cell runs: one window in range a chain
             wanted = ("update", "group_sketch" if who == "pem" else "finalize")
+        elif digest:
+            wanted = ("update", "update_all", "mesh_agg_step")
+            programs["mesh_agg_step"] = lambda: _mesh_step(frag, relation)
         elif allow_dense:
             wanted = ("finalize", "merge_states", "update", "update_all")
         else:  # the Kelvin folds no window: it merges and finalizes
             wanted = ("finalize", "merge_states")
         for program in sorted(wanted):
             lowered = programs[program]()
-            more = {"compile_s": _compile_s(lowered)} if flow else {}
+            more = (
+                {"compile_s": _compile_s(lowered)} if flow or digest else {}
+            )
             _record(lines, out_dir, name, program, frag.fold,
                     _without_kernel_locations(lowered.as_text()), **more)
+
+
+def _folds_quantiles(ops) -> bool:
+    """The chain is ``px/service_stats``': its AggOp holds a t-digest."""
+    from pixie_tpu.exec.plan import AggOp
+
+    agg = next(op for op in ops if isinstance(op, AggOp))
+    return any(a.uda_name.startswith("_quantile_") for a in agg.aggs)
+
+
+def _mesh_step(frag, relation):
+    """The four-chip cell's window step (``parallel/executor.py``
+    ``distributed_agg_step``, the device-resident form), lowered over
+    the described topology's four devices: a 2^21-row window row-sharded,
+    2^19 rows a chip, the state replicated."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pixie_tpu.parallel.executor import distributed_agg_step
+    from pixie_tpu.parallel.mesh import agent_mesh, row_sharding
+    from pixie_tpu.types.dtypes import device_dtypes
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = agent_mesh(4, devices=topo.devices)
+    whole = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+        jax.eval_shape(frag.init_state),
+    )
+    cols = {
+        c: tuple(jax.ShapeDtypeStruct((WINDOW,), dt,
+                                      sharding=row_sharding(mesh))
+                 for dt in device_dtypes(t))
+        for c, t in relation.items()
+    }
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)
+    return distributed_agg_step(frag, mesh, range_valid=True).lower(
+        state, cols, {}, (scalar, scalar))
 
 
 def _compile_s(lowered) -> float:
@@ -325,7 +379,7 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--out", default=None, help="directory for the texts")
     ap.add_argument("--cases", default="dense,keyed,flow",
-                    help="comma-separated, of dense, keyed, flow")
+                    help="comma-separated, of dense, keyed, flow, digest")
     args = ap.parse_args()
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -357,6 +411,7 @@ def main():
     cases = {
         "dense": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "keyed": keyed, "flow": flow,
+        "digest": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
     }
     captured = {case: cases[case]() for case in args.cases.split(",")}
 

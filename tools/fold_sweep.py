@@ -4,8 +4,10 @@ and (``--keyed``) what the keyed fold costs, old route against new.
     python tools/fold_sweep.py                 # on a chip: the sweep
     python tools/fold_sweep.py --keyed         # on a chip: the keyed fold
     python tools/fold_sweep.py --micro         # on a chip: sorts, gathers, scans
+    python tools/fold_sweep.py --digest        # on a chip: the quantile digest
     JAX_PLATFORMS=cpu python tools/fold_sweep.py --rows 4096 --groups 128
     JAX_PLATFORMS=cpu python tools/fold_sweep.py --keyed --rows 4096 --groups 256
+    JAX_PLATFORMS=cpu python tools/fold_sweep.py --digest --rows 4096 --groups 2 33
 
 Times one window's fold of ``px/http_stats``' aggregates (``count``,
 ``mean`` and ``max`` of one INT64 column) two ways over a range of group
@@ -26,6 +28,15 @@ slots and merged into an accumulated one, two ways: the id form
 or scatter a step) and ``sorted_group_fold`` (the rows ride the sort).
 The pieces are timed apart, and the two states are compared bit for bit.
 PERF.md section 6 (PR 29) holds the chip's output.
+
+``--digest``: one window of ``px/service_stats``' ``quantiles`` aggregate
+(``ops/tdigest.py`` ``digest_update`` of a [G, 128] carry, then the
+window's merge into the accumulated state), the scatter route (the CPU's:
+two row scatters into the [G, B] histogram, then its compress) against
+the sorted route (the TPU's: one payload-carrying sort, positions, a
+reduction of sorted ids), at 2^19 and 2^21 rows and G = 1, 2, 4, 33,
+8,192, the two routes' quantiles compared, the sorted route's pieces
+timed apart. PERF.md section 6 (PR 33) holds the chip's output.
 """
 
 from __future__ import annotations
@@ -174,6 +185,92 @@ def keyed_sweep(args) -> int:
     return 0
 
 
+def digest_sweep(args) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import pixie_tpu  # noqa: F401  (x64 on)
+    from pixie_tpu.ops import routes, tdigest
+    from pixie_tpu.ops.pallas_tdigest import sorted_centroid_fold
+
+    dev = jax.devices()[0]
+    print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
+                      "digest": True}), flush=True)
+    qs = (0.01, 0.10, 0.25, 0.50, 0.75, 0.90, 0.99)
+    k = tdigest.DEFAULT_K
+    real_platform = routes.routes_platform
+
+    def on(platform, fn):
+        """``fn`` jitted and traced under ``platform``'s routes."""
+        def traced(*a):
+            routes.routes_platform = lambda: platform
+            try:
+                return fn(*a)
+            finally:
+                routes.routes_platform = real_platform
+        return jax.jit(traced)
+
+    rng = np.random.default_rng(args.seed)
+    for n in ([args.rows] if args.rows != 1 << 21 else [1 << 19, 1 << 21]):
+        lat = jnp.asarray(np.exp(rng.normal(15, 1.2, n)).astype(np.float32))
+        valid = jnp.asarray(rng.random(n) < 0.92)
+        for g in args.groups or [1, 2, 4, 33, 8192]:
+            gids = jnp.asarray(rng.integers(0, g, n).astype(np.int32))
+            carry = jax.block_until_ready(tdigest.digest_init(g, k))
+
+            def window(carry, gids, valid, lat):
+                # What a fold pays a window: the UDA's update of a fresh
+                # carry, then the merge into the accumulated state.
+                fresh = tdigest.digest_update(
+                    tdigest.digest_init(g, k), gids, valid, lat)
+                return tdigest.digest_merge(carry, fresh)
+
+            line = {"rows": n, "groups": g, "bins": tdigest._hist_bins(g)}
+            out = {}
+            for name, platform in (("scatter", "cpu"), ("sorted", "tpu")):
+                fold = on(platform, window)
+                out[name] = np.asarray(tdigest.digest_quantile(
+                    fold(carry, gids, valid, lat), qs))
+                line[f"{name}_ms"] = round(
+                    _time(fold, carry, gids, valid, lat, reps=args.reps), 3)
+            live = ~np.isnan(out["scatter"])
+            assert (live == ~np.isnan(out["sorted"])).all()
+            line["quantiles_max_relerr"] = float(np.max(
+                np.abs(out["sorted"][live] / out["scatter"][live] - 1.0)))
+
+            # -- the sorted route's pieces, apart
+            v, m, gi, bins, b = tdigest._row_bins(lat, gids, valid, g)
+            key = jnp.where(m & (gi < g), (gi * b + bins).astype(jnp.uint32),
+                            jnp.uint32(0xFFFFFFFF))
+            iota = jnp.arange(n, dtype=jnp.int32)
+            s_key, s_val = jax.block_until_ready(jax.lax.sort(
+                (key, v), num_keys=1, is_stable=False))
+            ids = jnp.sort(jnp.asarray(
+                rng.integers(0, g * k, n).astype(np.int32)))
+            scatter_rows = on("cpu", lambda *a: tdigest.batch_to_digest(
+                *a, g, k))
+            digest = jax.block_until_ready(scatter_rows(lat, gids, valid))
+            pieces = {
+                "sort": (lambda a, c: jax.lax.sort(
+                    (a, c), num_keys=1, is_stable=False), (key, v)),
+                "two_scans": (lambda a: tdigest._span_bounds(
+                    a[1:] != a[:-1], iota), (s_key,)),
+                "reduction": (lambda i, c: sorted_centroid_fold(
+                    i, c, g * k, interpret=routes.kernels_interpreted()),
+                    (ids, s_val)),
+                "two_merges": (lambda c, f: tdigest.digest_merge(
+                    c, tdigest.digest_merge(tdigest.digest_init(g, k), f)),
+                    (carry, digest)),
+                "scatter_rows": (scatter_rows, (lat, gids, valid)),
+            }
+            for name, (fn, a) in pieces.items():
+                line[f"{name}_ms"] = round(
+                    _time(jax.jit(fn), *a, reps=args.reps), 3)
+            print(json.dumps(line), flush=True)
+    return 0
+
+
 def micro_sweep(args) -> int:
     """What the keyed fold is built from, one primitive a line, at the
     window's and the merge's lengths: sorts by operand and key count, the
@@ -238,6 +335,9 @@ def main(argv=None) -> int:
                     help="the keyed fold: the id form against the payload sort")
     ap.add_argument("--micro", action="store_true",
                     help="the primitives the keyed fold is built from")
+    ap.add_argument("--digest", action="store_true",
+                    help="the quantile digest: the scatter route against "
+                         "the sorted route")
     ap.add_argument("--groups", type=int, nargs="*", default=None)
     ap.add_argument("--blocks", nargs="*", default=[],
                     help="extra kernel blockings chunk,g_block")
@@ -246,6 +346,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.micro:
         return micro_sweep(args)
+    if args.digest:
+        return digest_sweep(args)
     if args.keyed:
         return keyed_sweep(args)
     if args.groups is None:
