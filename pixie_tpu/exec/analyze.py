@@ -72,6 +72,11 @@ class FragmentStats:
     # (``slots``); beside ``fold`` wherever that goes.
     group: str = ""
     slots: int = 0
+    # The largest operand table its fold programs read (a dictionary-side
+    # UDF's remap, padded to its bucket: ``CompiledFragment.
+    # remap_entries``); 0 without one. Beside ``fold`` on a traced
+    # fragment's ``compute`` dispatches.
+    remap_entries: int = 0
     # Staging runs on the prefetch thread concurrently with compute on
     # the query thread (pipeline.py), so stage accumulation is locked.
     _lock: threading.Lock = field(

@@ -87,6 +87,8 @@ from .stream import (  # noqa: F401  (re-exported)
     _subspan,
     _probed_capacity,
     _PROBE_MIN_SLOTS,
+    _computed_group_keys,
+    _rows_at_source,
     _rows_in_hand,
     _remember_climb,
     _stream_with_groups,
@@ -1026,6 +1028,7 @@ class Engine:
             stats.fold, stats.group, stats.slots = (
                 frag.fold, frag.group, frag.slots
             )
+            stats.remap_entries = frag.remap_entries
         # Scan-folding trades W dispatches for one; on the CPU backend
         # dispatches are cheap and the jnp.stack of window planes is a
         # pure memory-bandwidth loss.
@@ -1510,7 +1513,9 @@ class Engine:
         fold it at (``exec/stream.py``, "the capacity of a keyed
         aggregate"): the one remembered for this chain and these tables;
         else, for a keyed aggregate whose plan asks for many slots, or
-        for fewer than the rows in hand it is about to fold, the one a
+        for fewer than the rows in hand it is about to fold, or for
+        fewer than its tables hold where a group key is computed (the
+        plan's capacity is then a default, not an estimate), the one a
         sketch of the joint key over the windows in range gives, which
         is then remembered; else the plan's."""
         from .joins import learned_capacity, remember_capacity
@@ -1533,7 +1538,9 @@ class Engine:
             known is None and key is not None and self.probe_group_keys
             and frag.group_sketch is not None
             and (frag.slots >= _PROBE_MIN_SLOTS
-                 or _rows_in_hand(stream) > frag.slots)
+                 or _rows_in_hand(stream) > frag.slots
+                 or (_computed_group_keys(stream.chain)
+                     and _rows_at_source(stream) > frag.slots))
         ):
             cap = _probed_capacity(
                 self._sketch_agg_groups(stream, frag), frag.slots

@@ -9,28 +9,125 @@ Binding rules:
 - DEVICE UDFs: recursive bind, implicit casts from the lattice, traced.
 - HOST_DICT UDFs: the string argument's dictionary is transformed
   host-side at bind time; the device sees an int32 gather (lookup table
-  for scalar returns, id-remap for string returns).
+  for scalar returns, id-remap for string returns). A string result's
+  image of the dictionary is remembered (``StringDictionary.image``:
+  a second bind runs the UDF on no string, a dictionary that grew by k
+  on k), and its remap reaches a fragment's programs as an OPERAND, not
+  as a literal of their text ("operand tables" below): a column with
+  millions of distinct strings neither costs a bind seconds each nor
+  writes its dictionary into the program.
 - STRING literals are encoded against the sibling argument's dictionary
   (equality filters on unseen literals become id==-1: always false).
 """
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from dataclasses import dataclass
 from typing import Callable, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
+from ..types.batch import bucket_capacity
 from ..types.dtypes import DataType
 from ..types.strings import NULL_ID, StringDictionary
 from ..udf.registry import Registry
 from ..udf.udf import Executor, apply_cast
+from . import trace as _trace
 from .plan import ColumnRef, Expr, FuncCall, Literal
 
 
 class BindError(TypeError):
     pass
+
+
+# -- operand tables ------------------------------------------------------------
+# A table a bound expression gathers from and that follows the DATA (a
+# dictionary-side UDF's remap: 4 B a distinct string of the column) is
+# an operand of the fragment's programs, not a literal in their text:
+# the text then depends on the table's bucket alone, so two dictionaries
+# of one bucket share a compiled program (the persistent cache hits on
+# another table's strings), and the table is uploaded once, not folded
+# into every program that reads it.
+#
+# Bind time: ``compile_fragment`` binds inside ``collect_operands()``;
+# a binder with such a table registers it (``_operand``) and gets a name.
+# Trace time: the fragment's jitted entry points take the tables as one
+# more argument and trace their bodies inside ``operands_bound(tables)``;
+# the bound closure asks ``_operand_value(name)`` and gathers from the
+# traced argument. Outside both (an expression bound on its own, a
+# fragment's unjitted pieces inside another program) the closure falls
+# back to the literal, as it always was.
+
+_operands = threading.local()
+
+
+class Operand:
+    """One operand table: the host array (padded to its bucket) and its
+    copy on the device, made at the first dispatch that needs it and
+    kept. Remembered on the image it was made from
+    (``DictImage.derived``), so every fragment over one image shares the
+    one copy."""
+
+    __slots__ = ("host", "_device", "_lock")
+
+    def __init__(self, host: np.ndarray):
+        self.host = host
+        self._device = None
+        self._lock = threading.Lock()
+
+    def device(self):
+        if self._device is None:
+            import jax
+
+            with self._lock:
+                if self._device is None:
+                    self._device = jax.device_put(self.host)
+        return self._device
+
+
+@contextlib.contextmanager
+def collect_operands():
+    """Bind time: the operand tables registered inside the block, by the
+    name their closures ask for ({name: Operand}, in bind order)."""
+    prev = getattr(_operands, "collecting", None)
+    found: dict = {}
+    _operands.collecting = found
+    try:
+        yield found
+    finally:
+        _operands.collecting = prev
+
+
+@contextlib.contextmanager
+def operands_bound(values: dict):
+    """Trace time: ``values`` ({name: array}) are the tables the closures
+    traced inside the block gather from."""
+    prev = getattr(_operands, "values", None)
+    _operands.values = values
+    try:
+        yield
+    finally:
+        _operands.values = prev
+
+
+def _operand(kind: str, make):
+    """Register the table ``make()`` gives with the collecting fragment
+    and return its name; None where nothing collects. Names count up in
+    bind order, so equal chains name their tables alike."""
+    found = getattr(_operands, "collecting", None)
+    if found is None:
+        return None
+    name = f"{kind}:{len(found)}"
+    found[name] = make()
+    return name
+
+
+def _operand_value(name):
+    values = getattr(_operands, "values", None)
+    return None if values is None or name is None else values.get(name)
 
 
 @dataclass
@@ -177,15 +274,39 @@ def _bind_host_dict(expr, udf, bound, str_literals, relation, dicts, registry) -
 
     src_fn = src.fn
     if udf.return_type == DataType.STRING:
-        new_dict, remap = src_dict.transform(call_one)
-        remap_j = np.asarray(remap)
+        # The UDF's image of the dictionary, remembered by (the UDF, its
+        # literal arguments, the dictionary's content): inside a
+        # ``dict_udf`` span on the query's trace.
+        key = (udf.fn, tuple(sorted(literal_vals.items())))
+        with _trace.dict_udf_span(udf.name, len(src_dict)) as note:
+            img, memo, ran = src_dict.image(call_one, key)
+            note(strings=ran, memo=memo)
+        new_dict, remap = img.dict, img.remap
+
+        def padded() -> Operand:
+            # To a bucket, with the null id: ids past the image (rows
+            # appended since) read null, and a dictionary that grows
+            # inside its bucket asks for no new program.
+            if "operand" not in img.derived:
+                host = np.full(
+                    bucket_capacity(len(remap) + 1), NULL_ID, np.int32
+                )
+                host[:len(remap)] = remap
+                img.derived["operand"] = Operand(host)
+            return img.derived["operand"]
+
+        name = _operand("dict_udf", padded)
 
         def fn(cols):
-            # jnp.asarray at TRACE time: no concrete jax Array is
-            # captured as a jit constant.
             ids = src_fn(cols)
+            table = _operand_value(name)
+            if table is None:
+                # jnp.asarray at TRACE time: no concrete jax Array is
+                # captured as a jit constant.
+                table = jnp.asarray(remap)
             return jnp.where(
-                ids >= 0, jnp.asarray(remap_j)[jnp.clip(ids, 0)], NULL_ID
+                ids >= 0,
+                table[jnp.clip(ids, 0, table.shape[0] - 1)], NULL_ID,
             )
 
         return BoundExpr(fn=fn, dtype=DataType.STRING, dict=new_dict)

@@ -19,8 +19,9 @@ multi-device partial-agg merge uses.
 
 from __future__ import annotations
 
+import functools
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import NamedTuple, Optional
 
 import jax
@@ -43,7 +44,7 @@ from ..types.dtypes import DataType, device_dtypes, pad_values
 from ..types.relation import Relation
 from ..udf.registry import Registry
 from ..udf.udf import UDADef, apply_cast
-from .expr import BindError, bind_expr
+from .expr import BindError, bind_expr, collect_operands, operands_bound
 from .fold_plan import INT_KEY_TYPES, FoldPlan, plan_fold
 from .plan import (
     AggOp,
@@ -128,6 +129,64 @@ class CompiledFragment:
     # (``Engine._sized_agg_fragment``).
     group_sketch: object = None
     init_sketch: object = None
+    # The operand tables the chain's expressions gather from
+    # (``exec/expr.py``, "operand tables": a dictionary-side UDF's remap),
+    # {name: Operand} in bind order. The jitted entry points take them as
+    # an argument of their own (``OperandProgram``): callers call
+    # ``update(state, cols, valid)`` as ever. ``remap_entries`` is the
+    # largest table's length (its bucket), 0 without one: the
+    # ``remap_entries`` attribute of the fold programs' dispatch spans.
+    operands: dict = field(default_factory=dict)
+    remap_entries: int = 0
+
+
+class OperandProgram:
+    """A jitted entry point of a fragment whose expressions read operand
+    tables: the program's first argument is the tables, filled in here,
+    so it is called (and lowered) with the arguments it always had. The
+    tables' device copies are made once and shared
+    (``expr.Operand.device``)."""
+
+    __slots__ = ("fn", "operands")
+
+    def __init__(self, fn, operands: dict):
+        self.fn = fn  # the jit stage, or its TrackedProgram
+        self.operands = operands
+
+    def __call__(self, *args):
+        return self.fn(
+            {n: o.device() for n, o in self.operands.items()}, *args
+        )
+
+    def lower(self, *args):
+        return self.fn.lower(
+            {n: jax.ShapeDtypeStruct(o.host.shape, o.host.dtype)
+             for n, o in self.operands.items()}, *args
+        )
+
+    @property
+    def kind(self):
+        return getattr(self.fn, "kind", None)
+
+    @property
+    def __name__(self):
+        return getattr(self.fn, "__name__", "program")
+
+
+def _program(fn, operands: dict):
+    """``jax.jit(fn)``; where the fragment binds operand tables, the
+    program takes them as its first argument and traces ``fn`` with
+    them bound, under ``fn``'s own name (the device trace's
+    ``jit_update*``)."""
+    if not operands:
+        return jax.jit(fn)
+
+    @functools.wraps(fn)
+    def run(tables, *args):
+        with operands_bound(tables):
+            return fn(*args)
+
+    return OperandProgram(jax.jit(run), operands)
 
 
 _FRAGMENT_CACHE: dict = {}
@@ -260,22 +319,22 @@ def _track_fragment_programs(frag, ops, cache_key, input_dicts,
     preg = default_program_registry()
     label = ",".join(type(o).__name__ for o in ops) or "(scan)"
     pins = (tuple(input_dicts.values()), registry)
-    frag.update = preg.wrap(
-        frag.update, "fragment_update", (cache_key, "update"), label,
-        pins=pins,
-    )
-    frag.update_all = preg.wrap(
-        frag.update_all, "fragment_scan_fold", (cache_key, "update_all"),
-        label, pins=pins,
-    )
-    frag.finalize = preg.wrap(
-        frag.finalize, "fragment_finalize", (cache_key, "finalize"),
-        label, pins=pins,
-    )
+
+    def wrap(fn, kind, which):
+        if isinstance(fn, OperandProgram):  # the jit stage inside it
+            fn.fn = preg.wrap(fn.fn, kind, (cache_key, which), label,
+                              pins=pins)
+            return fn
+        return preg.wrap(fn, kind, (cache_key, which), label, pins=pins)
+
+    frag.update = wrap(frag.update, "fragment_update", "update")
+    frag.update_all = wrap(frag.update_all, "fragment_scan_fold",
+                           "update_all")
+    frag.finalize = wrap(frag.finalize, "fragment_finalize", "finalize")
     if frag.native_fold is not None:
-        frag.native_fold["inputs_jit"] = preg.wrap(
+        frag.native_fold["inputs_jit"] = wrap(
             frag.native_fold["inputs_jit"], "native_fold_inputs",
-            (cache_key, "native_inputs"), label, pins=pins,
+            "native_inputs",
         )
 
 
@@ -475,6 +534,22 @@ def _propagate_stats(ops, stats):
 
 def compile_fragment(ops, input_relation, input_dicts, registry: Registry,
                      allow_dense: bool = True, col_stats=None) -> CompiledFragment:
+    # Every bind of the chain happens inside: the tables its expressions
+    # gather from become the programs' operands.
+    with collect_operands() as operands:
+        frag = _compile_fragment(
+            ops, input_relation, input_dicts, registry, allow_dense,
+            col_stats, operands,
+        )
+    frag.operands = operands
+    frag.remap_entries = max(
+        (len(o.host) for o in operands.values()), default=0
+    )
+    return frag
+
+
+def _compile_fragment(ops, input_relation, input_dicts, registry,
+                      allow_dense, col_stats, operands) -> CompiledFragment:
     pre, agg, post, limit = _split_chain(ops)
     apply_pre, rel1, dicts1 = _bind_pre_stage(pre, input_relation, dict(input_dicts), registry)
 
@@ -485,19 +560,19 @@ def compile_fragment(ops, input_relation, input_dicts, registry: Registry,
             ColumnMeta(name=n, dtype=t, dict=dicts1.get(n)) for n, t in rel1.items()
         ]
 
-        @jax.jit
         def update(cols, valid):
             return apply_pre(cols, _range_valid(cols, valid))
 
         return CompiledFragment(
-            relation=rel1, out_meta=out_meta, is_agg=False, update=update,
+            relation=rel1, out_meta=out_meta, is_agg=False,
+            update=_program(update, operands),
             limit=limit, apply_rows=apply_pre,
         )
 
     return _compile_agg(
         agg, post, limit, apply_pre, rel1, dicts1, registry,
         allow_dense=allow_dense, col_stats=_propagate_stats(pre, col_stats),
-        pre_ops=pre,
+        pre_ops=pre, operands=operands,
     )
 
 
@@ -1140,7 +1215,7 @@ def _bind_post_stage(post, out_meta, registry):
 
 
 def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
-                 allow_dense=True, col_stats=None, pre_ops=()):
+                 allow_dense=True, col_stats=None, pre_ops=(), operands=None):
     for c in agg.group_cols:
         if not rel1.has_column(c):
             raise BindError(f"group column {c!r} not in {rel1}")
@@ -1211,11 +1286,12 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         """
         return fold.merge(sa, sb)
 
-    @jax.jit
+    # ``update``, ``update_all``, ``group_sketch`` and ``finalize`` become
+    # programs at the end, once every expression of the chain is bound
+    # and the operand tables they read are known (``_program``).
     def update(state, cols, valid):
         return merge_states(state, window_state(cols, valid))
 
-    @jax.jit
     def update_all(state, cols_list, los, his):
         """Fold MANY equal-capacity windows in ONE program: stack the
         per-window planes on device and lax.scan the window fold. One
@@ -1250,7 +1326,6 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
 
     group_sketch = None
     if plan.layout != "dense" and group_cols:
-        @jax.jit
         def group_sketch(registers, cols, valid):
             valid = _range_valid(cols, valid)
             cols, valid = apply_pre(cols, valid)
@@ -1387,7 +1462,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 }
 
         native_fold = {
-            "inputs_jit": jax.jit(fold_inputs),
+            "inputs_jit": _program(fold_inputs, operands),
             "plan": tuple(
                 (ae.out_name, ae.uda_name, uda.init)
                 for ae, uda, _b, _c in aggs_bound
@@ -1399,9 +1474,9 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         relation=out_rel,
         out_meta=final_meta,
         is_agg=True,
-        update=update,
-        update_all=update_all,
-        finalize=jax.jit(finalize),
+        update=_program(update, operands),
+        update_all=_program(update_all, operands),
+        finalize=_program(finalize, operands),
         finalize_state=finalize,
         init_state=init_state,
         limit=limit,
@@ -1419,7 +1494,9 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         fold=plan.fold,
         group=plan.layout,
         slots=g,
-        group_sketch=group_sketch,
+        group_sketch=(
+            _program(group_sketch, operands) if group_sketch else None
+        ),
         init_sketch=(
             (lambda: hll.hll_init(1, _SKETCH_P)) if group_sketch else None
         ),

@@ -140,6 +140,13 @@ def _double_agg_groups(stream: "_Stream") -> "_Stream":
 #   (a join's output) and outnumber it: without sketches the plan's is
 #   AggOp's default, and 45 k edges climb from 4,096 slots by four
 #   compiles of the sort route;
+#   and where the chain COMPUTES a group key (a time bin, a UDF's image
+#   of a string column: ``_computed_group_keys``) over tables that hold
+#   more rows than the plan has slots: the planner has no sketch of a
+#   computed column, the plan's capacity is again AggOp's default, and
+#   a script's 75 k (query shape, second) groups climbed from 4,096 slots by
+#   six rebuckets, 33 s of compiling each, past the request's timeout
+#   (my chip run, PR 34: PERF.md section 6);
 # - after a fold that overflowed, the rung its climb ended on.
 #
 # Either is remembered per (chain, source tables) on the engine, beside
@@ -226,6 +233,40 @@ def _rows_in_hand(stream: "_Stream") -> int:
     join's output, a merged aggregate): known before any fold, where a
     table's rows in range are not."""
     return stream.source.length if isinstance(stream.source, HostBatch) else 0
+
+
+def _computed_group_keys(chain) -> bool:
+    """Whether the chain's aggregate groups by a column its own Maps
+    COMPUTE (``px.bin(time_)``, a UDF's image of a string column) and do
+    not merely select or rename: the planner sizes an aggregate from its
+    group columns' sketches at the source, so such a key leaves the plan
+    with AggOp's default capacity, which is no estimate."""
+    from .plan import ColumnRef, MapOp
+
+    agg_at = next(
+        (i for i, op in enumerate(chain) if isinstance(op, AggOp)), None
+    )
+    if agg_at is None:
+        return False
+    cols = set(chain[agg_at].group_cols)
+    for op in reversed(chain[:agg_at]):
+        if not isinstance(op, MapOp):
+            continue  # a filter neither makes nor renames a column
+        exprs = dict(op.exprs)
+        if any(not isinstance(exprs.get(c), ColumnRef) for c in cols):
+            return True
+        cols = {exprs[c].name for c in cols}
+    return False
+
+
+def _rows_at_source(stream: "_Stream") -> int:
+    """The rows a stream's tables hold (all their retention: a bound on
+    the rows in range, known before any window is staged); 0 for any
+    other source."""
+    src = stream.source
+    if not isinstance(src, list):
+        return 0
+    return sum(int(getattr(t, "num_rows", 0) or 0) for t in src)
 
 
 def _remember_climb(engine, chain, source, where: str, frag) -> None:

@@ -84,6 +84,7 @@ Consumers:
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import hashlib
 import json
@@ -95,7 +96,7 @@ from collections import deque
 from dataclasses import asdict, dataclass, field
 
 from ..config import get_flag
-from . import tracectx
+from . import threadmap, tracectx
 from .analyze import FragmentStats, QueryStats, StageStat, _Timer
 
 logger = logging.getLogger("pixie_tpu.slow_query")
@@ -200,6 +201,10 @@ class QueryResourceUsage:
     - ``join_rows_in`` / ``join_rows_out`` rows the query's joins took
       in (build + probe) and gave out: the ``join`` spans' ``build_rows``
       + ``probe_rows`` and ``rows_out`` (a span around every ``JoinOp``)
+    - ``dict_udf_strings`` strings the query's dictionary-side UDFs were
+      run on: the ``dict_udf`` spans' ``strings`` (a span around every
+      bind of a UDF that maps a string column's dictionary to strings;
+      0 once the images are remembered: ``StringDictionary.image``)
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -228,6 +233,7 @@ class QueryResourceUsage:
     merge_prepared_misses: int = 0
     join_rows_in: int = 0
     join_rows_out: int = 0
+    dict_udf_strings: int = 0
     skipped_windows: int = 0
     device_peak_bytes: int = 0
     freshness_lag_ms: float = 0.0
@@ -252,7 +258,8 @@ class QueryResourceUsage:
             "rows_in", "rows_out", "windows", "bytes_staged",
             "bytes_restaged", "wire_bytes", "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
-            "join_rows_in", "join_rows_out", "skipped_windows",
+            "join_rows_in", "join_rows_out", "dict_udf_strings",
+            "skipped_windows",
         ):
             setattr(self, k, getattr(self, k) + int(d.get(k, 0)))
         for k in ("device_ms", "compile_ms", "stall_ms", "decode_ms"):
@@ -435,6 +442,8 @@ class TracedFragment(FragmentStats):
             attrs["fold"] = self.fold
             if self.group:
                 attrs["group"], attrs["slots"] = self.group, int(self.slots)
+            if self.remap_entries:
+                attrs["remap_entries"] = int(self.remap_entries)
         return _FragmentSpanCtx(self, "device.dispatch", attrs, stage=stage)
 
     def _note_device(self, start_ns: int, end_ns: int) -> None:
@@ -690,6 +699,8 @@ class QueryTrace:
                     a.get("build_rows", 0) + a.get("probe_rows", 0)
                 )
                 u.join_rows_out += a.get("rows_out", 0)
+            elif s.name == "dict_udf":
+                u.dict_udf_strings += s.attributes.get("strings", 0)
             elif s.attributes.get("prepared") == "hit":
                 u.merge_prepared_hits += 1
             elif s.attributes.get("prepared") == "miss":
@@ -775,6 +786,35 @@ class QueryTrace:
                 }],
             }]
         }
+
+
+def current_trace():
+    """The trace of the query the calling thread is executing
+    (``Engine._execute_plan_scoped`` binds it through ``threadmap``), or
+    None outside one: for code the engine reaches but does not hand its
+    stats spine to (the expression binder)."""
+    entry = threadmap.current_entry()
+    t = entry.get("trace") if entry else None
+    return t if isinstance(t, QueryTrace) else None
+
+
+@contextlib.contextmanager
+def dict_udf_span(udf: str, entries: int):
+    """Around one bind of a dictionary-side UDF with a string result
+    (``exec/expr.py`` ``_bind_host_dict``): a ``dict_udf`` span under the
+    root of the query's trace, on whichever engine binds it, with ``udf``
+    and ``entries`` (the source dictionary's size). Yields ``note``,
+    which the binder calls with ``strings`` (how many the UDF was run
+    on) and ``memo`` (``hit`` / ``extend`` / ``miss``);
+    ``_finalize_usage`` counts ``strings`` into
+    ``usage.dict_udf_strings``. No span, and a ``note`` that does
+    nothing, outside a query."""
+    t = current_trace()
+    if t is None:
+        yield lambda **attrs: None
+        return
+    with t.span("dict_udf", udf=udf, entries=int(entries)) as sp:
+        yield sp.attributes.update
 
 
 class Tracer:

@@ -16,6 +16,7 @@ from __future__ import annotations
 import hashlib
 import struct
 import threading
+from collections import OrderedDict
 from typing import Iterable
 
 import numpy as np
@@ -23,11 +24,36 @@ import numpy as np
 NULL_ID = -1
 
 
+class DictImage:
+    """What a string -> string function makes of a dictionary's first
+    ``n`` strings: ``dict`` holds its results, ``remap[old_id]`` is a
+    result's id there. ``derived`` is the holder's to hang on it what
+    it makes of the remap once (the binder's padded device table)."""
+
+    __slots__ = ("dict", "remap", "n", "derived", "source")
+
+    def __init__(self, dict_: "StringDictionary", remap: np.ndarray,
+                 source: tuple = ()):
+        self.dict = dict_
+        self.remap = remap
+        self.n = len(remap)
+        self.derived: dict = {}
+        self.source = source  # the ``content_key`` it is the image of
+
+
+# Images by (function key, source ``content_key``): equal content from a
+# fresh object (a dictionary decoded from the wire) finds the image its
+# twin made. LRU: an image of a large dictionary holds 4 B a string.
+_IMAGES: "OrderedDict[tuple, DictImage]" = OrderedDict()
+_IMAGES_MAX = 32
+_IMAGES_LOCK = threading.Lock()
+
+
 class StringDictionary:
     """Append-only string <-> int32 id mapping."""
 
     __slots__ = ("_str_to_id", "_strings", "_fp", "_fp_len", "_fp_digest",
-                 "_fp_lock")
+                 "_fp_lock", "_images", "_images_lock")
 
     def __init__(self, strings: Iterable[str] = ()):
         self._strings: list[str] = []
@@ -43,6 +69,10 @@ class StringDictionary:
         self._fp_len = 0
         self._fp_digest = b""
         self._fp_lock = threading.Lock()
+        # Images of this dictionary under string -> string functions
+        # (``image``), by the function's key.
+        self._images: dict = {}
+        self._images_lock = threading.Lock()
         for s in strings:
             self.get_or_add(s)
 
@@ -122,11 +152,61 @@ class StringDictionary:
         Returns (new_dict, remap) where ``remap[old_id] -> new_id``; device
         side applies the remap as a gather. O(K distinct), not O(rows).
         """
-        new = StringDictionary()
-        remap = np.empty(len(self._strings), dtype=np.int32)
-        for i, s in enumerate(self._strings):
-            remap[i] = new.get_or_add(fn(s))
-        return new, remap
+        img = self._extended(fn, None, len(self._strings))
+        return img.dict, img.remap
+
+    def _extended(self, fn, have: "DictImage | None", n: int) -> DictImage:
+        """The image of the first ``n`` strings under ``fn``: ``have``
+        (the image of fewer) taken on by the strings it lacks. The
+        results' dictionary is shared with ``have`` and grows in place:
+        it is append-only, so the ids ``have`` handed out stay good."""
+        new = StringDictionary() if have is None else have.dict
+        done = 0 if have is None else have.n
+        remap = np.empty(n, dtype=np.int32)
+        remap[:done] = () if have is None else have.remap
+        strings = self._strings
+        for i in range(done, n):
+            remap[i] = new.get_or_add(fn(strings[i]))
+        return DictImage(new, remap)
+
+    def image(self, fn, key) -> tuple:
+        """``transform`` remembered: (image, ``hit`` / ``extend`` /
+        ``miss``, the strings ``fn`` was run on).
+
+        ``key`` stands for ``fn`` (the UDF and its literal arguments;
+        hashable, and it holds whatever its identity rests on). An image
+        is remembered on this dictionary and, by ``content_key``, for
+        every dictionary of equal content, and it grows as its source
+        does, as ``content_key`` itself: a second bind of the same
+        function pays for no string, a dictionary that grew by k for k.
+        Binds of one dictionary queue on its lock: the second of two
+        that race waits for the first and hits."""
+        with self._images_lock:
+            ck = self.content_key()
+            n = ck[0]
+            mine = self._images.get(key)
+            if mine is not None and mine.n == n:
+                return mine, "hit", 0
+            if mine is None:
+                with _IMAGES_LOCK:
+                    shared = _IMAGES.get((key, ck))
+                    if shared is not None:
+                        _IMAGES.move_to_end((key, ck))
+                if shared is not None:
+                    self._images[key] = shared
+                    return shared, "hit", 0
+            img = self._extended(fn, mine, n)
+            img.source = ck
+            self._images[key] = img
+            with _IMAGES_LOCK:
+                if mine is not None:  # the shorter image it grew out of
+                    _IMAGES.pop((key, mine.source), None)
+                _IMAGES[(key, ck)] = img
+                while len(_IMAGES) > _IMAGES_MAX:
+                    _IMAGES.popitem(last=False)
+            if mine is None:
+                return img, "miss", n
+            return img, "extend", n - mine.n
 
     def union(self, other: "StringDictionary") -> tuple["StringDictionary", np.ndarray, np.ndarray]:
         """Merged dict + id remaps for self and other (join/union alignment)."""

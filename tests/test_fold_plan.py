@@ -165,6 +165,22 @@ KEYED_CASES = [
     ("two_keys_have_the_base_budget", (("shard", D.INT64), ("ok", D.BOOLEAN)),
      [(1 << 20, 0, 1), (2, 0, 1)], HTTP, {},
      (True, None, False, "sorted_int")),
+    # px/sql_stats' two COMPUTED keys: the ids of a dictionary a UDF made
+    # at bind time (290 shapes) and a one-second bin of ``time_`` whose
+    # domain the table's stats give (4,096 steps): known domains, over the
+    # base budget together, and an integer key does not pack, so the
+    # planes sort as they are behind the leading id, on the PEM and, with
+    # no domain trusted, on the Kelvin.
+    ("two_computed_keys", (("query_norm", D.STRING), ("window", D.TIME64NS)),
+     [(291, 0, 1), (4_096, 1_699_996_000_000_000_000, 1_000_000_000)],
+     (("n", "count", I64), ("lat_mean", "mean", I64)),
+     {"max_groups": 1 << 17}, (True, None, True, "sorted_int")),
+    ("two_computed_keys_on_the_kelvin",
+     (("query_norm", D.STRING), ("window", D.TIME64NS)),
+     [(291, 0, 1), (4_096, 1_699_996_000_000_000_000, 1_000_000_000)],
+     (("n", "count", I64), ("lat_mean", "mean", I64)),
+     {"max_groups": 1 << 17, "allow_dense": False},
+     (True, None, True, "sorted_int")),
     ("count_alone", (SVC, PATH), KEYED, (("n", "count", I64),), {},
      (True, (33, 65_537), False, "sorted_int")),
     # An aggregate that needs a row's group id keeps the id form; under

@@ -27,7 +27,16 @@ case (PR 33) is ``px/service_stats``' fold, the one chain whose
 ``quantiles`` sort their rows (``ops/tdigest.py``): ``update`` and
 ``update_all`` at the 2^21-row window on one chip, and the four-chip
 cell's ``shard_map`` step over the described 2x2's four devices (2^19 rows
-a chip), each lowered AND compiled.
+a chip), each lowered AND compiled. The ``sql`` case (PR 34) is
+``px/sql_stats`` over ``sql_stats_1chip``'s ``mysql_events``, served on TWO
+seeds (``sql`` and ``sql2``): the PEM's keyed fold of one window and its
+joint-key sketch, and the Kelvin's unpacked ``merge_finalize``, at the
+cell's capacities (``SQL``), lowered AND compiled. The two seeds' strings
+differ and their texts must not: the remap of ``px.normalize_mysql`` is
+an operand of the programs (``exec/expr.py``), so the last line says
+``two_seeds_one_text`` and the exit code is 1 if it is false. (The
+operand's shape follows the served run's small dictionary, not the
+cell's 2^22-entry bucket.)
 
     JAX_PLATFORMS=cpu python tools/fold_hlo.py --out DIR
 
@@ -67,6 +76,19 @@ FLOW = {
 }
 #: The single-shot join's (build, probe, output) buckets.
 FLOW_JOIN = (1 << 12, 1 << 16, 1 << 17)
+#: The ``sql`` case's shapes, as ``FLOW``'s: ``sql_stats_1chip.sql_recent``
+#: settles on 2^17 slots for its 74 k (shape, second) groups, which the
+#: Kelvin merges in a 2^17 bucket.
+SQL = {"count+mean_by_query_norm_window": (1 << 17, 1 << 17, WINDOW)}
+SQL_ROWS = 1 << 16  # every one of the 290 shapes has rows at this size
+
+
+def _sized(case):
+    """The chains' (capacity, merge bucket, window) where the case runs a
+    cell's one script at the cell's shapes; None otherwise."""
+    if case.startswith("flow"):
+        return FLOW
+    return SQL if case.startswith("sql") else None
 
 
 def _capture(batches, table="http_events",
@@ -180,7 +202,7 @@ def _lower(case, captured, topo_device, out_dir, lines):
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from pixie_tpu.exec.fragment import compile_fragment
+    from pixie_tpu.exec.fragment import OperandProgram, compile_fragment
     from pixie_tpu.exec.plan import AggOp
     from pixie_tpu.types.dtypes import device_dtypes
     from pixie_tpu.udf.registry import default_registry
@@ -194,7 +216,7 @@ def _lower(case, captured, topo_device, out_dir, lines):
         )
 
     for ops, relation, dicts, allow_dense, col_stats in captured:
-        window, flow = WINDOW, case.startswith("flow")
+        window, flow = WINDOW, _sized(case) is not None
         digest = case == "digest"
         if digest and not (allow_dense and _folds_quantiles(ops)):
             continue
@@ -203,7 +225,7 @@ def _lower(case, captured, topo_device, out_dir, lines):
         elif flow:
             if not allow_dense:
                 continue  # the Kelvin's merge of a chain: ``_lower_merges``
-            slots, merged_at, window = FLOW[_agg_label(ops)]
+            slots, merged_at, window = _sized(case)[_agg_label(ops)]
         else:
             slots = None
         if slots is not None:
@@ -234,8 +256,13 @@ def _lower(case, captured, topo_device, out_dir, lines):
                 state, cols, (scalar, scalar)),
             "update_all": lambda: frag.update_all.lower(
                 state, (cols,) * 3, bounds, bounds),
-            "group_sketch": lambda: jax.jit(frag.group_sketch).lower(
-                on(jax.eval_shape(frag.init_sketch)), cols, valid),
+            # (A program that takes operand tables lowers itself, with
+            # the tables' shapes: ``fragment.OperandProgram``.)
+            "group_sketch": lambda: (
+                frag.group_sketch
+                if isinstance(frag.group_sketch, OperandProgram)
+                else jax.jit(frag.group_sketch)
+            ).lower(on(jax.eval_shape(frag.init_sketch)), cols, valid),
         }
         if flow:  # what the cell runs: one window in range a chain
             wanted = ("update", "group_sketch" if who == "pem" else "finalize")
@@ -352,8 +379,9 @@ def _lower_merges(case, merges, topo_device, out_dir, lines):
         caps = [cap for _idx, _live, cap in slots]
         if case.startswith("keyed") and not payloads[0].dense_domains:
             caps = [KEYED_SLOTS // 2] * len(payloads)
-        elif case.startswith("flow"):
-            caps = [FLOW[_agg_label(payloads[0].chain)][1]] * len(payloads)
+        elif _sized(case) is not None:
+            caps = [_sized(case)[_agg_label(payloads[0].chain)][1]] * len(
+                payloads)
         g = bucket_capacity(sum(caps))
         rec = bridge._prepare_merge(engine, payloads, tail, g, None)
         name = (f"{case}.kelvin.{_agg_label(payloads[0].chain)}"
@@ -379,7 +407,8 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--out", default=None, help="directory for the texts")
     ap.add_argument("--cases", default="dense,keyed,flow",
-                    help="comma-separated, of dense, keyed, flow, digest")
+                    help="comma-separated, of dense, keyed, flow, digest, "
+                         "sql")
     args = ap.parse_args()
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -387,7 +416,7 @@ def main():
     import jax
 
     import pixie_tpu  # noqa: F401
-    from benchmark.builders import served_conn, served_http_skew
+    from benchmark.builders import served_conn, served_http_skew, served_sql
     from pixie_tpu.ingest.replay import gen_http_events
 
     def config(name):
@@ -408,12 +437,23 @@ def main():
             list(served_conn.batches(conn, SMALL_WINDOW, 0, ROWS)),
             table="conn_stats", scripts=("px/net_flow_graph",))
 
+    def sql(seed):
+        data = served_sql.make_data(config("sql_stats_1chip"), seed, SQL_ROWS)
+        return _capture(
+            list(served_sql.batches(data, SMALL_WINDOW, 0, SQL_ROWS)),
+            table="mysql_events", scripts=("px/sql_stats",))
+
     cases = {
         "dense": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "keyed": keyed, "flow": flow,
         "digest": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
+        "sql": lambda: sql(3_400_000_019),
+        "sql2": lambda: sql(3_400_000_023),
     }
-    captured = {case: cases[case]() for case in args.cases.split(",")}
+    wanted = args.cases.split(",")
+    if "sql" in wanted:
+        wanted.append("sql2")
+    captured = {case: cases[case]() for case in wanted}
 
     # From here on the code sees the chip: the one read of the backend
     # (ops/routes.py) answers "tpu", and every shape sits on a described
@@ -432,6 +472,17 @@ def main():
         _lower_merges(case, merges, topo.devices[0], args.out, lines)
         if case == "flow":
             _lower_join(case, topo.devices[0], args.out, lines)
+    if "sql" in captured:
+        texts = {
+            case: sorted((line["case"].split(".", 1)[1], line["program"],
+                          line["sha256"]) for line in lines
+                         if line["case"].split(".")[0] == case)
+            for case in ("sql", "sql2")
+        }
+        same = bool(texts["sql"]) and texts["sql"] == texts["sql2"]
+        print(json.dumps({"case": "sql", "programs": len(texts["sql"]),
+                          "two_seeds_one_text": same}), flush=True)
+        return 0 if same else 1
     return 0
 
 
