@@ -336,12 +336,13 @@ def _timed_query(eng, pxl: str):
     return time.perf_counter() - t0, host
 
 
-def _fold_routes(eng) -> list:
+def _fold_routes(eng, attr: str = "fold") -> list:
     """The ``fold`` attributes of the last query's device.dispatch spans:
-    how its window-fold programs said they fold."""
+    how its window-fold programs said they fold (``attr`` ``ride``: how
+    a keyed sorted fold's windows carried their sums)."""
     return sorted({
-        sp.attributes["fold"] for sp in eng.tracer.last().spans
-        if sp.name == "device.dispatch" and "fold" in sp.attributes
+        sp.attributes[attr] for sp in eng.tracer.last().spans
+        if sp.name == "device.dispatch" and attr in sp.attributes
     })
 
 
@@ -683,8 +684,8 @@ def phase_flow(seed: int, rows: int, meter: CompileMeter,
                  if sp.name == "join"]
         emit(phase="flow", query="px/net_flow_graph", run=run, rows=rows,
              secs=secs, edges=len(want["key"]), fold=_fold_routes(eng),
-             group=_fold_groups(eng), join=joins, compile=compiled,
-             numbers=numbers)
+             group=_fold_groups(eng), ride=_fold_routes(eng, "ride"),
+             join=joins, compile=compiled, numbers=numbers)
         over = sorted(k for k, v in numbers.items() if v > ref.LIMITS[k])
         assert not over, f"px/net_flow_graph ({run}): over its limit: {over}"
     assert compiled["programs"] == 0, (
@@ -696,6 +697,10 @@ def phase_flow(seed: int, rows: int, meter: CompileMeter,
         f"the join ran on the {join['where']}: {join}")
     assert not on_tpu or _fold_routes(eng) == ["sorted_int"], (
         f"fold spans say {_fold_routes(eng)}, not sorted_int")
+    # ``flows``' two sums at a 2^21-row window ride the key sort (PR 35);
+    # the re-aggregation of the join's rows is short against its slots.
+    assert not on_tpu or "payload" in _fold_routes(eng, "ride"), (
+        f"ride spans say {_fold_routes(eng, 'ride')}, no payload")
 
 
 def main(argv=None) -> int:

@@ -44,8 +44,9 @@ from .bridge import (  # noqa: F401  (re-exported)
 )
 from . import threadmap
 from .fragment import compile_fragment_cached as compile_fragment
+from .fragment import window_rows
 from .pipeline import WindowPipeline
-from .trace import Tracer, clock_ns, plan_script
+from .trace import Span, Tracer, clock_ns, plan_script
 from .joins import (  # noqa: F401  (re-exported)
     _join_dispatch,
     _union_host,
@@ -1042,18 +1043,28 @@ class Engine:
         )
         pend_cols, pend_lo, pend_hi = [], [], []
 
+        def note_ride(span, cols):
+            # How a keyed sorted fold carries this window's sums, on a
+            # traced fragment's ``device.dispatch``.
+            if frag.ride is not None and isinstance(span, Span):
+                way = frag.ride(window_rows(cols))
+                if way:
+                    span.attributes["ride"] = way
+
         def flush_pending(state):
             if not pend_cols:
                 return state
             if len(pend_cols) == 1:
-                with _dispatch(stats, agg_step):
+                with _dispatch(stats, agg_step) as span:
+                    note_ride(span, pend_cols[0])
                     state = agg_step(
                         state, pend_cols[0], (pend_lo[0], pend_hi[0])
                     )
                     _block_if(stats, state)
             else:
                 with _dispatch(stats, frag.update_all,
-                               windows=len(pend_cols)):
+                               windows=len(pend_cols)) as span:
+                    note_ride(span, pend_cols[0])
                     state = frag.update_all(
                         state, tuple(pend_cols),
                         # Host int lists, not device buffers — no sync.
@@ -1087,7 +1098,8 @@ class Engine:
                         state = flush_pending(state)
                 else:
                     state = flush_pending(state)
-                    with _dispatch(stats, agg_step):
+                    with _dispatch(stats, agg_step) as span:
+                        note_ride(span, cols)
                         state = agg_step(state, cols, valid)
                         _block_if(stats, state)
                 if stats is not None:

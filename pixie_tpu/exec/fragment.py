@@ -121,6 +121,10 @@ class CompiledFragment:
     fold: str = ""
     group: str = ""
     slots: int = 0
+    # Under ``sorted_int``: n -> how an n-row window's sum planes reach
+    # group order (``ops/routes.py`` ``sorted_fold_ride``), the ``ride``
+    # attribute of the window programs' dispatch spans. None otherwise.
+    ride: object = None
     # A keyed fold's probe (agg only, None on a dense domain): jitted
     # (registers, cols, valid) -> registers, the window's rows folded
     # into ONE HyperLogLog row over the JOINT group key (``ops/hll.py``),
@@ -338,6 +342,11 @@ def _track_fragment_programs(frag, ops, cache_key, input_dicts,
         )
 
 
+def window_rows(cols) -> int:
+    """The length of a staged window's planes."""
+    return next(p for c, p in cols.items() if c != "__side__")[0].shape[0]
+
+
 def _range_valid(cols, valid):
     """Materialize ``valid`` when it arrives as a (lo, hi) row-range pair
     (device-resident windows carry no mask; rather than a separate
@@ -345,10 +354,7 @@ def _range_valid(cols, valid):
     from two scalars)."""
     if isinstance(valid, tuple):
         lo, hi = valid
-        n = next(
-            p for c, p in cols.items() if c != "__side__"
-        )[0].shape[0]
-        iota = jnp.arange(n, dtype=jnp.int32)
+        iota = jnp.arange(window_rows(cols), dtype=jnp.int32)
         return (iota >= lo) & (iota < hi)
     return valid
 
@@ -698,6 +704,7 @@ class _Fold(NamedTuple):
     window: object  # (cols, valid), pre-stage applied -> the window's state
     merge: object  # (state_a, state_b) -> merged state
     key_planes: object  # state -> [g] key planes, ``key_plane_index`` order
+    ride: object = None  # ``CompiledFragment.ride`` (the sorted fold's)
 
 
 def _dense_slot_ids(plan, rel1, key_plane_index, cols, valid):
@@ -1058,23 +1065,47 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
             "overflow": n_groups > g,
         }
 
+    # A window's statistic planes by aggregate, each (kind, which distinct
+    # argument expression): static, so the span can say how they ride.
+    spec = {}
+    for ae, _uda, _b, casts in aggs_bound:
+        if ae.uda_name == "count":
+            spec[ae.out_name] = (("rows", None),)
+            continue
+        fkey = (_struct_key(ae.args), casts[0])
+        spec[ae.out_name] = (
+            (("sum", fkey), ("rows", None)) if ae.uda_name == "mean"
+            else ((ae.uda_name, fkey),)
+        )
+
+    # The first maximum is a sort key; a sum of its own plane is read off
+    # it (a minimum's plane is ``~v``: another plane). The other distinct
+    # sum planes ride, by the window's length (``sorted_fold_ride``).
+    stats = [s for kinds in spec.values() for s in kinds]
+    primary = next((s for s in stats if s[0] in ("max", "min")), None)
+    ride_planes = {fkey for kind, fkey in stats if kind == "sum"}
+    if primary is not None and primary[0] == "max":
+        ride_planes.discard(primary[1])
+    lead_words = 1 if pack_doms is not None else sum(
+        2 if jnp.dtype(dt).itemsize == 8 else 1 for dt in key_dtypes
+    ) + (0 if plan.lead_id else 1)
+    ride = functools.partial(
+        _routes.sorted_fold_ride, g=g, planes=len(ride_planes),
+        key_words=lead_words + (2 if primary is not None else 0),
+    )
+
     def window(cols, valid):
         planes = {}  # one plane a distinct argument expression
-        leaves = {}
         for ae, _uda, arg_bound, casts in aggs_bound:
-            if ae.uda_name == "count":
-                leaves[ae.out_name] = (("rows", None),)
-                continue
-            fkey = (_struct_key(ae.args), casts[0])
-            if fkey not in planes:
-                a = apply_cast(arg_bound[0].fn(cols), *casts[0])
-                planes[fkey] = jnp.broadcast_to(
-                    a, valid.shape).astype(jnp.int64)
-            v = planes[fkey]
-            leaves[ae.out_name] = (
-                (("sum", v), ("rows", None)) if ae.uda_name == "mean"
-                else ((ae.uda_name, v),)
-            )
+            for _kind, fkey in spec[ae.out_name]:
+                if fkey is not None and fkey not in planes:
+                    a = apply_cast(arg_bound[0].fn(cols), *casts[0])
+                    planes[fkey] = jnp.broadcast_to(
+                        a, valid.shape).astype(jnp.int64)
+        leaves = {
+            out: tuple((kind, planes.get(fkey)) for kind, fkey in kinds)
+            for out, kinds in spec.items()
+        }
         return _sorted_state(
             [cols[c][i] for c, i in key_plane_index], valid, leaves
         )
@@ -1107,7 +1138,7 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
 
     return _Fold(
         _keyed_init_keys(g, rel1, key_plane_index), window, merge,
-        lambda state: state["keys"],
+        lambda state: state["keys"], ride,
     )
 
 
@@ -1494,6 +1525,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         fold=plan.fold,
         group=plan.layout,
         slots=g,
+        ride=fold.ride,
         group_sketch=(
             _program(group_sketch, operands) if group_sketch else None
         ),

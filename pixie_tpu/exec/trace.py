@@ -35,8 +35,10 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   ``pallas_f32``, ``sorted_digest``, ``xla``, ``mixed:...`` or
   ``sorted_int``, as ``CompiledFragment.fold``
   decided at compile time, with ``group``: ``dense`` / ``sorted`` /
-  ``hashed`` and ``slots``: the capacity g it was compiled at); child
-  of its fragment
+  ``hashed`` and ``slots``: the capacity g it was compiled at; under
+  ``sorted_int`` also ``ride``: ``payload`` / ``index``, how this
+  window's sum planes reach group order, ``ops/routes.py``
+  ``sorted_fold_ride``, absent where none rides); child of its fragment
 - ``rebucket``            one per re-fold after a group-capacity overflow
   (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
   rows, ``kelvin`` the merge of states): the compile at twice the slots

@@ -42,6 +42,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .routes import sorted_fold_ride
 from .scan import _CHUNK, blocked_cumsum
 
 
@@ -325,14 +326,27 @@ def scatter_carry(carry, ids, valid, capacity: int, init_carry):
 # is elementwise work, scans, and moving one row a group to the front
 # (``_front``). No argsort-and-fetch, no window-long gather or scatter.
 #
-# What shapes the sorts is the TPU compiler's time, not the chip's: a
-# 1-D sort of k u32 operands compiles in 3 / 8 / 13 / 38 / 86 s at k = 1 /
-# 2 / 3 / 6 / 10 (a described v5e, PR 29; more with several keys, more
-# again with 64-bit operands), the same at 2^18 rows as at 2^21, while a
-# BATCHED two-operand sort ([P, N] along N, the key row repeated) compiles
-# in 5 s whatever P (and runs in 3 ms at 2^18 rows, but 19-30 ms at 2^21).
-# So only the keys ride the first sort, and payload planes follow under a
-# unique key (``_batched_sort``, ``_front``).
+# What shapes the sorts is the TPU compiler's time beside the chip's: at
+# 2^21 rows the sums' words as payload operands of the key sort take 5-13
+# ms where a row index, an inverse sort and a BATCHED two-operand sort
+# ([P, N] along N, the key row repeated) take 25-38, but every operand and
+# every key adds seconds to the sort's compile, and at 2^18 rows there are
+# 2 ms to win (the sweep: ``ops/routes.py`` ``SORT_PAYLOAD_MAX_OPERANDS``).
+# So what rides which sort follows the rows against the slots and the
+# operand count (``sorted_fold_ride`` there, static at trace time):
+#
+# - The group key's words and the primary maximum's are the keys of the
+#   one sort, always; a sum of the primary maximum's own plane is read
+#   off it.
+# - A WINDOW's other sum planes (n >= 4 g: 2^21 rows into 2^17 slots)
+#   ride that sort as payload operands (``payload``), where keys and
+#   words together stay within ``SORT_PAYLOAD_MAX_OPERANDS``.
+# - A MERGE of two states (n = 2 g: short, and a mean's two sums and a
+#   count beside the key would make a 7-to-9-operand sort) carries the
+#   row index instead: an inverse sort, then the planes
+#   follow under that unique key in one batched sort (``index``,
+#   ``_batched_sort``). So does a window past the operand limit.
+# - One row a group moves to the front by ``_front``, on the same rule.
 
 _U32_MAX = 0xFFFFFFFF
 _I32_MAX = 0x7FFFFFFF
@@ -433,10 +447,16 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
         or one packed code); equal keys are one group.
       valid: bool[N].
       sums: list of int64[N] planes to add up a group (wrapping, exact).
+        One that is also (``is``) the first of ``maxes`` is carried once,
+        as that key. The others reach group order as ``ops/routes.py``
+        ``sorted_fold_ride`` says for these N, g and operand counts: a
+        window's (N >= 4 g) as payload operands of the key sort, up to
+        ``SORT_PAYLOAD_MAX_OPERANDS`` in all; a merge's (N = 2 g), and
+        past that limit, through the row index, an inverse sort and one
+        batched sort.
       maxes: list of int64[N] planes to take the greatest of a group (a
         minimum is the maximum of ``~v``). The first rides the sort as
-        its last key, and a plane that is also (``is``) in ``sums`` is
-        carried once; each further maximum costs a sort of its own.
+        its last key; each further maximum costs a sort of its own.
       max_groups: static slot count g.
       folded_flag: the caller guarantees ``keys[0]`` of a valid row is
         never 0xFFFFFFFF, so "not valid" needs no operand of its own.
@@ -459,22 +479,30 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
     ride = [s for s in sums if s is not primary]
     operands = lead + (_i64_words(primary) if primary is not None else [])
     n_keys = len(operands)
-    # Only keys (and, when planes must follow, the row index) ride the
-    # many-key sort; a group's rows may come out in any order.
-    out = jax.lax.sort(operands + ([iota] if ride else []), dimension=0,
+    way = sorted_fold_ride(n, g, n_keys, len(ride))
+
+    def ride_words():
+        return [w for s in ride for w in _i64_words(jnp.where(valid, s, 0))]
+
+    # A group's rows may come out in any order. Beside the keys ride a
+    # window's sum words (``payload``) or, where the planes follow
+    # through it, the row index (``index``).
+    carried = (ride_words() if way == "payload"
+               else [iota] if way == "index" else [])
+    out = jax.lax.sort(operands + carried, dimension=0,
                        is_stable=False, num_keys=n_keys)
     s_lead = list(out[:n_lead])
     s_valid = (s_lead[0] != u32(_U32_MAX)) if folded_flag else (s_lead[0] == 0)
     s_primary = (
         _i64_from_words(*out[n_lead:n_keys]) if primary is not None else None
     )
-    if ride:
+    if way == "payload":
+        rode = list(out[n_keys:])
+    elif way == "index":
         # Where each row went: the inverse of the order it came out in.
         dest = jax.lax.sort([out[n_keys], iota], dimension=0,
                             is_stable=False, num_keys=1)[1]
-        rode = _batched_sort(dest, [
-            w for s in ride for w in _i64_words(jnp.where(valid, s, 0))
-        ])[1]
+        rode = _batched_sort(dest, ride_words())[1]
     sorted_sums = []
     for s in sums:
         if s is primary:

@@ -88,6 +88,52 @@ def digest_route(platform: str, slots: int) -> str:
 JOIN_SORT_IDS_MAX_ROWS = 1 << 21
 
 
+#: Most u32 operands (key words + payload words) of the keyed fold's one
+#: ``lax.sort`` (``ops/groupby.py`` ``sorted_group_fold``): up to it a
+#: window's sum planes ride the key sort as payload, past it they follow
+#: through a row index as a merge's do. What bounds it is the compiler's
+#: seconds in a script's first request (120 s to answer), against 20 ms a
+#: window ever after. ``tools/fold_sweep.py --micro --only payload_sort
+#: index_way`` on the chip's host (a v5e, PR 35), run ms / compile s of
+#: the payload sort against the index way's three sorts, 2^21 rows:
+#:
+#:   key words  payload words  operands   payload       index
+#:       1            2            3      5.0 / 16.8   24.9 / 10.7
+#:       1            4            5      7.2 / 25.5   27.5 /  8.5
+#:       1            6            7      9.6 / 38.0   35.3 /  9.6
+#:       3            2            5      7.6 / 44.7   27.7 / 30.4
+#:       3            4            7     10.0 / 76.8   30.0 / 33.2
+#:       3            6            9     13.1 / 89.6   38.4 / 30.9
+#:
+#: (2^18 rows: 1.3-2.2 against 3.2-4.0 ms at the same compile seconds.)
+#: The payload sort is 20-25 ms a window faster wherever it was tried and
+#: 6-17 s dearer to compile up to five operands, 28-59 s from seven: with
+#: three key words a first request that compiles ~35 s beside its window
+#: program would pass 100 s. One sort a plane under the same keys (what a
+#: further maximum takes) was reckoned as a step between and is none: two
+#: or three five-operand sorts compile in 89 / 134 s and run in 15 / 23
+#: ms where one sort carries the same planes in 77 / 90 s and 10 / 13 ms.
+SORT_PAYLOAD_MAX_OPERANDS = 5
+
+
+def sorted_fold_ride(n: int, g: int, key_words: int, planes: int) -> str:
+    """How the sum planes that are not the primary maximum reach group
+    order in ``sorted_group_fold``, from what is static at trace time: n
+    rows into g slots, ``key_words`` sort keys (the group key's and the
+    primary maximum's), ``planes`` INT64 planes of two words each. ``""``
+    with no such plane; else ``payload`` (operands of the key sort) or
+    ``index`` (the row index rides, an inverse sort, a batched [P, N]
+    sort). The ``ride`` attribute of a ``sorted_int`` window's
+    ``device.dispatch``."""
+    if not planes:
+        return ""
+    # ``_front``'s rule: a window is long against its slots, a merge of
+    # two states is N = 2 g.
+    if n >= 4 * g and key_words + 2 * planes <= SORT_PAYLOAD_MAX_OPERANDS:
+        return "payload"
+    return "index"
+
+
 def int_fold_groups(g: int) -> int:
     """g padded for ``dense_group_fold_int``: to whole 128-lane tiles,
     and above one group block to whole blocks (so a dictionary one entry

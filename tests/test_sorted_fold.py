@@ -23,9 +23,11 @@ from pixie_tpu.config import override_flag
 from pixie_tpu.exec.engine import Engine
 from pixie_tpu.exec.fragment import compile_fragment
 from pixie_tpu.exec.plan import AggExpr, AggOp, ColumnRef
+from pixie_tpu.ops import routes
 from pixie_tpu.ops.groupby import (
     _to_bits, join_u32, sorted_group_fold, split_u32,
 )
+from pixie_tpu.ops.routes import sorted_fold_ride
 from pixie_tpu.types.dtypes import DataType
 from pixie_tpu.types.relation import Relation
 from pixie_tpu.types.strings import NULL_ID, StringDictionary
@@ -175,6 +177,202 @@ def test_more_groups_than_slots_is_reported():
     assert (np.asarray(rows) == 1).all()
 
 
+# -- a window's sums ride the key sort (PR 35) ----------------------------------
+
+def _sort_eqns(jaxpr):
+    """Every ``sort`` equation of a jaxpr, nested ones too, in order:
+    (operand shapes, num_keys, dimension, the primitives that made each
+    operand)."""
+    made, out = {}, []
+
+    def walk(jp):
+        for e in jp.eqns:
+            for v in e.outvars:
+                made[id(v)] = e.primitive.name
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+            if e.primitive.name == "sort":
+                out.append((
+                    [tuple(v.aval.shape) for v in e.invars],
+                    e.params["num_keys"], e.params["dimension"],
+                    [made.get(id(v)) for v in e.invars],
+                ))
+
+    walk(jaxpr.jaxpr)
+    return out
+
+
+def _ride_case(keys, planes, shape, n, g, seed):
+    """(key words, valid, sum planes) of an n-row window: ``packed`` is
+    one u32 code, ``lead_id`` a dictionary id + 1 and an INT64's two
+    words, as ``exec/fragment.py`` hands a packed and an unpacked key
+    (``folded_flag`` both)."""
+    rng = np.random.default_rng(seed)
+    distinct = 3 * g if shape == "overflow" else max(g // 2, 1)
+    label = rng.integers(0, distinct, n)
+    if keys == "packed":
+        words = [label.astype(np.uint32)]
+    else:
+        second = (label % 7).astype(np.int64) * (1 << 33) - (1 << 34)
+        words = [(label // 7).astype(np.uint32)] + [
+            np.asarray(w) for w in split_u32(_to_bits(jnp.asarray(second)))]
+    valid = rng.random(n) < 0.75
+    vals = [rng.integers(-(1 << 50), 1 << 50, n).astype(np.int64)
+            for _ in range(planes)]
+    if shape == "int64_ends":
+        vals = [rng.choice(np.array(
+            [I64.max, I64.max - 1, I64.min, I64.min + 1, -1, 1], np.int64), n)
+            for _ in range(planes)]
+    if shape == "junk_invalid":  # what a masked row holds must not count
+        for v in vals:
+            v[~valid] = rng.choice(
+                np.array([I64.max, I64.min, 12345], np.int64), (~valid).sum())
+    return words, valid, vals
+
+
+def _assert_window_is_numpys(got, words, valid, vals, g):
+    """Keys, counts and wrapping INT64 sums of the first g groups in key
+    order, bit for bit; empty slots zero."""
+    keys_g, valid_g, rows, sums, _maxes, n_groups = jax.device_get(got)
+    order = np.lexsort([w[valid] for w in words[::-1]])
+    live, first, count = np.unique(
+        np.stack([w[valid][order] for w in words]), axis=1,
+        return_index=True, return_counts=True)
+    k = min(live.shape[1], g)
+    assert int(n_groups) == live.shape[1]
+    assert valid_g[:k].all() and not valid_g[k:].any()
+    for got_w, want_w in zip(keys_g, live):
+        assert np.array_equal(got_w[:k], want_w[:k])
+    assert np.array_equal(rows[:k], count[:k]) and not rows[k:].any()
+    for got_s, v in zip(sums, vals):
+        with np.errstate(over="ignore"):
+            want = np.add.reduceat(v[valid][order], first)
+        assert np.array_equal(got_s[:k], want[:k]) and not got_s[k:].any()
+
+
+def _fold_no_max(g):
+    def fold(words, valid, vals):
+        return sorted_group_fold(words, valid, vals, [], g, folded_flag=True)
+    return fold
+
+
+def _assert_sorts_are(sorts, way, n, n_words, planes):
+    """The window program's sorts, by operand count: on the payload way
+    the one key sort carries the sums' words and there is no row index, no
+    inverse sort and no batched [P, N] sort; on the index way those three.
+    Last ``_front``'s one-operand sort of ``pos``."""
+    counts = [len(shapes) for shapes, _k, _d, _m in sorts]
+    assert counts == {
+        "payload": [n_words + 2 * planes, 1],
+        "index": [n_words + 1, 2, 2, 1],  # keys + iota, inverse, batched
+    }[way]
+    shapes, num_keys, dimension, made = sorts[0]
+    assert shapes == [(n,)] * counts[0]
+    assert (num_keys, dimension) == (n_words, 0)
+    assert ("iota" in made) == (way == "index")
+    batched = [shapes[0] for shapes, _k, d, _m in sorts if d == 1]
+    assert batched == ([(2 * planes, n)] if way == "index" else [])
+
+
+@pytest.mark.parametrize("shape", ["junk_invalid", "overflow", "int64_ends"])
+@pytest.mark.parametrize("planes", [1, 2])
+@pytest.mark.parametrize("keys", ["packed", "lead_id"])
+def test_a_windows_sums_with_no_max_ride_the_key_sort(keys, planes, shape):
+    """Sums and NO maximum at window length (N >= 4 g), as ``px/sql_stats``
+    (three unpacked key words, one sum) and ``px/net_flow_graph`` (one
+    packed word, two sums) fold: numpy's keys, counts and wrapping INT64
+    sums, the sum words operands of the one sort. Three key words and two
+    sums are seven operands, past the limit: the same answer by the row
+    index."""
+    n, g = 4_096, 256
+    words, valid, vals = _ride_case(keys, planes, shape, n, g,
+                                    seed=len(keys) + 7 * planes)
+    way = "index" if (keys, planes) == ("lead_id", 2) else "payload"
+    assert sorted_fold_ride(n, g, len(words), planes) == way
+    fold = _fold_no_max(g)
+    args = ([jnp.asarray(w) for w in words], jnp.asarray(valid),
+            [jnp.asarray(v) for v in vals])
+    _assert_window_is_numpys(jax.jit(fold)(*args), words, valid, vals, g)
+    _assert_sorts_are(_sort_eqns(jax.make_jaxpr(fold)(*args)), way, n,
+                      len(words), planes)
+
+
+@pytest.mark.parametrize("case", ["at_the_limit", "a_plane_more", "no_room"])
+def test_each_side_of_the_operand_limit(case):
+    """Key words and sum words within ``SORT_PAYLOAD_MAX_OPERANDS`` ride
+    one sort; a plane more than fits, or key words that leave no room for
+    one, take the index way. One answer, numpy's, whichever."""
+    limit = routes.SORT_PAYLOAD_MAX_OPERANDS
+    n_words, planes, way = {
+        "at_the_limit": (limit - 4, 2, "payload"),
+        "a_plane_more": (limit - 4, 3, "index"),
+        "no_room": (limit - 1, 1, "index"),
+    }[case]
+    n, g = 2_048, 128
+    rng = np.random.default_rng(n_words)
+    label = rng.integers(0, 90, n)
+    # Word i holds digit i of the label (base 3), the last the rest.
+    words = [(label // 3 ** i % 3).astype(np.uint32) for i in range(n_words - 1)]
+    words.append((label // 3 ** (n_words - 1)).astype(np.uint32))
+    valid = rng.random(n) < 0.8
+    vals = [rng.integers(I64.min, I64.max, n).astype(np.int64)
+            for _ in range(planes)]
+    assert sorted_fold_ride(n, g, n_words, planes) == way
+    fold = _fold_no_max(g)
+    args = ([jnp.asarray(w) for w in words], jnp.asarray(valid),
+            [jnp.asarray(v) for v in vals])
+    _assert_window_is_numpys(jax.jit(fold)(*args), words, valid, vals, g)
+    _assert_sorts_are(_sort_eqns(jax.make_jaxpr(fold)(*args)), way, n,
+                      n_words, planes)
+
+
+@pytest.mark.parametrize("sums_are", ["ride", "primary_and_ride"])
+def test_a_merge_keeps_the_index_way_whatever_the_limit(monkeypatch, sums_are):
+    """N = 2 g, the merge of two states: the row index rides the key sort,
+    an inverse sort, the sums' words in one batched [P, N] sort, and
+    ``_front``'s planes in another, as before PR 35; the jaxpr does not
+    read the operand limit."""
+    g = 128
+    n = 2 * g
+    rng = np.random.default_rng(9)
+    code = jnp.asarray(rng.integers(0, 100, n).astype(np.uint32))
+    valid = jnp.asarray(rng.random(n) < 0.9)
+    a, b = (jnp.asarray(rng.integers(-99, 99, n).astype(np.int64))
+            for _ in range(2))
+
+    def fold(code, valid, a, b):
+        maxes = [a] if sums_are == "primary_and_ride" else []
+        return sorted_group_fold([code], valid, [a, b], maxes, g,
+                                 folded_flag=True)
+
+    jaxpr = jax.make_jaxpr(fold)(code, valid, a, b)
+    rides = 1 if sums_are == "primary_and_ride" else 2
+    n_keys = 3 if sums_are == "primary_and_ride" else 1
+    assert sorted_fold_ride(n, g, n_keys, rides) == "index"
+    main, inverse, carried, front = _sort_eqns(jaxpr)
+    assert main[0] == [(n,)] * (n_keys + 1) and main[3][-1] == "iota"
+    assert (inverse[0], inverse[1]) == ([(n,), (n,)], 1)
+    assert carried[0] == [(2 * rides, n)] * 2 and carried[2] == 1
+    assert front[0][0][1] == n and front[2] == 1
+    for limit in (0, 100):
+        monkeypatch.setattr(routes, "SORT_PAYLOAD_MAX_OPERANDS", limit)
+        assert str(jax.make_jaxpr(fold)(code, valid, a, b)) == str(jaxpr)
+
+
+def test_the_cells_own_shapes():
+    """2^21 rows into 2^17 slots, as ``sql_stats_1chip.sql_recent`` (three
+    key words, one sum: five operands) folds its padded window."""
+    n, g = 1 << 21, 1 << 17
+    words, valid, vals = _ride_case("lead_id", 1, "junk_invalid", n, g, 35)
+    valid[790_568:] = False  # the rows ``-5m`` holds; the rest is padding
+    assert sorted_fold_ride(n, g, 3, 1) == "payload"
+    got = jax.jit(lambda w, v, s: sorted_group_fold(
+        w, v, s, [], g, folded_flag=True))(
+        [jnp.asarray(w) for w in words], jnp.asarray(valid),
+        [jnp.asarray(v) for v in vals])
+    _assert_window_is_numpys(got, words, valid, vals, g)
+
+
 # -- the route, against the id form ---------------------------------------------
 
 REL = Relation([
@@ -313,6 +511,36 @@ def test_what_chooses_the_route(aggs, keys, platform, allow_dense, fold):
     assert frag.fold == fold
     if fold == "sorted_int":
         assert frag.group == "sorted"
+
+
+RIDE_AGGS = dict(AGG_SETS, sum_alone=(("s", "sum", "bytes"),),
+                 two_sums=(("a", "sum", "lat"), ("b", "sum", "bytes")))
+
+
+@pytest.mark.parametrize("keys", list(KEY_SETS))
+@pytest.mark.parametrize("aggs", list(RIDE_AGGS))
+def test_the_span_says_what_the_window_program_holds(aggs, keys):
+    """``CompiledFragment.ride`` reckons from the plan what
+    ``sorted_group_fold`` decides from its operands: at window length
+    ``update``'s n-long sorts hold the sums' words, or a row index and a
+    batched [P, n] sort, as the span's ``ride`` will say."""
+    n, g = 2_048, 256
+    frag = _frag(KEY_SETS[keys], RIDE_AGGS[aggs], g)
+    cols = {c: (jnp.zeros(n, dt),) for c, dt in (
+        ("lat", jnp.int64), ("bytes", jnp.int64), ("t", jnp.int64),
+        ("err", jnp.bool_), ("svc", jnp.int32), ("path", jnp.int32),
+        ("shard", jnp.int64))}
+    sorts = [s for s in _sort_eqns(jax.make_jaxpr(frag.update)(
+        frag.init_state(), cols, (jnp.int32(0), jnp.int32(n))))
+        if s[0][0][-1] == n]
+    shapes, num_keys, _d, made = sorts[0]
+    in_program = (
+        "index" if any(d == 1 for _s, _k, d, _m in sorts)
+        else "payload" if len(shapes) > num_keys else ""
+    )
+    assert frag.ride(n) == in_program
+    assert ("iota" in made) == (in_program == "index")
+    assert frag.ride(2 * g) in ("", "index")  # as a merge folds
 
 
 @pytest.mark.parametrize("uda,col,fold", [
@@ -455,6 +683,47 @@ def test_the_engine_refolds_after_an_overflow(start):
     assert folds and {s.attributes["fold"] for s in folds} == {"sorted_int"}
     assert {s.attributes["group"] for s in folds} == {"sorted"}
     assert (trace.usage.rebuckets > 0) == (start == 64)
+
+
+RIDE_PXL = """import px
+df = px.DataFrame(table='events')
+df = df.groupby(['svc', 'path']).agg(%s)
+px.display(df)
+"""
+
+
+@pytest.mark.parametrize("aggs,slots,ride", [
+    # px/sql_stats' and px/net_flow_graph's kind: sums, no maximum.
+    ("n=('lat', px.count), m=('lat', px.mean)", 256, "payload"),
+    ("a=('lat', px.sum), b=('size', px.sum)", 256, "payload"),
+    # A window short against its slots folds as a merge does.
+    ("n=('lat', px.count), m=('lat', px.mean)", 2_048, "index"),
+    # px/http_stats' kind: the sum IS the primary maximum's plane, and a
+    # count is no plane at all.
+    ("m=('lat', px.mean), mx=('lat', px.max)", 256, None),
+    ("n=('lat', px.count)", 256, None),
+])
+def test_a_sorted_window_dispatch_says_how_its_sums_ride(aggs, slots, ride):
+    """Span shape: a ``sorted_int`` window's ``device.dispatch`` carries
+    ``ride`` beside ``fold`` / ``group`` / ``slots`` on the TPU's routes,
+    and no attribute where no sum plane rides."""
+    from pixie_tpu.planner import CompilerState, compile_pxl
+
+    data = _events(5, 4_000, [f"svc-{i}" for i in range(8)], 25)
+    with routes_of("tpu"), override_flag("dense_domain_limit", 16):
+        eng = Engine(window_rows=4_096)
+        eng.append_data("events", data)
+        state = CompilerState(
+            schemas={n: t.relation for n, t in eng.tables.items()},
+            registry=eng.registry, now_ns=0, max_groups=slots,
+        )
+        eng.execute_plan(compile_pxl(RIDE_PXL % aggs, state).plan)
+    folds = [s.attributes for s in eng.tracer.last().spans
+             if s.name == "device.dispatch" and "fold" in s.attributes]
+    assert folds and all(
+        (a["fold"], a["group"], a["slots"]) == ("sorted_int", "sorted", slots)
+        for a in folds)
+    assert {a.get("ride") for a in folds} == {ride}
 
 
 @pytest.fixture(params=[2, 3], ids=["two_pems", "three_pems"])

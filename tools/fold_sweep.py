@@ -274,8 +274,9 @@ def digest_sweep(args) -> int:
 def micro_sweep(args) -> int:
     """What the keyed fold is built from, one primitive a line, at the
     window's and the merge's lengths: sorts by operand and key count, the
-    batched sort, gathers, a scatter, the scans (``first_s`` is the first
-    call: compile, or the cache)."""
+    payload sort against the index way's three, the batched sort, gathers,
+    a scatter, the scans (``first_s`` is the first call: compile, or the
+    cache)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -290,6 +291,8 @@ def micro_sweep(args) -> int:
     g = 1 << 17
 
     def bench(name, fn, *a):
+        if args.only and not name.startswith(tuple(args.only)):
+            return
         t0 = time.perf_counter()
         jax.block_until_ready(fn(*a))
         first = time.perf_counter() - t0
@@ -308,6 +311,32 @@ def micro_sweep(args) -> int:
                 lambda *o, k=keys: jax.lax.sort(
                     list(o), dimension=0, is_stable=False, num_keys=k)),
                 code, *u[:ops - 1])
+        # How a window's sum words reach group order (``ops/groupby.py``
+        # ``sorted_group_fold``, ``ops/routes.py`` ``sorted_fold_ride``):
+        # as payload operands of the key sort, against the index way's
+        # three sorts (keys + the row index, the inverse, the batched
+        # [P, N]). ``SORT_PAYLOAD_MAX_OPERANDS`` is set from these rows.
+        for keys in (1, 3):
+            for pay in (2, 4, 6):
+                ks, ps = [code] + u[:keys - 1], u[2:2 + pay]
+                bench(f"payload_sort n={n} keys={keys} payload={pay}", jax.jit(
+                    lambda *o, k=keys: jax.lax.sort(
+                        list(o), dimension=0, is_stable=False, num_keys=k)),
+                    *ks, *ps)
+
+                def index_way(*o, k=keys):
+                    iota = jnp.arange(o[0].shape[0], dtype=jnp.int32)
+                    out = jax.lax.sort(list(o[:k]) + [iota], dimension=0,
+                                       is_stable=False, num_keys=k)
+                    dest = jax.lax.sort([out[k], iota], dimension=0,
+                                        is_stable=False, num_keys=1)[1]
+                    p = jnp.stack(o[k:])
+                    return out[:k], jax.lax.sort(
+                        [jnp.broadcast_to(dest[None, :], p.shape), p],
+                        dimension=1, is_stable=False, num_keys=1)[1]
+
+                bench(f"index_way n={n} keys={keys} payload={pay}",
+                      jax.jit(index_way), *ks, *ps)
         for planes in (1, 4, 8):
             bench(f"batched_sort n={n} planes={planes}", jax.jit(
                 lambda k, p: jax.lax.sort(
@@ -338,6 +367,8 @@ def main(argv=None) -> int:
     ap.add_argument("--digest", action="store_true",
                     help="the quantile digest: the scatter route against "
                          "the sorted route")
+    ap.add_argument("--only", nargs="*", default=[],
+                    help="--micro: only the rows whose name starts so")
     ap.add_argument("--groups", type=int, nargs="*", default=None)
     ap.add_argument("--blocks", nargs="*", default=[],
                     help="extra kernel blockings chunk,g_block")

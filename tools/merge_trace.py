@@ -34,8 +34,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 _SHOWN = ("program", "windows", "slots", "prepared", "ops", "from", "to",
-          "fold", "rows", "strategy", "where", "build_rows", "probe_rows",
-          "rows_out")
+          "fold", "ride", "rows", "strategy", "where", "build_rows",
+          "probe_rows", "rows_out")
 
 
 def _merge_traces(spans: dict, qids: list, tracer: str = "kelvin",
