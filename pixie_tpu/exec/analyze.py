@@ -98,8 +98,17 @@ class FragmentStats:
     def timed(self, stage: str, rows: int = 0, nbytes: int = 0):
         return _Timer(self, stage, rows, nbytes)
 
-    def subspan(self, name: str, **attrs):
-        """A named span under this fragment; nothing without a trace."""
+    def keeps_interval(self, n: int) -> bool:
+        """Whether a per-window interval becomes a span: never without
+        a trace."""
+        return False
+
+    def stamped(self, name: str, start_ns: int, **attrs) -> None:
+        """A span from ``start_ns`` to now; nothing without a trace."""
+
+    def subspan(self, name: str, parent=None, **attrs):
+        """A named span under this fragment (or under ``parent``, a span
+        of it); nothing without a trace."""
         return contextlib.nullcontext()
 
     def dispatch(self, program: str, stage: str = "compute",

@@ -22,8 +22,11 @@ from .joins import learned_capacity
 from .plan import AggOp
 from .stream import (
     _apply_limit,
+    _device_fetch,
     _device_wait,
     _dispatch,
+    _note_fetched,
+    _query_trace,
     _timed,
     _NO_STATS,
     QueryError,
@@ -31,6 +34,7 @@ from .stream import (
     _double_agg_groups,
     _rebucket,
     _remember_climb,
+    _root_span,
     _Stream,
     _with_agg_groups,
     _stream_col_stats,
@@ -150,9 +154,20 @@ def payload_nbytes(p) -> int:
     return 0
 
 
+def _count_wire(engine, payload):
+    """Wire accounting (``QueryResourceUsage.wire_bytes``): bridge
+    egress is what a fragment ships to the merge tier."""
+    trace = _query_trace(engine)
+    if trace is not None:
+        trace.add_wire_bytes(payload_nbytes(payload))
+    return payload
+
+
 def bridge_payload(engine, res):
     """Produce a BridgeSink payload: partial-agg state for agg chains,
-    materialized rows otherwise (GRPCSinkNode's two modes)."""
+    materialized rows otherwise (GRPCSinkNode's two modes). What follows
+    the fragment's last ``device.wait`` (the payload built, its wire
+    bytes counted) is the trace's ``payload`` span."""
     if isinstance(res, _Stream) and any(
         isinstance(o, AggOp) for o in res.chain
     ):
@@ -179,29 +194,37 @@ def bridge_payload(engine, res):
                 state = engine._fold_agg_state(res, frag, stats)
                 # The fragment's sync: the fold has run when its overflow
                 # flag is on the host; then the state that ships, a copy
-                # a leaf (as ever: see stream._fetch_result).
-                with _device_wait(stats):
+                # a leaf (as ever: see stream._fetch_result): the wait's
+                # ``device.fetch``.
+                with _device_wait(stats) as wait:
                     overflowed = bool(np.asarray(state["overflow"]))
                     if not overflowed:
-                        state = jax.tree_util.tree_map(np.asarray, state)
+                        with _device_fetch(stats, wait) as fetch:
+                            state = jax.tree_util.tree_map(np.asarray, state)
+                            _note_fetched(
+                                fetch, jax.tree_util.tree_leaves(state)
+                            )
             if not overflowed:
                 break
             res = _double_agg_groups(res)  # rebucket before shipping
             climbed = True
             retry = _rebucket(stats, frag.slots, frag.slots * 2, "pem")
             frag = None
-        if climbed:
-            _remember_climb(engine, res.chain, res.source, "pem", frag)
-        return AggStatePayload(
-            chain=tuple(res.chain),
-            input_relation=res.relation,
-            input_dicts=dict(res.dicts),
-            state=state,
-            dense_domains=frag.dense_domains,
-            dense_offsets=frag.dense_offsets,
-            dense_strides=frag.dense_strides,
-        )
-    return RowsPayload(batch=engine._materialize(res))
+        with _root_span(engine, "payload", kind="agg_state"):
+            if climbed:
+                _remember_climb(engine, res.chain, res.source, "pem", frag)
+            return _count_wire(engine, AggStatePayload(
+                chain=tuple(res.chain),
+                input_relation=res.relation,
+                input_dicts=dict(res.dicts),
+                state=state,
+                dense_domains=frag.dense_domains,
+                dense_offsets=frag.dense_offsets,
+                dense_strides=frag.dense_strides,
+            ))
+    batch = engine._materialize(res)
+    with _root_span(engine, "payload", kind="rows"):
+        return _count_wire(engine, RowsPayload(batch=batch))
 
 
 def bind_bridge(payloads):
@@ -495,19 +518,23 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge,
         qstats.new_fragment((*p0.chain, *tail)) if qstats is not None
         else None
     )
-    for p in payloads:
-        if bool(np.asarray(p.state["overflow"])):
-            # Lost groups at the source cannot be recovered here; the
-            # producing agent rebuckets before shipping (bridge_payload).
-            raise QueryError(
-                "bridge payload arrived with group overflow; producing "
-                "agent failed to rebucket"
-            )
-    parts = [_live_slots(p.state) for p in payloads]
-    sigs = [
-        (shape_signature(p.state), cap)
-        for p, (_idx, _live, cap) in zip(payloads, parts)
-    ]
+    # ``merge.compact``: the states' live slots found here, and taken
+    # below once the prepared merge has said what the key planes hold.
+    with _root_span(engine, "merge.compact", payloads=len(payloads)):
+        for p in payloads:
+            if bool(np.asarray(p.state["overflow"])):
+                # Lost groups at the source cannot be recovered here;
+                # the producing agent rebuckets before shipping
+                # (bridge_payload).
+                raise QueryError(
+                    "bridge payload arrived with group overflow; "
+                    "producing agent failed to rebucket"
+                )
+        parts = [_live_slots(p.state) for p in payloads]
+        sigs = [
+            (shape_signature(p.state), cap)
+            for p, (_idx, _live, cap) in zip(payloads, parts)
+        ]
     # The capacity: the bucket of what the payloads hold live (their
     # union cannot spill it), or the bucket an earlier merge of this
     # chain saw the union fit where that is smaller: the union of the
@@ -525,19 +552,26 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge,
         # it at is before an attempt's dispatch.
         engine._check_cancel()
         with retry:
-            rec, prepared = _prepared_merge(
-                engine, payloads, tail, sigs, g, cap_key
-            )
-            states = [
-                _explicit_state(p, idx, rec.key_types)
-                for p, (idx, _live, _cap) in zip(payloads, parts)
-            ]
+            with _root_span(engine, "fragment.bind") as bind:
+                rec, prepared = _prepared_merge(
+                    engine, payloads, tail, sigs, g, cap_key
+                )
+                if bind is not None:
+                    bind.attributes["cached"] = prepared
+            with _root_span(engine, "merge.compact",
+                            payloads=len(payloads), slots=g):
+                states = [
+                    _explicit_state(p, idx, rec.key_types)
+                    for p, (idx, _live, _cap) in zip(payloads, parts)
+                ]
             with _dispatch(stats, rec.program, "finalize") as span:
                 if span is not None:
                     span.attributes.update(prepared=prepared, slots=g)
                 out = rec.program(jax.device_put(states), rec.remaps)
-            with _device_wait(stats):
-                cols, valid, overflowed, live = jax.device_get(out)
+            with _device_wait(stats) as wait:
+                out = jax.device_get(out)
+                _note_fetched(wait, jax.tree_util.tree_leaves(out))
+                cols, valid, overflowed, live = out
         if not overflowed:
             break
         if g * 2 > get_flag("max_groups_limit"):

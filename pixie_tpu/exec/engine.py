@@ -85,7 +85,9 @@ from .stream import (  # noqa: F401  (re-exported)
     _agg_capacity_key,
     _double_agg_groups,
     _rebucket,
+    _root_span,
     _subspan,
+    _window_selected,
     _probed_capacity,
     _PROBE_MIN_SLOTS,
     _computed_group_keys,
@@ -95,6 +97,7 @@ from .stream import (  # noqa: F401  (re-exported)
     _stream_with_groups,
     _empty_host_batch,
     _fetch_result,
+    _note_fetched,
     _Stream,
     _stream_col_stats,
     _timed,
@@ -179,8 +182,12 @@ class DeviceResult:
                 with _dispatch(stats, frag.finalize, "finalize"):
                     cols, valid, overflow = frag.finalize(state)
                     _block_if(stats, (cols, valid, overflow))
-        with _device_wait(stats):
-            cols, valid = _fetch_result(frag.out_meta, cols, valid)
+        # The overflow flag above was this program's sync: all of this
+        # wait is the fetch.
+        with _device_wait(stats) as wait:
+            cols, valid = _fetch_result(
+                frag.out_meta, cols, valid, stats, wait, synced=True
+            )
         if climbed:
             _remember_climb(eng, stream.chain, stream.source, "pem", frag)
         with _timed(stats, "materialize"):
@@ -211,6 +218,55 @@ def _agg_tail(plan: Plan, nid: int) -> tuple:
         tail.append(last)
         if isinstance(readers[0].op, LimitOp):
             return tail, last
+
+
+def _counting(prune, skipped: list):
+    """``prune`` (``zoneskip.chain_pruner``'s, or None) counting the
+    windows it skips into ``skipped[0]``."""
+    if prune is None:
+        return None
+
+    def counted(lo, hi):
+        skip = prune(lo, hi)
+        skipped[0] += bool(skip)
+        return skip
+
+    return counted
+
+
+class _PlanWalk:
+    """The ``plan.walk`` span of ``_execute_plan_inner``: open while the
+    loop walks the plan (sources found, chains extended), closed around
+    every op that runs work (``with walk.paused():``), so the stretches
+    of host time between a trace's fragments, joins and merges carry a
+    name. One span a stretch; it opens again at the next node's
+    ``step``, so a plan's last sink leaves no empty stretch behind."""
+
+    def __init__(self, trace):
+        self.trace = trace
+        self._ctx = None
+        self._pauses = 0  # ops that run work nest (a join materializes)
+
+    def step(self) -> None:
+        """At a node of the plan: the walk is on (again)."""
+        if self._ctx is None and not self._pauses:
+            self._ctx = self.trace.span("plan.walk")
+            self._ctx.__enter__()
+
+    def end(self) -> None:
+        if self._ctx is not None:
+            self._ctx.__exit__(None, None, None)
+            self._ctx = None
+
+    def paused(self) -> "_PlanWalk":
+        return self
+
+    def __enter__(self) -> None:
+        self._pauses += 1
+        self.end()
+
+    def __exit__(self, *exc) -> None:
+        self._pauses -= 1
 
 
 class _QueryScratch:
@@ -767,6 +823,15 @@ class Engine:
         self, plan: Plan, bridge_inputs: dict | None = None,
         materialize: bool = True,
     ) -> dict:
+        walk = _PlanWalk(self._query_stats.trace)
+        try:
+            return self._walk_plan(plan, bridge_inputs, materialize, walk)
+        finally:
+            walk.end()
+
+    def _walk_plan(self, plan: Plan, bridge_inputs, materialize: bool,
+                   walk: _PlanWalk) -> dict:
+        trace = walk.trace
         results: dict[int, object] = {}
         outputs: dict = {}
         consumers: dict[int, int] = {}
@@ -774,18 +839,31 @@ class Engine:
             for i in n.inputs:
                 consumers[i] = consumers.get(i, 0) + 1
 
+        def materialized(res):
+            with walk.paused():
+                return self._materialize(res)
+
         def mat_input(nid):
             """Materialize a node's result once; cache for fan-out."""
             r = results[nid]
             if not isinstance(r, HostBatch):
-                r = self._materialize(r)
-                results[nid] = r
+                r = results[nid] = materialized(r)
             return r
+
+        def as_stream(res):
+            """``res`` as the source of the next fragment: a batch in
+            hand (a join's rows, a materialized aggregate) under a
+            ``restream`` span."""
+            if isinstance(res, _Stream):
+                return res
+            with walk.paused(), trace.span("restream", rows=res.length):
+                return self._as_stream(res)
 
         absorbed: set = set()  # nodes a merge's program has run already
         for nid in plan.topo_order():
             if nid in absorbed:
                 continue
+            walk.step()
             node = plan.nodes[nid]
             op = node.op
             if isinstance(op, MemorySourceOp):
@@ -825,15 +903,16 @@ class Engine:
                     # program, not as a fragment over its rows.
                     tail, last = _agg_tail(plan, nid)
                     absorbed.update(tail)
-                    results[last] = merge_agg_bridge(
-                        self, upstream, [plan.nodes[t].op for t in tail]
-                    )
+                    with walk.paused():
+                        results[last] = merge_agg_bridge(
+                            self, upstream, [plan.nodes[t].op for t in tail]
+                        )
                     continue
-                st = self._as_stream(upstream)
+                st = as_stream(upstream)
                 if st.chain and isinstance(st.chain[-1], LimitOp):
                     # A limit terminates its fragment: apply the cap at its
                     # plan position, then keep chaining on the result.
-                    st = self._as_stream(self._materialize(st))
+                    st = as_stream(materialized(st))
                 if isinstance(op, AggOp) and any(
                     isinstance(o, AggOp) for o in st.chain
                 ):
@@ -841,62 +920,64 @@ class Engine:
                     # materializes (its output is small), the second re-
                     # aggregates it (the splitter's cut-at-blocking-op rule,
                     # planner/distributed/splitter/splitter.h:75).
-                    st = self._as_stream(self._materialize(st))
+                    st = as_stream(materialized(st))
                 results[nid] = st.extend(op)
             elif isinstance(op, JoinOp):
-                t_join = clock_ns()
-                fused = try_fused_join(self, nid, node, results, consumers)
-                if fused is not None:
-                    from .joins import JoinDecision
+                with walk.paused():
+                    t_join = clock_ns()
+                    fused = try_fused_join(self, nid, node, results, consumers)
+                    if fused is not None:
+                        from .joins import JoinDecision
 
-                    self.last_join_decision = JoinDecision(
-                        strategy="fused",
-                        reason="dense-domain N:1 in-fragment lookup",
-                    )
-                    results[nid] = fused
-                    qstats = self._query_stats
-                    if qstats is not None:
-                        # The build alone: the probe rows flow through
-                        # the fragment the lookup fused into, uncounted.
-                        qstats.trace.add_span(
-                            "join", t_join, clock_ns(), strategy="fused",
-                            where="device", how=op.how,
-                            build_rows=int(fused.chain[-1].dom),
-                            probe_rows=0, rows_out=0,
+                        self.last_join_decision = JoinDecision(
+                            strategy="fused",
+                            reason="dense-domain N:1 in-fragment lookup",
                         )
-                else:
-                    from .joins import stream_join_stats
+                        results[nid] = fused
+                        qstats = self._query_stats
+                        if qstats is not None:
+                            # The build alone: the probe rows flow through
+                            # the fragment the lookup fused into, uncounted.
+                            qstats.trace.add_span(
+                                "join", t_join, clock_ns(), strategy="fused",
+                                where="device", how=op.how,
+                                build_rows=int(fused.chain[-1].dom),
+                                probe_rows=0, rows_out=0,
+                            )
+                    else:
+                        from .joins import stream_join_stats
 
-                    # Ingest-sketch stats must be read BEFORE
-                    # materialization (the table provenance dies with
-                    # the stream); they steer build-side choice,
-                    # capacity estimation and zone skipping.
-                    lstats = stream_join_stats(
-                        results[node.inputs[0]], op.left_on
-                    )
-                    rstats = stream_join_stats(
-                        results[node.inputs[1]], op.right_on
-                    )
-                    left = mat_input(node.inputs[0])
-                    right = mat_input(node.inputs[1])
-                    # Join-buffer pre-sizing (pxbound): the plan-time
-                    # capacity estimate covers inputs run-time sketches
-                    # cannot see (post-aggregate build sides) — used as
-                    # the fallback rung before the historical default.
-                    report = self.last_resource_report
-                    planned = (
-                        report.join_capacity.get(nid)
-                        if report is not None else None
-                    )
-                    results[nid] = traced_join_dispatch(
-                        left, right, op, self,
-                        left_stats=lstats, right_stats=rstats,
-                        cap_key=(self._plan_fingerprint(plan), nid),
-                        planned_capacity=planned,
-                    )
+                        # Ingest-sketch stats must be read BEFORE
+                        # materialization (the table provenance dies with
+                        # the stream); they steer build-side choice,
+                        # capacity estimation and zone skipping.
+                        lstats = stream_join_stats(
+                            results[node.inputs[0]], op.left_on
+                        )
+                        rstats = stream_join_stats(
+                            results[node.inputs[1]], op.right_on
+                        )
+                        left = mat_input(node.inputs[0])
+                        right = mat_input(node.inputs[1])
+                        # Join-buffer pre-sizing (pxbound): the plan-time
+                        # capacity estimate covers inputs run-time sketches
+                        # cannot see (post-aggregate build sides) — used as
+                        # the fallback rung before the historical default.
+                        report = self.last_resource_report
+                        planned = (
+                            report.join_capacity.get(nid)
+                            if report is not None else None
+                        )
+                        results[nid] = traced_join_dispatch(
+                            left, right, op, self,
+                            left_stats=lstats, right_stats=rstats,
+                            cap_key=(self._plan_fingerprint(plan), nid),
+                            planned_capacity=planned,
+                        )
             elif isinstance(op, UnionOp):
                 mats = [mat_input(i) for i in node.inputs]
-                results[nid] = _union_host(mats)
+                with walk.paused():
+                    results[nid] = _union_host(mats)
             elif isinstance(op, ResultSinkOp):
                 src_id = node.inputs[0]
                 r = results[src_id]
@@ -907,30 +988,32 @@ class Engine:
                 ):
                     # Device-resident result: the readback (and any
                     # overflow rebucket) happens in DeviceResult.to_host.
-                    outputs[op.name] = self._run_fragment(r)
+                    with walk.paused():
+                        outputs[op.name] = self._run_fragment(r)
                 else:
                     outputs[op.name] = mat_input(src_id)
             elif isinstance(op, TableSinkOp):
                 hb = mat_input(node.inputs[0])
-                self.append_data(op.table, hb)
+                with walk.paused():
+                    self.append_data(op.table, hb)
                 # Not a client output (clients iterate result tables);
                 # recorded on the engine for callers/tests.
                 self.last_table_sinks[op.table] = hb.length
             elif isinstance(op, OTelExportSinkOp):
                 from .otel import batch_to_otlp
 
-                payload = batch_to_otlp(mat_input(node.inputs[0]), op.spec)
-                self.export_otel(payload, op.spec.endpoint)
+                hb = mat_input(node.inputs[0])
+                with walk.paused():
+                    self.export_otel(
+                        batch_to_otlp(hb, op.spec), op.spec.endpoint
+                    )
             elif isinstance(op, BridgeSinkOp):
-                from .bridge import payload_nbytes
-
-                payload = bridge_payload(self, results[node.inputs[0]])
-                outputs[("bridge", op.bridge_id)] = payload
-                # Wire accounting (QueryResourceUsage): bridge egress is
-                # what this fragment ships to the merge tier.
-                qstats = self._query_stats
-                if qstats is not None and getattr(qstats, "trace", None):
-                    qstats.trace.add_wire_bytes(payload_nbytes(payload))
+                # (the payload's wire bytes are counted where it is
+                # built: its ``payload`` span)
+                with walk.paused():
+                    outputs[("bridge", op.bridge_id)] = bridge_payload(
+                        self, results[node.inputs[0]]
+                    )
             elif isinstance(op, BridgeSourceOp):
                 if not bridge_inputs or op.bridge_id not in bridge_inputs:
                     raise QueryError(f"no input for bridge {op.bridge_id}")
@@ -955,7 +1038,7 @@ class Engine:
                     and _pure_select_map(st.chain) is not None
                 )
                 if not pure_scan:
-                    results[nid] = self._materialize(st)
+                    results[nid] = materialized(st)
         return outputs
 
     def _note_scan_freshness(self, op, tablets) -> None:
@@ -1023,7 +1106,10 @@ class Engine:
             state = self._fold_agg_state_native(stream, frag, stats)
             if state is not None:
                 return state
-        state = init_state()
+        # The fold's empty state: a handful of eager array constructions
+        # on the device, before the first window's program has work.
+        with _subspan(stats, "state.init"):
+            state = init_state()
         if stats is not None:
             # Onto its device.dispatch spans.
             stats.fold, stats.group, stats.slots = (
@@ -1461,13 +1547,29 @@ class Engine:
             for t in tables:
                 if getattr(t, "_backend", None) is None:
                     continue
-                pruner = chain_pruner(
-                    t, stream.chain, getattr(t, "dicts", stream.dicts),
-                    stats=stats,
-                )
-                for win, lo, hi in t.device_scan(
-                    start, stop, window_rows=self.window_rows, prune=pruner
-                ):
+                scan, skipped, n = None, [0], 0
+                while True:
+                    # A ``window.select`` a window the scan hands over,
+                    # on whichever thread stages (the prefetch producer's
+                    # when the pipeline is deep): the table's range and
+                    # pruner on the first, then the zone-map skip and
+                    # the resident window found (or staged).
+                    t_select, before = clock_ns(), skipped[0]
+                    if scan is None:
+                        scan = t.device_scan(
+                            start, stop, window_rows=self.window_rows,
+                            prune=_counting(chain_pruner(
+                                t, stream.chain,
+                                getattr(t, "dicts", stream.dicts),
+                                stats=stats,
+                            ), skipped),
+                        )
+                    item = next(scan, None)
+                    if item is None:
+                        break
+                    _window_selected(stats, n, t_select, skipped[0] - before)
+                    n += 1
+                    win, lo, hi = item
                     self._check_cancel()
                     # Cold-tier decode ran inside device_scan's staging
                     # (on THIS thread — the pipeline producer when
@@ -1530,12 +1632,22 @@ class Engine:
         plan's capacity is then a default, not an estimate), the one a
         sketch of the joint key over the windows in range gives, which
         is then remembered; else the plan's."""
+        with _root_span(self, "fragment.bind") as sp:
+            lookups: list = []  # "hit" / "miss" a compile_fragment call
+            bound = self._bind_sized(stream, lookups.append)
+            if sp is not None:
+                sp.attributes["cached"] = (
+                    "miss" if "miss" in lookups else "hit"
+                )
+            return bound
+
+    def _bind_sized(self, stream: "_Stream", note):
         from .joins import learned_capacity, remember_capacity
 
         def compiled(st):
             return compile_fragment(
                 st.chain, st.relation, st.dicts, self.registry,
-                col_stats=_stream_col_stats(st),
+                col_stats=_stream_col_stats(st), note=note,
             )
 
         frag = compiled(stream)
@@ -1589,8 +1701,9 @@ class Engine:
             finally:
                 pipe.close()
                 self._note_pipeline(pipe)
-            with _device_wait(stats):
+            with _device_wait(stats) as wait:
                 registers = jax.device_get(registers)
+                _note_fetched(wait, jax.tree_util.tree_leaves(registers))
             estimate = hll_estimate_np(registers[0])
             if sp is not None:
                 sp.attributes["estimate"] = estimate
@@ -1628,9 +1741,9 @@ class Engine:
                     _block_if(stats, (out_cols, out_valid))
                 if stats is not None:
                     stats.windows += 1
-                with _device_wait(stats):
+                with _device_wait(stats) as wait:
                     out_cols, out_valid = _fetch_result(
-                        frag.out_meta, out_cols, out_valid
+                        frag.out_meta, out_cols, out_valid, stats, wait
                     )
                 with _timed(stats, "materialize"):
                     piece = _to_host_batch(frag.out_meta, out_cols, out_valid)

@@ -240,7 +240,8 @@ def _stats_cache_key(ops, col_stats):
 
 
 def compile_fragment_cached(ops, input_relation, input_dicts, registry,
-                            allow_dense: bool = True, col_stats=None):
+                            allow_dense: bool = True, col_stats=None,
+                            note=None):
     """``compile_fragment`` memoized on plan structure.
 
     A fragment's jitted ``update``/``finalize`` closures hold the XLA
@@ -252,6 +253,8 @@ def compile_fragment_cached(ops, input_relation, input_dicts, registry,
     compile-time behavior — literal ``lookup`` ids, out_meta decode —
     is a pure function of its ordered contents, and growth re-encodes
     string literals under a new key), and the registry identity.
+    ``note``, when given, is called with ``"hit"`` or ``"miss"``: what
+    the lookup found (a ``fragment.bind`` span's ``cached``).
     Content- rather than id()-keyed because the merge tier's bridge
     payloads decode FRESH dictionary objects from the wire on every
     distributed query: identity keying missed the cache (and recompiled
@@ -273,11 +276,15 @@ def compile_fragment_cached(ops, input_relation, input_dicts, registry,
         )
         hash(key)
     except TypeError:
+        if note is not None:
+            note("miss")
         return compile_fragment(
             ops, input_relation, input_dicts, registry, allow_dense,
             col_stats=col_stats,
         )
     hit = _FRAGMENT_CACHE.get(key)
+    if note is not None:
+        note("miss" if hit is None else "hit")
     if hit is None:
         # Compile OUTSIDE the cache lock (compiles are slow and must
         # not serialize concurrent queries' unrelated misses); a

@@ -42,12 +42,14 @@ from ..types.strings import NULL_ID, StringDictionary
 from .fragment import compile_fragment_cached as compile_fragment
 from .plan import AggOp, JoinOp, LimitOp, LookupJoinOp, MapOp
 from .stream import (
+    _NO_STATS,
     QueryError,
     _chain_out_relation,
     _col,
     _Stream,
     _stream_col_stats,
 )
+from .trace import current_trace
 
 
 def _key_tuples(hb: HostBatch, on, remaps):
@@ -462,22 +464,44 @@ class _BuildNotUnique(Exception):
     pass
 
 
+def _join_child(name: str, **attrs):
+    """A child span of the open ``join`` span of the query this thread
+    runs (``traced_join_dispatch``'s); no-op, and ``as`` binds None,
+    outside one."""
+    trace = current_trace()
+    join = trace.open_span("join") if trace is not None else None
+    if join is None:
+        return _NO_STATS
+    return trace.span(name, parent=join, **attrs)
+
+
 def _align_join_dicts(left, right, op):
     """String-dictionary id remaps so key ids compare across sides.
 
     Returns (l_remap, r_remap, key_dicts): key_dicts maps a left key
     column to the merged dictionary (union preserves left ids, so pair
     rows stay valid and coalesced build-side ids land past them).
+    A traced join's ``join.align`` span: ``strings`` hashed into the
+    unions; ``memo`` is ``miss`` where a union was built and ``none``
+    where the sides share their dictionaries (nothing remembers a union
+    yet, so never ``hit``).
     """
     l_remap: dict = {}
     r_remap: dict = {}
     key_dicts: dict = {}
-    for lc, rc in zip(op.left_on, op.right_on):
-        ld, rd = left.dicts.get(lc), right.dicts.get(rc)
-        if ld is not None and rd is not None and ld is not rd:
-            merged, rl, rr = ld.union(rd)
-            l_remap[lc], r_remap[rc] = rl, rr
-            key_dicts[lc] = merged
+    with _join_child("join.align") as sp:
+        strings = 0
+        for lc, rc in zip(op.left_on, op.right_on):
+            ld, rd = left.dicts.get(lc), right.dicts.get(rc)
+            if ld is not None and rd is not None and ld is not rd:
+                merged, rl, rr = ld.union(rd)
+                l_remap[lc], r_remap[rc] = rl, rr
+                key_dicts[lc] = merged
+                strings += len(rd)
+        if sp is not None:
+            sp.attributes.update(
+                strings=strings, memo="miss" if key_dicts else "none"
+            )
     return l_remap, r_remap, key_dicts
 
 
@@ -524,8 +548,13 @@ def _assemble_join(left, right, op, out_rel, src, l_idx, l_take, r_idx, r_take,
     row — whose probe side is null — takes its key from the build side,
     remapped into the merged dictionary for strings.
     """
-    r_remap = r_remap or {}
-    key_dicts = key_dicts or {}
+    with _join_child("join.assemble", rows_out=len(l_idx)):
+        return _gather_join(left, right, op, out_rel, src, l_idx, l_take,
+                            r_idx, r_take, r_remap or {}, key_dicts or {})
+
+
+def _gather_join(left, right, op, out_rel, src, l_idx, l_take, r_idx, r_take,
+                 r_remap, key_dicts):
     key_map = dict(zip(op.left_on, op.right_on))
     out_cols: dict = {}
     out_dicts: dict = {}
@@ -1253,6 +1282,11 @@ def _packed_key_ids(left, left_on, l_remap, right, right_on, r_remap):
 
 def _assemble_join_host(left, right, op, l_idx, r_idx) -> HostBatch:
     """Row assembly for the host N:1 / N:M paths (r_idx=-1 -> null)."""
+    with _join_child("join.assemble", rows_out=len(l_idx)):
+        return _gather_join_host(left, right, op, l_idx, r_idx)
+
+
+def _gather_join_host(left, right, op, l_idx, r_idx) -> HostBatch:
     out_rel = left.relation.merge(
         right.relation.select(
             [c for c in right.relation.column_names if c not in op.right_on]

@@ -39,6 +39,7 @@ totals for bench.py's overlap report and the observability gauges.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -199,10 +200,19 @@ class WindowPipeline:
                 self.windows += 1
                 yield item
             return
-        self._thread = threading.Thread(
-            target=self._produce, name="pixie-window-prefetch", daemon=True
+        # The prefetch thread's creation and start: half a millisecond
+        # of the fragment's head on the chip's host (``pipeline.start``
+        # on a traced fragment).
+        started = (
+            self._stats.subspan("pipeline.start")
+            if self._stats is not None else contextlib.nullcontext()
         )
-        self._thread.start()
+        with started:
+            self._thread = threading.Thread(
+                target=self._produce, name="pixie-window-prefetch",
+                daemon=True,
+            )
+            self._thread.start()
         try:
             while True:
                 self._check_cancel()

@@ -325,11 +325,62 @@ def _subspan(stats, name: str, **attrs):
     return stats.subspan(name, **attrs)
 
 
+def _query_trace(engine):
+    """The trace of the query ``engine`` is running on this thread, or
+    None outside one."""
+    return getattr(getattr(engine, "_query_stats", None), "trace", None)
+
+
+def _root_span(engine, name: str, **attrs):
+    """A named child of the root of the trace of the query ``engine``
+    is running on this thread (no-op outside one; ``as`` then binds
+    None): host work between a trace's fragments."""
+    trace = _query_trace(engine)
+    if trace is None:
+        return _NO_STATS
+    return trace.span(name, **attrs)
+
+
+def _window_selected(stats, n: int, start_ns: int, skipped: int) -> None:
+    """The ``n``-th window a table scan handed over, asked for at
+    ``start_ns`` (the range and pruner on the first, the zone-map skip,
+    the resident window found or staged): a ``window.select`` span on a
+    traced fragment, sampled as the per-window stage spans are (no-op
+    without stats)."""
+    if stats is not None and stats.keeps_interval(n):
+        stats.stamped("window.select", start_ns, skipped=int(skipped))
+
+
 def _device_wait(stats):
     """Around the sync the path has anyway — the host asks for a result
     until its bytes are on the host: a ``device.wait`` span on a traced
-    fragment (no-op without stats). Never adds a sync of its own."""
+    fragment (no-op without stats; ``as`` binds the span, or None).
+    Never adds a sync of its own."""
     return _subspan(stats, "device.wait")
+
+
+def _device_fetch(stats, wait):
+    """Inside a ``device.wait``, from the instant the path's own sync
+    has returned to the last leaf on the host: a ``device.fetch`` span,
+    child of that wait (``wait`` is what ``_device_wait`` bound). The
+    program has run by then; what is left of the wait is copies, a leaf
+    at a time. ``_note_fetched`` gives it its ``leaves`` and ``bytes``."""
+    if stats is None or wait is None:
+        return _NO_STATS
+    return stats.subspan("device.fetch", parent=wait)
+
+
+def _note_fetched(span, leaves) -> None:
+    """``leaves`` and ``bytes`` of the host arrays a fetch left in hand,
+    onto its span: a ``device.fetch``, or the ``device.wait`` itself
+    where the path fetched by one batched ``jax.device_get`` (no-op
+    without a span). ``QueryTrace._finalize_usage`` counts the bytes
+    into ``usage.bytes_fetched``."""
+    if span is not None:
+        span.attributes.update(
+            leaves=len(leaves),
+            bytes=int(sum(getattr(a, "nbytes", 0) for a in leaves)),
+        )
 
 
 def _block_if(stats, x) -> None:
@@ -345,18 +396,31 @@ def _block_if(stats, x) -> None:
 
 
 # -- host-batch assembly ------------------------------------------------------
-def _fetch_result(meta_list, cols, valid):
+def _fetch_result(meta_list, cols, valid, stats=None, wait=None,
+                  synced: bool = False):
     """A result's validity and planes to the host — what a
     ``device.wait`` span is put around. One copy a plane, the planes
     ``_to_host_batch`` reads and in its order, exactly the copies the
     path made when they were interleaved with the assembly (one batched
     ``jax.device_get`` is fewer round trips: PERF.md, PR 25, left to a
-    ``perf_opt`` issue). Returns (host cols, host valid)."""
-    valid = np.asarray(valid)
-    host: dict = {}
-    for m in meta_list:
-        n = 1 if m.struct_fields is not None else len(host_dtypes(m.dtype))
-        host[m.name] = tuple(np.asarray(p) for p in cols[m.name][:n])
+    ``perf_opt`` issue). Returns (host cols, host valid).
+
+    The validity's copy is the path's sync: the planes' copies after it
+    are the wait's ``device.fetch`` (``stats`` and ``wait``: the
+    fragment and its ``device.wait`` span). ``synced``: the caller has
+    read a flag of the same program already, and the validity's copy is
+    part of the fetch."""
+    if not synced:
+        valid = np.asarray(valid)  # the path's sync
+    with _device_fetch(stats, wait) as fetch:
+        valid = np.asarray(valid)  # (in hand already unless ``synced``)
+        host: dict = {}
+        for m in meta_list:
+            n = 1 if m.struct_fields is not None else len(host_dtypes(m.dtype))
+            host[m.name] = tuple(np.asarray(p) for p in cols[m.name][:n])
+        _note_fetched(
+            fetch, [valid, *(p for ps in host.values() for p in ps)]
+        )
     return host, valid
 
 

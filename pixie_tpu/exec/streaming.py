@@ -469,9 +469,9 @@ class StreamingQuery:
                     continue
                 with _dispatch(st, frag.update):
                     out_cols, out_valid = frag.update(cols, valid)
-                with _device_wait(st):
+                with _device_wait(st) as wait:
                     out_cols, out_valid = _fetch_result(
-                        frag.out_meta, out_cols, out_valid
+                        frag.out_meta, out_cols, out_valid, st, wait
                     )
                 with _timed(st, "materialize"):
                     hb = _to_host_batch(frag.out_meta, out_cols, out_valid)
@@ -550,9 +550,9 @@ class StreamingQuery:
                     continue
                 with _dispatch(st, frag.update):
                     out_cols, out_valid = frag.update(cols, valid)
-                with _device_wait(st):
+                with _device_wait(st) as wait:
                     out_cols, out_valid = _fetch_result(
-                        frag.out_meta, out_cols, out_valid
+                        frag.out_meta, out_cols, out_valid, st, wait
                     )
                 with _timed(st, "materialize"):
                     hb = _to_host_batch(frag.out_meta, out_cols, out_valid)

@@ -52,11 +52,73 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   / ``device``, ``how``, ``build_rows``, ``probe_rows``, ``rows_out``;
   a fused lookup join's span covers its build alone)
 - ``device.wait``         the host asks for a result until the bytes are
-  on the host, at the sync the path has anyway
+  on the host, at the sync the path has anyway (where the path fetches
+  by ONE batched ``jax.device_get`` the span carries ``leaves`` and
+  ``bytes`` itself)
+- ``device.fetch``        child of its ``device.wait``: from the instant
+  the path's own sync has returned (an overflow scalar, a validity
+  plane) to the last leaf on the host, a copy a leaf (attributes
+  ``leaves``, ``bytes``): the device is idle here though the wait goes
+  on. Counted in ``usage.bytes_fetched`` / ``usage.fetches``
+- ``plan.walk``           child of the root: the plan's loop between the
+  ops that run work (sources found, chains extended), one span a stretch
+- ``fragment.bind``       child of the root: a chain's fragment found or
+  compiled at the capacity to fold it at (content keys, the fragment
+  cache's lookup, the remembered capacity; on the Kelvin the prepared
+  merge's lookup); ``cached``: ``hit`` / ``miss``
+- ``pipeline.start``      child of its fragment: the window pipeline's
+  prefetch thread made and started
+- ``window.select``       child of its fragment: one a window a table
+  scan hands over (the range and pruner on the first, the zone-map
+  skip, the resident window found or staged), stamped on the thread
+  that stages; ``skipped``: windows the zone maps pruned on the way
+- ``state.init``          child of its fragment: a fold's empty group
+  state made (eager array constructions on the device, before the
+  first window's program)
+- ``merge.compact``       child of the root, on the Kelvin: the shipped
+  states' live slots found and taken (``payloads``, ``slots``: the
+  bucket they are merged at)
+- ``payload``             child of the root: the fragment's last
+  ``device.wait`` end to the bridge payload built and its wire bytes
+  counted (``kind``: ``agg_state`` / ``rows``)
+- ``join.align`` / ``join.assemble``  children of ``join``: the key
+  dictionaries' union and remaps (``strings`` hashed; ``memo``: ``miss``
+  where a union was built, ``none`` where the sides share dictionaries:
+  no memo exists yet, so never ``hit``) and the output rows gathered on
+  the host (``rows_out``)
+- ``restream``            child of the root: a batch in hand (a join's
+  rows, a materialized aggregate) made the source of the next fragment
+  (``rows``)
 - ``window.stage`` / ``window.stall`` / ``materialize``  windows that
   are staged, the consumer blocked on the prefetch pipe, host batch
   assembly after the wait. Every interval of a stage up to
   ``trace_window_sample``, then every that-many-th.
+- ``bus.deliver``         one a bus message that starts or feeds a
+  trace: its enqueue on the subscription's queue to the handler's entry
+  on the dispatcher thread (``services/msgbus.py`` stamps both on this
+  clock, and ``busstats``' dispatcher lag is the same pair; ``topic``:
+  the topic class, ``bytes``: the bus's estimate).
+  Before the root (``outside_root`` = ``before``) on the PEM's
+  ``fragment`` trace (the execute message) and the Kelvin's ``merge``
+  trace (each bridge payload); child of ``await.results`` on the
+  broker's
+- ``trace.sinks``         after the root (``outside_root`` = ``after``):
+  ``Tracer.end_query`` from the root's end to the last listener's
+  return (usage, ring, metrics, slow-query check, export, listeners:
+  the telemetry fold); ``listeners``: how many ran
+
+The agents' own (``services/agent.py``): ``merge.wait`` (before the
+Kelvin's root: merge installed until the last bridge payload is in) and
+``publish`` (after an agent's root: its payloads, stats and eos on the
+bus). The binder's: ``dict_udf`` (``dict_udf_span`` below). The
+broker's stages on its ``distributed`` trace
+(``services/query_broker.py``): ``snapshot``, ``compile``, ``plan``,
+``admit``, ``register``, ``dispatch`` (``dispatch.retry`` a re-publish),
+``await`` > ``await.results`` / ``await.stats``, ``finish``, and
+``failover`` where an agent was lost.
+
+``SPAN_NAMES`` below is every name the program stamps; a test holds the
+served scripts and ``docs/OBSERVABILITY.md`` to it.
 
 The stats spine is shared with ``analyze`` (``analyze.py``): a trace
 owns a ``QueryStats`` whose fragments the engine fills exactly as
@@ -89,6 +151,7 @@ from __future__ import annotations
 import contextlib
 import gc
 import hashlib
+import itertools
 import json
 import logging
 import os
@@ -132,6 +195,22 @@ STAGE_SPANS = {
     "materialize": "materialize",
 }
 
+#: Every span name the program stamps (engine, agents, broker), so that
+#: a reader of the spans can tell the program's from anyone else's
+#: (``tests/test_host_path_spans.py`` holds the served scripts to it).
+SPAN_NAMES = frozenset({
+    "query", "compile", "fragment", "device.dispatch", "device.wait",
+    "device.fetch", "rebucket", "group_probe", "join", "join.align",
+    "join.assemble", "dict_udf", "plan.walk", "fragment.bind",
+    "pipeline.start", "window.select", "state.init",
+    "merge.compact", "payload", "restream",
+    "bus.deliver", "merge.wait", "publish", "trace.sinks",
+    # the broker's stages (services/query_broker.py)
+    "snapshot", "plan", "admit", "register", "dispatch", "dispatch.retry",
+    "await", "await.results", "await.stats", "finish", "failover",
+    *STAGE_SPANS.values(),
+})
+
 #: THE clock: both ends of every span and of every background entry,
 #: on every thread. It is the clock of ``time.perf_counter()``, which
 #: callers (the benchmark's driver) time requests with.
@@ -150,18 +229,42 @@ def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
 
 
+def _seed_span_ids() -> None:
+    global _span_ids
+    _span_ids = itertools.count(int.from_bytes(os.urandom(8), "big"))
+
+
+def _new_span_id() -> str:
+    """A span's 64-bit id: a counter from a start drawn once a process
+    (and again in a forked child), so a span does not read the entropy
+    pool. A ``getrandom`` a span was half of what a span costs on the
+    chip's host (5 of 10 us: PERF.md section 6, PR 37), and a request
+    leaves some fifty spans."""
+    return f"{next(_span_ids) & 0xFFFFFFFFFFFFFFFF:016x}"
+
+
+_seed_span_ids()
+os.register_at_fork(after_in_child=_seed_span_ids)
+
+
 _TraceAnnotation = None
+#: What ``_annotation`` hands out while no profiler session is on.
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 def _annotation(name: str, qid: str = ""):
     """A ``jax.profiler.TraceAnnotation`` (a context manager): the
-    benchmark's ``harness.mark`` for the program's own spans. Entering
-    it is a flag test unless a profiler session is on."""
+    benchmark's ``harness.mark`` for the program's own spans. With no
+    profiler session on it is a flag test (``is_enabled``) and one
+    shared no-op: an annotation object a span was a fifth of a span's
+    cost."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation
 
         _TraceAnnotation = TraceAnnotation
+    if not _TraceAnnotation.is_enabled():
+        return _NO_ANNOTATION
     return _TraceAnnotation(name, qid=qid) if qid else _TraceAnnotation(name)
 
 
@@ -181,6 +284,11 @@ class QueryResourceUsage:
       staged again (``Table.device_scan``; padded planes, every column).
       A counter of its own: admission's observed floor and pxbound's
       check read ``bytes_staged`` alone
+    - ``bytes_fetched`` / ``fetches`` device->host bytes the query's
+      results and shipped states crossed by, and the fetches they took:
+      the ``device.fetch`` spans' ``bytes`` (a copy a leaf) and the
+      ``device.wait`` spans' that carry ``bytes`` themselves (one batched
+      ``jax.device_get``); ``nbytes`` of the host arrays in hand
     - ``device_ms``     the time the query had work on the device or
       was waiting for it: each fragment's first ``device.dispatch``
       start to its last ``device.wait`` end, summed over fragments
@@ -225,6 +333,8 @@ class QueryResourceUsage:
     windows: int = 0
     bytes_staged: int = 0
     bytes_restaged: int = 0
+    bytes_fetched: int = 0
+    fetches: int = 0
     device_ms: float = 0.0
     compile_ms: float = 0.0
     stall_ms: float = 0.0
@@ -258,7 +368,8 @@ class QueryResourceUsage:
         d = other if isinstance(other, dict) else asdict(other)
         for k in (
             "rows_in", "rows_out", "windows", "bytes_staged",
-            "bytes_restaged", "wire_bytes", "retries", "rebuckets",
+            "bytes_restaged", "bytes_fetched", "fetches", "wire_bytes",
+            "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
             "join_rows_in", "join_rows_out", "dict_udf_strings",
             "skipped_windows",
@@ -286,7 +397,7 @@ class Span:
 
     name: str
     trace_id: str
-    span_id: str = field(default_factory=lambda: _new_id(8))
+    span_id: str = field(default_factory=_new_span_id)
     parent_id: str = ""
     start_ns: int = 0  # both on ``clock_ns``; 0 = not stamped yet
     end_ns: int = 0
@@ -346,8 +457,9 @@ class _FragmentSpanCtx(_SpanCtx):
     stage's timer (a program's enqueue is both)."""
 
     def __init__(self, frag: "TracedFragment", name: str,
-                 attrs: dict | None = None, stage: str | None = None):
-        super().__init__(frag.trace, name, frag.span, attrs)
+                 attrs: dict | None = None, stage: str | None = None,
+                 parent: Span | None = None):
+        super().__init__(frag.trace, name, parent or frag.span, attrs)
         self.frag = frag
         self.stage = stage
 
@@ -409,11 +521,10 @@ class TracedFragment(FragmentStats):
         if tracer is not None:
             tracer._observe_stage(stage, seconds)
         name = STAGE_SPANS.get(stage)
-        k = self.trace.window_sample
         # A span needs its two stamps: a bare duration (the cold tier's
         # decode meter) is a stage total only.
-        if (name and start_ns and end_ns and k
-                and (count <= k or (count - 1) % k == 0)):
+        if (name and start_ns and end_ns
+                and self.keeps_interval(count - 1)):
             attrs = {"interval": count - 1}
             if rows:
                 attrs["rows"] = int(rows)
@@ -426,6 +537,21 @@ class TracedFragment(FragmentStats):
                 attributes=attrs,
             ))
 
+    def keeps_interval(self, n: int) -> bool:
+        """Whether the ``n``-th (from 0) interval of a per-window stage
+        becomes a span: every one up to ``trace_window_sample``, then
+        every that-many-th."""
+        k = self.trace.window_sample
+        return bool(k) and (n < k or n % k == 0)
+
+    def stamped(self, name: str, start_ns: int, **attrs) -> None:
+        """A span under the fragment's from ``start_ns`` (``clock_ns``,
+        read where it began) to now: for work on a thread that is not
+        the query's and lives for one fragment (no profiler annotation
+        there)."""
+        self.trace.add_span(name, start_ns, clock_ns(), parent=self.span,
+                            **attrs)
+
     def timed(self, stage: str, rows: int = 0, nbytes: int = 0):
         """The stage timer; a stage that becomes a span is also the
         profiler's annotation of that name while it runs."""
@@ -434,8 +560,12 @@ class TracedFragment(FragmentStats):
             return super().timed(stage, rows, nbytes)
         return _AnnotatedTimer(self, stage, rows, nbytes, name)
 
-    def subspan(self, name: str, **attrs) -> _FragmentSpanCtx:
-        return _FragmentSpanCtx(self, name, attrs)
+    def subspan(self, name: str, parent: Span | None = None,
+                **attrs) -> _FragmentSpanCtx:
+        """A named span under the fragment's, or under ``parent``: a
+        span of this fragment (a ``device.fetch`` inside its
+        ``device.wait``)."""
+        return _FragmentSpanCtx(self, name, attrs, parent=parent)
 
     def dispatch(self, program: str, stage: str = "compute",
                  windows: int = 1) -> _FragmentSpanCtx:
@@ -628,6 +758,15 @@ class QueryTrace:
         self._add_span(sp)
         return sp
 
+    def open_span(self, name: str) -> Span | None:
+        """The newest span of that name that has not ended (a ``join``
+        whose pieces want to be its children), or None."""
+        with self._lock:
+            for s in reversed(self.spans):
+                if s.name == name and not s.end_ns:
+                    return s
+        return None
+
     # -- derived views -------------------------------------------------------
     @property
     def rows_in(self) -> int:
@@ -703,6 +842,12 @@ class QueryTrace:
                 u.join_rows_out += a.get("rows_out", 0)
             elif s.name == "dict_udf":
                 u.dict_udf_strings += s.attributes.get("strings", 0)
+            elif (s.name in ("device.fetch", "device.wait")
+                  and "bytes" in s.attributes):
+                # A copy a leaf (the child) or one batched get (the
+                # wait itself carries the bytes): never both.
+                u.bytes_fetched += s.attributes["bytes"]
+                u.fetches += 1
             elif s.attributes.get("prepared") == "hit":
                 u.merge_prepared_hits += 1
             elif s.attributes.get("prepared") == "miss":
@@ -954,7 +1099,20 @@ class Tracer:
         with self._lock:
             if self._inflight.pop(trace.root.span_id, None) is None:
                 return  # already ended (or foreign trace)
-        trace._finalize(status, error)
+        # ``trace.sinks`` lies AFTER the trace's root, from the root's
+        # end to the last listener's return. It joins the trace's spans
+        # once the sinks have run: what they export and fold is the
+        # trace as its root's end left it.
+        with _annotation("trace.sinks", trace.qid):
+            trace._finalize(status, error)
+            listeners = self._run_sinks(trace, status)
+            sinks_end_ns = clock_ns()
+        trace.add_span("trace.sinks", trace.end_ns, sinks_end_ns,
+                       outside_root="after", listeners=listeners)
+
+    def _run_sinks(self, trace: QueryTrace, status: str) -> int:
+        """The finished trace into the ring, the metrics, the slow-query
+        log, the OTLP push and the listeners; how many listeners ran."""
         m = self._m()
         with self._lock:
             # Ring-drop accounting (satellite): an evicted trace that
@@ -976,12 +1134,13 @@ class Tracer:
         m["wire_bytes"].observe(u.wire_bytes)
         self._slow_query_check(trace, m)
         self._export(trace, m)
-        self._notify(trace)
+        return self._notify(trace)
 
-    def _notify(self, trace: QueryTrace) -> None:
+    def _notify(self, trace: QueryTrace) -> int:
         if self._closed:
-            return
-        for fn in list(self._listeners):
+            return 0
+        listeners = list(self._listeners)
+        for fn in listeners:
             try:
                 fn(trace)
             except Exception:
@@ -989,6 +1148,7 @@ class Tracer:
                 logging.getLogger("pixie_tpu.trace").warning(
                     "trace listener %r failed", fn, exc_info=True
                 )
+        return len(listeners)
 
     def _slow_query_check(self, trace: QueryTrace, m: dict) -> None:
         thresh_ms = float(get_flag("slow_query_threshold_ms"))

@@ -21,7 +21,7 @@ from ..exec.engine import Engine, QueryError
 from ..exec.pipeline import DeadlineEvent
 from ..exec.stream import QueryCancelled
 from ..exec.trace import background, clock_ns, plan_script
-from .msgbus import MessageBus
+from .msgbus import MessageBus, add_delivery_span, current_delivery
 from .tracker import TOPIC_HEARTBEAT, TOPIC_REGISTER
 
 DEFAULT_HEARTBEAT_INTERVAL_S = 5.0
@@ -500,6 +500,9 @@ class Agent:
                 return
             self._running[qid] = ev
         trace = self._begin_fragment_trace(msg, qid, plan, "fragment")
+        # The execute message's hop onto this dispatcher thread lies
+        # BEFORE the trace's root, as the Kelvin's ``merge.wait`` does.
+        add_delivery_span(trace, current_delivery(), outside_root="before")
         try:
             t0 = time.perf_counter()
             outputs = self.engine.execute_plan(
@@ -577,9 +580,12 @@ class Agent:
         # install-time context is stored, not inherited.
         # "installed_ns": when the merge plan landed (clock_ns): the
         # start of the merge trace's ``merge.wait`` span.
+        # "hops": each accepted bridge payload's hop onto its dispatcher
+        # thread (``msgbus.current_delivery``): the merge trace's
+        # ``bus.deliver`` spans.
         return {"plan": None, "expect": None, "got": {}, "got_keys": set(),
                 "keep": None, "trace_ctx": None, "deadline": None,
-                "tenant": "", "installed_ns": 0}
+                "tenant": "", "installed_ns": 0, "hops": []}
 
     def _on_merge(self, msg):
         """Install a merge fragment; runs once all bridge payloads land."""
@@ -641,6 +647,7 @@ class Agent:
                 (msg["from_agent"], msg["payload"])
             )
             pm["got_keys"].add(key)
+            pm["hops"].append(current_delivery())
         self._maybe_finish_merge(qid)
 
     def _on_merge_update(self, msg):
@@ -721,6 +728,8 @@ class Agent:
         # happened.
         trace.add_span("merge.wait", pm["installed_ns"], trace.start_ns,
                        outside_root="before")
+        for hop in pm["hops"]:
+            add_delivery_span(trace, hop, outside_root="before")
         # The merge respects the query deadline AND query.cancel:
         # folding states for a client the broker already answered is
         # dead work — the same window-boundary abort as data fragments.

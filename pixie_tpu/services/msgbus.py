@@ -13,8 +13,15 @@ the bus stamps per-topic-class publish/deliver/byte counters,
 publish-to-handler-entry dispatcher-lag and handler service-time
 histograms, per-subscription queue-depth high-water marks (the
 backpressure signal), handler-error counts, and a slow-handler log —
-monotonic clock reads only on the hot path, served via ``busz()`` /
+clock reads only on the hot path, served via ``busz()`` /
 ``/debug/busz`` and folded into the ``__bus__`` telemetry ring.
+
+**One stamp a hop, on the spans' clock.** ``_deliver`` reads
+``exec.trace.clock_ns`` once a message, always; the dispatcher thread
+reads it again at the handler's entry. The pair is the dispatcher lag
+``busstats`` keeps and, for a handler that starts or feeds a query
+trace, that trace's ``bus.deliver`` span: the handler asks
+``current_delivery()`` for it.
 """
 
 from __future__ import annotations
@@ -27,7 +34,27 @@ from typing import Callable
 
 from ..config import get_flag
 from ..exec import tracectx
+from ..exec.trace import clock_ns
 from .busstats import BusStats, HANDLER_ERROR_RING, topic_class
+
+
+_delivering = threading.local()
+
+
+def current_delivery():
+    """``(enqueued_ns, entered_ns, topic_class, nbytes)`` of the message
+    whose handler the calling dispatcher thread is running, both stamps
+    on ``exec.trace.clock_ns``; None on any other thread (a one-shot
+    inbox's waiter, a remote transport's reader)."""
+    return getattr(_delivering, "hop", None)
+
+
+def add_delivery_span(trace, hop, parent=None, **attrs) -> None:
+    """The hop ``current_delivery`` gave, as ``trace``'s ``bus.deliver``
+    span (no-op without a hop)."""
+    if hop is not None:
+        trace.add_span("bus.deliver", hop[0], hop[1], parent=parent,
+                       topic=hop[2], bytes=hop[3], **attrs)
 
 
 class Subscription:
@@ -37,9 +64,7 @@ class Subscription:
         self.fn = fn
         self._q: queue.Queue = queue.Queue()
         self._alive = True
-        # Stamping is decided once at subscribe time (the bus's stats
-        # object never changes after construction), so queue items are
-        # uniformly raw messages or (msg, enqueue_monotonic) pairs.
+        # Queue items are (msg, enqueued clock_ns, nbytes) triples.
         self._cls = topic_class(topic)
         self._hw = 0
         # Named for observability (and the ack-thread regression test):
@@ -55,12 +80,9 @@ class Subscription:
             item = self._q.get()
             if item is _CLOSE:
                 return
-            if st is not None:
-                msg, enq_t = item
-                t0 = time.monotonic()
-                lag_s = t0 - enq_t
-            else:
-                msg = item
+            msg, enq_ns, nbytes = item
+            t0 = clock_ns()
+            _delivering.hop = (enq_ns, t0, self._cls, nbytes)
             err = False
             try:
                 # Distributed-trace propagation: bind the message's
@@ -72,10 +94,11 @@ class Subscription:
             except Exception as e:  # handler errors must not kill delivery
                 err = True
                 self.bus._on_handler_error(self.topic, e)
+            _delivering.hop = None
             if st is not None:
                 st.on_handled(
-                    self._cls, self.topic, lag_s,
-                    time.monotonic() - t0, error=err,
+                    self._cls, self.topic, (t0 - enq_ns) / 1e9,
+                    (clock_ns() - t0) / 1e9, error=err,
                 )
 
     def _deliver(self, msg, nbytes: int = 0):
@@ -87,9 +110,7 @@ class Subscription:
             if depth > self._hw:
                 self._hw = depth
             st.on_deliver(self._cls, nbytes, depth)
-            self._q.put((msg, time.monotonic()))
-        else:
-            self._q.put(msg)
+        self._q.put((msg, clock_ns(), nbytes))
 
     def unsubscribe(self):
         self._alive = False

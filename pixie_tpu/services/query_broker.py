@@ -23,7 +23,7 @@ from ..planner.distributed import DistributedPlanner
 from ..planner.distributed.coordinator import PlanningError
 from ..udf.registry import Registry, default_registry
 from ..exec.trace import background
-from .msgbus import MessageBus
+from .msgbus import MessageBus, add_delivery_span, current_delivery
 from .tracker import AgentTracker
 
 #: Dispatch-retry backoff hard cap (seconds) — dispatch_backoff_ms
@@ -459,8 +459,20 @@ class QueryResultForwarder:
                     st["acked"].add((m.get("agent"), m.get("ack")))
             q.put(m)
 
+        def on_results(m):
+            # A results message's hop onto this dispatcher thread, on
+            # the query's trace: under ``await.results`` while the wait
+            # loop holds it open.
+            if trace is not None:
+                st = self._active.get(qid)  # a dict read: no lock
+                add_delivery_span(
+                    trace, current_delivery(),
+                    parent=st.get("await_results") if st else None,
+                )
+            q.put(m)
+
         subs = [
-            self.bus.subscribe(f"query.{qid}.results", q.put),
+            self.bus.subscribe(f"query.{qid}.results", on_results),
             self.bus.subscribe(f"query.{qid}.agent_done", q.put),
             self.bus.subscribe(f"query.{qid}.ack", on_ack),
             self.bus.subscribe(f"query.{qid}.agent_lost", q.put),
@@ -533,7 +545,7 @@ class QueryResultForwarder:
             open_spans.append(tr.span("await"))
             await_sp = open_spans[0].__enter__()
             open_spans.append(tr.span("await.results", parent=await_sp))
-            open_spans[1].__enter__()
+            st["await_results"] = open_spans[1].__enter__()
         # Inactivity watchdog: only QUERY-RELEVANT activity pushes the
         # deadline out — unrelated cluster churn (another query's agent
         # expiring) must not postpone a hung query's timeout forever.

@@ -29,9 +29,12 @@ from pixie_tpu.services.observability import (
 )
 
 HEARTBEAT_S = 0.05
+#: (the two ``bus.deliver``: the Kelvin's rows and its eos on the
+#: results topic; ``trace.sinks`` joins the trace once its sinks ran)
 BROKER_SPANS = ["snapshot", "snapshot", "compile", "plan", "admit",
                 "register", "dispatch", "await", "await.results",
-                "await.stats", "finish"]
+                "bus.deliver", "bus.deliver", "await.stats", "finish",
+                "trace.sinks"]
 
 
 @pytest.fixture(scope="module")
@@ -111,10 +114,25 @@ def test_broker_trace_names_the_served_paths_stages(served):
     assert spans["admit"].attributes == {"queued": False}
     assert spans["await.results"].parent_id == spans["await"].span_id
     assert spans["await.stats"].start_ns >= spans["await.results"].end_ns
-    # The stages tile the root: what no child covers is small change.
-    top = [s for s in tr.spans if s.parent_id == tr.root.span_id]
-    covered = sum(s.end_ns - s.start_ns for s in top)
-    assert 0 <= (tr.end_ns - tr.start_ns) - covered < 5e6
+    # The stages tile the root: every one of them there, in this order,
+    # none overlapping the next, all inside the root. (How much of the
+    # root no stage covers is a size, not an order: the benchmark's
+    # ``unnamed_ms`` measures it on the chip's host; on a loaded test
+    # box it was a coin's toss, ROADMAP D13.)
+    top = [s for s in tr.spans if s.parent_id == tr.root.span_id
+           and s.attributes.get("outside_root") is None]
+    assert [s.name for s in top] == [
+        "snapshot", "snapshot", "compile", "plan", "admit", "register",
+        "dispatch", "await", "finish",
+    ]
+    edges = [tr.start_ns]
+    for s in top:
+        edges += [s.start_ns, s.end_ns]
+    edges.append(tr.end_ns)
+    assert edges == sorted(edges)
+    sinks = spans["trace.sinks"]
+    assert sinks.attributes["outside_root"] == "after"
+    assert sinks.start_ns == tr.end_ns <= sinks.end_ns
 
 
 def test_agents_traces_lie_inside_the_brokers_root(served):

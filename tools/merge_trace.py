@@ -12,7 +12,12 @@ of the Kelvin's merge trace (root to root) and of each of its spans,
 then the LAST refresh's trace as a table (offset from the root's start,
 length, attributes; the rows between two spans are host work with no
 span of its own), and the PEM's fragment trace of the same request
-after it. One more refresh then counts, inside the Kelvin's
+after it, then the whole request on one axis: the broker's, the PEM's
+and the Kelvin's spans by their start (offset from the broker root's
+start), with a row ``(unnamed)`` for every stretch of the broker's root
+that no span of the three traces names (what ``unnamed_ms`` sums: the
+spans that only hold others or only say that someone waited do not
+count). One more refresh then counts, inside the Kelvin's
 ``execute_plan`` and inside its ``merge_agg_bridge`` alone, the calls of
 ``StringDictionary.get_or_add`` and the strings ``content_key`` hashed,
 and the programs JAX compiled: a warm merge is expected to make none of
@@ -24,6 +29,7 @@ object. ``--rehearse-rows`` walks the same flow on the CPU at that size.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import statistics
@@ -35,7 +41,10 @@ sys.path.insert(0, ROOT)
 
 _SHOWN = ("program", "windows", "slots", "prepared", "ops", "from", "to",
           "fold", "ride", "rows", "strategy", "where", "build_rows",
-          "probe_rows", "rows_out")
+          "probe_rows", "rows_out", "leaves", "bytes", "cached", "memo",
+          "skipped", "topic", "listeners")
+#: Under this a stretch no span names is not worth a row.
+_UNNAMED_MIN_MS = 0.05
 
 
 def _merge_traces(spans: dict, qids: list, tracer: str = "kelvin",
@@ -47,8 +56,9 @@ def _merge_traces(spans: dict, qids: list, tracer: str = "kelvin",
 def _print_table(rows: list) -> None:
     for row in rows:
         attrs = {k: v for k, v in row.items()
-                 if k not in ("span", "at_ms", "ms")}
-        print(f"  {row['span']:<16} +{row['at_ms']:>9.3f} "
+                 if k not in ("span", "at_ms", "ms", "who")}
+        who = f"{row['who']:<7}" if "who" in row else ""
+        print(f"  {who}{row['span']:<16} +{row['at_ms']:>9.3f} "
               f"{row['ms']:>9.3f} ms  {attrs or ''}")
 
 
@@ -70,6 +80,34 @@ def _table(trace) -> list:
             **{k: v for k, v in s.attributes.items() if k in _SHOWN},
         })
     return rows
+
+
+def _request_table(traces: dict) -> tuple:
+    """(rows, unnamed ms): the spans of a request's traces ({who:
+    trace}, the broker's among them) in start order on the broker
+    root's axis, and a row for every stretch of that root no span
+    names."""
+    from benchmark.layer_metrics.unnamed_ms import named_intervals
+    from benchmark.xplane import _clip, _union
+
+    root = traces["broker"].root
+    rows = [
+        {"who": who, **row, "at_ms": round(
+            row["at_ms"] + (t.root.start_ns - root.start_ns) / 1e6, 3)}
+        for who, t in traces.items() for row in _table(t)
+    ]
+    cur, unnamed = root.start_ns, 0.0
+    named = _union(_clip(named_intervals(list(traces.values())),
+                         root.start_ns, root.end_ns))
+    for lo, hi in [*named, (root.end_ns, root.end_ns)]:
+        gap = (lo - cur) / 1e6
+        unnamed += gap
+        if gap >= _UNNAMED_MIN_MS:
+            rows.append({"who": "", "span": "(unnamed)", "ms": round(gap, 3),
+                         "at_ms": round((cur - root.start_ns) / 1e6, 3)})
+        cur = max(cur, hi)
+    rows.sort(key=lambda r: (r["at_ms"], -r["ms"]))
+    return rows, round(unnamed, 3)
 
 
 class _KelvinCounter:
@@ -131,6 +169,76 @@ class _KelvinCounter:
         del self.engine.execute_plan
 
 
+@contextlib.contextmanager
+def warmed_cell(workload: str, seed: int, rehearse_rows: int | None = None):
+    """A cell's deployment by the benchmark's own builder, its data
+    ingested and every program of its traffic compiled (the harness's
+    warm-up), as a namespace: ``stack``, ``spec``, ``traffic``,
+    ``driver``, ``requests``, ``now_ns``, ``meter`` (the compile meter),
+    ``log`` (a ``SpanLog`` cut after the warm-up), ``device_kind``,
+    ``rehearsal`` and ``refresh()`` (one refresh; its records). Yields
+    None, with the error said, where there is no TPU and no
+    ``rehearse_rows`` (which walks the same flow on the CPU at that
+    size). Closes the stack on the way out."""
+    import types
+
+    import jax
+
+    from benchmark import harness
+    from pixie_tpu.utils.cache import configure_jax_cache
+
+    spec = harness.load_cell(workload)
+    cfg, traffic = spec["config"], spec["traffic"]
+    configure_jax_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    devices = jax.devices()
+    if rehearse_rows is None and devices[0].platform != "tpu":
+        print("error: no TPU (use --rehearse-rows)", file=sys.stderr)
+        yield None
+        return
+    rows = cfg["rows"] if rehearse_rows is None else rehearse_rows
+    window_rows = cfg["window_rows"]
+    if rehearse_rows is not None:
+        window_rows = max(1024, rows // (cfg["rows"] // window_rows))
+    with contextlib.ExitStack() as flags:
+        if devices[0].platform != "tpu":
+            from pixie_tpu.config import override_flag
+
+            flags.enter_context(override_flag("cpu_fold_threads", 1))
+        meter = harness.CompileMeter()
+        builder = harness.module("builders", cfg["builder"])
+        driver = harness.module("drivers", traffic["driver"])
+        stack = builder.build(cfg, window_rows)
+        flags.callback(stack.close)
+        stack.ingest(builder.make_data(cfg, seed, rows))
+        _lo, now_ns = harness.range_lo_ns(cfg, traffic)
+        requests = harness.requests_of(spec)
+        log = harness.SpanLog(stack.tracers)
+
+        def refresh():
+            recs, _ = driver.refresh(
+                stack, requests, now_ns, traffic["timeout_s"], harness.mark
+            )
+            return recs
+
+        quiet, n = False, 0
+        while not quiet and n < harness.MAX_WARMUPS:
+            before = meter.programs
+            refresh()
+            n += 1
+            quiet = meter.programs == before
+        for _ in range(traffic["warmup_extra"]):
+            refresh()
+        log.cut()
+        yield types.SimpleNamespace(
+            stack=stack, spec=spec, traffic=traffic, driver=driver,
+            requests=requests, now_ns=now_ns, meter=meter, log=log,
+            device_kind=devices[0].device_kind,
+            rehearsal=rehearse_rows is not None, refresh=refresh,
+        )
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -139,113 +247,81 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-rows", type=int, default=None)
     args = ap.parse_args(argv)
 
-    from benchmark import harness
+    with warmed_cell(args.workload, args.seed, args.rehearse_rows) as cell:
+        if cell is None:
+            return 2
+        stack, meter, requests = cell.stack, cell.meter, cell.requests
+        out = {"workload": args.workload, "device": cell.device_kind,
+               "rehearsal": cell.rehearsal, "scripts": {}}
 
-    spec = harness.load_cell(args.workload)
-    cfg, traffic = spec["config"], spec["traffic"]
-    from pixie_tpu.utils.cache import configure_jax_cache
+        def refresh():
+            return [r["qid"] for r in cell.refresh()]
 
-    configure_jax_cache()
-    import contextlib
-
-    import jax
-
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    devices = jax.devices()
-    if args.rehearse_rows is None and devices[0].platform != "tpu":
-        print("error: no TPU (use --rehearse-rows)", file=sys.stderr)
-        return 2
-    rows = cfg["rows"] if args.rehearse_rows is None else args.rehearse_rows
-    window_rows = cfg["window_rows"]
-    if args.rehearse_rows is not None:
-        window_rows = max(1024, rows // (cfg["rows"] // window_rows))
-    flags = contextlib.ExitStack()
-    if devices[0].platform != "tpu":
-        from pixie_tpu.config import override_flag
-
-        flags.enter_context(override_flag("cpu_fold_threads", 1))
-    meter = harness.CompileMeter()
-    builder = harness.module("builders", cfg["builder"])
-    driver = harness.module("drivers", traffic["driver"])
-    out = {"workload": args.workload, "device": devices[0].device_kind,
-           "rehearsal": args.rehearse_rows is not None, "scripts": {}}
-    with flags:
-        stack = builder.build(cfg, window_rows)
-        try:
-            stack.ingest(builder.make_data(cfg, args.seed, rows))
-            _lo, now_ns = harness.range_lo_ns(cfg, traffic)
-            requests = harness.requests_of(spec)
-            log = harness.SpanLog(stack.tracers)
-
-            def refresh():
-                recs, _ = driver.refresh(
-                    stack, requests, now_ns, traffic["timeout_s"],
-                    harness.mark,
-                )
-                return [r["qid"] for r in recs]
-
-            quiet, n = False, 0
-            while not quiet and n < harness.MAX_WARMUPS:
-                before = meter.programs
-                refresh()
-                n += 1
-                quiet = meter.programs == before
-            for _ in range(traffic["warmup_extra"]):
-                refresh()
-            log.cut()
-            qids = [refresh() for _ in range(args.refreshes)]
-            spans = log.cut()
-            for i, req in enumerate(requests):
-                traces = _merge_traces(spans, [q[i] for q in qids])
-                names = sorted({s.name for t in traces for s in t.spans})
-                med = {
-                    name: round(statistics.median(
-                        sum(_ms(s) for s in t.spans if s.name == name)
-                        for t in traces
-                    ), 3)
-                    for name in names
-                }
-                counts = {
-                    name: statistics.median(
-                        sum(1 for s in t.spans if s.name == name)
-                        for t in traces
-                    )
-                    for name in names
-                }
-                out["scripts"][req["label"]] = {
-                    "merge_ms_p50": round(statistics.median(
-                        _ms(t.root) for t in traces), 3),
-                    "span_ms_p50": med, "span_count_p50": counts,
-                    "usage": traces[-1].usage.to_dict(),
-                    "last": _table(traces[-1]),
-                }
-                print(f"== {req['label']}: merge trace, root to root, "
-                      f"p50 of {len(traces)} = "
-                      f"{out['scripts'][req['label']]['merge_ms_p50']} ms")
-                _print_table(out["scripts"][req["label"]]["last"])
-                # The same request on the PEM, for what the Kelvin's
-                # parts cost beside the folds.
-                pem = _merge_traces(spans, [q[i] for q in qids],
-                                    "pem", "fragment")
-                out["scripts"][req["label"]]["pem_ms_p50"] = round(
-                    statistics.median(_ms(t.root) for t in pem), 3)
-                out["scripts"][req["label"]]["pem_last"] = _table(pem[-1])
-                print(f"== {req['label']}: the PEM's fragment trace, p50 = "
-                      f"{out['scripts'][req['label']]['pem_ms_p50']} ms")
-                _print_table(out["scripts"][req["label"]]["pem_last"])
-            counter = _KelvinCounter(stack.kelvin.engine)
-            before = meter.programs
-            try:
-                refresh()
-            finally:
-                counter.close()
-            out["warm_request"] = {
-                **counter.counts,
-                "programs_compiled": meter.programs - before,
+        qids = [refresh() for _ in range(args.refreshes)]
+        spans = cell.log.cut()
+        for i, req in enumerate(requests):
+            traces = _merge_traces(spans, [q[i] for q in qids])
+            names = sorted({s.name for t in traces for s in t.spans})
+            med = {
+                name: round(statistics.median(
+                    sum(_ms(s) for s in t.spans if s.name == name)
+                    for t in traces
+                ), 3)
+                for name in names
             }
+            counts = {
+                name: statistics.median(
+                    sum(1 for s in t.spans if s.name == name)
+                    for t in traces
+                )
+                for name in names
+            }
+            out["scripts"][req["label"]] = {
+                "merge_ms_p50": round(statistics.median(
+                    _ms(t.root) for t in traces), 3),
+                "span_ms_p50": med, "span_count_p50": counts,
+                "usage": traces[-1].usage.to_dict(),
+                "last": _table(traces[-1]),
+            }
+            print(f"== {req['label']}: merge trace, root to root, "
+                  f"p50 of {len(traces)} = "
+                  f"{out['scripts'][req['label']]['merge_ms_p50']} ms")
+            _print_table(out["scripts"][req["label"]]["last"])
+            # The same request on the PEM, for what the Kelvin's
+            # parts cost beside the folds.
+            pem = _merge_traces(spans, [q[i] for q in qids],
+                                "pem", "fragment")
+            out["scripts"][req["label"]]["pem_ms_p50"] = round(
+                statistics.median(_ms(t.root) for t in pem), 3)
+            out["scripts"][req["label"]]["pem_last"] = _table(pem[-1])
+            print(f"== {req['label']}: the PEM's fragment trace, p50 = "
+                  f"{out['scripts'][req['label']]['pem_ms_p50']} ms")
+            _print_table(out["scripts"][req["label"]]["pem_last"])
+            broker = _merge_traces(spans, [qids[-1][i]], "broker",
+                                   "distributed")
+            if broker:
+                rows, unnamed = _request_table({
+                    "broker": broker[-1], "pem": pem[-1],
+                    "kelvin": traces[-1],
+                })
+                out["scripts"][req["label"]].update(
+                    request_last=rows, unnamed_ms_last=unnamed,
+                    broker_ms_last=round(_ms(broker[-1].root), 3),
+                )
+                print(f"== {req['label']}: the request on the broker "
+                      f"root's axis, {_ms(broker[-1].root):.3f} ms, of "
+                      f"it unnamed {unnamed} ms")
+                _print_table(rows)
+        counter = _KelvinCounter(stack.kelvin.engine)
+        before = meter.programs
+        try:
+            refresh()
         finally:
-            stack.close()
+            counter.close()
+        out["warm_request"] = {
+            **counter.counts,
+            "programs_compiled": meter.programs - before,
+        }
     print(json.dumps(out), flush=True)
     return 0
 
