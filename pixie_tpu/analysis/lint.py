@@ -8,7 +8,8 @@ so linting never executes device code.
 Rules:
 
 - ``host-sync-hot-path``: no ``block_until_ready`` / ``.item()`` /
-  ``np.asarray`` / ``jax.device_get`` inside registered hot regions
+  ``np.asarray`` / ``jax.device_get`` / ``_fetch_tree`` (the exec
+  layer's one batched get) inside registered hot regions
   (the per-window execution path). A host sync per window serializes
   the pipelined executor (docs/EXECUTOR.md). Hot regions are
   *registered* by
@@ -179,8 +180,8 @@ def _hot_regions(ctxs, repo_root=None) -> list[tuple[str, str]]:
 class HostSyncHotPathRule:
     name = "host-sync-hot-path"
     description = (
-        "no block_until_ready/.item()/np.asarray/jax.device_get inside "
-        "registered hot regions (PXLINT_HOT_REGIONS)"
+        "no block_until_ready/.item()/np.asarray/jax.device_get/"
+        "_fetch_tree inside registered hot regions (PXLINT_HOT_REGIONS)"
     )
 
     def __init__(self):
@@ -240,6 +241,9 @@ class HostSyncHotPathRule:
                     and f.value.id == "jax"
                 ):
                     msg = "jax.device_get() forces a host readback"
+            elif isinstance(f, ast.Name) and f.id == "_fetch_tree":
+                # exec/stream.py's one batched get: a readback by name.
+                msg = "_fetch_tree() forces a host readback"
             if msg:
                 yield Finding(
                     rule=self.name,

@@ -25,6 +25,7 @@ from .stream import (
     _device_fetch,
     _device_wait,
     _dispatch,
+    _fetch_tree,
     _note_fetched,
     _query_trace,
     _timed,
@@ -35,6 +36,7 @@ from .stream import (
     _rebucket,
     _remember_climb,
     _root_span,
+    _start_fetch,
     _Stream,
     _with_agg_groups,
     _stream_col_stats,
@@ -192,15 +194,18 @@ def bridge_payload(engine, res):
                     else None
                 )
                 state = engine._fold_agg_state(res, frag, stats)
-                # The fragment's sync: the fold has run when its overflow
-                # flag is on the host; then the state that ships, a copy
-                # a leaf (as ever: see stream._fetch_result): the wait's
-                # ``device.fetch``.
+                # The fold's last program is enqueued: the state that
+                # ships starts for the host behind it. The fragment's
+                # sync: the fold has run when its overflow flag is on
+                # the host; then the state by one batched get, the
+                # wait's ``device.fetch`` (an overflowed state is dropped
+                # with its copies).
+                _start_fetch(state)
                 with _device_wait(stats) as wait:
                     overflowed = bool(np.asarray(state["overflow"]))
                     if not overflowed:
                         with _device_fetch(stats, wait) as fetch:
-                            state = jax.tree_util.tree_map(np.asarray, state)
+                            state = _fetch_tree(state)
                             _note_fetched(
                                 fetch, jax.tree_util.tree_leaves(state)
                             )

@@ -98,12 +98,25 @@ from .stream import (  # noqa: F401  (re-exported)
     _empty_host_batch,
     _fetch_result,
     _note_fetched,
+    _start_result_fetch,
     _Stream,
     _stream_col_stats,
     _timed,
     _to_host_batch,
     _window_shapes,
 )
+
+
+def _dispatch_finalize(frag, state, stats):
+    """Enqueue ``frag.finalize`` over a fold's state: (cols, valid,
+    overflow) on the device, with what ``DeviceResult.to_host`` reads of
+    them started for the host behind the program (no sync: the result
+    stays the caller's to read, or to drop)."""
+    with _dispatch(stats, frag.finalize, "finalize"):
+        out = cols, valid, overflow = frag.finalize(state)
+        _start_result_fetch(frag.out_meta, cols, valid, overflow)
+        _block_if(stats, out)
+    return out
 
 
 class DeviceResult:
@@ -179,11 +192,10 @@ class DeviceResult:
                     stats = self._qstats.new_fragment(stream.chain)
                     stats.ops = stats.ops + ("rebucket",)
                 state = eng._fold_agg_state(stream, frag, stats)
-                with _dispatch(stats, frag.finalize, "finalize"):
-                    cols, valid, overflow = frag.finalize(state)
-                    _block_if(stats, (cols, valid, overflow))
+                cols, valid, overflow = _dispatch_finalize(frag, state, stats)
         # The overflow flag above was this program's sync: all of this
-        # wait is the fetch.
+        # wait is the fetch (of copies started where ``finalize`` was
+        # dispatched).
         with _device_wait(stats) as wait:
             cols, valid = _fetch_result(
                 frag.out_meta, cols, valid, stats, wait, synced=True
@@ -1722,9 +1734,7 @@ class Engine:
 
         if frag.is_agg:
             state = self._fold_agg_state(stream, frag, stats)
-            with _dispatch(stats, frag.finalize, "finalize"):
-                cols, valid, overflow = frag.finalize(state)
-                _block_if(stats, (cols, valid, overflow))
+            cols, valid, overflow = _dispatch_finalize(frag, state, stats)
             return DeviceResult(
                 self, stream, frag, cols, valid, overflow, stats,
                 qstats=getattr(self, "_query_stats", None),

@@ -46,6 +46,7 @@ from .stream import (
     QueryError,
     _chain_out_relation,
     _col,
+    _fetch_tree,
     _Stream,
     _stream_col_stats,
 )
@@ -908,7 +909,7 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
                 # per-window bool(overflow) readback).
                 with _device_wait(stats):
                     p_idx, p_take, b_idx, b_take, out_valid, overflow = (
-                        np.asarray(a) for a in out  # pxlint: disable=host-sync-hot-path
+                        _fetch_tree(out)  # pxlint: disable=host-sync-hot-path
                     )
                 if not bool(overflow):
                     break
@@ -1089,8 +1090,11 @@ def _join_device(left: HostBatch, right: HostBatch, op: JoinOp,
             nb, np_, tuple(str(p.dtype) for p in bk), capacity, op.how,
             routes.routes_platform(),
         )
-        p_idx, p_take, b_idx, b_take, out_valid, overflow = (
-            np.asarray(a) for a in fn(bk, bv, pk, pv)
+        # The kernel's six outputs by one batched get, the overflow flag
+        # among them: every copy queued behind the program at once, the
+        # get's first wait the path's sync.
+        p_idx, p_take, b_idx, b_take, out_valid, overflow = _fetch_tree(
+            fn(bk, bv, pk, pv)
         )
         if not bool(overflow):
             break

@@ -363,17 +363,48 @@ def _device_fetch(stats, wait):
     """Inside a ``device.wait``, from the instant the path's own sync
     has returned to the last leaf on the host: a ``device.fetch`` span,
     child of that wait (``wait`` is what ``_device_wait`` bound). The
-    program has run by then; what is left of the wait is copies, a leaf
-    at a time. ``_note_fetched`` gives it its ``leaves`` and ``bytes``."""
+    program has run by then; what is left of the wait is its outputs'
+    copies, started at the dispatch (``_start_fetch``) and waited for
+    together (``_fetch_tree``). ``_note_fetched`` gives it its
+    ``leaves`` and ``bytes``."""
     if stats is None or wait is None:
         return _NO_STATS
     return stats.subspan("device.fetch", parent=wait)
 
 
+def _start_fetch(tree) -> None:
+    """Start the device-to-host copy of every device leaf of ``tree``
+    and return at once: no sync, the copies queue behind the program
+    that makes the leaves. Called where that program is enqueued, so
+    the bytes cross while the host waits for the path's sync and
+    ``_fetch_tree`` finds them in flight or on the host already. Host
+    leaves and Python scalars have nothing to start. A tree that is
+    dropped unread (a fold that overflowed) drops its copies with it."""
+    import jax
+
+    for leaf in jax.tree_util.tree_leaves(tree):
+        start = getattr(leaf, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+
+def _fetch_tree(tree):
+    """``tree`` on the host by ONE batched ``jax.device_get``: every
+    leaf's copy in flight together (those ``_start_fetch`` started are
+    found, the rest start here), then one wait a leaf in order; never a
+    blocking copy a leaf. The one way anything a program made leaves
+    the device in this layer. Device leaves come back as the numpy
+    arrays ``np.asarray`` would give, bit for bit; host leaves and
+    Python scalars pass through."""
+    import jax
+
+    return jax.device_get(tree)
+
+
 def _note_fetched(span, leaves) -> None:
     """``leaves`` and ``bytes`` of the host arrays a fetch left in hand,
     onto its span: a ``device.fetch``, or the ``device.wait`` itself
-    where the path fetched by one batched ``jax.device_get`` (no-op
+    where the path's one batched get is also its sync (no-op
     without a span). ``QueryTrace._finalize_usage`` counts the bytes
     into ``usage.bytes_fetched``."""
     if span is not None:
@@ -396,32 +427,46 @@ def _block_if(stats, x) -> None:
 
 
 # -- host-batch assembly ------------------------------------------------------
+def _result_tree(meta_list, cols, valid) -> tuple:
+    """(validity, [a column's planes, ...]): the planes
+    ``_to_host_batch`` reads, in the order it reads them (a struct
+    column's one plane; a plane a host dtype otherwise): what
+    ``_start_fetch`` / ``_fetch_result`` bring to the host."""
+    return valid, [
+        tuple(cols[m.name][
+            :1 if m.struct_fields is not None else len(host_dtypes(m.dtype))
+        ])
+        for m in meta_list
+    ]
+
+
+def _start_result_fetch(meta_list, cols, valid, overflow) -> None:
+    """``_start_fetch`` of what a ``finalize`` program's caller will
+    read: its overflow flag and ``_result_tree``. Where the program is
+    dispatched; ``_fetch_result(..., synced=True)`` collects them."""
+    _start_fetch((overflow, _result_tree(meta_list, cols, valid)))
+
+
 def _fetch_result(meta_list, cols, valid, stats=None, wait=None,
                   synced: bool = False):
     """A result's validity and planes to the host — what a
-    ``device.wait`` span is put around. One copy a plane, the planes
-    ``_to_host_batch`` reads and in its order, exactly the copies the
-    path made when they were interleaved with the assembly (one batched
-    ``jax.device_get`` is fewer round trips: PERF.md, PR 25, left to a
-    ``perf_opt`` issue). Returns (host cols, host valid).
+    ``device.wait`` span is put around: ``_result_tree`` by one
+    ``_fetch_tree``. Returns (host cols, host valid).
 
-    The validity's copy is the path's sync: the planes' copies after it
-    are the wait's ``device.fetch`` (``stats`` and ``wait``: the
-    fragment and its ``device.wait`` span). ``synced``: the caller has
-    read a flag of the same program already, and the validity's copy is
-    part of the fetch."""
+    The validity's read is the path's sync, with every plane's copy
+    started before it; what follows is the wait's ``device.fetch``
+    (``stats`` and ``wait``: the fragment and its ``device.wait``
+    span). ``synced``: the caller has read a flag of the same program
+    already (and started the copies where it dispatched the program),
+    and the validity is part of the fetch."""
+    tree = _result_tree(meta_list, cols, valid)
     if not synced:
-        valid = np.asarray(valid)  # the path's sync
+        _start_fetch(tree)
+        np.asarray(valid)  # the path's sync
     with _device_fetch(stats, wait) as fetch:
-        valid = np.asarray(valid)  # (in hand already unless ``synced``)
-        host: dict = {}
-        for m in meta_list:
-            n = 1 if m.struct_fields is not None else len(host_dtypes(m.dtype))
-            host[m.name] = tuple(np.asarray(p) for p in cols[m.name][:n])
-        _note_fetched(
-            fetch, [valid, *(p for ps in host.values() for p in ps)]
-        )
-    return host, valid
+        valid, planes = _fetch_tree(tree)
+        _note_fetched(fetch, [valid, *(p for ps in planes for p in ps)])
+    return {m.name: ps for m, ps in zip(meta_list, planes)}, valid
 
 
 def _to_host_batch(meta_list, cols, valid) -> HostBatch:

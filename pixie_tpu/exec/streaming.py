@@ -56,6 +56,7 @@ from .plan import (
     ResultSinkOp,
     TableSinkOp,
 )
+from .stream import _fetch_tree, _start_fetch, _start_result_fetch
 
 
 @dataclass
@@ -444,10 +445,14 @@ class StreamingQuery:
             if not folded and self.seq > 0:
                 return 0
             cols, valid, overflow = frag.finalize(self._state)
+            _start_result_fetch(frag.out_meta, cols, valid, overflow)
             if bool(np.asarray(overflow)):
                 self._rebucket()
                 return self._poll_inner()
-            hb = _to_host_batch(frag.out_meta, cols, np.asarray(valid))
+            cols, valid = _fetch_result(
+                frag.out_meta, cols, valid, synced=True
+            )
+            hb = _to_host_batch(frag.out_meta, cols, valid)
             if frag.limit is not None and hb.length > frag.limit:
                 hb = _head(hb, frag.limit)
             self.emit(StreamUpdate(
@@ -508,8 +513,6 @@ class StreamingQuery:
         """Per-agent half of a distributed live query: fold new windows,
         ship the current partial state (agg bridges) or the new rows
         (row-gather bridges) to the merge tier."""
-        import jax
-
         from .engine import AggStatePayload, RowsPayload
 
         rows = 0
@@ -520,6 +523,10 @@ class StreamingQuery:
             # idle agent must not blank the whole live view.
             if not folded and self.seq > 0:
                 return 0
+            # The state stays on the device for the next poll's fold;
+            # what ships is its image on the host, by one batched get
+            # whose copies start before the flag's read.
+            _start_fetch(self._state)
             if bool(np.asarray(self._state["overflow"])):
                 self._rebucket()
                 return self._poll_bridge(self._frag)
@@ -527,7 +534,7 @@ class StreamingQuery:
                 chain=tuple(self.ops),
                 input_relation=self.relation,
                 input_dicts=dict(self.dicts),
-                state=jax.tree_util.tree_map(np.asarray, self._state),
+                state=_fetch_tree(self._state),
                 dense_domains=frag.dense_domains,
                 dense_offsets=frag.dense_offsets,
                 dense_strides=frag.dense_strides,

@@ -52,14 +52,16 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   / ``device``, ``how``, ``build_rows``, ``probe_rows``, ``rows_out``;
   a fused lookup join's span covers its build alone)
 - ``device.wait``         the host asks for a result until the bytes are
-  on the host, at the sync the path has anyway (where the path fetches
-  by ONE batched ``jax.device_get`` the span carries ``leaves`` and
-  ``bytes`` itself)
+  on the host, at the sync the path has anyway (where the path's one
+  batched ``jax.device_get`` is also its sync the span carries
+  ``leaves`` and ``bytes`` itself)
 - ``device.fetch``        child of its ``device.wait``: from the instant
   the path's own sync has returned (an overflow scalar, a validity
-  plane) to the last leaf on the host, a copy a leaf (attributes
-  ``leaves``, ``bytes``): the device is idle here though the wait goes
-  on. Counted in ``usage.bytes_fetched`` / ``usage.fetches``
+  plane) to the last leaf on the host: one batched get
+  (``stream._fetch_tree``) of copies started where the program was
+  enqueued (``stream._start_fetch``), so only what is still in flight
+  is waited for here (attributes ``leaves``, ``bytes``). Counted in
+  ``usage.bytes_fetched`` / ``usage.fetches``
 - ``plan.walk``           child of the root: the plan's loop between the
   ops that run work (sources found, chains extended), one span a stretch
 - ``fragment.bind``       child of the root: a chain's fragment found or
@@ -286,9 +288,10 @@ class QueryResourceUsage:
       check read ``bytes_staged`` alone
     - ``bytes_fetched`` / ``fetches`` device->host bytes the query's
       results and shipped states crossed by, and the fetches they took:
-      the ``device.fetch`` spans' ``bytes`` (a copy a leaf) and the
-      ``device.wait`` spans' that carry ``bytes`` themselves (one batched
-      ``jax.device_get``); ``nbytes`` of the host arrays in hand
+      the ``device.fetch`` spans' ``bytes`` (a fragment's one batched
+      get after its sync) and the ``device.wait`` spans' that carry
+      ``bytes`` themselves (the get is the sync); ``nbytes`` of the
+      host arrays in hand
     - ``device_ms``     the time the query had work on the device or
       was waiting for it: each fragment's first ``device.dispatch``
       start to its last ``device.wait`` end, summed over fragments
@@ -844,8 +847,9 @@ class QueryTrace:
                 u.dict_udf_strings += s.attributes.get("strings", 0)
             elif (s.name in ("device.fetch", "device.wait")
                   and "bytes" in s.attributes):
-                # A copy a leaf (the child) or one batched get (the
-                # wait itself carries the bytes): never both.
+                # One batched get after the path's sync (the child) or
+                # as the sync (the wait itself carries the bytes): never
+                # both.
                 u.bytes_fetched += s.attributes["bytes"]
                 u.fetches += 1
             elif s.attributes.get("prepared") == "hit":

@@ -70,6 +70,34 @@ def test_host_sync_rule_flags_registered_regions(tmp_path):
     assert all(f.symbol == "Runner._loop" for f in report.findings)
 
 
+def test_host_sync_rule_knows_the_batched_get(tmp_path):
+    # exec/stream.py's ``_fetch_tree`` (one jax.device_get of a pytree)
+    # is a readback by name: a hot region that calls it says why.
+    report = _lint_src(
+        tmp_path, "hot_mod.py",
+        """
+        import jax
+
+        from stream import _fetch_tree
+
+        PXLINT_HOT_REGIONS = (
+            "hot_mod.py:Runner._loop*",
+        )
+
+        class Runner:
+            def _loop(self, outs):
+                a = jax.device_get(outs)
+                b = _fetch_tree(outs)
+                c = _fetch_tree(outs)  # pxlint: disable=host-sync-hot-path
+                return a, b, c
+        """,
+        rules={"host-sync-hot-path"},
+    )
+    msgs = sorted(f.message for f in report.findings)
+    assert len(msgs) == 2
+    assert "_fetch_tree()" in msgs[0] and "jax.device_get()" in msgs[1]
+
+
 def test_host_sync_nested_def_reports_once(tmp_path):
     report = _lint_src(
         tmp_path, "hot_mod.py",

@@ -16,6 +16,16 @@ run uses): each window's median refresh on the client's clock, and the
 spans a request leaves on its three traces, by trace and by name. The
 last line is one JSON object. ``--rehearse-rows`` walks the same flow on
 the CPU at that size.
+
+With ``--fetch`` (and no cell): what a program's outputs cost to bring
+to the host on that machine, at the three shapes the cells' fragments
+ship (``FETCH_SHAPES``), three ways, ms over ``--reps`` fresh runs of
+one program: (a) a ``np.asarray`` a leaf after ``block_until_ready``;
+(b) one ``jax.device_get`` after it; (c) ``copy_to_host_async`` on every
+leaf at the dispatch, the flag's read as the sync, then one
+``jax.device_get``: what ``exec/stream.py`` ``_start_fetch`` /
+``_fetch_tree`` do. ``fetch_ms`` is the sync's return to the last leaf
+on the host, ``total_ms`` the dispatch to it.
 """
 
 from __future__ import annotations
@@ -74,6 +84,85 @@ def span_ns(n: int) -> dict:
     }
 
 
+#: name -> (leaves of i32, leaves of i64, slots): the states the cells'
+#: fragments ship. The dashboard scripts' dense states (15 leaves a
+#: refresh at 2,145 slots, 0.22 MB); ``px/sql_stats``' keyed state (7
+#: leaves at 2^17 slots, 4.85 MB read, 5.2 here); the re-aggregation's
+#: result in ``flow_recent`` (5 leaves at 2^16 slots, 1.64 MB read, 1.57
+#: here). Each with a 0-d flag beside it, as a state's ``overflow``.
+FETCH_SHAPES = {
+    "dense_15x15KB": (5, 10, 2145),
+    "keyed_7x0.7MB": (4, 3, 1 << 17),
+    "result_5x0.33MB": (4, 1, 1 << 16),
+}
+
+
+def fetch_ms(reps: int) -> dict:
+    """The three ways of bringing a program's outputs to the host, at
+    ``FETCH_SHAPES``: median ms of ``reps`` runs each, the ways taking
+    turns run by run."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import pixie_tpu  # noqa: F401  (x64 on, as the engine's states are)
+
+    def timed(way, fn, x, salt):
+        t0 = time.perf_counter_ns()
+        outs = fn(x, salt)
+        flag = outs[-1]
+        if way == "c":
+            for o in outs:
+                o.copy_to_host_async()
+            bool(np.asarray(flag))
+        else:
+            jax.block_until_ready(outs)
+        t1 = time.perf_counter_ns()
+        if way == "a":
+            host = [np.asarray(o) for o in outs]
+        else:
+            host = jax.device_get(outs)
+        t2 = time.perf_counter_ns()
+        assert all(isinstance(h, np.ndarray) for h in host)
+        return (t2 - t1) / 1e6, (t2 - t0) / 1e6
+
+    out = {}
+    for name, (n32, n64, slots) in FETCH_SHAPES.items():
+        def program(x, salt, n32=n32, n64=n64, slots=slots):
+            # Some device time in front of the outputs (a 2^21-row
+            # sort, a window fold's order of work), so that copies
+            # started at the dispatch queue behind a running program.
+            s = jnp.sort(x ^ salt)[:slots]
+            leaves = [s + jnp.int32(i) for i in range(n32)]
+            leaves += [s.astype(jnp.int64) << (i + 1) for i in range(n64)]
+            return (*leaves, jnp.any(s > jnp.int32(1 << 30)))
+
+        fn = jax.jit(program)
+        x = jnp.arange(1 << 21, dtype=jnp.int32)[::-1] % jnp.int32(99991)
+        for i in range(3):  # compile, and warm each way
+            for way in "abc":
+                timed(way, fn, x, jnp.int32(i))
+        runs = {way: [] for way in "abc"}
+        for i in range(reps):
+            for way in ("abc", "bca", "cab")[i % 3]:
+                runs[way].append(timed(way, fn, x, jnp.int32(3 + i)))
+        row = {
+            "leaves": n32 + n64 + 1,
+            "bytes": slots * (4 * n32 + 8 * n64) + 1,
+        }
+        for way, label in (("a", "a_asarray_a_leaf"), ("b", "b_device_get"),
+                           ("c", "c_async_at_dispatch")):
+            row[label] = {
+                "fetch_ms": round(statistics.median(
+                    f for f, _ in runs[way]), 3),
+                "total_ms": round(statistics.median(
+                    t for _, t in runs[way]), 3),
+            }
+        out[name] = row
+        print(json.dumps({name: row}), flush=True)
+    return out
+
+
 def spans_a_request(spans: dict, qids: list) -> dict:
     """Median spans a request on each of its traces, and by name over
     the three."""
@@ -99,7 +188,18 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--rehearse-rows", type=int, default=None)
+    ap.add_argument("--fetch", action="store_true",
+                    help="the three device-to-host readings")
+    ap.add_argument("--reps", type=int, default=30)
     args = ap.parse_args(argv)
+
+    if args.fetch:
+        import jax
+
+        out = {"device": jax.devices()[0].device_kind,
+               "fetch": fetch_ms(args.reps)}
+        print(json.dumps(out), flush=True)
+        return 0
 
     out = {"span": span_ns(args.spans)}
     print(json.dumps(out["span"]), flush=True)
