@@ -833,7 +833,9 @@ def _shape_sql_stats(n, window):
 
 
 def _shape_perf_flamegraph(n, window):
-    """configs[4]: stack-trace groupby-count (continuous profiler shape)."""
+    """configs[4]: the continuous profiler's shape, as upstream's script
+    has it: ``any`` of the folded stack and the sum of ``count`` by
+    (pod, stack_trace_id), the sums by pod, their join, the percent."""
     from pixie_tpu.types.dtypes import DataType
     from pixie_tpu.types.relation import Relation
     from pixie_tpu.types.strings import StringDictionary
@@ -846,11 +848,16 @@ def _shape_perf_flamegraph(n, window):
         stacks.append(";".join(frames[(i + d) % len(frames)] + f"_{(i * 7 + d) % 97}"
                                for d in range(depth)))
     st_dict = StringDictionary(stacks)
+    n_pods = 16
+    pod_dict = StringDictionary(f"ns/pod-{i}" for i in range(n_pods))
     rel = Relation([
         ("time_", DataType.TIME64NS),
+        ("stack_trace_id", DataType.INT64),
         ("stack_trace", DataType.STRING),
         ("count", DataType.INT64),
+        ("pod", DataType.STRING),
     ])
+    # One id a (pod, stack): the stack's pod follows from its number.
     sc = _codes(rng, n, len(stacks))
     cnt = rng.integers(1, 50, n)
 
@@ -858,25 +865,34 @@ def _shape_perf_flamegraph(n, window):
         s = slice(off, off + m)
         return {
             "time_": (np.arange(off, off + m, dtype=np.int64),),
+            "stack_trace_id": (sc[s].astype(np.int64),),
             "stack_trace": (sc[s],),
             "count": (cnt[s],),
+            "pod": ((sc[s] % n_pods).astype(np.int32),),
         }
 
     eng, warm = _build_engines("stack_traces.beta", rel, cols, n, window,
-                               {"stack_trace": st_dict})
+                               {"stack_trace": st_dict, "pod": pod_dict})
 
     query = _script("px/perf_flamegraph")
     rps, dt, out = _time_query(eng, query, n, warm_eng=warm)
 
     t0 = time.perf_counter()
     ref = np.bincount(sc, weights=cnt.astype(np.float64), minlength=len(stacks))
+    by_pod = np.bincount(np.arange(len(stacks)) % n_pods, weights=ref,
+                         minlength=n_pods)
     base_dt = time.perf_counter() - t0
 
     got = out["output"].to_pydict(decode_strings=False)
-    order = np.argsort(got["stack_trace"])
+    order = np.argsort(got["stack_trace_id"])
     present = np.nonzero(ref)[0]
-    assert np.array_equal(got["stack_trace"][order], present), "stack keys mismatch"
+    assert np.array_equal(got["stack_trace_id"][order], present), "stack keys mismatch"
+    assert np.array_equal(got["stack_trace"][order], present), "any(stack_trace) mismatch"
+    assert np.array_equal(got["pod"][order], present % n_pods), "pod mismatch"
     np.testing.assert_allclose(got["count"][order], ref[present], rtol=1e-6)
+    np.testing.assert_allclose(
+        got["percent"][order], 100.0 * ref[present] / by_pod[present % n_pods],
+        rtol=1e-6)
     return _with_pipeline({
         "rows": n, "rows_per_sec": round(rps), "secs": round(dt, 3),
         "vs_baseline": round(rps / (n / base_dt), 3), "checked": True,

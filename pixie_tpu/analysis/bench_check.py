@@ -59,7 +59,8 @@ SHAPE_SCHEMAS = {
     },
     "perf_flamegraph": {
         "stack_traces.beta": Relation([
-            ("time_", T), ("stack_trace", S), ("count", I),
+            ("time_", T), ("stack_trace_id", I), ("stack_trace", S),
+            ("count", I), ("pod", S),
         ]),
     },
     "device_join": {

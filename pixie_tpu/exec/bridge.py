@@ -53,7 +53,9 @@ class AggStatePayload:
     merge tier to recompile the identical fragment and realign string
     dictionary ids. String-valued *carries* (e.g. ``any`` over a string
     column) are not realigned — only group keys are; such UDAs need a
-    shared dictionary to cross agents.
+    shared dictionary to cross agents, and the merge refuses them loudly
+    where the agents' dictionaries differ in content (``_prepare_merge``,
+    ``CompiledFragment.string_carry_sources``).
     """
 
     chain: tuple  # fragment ops [pre..., AggOp]
@@ -422,8 +424,11 @@ def _prepare_merge(engine, payloads, tail, slots: int, key) -> _PreparedMerge:
     )
     program = _merge_program(frag, apply_tail, meta)
     if key is not None:
+        # (The registry's identity is part of the key: its UDAs' finalize
+        # is in the program, and two engines of one process may differ.)
         program = default_program_registry().wrap(
-            program, "merge_finalize", (key, "merge_finalize"),
+            program, "merge_finalize",
+            (key, id(engine.registry), "merge_finalize"),
             ",".join(type(o).__name__ for o in (*p0.chain, *tail)),
             pins=(tuple(canonical.values()), engine.registry),
         )

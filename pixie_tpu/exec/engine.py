@@ -1004,6 +1004,17 @@ class Engine:
                         outputs[op.name] = self._run_fragment(r)
                 else:
                     outputs[op.name] = mat_input(src_id)
+                    # The answer leaves the engine as ids and their
+                    # dictionaries; what a client's decode will hold.
+                    with walk.paused(), _root_span(
+                        self, "payload", kind="result"
+                    ) as sp:
+                        if sp is not None:
+                            out = outputs[op.name]
+                            sp.attributes.update(
+                                rows=out.length,
+                                string_bytes=out.string_nbytes(),
+                            )
             elif isinstance(op, TableSinkOp):
                 hb = mat_input(node.inputs[0])
                 with walk.paused():
@@ -1142,12 +1153,15 @@ class Engine:
         pend_cols, pend_lo, pend_hi = [], [], []
 
         def note_ride(span, cols):
-            # How a keyed sorted fold carries this window's sums, on a
-            # traced fragment's ``device.dispatch``.
+            # How a keyed sorted fold carries this window's sums, and the
+            # words of the maxima it sorts by (an ``any``'s among them),
+            # on a traced fragment's ``device.dispatch``.
             if frag.ride is not None and isinstance(span, Span):
                 way = frag.ride(window_rows(cols))
                 if way:
                     span.attributes["ride"] = way
+                if frag.plan.max_words:
+                    span.attributes["max_words"] = frag.plan.max_words
 
         def flush_pending(state):
             if not pend_cols:

@@ -18,7 +18,8 @@ Three folds, told apart by ``layout`` and ``payload_sort``:
   packed key code IS the slot. Each aggregate takes its own route: the
   exact integer Pallas kernel, the f32 one, or XLA (``uda.update``).
 - keyed, ``payload_sort``: no dense domain, every aggregate an exact
-  integer statistic, on the TPU: rows ride one sort with their keys and
+  integer statistic or an ``any`` the sort carries as a maximum
+  (``_sort_max``), on the TPU: rows ride one sort with their keys and
   values (``ops/groupby.py`` ``sorted_group_fold``), windows and merges
   alike. ``layout`` reads ``sorted``.
 - keyed, group ids in row order (a ``quantiles`` or FLOAT64 sum needs
@@ -87,6 +88,10 @@ class FoldPlan:
     #: Unpacked, a leading dictionary id still spares the flag operand:
     #: ids are >= NULL_ID (-1), so id + 1 never reads 0xFFFFFFFF.
     lead_id: bool = False
+    #: Under it, the u32 words of the maxima the sorts carry as keys
+    #: (``_sort_max`` over the aggregates): the ``max_words`` attribute
+    #: of the fold programs' ``device.dispatch`` spans.
+    max_words: int = 0
 
 
 def _digest(uda_name: str) -> bool:
@@ -105,6 +110,22 @@ def _int_stat(uda_name: str, arg_types: tuple) -> bool:
             want == DataType.BOOLEAN and uda_name in ("sum", "mean")
         )
     return False
+
+
+def _sort_max(uda_name: str, arg_types: tuple) -> int:
+    """The u32 words of the maximum the keyed sort carries for this
+    aggregate, 0 where it carries none: ``max`` / ``min`` of an INT64 /
+    TIME64NS (two), and ``any`` (``udf/builtins/collections.py``: a
+    segment maximum) of one (two) or of a STRING (one: the maximum of
+    its int32 dictionary ids)."""
+    if len(arg_types) != 1:
+        return 0
+    if uda_name in ("max", "min"):
+        return 2 if arg_types[0] in INT_KEY_TYPES else 0
+    if uda_name == "any":
+        return (1 if arg_types[0] == DataType.STRING
+                else 2 if arg_types[0] in INT_KEY_TYPES else 0)
+    return 0
 
 
 def plan_fold(group_cols, domains, aggs, *, max_groups: int,
@@ -173,12 +194,14 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
             routes[out] = count_route
 
     # Keyed integer fold: chosen where the key has no dense domain, every
-    # aggregate is exact-integer and the platform sorts. Anything else
+    # aggregate is exact-integer or an ``any`` that is a maximum of
+    # integers (dictionary ids are) and the platform sorts. Anything else
     # keeps group ids in row order, which a ``quantiles`` or a FLOAT64
     # sum needs.
     payload_sort = (
         not dense and bool(group_cols) and tpu
         and all(uda == "count" or _int_stat(uda, types)
+                or _sort_max(uda, types)
                 for _out, uda, types in aggs)
     )
     pack_doms = None
@@ -220,4 +243,7 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
         payload_sort=payload_sort,
         pack_doms=pack_doms,
         lead_id=lead_id,
+        max_words=sum(
+            _sort_max(uda, types) for _out, uda, types in aggs
+        ) if payload_sort else 0,
     )

@@ -712,6 +712,9 @@ class _Fold(NamedTuple):
     merge: object  # (state_a, state_b) -> merged state
     key_planes: object  # state -> [g] key planes, ``key_plane_index`` order
     ride: object = None  # ``CompiledFragment.ride`` (the sorted fold's)
+    # (state, cols, valid), pre-stage applied -> the state with the window
+    # folded in; None: ``merge(state, window(cols, valid))``.
+    absorb: object = None
 
 
 def _dense_slot_ids(plan, rel1, key_plane_index, cols, valid):
@@ -1074,15 +1077,21 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
 
     # A window's statistic planes by aggregate, each (kind, which distinct
     # argument expression): static, so the span can say how they ride.
-    spec = {}
+    # ``any`` is a maximum (``fold_plan._sort_max``); of a STRING it is
+    # one of int32 dictionary ids, whose plane keeps its one word.
+    spec, plane_dtype = {}, {}
     for ae, _uda, _b, casts in aggs_bound:
         if ae.uda_name == "count":
             spec[ae.out_name] = (("rows", None),)
             continue
         fkey = (_struct_key(ae.args), casts[0])
+        plane_dtype[fkey] = (
+            jnp.int32 if casts[0][1] == DataType.STRING else jnp.int64
+        )
+        kind = "max" if ae.uda_name == "any" else ae.uda_name
         spec[ae.out_name] = (
-            (("sum", fkey), ("rows", None)) if ae.uda_name == "mean"
-            else ((ae.uda_name, fkey),)
+            (("sum", fkey), ("rows", None)) if kind == "mean"
+            else ((kind, fkey),)
         )
 
     # The first maximum is a sort key; a sum of its own plane is read off
@@ -1098,17 +1107,24 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     ) + (0 if plan.lead_id else 1)
     ride = functools.partial(
         _routes.sorted_fold_ride, g=g, planes=len(ride_planes),
-        key_words=lead_words + (2 if primary is not None else 0),
+        key_words=lead_words + (
+            0 if primary is None
+            else 1 if plane_dtype[primary[1]] == jnp.int32 else 2
+        ),
     )
 
-    def window(cols, valid):
+    def arg_planes(cols, valid):
         planes = {}  # one plane a distinct argument expression
         for ae, _uda, arg_bound, casts in aggs_bound:
             for _kind, fkey in spec[ae.out_name]:
                 if fkey is not None and fkey not in planes:
                     a = apply_cast(arg_bound[0].fn(cols), *casts[0])
                     planes[fkey] = jnp.broadcast_to(
-                        a, valid.shape).astype(jnp.int64)
+                        a, valid.shape).astype(plane_dtype[fkey])
+        return planes
+
+    def window(cols, valid):
+        planes = arg_planes(cols, valid)
         leaves = {
             out: tuple((kind, planes.get(fkey)) for kind, fkey in kinds)
             for out, kinds in spec.items()
@@ -1132,7 +1148,8 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
                     ("sum", cat(ca[0], cb[0])), ("sum", cat(ca[1], cb[1])),
                 )
             else:
-                kind = ae.uda_name if ae.uda_name in ("max", "min") else "sum"
+                (kind, _fkey), = spec[ae.out_name]
+                kind = kind if kind in ("max", "min") else "sum"
                 leaves[ae.out_name] = ((kind, cat(ca, cb)),)
         merged = _sorted_state(
             [cat(a, b) for a, b in zip(sa["keys"], sb["keys"])],
@@ -1143,9 +1160,38 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
         )
         return merged
 
+    def absorb(state, cols, valid):
+        """The state with a window folded in. A window long against the
+        slots folds alone and merges (``_front``'s rule, n >= 4 g). One
+        short against them (2^21 rows into 2^20 slots) would sort as a
+        merge does, twice: its rows are partial groups as the state's
+        slots are, so they are lifted to carries (a count of one, a sum,
+        an extreme or an ``any`` of the row's own value) and folded WITH
+        the state, one concatenation and one set of sorts for the two."""
+        if valid.shape[0] >= 4 * g:
+            return merge(state, window(cols, valid))
+        planes = arg_planes(cols, valid)
+        one = jnp.ones(valid.shape, jnp.int64)
+        carries = {}
+        for ae, _uda, _b, _c in aggs_bound:
+            lifted = tuple(
+                one if fkey is None else planes[fkey]
+                for _kind, fkey in spec[ae.out_name]
+            )
+            have = state["carries"][ae.out_name]
+            carries[ae.out_name] = jax.tree_util.tree_map(
+                lambda row, slot: row.astype(slot.dtype),
+                lifted if isinstance(have, tuple) else lifted[0], have,
+            )
+        return merge(state, {
+            "keys": tuple(cols[c][i] for c, i in key_plane_index),
+            "valid": valid, "carries": carries,
+            "overflow": jnp.zeros((), jnp.bool_),
+        })
+
     return _Fold(
         _keyed_init_keys(g, rel1, key_plane_index), window, merge,
-        lambda state: state["keys"], ride,
+        lambda state: state["keys"], ride, absorb,
     )
 
 
@@ -1328,7 +1374,11 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
     # programs at the end, once every expression of the chain is bound
     # and the operand tables they read are known (``_program``).
     def update(state, cols, valid):
-        return merge_states(state, window_state(cols, valid))
+        valid = _range_valid(cols, valid)
+        cols, valid = apply_pre(cols, valid)
+        if fold.absorb is not None:
+            return fold.absorb(state, cols, valid)
+        return fold.merge(state, fold.window(cols, valid))
 
     def update_all(state, cols_list, los, his):
         """Fold MANY equal-capacity windows in ONE program: stack the
@@ -1357,7 +1407,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
             c, lo, hi = xs
             if side is not None:
                 c = {**c, "__side__": side}
-            return merge_states(st, window_state(c, (lo, hi))), None
+            return update(st, c, (lo, hi)), None
 
         out, _ = jax.lax.scan(body, state, (stacked, los, his))
         return out

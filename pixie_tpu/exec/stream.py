@@ -133,9 +133,11 @@ def _double_agg_groups(stream: "_Stream") -> "_Stream":
 #   (``CompiledFragment.group_sketch``, ``Engine._sized_agg_fragment``),
 #   a pass that neither sorts nor keeps a keyed state. Asked for only
 #   where the plan's capacity is large enough for a wrong one to hurt,
-#   and taken only where it is worth a program (a sort-route fold takes
-#   the chip's compiler most of a minute): at an eighth of the plan's or
-#   less;
+#   and taken only where it is far enough under the plan's to change
+#   what a fold costs: at a quarter of the plan's or less (an eighth
+#   until px/perf_flamegraph, whose 0.64 M live groups under a plan
+#   clamped at 2^22 slots stayed there and folded 2^21-row windows into
+#   four times the slots they need: PERF.md section 6, PR 39);
 #   likewise where the plan's capacity is small but the rows are in hand
 #   (a join's output) and outnumber it: without sketches the plan's is
 #   AggOp's default, and 45 k edges climb from 4,096 slots by four
@@ -170,9 +172,10 @@ _CAPACITY_SLACK = 1.25
 _CAPACITY_FLOOR = 1024
 #: The planner's capacity is given up for a probed one from this ratio
 #: on.
-_CAPACITY_SHRINK = 8
-#: ... so a plan's capacity under this many slots is never probed.
-_PROBE_MIN_SLOTS = _CAPACITY_FLOOR * _CAPACITY_SHRINK
+_CAPACITY_SHRINK = 4
+#: A plan's capacity under this many slots is neither probed nor given
+#: up for a smaller one: small states cost nothing to keep.
+_PROBE_MIN_SLOTS = _CAPACITY_FLOOR * 8
 
 
 def _agg_capacity_key(chain, source, where: str):
@@ -225,7 +228,8 @@ def _probed_capacity(estimate: int, planned: int) -> int:
     cap = 1 << (max(want, _CAPACITY_FLOOR) - 1).bit_length()
     if want > planned:
         return cap
-    return cap if cap * _CAPACITY_SHRINK <= planned else planned
+    shrink = planned >= _PROBE_MIN_SLOTS and cap * _CAPACITY_SHRINK <= planned
+    return cap if shrink else planned
 
 
 def _rows_in_hand(stream: "_Stream") -> int:

@@ -38,7 +38,10 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   ``hashed`` and ``slots``: the capacity g it was compiled at; under
   ``sorted_int`` also ``ride``: ``payload`` / ``index``, how this
   window's sum planes reach group order, ``ops/routes.py``
-  ``sorted_fold_ride``, absent where none rides); child of its fragment
+  ``sorted_fold_ride``, absent where none rides, and ``max_words``: the
+  u32 words of the maxima its sorts carry as keys, two an INT64 max /
+  min / ``any``, one an ``any`` of a string, absent at 0); child of its
+  fragment
 - ``rebucket``            one per re-fold after a group-capacity overflow
   (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
   rows, ``kelvin`` the merge of states): the compile at twice the slots
@@ -82,7 +85,10 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   bucket they are merged at)
 - ``payload``             child of the root: the fragment's last
   ``device.wait`` end to the bridge payload built and its wire bytes
-  counted (``kind``: ``agg_state`` / ``rows``)
+  counted (``kind``: ``agg_state`` / ``rows``); and a result sink's
+  answer in hand (``kind``: ``result``, ``rows``, ``string_bytes``: the
+  UTF-8 bytes its STRING columns' ids stand for; counted in
+  ``usage.answer_rows`` / ``usage.string_bytes_out``)
 - ``join.align`` / ``join.assemble``  children of ``join``: the key
   dictionaries' union and remaps (``strings`` hashed; ``memo``: ``miss``
   where a union was built, ``none`` where the sides share dictionaries:
@@ -318,6 +324,12 @@ class QueryResourceUsage:
       run on: the ``dict_udf`` spans' ``strings`` (a span around every
       bind of a UDF that maps a string column's dictionary to strings;
       0 once the images are remembered: ``StringDictionary.image``)
+    - ``answer_rows`` / ``string_bytes_out`` rows of the result tables
+      the query handed back and the UTF-8 bytes their STRING columns' ids
+      stand for: the ``rows`` and ``string_bytes`` of the ``payload``
+      spans of kind ``result`` (one a ``ResultSinkOp``, on the engine
+      that runs it; ``rows_out`` is every fragment's output, a merge's
+      and a join's among them, not the answer's)
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -349,6 +361,8 @@ class QueryResourceUsage:
     join_rows_in: int = 0
     join_rows_out: int = 0
     dict_udf_strings: int = 0
+    answer_rows: int = 0
+    string_bytes_out: int = 0
     skipped_windows: int = 0
     device_peak_bytes: int = 0
     freshness_lag_ms: float = 0.0
@@ -375,7 +389,7 @@ class QueryResourceUsage:
             "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
             "join_rows_in", "join_rows_out", "dict_udf_strings",
-            "skipped_windows",
+            "answer_rows", "string_bytes_out", "skipped_windows",
         ):
             setattr(self, k, getattr(self, k) + int(d.get(k, 0)))
         for k in ("device_ms", "compile_ms", "stall_ms", "decode_ms"):
@@ -845,6 +859,9 @@ class QueryTrace:
                 u.join_rows_out += a.get("rows_out", 0)
             elif s.name == "dict_udf":
                 u.dict_udf_strings += s.attributes.get("strings", 0)
+            elif s.name == "payload" and s.attributes.get("kind") == "result":
+                u.answer_rows += s.attributes.get("rows", 0)
+                u.string_bytes_out += s.attributes.get("string_bytes", 0)
             elif (s.name in ("device.fetch", "device.wait")
                   and "bytes" in s.attributes):
                 # One batched get after the path's sync (the child) or

@@ -385,6 +385,28 @@ def _i64_from_words(hi, lo):
     return jax.lax.bitcast_convert_type(u, jnp.int64)
 
 
+def _order_words(v):
+    """An INT64 / int32 plane as u32 words whose unsigned lexicographic
+    order is the signed order of ``v``: two, or one (a maximum of
+    dictionary ids is one word)."""
+    if v.dtype == jnp.int64:
+        return _i64_words(v)
+    return [jax.lax.bitcast_convert_type(v, jnp.uint32) ^ jnp.uint32(_SIGN)]
+
+
+def _max_plane(words):
+    """A maximum's sorted order words as the one plane ``_front`` moves
+    (u32 or int64): an INT64 as itself, an int32 as its order word."""
+    return _i64_from_words(*words) if len(words) == 2 else words[0]
+
+
+def _max_value(plane):
+    """Inverse of ``_max_plane`` on the [g] slots."""
+    if plane.dtype == jnp.int64:
+        return plane
+    return jax.lax.bitcast_convert_type(plane ^ jnp.uint32(_SIGN), jnp.int32)
+
+
 def _batched_sort(key, words):
     """u32[N] ``words`` in ascending order of the unique int32 ``key``:
     one two-operand sort along the rows of [P, N], the key row repeated.
@@ -454,16 +476,19 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
         ``SORT_PAYLOAD_MAX_OPERANDS`` in all; a merge's (N = 2 g), and
         past that limit, through the row index, an inverse sort and one
         batched sort.
-      maxes: list of int64[N] planes to take the greatest of a group (a
-        minimum is the maximum of ``~v``). The first rides the sort as
-        its last key; each further maximum costs a sort of its own.
+      maxes: list of int64[N] or int32[N] planes to take the greatest
+        of a group (a minimum is the maximum of ``~v``; ``any`` of a
+        string is the maximum of its int32 dictionary ids: one word).
+        The first rides the sort as its last key; each further maximum
+        costs a sort of its own.
       max_groups: static slot count g.
       folded_flag: the caller guarantees ``keys[0]`` of a valid row is
         never 0xFFFFFFFF, so "not valid" needs no operand of its own.
 
     Returns (keys[g], valid[g], rows[g], sums[g], maxes[g], n_groups):
     slot k is the k-th group in key order; ``rows`` (int32) is its count
-    of valid rows; empty slots read zero sums and INT64_MIN maxes;
+    of valid rows; empty slots read zero sums and the least value of a
+    maximum's dtype;
     n_groups may exceed g (the caller's overflow).
     """
     g = max_groups
@@ -477,7 +502,7 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
     n_lead = len(lead)
     primary = maxes[0] if maxes else None
     ride = [s for s in sums if s is not primary]
-    operands = lead + (_i64_words(primary) if primary is not None else [])
+    operands = lead + (_order_words(primary) if primary is not None else [])
     n_keys = len(operands)
     way = sorted_fold_ride(n, g, n_keys, len(ride))
 
@@ -494,7 +519,7 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
     s_lead = list(out[:n_lead])
     s_valid = (s_lead[0] != u32(_U32_MAX)) if folded_flag else (s_lead[0] == 0)
     s_primary = (
-        _i64_from_words(*out[n_lead:n_keys]) if primary is not None else None
+        _max_plane(out[n_lead:n_keys]) if primary is not None else None
     )
     if way == "payload":
         rode = list(out[n_keys:])
@@ -537,10 +562,10 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
     rows = jnp.where(slot_valid, rows, 0)
     keys_g = packed[:n_lead] if folded_flag else packed[1:n_lead]
     at = n_lead
-    i64_min = jnp.iinfo(jnp.int64).min
     maxes_g = []
     if primary is not None:
-        maxes_g.append(jnp.where(slot_valid, packed[at], i64_min))
+        mx = _max_value(packed[at])
+        maxes_g.append(jnp.where(slot_valid, mx, jnp.iinfo(mx.dtype).min))
         at += 1
     sums_g = []
     for cs in packed[at:]:
@@ -552,8 +577,9 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
     # ``is_last`` marks the same rows; only the order inside a group
     # changes.
     for extra in maxes[1:]:
-        words = jax.lax.sort(lead + _i64_words(extra), dimension=0,
-                             is_stable=False, num_keys=n_lead + 2)[n_lead:]
-        (mx,) = _front(pos, [_i64_from_words(*words)], g)[1]
-        maxes_g.append(jnp.where(slot_valid, mx, i64_min))
+        words = _order_words(extra)
+        words = jax.lax.sort(lead + words, dimension=0, is_stable=False,
+                             num_keys=n_lead + len(words))[n_lead:]
+        mx = _max_value(_front(pos, [_max_plane(words)], g)[1][0])
+        maxes_g.append(jnp.where(slot_valid, mx, jnp.iinfo(mx.dtype).min))
     return list(keys_g), slot_valid, rows, sums_g, maxes_g, n_groups

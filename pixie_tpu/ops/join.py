@@ -8,8 +8,11 @@ reusing the group-by machinery (``pixie_tpu.ops.groupby``):
 1. Both sides' key planes are mapped to one exact dense key-id space by
    ``dense_group_ids`` over the concatenated rows (multi-key sort — no
    hash collisions, static shapes).
-2. The build side is sorted by key id; ``searchsorted`` gives each probe
-   row its contiguous match range [lo, hi).
+2. The build side is sorted by key id; a probe row's contiguous match
+   range [lo, lo + m) is read at its id from the build rows' counts by
+   id and their exclusive prefix (the ids are dense, so nothing is
+   searched; the windowed drivers below still ``searchsorted`` a build
+   sorted once against each probe window).
 3. Match ranges expand into a fixed-capacity output via exclusive prefix
    sums + a scatter/cummax ownership scan; rows beyond ``capacity`` are
    dropped and flagged (``overflow=True``) so the caller can re-run with
@@ -32,7 +35,7 @@ import numpy as np
 from .groupby import dense_group_ids, dense_group_ids_hash
 from .hashtable import _mix64, _mix64_j
 from . import routes
-from .scan import blocked_cumsum
+from .scan import blocked_cummax, blocked_cumsum
 
 
 def _exclusive_cumsum(x):
@@ -43,8 +46,20 @@ def _exclusive_cumsum(x):
     return jnp.concatenate([jnp.zeros(1, x.dtype), c[:-1]]), c[-1]
 
 
+#: Longest ownership scan taken as ONE ``lax.associative_scan``; above it
+#: the two-level blocked scan (``ops/scan.py``). The flat scan's compile
+#: seconds grow with its length: 93 s at 2^20 output slots against 1.1 s
+#: blocked (a described v5e, PR 39: px/perf_flamegraph's join of 0.64 M
+#: rows, whose first request did not end inside its timeout); the limit
+#: is the one size a cell runs it flat at (px/net_flow_graph's 2^17
+#: slots, where it compiles in seconds).
+_FLAT_CUMMAX_MAX = 1 << 17
+
+
 def _cummax(x):
     """Inclusive cumulative max (associative scan -> O(log n) on device)."""
+    if x.shape[0] > _FLAT_CUMMAX_MAX:
+        return blocked_cummax(x, force=True)
     return jax.lax.associative_scan(jnp.maximum, x)
 
 
@@ -131,11 +146,16 @@ def _expand_ranges(lo, hi, probe_valid, capacity: int, how: str, b: int):
 
     slot_of = jnp.where((e > 0) & (start < c), start, c)
     owner1 = _owners(slot_of, (e > 0).astype(jnp.int32), n, c)
-    probe_idx = jnp.maximum(owner1 - 1, 0)
 
+    # A slot past the pairs has no owner to read; it reads the row of its
+    # own number. Left to the scan it inherits the LAST owner, so every
+    # idle slot of the output (more than half of it at the estimate's
+    # head-room) gathered one address: 46 ms or 62 for the same 2^21-slot
+    # gather, by where the seed's last row fell (PERF.md section 6, PR 39).
     j = jnp.arange(c, dtype=jnp.int32)
-    t = j - start[probe_idx]
     pair_valid = (j < total_pairs) & (owner1 > 0)
+    probe_idx = jnp.where(pair_valid, owner1 - 1, j % n)
+    t = j - start[probe_idx]
     is_match = t < m[probe_idx]
     build_idx = jnp.clip(
         lo[probe_idx] + jnp.minimum(t, m[probe_idx] - 1), 0, b - 1
@@ -291,12 +311,19 @@ def device_join(
     kb = jnp.where(build_valid, ids[:b], b + n)
     kp = jnp.where(probe_valid, ids[b:], b + n + 1)
 
-    # 2. Sort build by key id; per-probe match ranges.
+    # 2. Sort build by key id; per-probe match ranges. The ids are dense
+    # (0 .. b+n+1), so a probe row's range in the sorted build needs no
+    # search: the build rows of each id are counted (a scatter of b
+    # rows), the rows before an id are the counts' exclusive prefix, and
+    # a probe row reads both at its id. (Two binary searches were 2 x
+    # log2(b) full-length gathers at addresses that follow the data:
+    # most of the kernel at 2^20 probe rows, and its time moved with the
+    # seed; PERF.md section 6, PR 39.)
     perm = jnp.argsort(kb, stable=True).astype(jnp.int32)  # invalid last
-    skb = kb[perm]
-    lo = jnp.searchsorted(skb, kp, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(skb, kp, side="right").astype(jnp.int32)
-    m = hi - lo  # matches per probe row (0 for invalid probe rows)
+    per_id = jnp.zeros(b + n + 2, dtype=jnp.int32).at[kb].add(1)
+    before, _ = _exclusive_cumsum(per_id)
+    lo = before[kp]
+    m = per_id[kp]  # matches per probe row (0 for invalid probe rows)
 
     # 3. Expansion: emitted rows per probe row.
     pad_unmatched = how in ("left", "outer")
@@ -306,11 +333,16 @@ def device_join(
 
     slot_of = jnp.where((e > 0) & (start < c), start, c)
     owner1 = _owners(slot_of, (e > 0).astype(jnp.int32), n, c)
-    probe_idx = jnp.maximum(owner1 - 1, 0)
 
+    # A slot past the pairs has no owner to read; it reads the row of its
+    # own number. Left to the scan it inherits the LAST owner, so every
+    # idle slot of the output (more than half of it at the estimate's
+    # head-room) gathered one address: 46 ms or 62 for the same 2^21-slot
+    # gather, by where the seed's last row fell (PERF.md section 6, PR 39).
     j = jnp.arange(c, dtype=jnp.int32)
-    t = j - start[probe_idx]
     pair_valid = (j < total_pairs) & (owner1 > 0)
+    probe_idx = jnp.where(pair_valid, owner1 - 1, j % n)
+    t = j - start[probe_idx]
     is_match = t < m[probe_idx]
     build_idx = perm[
         jnp.clip(lo[probe_idx] + jnp.minimum(t, m[probe_idx] - 1), 0, b - 1)

@@ -115,6 +115,24 @@ class HostBatch:
             p.nbytes for planes in self.cols.values() for p in planes
         ))
 
+    def string_nbytes(self) -> int:
+        """UTF-8 bytes of the values the STRING columns' ids stand for
+        (what ``to_pydict`` hands a client by reference); null and
+        out-of-range ids count nothing."""
+        total = 0
+        for name, dt in self.relation.items():
+            d = self.dicts.get(name)
+            if dt != DataType.STRING or d is None or not len(d):
+                continue
+            lengths = d.byte_lengths()
+            ids = self.cols[name][0]
+            if not len(ids):
+                continue
+            if ids.min() < 0 or ids.max() >= len(lengths):
+                ids = ids[(ids >= 0) & (ids < len(lengths))]
+            total += int(lengths.take(ids).sum(dtype=np.int64))
+        return total
+
     def to_pydict(self, decode_strings: bool = True) -> dict[str, np.ndarray]:
         out: dict[str, np.ndarray] = {}
         for name, dt in self.relation.items():

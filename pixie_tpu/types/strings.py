@@ -53,7 +53,7 @@ class StringDictionary:
     """Append-only string <-> int32 id mapping."""
 
     __slots__ = ("_str_to_id", "_strings", "_fp", "_fp_len", "_fp_digest",
-                 "_fp_lock", "_images", "_images_lock")
+                 "_fp_lock", "_images", "_images_lock", "_byte_lengths")
 
     def __init__(self, strings: Iterable[str] = ()):
         self._strings: list[str] = []
@@ -73,8 +73,25 @@ class StringDictionary:
         # (``image``), by the function's key.
         self._images: dict = {}
         self._images_lock = threading.Lock()
+        # UTF-8 byte length of each string (``byte_lengths``), extended
+        # as the dictionary grows.
+        self._byte_lengths = np.zeros(0, dtype=np.int32)
         for s in strings:
             self.get_or_add(s)
+
+    def byte_lengths(self) -> np.ndarray:
+        """int32[len]: the UTF-8 bytes of each string, by id. Amortized
+        O(new strings): the dictionary is append-only, so the lengths in
+        hand stay good and only the strings past them are measured."""
+        have = self._byte_lengths
+        n = len(self._strings)
+        if len(have) < n:
+            have = np.concatenate([have, np.fromiter(
+                (len(s.encode("utf-8", "surrogatepass"))
+                 for s in self._strings[len(have):n]),
+                np.int32, n - len(have))])
+            self._byte_lengths = have
+        return have[:n]
 
     def content_key(self) -> tuple:
         """Content-addressed identity: ``(len, digest)`` over the

@@ -15,6 +15,7 @@ import threading
 import numpy as np
 import pytest
 
+from conftest import routes_of
 from pixie_tpu.exec.engine import Engine, QueryError
 from pixie_tpu.exec.plan import (
     AggExpr, AggOp, ColumnRef as C, LimitOp, MapOp, MemorySourceOp, Plan,
@@ -318,7 +319,19 @@ def test_a_union_that_overflows_the_remembered_capacity_refolds_once():
     assert _dispatches(trace)[0].attributes["slots"] == 2048
 
 
-def test_a_string_carry_over_disagreeing_dictionaries_is_refused():
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_a_string_carry_over_disagreeing_dictionaries_is_refused(platform):
+    """``any`` of a string carries dictionary ids, which the merge does
+    not realign (only group keys are): from k = 2 agents whose
+    dictionaries differ in content it is refused loudly, on the CPU's
+    routes and on the TPU's (where the ``any`` rides the keyed sort as a
+    maximum of ids); over equal dictionaries it merges to the greatest
+    id's string a group."""
+    with routes_of(platform):
+        _a_string_carry_across_agents(platform)
+
+
+def _a_string_carry_across_agents(platform):
     p = Plan()
     src = p.add(MemorySourceOp(table="t"))
     agg = p.add(AggOp(("code",), (AggExpr("some", "any", (C("svc"),)),)),
@@ -339,10 +352,21 @@ def test_a_string_carry_over_disagreeing_dictionaries_is_refused():
     payloads = _payloads(
         split, [_agent(_rows(a, "equal")) for a in range(2)]
     )
+    assert {p.chain[-1].aggs[0].uda_name for p in payloads} == {"any"}
     out = kelvin.execute_plan(
         split.after_blocking, bridge_inputs={0: payloads}
     )["output"].to_pydict()
-    assert set(out["some"]) <= set(_names(0, "equal"))
+    names = _names(0, "equal")
+    want = {}
+    for a in range(2):
+        rows = _rows(a, "equal")
+        for code, svc in zip(rows["code"].tolist(), rows["svc"]):
+            want[code] = max(want.get(code, -1), names.index(svc))
+    assert dict(zip(out["code"].tolist(), out["some"])) == {
+        code: names[i] for code, i in want.items()}
+    fold = [s.attributes.get("fold") for s in kelvin.tracer.last().spans
+            if s.name == "device.dispatch"]
+    assert fold == [None]  # the merge's one program
 
 
 def test_an_overflowed_payload_is_refused():

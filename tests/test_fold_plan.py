@@ -183,6 +183,26 @@ KEYED_CASES = [
      (True, None, True, "sorted_int")),
     ("count_alone", (SVC, PATH), KEYED, (("n", "count", I64),), {},
      (True, (33, 65_537), False, "sorted_int")),
+    # px/perf_flamegraph's: ``any`` is a segment maximum, of a STRING's
+    # int32 dictionary ids or of an INT64, so it rides the sort as a
+    # maximum does, on the PEM (2^20 slots) and on the Kelvin; of a
+    # FLOAT64 or a BOOLEAN it keeps the id form.
+    ("any_of_a_string_beside_a_sum",
+     (("pod", D.STRING), ("stack_trace_id", D.INT64)), None,
+     (("stack_trace", "any", (D.STRING,)), ("count", "sum", I64)),
+     {"max_groups": 1 << 20}, (True, None, True, "sorted_int")),
+    ("any_of_a_string_on_the_kelvin",
+     (("pod", D.STRING), ("stack_trace_id", D.INT64)), None,
+     (("stack_trace", "any", (D.STRING,)), ("count", "sum", I64)),
+     {"max_groups": 1 << 20, "allow_dense": False},
+     (True, None, True, "sorted_int")),
+    ("any_of_an_int64_beside_a_sum", (SVC, PATH), KEYED,
+     (("first", "any", I64), ("seen", "any", T64), ("s", "sum", I64)), {},
+     (True, (33, 65_537), False, "sorted_int")),
+    ("any_of_a_float", (SVC, PATH), KEYED,
+     (("x", "any", F64), ("s", "sum", I64)), {}, (False, None, False, "xla")),
+    ("any_of_a_boolean", (SVC, PATH), KEYED, (("x", "any", BOOL),), {},
+     (False, None, False, "xla")),
     # An aggregate that needs a row's group id keeps the id form; under
     # the ids a ``quantiles`` sorts its rows while its centroids fit.
     ("with_a_quantiles", (SVC, PATH), KEYED,
@@ -214,6 +234,21 @@ def test_a_keyed_state(group_cols, domains, aggs, kw, tpu):
     assert (cpu.layout, cpu.slots, cpu.fold) == ("hashed", plan.slots, "xla")
     assert not cpu.payload_sort and cpu.pack_doms is None and not cpu.lead_id
     assert set(_routes(cpu)) == {"xla"}
+
+
+@pytest.mark.parametrize("aggs,words", [
+    (HTTP, 2),  # px/http_stats' one INT64 maximum
+    ((("n", "count", I64), ("m", "mean", I64)), 0),
+    ((("stack_trace", "any", (D.STRING,)), ("count", "sum", I64)), 1),
+    ((("a", "any", I64), ("b", "min", T64), ("c", "any", (D.STRING,))), 5),
+])
+def test_the_words_of_the_maxima_the_sort_carries(aggs, words):
+    """``max_words`` (the span's attribute): two an INT64 maximum,
+    minimum or ``any``, one an ``any`` of dictionary ids; 0 off the
+    sorted fold."""
+    assert _plan((SVC, PATH), KEYED, aggs, "tpu").max_words == words
+    assert _plan((SVC, PATH), KEYED, aggs, "cpu").max_words == 0
+    assert _plan((SVC,), [(33, 0, 1)], aggs, "tpu").max_words == 0
 
 
 def test_the_record_is_frozen_and_names_its_platform():

@@ -254,6 +254,56 @@ class TestJoinScale:
         np.testing.assert_array_equal(emitted[:n], cnt[pk[:n]])
 
 
+class TestJoinPastTheFlatScan:
+    """The single-shot kernel above ``ops.join._FLAT_CUMMAX_MAX`` output
+    slots, where its ownership scan is the blocked one
+    (px/perf_flamegraph's N:1 join of 0.64 M rows against 4,096): on
+    both platforms' routes, against numpy."""
+
+    @pytest.mark.parametrize("n", [1 << 17, (1 << 17) + 1, 300_001])
+    def test_the_ownership_scan_is_numpys_either_side_of_the_limit(self, n):
+        import jax.numpy as jnp
+
+        from pixie_tpu.ops.join import _FLAT_CUMMAX_MAX, _cummax
+
+        assert _FLAT_CUMMAX_MAX == 1 << 17
+        x = np.random.default_rng(n).integers(0, n, n).astype(np.int32)
+        x[x % 3 > 0] = 0  # mostly "no owner yet", as the markers are
+        assert np.array_equal(np.asarray(_cummax(jnp.asarray(x))),
+                              np.maximum.accumulate(x))
+
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    @pytest.mark.parametrize("platform", ["cpu", "tpu"])
+    def test_an_n_to_1_join_of_200k_rows_matches_numpy(self, platform, how):
+        import jax.numpy as jnp
+
+        from conftest import routes_of
+        from pixie_tpu.ops.join import device_join
+
+        rng = np.random.default_rng(5)
+        nb, n, cap = 512, 200_000, 1 << 18
+        bk = rng.permutation(nb + 64)[:nb].astype(np.int32)  # unique keys
+        bv = rng.random(nb) < 0.9
+        pk = rng.integers(-1, nb + 64, n).astype(np.int32)
+        pv = rng.random(n) < 0.95
+        with routes_of(platform):
+            out = device_join([jnp.asarray(bk)], jnp.asarray(bv),
+                              [jnp.asarray(pk)], jnp.asarray(pv), cap, how)
+        p_idx, p_take, b_idx, b_take, out_valid, overflow = (
+            np.asarray(a) for a in out)
+        assert not bool(overflow)
+        row_of = {int(k): i for i, k in enumerate(bk) if bv[i]}
+        hit = np.asarray([int(k) in row_of for k in pk]) & pv
+        want = np.flatnonzero(hit if how == "inner" else pv)
+        sel = np.flatnonzero(out_valid)
+        assert np.array_equal(p_idx[sel], want)  # probe order, each once
+        assert p_take[sel].all()
+        assert np.array_equal(b_take[sel], hit[want])
+        matched = sel[b_take[sel]]
+        assert np.array_equal(
+            b_idx[matched], [row_of[int(k)] for k in pk[p_idx[matched]]])
+
+
 class TestHostNMJoinMultiKey:
     def test_two_key_nm_join_above_threshold(self, monkeypatch):
         """Multi-plane keys route through the dense-id (np.unique) path of

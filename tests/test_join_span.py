@@ -277,6 +277,9 @@ def test_the_bulk_join_route_follows_routes_platform(platform, strategy):
 @pytest.mark.parametrize("estimate,planned,want", [
     (63_000, 1 << 22, 1 << 17),   # far under the plan's: worth a program
     (5_000, 1 << 13, 1 << 13),    # near the plan's: the plan's
+    (643_000, 1 << 22, 1 << 20),  # a quarter of the plan's: taken
+    (330_000, 1 << 20, 1 << 20),  # half of the plan's: the plan's
+    (1_200, 1 << 13, 1 << 11),    # the least plan that is given up
     (31_556, 1 << 12, 1 << 16),   # over the plan's: where a climb would end
     (10, 1 << 12, 1 << 12),
     (4, 8, 8),                    # the floor alone never grows a plan's

@@ -165,26 +165,41 @@ class TestExecuteBenchShapes:
         assert len(set(out["query_norm"])) == 3  # one shape per table name
 
     def test_perf_flamegraph_runs(self):
+        """Upstream's shape: ``any`` of the stack and the sum of count
+        by (pod, stack_trace_id), each stack's percent of its pod."""
         eng = Engine(window_rows=1 << 12)
         init_schemas(eng)
         rng = np.random.default_rng(8)
         n = 2000
         stacks = [f"main;f{i};g{i % 7}" for i in range(40)]
         sc = rng.integers(0, len(stacks), n)
+        pod = sc % 3  # an id names one (pod, stack)
         cnt = rng.integers(1, 20, n)
         eng.append_data("stack_traces.beta", {
             "time_": np.arange(n, dtype=np.int64),
             "upid": np.stack([np.full(n, 1, np.uint64),
-                              np.full(n, 9, np.uint64)], axis=1),
+                              pod.astype(np.uint64)], axis=1),
             "stack_trace_id": sc.astype(np.int64),
             "stack_trace": [stacks[i] for i in sc],
             "count": cnt.astype(np.int64),
-            "pod": ["ns/p0"] * n,
+            "pod": [f"ns/p{i}" for i in pod],
         })
         s = load_script("px/perf_flamegraph")
         out = eng.execute_query(s.pxl)["output"].to_pydict()
+        assert list(out) == ["pod", "stack_trace_id", "stack_trace",
+                             "count", "percent"]
         assert out["count"].sum() == cnt.sum()
         assert len(out["stack_trace"]) == len(np.unique(sc))
+        by_pod = np.bincount(pod, weights=cnt)
+        for p, sid, st, c, pct in zip(*out.values()):
+            assert (p, st) == (f"ns/p{sid % 3}", stacks[sid])
+            assert c == cnt[sc == sid].sum()
+            assert pct == pytest.approx(100.0 * c / by_pod[sid % 3], rel=1e-6)
+        # The widget reads columns the answer has.
+        import json
+
+        spec = json.loads(s.vis)["widgets"][0]["displaySpec"]
+        assert {spec["stacktraceColumn"], spec["countColumn"]} <= set(out)
 
 
 # -- execute EVERY script over synthetic tables -------------------------------

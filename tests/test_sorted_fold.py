@@ -135,6 +135,54 @@ def test_fold_equals_numpy(kind, shape):
     assert (maxes[0][empty] == I64.min).all()
 
 
+@pytest.mark.parametrize("n,g", [(4_096, 512), (1_024, 512)],
+                         ids=["window", "merge"])
+@pytest.mark.parametrize("order", ["id_first", "id_second", "id_alone"])
+def test_a_maximum_of_dictionary_ids_is_one_word(order, n, g):
+    """``any`` of a string: an int32 plane among ``maxes`` rides as ONE
+    order word (NULL_ID and INT32_MIN among the ids), as the sort's last
+    key when it is the first maximum and by a sort of its own otherwise,
+    beside a sum; at window length (n >= 4 g) and as a merge (n = 2 g).
+    Bit for bit numpy's; empty slots read INT32_MIN."""
+    rng = np.random.default_rng(n + len(order))
+    key = rng.integers(0, 120, n).astype(np.int32)
+    big = rng.integers(0, 3, n).astype(np.int64) * (1 << 40)
+    valid = rng.random(n) < 0.9
+    ids = rng.choice(np.array(
+        [NULL_ID, -(1 << 31), 0, 1, 65_536, (1 << 31) - 1], np.int32), n)
+    a = rng.integers(-(1 << 40), 1 << 40, n).astype(np.int64)
+    words = [w for p in (key, big)
+             for w in split_u32(_to_bits(jnp.asarray(p)))]
+    maxes = {"id_first": [ids, a], "id_second": [a, ids],
+             "id_alone": [ids]}[order]
+    sorts = _sort_eqns(jax.make_jaxpr(lambda w, v, s, m: sorted_group_fold(
+        w, v, [s], m, g))(words, jnp.asarray(valid), jnp.asarray(a),
+                          [jnp.asarray(m) for m in maxes]))
+    # The key sort: the flag, three key words, the first maximum's words.
+    assert sorts[0][1] == 4 + (2 if order == "id_second" else 1)
+    _k, valid_g, rows, sums, maxes_g, n_groups = jax.jit(
+        lambda w, v, s, m: sorted_group_fold(w, v, [s], m, g)
+    )(words, jnp.asarray(valid), jnp.asarray(a),
+      [jnp.asarray(m) for m in maxes])
+    live = np.flatnonzero(valid)
+    order_np = live[np.lexsort((big[live], key[live]))]
+    k2 = np.stack([key[order_np], big[order_np]], 1)
+    starts = np.flatnonzero(
+        np.r_[True, (k2[1:] != k2[:-1]).any(1)]) if len(k2) else []
+    assert int(n_groups) == len(starts)
+    valid_g = np.asarray(valid_g)
+    for got, plane in zip(maxes_g, maxes):
+        got = np.asarray(got)
+        assert got.dtype == plane.dtype
+        want = np.maximum.reduceat(plane[order_np], starts)
+        assert (got[: len(starts)] == want).all()
+        assert (got[~valid_g] == np.iinfo(plane.dtype).min).all()
+    assert (np.asarray(sums[0])[: len(starts)]
+            == np.add.reduceat(a[order_np], starts)).all()
+    assert (np.asarray(rows)[: len(starts)]
+            == np.diff(np.r_[starts, len(order_np)])).all()
+
+
 @pytest.mark.parametrize("rows_valid", ["some", "none"])
 def test_a_packed_code_needs_no_flag_operand(rows_valid):
     """``folded_flag``: a first key word that is never 0xFFFFFFFF on a
@@ -394,6 +442,11 @@ AGG_SETS = {
                               ("e", "max", "bytes")),
     "time_extremes": (("first", "min", "t"), ("last", "max", "t"),
                       ("n", "count", "t")),
+    # px/perf_flamegraph's kind: ``any`` of a STRING (a maximum of its
+    # dictionary ids, one word) and of an INT64, beside a sum.
+    "any_string_sum": (("st", "any", "path"), ("c", "sum", "bytes")),
+    "any_int64_sum_any_string": (("one", "any", "lat"), ("c", "sum", "bytes"),
+                                 ("st", "any", "svc"), ("n", "count", "lat")),
 }
 KEY_SETS = {"strings": ("svc", "path"), "string_int": ("svc", "shard")}
 N_ROWS, WINDOW = 6_000, 1_024
@@ -471,7 +524,8 @@ def _numpy_answer(table, keys, aggs):
         groups.setdefault(tuple(table[k][i].item() for k in keys), []).append(i)
     fn = {"count": lambda v: len(v), "sum": lambda v: int(v.astype(np.int64).sum()),
           "mean": lambda v: float(int(v.astype(np.int64).sum())) / len(v),
-          "max": lambda v: int(v.max()), "min": lambda v: int(v.min())}
+          "max": lambda v: int(v.max()), "min": lambda v: int(v.min()),
+          "any": lambda v: int(v.max())}
     return {k: tuple(fn[u](table[c][idx]) for _o, u, c in aggs)
             for k, idx in groups.items()}
 
@@ -569,6 +623,63 @@ def test_any_cut_into_windows_gives_one_answer(n_windows, scan, allow_dense):
     assert not overflow
     got = _by_key(cols, valid, ("svc", "path"), ("n", "m", "mx"))
     assert got == _numpy_answer(table, ("svc", "path"), aggs)
+
+
+@pytest.mark.parametrize("allow_dense", [True, False], ids=["pem", "kelvin"])
+@pytest.mark.parametrize("n_windows,scan", [(1, False), (3, True), (7, False)])
+@pytest.mark.parametrize("aggs", ["any_string_sum",
+                                  "any_int64_sum_any_string"])
+def test_an_any_beside_a_sum_gives_numpys_answer(aggs, n_windows, scan,
+                                                 allow_dense):
+    """px/perf_flamegraph's kind on (a string, an INT64): one window long
+    against the slots (6,000 rows into 1,024: folded alone, then
+    merged), three in one ``update_all`` scan and seven (short: folded
+    WITH the state), on the PEM's fragment and on the Kelvin's. Bit for
+    bit numpy's maximum of ids / of values and its sums."""
+    table = _table(seed=5)
+    keys = KEY_SETS["string_int"]
+    frag = _frag(keys, AGG_SETS[aggs], 1_024, allow_dense=allow_dense)
+    assert (frag.fold, frag.group) == ("sorted_int", "sorted")
+    assert frag.plan.max_words == {"any_string_sum": 1}.get(aggs, 3)
+    cols, valid, overflow = _fold(frag, table, n_windows, scan)
+    assert not overflow
+    outs = [o for o, _u, _c in AGG_SETS[aggs]]
+    assert _by_key(cols, valid, keys, outs) == _numpy_answer(
+        table, keys, AGG_SETS[aggs])
+
+
+@pytest.mark.parametrize("aggs", ["any_string_sum", "count_mean_max",
+                                  "two_extremes_two_sums"])
+def test_a_short_window_is_folded_with_the_state_in_one_set_of_sorts(aggs):
+    """n < 4 g (a 2^21-row window into 2^20 slots): ``update`` lifts the
+    rows to partial groups and folds g + n of them once, where the
+    window's fold and the merge would each sort. The answer is the one
+    ``merge_states(state, window_state(...))`` gives; at n >= 4 g the
+    program is that composition, as it was."""
+    table = _table(seed=6)
+    keys = KEY_SETS["string_int"]
+    g = 1_024
+    frag = _frag(keys, AGG_SETS[aggs], g)
+    (first, n1), (second, n2) = _windows(table, 3)[:2]
+    state = frag.update(frag.init_state(), first, (jnp.int32(0), jnp.int32(n1)))
+    rng = (jnp.int32(0), jnp.int32(n2))
+    one = jax.device_get(frag.finalize(frag.update(state, second, rng)))
+    two = jax.device_get(frag.finalize(jax.jit(
+        lambda st, c, r: frag.merge_states(st, frag.window_state(c, r))
+    )(state, second, rng)))
+    outs = [o for o, _u, _c in AGG_SETS[aggs]]
+    assert _by_key(one[0], one[1], keys, outs) == _by_key(
+        two[0], two[1], keys, outs)
+
+    def sorts(n):
+        cols = {c: (jnp.zeros(n, p[0].dtype),) for c, p in second.items()}
+        return _sort_eqns(jax.make_jaxpr(frag.update)(
+            frag.init_state(), cols, (jnp.int32(0), jnp.int32(n))))
+
+    short, long = sorts(2 * g), sorts(4 * g)
+    assert {s[0][0][-1] for s in short} == {3 * g}  # g slots + n rows, once
+    assert {s[0][0][-1] for s in long} == {4 * g, 2 * g}  # a fold, a merge
+    assert len(short) < len(long)
 
 
 def test_a_merge_takes_its_sides_in_any_order():
@@ -702,11 +813,16 @@ px.display(df)
     # count is no plane at all.
     ("m=('lat', px.mean), mx=('lat', px.max)", 256, None),
     ("n=('lat', px.count)", 256, None),
+    # px/perf_flamegraph's kind: an ``any`` is a maximum the sort carries
+    # (two words an INT64), the sum beside it rides.
+    ("a=('lat', px.any), c=('size', px.sum)", 256, "payload"),
+    ("a=('lat', px.any), c=('size', px.sum)", 2_048, "index"),
 ])
 def test_a_sorted_window_dispatch_says_how_its_sums_ride(aggs, slots, ride):
     """Span shape: a ``sorted_int`` window's ``device.dispatch`` carries
     ``ride`` beside ``fold`` / ``group`` / ``slots`` on the TPU's routes,
-    and no attribute where no sum plane rides."""
+    and no attribute where no sum plane rides; ``max_words`` says how
+    many words of maxima its sorts carry as keys, absent at 0."""
     from pixie_tpu.planner import CompilerState, compile_pxl
 
     data = _events(5, 4_000, [f"svc-{i}" for i in range(8)], 25)
@@ -724,6 +840,8 @@ def test_a_sorted_window_dispatch_says_how_its_sums_ride(aggs, slots, ride):
         (a["fold"], a["group"], a["slots"]) == ("sorted_int", "sorted", slots)
         for a in folds)
     assert {a.get("ride") for a in folds} == {ride}
+    words = 2 * ("px.max" in aggs) + 2 * ("px.any" in aggs)
+    assert {a.get("max_words") for a in folds} == {words or None}
 
 
 @pytest.fixture(params=[2, 3], ids=["two_pems", "three_pems"])

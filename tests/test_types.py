@@ -70,7 +70,30 @@ class TestStringDictionary:
         assert list(rb) == [1, 2]
 
 
+    def test_byte_lengths_follow_the_dictionary_as_it_grows(self):
+        d = StringDictionary(["a", "h\u00e9", ""])
+        assert d.byte_lengths().tolist() == [1, 3, 0]
+        d.get_or_add("\u65e5\u672c")
+        assert d.byte_lengths().tolist() == [1, 3, 0, 6]
+        assert d.byte_lengths().dtype == np.int32
+
+
 class TestHostBatch:
+    @pytest.mark.parametrize("ids,want", [
+        ([0, 1, 1, 2], 1 + 3 + 3 + 0),
+        ([0, -1, 1, 7, 1], 1 + 3 + 3),  # a null id and one out of range
+        ([], 0),
+    ])
+    def test_string_nbytes_counts_what_the_ids_stand_for(self, ids, want):
+        d = StringDictionary(["a", "h\u00e9", ""])
+        hb = HostBatch(
+            relation=Relation({"s": DataType.STRING, "n": DataType.INT64}),
+            length=len(ids), dicts={"s": d},
+            cols={"s": (np.asarray(ids, np.int32),),
+                  "n": (np.arange(len(ids), dtype=np.int64),)},
+        )
+        assert hb.string_nbytes() == want
+
     def test_infer_relation(self):
         hb = HostBatch.from_pydict(
             {

@@ -38,6 +38,14 @@ an operand of the programs (``exec/expr.py``), so the last line says
 operand's shape follows the served run's small dictionary, not the
 cell's 2^22-entry bucket.)
 
+The ``flame`` case (PR 39) is ``px/perf_flamegraph`` over
+``stack_flame_1chip``'s ``stack_traces.beta``: what a cold run of its cell
+compiles, lowered AND compiled at the cell's shapes (``FLAME``): the PEM's
+keyed fold of the two windows in range with ``any`` of a string beside a
+sum at 2^20 slots (``update_all``) and its joint-key sketch, the dense
+fold by pod, the Kelvin's two ``merge_finalize``, the single-shot device
+join of 4,096 + 2^20 rows into 2^21 output slots.
+
     JAX_PLATFORMS=cpu python tools/fold_hlo.py --out DIR
 
 writes one ``<case>.<program>.txt`` a program and prints one line a
@@ -81,6 +89,18 @@ FLOW_JOIN = (1 << 12, 1 << 16, 1 << 17)
 #: Kelvin merges in a 2^17 bucket.
 SQL = {"count+mean_by_query_norm_window": (1 << 17, 1 << 17, WINDOW)}
 SQL_ROWS = 1 << 16  # every one of the 290 shapes has rows at this size
+#: The ``flame`` case's shapes, as ``FLOW``'s:
+#: ``stack_flame_1chip.flame_recent`` settles on 2^20 slots for its 0.64 M
+#: (pod, stack_trace_id) groups, merged in a 2^20 bucket; the chain by pod
+#: is dense (its capacity is its dictionary's) and merges in 4,096.
+FLAME = {
+    "any+sum_by_pod_stack_trace_id": (1 << 20, 1 << 20, WINDOW),
+    "sum_by_pod": (None, 1 << 12, WINDOW),
+}
+#: (The join's output is sized at twice the probe's 0.64 M rows,
+#: ``join_capacity_safety``: 2^21 slots.)
+FLAME_JOIN = (1 << 12, 1 << 20, 1 << 21)
+FLAME_WINDOWS = 2  # the windows ``-5m`` holds
 
 
 def _sized(case):
@@ -88,6 +108,8 @@ def _sized(case):
     cell's one script at the cell's shapes; None otherwise."""
     if case.startswith("flow"):
         return FLOW
+    if case.startswith("flame"):
+        return FLAME
     return SQL if case.startswith("sql") else None
 
 
@@ -246,7 +268,8 @@ def _lower(case, captured, topo_device, out_dir, lines):
             for c, t in relation.items()
         }
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
-        bounds = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=chip)
+        n_windows = FLAME_WINDOWS if case == "flame" else 3
+        bounds = jax.ShapeDtypeStruct((n_windows,), jnp.int32, sharding=chip)
         valid = jax.ShapeDtypeStruct((window,), jnp.bool_, sharding=chip)
         programs = {
             "merge_states": lambda: jax.jit(frag.merge_states).lower(
@@ -255,7 +278,7 @@ def _lower(case, captured, topo_device, out_dir, lines):
             "update": lambda: frag.update.lower(
                 state, cols, (scalar, scalar)),
             "update_all": lambda: frag.update_all.lower(
-                state, (cols,) * 3, bounds, bounds),
+                state, (cols,) * n_windows, bounds, bounds),
             # (A program that takes operand tables lowers itself, with
             # the tables' shapes: ``fragment.OperandProgram``.)
             "group_sketch": lambda: (
@@ -264,7 +287,10 @@ def _lower(case, captured, topo_device, out_dir, lines):
                 else jax.jit(frag.group_sketch)
             ).lower(on(jax.eval_shape(frag.init_sketch)), cols, valid),
         }
-        if flow:  # what the cell runs: one window in range a chain
+        if case == "flame":  # two windows in range: one scan program
+            wanted = ("update_all",) + (
+                ("group_sketch",) if frag.group_sketch else ())
+        elif flow:  # what the cell runs: one window in range a chain
             wanted = ("update", "group_sketch" if who == "pem" else "finalize")
         elif digest:
             wanted = ("update", "update_all", "mesh_agg_step")
@@ -332,9 +358,10 @@ def _compile_s(lowered) -> float:
 
 
 def _lower_join(case, topo_device, out_dir, lines):
-    """The single-shot device join at the ``flow`` case's buckets: the
-    merged ``addrs`` build, the merged ``flows`` probe, int32 string
-    codes aligned to one dictionary."""
+    """The single-shot device join at the case's buckets (``flow``: the
+    merged ``addrs`` build, the merged ``flows`` probe; ``flame``: the
+    pods' totals, the merged stacks), int32 string codes aligned to one
+    dictionary."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -342,7 +369,7 @@ def _lower_join(case, topo_device, out_dir, lines):
     from pixie_tpu.ops.join import device_join
 
     chip = SingleDeviceSharding(topo_device)
-    nb, npr, cap = FLOW_JOIN
+    nb, npr, cap = FLAME_JOIN if case == "flame" else FLOW_JOIN
 
     def plane(n, dt):
         return jax.ShapeDtypeStruct((n,), dt, sharding=chip)
@@ -408,7 +435,7 @@ def main():
     ap.add_argument("--out", default=None, help="directory for the texts")
     ap.add_argument("--cases", default="dense,keyed,flow",
                     help="comma-separated, of dense, keyed, flow, digest, "
-                         "sql")
+                         "sql, flame")
     args = ap.parse_args()
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -416,7 +443,9 @@ def main():
     import jax
 
     import pixie_tpu  # noqa: F401
-    from benchmark.builders import served_conn, served_http_skew, served_sql
+    from benchmark.builders import (
+        served_conn, served_http_skew, served_sql, served_stacks,
+    )
     from pixie_tpu.ingest.replay import gen_http_events
 
     def config(name):
@@ -443,12 +472,20 @@ def main():
             list(served_sql.batches(data, SMALL_WINDOW, 0, SQL_ROWS)),
             table="mysql_events", scripts=("px/sql_stats",))
 
+    def flame():
+        data = served_stacks.make_data(
+            config("stack_flame_1chip"), 3_900_000_019, SQL_ROWS)
+        return _capture(
+            list(served_stacks.batches(data, SMALL_WINDOW, 0, SQL_ROWS)),
+            table="stack_traces.beta", scripts=("px/perf_flamegraph",))
+
     cases = {
         "dense": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "keyed": keyed, "flow": flow,
         "digest": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "sql": lambda: sql(3_400_000_019),
         "sql2": lambda: sql(3_400_000_023),
+        "flame": flame,
     }
     wanted = args.cases.split(",")
     if "sql" in wanted:
@@ -470,7 +507,7 @@ def main():
     for case, (seen, merges) in captured.items():
         _lower(case, seen, topo.devices[0], args.out, lines)
         _lower_merges(case, merges, topo.devices[0], args.out, lines)
-        if case == "flow":
+        if case in ("flow", "flame"):
             _lower_join(case, topo.devices[0], args.out, lines)
     if "sql" in captured:
         texts = {
