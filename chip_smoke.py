@@ -648,14 +648,14 @@ def phase_flow(seed: int, rows: int, meter: CompileMeter,
     ``rows`` rows through ``Engine``, the bundled px/net_flow_graph
     against the benchmark's plain numpy reference, every number exact.
     Two keyed group-bys ride the sort, their rows join on the address
-    strings of two dictionaries (past ``DEVICE_JOIN_MIN_ROWS`` the chip's
-    bulk route: the single-shot device kernel, by ``routes_platform``,
-    so a rehearsal takes it too), and the join's rows are aggregated
-    again; the second run compiles nothing."""
+    strings of two dictionaries (one address a pod, so the build side is
+    unique on one dictionary-coded key: a ``host_table`` lookup on the
+    host at any size, on the chip and in a rehearsal alike, and no join
+    program), and the join's rows are aggregated again; the second run
+    compiles nothing."""
     from benchmark.builders import served_conn
     from benchmark.reference import px_net_flow_graph as ref
     from pixie_tpu.exec.engine import Engine
-    from pixie_tpu.exec.joins import DEVICE_JOIN_MIN_ROWS
     from pixie_tpu.scripts import load_script
 
     with open(os.path.join(REPO, "benchmark", "configs",
@@ -692,9 +692,9 @@ def phase_flow(seed: int, rows: int, meter: CompileMeter,
         f"px/net_flow_graph: second run compiled {compiled['programs']} "
         "program(s)")
     (join,) = joins
-    bulk = join["build_rows"] + join["probe_rows"] >= DEVICE_JOIN_MIN_ROWS
-    assert join["where"] == ("device" if bulk else "host"), (
-        f"the join ran on the {join['where']}: {join}")
+    assert (join["strategy"], join["where"]) == ("host_table", "host"), (
+        f"the join of a unique dense build was no host lookup: {join}")
+    assert join["domain"] > join["build_rows"], f"no table: {join}"
     assert not on_tpu or _fold_routes(eng) == ["sorted_int"], (
         f"fold spans say {_fold_routes(eng)}, not sorted_int")
     # ``flows``' two sums at a 2^21-row window ride the key sort (PR 35);

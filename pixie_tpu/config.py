@@ -161,7 +161,8 @@ define_flag("ingest_sketches", True,
             "zone maps on key columns) on the append path; join routing "
             "and the planner's eager-aggregation sizing consult them.")
 define_flag("join_strategy", "auto",
-            "N:M join strategy: 'auto' (sketch-guided routing picks "
+            "N:M join strategy: 'auto' (a unique dense build side is a "
+            "host table lookup; else sketch-guided routing picks "
             "host-dict / host-hash / single-shot / windowed sorted-probe "
             "/ windowed radix by shape, backend and sketches), or force "
             "'host', 'single', 'sorted', 'radix' for testing/bench.")

@@ -6,7 +6,10 @@ join) and ``union_node.cc`` (k-way ordered merge). The TPU redesign
 routes by shape, backend and *ingest sketches* instead of always
 hash-joining (see docs/JOINS.md for the full strategy matrix):
 
-- small unique-key inner/left joins run a host dict join,
+- inner/left joins whose build side is unique on ONE dense key (a
+  dictionary's codes, or integers in a narrow range: the post-agg
+  common case) are a table lookup on the host at any size
+  (``host_table``); small unique multi-column keys run a host dict join,
 - large N:M joins run a device kernel — single-shot sort-based, or the
   windowed drivers (sorted-probe / radix-partitioned) that stage the
   build side once and stream probe windows through the prefetch
@@ -67,7 +70,8 @@ def _key_tuples(hb: HostBatch, on, remaps):
     return list(zip(*(list(k) for k in (keys + extra)))) if keys else []
 
 
-# Inputs smaller than this run the host dict join (when N:1 applies);
+# Inputs smaller than this run the host dict join (when N:1 applies and
+# the build side is no table: ``_join_host_table`` has no row limit);
 # larger inputs and right/outer/N:M joins go to the device kernel.
 DEVICE_JOIN_MIN_ROWS = 1 << 15
 
@@ -303,13 +307,15 @@ class JoinDecision:
     """Routing outcome, recorded on ``engine.last_join_decision`` so
     bench and tests can see which strategy served a query."""
 
-    strategy: str  # degenerate|host_dict|host_hash|single|sorted|radix
+    # degenerate|host_table|host_dict|host_hash|single|sorted|radix
+    strategy: str
     swap: bool = False  # probe the RIGHT side (inner only)
     capacity: int | None = None  # initial output capacity (per window)
     window_rows: int = 0  # probe rows per dispatch (windowed paths)
     zone_skip: bool = False
     retries: int = 0  # overflow retries actually paid
     skipped_windows: int = 0
+    domain: int = 0  # the lookup table's length (host_table)
     reason: str = ""
 
 
@@ -395,8 +401,9 @@ def traced_join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
     """``_join_dispatch`` inside a ``join`` span on the query's trace:
     the dictionaries' alignment, the strategy's build and probe and the
     output rows' assembly, with what the ``JoinDecision`` chose
-    (``strategy``, ``where``: ``host`` / ``device``), ``how`` and the
-    rows of both sides and of the output. ``QueryTrace._finalize_usage``
+    (``strategy``, ``where``: ``host`` / ``device``), ``how``, the
+    rows of both sides and of the output and, of a ``host_table``
+    lookup, the table's length (``domain``). ``QueryTrace._finalize_usage``
     counts them into ``usage.join_rows_in`` / ``join_rows_out``."""
     qstats = getattr(engine, "_query_stats", None)
     if qstats is None:
@@ -412,18 +419,22 @@ def traced_join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
             build_rows=build.length, probe_rows=probe.length,
             rows_out=out.length,
         )
+        if decision.strategy == "host_table":
+            sp.attributes["domain"] = decision.domain
     return out
 
 
 def _join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
                    engine=None, left_stats=None, right_stats=None,
                    cap_key=None, planned_capacity=None) -> HostBatch:
-    """Route a join: host N:1 dict, native host hash, or a device
-    kernel strategy chosen by ``choose_join_strategy``.
+    """Route a join: host N:1 table lookup or dict, native host hash,
+    or a device kernel strategy chosen by ``choose_join_strategy``.
 
-    Reference: ``equijoin_node.cc`` always hash-joins; here small unique-
-    key inner/left joins (the post-agg common case) stay on host, and
-    everything else routes by shape/backend/sketches. ``engine`` (when
+    Reference: ``equijoin_node.cc`` always hash-joins; here an inner/left
+    join against a build side that is unique on one dense key (the
+    post-agg common case) is a lookup on the host whatever its size, a
+    small unique multi-column key a dict join there, and everything else
+    routes by shape/backend/sketches. ``engine`` (when
     the call comes from a query) carries the pipeline depth and the
     per-query cancel handle into the windowed device drivers;
     ``left_stats``/``right_stats`` are ingest-sketch
@@ -431,6 +442,18 @@ def _join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
     """
     if len(op.left_on) != len(op.right_on):
         raise QueryError("join key arity mismatch")
+    from ..config import get_flag
+
+    if op.how in ("inner", "left") and get_flag("join_strategy") == "auto":
+        looked_up = _join_host_table(left, right, op)
+        if looked_up is not None:
+            out, domain = looked_up
+            if engine is not None:
+                engine.last_join_decision = JoinDecision(
+                    strategy="host_table", domain=domain,
+                    reason="unique dense build",
+                )
+            return out
     small = left.length + right.length < DEVICE_JOIN_MIN_ROWS
     if op.how in ("inner", "left") and small:
         try:
@@ -1112,6 +1135,103 @@ def _join_device(left: HostBatch, right: HostBatch, op: JoinOp,
     )
 
 
+def _unique_dense_build(kb: np.ndarray, dtype: DataType,
+                        strings: int | None, nulls_join: bool):
+    """``(lo, dom, row_of)`` where a build side's ONE key plane ``kb``
+    makes it a table, else None: the one statement of the rule the host
+    lookup (``_join_host_table``) and the fused lookup
+    (``_host_table_build``) share.
+
+    *Dense*: STRING codes of a dictionary of ``strings`` entries (domain
+    ``strings`` + 1), or INT64 / TIME64NS whose range ``hi - lo + 1`` is
+    within ``int_dense_domain_limit``; any other type, or a wider range,
+    is not. *Unique*, exactly: ``row_of`` (int32, ``dom`` long) holds at
+    code ``k - lo`` the build row whose key is ``k`` and -1 where none
+    is; two rows on one code (N:M) leave fewer codes set than rows, and
+    that is not a table. O(rows + dom), one vectorized pass.
+
+    ``nulls_join``: a null string id (-1) is a key like any other (as
+    the dict, hash and device routes compare ids: ``lo`` = -1), or a row
+    that can match nothing and is left out of the table (the fused
+    lookup's: ``lo`` = 0)."""
+    from ..config import get_flag
+
+    rows = None  # every row is keyed
+    if dtype == DataType.STRING:
+        lo, dom = (-1 if nulls_join else 0), strings + 1
+        if not nulls_join:
+            rows = np.nonzero(kb >= 0)[0]
+            kb = kb[rows]
+    elif dtype in (DataType.INT64, DataType.TIME64NS) and len(kb):
+        lo = int(kb.min())
+        dom = int(kb.max()) - lo + 1
+        if dom > get_flag("int_dense_domain_limit"):
+            return None
+    else:
+        return None
+    if len(kb) > dom:
+        return None  # more rows than codes: some code is two rows'
+    row_of = np.full(dom, -1, dtype=np.int32)
+    row_of[kb - lo] = np.arange(len(kb)) if rows is None else rows
+    if np.count_nonzero(row_of >= 0) != len(kb):
+        return None
+    return lo, dom, row_of
+
+
+def _join_host_table(left: HostBatch, right: HostBatch, op: JoinOp):
+    """Inner / left N:1 equijoin as a table lookup on the host, at any
+    size: ``(rows, the table's length)``, or None where the build side
+    is not a table (``_unique_dense_build``: two key columns or planes,
+    a type with no dense codes, a range too wide, a duplicate key) or a
+    side is empty, and the caller's routing goes on as it was.
+
+    The probe is one gather at the PROBE's own length from a table that
+    fits in cache; rows come in probe order. Both sides are on the host
+    when a join starts (merged aggregates, materialized), so a device
+    kernel would round-trip every byte: docs/JOINS.md."""
+    if len(op.left_on) != 1 or not left.length or not right.length:
+        return None
+    lc, rc = op.left_on[0], op.right_on[0]
+    dtype = left.relation.col_type(lc)
+    if (
+        right.relation.col_type(rc) != dtype
+        or len(left.cols[lc]) != 1 or len(right.cols[rc]) != 1
+    ):
+        return None
+    strings = None
+    if dtype == DataType.STRING:
+        if lc not in left.dicts or rc not in right.dicts:
+            return None
+        strings = len(right.dicts[rc])
+    # A remap is one to one, so a build side unique in its own codes is
+    # unique in a union's: asked before one is built.
+    built = _unique_dense_build(right.cols[rc][0], dtype, strings, True)
+    if built is None:
+        return None
+    _, r_remap, key_dicts = _align_join_dicts(left, right, op)
+    if r_remap:
+        # The build's keys in the union's codes, which are the probe's
+        # own (a union keeps the left side's ids).
+        built = _unique_dense_build(
+            _join_key_planes(right, op.right_on, r_remap)[0], dtype,
+            len(key_dicts[lc]), True,
+        )
+    lo, dom, row_of = built
+    hi = lo + dom - 1
+    kp = left.cols[lc][0]
+    if int(kp.min()) >= lo and int(kp.max()) <= hi:
+        match = row_of.take(kp - lo)
+    else:  # probe keys past the build's range match nothing
+        match = np.full(left.length, -1, dtype=np.int32)
+        inside = np.nonzero((kp >= lo) & (kp <= hi))[0]
+        match[inside] = row_of.take(kp[inside] - lo)
+    l_idx = None  # every probe row, in order: its planes pass through
+    if op.how == "inner" and int(match.min()) < 0:
+        l_idx = np.nonzero(match >= 0)[0]
+        match = match[l_idx]
+    return _assemble_join_host(left, right, op, l_idx, match), dom
+
+
 def _join_host(left: HostBatch, right: HostBatch, op: JoinOp) -> HostBatch:
     """N:1 equijoin on host (post-agg inputs are small).
 
@@ -1285,8 +1405,9 @@ def _packed_key_ids(left, left_on, l_remap, right, right_on, r_remap):
 
 
 def _assemble_join_host(left, right, op, l_idx, r_idx) -> HostBatch:
-    """Row assembly for the host N:1 / N:M paths (r_idx=-1 -> null)."""
-    with _join_child("join.assemble", rows_out=len(l_idx)):
+    """Row assembly for the host N:1 / N:M paths (r_idx=-1 -> null;
+    l_idx=None -> every left row in order, its planes as they are)."""
+    with _join_child("join.assemble", rows_out=len(r_idx)):
         return _gather_join_host(left, right, op, l_idx, r_idx)
 
 
@@ -1302,7 +1423,10 @@ def _gather_join_host(left, right, op, l_idx, r_idx) -> HostBatch:
     names = iter(out_rel.column_names)
     for c in left.relation.column_names:
         n = next(names)
-        out_cols[n] = tuple(p[l_idx] for p in left.cols[c])
+        out_cols[n] = (
+            left.cols[c] if l_idx is None
+            else tuple(p[l_idx] for p in left.cols[c])
+        )
         if c in left.dicts:
             out_dicts[n] = left.dicts[c]
     for c in right.relation.column_names:
@@ -1313,7 +1437,7 @@ def _gather_join_host(left, right, op, l_idx, r_idx) -> HostBatch:
         nullv = NULL_ID if right.relation.col_type(c) == DataType.STRING else 0
         for p in right.cols[c]:
             if len(p) == 0:  # empty build side: all-null fill
-                taken = np.full(len(l_idx), nullv, dtype=p.dtype)
+                taken = np.full(len(r_idx), nullv, dtype=p.dtype)
             else:
                 taken = p[np.clip(r_idx, 0, None)]
                 if op.how == "left":
@@ -1323,7 +1447,7 @@ def _gather_join_host(left, right, op, l_idx, r_idx) -> HostBatch:
         if c in right.dicts:
             out_dicts[n] = right.dicts[c]
     return HostBatch(
-        relation=out_rel, cols=out_cols, length=len(l_idx), dicts=out_dicts
+        relation=out_rel, cols=out_cols, length=len(r_idx), dicts=out_dicts
     )
 
 
@@ -1547,8 +1671,6 @@ def _dense_agg_build(engine, right_stream, op, l_dt, left_dicts, lc, rc):
 def _host_table_build(right_hb, op, l_dt, left_dicts, lc, rc):
     """Build dense lookup tables from a materialized unique-key host
     batch (the post-agg N:1 case arriving as rows)."""
-    from ..config import get_flag
-
     if not right_hb.relation.has_column(rc):
         return None
     if right_hb.relation.col_type(rc) != l_dt:
@@ -1556,6 +1678,7 @@ def _host_table_build(right_hb, op, l_dt, left_dicts, lc, rc):
     if right_hb.length == 0:
         return None
     kb = np.asarray(right_hb.cols[rc][0])
+    strings = None
     if l_dt == DataType.STRING:
         ld = left_dicts.get(lc)
         rd = right_hb.dicts.get(rc)
@@ -1569,22 +1692,15 @@ def _host_table_build(right_hb, op, l_dt, left_dicts, lc, rc):
                 dtype=np.int64, count=len(rd),
             )
             kb = np.where(kb >= 0, remap[np.clip(kb, 0, None)], -1)
-        lo, dom = 0, len(ld) + 1
-        in_dom = kb >= 0
-    elif l_dt in (DataType.INT64, DataType.TIME64NS):
-        lo, hi = int(kb.min()), int(kb.max())
-        dom = hi - lo + 1
-        if dom > get_flag("int_dense_domain_limit"):
-            return None
-        in_dom = np.ones(len(kb), dtype=bool)
-    else:
+        strings = len(ld)
+    # Dense and unique, or N:M / too wide and not this path.
+    built = _unique_dense_build(kb, l_dt, strings, nulls_join=False)
+    if built is None:
         return None
-    idx = np.where(in_dom, kb - lo, 0)
-    found = np.zeros(dom, dtype=bool)
-    # Uniqueness: a duplicate build key means N:M — not this path.
-    found[idx[in_dom]] = True
-    if int(found.sum()) != int(in_dom.sum()):
-        return None
+    lo, dom, row_of = built
+    found = row_of >= 0
+    slots = np.nonzero(found)[0]
+    rows = row_of[slots]
     from ..types.dtypes import device_dtypes
 
     value_tables = {}
@@ -1600,7 +1716,7 @@ def _host_table_build(right_hb, op, l_dt, left_dicts, lc, rc):
             p = np.asarray(p)
             t = np.zeros(dom, dtype=ddt)
             if len(p):
-                t[idx[in_dom]] = p[in_dom]
+                t[slots] = p[rows]
             planes.append(t)
         value_tables[c] = tuple(planes)
     return lo, dom, found, value_tables, right_hb.relation

@@ -52,8 +52,9 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
 - ``join``                one per ``JoinOp``, child of the root: the
   dictionaries' alignment, build and probe, the output rows' assembly
   (attributes ``strategy``: the ``JoinDecision``'s, ``where``: ``host``
-  / ``device``, ``how``, ``build_rows``, ``probe_rows``, ``rows_out``;
-  a fused lookup join's span covers its build alone)
+  / ``device``, ``how``, ``build_rows``, ``probe_rows``, ``rows_out``,
+  and ``domain``, the table's length, under ``host_table``; a fused
+  lookup join's span covers its build alone)
 - ``device.wait``         the host asks for a result until the bytes are
   on the host, at the sync the path has anyway (where the path's one
   batched ``jax.device_get`` is also its sync the span carries

@@ -215,14 +215,23 @@ def test_head_ms_is_the_brokers_stages_the_hop_and_the_pems_head(served):
 
 
 @pytest.fixture(scope="module")
-def rehearsed_names():
-    """{cell: {tracer: [traces]}} of two refreshes of each script the
-    suite rehearses, on the benchmark's own builders at a small size."""
+def rehearsed():
+    """Two refreshes of each script the suite rehearses, on the
+    benchmark's own builders at a small size: ``spans`` {cell: {tracer:
+    [traces]}} and ``join_programs`` {cell: the join programs the
+    refreshes registered}."""
     from benchmark import harness
+    from pixie_tpu.exec.programs import default_program_registry
 
-    out = {}
+    def join_programs():
+        return {r["program_id"]
+                for r in default_program_registry().programz()["programs"]
+                if r["kind"].startswith("join")}
+
+    out = {"spans": {}, "join_programs": {}}
     for cell in ("http_pem_1chip.dash_recent", "conn_flow_1chip.flow_recent",
-                 "sql_stats_1chip.sql_recent"):
+                 "sql_stats_1chip.sql_recent",
+                 "stack_flame_1chip.flame_recent"):
         spec = harness.load_cell(cell)
         cfg, traffic = spec["config"], spec["traffic"]
         builder = harness.module("builders", cfg["builder"])
@@ -233,14 +242,21 @@ def rehearsed_names():
                 stack.ingest(builder.make_data(cfg, 3_700_000_019, 1 << 15))
                 log = harness.SpanLog(stack.tracers)
                 _lo, now_ns = harness.range_lo_ns(cfg, traffic)
+                before = join_programs()
                 for _ in range(2):
                     driver.refresh(stack, harness.requests_of(spec), now_ns,
                                    120, harness.mark)
                 time.sleep(0.1)
-                out[cell] = log.cut()
+                out["spans"][cell] = log.cut()
+                out["join_programs"][cell] = join_programs() - before
             finally:
                 stack.close()
     return out
+
+
+@pytest.fixture(scope="module")
+def rehearsed_names(rehearsed):
+    return rehearsed["spans"]
 
 
 def test_served_scripts_stamp_no_name_outside_span_names(rehearsed_names):
@@ -288,6 +304,34 @@ def test_the_joins_pieces_are_children_of_its_span(rehearsed_names):
         assert a.end_ns <= b.start_ns
     assert not any(w.start_ns < join.end_ns and join.start_ns < w.end_ns
                    for w in walks)
+
+
+@pytest.mark.parametrize("cell,build_is", [
+    ("conn_flow_1chip.flow_recent", "one address a pod"),
+    ("stack_flame_1chip.flame_recent", "one total a pod"),
+])
+def test_the_served_joins_are_lookups_on_the_host(rehearsed, cell, build_is):
+    """``px/net_flow_graph``'s and ``px/perf_flamegraph``'s join on the
+    Kelvin: a merged aggregate probed against a smaller one that is
+    unique on one dictionary-coded key (ISSUE 40). The span says so, its
+    pieces stay its children, and no join program is registered."""
+    kelvins = [t for t in rehearsed["spans"][cell]["kelvin"]
+               if _named(t, "join")]
+    assert len(kelvins) == 2
+    for kelvin in kelvins:
+        join = _one(kelvin, "join")
+        a = join.attributes
+        assert (a["strategy"], a["where"], a["how"]) == (
+            "host_table", "host", "inner"), build_is
+        # A code of the key's dictionary a slot, and the null's.
+        assert a["domain"] > a["build_rows"] > 0
+        assert 0 < a["rows_out"] <= a["probe_rows"]
+        align = _one(kelvin, "join.align")
+        assemble = _one(kelvin, "join.assemble")
+        assert align.parent_id == assemble.parent_id == join.span_id
+        assert align.end_ns <= assemble.start_ns <= assemble.end_ns
+        assert assemble.attributes["rows_out"] == a["rows_out"]
+    assert rehearsed["join_programs"][cell] == set()
 
 
 def test_one_stamp_a_bus_message_serves_the_lag_and_the_span():

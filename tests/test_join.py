@@ -167,9 +167,11 @@ class TestDeviceJoinKernel:
 
 class TestJoinRouting:
     def test_large_inputs_route_off_n1_host_path(self, monkeypatch):
-        """Above the threshold the N:1 host path is skipped: the device
-        kernel on TPU, the vectorized numpy N:M join on CPU (XLA CPU
-        sorts make the device kernel a regression there)."""
+        """Above the threshold the N:1 host dict path is skipped: the
+        device kernel on TPU, the vectorized numpy N:M join on CPU (XLA
+        CPU sorts make the device kernel a regression there). The build
+        side has a duplicate key: a unique one is the ``host_table``
+        lookup at any size (``tests/test_join_host_table.py``)."""
         import jax
 
         import pixie_tpu.exec.joins as eng_mod
@@ -187,7 +189,7 @@ class TestJoinRouting:
             return orig(left, right, op, *a, **kw)
 
         monkeypatch.setattr(eng_mod, expected, spy)
-        _check([1, 2, 3], [2, 3, 4], "inner")
+        _check([1, 2, 3], [2, 3, 3, 4], "inner")
         assert calls == ["inner"]
 
     def test_pxl_right_and_outer_merge(self):

@@ -201,18 +201,23 @@ px.display(g, 'j')
             joins_mod.DEVICE_JOIN_MIN_ROWS = old
         assert second == first  # no re-climb on the repeat run
 
-    def test_host_dict_agrees_on_unique_build(self):
-        """The small-N:1 host-dict path (auto route) agrees with every
-        forced bulk strategy."""
+    @pytest.mark.parametrize("strategy,route", [
+        ("auto", "host_table"), ("host", "host_dict"),
+    ])
+    def test_host_n1_routes_agree_on_unique_build(self, strategy, route):
+        """The N:1 host paths agree with every forced bulk strategy: the
+        table lookup (the auto route of a unique dense build) and the
+        small-input dict join, which a forced strategy still reaches
+        under the row limit."""
         rng = np.random.default_rng(29)
         lk = rng.integers(0, 40, 200)
         rk = rng.permutation(40)[:30]  # unique build keys
         for how in ("inner", "left"):
             ref = _ref_join(lk.tolist(), rk.tolist(), how)
-            got, e = _run_strategy(lk, rk, how, "auto",
+            got, e = _run_strategy(lk, rk, how, strategy,
                                    min_rows=1 << 15)
             assert got == ref
-            assert e.last_join_decision.strategy == "host_dict"
+            assert e.last_join_decision.strategy == route
             for s in STRATEGIES:
                 got_s, _e = _run_strategy(lk, rk, how, s)
                 assert got_s == ref, (how, s)

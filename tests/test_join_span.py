@@ -196,9 +196,15 @@ def test_usage_counters_reach_the_brokers_trace(cluster):
     assert total.join_rows_out == 2 * mine.join_rows_out
 
 
-def test_a_unique_small_build_stays_on_the_host_and_says_so():
+@pytest.mark.parametrize("forced,strategy", [
+    ("auto", "host_table"), ("host", "host_dict"),
+])
+def test_a_unique_small_build_stays_on_the_host_and_says_so(forced, strategy):
     """The bundled script over one address a pod: the N:1 host lookup,
-    on a bare engine's trace."""
+    on a bare engine's trace. By the table where the strategy is the
+    engine's to choose (one dictionary-coded key); by the dict join
+    where one is forced, which small inputs still reach."""
+    from pixie_tpu.config import override_flag
     from pixie_tpu.ingest.schemas import init_schemas
     from pixie_tpu.scripts import load_script
 
@@ -212,7 +218,8 @@ def test_a_unique_small_build_stays_on_the_host_and_says_so():
     eng = Engine(window_rows=WINDOW)
     init_schemas(eng)
     eng.append_data("conn_stats", _table(d, np.ones(ROWS, bool)))
-    out = eng.execute_query(load_script("px/net_flow_graph").pxl)
+    with override_flag("join_strategy", forced):
+        out = eng.execute_query(load_script("px/net_flow_graph").pxl)
     got = reference.rows(out["output"].to_pydict())
     assert reference.numbers(got, reference.answer(d, None)) == {
         "net_flow_graph.keys_differ": 0,
@@ -220,9 +227,12 @@ def test_a_unique_small_build_stays_on_the_host_and_says_so():
         "net_flow_graph.bytes_recv_differ": 0,
     }
     (span,) = _joins(eng.tracer.last())
-    assert span.attributes["strategy"] == "host_dict"
+    assert span.attributes["strategy"] == strategy
     assert span.attributes["where"] == "host"
     assert span.attributes["build_rows"] == PODS
+    # The union of the two address dictionaries and the null's slot.
+    assert span.attributes.get("domain") == (
+        {"host_table": OUTSIDE + PODS + 1, "host_dict": None}[strategy])
     assert eng.tracer.last().usage.join_rows_out == span.attributes["rows_out"]
 
 

@@ -41,9 +41,9 @@ sys.path.insert(0, ROOT)
 
 _SHOWN = ("program", "windows", "slots", "prepared", "ops", "from", "to",
           "fold", "ride", "max_words", "rows", "string_bytes", "estimate",
-          "strategy", "where", "build_rows", "probe_rows", "rows_out",
-          "leaves", "bytes", "cached", "memo", "skipped", "topic",
-          "listeners")
+          "strategy", "where", "domain", "build_rows", "probe_rows",
+          "rows_out", "leaves", "bytes", "cached", "memo", "skipped",
+          "topic", "listeners")
 #: Under this a stretch no span names is not worth a row.
 _UNNAMED_MIN_MS = 0.05
 
