@@ -1,6 +1,6 @@
 """Chip smoke: the query path, once, on the TPU it was written for.
 
-    python chip_smoke.py            # one chip: kernel, engine, served, skew, flow
+    python chip_smoke.py            # one chip: kernel, engine, served, skew, flow, edges
     python chip_smoke.py --chips 4  # four chips: kernel + DistributedEngine only
 
 One process, no child that needs the chip. A seeded ``http_events``
@@ -36,6 +36,7 @@ WINDOW = 1 << 21  # one window size: one update + one finalize per query
 REHEARSAL_MAX_ROWS = 1 << 20  # what a run without a TPU may be asked for
 SKEW_ROWS = 1 << 23  # the non-dense phase: four windows at ten columns
 FLOW_ROWS = 1 << 22  # the join phase: two windows at conn_stats' fifteen
+EDGES_ROWS = 1 << 22  # the keyed quantiles phase: two windows at ten columns
 
 SERVICES = [f"svc-{i}" for i in range(32)]
 PATHS = [f"/api/v1/ep{i}" for i in range(8)]
@@ -703,6 +704,65 @@ def phase_flow(seed: int, rows: int, meter: CompileMeter,
         f"ride spans say {_fold_routes(eng, 'ride')}, no payload")
 
 
+def phase_edges(seed: int, rows: int, meter: CompileMeter,
+                on_tpu: bool) -> None:
+    """The keyed quantiles: configuration ``http_edges_1chip``'s
+    ``http_events`` (a pod's twenty clients in ``remote_addr``) at
+    ``rows`` rows through ``Engine``, the cell's own script (the cluster
+    view's service graph: three plucked quantiles, a BOOLEAN mean, a
+    count and an INT64 sum by (remote_addr, pod, service)) over the whole
+    table against the benchmark's plain numpy reference, every number
+    inside its limit. On the chip's routes the integer aggregates ride
+    the keyed sort and the digests are built beside it
+    (``mixed:sorted_int=3,keyed_digest=3``), binned at nothing; the
+    second run compiles nothing."""
+    from benchmark.builders import served_http_edges
+    from benchmark.reference import px_service_graph as ref
+    from pixie_tpu.exec.engine import Engine
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "http_edges_1chip.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(REPO, "benchmark", "traffic", "graph_recent",
+                           "service_graph.pxl")) as f:
+        pxl = f.read().replace(", start_time='-5m'", "")
+    t0 = time.perf_counter()
+    data = served_http_edges.make_data(cfg, seed, rows)
+    eng = Engine(window_rows=WINDOW)
+    for hb in served_http_edges.batches(data, WINDOW):
+        eng.append_data("http_events", hb)
+    res = _resident(eng.tables["http_events"])
+    emit(phase="edges", step="ingest", rows=rows,
+         secs=time.perf_counter() - t0, resident=res)
+    assert res["rows"] == rows, f"resident rows {res['rows']} != {rows}"
+    want = ref.answer(data, None)
+    for run in ("first", "warm"):
+        mark = meter.mark()
+        t0 = time.perf_counter()
+        # Every edge: the default cut is 10,000 rows a table.
+        got = eng.execute_query(pxl, max_output_rows=1 << 17)
+        secs = time.perf_counter() - t0
+        numbers = ref.numbers(ref.rows(got["output"].to_pydict()), want)
+        compiled = meter.since(mark)
+        emit(phase="edges", query="px/service_graph", run=run, rows=rows,
+             secs=secs, edges=len(want["key"]), fold=_fold_routes(eng),
+             group=_fold_groups(eng), ride=_fold_routes(eng, "ride"),
+             digests=[_fold_routes(eng, a) for a in
+                      ("digests", "digest_slots", "digest_bins")],
+             compile=compiled, numbers=numbers)
+        over = sorted(k for k, v in numbers.items() if v > ref.LIMITS[k])
+        assert not over, f"px/service_graph ({run}): over its limit: {over}"
+    assert compiled["programs"] == 0, (
+        f"px/service_graph: second run compiled {compiled['programs']} "
+        "program(s)")
+    want_fold = "mixed:sorted_int=3,keyed_digest=3"
+    assert not on_tpu or _fold_routes(eng) == [want_fold], (
+        f"fold spans say {_fold_routes(eng)}, not {want_fold}")
+    assert _fold_routes(eng, "digests") == [3], "no three digests"
+    assert _fold_routes(eng, "digest_bins") == [1 << 32], (
+        f"a window's rows were binned: {_fold_routes(eng, 'digest_bins')}")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=16 << 20,
@@ -758,6 +818,8 @@ def main(argv=None) -> int:
                            on_tpu)
                 phase_flow(args.seed, min(args.rows, FLOW_ROWS), meter,
                            on_tpu)
+                phase_edges(args.seed, min(args.rows, EDGES_ROWS), meter,
+                            on_tpu)
         emit(total_compile=meter.since())
         ok = on_tpu
         if not ok:

@@ -17,6 +17,7 @@ import numpy as np
 from ..types.batch import HostBatch, bucket_capacity
 from ..types.dtypes import DataType, device_dtypes
 from ..types.strings import NULL_ID, StringDictionary
+from .fold_plan import is_digest
 from .fragment import ColumnMeta, compile_fragment_cached as compile_fragment
 from .joins import learned_capacity
 from .plan import AggOp
@@ -217,9 +218,19 @@ def bridge_payload(engine, res):
             climbed = True
             retry = _rebucket(stats, frag.slots, frag.slots * 2, "pem")
             frag = None
-        with _root_span(engine, "payload", kind="agg_state"):
+        with _root_span(engine, "payload", kind="agg_state") as span:
             if climbed:
                 _remember_climb(engine, res.chain, res.source, "pem", frag)
+            if span is not None and frag.plan.digests:
+                # The [slots, K] planes of the ``quantiles`` aggregates
+                # among what ships (``usage.digest_bytes``).
+                span.attributes["digest_bytes"] = sum(
+                    leaf.nbytes
+                    for op in res.chain if isinstance(op, AggOp)
+                    for ae in op.aggs if is_digest(ae.uda_name)
+                    for leaf in jax.tree_util.tree_leaves(
+                        state["carries"][ae.out_name])
+                )
             return _count_wire(engine, AggStatePayload(
                 chain=tuple(res.chain),
                 input_relation=res.relation,

@@ -1139,6 +1139,10 @@ class Engine:
                 frag.fold, frag.group, frag.slots
             )
             stats.remap_entries = frag.remap_entries
+            stats.digests, stats.digest_slots, stats.digest_bins = (
+                frag.plan.digests, frag.plan.digest_slots,
+                frag.plan.digest_bins,
+            )
         # Scan-folding trades W dispatches for one; on the CPU backend
         # dispatches are cheap and the jnp.stack of window planes is a
         # pure memory-bandwidth loss.
@@ -1256,10 +1260,10 @@ class Engine:
                 # replaces the XLA path's per-window compress+merge
                 # (histogram addition is exact — strictly less work,
                 # no added error).
-                from ..ops.tdigest import _hist_bins
+                from ..ops.routes import digest_hist_bins
 
-                b = _hist_bins(g)
-                if g * b > (1 << 22):  # host-table budget: XLA instead
+                b = digest_hist_bins(g)
+                if not b or g * b > (1 << 22):  # host-table budget: XLA instead
                     return None
                 hist_shift = 32 - b.bit_length() + 1
                 digests.append((
@@ -1381,7 +1385,7 @@ class Engine:
             means = np.where(w2 > 0, mw.reshape(g, b) / np.maximum(w2, 1e-30),
                              0.0).astype(np.float32)
             carries[out_name] = _compress(
-                jnp.asarray(means), jnp.asarray(w2), kk, ordered=True
+                jnp.asarray(means), jnp.asarray(w2), kk
             )
         count_out = next(
             o for (op, _dt, _a), o in zip(specs, outs) if op == 0

@@ -18,14 +18,18 @@ Three folds, told apart by ``layout`` and ``payload_sort``:
   packed key code IS the slot. Each aggregate takes its own route: the
   exact integer Pallas kernel, the f32 one, or XLA (``uda.update``).
 - keyed, ``payload_sort``: no dense domain, every aggregate an exact
-  integer statistic or an ``any`` the sort carries as a maximum
-  (``_sort_max``), on the TPU: rows ride one sort with their keys and
-  values (``ops/groupby.py`` ``sorted_group_fold``), windows and merges
-  alike. ``layout`` reads ``sorted``.
-- keyed, group ids in row order (a ``quantiles`` or FLOAT64 sum needs
-  them): ids by sort on the TPU (``layout`` ``sorted``), by the bounded
-  hash table on the CPU (``hashed``), then ``uda.update``; states merge
-  by regroup + scatter.
+  integer statistic, an ``any`` the sort carries as a maximum
+  (``_sort_max``) or a ``quantiles`` (PR 41), on the TPU: rows ride one
+  sort with their keys and values (``ops/groupby.py``
+  ``sorted_group_fold``), windows and merges alike, and a ``quantiles``
+  argument's rows sort once more by (the same key words, the value) into
+  the digest of the same slots (route ``keyed_digest``: ``ops/tdigest.py``
+  ``ordered_batch_to_digest``; a merge moves a digest to its group's new
+  slot and merges it there). ``layout`` reads ``sorted``.
+- keyed, group ids in row order (a FLOAT64 sum needs them, and a
+  ``quantiles`` on the CPU): ids by sort on the TPU (``layout``
+  ``sorted``), by the bounded hash table on the CPU (``hashed``), then
+  ``uda.update``; states merge by regroup + scatter.
 """
 
 from __future__ import annotations
@@ -39,6 +43,7 @@ from ..ops.routes import (
     DIGEST_K,
     F32_FOLD_MAX_GROUPS,
     INT_FOLD_MAX_GROUPS,
+    digest_bins,
     digest_route,
     int_fold_groups,
 )
@@ -65,7 +70,7 @@ class FoldPlan:
     #: under group ids, ``sorted_digest`` for a ``quantiles`` aggregate
     #: whose window digest is built by sorting the rows
     #: (``ops/tdigest.py``), ``sorted_int`` under the payload-carrying
-    #: sort.
+    #: sort and ``keyed_digest`` for a ``quantiles`` beside it.
     routes: tuple
     #: The kernel a dense window's per-slot row count rides (a ``count``
     #: aggregate's route, and where the state's ``valid`` comes from
@@ -92,9 +97,19 @@ class FoldPlan:
     #: (``_sort_max`` over the aggregates): the ``max_words`` attribute
     #: of the fold programs' ``device.dispatch`` spans.
     max_words: int = 0
+    #: The ``quantiles`` aggregates' digests (``digests``, ``digest_slots``
+    #: and ``digest_bins`` on the fold programs' ``device.dispatch``
+    #: spans): how many [slots, K] carries the state holds, one's groups x
+    #: centroids, and the width B a window's rows are binned at
+    #: (``routes.digest_bins``: a histogram's or a (slot, bin) sort's
+    #: width, ``KEYED_DIGEST_BINS`` where a sort orders the values
+    #: themselves). 0 without one.
+    digests: int = 0
+    digest_slots: int = 0
+    digest_bins: int = 0
 
 
-def _digest(uda_name: str) -> bool:
+def is_digest(uda_name: str) -> bool:
     """A t-digest aggregate (``udf/builtins/math_sketches.py``):
     ``quantiles`` or one of the planner's ``_quantile_pXX``."""
     return uda_name == "quantiles" or uda_name.startswith("_quantile_")
@@ -172,7 +187,7 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
         if (uda_name in _STATS and len(arg_types) == 1
                 and arg_types[0] == DataType.FLOAT64):
             return "pallas_f32" if f32_ok else "xla"
-        if _digest(uda_name):
+        if is_digest(uda_name):
             # Dense or under group ids alike: ``uda.update`` takes the ids
             # and asks the same function (``ops/tdigest.py``).
             return digest_route(platform, g * DIGEST_K)
@@ -194,20 +209,24 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
             routes[out] = count_route
 
     # Keyed integer fold: chosen where the key has no dense domain, every
-    # aggregate is exact-integer or an ``any`` that is a maximum of
-    # integers (dictionary ids are) and the platform sorts. Anything else
-    # keeps group ids in row order, which a ``quantiles`` or a FLOAT64
-    # sum needs.
+    # aggregate is exact-integer, an ``any`` that is a maximum of
+    # integers (dictionary ids are) or a ``quantiles`` (its digest is
+    # built by a sort of its own under the same key words), and the
+    # platform sorts. Anything else keeps group ids in row order, which a
+    # FLOAT64 sum needs.
     payload_sort = (
         not dense and bool(group_cols) and tpu
         and all(uda == "count" or _int_stat(uda, types)
-                or _sort_max(uda, types)
+                or _sort_max(uda, types) or is_digest(uda)
                 for _out, uda, types in aggs)
     )
     pack_doms = None
     lead_id = False
     if payload_sort:
-        routes = dict.fromkeys(routes, "sorted_int")
+        routes = {
+            out: "keyed_digest" if is_digest(uda) else "sorted_int"
+            for out, uda, _types in aggs
+        }
         # Dictionary ids and booleans pack exactly, as the dense route
         # trusts them (33 x 65,537 codes are 22 bits); the top bit is
         # left for "not valid".
@@ -222,14 +241,16 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
 
     tally = Counter(routes.values())
     fold = (
-        "sorted_int" if payload_sort
-        else next(iter(tally), "xla") if len(tally) <= 1
+        next(iter(tally), "sorted_int" if payload_sort else "xla")
+        if len(tally) <= 1
         else "mixed:" + ",".join(
             f"{r}={tally[r]}"
-            for r in ("pallas_int", "pallas_f32", "sorted_digest", "xla")
+            for r in ("pallas_int", "pallas_f32", "sorted_int",
+                      "sorted_digest", "keyed_digest", "xla")
             if r in tally
         )
     )
+    digests = sum(is_digest(uda) for _out, uda, _types in aggs)
     return FoldPlan(
         platform=platform,
         layout="dense" if dense else "sorted" if tpu else "hashed",
@@ -246,4 +267,7 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
         max_words=sum(
             _sort_max(uda, types) for _out, uda, types in aggs
         ) if payload_sort else 0,
+        digests=digests,
+        digest_slots=g * DIGEST_K if digests else 0,
+        digest_bins=digest_bins(g, payload_sort) if digests else 0,
     )

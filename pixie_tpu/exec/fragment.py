@@ -35,17 +35,20 @@ from ..ops.groupby import (
     dense_group_ids,
     dense_group_ids_hash,
     join_u32,
+    lead_words,
     regroup_pair,
     scatter_carry,
     sorted_group_fold,
+    sorted_slot_ids,
     split_u32,
 )
+from ..ops.tdigest import digest_merge, ordered_batch_to_digest
 from ..types.dtypes import DataType, device_dtypes, pad_values
 from ..types.relation import Relation
 from ..udf.registry import Registry
 from ..udf.udf import UDADef, apply_cast
 from .expr import BindError, bind_expr, collect_operands, operands_bound
-from .fold_plan import INT_KEY_TYPES, FoldPlan, plan_fold
+from .fold_plan import INT_KEY_TYPES, FoldPlan, is_digest, plan_fold
 from .plan import (
     AggOp,
     ColumnRef,
@@ -998,9 +1001,19 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     integer set folds by sorting the rows themselves, keys, values and
     carries riding one ``lax.sort`` (ops/groupby.py sorted_group_fold):
     no argsort, no window-long gather or scatter, and the same function
-    is the window fold and the merge of two keyed states."""
+    is the window fold and the merge of two keyed states.
+
+    A ``quantiles`` aggregate beside them (route ``keyed_digest``) rides
+    nothing: its [g, K] digest is built by a sort of its own under the
+    same key words (``ops/tdigest.py`` ``ordered_batch_to_digest``, slot
+    for slot the integer fold's groups, once a distinct argument), and
+    in a merge each side's digests are moved to their groups' new slots
+    (``sorted_slot_ids``, a gather of rows) and merged there."""
     g = plan.slots
     pack_doms = plan.pack_doms
+    folded = pack_doms is not None or plan.lead_id
+    digest_aggs = [ab for ab in aggs_bound if is_digest(ab[0].uda_name)]
+    int_aggs = [ab for ab in aggs_bound if not is_digest(ab[0].uda_name)]
     key_dtypes = [
         device_dtypes(rel1.col_type(c))[i] for c, i in key_plane_index
     ]
@@ -1050,11 +1063,11 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
         with jax.named_scope("sorted_fold"):
             words, valid_g, rows, sums_g, maxes_g, n_groups = sorted_group_fold(
                 _key_words(key_planes), valid, sums, maxes, g,
-                folded_flag=pack_doms is not None or plan.lead_id,
+                folded_flag=folded,
             )
         carries = {}
         n_max = 0
-        for ae, uda, _b, _c in aggs_bound:
+        for ae, uda, _b, _c in int_aggs:
             init = uda.init(g)
             init_leaves = init if isinstance(init, tuple) else (init,)
             out = []
@@ -1080,7 +1093,7 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     # ``any`` is a maximum (``fold_plan._sort_max``); of a STRING it is
     # one of int32 dictionary ids, whose plane keeps its one word.
     spec, plane_dtype = {}, {}
-    for ae, _uda, _b, casts in aggs_bound:
+    for ae, _uda, _b, casts in int_aggs:
         if ae.uda_name == "count":
             spec[ae.out_name] = (("rows", None),)
             continue
@@ -1102,12 +1115,12 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     ride_planes = {fkey for kind, fkey in stats if kind == "sum"}
     if primary is not None and primary[0] == "max":
         ride_planes.discard(primary[1])
-    lead_words = 1 if pack_doms is not None else sum(
+    n_lead = 1 if pack_doms is not None else sum(
         2 if jnp.dtype(dt).itemsize == 8 else 1 for dt in key_dtypes
     ) + (0 if plan.lead_id else 1)
     ride = functools.partial(
         _routes.sorted_fold_ride, g=g, planes=len(ride_planes),
-        key_words=lead_words + (
+        key_words=n_lead + (
             0 if primary is None
             else 1 if plane_dtype[primary[1]] == jnp.int32 else 2
         ),
@@ -1115,7 +1128,7 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
 
     def arg_planes(cols, valid):
         planes = {}  # one plane a distinct argument expression
-        for ae, _uda, arg_bound, casts in aggs_bound:
+        for ae, _uda, arg_bound, casts in int_aggs:
             for _kind, fkey in spec[ae.out_name]:
                 if fkey is not None and fkey not in planes:
                     a = apply_cast(arg_bound[0].fn(cols), *casts[0])
@@ -1123,15 +1136,68 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
                         a, valid.shape).astype(plane_dtype[fkey])
         return planes
 
+    def window_digests(key_planes, cols, valid):
+        """{out_name: the window's [g, K] digest}: one sort a distinct
+        argument, under the integer fold's own lead words."""
+        lead = lead_words(_key_words(key_planes), valid, folded)
+        built, carries = {}, {}
+        for ae, _uda, arg_bound, casts in digest_aggs:
+            fkey = (_struct_key(ae.args), casts[0])
+            if fkey not in built:
+                values = jnp.broadcast_to(
+                    apply_cast(arg_bound[0].fn(cols), *casts[0]), valid.shape
+                )
+                with jax.named_scope("keyed_digest"):
+                    built[fkey] = ordered_batch_to_digest(
+                        lead, folded, values, g
+                    )
+            carries[ae.out_name] = built[fkey]
+        return carries
+
     def window(cols, valid):
         planes = arg_planes(cols, valid)
         leaves = {
             out: tuple((kind, planes.get(fkey)) for kind, fkey in kinds)
             for out, kinds in spec.items()
         }
-        return _sorted_state(
-            [cols[c][i] for c, i in key_plane_index], valid, leaves
-        )
+        key_planes = [cols[c][i] for c, i in key_plane_index]
+        state = _sorted_state(key_planes, valid, leaves)
+        if digest_aggs:
+            state["carries"].update(window_digests(key_planes, cols, valid))
+        return state
+
+    def merge_digests(sa, sb, key_planes, valid):
+        """{out_name: merged digest}: where each side's slots go in the
+        merged state, their digests' rows taken there (a slot no side
+        fills reads the empty digest), then the digests' own merge."""
+        with jax.named_scope("digest_slots"):
+            dest = sorted_slot_ids(_key_words(key_planes), valid, g, folded)
+        ga = sa["valid"].shape[0]
+
+        def sources(dest):
+            """The source slot of each merged slot (its own count where
+            this side fills it not: the empty row below)."""
+            n = dest.shape[0]
+            return jnp.full(g + 1, n, jnp.int32).at[dest].set(
+                jnp.arange(n, dtype=jnp.int32)
+            )[:g]
+
+        def placed(carry, src):
+            return tuple(
+                jnp.take(jnp.concatenate([p, jnp.zeros_like(p[:1])]), src,
+                         axis=0)
+                for p in carry
+            )
+
+        src_a, src_b = sources(dest[:ga]), sources(dest[ga:])
+        carries = {}
+        for ae, _uda, _b, _c in digest_aggs:
+            with jax.named_scope("digest_merge"):
+                carries[ae.out_name] = digest_merge(
+                    placed(sa["carries"][ae.out_name], src_a),
+                    placed(sb["carries"][ae.out_name], src_b),
+                )
+        return carries
 
     def merge(sa, sb):
         # Slots are partial groups like rows are: one concatenation,
@@ -1141,7 +1207,7 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
             return jnp.concatenate([jnp.asarray(a), jnp.asarray(b)])
 
         leaves = {}
-        for ae, uda, _b, _c in aggs_bound:
+        for ae, uda, _b, _c in int_aggs:
             ca, cb = sa["carries"][ae.out_name], sb["carries"][ae.out_name]
             if ae.uda_name == "mean":
                 leaves[ae.out_name] = (
@@ -1151,10 +1217,11 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
                 (kind, _fkey), = spec[ae.out_name]
                 kind = kind if kind in ("max", "min") else "sum"
                 leaves[ae.out_name] = ((kind, cat(ca, cb)),)
-        merged = _sorted_state(
-            [cat(a, b) for a, b in zip(sa["keys"], sb["keys"])],
-            cat(sa["valid"], sb["valid"]), leaves,
-        )
+        key_planes = [cat(a, b) for a, b in zip(sa["keys"], sb["keys"])]
+        valid = cat(sa["valid"], sb["valid"])
+        merged = _sorted_state(key_planes, valid, leaves)
+        if digest_aggs:
+            merged["carries"].update(merge_digests(sa, sb, key_planes, valid))
         merged["overflow"] = (
             merged["overflow"] | sa["overflow"] | sb["overflow"]
         )
@@ -1189,9 +1256,11 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
             "overflow": jnp.zeros((), jnp.bool_),
         })
 
+    # A digest is no row's carry: with one in the state a window folds
+    # alone and merges, whatever its length.
     return _Fold(
         _keyed_init_keys(g, rel1, key_plane_index), window, merge,
-        lambda state: state["keys"], ride, absorb,
+        lambda state: state["keys"], ride, None if digest_aggs else absorb,
     )
 
 

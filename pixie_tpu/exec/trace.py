@@ -32,16 +32,21 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
 - ``device.dispatch``     one per program enqueued: the host's enqueue
   call (attributes ``program`` as ``ProgramRegistry`` names its kind,
   ``windows``, and on a window-fold program ``fold``: ``pallas_int``,
-  ``pallas_f32``, ``sorted_digest``, ``xla``, ``mixed:...`` or
-  ``sorted_int``, as ``CompiledFragment.fold``
+  ``pallas_f32``, ``sorted_digest``, ``keyed_digest``, ``xla``,
+  ``mixed:...`` or ``sorted_int``, as ``CompiledFragment.fold``
   decided at compile time, with ``group``: ``dense`` / ``sorted`` /
   ``hashed`` and ``slots``: the capacity g it was compiled at; under
   ``sorted_int`` also ``ride``: ``payload`` / ``index``, how this
   window's sum planes reach group order, ``ops/routes.py``
   ``sorted_fold_ride``, absent where none rides, and ``max_words``: the
   u32 words of the maxima its sorts carry as keys, two an INT64 max /
-  min / ``any``, one an ``any`` of a string, absent at 0); child of its
-  fragment
+  min / ``any``, one an ``any`` of a string, absent at 0; a keyed fold
+  that also holds ``quantiles`` reads ``mixed:sorted_int=<n>,
+  keyed_digest=<m>``; with a ``quantiles`` aggregate on any layout
+  ``digests``: the digest carries the program holds, ``digest_slots``:
+  groups x centroids of one, and ``digest_bins``: the width B its
+  windows' rows are binned at, 2^32 where a sort orders the values
+  themselves, ``ops/routes.py`` ``digest_bins``); child of its fragment
 - ``rebucket``            one per re-fold after a group-capacity overflow
   (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
   rows, ``kelvin`` the merge of states): the compile at twice the slots
@@ -86,7 +91,9 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   bucket they are merged at)
 - ``payload``             child of the root: the fragment's last
   ``device.wait`` end to the bridge payload built and its wire bytes
-  counted (``kind``: ``agg_state`` / ``rows``); and a result sink's
+  counted (``kind``: ``agg_state`` / ``rows``; an ``agg_state`` that
+  holds ``quantiles`` digests says ``digest_bytes``, counted in
+  ``usage.digest_bytes``); and a result sink's
   answer in hand (``kind``: ``result``, ``rows``, ``string_bytes``: the
   UTF-8 bytes its STRING columns' ids stand for; counted in
   ``usage.answer_rows`` / ``usage.string_bytes_out``)
@@ -331,6 +338,10 @@ class QueryResourceUsage:
       spans of kind ``result`` (one a ``ResultSinkOp``, on the engine
       that runs it; ``rows_out`` is every fragment's output, a merge's
       and a join's among them, not the answer's)
+    - ``digest_bytes`` bytes of the ``quantiles`` aggregates' [slots, K]
+      digest planes in the partial-agg states the query shipped: the
+      ``digest_bytes`` of its ``payload`` spans of kind ``agg_state``
+      (part of ``wire_bytes``; 2 x slots x 128 x 4 B a digest)
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -364,6 +375,7 @@ class QueryResourceUsage:
     dict_udf_strings: int = 0
     answer_rows: int = 0
     string_bytes_out: int = 0
+    digest_bytes: int = 0
     skipped_windows: int = 0
     device_peak_bytes: int = 0
     freshness_lag_ms: float = 0.0
@@ -390,7 +402,8 @@ class QueryResourceUsage:
             "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
             "join_rows_in", "join_rows_out", "dict_udf_strings",
-            "answer_rows", "string_bytes_out", "skipped_windows",
+            "answer_rows", "string_bytes_out", "digest_bytes",
+            "skipped_windows",
         ):
             setattr(self, k, getattr(self, k) + int(d.get(k, 0)))
         for k in ("device_ms", "compile_ms", "stall_ms", "decode_ms"):
@@ -594,6 +607,10 @@ class TracedFragment(FragmentStats):
                 attrs["group"], attrs["slots"] = self.group, int(self.slots)
             if self.remap_entries:
                 attrs["remap_entries"] = int(self.remap_entries)
+            if self.digests:
+                attrs["digests"] = int(self.digests)
+                attrs["digest_slots"] = int(self.digest_slots)
+                attrs["digest_bins"] = int(self.digest_bins)
         return _FragmentSpanCtx(self, "device.dispatch", attrs, stage=stage)
 
     def _note_device(self, start_ns: int, end_ns: int) -> None:
@@ -863,6 +880,8 @@ class QueryTrace:
             elif s.name == "payload" and s.attributes.get("kind") == "result":
                 u.answer_rows += s.attributes.get("rows", 0)
                 u.string_bytes_out += s.attributes.get("string_bytes", 0)
+            elif s.name == "payload":
+                u.digest_bytes += s.attributes.get("digest_bytes", 0)
             elif (s.name in ("device.fetch", "device.wait")
                   and "bytes" in s.attributes):
                 # One batched get after the path's sync (the child) or

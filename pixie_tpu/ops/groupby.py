@@ -455,6 +455,52 @@ def _front(pos, planes, g):
     return pos_g, out
 
 
+def lead_words(keys, valid, folded_flag: bool) -> list:
+    """The u32 words a keyed fold's rows sort by, ahead of anything they
+    carry: ``keys`` with "not valid" folded into the first as 0xFFFFFFFF
+    (``folded_flag``: a valid row never reads that) or as a flag word of
+    its own ahead of them. Valid rows sort first, equal words are one
+    group, and the g-th distinct run is slot g: ``sorted_group_fold``,
+    ``sorted_slot_ids`` and ``ops/tdigest.py`` ``ordered_batch_to_digest``
+    number groups by these words alike."""
+    if folded_flag:
+        return [jnp.where(valid, keys[0], jnp.uint32(_U32_MAX))] + list(keys[1:])
+    return [(~valid).astype(jnp.uint32)] + list(keys)
+
+
+def sorted_lead_runs(s_lead, folded_flag: bool):
+    """(valid, differs) of rows SORTED by their lead words: which rows
+    are valid, and bool[N - 1] whether row i + 1 opens a new group (a
+    word differs from the row before)."""
+    s_valid = ((s_lead[0] != jnp.uint32(_U32_MAX)) if folded_flag
+               else (s_lead[0] == 0))
+    differs = jnp.zeros(s_lead[0].shape[0] - 1, dtype=jnp.bool_)
+    for p in s_lead:
+        differs = differs | (p[1:] != p[:-1])
+    return s_valid, differs
+
+
+def sorted_slot_ids(keys, valid, max_groups: int, folded_flag: bool = False):
+    """int32[N]: the slot ``sorted_group_fold`` gives each of N partial
+    groups' key (the rank of its key among the distinct valid keys), in
+    the rows' own order; ``max_groups`` for a row that is not valid or
+    whose group overflows. A sort by the lead words with the row index
+    riding, a count of the runs, and the inverse sort: what a carry that
+    cannot ride the sort (a [g, K] digest) follows its slot by."""
+    n = valid.shape[0]
+    lead = lead_words(keys, valid, folded_flag)
+    iota = jnp.arange(n, dtype=jnp.int32)
+    out = jax.lax.sort(lead + [iota], dimension=0, is_stable=False,
+                       num_keys=len(lead))
+    s_lead, s_row = out[:-1], out[-1]
+    s_valid, differs = sorted_lead_runs(s_lead, folded_flag)
+    starts = jnp.concatenate([jnp.ones(1, jnp.bool_), differs]) & s_valid
+    gid = blocked_cumsum(starts.astype(jnp.int32), force=n > _CHUNK) - 1
+    gid = jnp.where(s_valid & (gid < max_groups), gid, max_groups)
+    return jax.lax.sort([s_row, gid], dimension=0, is_stable=False,
+                        num_keys=1)[1]
+
+
 def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
                       folded_flag: bool = False):
     """Fold N partial groups into ``max_groups`` slots by sorting the rows.
@@ -495,10 +541,7 @@ def sorted_group_fold(keys, valid, sums, maxes, max_groups: int,
     n = valid.shape[0]
     u32 = jnp.uint32
     iota = jnp.arange(n, dtype=jnp.int32)
-    if folded_flag:
-        lead = [jnp.where(valid, keys[0], u32(_U32_MAX))] + list(keys[1:])
-    else:
-        lead = [(~valid).astype(u32)] + list(keys)
+    lead = lead_words(keys, valid, folded_flag)
     n_lead = len(lead)
     primary = maxes[0] if maxes else None
     ride = [s for s in sums if s is not primary]

@@ -54,19 +54,56 @@ INT_FOLD_GROUP_BLOCK = 1024
 F32_FOLD_MAX_GROUPS = 2048
 #: Centroids a group's t-digest keeps (``ops/tdigest.py``).
 DIGEST_K = 128
-#: Largest groups x centroids whose window digest is built by SORTING the
-#: rows (``ops/tdigest.py`` ``_sorted_batch_to_digest``): the reduction's
-#: two f32 accumulators stay in VMEM (2 x 4 MiB here). Above it, and on
-#: the CPU, rows scatter into the [G, B] histogram.
+#: Largest groups x centroids of a DENSE (or id-form) group-by whose window
+#: digest is built by SORTING the rows by (slot, bin)
+#: (``ops/tdigest.py`` ``_sorted_batch_to_digest``): the reduction's two
+#: f32 accumulators stay in VMEM (2 x 4 MiB here). Above it, and on the
+#: CPU, such a fold's rows scatter into the [G, B] histogram. The limit
+#: says nothing of a KEYED group-by on the TPU's routes (PR 41): there
+#: the rows sort by (packed key, value) beside the keyed integer fold's
+#: own sort, whatever the slot count (``ordered_batch_to_digest``: 2^24
+#: slots in ``http_edges_1chip``), and no histogram exists.
 SORTED_DIGEST_MAX_SLOTS = 1 << 20
+#: What ``digest_bins`` reads where a window's rows are not binned at all:
+#: the keyed digest orders a group's rows by the value's whole 32-bit
+#: pattern.
+KEYED_DIGEST_BINS = 1 << 32
 
 
 def digest_route(platform: str, slots: int) -> str:
     """The route of a ``quantiles`` aggregate's window digest over
-    ``slots`` = groups x centroids: ``sorted_digest`` or ``xla``."""
+    ``slots`` = groups x centroids where the rows carry group ids
+    (dense, or the id form): ``sorted_digest`` or ``xla``."""
     if platform == "tpu" and slots <= SORTED_DIGEST_MAX_SLOTS:
         return "sorted_digest"
     return "xla"
+
+
+def digest_hist_bins(num_groups: int) -> int:
+    """Histogram width B of the binned routes (``sorted_digest``,
+    ``xla``), 0 where a window's rows are not binned.
+
+    B=8192 gives positive values 4 mantissa bits of resolution (bins are
+    ~4.4% wide in value; the within-bin weighted mean recovers most of
+    that), and the [G, B] f32 scratch is kept near 2^25 slots: 4,096
+    bins at 8,192 groups. Past that the width would go on halving (256
+    bins at 2^17 groups, a factor of four wide in value: most of a
+    small group's rows in one bin), so no histogram is built: the rows
+    sort by (group, value) and are not binned at all
+    (``ops/tdigest.py`` ``ordered_batch_to_digest``)."""
+    b = 8192
+    while b > 4096 and num_groups * b > (1 << 25):
+        b //= 2
+    return b if num_groups * b <= (1 << 25) else 0
+
+
+def digest_bins(num_groups: int, keyed_sort: bool) -> int:
+    """What ``digest_bins`` reads on a fold program's dispatch: the
+    histogram's width, or ``KEYED_DIGEST_BINS`` where the rows sort by
+    their values (the keyed fold's digests, and any past the widths a
+    histogram is built at)."""
+    return (KEYED_DIGEST_BINS if keyed_sort
+            else digest_hist_bins(num_groups) or KEYED_DIGEST_BINS)
 
 
 #: Largest build + probe rows whose shared key-id space ``ops/join.py``

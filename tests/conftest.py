@@ -125,6 +125,36 @@ _SUPERSEDED = {
         "with this test's other assertions, by tests/benchmark/"
         "test_stack_flame.py::test_the_three_follow_the_host_paths_seven"
     ),
+    "tests/benchmark/test_stack_flame.py::"
+    "test_the_file_agrees_with_benchmark_json_and_the_programs_split": (
+        "asserts stack_flame_1chip is the LAST configuration; PR 41 "
+        "appended http_edges_1chip after it (new entries go last): every "
+        "other assertion of this test is held by tests/benchmark/"
+        "test_http_edges.py::test_stack_flames_file_agrees_with_benchmark_"
+        "json_and_the_programs_split, the order (relatively, so that the "
+        "next PR supersedes nothing) by ::test_what_was_filed_is_a_prefix_"
+        "of_the_list"
+    ),
+    "tests/benchmark/test_stack_flame.py::"
+    "test_the_three_follow_the_host_paths_seven": (
+        "asserts the LAST ten per-layer metrics are PR 37's seven and PR "
+        "39's three; PR 41 appended its cell's three after them: the "
+        "order is held by tests/benchmark/test_http_edges.py::"
+        "test_what_was_filed_is_a_prefix_of_the_list, the seven's entries "
+        "by ::test_the_host_paths_seven_are_as_they_were_filed"
+    ),
+    **{
+        "tests/benchmark/test_stack_flame.py::"
+        f"test_the_new_metrics_are_filed_under_their_layers[{metric}]": (
+            "asserts PR 39's three per-layer metrics are the LAST three; "
+            "PR 41 appended its cell's three after them: this test's "
+            "other assertions are held by tests/benchmark/"
+            "test_http_edges.py::test_stack_flames_metrics_are_filed_"
+            f"under_their_layers[{metric}]"
+        )
+        for metric in ("answer_rows", "answer_string_mb",
+                       "perf_flamegraph_p50_ms")
+    },
 }
 
 

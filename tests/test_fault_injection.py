@@ -1179,9 +1179,13 @@ class TestQuarantineCooldownRecovery:
                 "service": [f"svc-{(i + j) % 3}" for j in range(n)],
             })
             pem._register()
+        # Every PEM's table, not the first to register it (as the helper
+        # at the top of the file waits): a plan made before the last one
+        # is known dispatches to a part of the shards.
         wait_until(
             lambda: len(tracker.agent_ids()) == 4
-            and "http_events" in tracker.schemas(),
+            and len(tracker.distributed_state().pems_with_table(
+                "http_events")) == len(pems),
             "the cluster never registered",
         )
         broker = QueryBroker(bus, tracker)

@@ -23,6 +23,14 @@ SERVICE = (("err", "mean", BOOL), ("n", "count", I64),
            ("p50", "_quantile_p50", F64), ("p99", "_quantile_p99", F64))
 DENSE_2145 = [(33, 0, 1), (65, 0, 1)]  # the dense cells' dictionaries
 KEYED = [(33, 0, 1), (65_537, 0, 1)]  # http_full_1chip's: over the limit
+# http_edges_1chip's service graph (benchmark/traffic/graph_recent).
+EDGE_KEYS = (("remote_addr", D.STRING), ("pod", D.STRING), SVC)
+EDGE_DOMAINS = [(8_193, 0, 1), (4_097, 0, 1), (33, 0, 1)]
+EDGES = (("latency_p50", "_quantile_p50", F64),
+         ("latency_p90", "_quantile_p90", F64),
+         ("latency_p99", "_quantile_p99", F64),
+         ("error_rate", "mean", BOOL), ("throughput_total", "count", I64),
+         ("outbound_bytes_total", "sum", I64))
 
 
 def _plan(group_cols, domains, aggs, platform, max_groups=4096,
@@ -203,14 +211,30 @@ KEYED_CASES = [
      (("x", "any", F64), ("s", "sum", I64)), {}, (False, None, False, "xla")),
     ("any_of_a_boolean", (SVC, PATH), KEYED, (("x", "any", BOOL),), {},
      (False, None, False, "xla")),
-    # An aggregate that needs a row's group id keeps the id form; under
-    # the ids a ``quantiles`` sorts its rows while its centroids fit.
+    # A ``quantiles`` rides nothing and needs no row's group id either
+    # (PR 41): the integer aggregates keep the payload sort and its
+    # digest is built by a sort of its own under the same key words,
+    # whatever the slots.
     ("with_a_quantiles", (SVC, PATH), KEYED,
      HTTP + (("q", "quantiles", F64),), {},
-     (False, None, False, "mixed:sorted_digest=1,xla=3")),
+     (True, (33, 65_537), False, "mixed:sorted_int=3,keyed_digest=1")),
     ("with_a_quantiles_at_the_cells_slots", (SVC, PATH), KEYED,
      HTTP + (("q", "quantiles", F64),), {"max_groups": 1 << 17},
-     (False, None, False, "xla")),
+     (True, (33, 65_537), False, "mixed:sorted_int=3,keyed_digest=1")),
+    # http_edges_1chip's AggOp: three plucked quantiles of one column, a
+    # BOOLEAN mean, a count and an INT64 sum on three dictionary keys
+    # that pack into one word (8,193 x 4,097 x 33 codes), on the PEM; on
+    # the Kelvin, which trusts no domain, behind the leading id.
+    ("the_service_graph", EDGE_KEYS, EDGE_DOMAINS, EDGES,
+     {"max_groups": 1 << 17},
+     (True, (8_193, 4_097, 33), False, "mixed:sorted_int=3,keyed_digest=3")),
+    ("the_service_graph_on_the_kelvin", EDGE_KEYS, EDGE_DOMAINS, EDGES,
+     {"max_groups": 1 << 17, "allow_dense": False},
+     (True, None, True, "mixed:sorted_int=3,keyed_digest=3")),
+    ("quantiles_alone_by_a_key", (SVC, PATH), KEYED,
+     (("q", "_quantile_p99", F64),), {},
+     (True, (33, 65_537), False, "keyed_digest")),
+    # An aggregate that needs a row's group id keeps the id form.
     ("with_a_float_sum", (SVC, PATH), KEYED, HTTP + (("s", "sum", F64),), {},
      (False, None, False, "xla")),
     ("with_a_boolean_max", (SVC, PATH), KEYED, (("any", "max", BOOL),), {},
@@ -227,8 +251,10 @@ def test_a_keyed_state(group_cols, domains, aggs, kw, tpu):
     plan = _plan(group_cols, domains, aggs, "tpu", **kw)
     assert (plan.layout, plan.slots) == ("sorted", kw.get("max_groups", 4096))
     assert (plan.payload_sort, plan.pack_doms, plan.lead_id, plan.fold) == tpu
-    assert set(_routes(plan)) - {"sorted_digest"} == {
+    assert set(_routes(plan)) - {"sorted_digest", "keyed_digest"} <= {
         "sorted_int" if payload_sort else "xla"}
+    assert ("keyed_digest" in _routes(plan)) == bool(
+        payload_sort and plan.digests)
     assert plan.count_route == "xla" and plan.domains == ()
     cpu = _plan(group_cols, domains, aggs, "cpu", **kw)
     assert (cpu.layout, cpu.slots, cpu.fold) == ("hashed", plan.slots, "xla")
@@ -249,6 +275,38 @@ def test_the_words_of_the_maxima_the_sort_carries(aggs, words):
     assert _plan((SVC, PATH), KEYED, aggs, "tpu").max_words == words
     assert _plan((SVC, PATH), KEYED, aggs, "cpu").max_words == 0
     assert _plan((SVC,), [(33, 0, 1)], aggs, "tpu").max_words == 0
+
+
+@pytest.mark.parametrize("platform,groups,allow_dense,want", [
+    # The cell's fold: three carries of 2^17 x 128 slots, rows ordered by
+    # their values (no histogram), on the PEM and on the Kelvin.
+    ("tpu", 1 << 17, True, (3, 1 << 24, 1 << 32)),
+    ("tpu", 1 << 17, False, (3, 1 << 24, 1 << 32)),
+    # The CPU's id form at that size bins nothing either: a histogram
+    # would be 256 bins wide.
+    ("cpu", 1 << 17, True, (3, 1 << 24, 1 << 32)),
+    # Small enough for a histogram: 8,192 bins to 4,096 groups, 4,096 at
+    # 8,192.
+    ("cpu", 4096, True, (3, 4096 * 128, 8192)),
+    ("cpu", 8192, True, (3, 8192 * 128, 4096)),
+])
+def test_the_digests_of_a_keyed_fold(platform, groups, allow_dense, want):
+    """``digests`` / ``digest_slots`` / ``digest_bins`` (the dispatch
+    span's attributes), and which aggregates ride the sort."""
+    plan = _plan(EDGE_KEYS, EDGE_DOMAINS, EDGES, platform,
+                 max_groups=groups, allow_dense=allow_dense)
+    assert (plan.digests, plan.digest_slots, plan.digest_bins) == want
+    if platform == "tpu":
+        assert _routes(plan) == ("keyed_digest",) * 3 + ("sorted_int",) * 3
+    else:
+        assert set(_routes(plan)) == {"xla"}
+
+
+def test_a_dense_fold_says_its_digests_too():
+    plan = _plan((SVC,), [(33, 0, 1)], SERVICE, "tpu")
+    assert (plan.digests, plan.digest_slots, plan.digest_bins) == (
+        2, 33 * DIGEST_K, 8192)
+    assert _plan((SVC, PATH), KEYED, HTTP, "tpu").digests == 0
 
 
 def test_the_record_is_frozen_and_names_its_platform():

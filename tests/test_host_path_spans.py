@@ -231,7 +231,8 @@ def rehearsed():
     out = {"spans": {}, "join_programs": {}}
     for cell in ("http_pem_1chip.dash_recent", "conn_flow_1chip.flow_recent",
                  "sql_stats_1chip.sql_recent",
-                 "stack_flame_1chip.flame_recent"):
+                 "stack_flame_1chip.flame_recent",
+                 "http_edges_1chip.graph_recent"):
         spec = harness.load_cell(cell)
         cfg, traffic = spec["config"], spec["traffic"]
         builder = harness.module("builders", cfg["builder"])
@@ -278,6 +279,47 @@ def test_the_docs_and_the_docstring_list_every_span_name():
         words = set(re.findall(r"[a-z_]+(?:\.[a-z_]+)*", text))
         missing = {n for n in trace_mod.SPAN_NAMES if n not in words}
         assert not missing, (where, sorted(missing))
+
+
+#: What a ``quantiles`` fold says of its digests (PR 41): on its fold
+#: programs' ``device.dispatch`` and on the shipped state's ``payload``.
+DIGEST_ATTRIBUTES = ("digests", "digest_slots", "digest_bins")
+
+
+def test_the_docs_and_the_docstring_name_the_digests_attributes():
+    doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
+    for text, where in ((doc, "docs/OBSERVABILITY.md"),
+                        (trace_mod.__doc__, "trace.py's docstring")):
+        for word in (*DIGEST_ATTRIBUTES, "digest_bytes", "keyed_digest"):
+            assert re.search(rf"\b{word}\b", text), (where, word)
+    assert "digest_bytes" in trace_mod.QueryResourceUsage.__doc__
+    assert trace_mod.QueryResourceUsage().digest_bytes == 0
+
+
+def test_a_served_quantiles_fold_says_its_digests(rehearsed_names):
+    """The service graph's fold dispatches on the PEM carry the three
+    attributes, its shipped state's ``payload`` the digests' bytes, and
+    the usage record sums them; a script without ``quantiles`` carries
+    none of them."""
+    spans = rehearsed_names["http_edges_1chip.graph_recent"]
+    pem = spans["pem"][-1]
+    folds = [s.attributes for s in _named(pem, "device.dispatch")
+             if "fold" in s.attributes]
+    assert folds
+    for a in folds:
+        assert a["digests"] == 3 and a["digest_slots"] == a["slots"] * 128
+        assert a["digest_bins"] in (8192, 4096, 1 << 32)
+    (payload,) = _named(pem, "payload")
+    assert payload.attributes["kind"] == "agg_state"
+    assert payload.attributes["digest_bytes"] == (
+        3 * 2 * folds[0]["digest_slots"] * 4) == pem.usage.digest_bytes
+    assert 0 < pem.usage.digest_bytes < pem.usage.wire_bytes
+    assert spans["kelvin"][-1].usage.digest_bytes == 0
+    for t in rehearsed_names["sql_stats_1chip.sql_recent"]["pem"]:
+        assert t.usage.digest_bytes == 0
+        for s in t.spans:
+            assert not set(s.attributes) & {*DIGEST_ATTRIBUTES,
+                                            "digest_bytes"}, s.name
 
 
 def test_the_joins_pieces_are_children_of_its_span(rehearsed_names):

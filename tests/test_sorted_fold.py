@@ -598,8 +598,6 @@ def test_the_span_says_what_the_window_program_holds(aggs, keys):
 
 
 @pytest.mark.parametrize("uda,col,fold", [
-    # Under the ids a ``quantiles`` sorts its rows by (id, bin).
-    ("quantiles", "lat", "mixed:sorted_digest=1,xla=3"),
     ("sum", "ratio", "xla"), ("max", "ratio", "xla"),
 ])
 def test_an_aggregate_that_needs_row_ids_keeps_the_id_form(uda, col, fold):
@@ -607,6 +605,19 @@ def test_an_aggregate_that_needs_row_ids_keeps_the_id_form(uda, col, fold):
                  extra=(("x", uda, col),))
     assert frag.fold == fold and frag.group == "sorted"
     assert not frag.plan.payload_sort
+
+
+def test_a_quantiles_beside_them_leaves_the_integers_on_the_sort():
+    """Since PR 41 a ``quantiles`` needs no row ids: the integer
+    aggregates keep the payload sort and the digest is built by a sort
+    of its own under the same key words; with one in the state a window
+    folds alone and merges (no ``absorb``)."""
+    frag = _frag(("svc", "path"), AGG_SETS["count_mean_max"], 256,
+                 extra=(("x", "quantiles", "lat"),))
+    assert frag.fold == "mixed:sorted_int=3,keyed_digest=1"
+    assert frag.group == "sorted" and frag.plan.payload_sort
+    assert (frag.plan.digests, frag.plan.digest_slots,
+            frag.plan.digest_bins) == (1, 256 * 128, 1 << 32)
 
 
 @pytest.mark.parametrize("allow_dense", [True, False],

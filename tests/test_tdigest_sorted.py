@@ -156,16 +156,20 @@ def test_the_cpus_route_sorts_nothing_and_the_tpus_scatters_nothing():
     """The choice is the platform's alone (``ops/routes.py``): the CPU's
     lowered text of the aggregate holds the scatters and no ``sort``
     (XLA's CPU sort is ~90x its scatter), the TPU's one sort and no
-    scatter of the rows; above the reduction's slot limit the TPU's
-    routes keep the scatters too."""
+    scatter of the rows. Above the reduction's slot limit no histogram
+    of a width worth having fits (``routes.digest_hist_bins``): on both
+    platforms' routes the rows then sort once, by (group, value), and
+    their centroids are scatter-added (PR 41)."""
     from pixie_tpu.ops.routes import DIGEST_K, SORTED_DIGEST_MAX_SLOTS
 
     cpu, tpu = _lowered("cpu"), _lowered("tpu")
     assert "stablehlo.sort" not in cpu and "stablehlo.scatter" in cpu
     assert tpu.count("stablehlo.sort") == 1
     assert "stablehlo.scatter" not in tpu
-    over = _lowered("tpu", g=SORTED_DIGEST_MAX_SLOTS // DIGEST_K + 1)
-    assert "stablehlo.sort" not in over and "stablehlo.scatter" in over
+    for platform in ("tpu", "cpu"):
+        over = _lowered(platform, g=SORTED_DIGEST_MAX_SLOTS // DIGEST_K + 1)
+        assert over.count("stablehlo.sort") == 1
+        assert "stablehlo.scatter" in over
 
 
 def test_a_served_refresh_names_the_sorted_digest():

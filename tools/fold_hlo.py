@@ -101,6 +101,14 @@ FLAME = {
 #: ``join_capacity_safety``: 2^21 slots.)
 FLAME_JOIN = (1 << 12, 1 << 20, 1 << 21)
 FLAME_WINDOWS = 2  # the windows ``-5m`` holds
+#: The ``edges`` case's shapes, as ``FLOW``'s:
+#: ``http_edges_1chip.graph_recent`` settles on 2^17 slots for its 80 k
+#: (remote_addr, pod, service) edges (three [2^17, 128] digests beside
+#: the integer carries), merged in a 2^17 bucket; three windows in range.
+EDGES = {
+    "mean+count+sum+_quantile_p50+_quantile_p90+_quantile_p99"
+    "_by_remote_addr_pod_service": (1 << 17, 1 << 17, WINDOW),
+}
 
 
 def _sized(case):
@@ -110,6 +118,8 @@ def _sized(case):
         return FLOW
     if case.startswith("flame"):
         return FLAME
+    if case.startswith("edges"):
+        return EDGES
     return SQL if case.startswith("sql") else None
 
 
@@ -156,7 +166,9 @@ def _capture(batches, table="http_events",
         _wait_for_table(tracker, table)
         broker = QueryBroker(bus, tracker)
         for script in scripts:
-            res = broker.execute_script(load_script(script).pxl,
+            # A bundled script's name, or the text of a traffic's own.
+            pxl = script if "\n" in script else load_script(script).pxl
+            res = broker.execute_script(pxl,
                                         timeout_s=300, max_output_rows=1 << 17)
             assert not res.get("partial"), script
     finally:
@@ -287,7 +299,7 @@ def _lower(case, captured, topo_device, out_dir, lines):
                 else jax.jit(frag.group_sketch)
             ).lower(on(jax.eval_shape(frag.init_sketch)), cols, valid),
         }
-        if case == "flame":  # two windows in range: one scan program
+        if case in ("flame", "edges"):  # windows in range: one scan program
             wanted = ("update_all",) + (
                 ("group_sketch",) if frag.group_sketch else ())
         elif flow:  # what the cell runs: one window in range a chain
@@ -435,7 +447,7 @@ def main():
     ap.add_argument("--out", default=None, help="directory for the texts")
     ap.add_argument("--cases", default="dense,keyed,flow",
                     help="comma-separated, of dense, keyed, flow, digest, "
-                         "sql, flame")
+                         "sql, flame, edges")
     args = ap.parse_args()
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -444,7 +456,8 @@ def main():
 
     import pixie_tpu  # noqa: F401
     from benchmark.builders import (
-        served_conn, served_http_skew, served_sql, served_stacks,
+        served_conn, served_http_edges, served_http_skew, served_sql,
+        served_stacks,
     )
     from pixie_tpu.ingest.replay import gen_http_events
 
@@ -479,13 +492,23 @@ def main():
             list(served_stacks.batches(data, SMALL_WINDOW, 0, SQL_ROWS)),
             table="stack_traces.beta", scripts=("px/perf_flamegraph",))
 
+    def edges():
+        cfg = {**config("http_edges_1chip"), "requires": {}}
+        data = served_http_edges.make_data(cfg, 4_100_000_019, SQL_ROWS)
+        with open(os.path.join(ROOT, "benchmark", "traffic", "graph_recent",
+                               "service_graph.pxl")) as f:
+            pxl = f.read()
+        return _capture(
+            list(served_http_edges.batches(data, SMALL_WINDOW, 0, SQL_ROWS)),
+            scripts=(pxl,))
+
     cases = {
         "dense": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "keyed": keyed, "flow": flow,
         "digest": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "sql": lambda: sql(3_400_000_019),
         "sql2": lambda: sql(3_400_000_023),
-        "flame": flame,
+        "flame": flame, "edges": edges,
     }
     wanted = args.cases.split(",")
     if "sql" in wanted:
