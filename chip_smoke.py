@@ -748,7 +748,8 @@ def phase_edges(seed: int, rows: int, meter: CompileMeter,
              secs=secs, edges=len(want["key"]), fold=_fold_routes(eng),
              group=_fold_groups(eng), ride=_fold_routes(eng, "ride"),
              digests=[_fold_routes(eng, a) for a in
-                      ("digests", "digest_slots", "digest_bins")],
+                      ("digests", "digest_outputs", "digest_slots",
+                       "digest_bins")],
              compile=compiled, numbers=numbers)
         over = sorted(k for k, v in numbers.items() if v > ref.LIMITS[k])
         assert not over, f"px/service_graph ({run}): over its limit: {over}"
@@ -758,7 +759,9 @@ def phase_edges(seed: int, rows: int, meter: CompileMeter,
     want_fold = "mixed:sorted_int=3,keyed_digest=3"
     assert not on_tpu or _fold_routes(eng) == [want_fold], (
         f"fold spans say {_fold_routes(eng)}, not {want_fold}")
-    assert _fold_routes(eng, "digests") == [3], "no three digests"
+    # The three plucked quantiles of one column share ONE carry.
+    assert _fold_routes(eng, "digests") == [1], "no one shared digest"
+    assert _fold_routes(eng, "digest_outputs") == [3], "no three outputs"
     assert _fold_routes(eng, "digest_bins") == [1 << 32], (
         f"a window's rows were binned: {_fold_routes(eng, 'digest_bins')}")
 

@@ -77,11 +77,14 @@ class FragmentStats:
     # remap_entries``); 0 without one. Beside ``fold`` on a traced
     # fragment's ``compute`` dispatches.
     remap_entries: int = 0
-    # The fold's ``quantiles`` digests (``FoldPlan.digests`` /
-    # ``digest_slots`` / ``digest_bins``); 0 without one. Beside ``fold``.
+    # The fold's ``quantiles`` digests (``FoldPlan.digests``: the carries,
+    # one an argument / ``digest_slots`` / ``digest_bins``, and
+    # ``digest_outputs``: the aggregates that read them); 0 without one.
+    # Beside ``fold``.
     digests: int = 0
     digest_slots: int = 0
     digest_bins: int = 0
+    digest_outputs: int = 0
     # Staging runs on the prefetch thread concurrently with compute on
     # the query thread (pipeline.py), so stage accumulation is locked.
     _lock: threading.Lock = field(

@@ -17,7 +17,6 @@ import numpy as np
 from ..types.batch import HostBatch, bucket_capacity
 from ..types.dtypes import DataType, device_dtypes
 from ..types.strings import NULL_ID, StringDictionary
-from .fold_plan import is_digest
 from .fragment import ColumnMeta, compile_fragment_cached as compile_fragment
 from .joins import learned_capacity
 from .plan import AggOp
@@ -223,13 +222,13 @@ def bridge_payload(engine, res):
                 _remember_climb(engine, res.chain, res.source, "pem", frag)
             if span is not None and frag.plan.digests:
                 # The [slots, K] planes of the ``quantiles`` aggregates
-                # among what ships (``usage.digest_bytes``).
+                # among what ships (``usage.digest_bytes``): one pair a
+                # carry, however many outputs read it.
                 span.attributes["digest_bytes"] = sum(
                     leaf.nbytes
-                    for op in res.chain if isinstance(op, AggOp)
-                    for ae in op.aggs if is_digest(ae.uda_name)
+                    for owner in {own for _o, own in frag.plan.digest_owners}
                     for leaf in jax.tree_util.tree_leaves(
-                        state["carries"][ae.out_name])
+                        state["carries"][owner])
                 )
             return _count_wire(engine, AggStatePayload(
                 chain=tuple(res.chain),

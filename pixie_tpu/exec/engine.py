@@ -1143,6 +1143,7 @@ class Engine:
                 frag.plan.digests, frag.plan.digest_slots,
                 frag.plan.digest_bins,
             )
+            stats.digest_outputs = len(frag.plan.digest_owners)
         # Scan-folding trades W dispatches for one; on the CPU backend
         # dispatches are cheap and the jnp.stack of window planes is a
         # pure memory-bandwidth loss.

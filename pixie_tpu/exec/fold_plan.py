@@ -99,20 +99,44 @@ class FoldPlan:
     max_words: int = 0
     #: The ``quantiles`` aggregates' digests (``digests``, ``digest_slots``
     #: and ``digest_bins`` on the fold programs' ``device.dispatch``
-    #: spans): how many [slots, K] carries the state holds, one's groups x
-    #: centroids, and the width B a window's rows are binned at
-    #: (``routes.digest_bins``: a histogram's or a (slot, bin) sort's
-    #: width, ``KEYED_DIGEST_BINS`` where a sort orders the values
-    #: themselves). 0 without one.
+    #: spans): how many [slots, K] carries the state holds (one an
+    #: argument: the digest aggregates of one argument share theirs,
+    #: ``digest_owners``), one's groups x centroids, and the width B a
+    #: window's rows are binned at (``routes.digest_bins``: a histogram's
+    #: or a (slot, bin) sort's width, ``KEYED_DIGEST_BINS`` where a sort
+    #: orders the values themselves). 0 without one.
     digests: int = 0
     digest_slots: int = 0
     digest_bins: int = 0
+    #: The digest aggregates, each with the output whose name its carry
+    #: is held under in ``state["carries"]``: ((out_name, owner), ...) in
+    #: the AggOp's order (``digest_owners``). An owner is its own; the
+    #: others hold no carry and read the owner's. Their count is the
+    #: spans' ``digest_outputs``.
+    digest_owners: tuple = ()
 
 
 def is_digest(uda_name: str) -> bool:
     """A t-digest aggregate (``udf/builtins/math_sketches.py``):
     ``quantiles`` or one of the planner's ``_quantile_pXX``."""
     return uda_name == "quantiles" or uda_name.startswith("_quantile_")
+
+
+def digest_owners(aggs) -> tuple:
+    """((out_name, owner), ...) of an AggOp's digest aggregates: those of
+    one argument (the fourth field of an ``aggs`` entry: the argument
+    expression's structure, equal where the expressions are) under one
+    cast share ONE carry, held under the first one's name. A digest is
+    its argument's and nobody's point (a ``_quantile_pXX`` differs from
+    ``quantiles`` only in what its read-out asks of it), so the shared
+    state is each of theirs value for value. An entry with no argument
+    field is not known to share and keeps a carry of its own."""
+    first, owners = {}, []
+    for out, uda, types, *arg in aggs:
+        if is_digest(uda):
+            key = (arg[0], types) if arg else (out,)
+            owners.append((out, first.setdefault(key, out)))
+    return tuple(owners)
 
 
 def _int_stat(uda_name: str, arg_types: tuple) -> bool:
@@ -151,7 +175,9 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
     ``group_cols``: ((name, DataType), ...). ``domains``: the columns'
     (size, offset, stride) triples (``_static_key_domains``) or None when
     any is not known at compile time. ``aggs``: ((out_name, uda_name,
-    cast argument types), ...). ``allow_dense`` False is the Kelvin's
+    cast argument types[, the argument expression's structure]), ...);
+    the fourth field is what tells two digests of one argument
+    (``digest_owners``). ``allow_dense`` False is the Kelvin's
     fragment: it merges ids remapped into a dictionary it was not
     compiled against, so it trusts no domain (no dense slot, no packed
     sort word)."""
@@ -193,6 +219,8 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
             return digest_route(platform, g * DIGEST_K)
         return "xla"
 
+    owners = digest_owners(aggs)
+    aggs = tuple(a[:3] for a in aggs)
     routes = {
         out: route(uda, types) for out, uda, types in aggs if uda != "count"
     }
@@ -250,7 +278,7 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
             if r in tally
         )
     )
-    digests = sum(is_digest(uda) for _out, uda, _types in aggs)
+    digests = len({owner for _out, owner in owners})
     return FoldPlan(
         platform=platform,
         layout="dense" if dense else "sorted" if tpu else "hashed",
@@ -270,4 +298,5 @@ def plan_fold(group_cols, domains, aggs, *, max_groups: int,
         digests=digests,
         digest_slots=g * DIGEST_K if digests else 0,
         digest_bins=digest_bins(g, payload_sort) if digests else 0,
+        digest_owners=owners,
     )

@@ -42,9 +42,14 @@ from ..ops.groupby import (
     sorted_slot_ids,
     split_u32,
 )
-from ..ops.tdigest import digest_merge, ordered_batch_to_digest
+from ..ops.tdigest import (
+    digest_merge,
+    digest_quantile,
+    ordered_batch_to_digest,
+)
 from ..types.dtypes import DataType, device_dtypes, pad_values
 from ..types.relation import Relation
+from ..udf.builtins.math_sketches import quantile_points
 from ..udf.registry import Registry
 from ..udf.udf import UDADef, apply_cast
 from .expr import BindError, bind_expr, collect_operands, operands_bound
@@ -88,7 +93,8 @@ class CompiledFragment:
     finalize_state: object = None  # ``finalize`` before jit (the merge tier)
     # Dense fragments whose aggregates are all count/sum/mean/min/max
     # expose the native-fold seam: {"inputs_jit": (cols, valid) ->
-    # (gids, per-agg args, oob), "plan": ((out_name, uda_name, init),...)}.
+    # (gids, an argument a carry, oob), "plan": ((out_name, uda_name,
+    # init), ...) of the aggregates that hold a carry}.
     # The engine's CPU backend runs the scatter passes in the native
     # multi-core kernel (native/seg_fold.cc) — XLA:CPU scatters are
     # single-threaded. None = not eligible.
@@ -1006,9 +1012,10 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     A ``quantiles`` aggregate beside them (route ``keyed_digest``) rides
     nothing: its [g, K] digest is built by a sort of its own under the
     same key words (``ops/tdigest.py`` ``ordered_batch_to_digest``, slot
-    for slot the integer fold's groups, once a distinct argument), and
-    in a merge each side's digests are moved to their groups' new slots
-    (``sorted_slot_ids``, a gather of rows) and merged there."""
+    for slot the integer fold's groups), and in a merge each side's
+    digests are moved to their groups' new slots (``sorted_slot_ids``, a
+    gather of rows) and merged there. ``aggs_bound`` holds the
+    aggregates with a carry: the digests of one argument are one."""
     g = plan.slots
     pack_doms = plan.pack_doms
     folded = pack_doms is not None or plan.lead_id
@@ -1137,21 +1144,19 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
         return planes
 
     def window_digests(key_planes, cols, valid):
-        """{out_name: the window's [g, K] digest}: one sort a distinct
-        argument, under the integer fold's own lead words."""
+        """{out_name: the window's [g, K] digest} of the digests that
+        hold a carry (one a distinct argument): one sort each, under the
+        integer fold's own lead words."""
         lead = lead_words(_key_words(key_planes), valid, folded)
-        built, carries = {}, {}
+        carries = {}
         for ae, _uda, arg_bound, casts in digest_aggs:
-            fkey = (_struct_key(ae.args), casts[0])
-            if fkey not in built:
-                values = jnp.broadcast_to(
-                    apply_cast(arg_bound[0].fn(cols), *casts[0]), valid.shape
+            values = jnp.broadcast_to(
+                apply_cast(arg_bound[0].fn(cols), *casts[0]), valid.shape
+            )
+            with jax.named_scope("keyed_digest"):
+                carries[ae.out_name] = ordered_batch_to_digest(
+                    lead, folded, values, g
                 )
-                with jax.named_scope("keyed_digest"):
-                    built[fkey] = ordered_batch_to_digest(
-                        lead, folded, values, g
-                    )
-            carries[ae.out_name] = built[fkey]
         return carries
 
     def window(cols, valid):
@@ -1392,7 +1397,8 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         tuple((c, rel1.col_type(c)) for c in group_cols),
         _static_key_domains(rel1, dicts1, group_cols, col_stats),
         tuple(
-            (ae.out_name, ae.uda_name, tuple(want for _have, want in casts))
+            (ae.out_name, ae.uda_name, tuple(want for _have, want in casts),
+             _struct_key(ae.args))
             for ae, _uda, _b, casts in aggs_bound
         ),
         max_groups=agg.max_groups,
@@ -1408,11 +1414,20 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         build = _sorted_fold
     else:
         build = _id_fold
-    fold = build(plan, aggs_bound, rel1, key_plane_index)
+    # The aggregates that hold a carry: all but a digest that reads
+    # another's (``fold_plan.digest_owners``: the digests of one argument
+    # share one). The folds, the state and what ships know these alone;
+    # ``finalize`` reads every output off them.
+    owner = dict(plan.digest_owners)  # a digest output -> its carry's name
+    carried = [
+        ab for ab in aggs_bound
+        if owner.get(ab[0].out_name, ab[0].out_name) == ab[0].out_name
+    ]
+    fold = build(plan, carried, rel1, key_plane_index)
 
     def init_state():
         keys = fold.init_keys()
-        carries = {ae.out_name: uda.init(g) for ae, uda, _, _ in aggs_bound}
+        carries = {ae.out_name: uda.init(g) for ae, uda, _, _ in carried}
         return {
             "keys": keys,
             "valid": jnp.zeros(g, dtype=jnp.bool_),
@@ -1518,6 +1533,31 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
 
     apply_post, final_meta, out_rel = _bind_post_stage(post, out_meta, registry)
 
+    # A digest's outputs by the carry they read, each with the points its
+    # read-out asks for: a shared digest is read ONCE, at all of them.
+    readers: dict = {}
+    for ae, uda, _b, _c in aggs_bound:
+        if ae.out_name in owner:
+            readers.setdefault(owner[ae.out_name], []).append(
+                (ae.out_name, quantile_points(ae.uda_name),
+                 bool(uda.struct_fields))
+            )
+
+    def digest_reads(carries):
+        """{out_name: a digest output's plane} ([g], or [g, points] for
+        an unplucked ``quantiles``): a column a point of one
+        ``digest_quantile`` a carry, as ``uda.finalize`` reads each."""
+        reads = {}
+        for own, outs in readers.items():
+            q = digest_quantile(
+                carries[own], tuple(p for _o, pts, _s in outs for p in pts)
+            )
+            at = 0
+            for out, pts, struct in outs:
+                reads[out] = q[:, at:at + len(pts)] if struct else q[:, at]
+                at += len(pts)
+        return reads
+
     def finalize(state):
         cols = {}
         key_planes = fold.key_planes(state)
@@ -1526,8 +1566,10 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 kp for kp, (kc, _i) in zip(key_planes, key_plane_index)
                 if kc == c
             )
+        reads = digest_reads(state["carries"])
         for ae, uda, _, _ in aggs_bound:
-            out = uda.finalize(state["carries"][ae.out_name])
+            out = reads[ae.out_name] if ae.out_name in reads else (
+                uda.finalize(state["carries"][ae.out_name]))
             cols[ae.out_name] = (out,)
         device_cols, valid = apply_post(cols, state["valid"])
         return device_cols, valid, state["overflow"]
@@ -1564,7 +1606,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 plan, rel1, key_plane_index, cols2, valid2
             )
             args = []
-            for ae, _uda, arg_bound, casts in aggs_bound:
+            for ae, _uda, arg_bound, casts in carried:
                 if ae.uda_name == "count":
                     args.append(None)  # count reads no value column
                     continue
@@ -1601,7 +1643,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
                 key_srcs.append(src)
             arg_srcs = []
             if key_srcs is not None:
-                for ae, _uda, _b, _c in aggs_bound:
+                for ae, _uda, _b, _c in carried:
                     if ae.uda_name == "count":
                         arg_srcs.append(None)
                         continue
@@ -1622,7 +1664,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
             "inputs_jit": _program(fold_inputs, operands),
             "plan": tuple(
                 (ae.out_name, ae.uda_name, uda.init)
-                for ae, uda, _b, _c in aggs_bound
+                for ae, uda, _b, _c in carried
             ),
             "raw": raw,
         }

@@ -43,7 +43,10 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   min / ``any``, one an ``any`` of a string, absent at 0; a keyed fold
   that also holds ``quantiles`` reads ``mixed:sorted_int=<n>,
   keyed_digest=<m>``; with a ``quantiles`` aggregate on any layout
-  ``digests``: the digest carries the program holds, ``digest_slots``:
+  ``digests``: the digest carries the program holds (one an argument:
+  the plucked quantiles of one column share theirs,
+  ``exec/fold_plan.py`` ``digest_owners``), ``digest_outputs``: the
+  aggregates that read them, ``digest_slots``:
   groups x centroids of one, and ``digest_bins``: the width B its
   windows' rows are binned at, 2^32 where a sort orders the values
   themselves, ``ops/routes.py`` ``digest_bins``); child of its fragment
@@ -341,7 +344,8 @@ class QueryResourceUsage:
     - ``digest_bytes`` bytes of the ``quantiles`` aggregates' [slots, K]
       digest planes in the partial-agg states the query shipped: the
       ``digest_bytes`` of its ``payload`` spans of kind ``agg_state``
-      (part of ``wire_bytes``; 2 x slots x 128 x 4 B a digest)
+      (part of ``wire_bytes``; 2 x slots x 128 x 4 B a digest, one an
+      argument however many outputs read it)
     - ``skipped_windows`` probe/scan windows never staged (zone maps)
     - ``device_peak_bytes`` high-water device ``bytes_in_use`` observed
       while the query ran (``exec/programs.py`` DeviceMemoryMonitor;
@@ -609,6 +613,7 @@ class TracedFragment(FragmentStats):
                 attrs["remap_entries"] = int(self.remap_entries)
             if self.digests:
                 attrs["digests"] = int(self.digests)
+                attrs["digest_outputs"] = int(self.digest_outputs)
                 attrs["digest_slots"] = int(self.digest_slots)
                 attrs["digest_bins"] = int(self.digest_bins)
         return _FragmentSpanCtx(self, "device.dispatch", attrs, stage=stage)

@@ -20,6 +20,17 @@ QUANTILE_FIELDS = ("p01", "p10", "p25", "p50", "p75", "p90", "p99")
 QUANTILE_POINTS = (0.01, 0.10, 0.25, 0.50, 0.75, 0.90, 0.99)
 
 
+def quantile_points(uda_name: str) -> tuple:
+    """The points a digest aggregate's read-out asks of its digest:
+    ``quantiles``' seven, a ``_quantile_pXX``'s one. The digest itself is
+    the argument's, whichever asks (``exec/fold_plan.py``
+    ``digest_owners``)."""
+    if uda_name == "quantiles":
+        return QUANTILE_POINTS
+    return (QUANTILE_POINTS[
+        QUANTILE_FIELDS.index(uda_name.removeprefix("_quantile_"))],)
+
+
 def register(reg):
     reg.uda(
         "quantiles",

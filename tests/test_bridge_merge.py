@@ -94,9 +94,12 @@ def _agent(rows: dict) -> Engine:
     return eng
 
 
-def _split(layout: str, limit: int = 10_000):
+def _split(layout: str, limit: int = 10_000, plucks=None):
     keys = ("svc", "code") if layout == "keyed" else ("svc",)
-    if layout == "digest":
+    if plucks is not None:
+        aggs = (AggExpr("n", "count", (C("lat"),)),) + tuple(
+            AggExpr(q, f"_quantile_{q}", (C("lat"),)) for q in plucks)
+    elif layout == "digest":
         aggs = (AggExpr("n", "count", (C("lat"),)),
                 AggExpr("p50", "_quantile_p50", (C("lat"),)))
     else:
@@ -203,6 +206,56 @@ def test_merge_equals_union_and_reduce(k, dicts, layout):
     assert calls[0] == 0
     assert _compiles[0] == before
     assert len(kelvin._prepared_merges) == 1
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("layout", ["dense", "keyed"])
+@pytest.mark.parametrize("k", [2, 4])
+def test_a_shared_digest_through_the_bridge(k, layout, platform):
+    """One digest an argument, PEM to Kelvin: three plucked quantiles of
+    one column ship ONE [slots, 128] carry from each of k PEMs with
+    dictionaries of their own (``digest_bytes`` counts its planes once),
+    the Kelvin's fragment of the same chain derives the same owner, and
+    every plucked quantile of the merged answer equals, value for value,
+    the merged answer of the chain with that pluck alone."""
+    all_rows = [_rows(a, "overlapping", seed=3) for a in range(k)]
+    plucks = ("p50", "p90", "p99")
+
+    def served(plucks):
+        split = _split(layout, plucks=plucks)
+        agents = [_agent(r) for r in all_rows]
+        payloads = _payloads(split, agents)
+        got, _trace = _merge(Engine(), split, payloads)
+        return got, payloads, [e.tracer.last() for e in agents]
+
+    with routes_of(platform):
+        got, payloads, traces = served(plucks)
+        for p, trace in zip(payloads, traces):
+            assert set(p.state["carries"]) == {"n", "p50"}
+            planes = p.state["carries"]["p50"]
+            slots = len(p.state["valid"])
+            assert [a.shape for a in planes] == [(slots, 128)] * 2
+            (payload,) = [s for s in trace.spans if s.name == "payload"]
+            assert payload.attributes["digest_bytes"] == (
+                1 * 2 * slots * 128 * 4) == trace.usage.digest_bytes
+            folds = [s.attributes for s in trace.spans
+                     if s.name == "device.dispatch"
+                     and "digests" in s.attributes]
+            # (The CPU's native dense fold dispatches no fold program.)
+            assert folds or (layout, platform) == ("dense", "cpu")
+            assert all(
+                (a["digests"], a["digest_outputs"]) == (1, 3) for a in folds)
+        counts = _reference(all_rows, layout if layout == "keyed" else "digest")
+        assert {key: row[0] for key, row in got.items()} == {
+            key: row[0] for key, row in counts.items()}
+        for at, pluck in enumerate(plucks):
+            alone, _payloads_, _traces = served((pluck,))
+            assert set(alone) == set(got)
+            for key, (n, q) in alone.items():
+                assert got[key][0] == n
+                np.testing.assert_array_equal(
+                    np.float64(got[key][1 + at]), np.float64(q),
+                    err_msg=f"{key} {pluck}")
 
 
 def test_equal_dictionaries_are_not_remapped_and_others_are():

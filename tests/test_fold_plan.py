@@ -8,7 +8,7 @@ import sys
 import pytest
 
 from conftest import routes_of
-from pixie_tpu.exec.fold_plan import FoldPlan, plan_fold
+from pixie_tpu.exec.fold_plan import FoldPlan, digest_owners, plan_fold
 from pixie_tpu.ops.routes import (
     DIGEST_K, F32_FOLD_MAX_GROUPS, INT_FOLD_MAX_GROUPS,
     SORTED_DIGEST_MAX_SLOTS, int_fold_groups,
@@ -17,18 +17,22 @@ from pixie_tpu.types.dtypes import DataType as D
 
 I64, F64, BOOL, T64 = (D.INT64,), (D.FLOAT64,), (D.BOOLEAN,), (D.TIME64NS,)
 SVC, PATH = ("service", D.STRING), ("req_path", D.STRING)
+# An aggregate's fourth field: its argument expression's structure (what
+# ``exec/fragment.py`` ``_struct_key`` gives; any hashable does here).
+LAT, SIZE = ("col", "latency_ns"), ("col", "resp_body_size")
 # px/http_stats' and px/service_stats' aggregates.
 HTTP = (("n", "count", I64), ("lat_mean", "mean", I64), ("lat_max", "max", I64))
 SERVICE = (("err", "mean", BOOL), ("n", "count", I64),
-           ("p50", "_quantile_p50", F64), ("p99", "_quantile_p99", F64))
+           ("p50", "_quantile_p50", F64, LAT),
+           ("p99", "_quantile_p99", F64, LAT))
 DENSE_2145 = [(33, 0, 1), (65, 0, 1)]  # the dense cells' dictionaries
 KEYED = [(33, 0, 1), (65_537, 0, 1)]  # http_full_1chip's: over the limit
 # http_edges_1chip's service graph (benchmark/traffic/graph_recent).
 EDGE_KEYS = (("remote_addr", D.STRING), ("pod", D.STRING), SVC)
 EDGE_DOMAINS = [(8_193, 0, 1), (4_097, 0, 1), (33, 0, 1)]
-EDGES = (("latency_p50", "_quantile_p50", F64),
-         ("latency_p90", "_quantile_p90", F64),
-         ("latency_p99", "_quantile_p99", F64),
+EDGES = (("latency_p50", "_quantile_p50", F64, LAT),
+         ("latency_p90", "_quantile_p90", F64, LAT),
+         ("latency_p99", "_quantile_p99", F64, LAT),
          ("error_rate", "mean", BOOL), ("throughput_total", "count", I64),
          ("outbound_bytes_total", "sum", I64))
 
@@ -278,24 +282,29 @@ def test_the_words_of_the_maxima_the_sort_carries(aggs, words):
 
 
 @pytest.mark.parametrize("platform,groups,allow_dense,want", [
-    # The cell's fold: three carries of 2^17 x 128 slots, rows ordered by
-    # their values (no histogram), on the PEM and on the Kelvin.
-    ("tpu", 1 << 17, True, (3, 1 << 24, 1 << 32)),
-    ("tpu", 1 << 17, False, (3, 1 << 24, 1 << 32)),
+    # The cell's fold: ONE carry of 2^17 x 128 slots for the three plucked
+    # quantiles of one column, rows ordered by their values (no
+    # histogram), on the PEM and on the Kelvin.
+    ("tpu", 1 << 17, True, (1, 1 << 24, 1 << 32)),
+    ("tpu", 1 << 17, False, (1, 1 << 24, 1 << 32)),
     # The CPU's id form at that size bins nothing either: a histogram
     # would be 256 bins wide.
-    ("cpu", 1 << 17, True, (3, 1 << 24, 1 << 32)),
+    ("cpu", 1 << 17, True, (1, 1 << 24, 1 << 32)),
     # Small enough for a histogram: 8,192 bins to 4,096 groups, 4,096 at
     # 8,192.
-    ("cpu", 4096, True, (3, 4096 * 128, 8192)),
-    ("cpu", 8192, True, (3, 8192 * 128, 4096)),
+    ("cpu", 4096, True, (1, 4096 * 128, 8192)),
+    ("cpu", 8192, True, (1, 8192 * 128, 4096)),
 ])
 def test_the_digests_of_a_keyed_fold(platform, groups, allow_dense, want):
-    """``digests`` / ``digest_slots`` / ``digest_bins`` (the dispatch
-    span's attributes), and which aggregates ride the sort."""
+    """``digests`` (the carries: one an argument) / ``digest_slots`` /
+    ``digest_bins`` (the dispatch span's attributes), the outputs that
+    read them, and which aggregates ride the sort."""
     plan = _plan(EDGE_KEYS, EDGE_DOMAINS, EDGES, platform,
                  max_groups=groups, allow_dense=allow_dense)
     assert (plan.digests, plan.digest_slots, plan.digest_bins) == want
+    assert plan.digest_owners == tuple(
+        (out, "latency_p50")
+        for out in ("latency_p50", "latency_p90", "latency_p99"))
     if platform == "tpu":
         assert _routes(plan) == ("keyed_digest",) * 3 + ("sorted_int",) * 3
     else:
@@ -305,8 +314,153 @@ def test_the_digests_of_a_keyed_fold(platform, groups, allow_dense, want):
 def test_a_dense_fold_says_its_digests_too():
     plan = _plan((SVC,), [(33, 0, 1)], SERVICE, "tpu")
     assert (plan.digests, plan.digest_slots, plan.digest_bins) == (
-        2, 33 * DIGEST_K, 8192)
-    assert _plan((SVC, PATH), KEYED, HTTP, "tpu").digests == 0
+        1, 33 * DIGEST_K, 8192)
+    assert plan.digest_owners == (("p50", "p50"), ("p99", "p50"))
+    none = _plan((SVC, PATH), KEYED, HTTP, "tpu")
+    assert (none.digests, none.digest_owners) == (0, ())
+
+
+def _pluck(point, arg, types=F64):
+    return (point, f"_quantile_{point}", types, arg)
+
+
+# (id, the digest aggregates, the carries: {owner: its readers}).
+OWNER_CASES = [
+    ("one_pluck", (_pluck("p50", LAT),), {"p50": ("p50",)}),
+    ("two_plucks", (_pluck("p50", LAT), _pluck("p99", LAT)),
+     {"p50": ("p50", "p99")}),
+    ("three_plucks",
+     (_pluck("p50", LAT), _pluck("p90", LAT), _pluck("p99", LAT)),
+     {"p50": ("p50", "p90", "p99")}),
+    # The first of an argument owns, whichever kind it is.
+    ("unplucked_beside_plucks",
+     (_pluck("p50", LAT), ("q", "quantiles", F64, LAT), _pluck("p99", LAT)),
+     {"p50": ("p50", "q", "p99")}),
+    ("unplucked_first",
+     (("q", "quantiles", F64, LAT), _pluck("p99", LAT)),
+     {"q": ("q", "p99")}),
+    ("two_arguments",
+     (_pluck("p50", LAT), ("size_p50", "_quantile_p50", F64, SIZE),
+      _pluck("p99", LAT), ("size_p99", "_quantile_p99", F64, SIZE)),
+     {"p50": ("p50", "p99"), "size_p50": ("size_p50", "size_p99")}),
+    ("one_argument_two_casts",
+     (_pluck("p50", LAT), _pluck("p99", LAT, I64)),
+     {"p50": ("p50",), "p99": ("p99",)}),
+    # No argument field: not known to share, a carry each.
+    ("arguments_not_told",
+     (("p50", "_quantile_p50", F64), ("p99", "_quantile_p99", F64)),
+     {"p50": ("p50",), "p99": ("p99",)}),
+]
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize(
+    "digests,carries", [c[1:] for c in OWNER_CASES],
+    ids=[c[0] for c in OWNER_CASES])
+def test_the_digests_of_one_argument_share_a_carry(digests, carries, platform):
+    """The owner record (``digest_owners``, decided beside ``plan_fold``
+    from the AggOp alone): the digests of one argument under one cast
+    hold ONE carry under the first one's name; ``digests`` counts the
+    carries; the routes stay one an output."""
+    aggs = (("n", "count", I64),) + digests + (("s", "sum", I64),)
+    want = tuple((out, own) for own, outs in carries.items() for out in outs)
+    assert sorted(digest_owners(aggs)) == sorted(want)
+    for keys, domains in (((SVC,), [(33, 0, 1)]), ((SVC, PATH), KEYED)):
+        plan = _plan(keys, domains, aggs, platform)
+        assert sorted(plan.digest_owners) == sorted(want)
+        assert [o for o, _own in plan.digest_owners] == [
+            a[0] for a in digests]  # the AggOp's order
+        assert plan.digests == len(carries)
+        assert plan.digest_slots == plan.slots * DIGEST_K
+        assert len(plan.routes) == len(aggs)
+        reads = {out for out, own in plan.digest_owners if out != own}
+        assert {a[0] for a in aggs} - reads == {"n", "s"} | set(carries)
+        n = len(digests)
+        if platform == "tpu" and len(keys) == 2:
+            assert plan.fold == f"mixed:sorted_int=2,keyed_digest={n}"
+
+
+def _compiled(aggs, keys=("service",), platform="tpu", **kw):
+    import pixie_tpu  # noqa: F401
+    from pixie_tpu.exec.fragment import compile_fragment
+    from pixie_tpu.exec.plan import AggExpr, AggOp
+    from pixie_tpu.types.relation import Relation
+    from pixie_tpu.types.strings import StringDictionary
+    from pixie_tpu.udf.registry import default_registry
+
+    rel = Relation([("latency_ns", D.INT64), ("resp_body_size", D.INT64),
+                    ("ratio", D.FLOAT64), ("service", D.STRING),
+                    ("req_path", D.STRING)])
+    dicts = {"service": StringDictionary(f"s{i}" for i in range(32)),
+             "req_path": StringDictionary(f"p{i}" for i in range(70_000))}
+    with routes_of(platform):
+        return compile_fragment(
+            [AggOp(tuple(keys), tuple(AggExpr(*a) for a in aggs),
+                   max_groups=256)],
+            rel, dicts, default_registry(), **kw)
+
+
+def _exprs():
+    from pixie_tpu.exec.plan import ColumnRef, FuncCall, Literal
+
+    lat, size = ColumnRef("latency_ns"), ColumnRef("resp_body_size")
+
+    def ms(col):
+        return FuncCall("divide", (col, Literal(1_000_000, D.INT64)))
+
+    return lat, size, ms
+
+
+# (id, aggregates as (out, uda, argument), the state's digest carries).
+FRAGMENT_CASES = [
+    ("three_plucks_of_a_column",
+     lambda lat, size, ms: (("p50", "_quantile_p50", (lat,)),
+                            ("n", "count", (lat,)),
+                            ("p90", "_quantile_p90", (lat,)),
+                            ("p99", "_quantile_p99", (lat,))),
+     {"p50"}),
+    ("an_expression_spelled_twice",
+     lambda lat, size, ms: (("p50", "_quantile_p50", (ms(lat),)),
+                            ("p99", "_quantile_p99", (ms(lat),)),
+                            ("raw", "_quantile_p99", (lat,))),
+     {"p50", "raw"}),
+    ("two_columns",
+     lambda lat, size, ms: (("p50", "_quantile_p50", (lat,)),
+                            ("s50", "_quantile_p50", (size,)),
+                            ("p99", "_quantile_p99", (lat,)),
+                            ("q", "quantiles", (size,))),
+     {"p50", "s50"}),
+]
+
+
+@pytest.mark.parametrize("layout", ["dense", "sorted", "kelvin", "hashed"])
+@pytest.mark.parametrize(
+    "build,owners", [c[1:] for c in FRAGMENT_CASES],
+    ids=[c[0] for c in FRAGMENT_CASES])
+def test_a_fragments_state_holds_one_digest_an_argument(build, owners, layout):
+    """Through ``compile_fragment``: the argument's structure is read
+    off the AggOp's expressions, the state holds the owners' carries
+    and no reader's, and the PEM's and the Kelvin's fragment of one
+    chain derive the same owners."""
+    aggs = build(*_exprs())
+    keys, platform, kw = {
+        "dense": (("service",), "tpu", {}),
+        "sorted": (("service", "req_path"), "tpu", {}),
+        "kelvin": (("service", "req_path"), "tpu", {"allow_dense": False}),
+        "hashed": (("service", "req_path"), "cpu", {}),
+    }[layout]
+    frag = _compiled(aggs, keys, platform, **kw)
+    plan = frag.plan
+    assert plan.layout == layout.replace("kelvin", "sorted")
+    digests = [a[0] for a in aggs if a[1] != "count"]
+    assert [o for o, _own in plan.digest_owners] == digests
+    assert {own for _o, own in plan.digest_owners} == owners
+    assert plan.digests == len(owners)
+    carries = frag.init_state()["carries"]
+    assert set(carries) == owners | {a[0] for a in aggs if a[1] == "count"}
+    for own in owners:
+        means, weights = carries[own]
+        assert means.shape == weights.shape == (plan.slots, DIGEST_K)
 
 
 def test_the_record_is_frozen_and_names_its_platform():

@@ -283,7 +283,8 @@ def test_the_docs_and_the_docstring_list_every_span_name():
 
 #: What a ``quantiles`` fold says of its digests (PR 41): on its fold
 #: programs' ``device.dispatch`` and on the shipped state's ``payload``.
-DIGEST_ATTRIBUTES = ("digests", "digest_slots", "digest_bins")
+DIGEST_ATTRIBUTES = ("digests", "digest_outputs", "digest_slots",
+                     "digest_bins")
 
 
 def test_the_docs_and_the_docstring_name_the_digests_attributes():
@@ -297,22 +298,24 @@ def test_the_docs_and_the_docstring_name_the_digests_attributes():
 
 
 def test_a_served_quantiles_fold_says_its_digests(rehearsed_names):
-    """The service graph's fold dispatches on the PEM carry the three
-    attributes, its shipped state's ``payload`` the digests' bytes, and
-    the usage record sums them; a script without ``quantiles`` carries
-    none of them."""
+    """The service graph's fold dispatches on the PEM carry the four
+    attributes (ONE carry for the three plucked quantiles of one
+    column), its shipped state's ``payload`` the digest's bytes, counted
+    once, and the usage record sums them; a script without ``quantiles``
+    carries none of them."""
     spans = rehearsed_names["http_edges_1chip.graph_recent"]
     pem = spans["pem"][-1]
     folds = [s.attributes for s in _named(pem, "device.dispatch")
              if "fold" in s.attributes]
     assert folds
     for a in folds:
-        assert a["digests"] == 3 and a["digest_slots"] == a["slots"] * 128
+        assert (a["digests"], a["digest_outputs"]) == (1, 3)
+        assert a["digest_slots"] == a["slots"] * 128
         assert a["digest_bins"] in (8192, 4096, 1 << 32)
     (payload,) = _named(pem, "payload")
     assert payload.attributes["kind"] == "agg_state"
     assert payload.attributes["digest_bytes"] == (
-        3 * 2 * folds[0]["digest_slots"] * 4) == pem.usage.digest_bytes
+        1 * 2 * folds[0]["digest_slots"] * 4) == pem.usage.digest_bytes
     assert 0 < pem.usage.digest_bytes < pem.usage.wire_bytes
     assert spans["kelvin"][-1].usage.digest_bytes == 0
     for t in rehearsed_names["sql_stats_1chip.sql_recent"]["pem"]:

@@ -288,14 +288,14 @@ def _edge_rows(rng, rows, late_share=0.0):
     }
 
 
-def _graph_plan(max_groups=4096):
+def _graph_plan(max_groups=4096, plucks=("p50", "p99"), more=()):
     p = Plan()
     src = p.add(MemorySourceOp(table="t"))
     agg = p.add(AggOp(
         ("remote_addr", "pod", "service"),
-        (AggExpr("p50", "_quantile_p50", (C("latency_ns"),)),
-         AggExpr("p99", "_quantile_p99", (C("latency_ns"),)),
-         AggExpr("error_rate", "mean", (C("failure"),)),
+        tuple(AggExpr(q, f"_quantile_{q}", (C("latency_ns"),))
+              for q in plucks) + tuple(more) +
+        (AggExpr("error_rate", "mean", (C("failure"),)),
          AggExpr("n", "count", (C("latency_ns"),)),
          AggExpr("bytes", "sum", (C("resp_body_size"),))),
         max_groups=max_groups,
@@ -325,7 +325,9 @@ def test_the_service_graph_through_an_engine(platform, late_share):
         trace = eng.tracer.last()
     folds = [s.attributes for s in trace.spans
              if s.name == "device.dispatch" and "fold" in s.attributes]
-    assert folds and all(a["digests"] == 2 for a in folds)
+    # ONE carry for the two plucked quantiles of one column.
+    assert folds and all(
+        (a["digests"], a["digest_outputs"]) == (1, 2) for a in folds)
     assert all(a["digest_slots"] == 4096 * K for a in folds)
     if platform == "tpu":
         assert {a["fold"] for a in folds} == {
@@ -352,6 +354,58 @@ def test_the_service_graph_through_an_engine(platform, late_share):
             under, at_or_under = np.mean(v < est), np.mean(v <= est)
             slack = (1.0 if platform == "tpu" else 2.0) / len(v) + 0.01
             assert under - slack <= q <= at_or_under + slack, (k, col, est, v)
+
+
+_ALL = ("p50", "p90", "p99")
+
+
+def _graph_answer(platform, plucks, more=()):
+    """{edge: row} of the service graph's AggOp with these plucks,
+    through an engine of six windows, and its fold dispatches."""
+    rows = _edge_rows(np.random.default_rng(4242), 6000, 0.5)
+    with routes_of(platform), override_flag("cpu_fold_threads", 1), \
+            override_flag("dense_domain_limit", 64):
+        eng = Engine(window_rows=1 << 10)
+        eng.append_data("t", rows)
+        out = eng.execute_plan(
+            _graph_plan(plucks=plucks, more=more))["output"].to_pydict()
+        trace = eng.tracer.last()
+    folds = [s.attributes for s in trace.spans
+             if s.name == "device.dispatch" and "fold" in s.attributes]
+    cols = [c for c in out if c not in ("remote_addr", "pod", "service")]
+    return {
+        k: {c: out[c][i] for c in cols}
+        for i, k in enumerate(zip(out["remote_addr"], out["pod"],
+                                  out["service"]))
+    }, folds
+
+
+_shared_answers = {}
+
+
+@pytest.mark.parametrize("pluck", _ALL)
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_a_pluck_beside_the_others_answers_as_alone(platform, pluck):
+    """One digest an argument through an engine: the three plucks (and
+    a second column's, with a carry of its own) in one AggOp against the
+    AggOp with this pluck alone, VALUE FOR VALUE on every edge; the
+    span says how many carries and how many outputs."""
+    if platform not in _shared_answers:
+        _shared_answers[platform] = _graph_answer(
+            platform, _ALL,
+            more=(AggExpr("size_p50", "_quantile_p50",
+                          (C("resp_body_size"),)),))
+    shared, folds = _shared_answers[platform]
+    assert folds and all(
+        (a["digests"], a["digest_outputs"]) == (2, 4) for a in folds)
+    alone, a_folds = _graph_answer(platform, (pluck,))
+    assert all((a["digests"], a["digest_outputs"]) == (1, 1)
+               for a in a_folds)
+    assert set(alone) == set(shared) and len(alone) > 300
+    for k, row in alone.items():
+        for c in ("n", "bytes", "error_rate", pluck):
+            np.testing.assert_array_equal(
+                np.float64(shared[k][c]), np.float64(row[c]), err_msg=str(k))
 
 
 def test_the_chip_smokes_edges_phase_rehearses():

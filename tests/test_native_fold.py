@@ -289,6 +289,47 @@ class TestNativeDigestFold:
             m = sc == svcs.index(s)
             assert lat[m].min() <= p50 <= lat[m].max()
 
+    @pytest.mark.parametrize("pluck", ["p50", "p99"])
+    def test_plucks_of_one_column_share_one_histogram(self, pluck,
+                                                      monkeypatch):
+        """One digest an argument on the native path: two plucks of one
+        column accumulate ONE dual histogram a window (a carry, not an
+        output), and each answers value for value what it answers
+        plucked alone."""
+        from pixie_tpu import native
+
+        calls = []
+        real = native.tdigest_hist_call
+
+        def counted(*a):
+            calls.append(1)
+            return real(*a)
+
+        monkeypatch.setattr(native, "tdigest_hist_call", counted)
+
+        def run(fields):
+            eng, _cols, _svcs = _mk_engine(n=30_000, seed=7)
+            del calls[:]
+            got = eng.execute_query(
+                "import px\ndf = px.DataFrame(table='t')\n"
+                "out = df.groupby('svc').agg(p=('lat', px.quantiles),"
+                " n=('lat', px.count))\n"
+                + "".join(f"out.{f} = px.pluck_float64(out.p, '{f}')\n"
+                          for f in fields)
+                + f"out = out[{['svc', 'n', *fields]!r}]\npx.display(out)"
+            )["output"].to_pydict()
+            folds = [s.attributes for s in eng.tracer.last().spans
+                     if s.name == "device.dispatch"
+                     and "digests" in s.attributes]
+            return got, len(calls), folds
+
+        both, hist_both, _f = run(["p50", "p99"])
+        alone, hist_alone, _f = run([pluck])
+        assert hist_both == hist_alone > 0
+        ob, oa = np.argsort(both["svc"]), np.argsort(alone["svc"])
+        assert np.array_equal(both["n"][ob], alone["n"][oa])
+        np.testing.assert_array_equal(both[pluck][ob], alone[pluck][oa])
+
     @pytest.mark.slow
     def test_windowed_quantiles_script_path(self):
         """service_let-style windowed quantiles run through the digest

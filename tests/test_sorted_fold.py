@@ -31,6 +31,7 @@ from pixie_tpu.ops.routes import sorted_fold_ride
 from pixie_tpu.types.dtypes import DataType
 from pixie_tpu.types.relation import Relation
 from pixie_tpu.types.strings import NULL_ID, StringDictionary
+from pixie_tpu.udf.builtins.math_sketches import QUANTILE_FIELDS
 from pixie_tpu.udf.registry import default_registry
 
 I64 = np.iinfo(np.int64)
@@ -618,6 +619,109 @@ def test_a_quantiles_beside_them_leaves_the_integers_on_the_sort():
     assert frag.group == "sorted" and frag.plan.payload_sort
     assert (frag.plan.digests, frag.plan.digest_slots,
             frag.plan.digest_bins) == (1, 256 * 128, 1 << 32)
+
+
+# -- one digest an argument: the plucked quantiles of one column share a
+# carry, and each answers what it answers computed alone -----------------------
+
+PLUCKS = (("p50", "_quantile_p50", "lat"), ("p90", "_quantile_p90", "lat"),
+          ("p99", "_quantile_p99", "lat"))
+#: (keys, platform, allow_dense, an aggregate beside them, layout, fold).
+SHARE_FORMS = {
+    "dense_tpu": (("svc",), "tpu", True, (), "dense",
+                  "mixed:pallas_int=2,sorted_digest=3"),
+    "dense_cpu": (("svc",), "cpu", True, (), "dense", "xla"),
+    "keyed_pem": (("svc", "path"), "tpu", True, (), "sorted",
+                  "mixed:sorted_int=2,keyed_digest=3"),
+    "keyed_kelvin": (("svc", "path"), "tpu", False, (), "sorted",
+                     "mixed:sorted_int=2,keyed_digest=3"),
+    # A FLOAT64 sum needs row ids: the id form, sorted and hashed.
+    "ids_tpu": (("svc", "path"), "tpu", True, (("x", "sum", "ratio"),),
+                "sorted", "mixed:sorted_digest=3,xla=3"),
+    "ids_cpu": (("svc", "path"), "cpu", True, (), "hashed", "xla"),
+}
+_BESIDE = (("n", "count", "lat"), ("m", "mean", "lat"))
+
+
+def _share_frag(form, plucks):
+    keys, platform, allow_dense, more, _layout, _fold = SHARE_FORMS[form]
+    return _frag(keys, _BESIDE + plucks, 1_024, platform, extra=more,
+                 allow_dense=allow_dense)
+
+
+def _share_fold(frag, platform, fold, **kw):
+    with routes_of(platform):
+        cols, valid, overflow = fold(frag, **kw)
+    assert not overflow
+    return cols, valid
+
+
+def _merged(frag, table, k):
+    """k windows folded apart, then merged: a fold of k - 1 merges."""
+    states = [
+        frag.window_state(cols, (jnp.int32(0), jnp.int32(rows)))
+        for cols, rows in _windows(table, k)
+    ]
+    acc = states[0]
+    for s in states[1:]:
+        acc = frag.merge_states(acc, s)
+    cols, valid, overflow = jax.device_get(frag.finalize(acc))
+    return cols, valid, bool(overflow)
+
+
+def _assert_each_pluck_is_its_own(form, fold):
+    """The AggOp with the three plucks (and an unplucked ``quantiles``
+    of the same column) against the same AggOp with each pluck alone:
+    equal VALUE FOR VALUE, group for group (the shared state is each of
+    theirs bit for bit, and one read-out at all the points reads each
+    point as a read-out of its own does)."""
+    keys, platform, _ad, _more, layout, label = SHARE_FORMS[form]
+    shared = _share_frag(form, PLUCKS + (("q", "quantiles", "lat"),))
+    assert shared.plan.layout == layout
+    assert shared.plan.digests == 1
+    assert len(shared.plan.digest_owners) == 4
+    assert set(shared.init_state()["carries"]) >= {"n", "m", "p50"}
+    assert not {"p90", "p99", "q"} & set(shared.init_state()["carries"])
+    three = _share_frag(form, PLUCKS)
+    assert three.fold == label and three.plan.digests == 1
+    cols, valid = _share_fold(shared, platform, fold)
+    got = _by_key(cols, valid, keys, ("n", "m", "p50", "p90", "p99"))
+    struct = dict(zip(
+        _by_key(cols, valid, keys, ("n",)),
+        np.asarray(cols["q"][0])[np.flatnonzero(valid)]))
+    assert len(got) >= 6
+    for at, pluck in enumerate(PLUCKS):
+        alone = _share_frag(form, (pluck,))
+        assert alone.plan.digests == 1
+        assert set(alone.init_state()["carries"]) == (
+            {"n", "m", pluck[0]} | ({"x"} if form == "ids_tpu" else set()))
+        a_cols, a_valid = _share_fold(alone, platform, fold)
+        want = _by_key(a_cols, a_valid, keys, ("n", "m", pluck[0]))
+        assert set(want) == set(got)
+        for k, (n, m, q) in want.items():
+            assert got[k][:2] == (n, m)
+            np.testing.assert_array_equal(
+                np.float32(got[k][2 + at]), np.float32(q), err_msg=str(k))
+            # The unplucked struct's column of that point, too.
+            field = QUANTILE_FIELDS.index(pluck[0])
+            np.testing.assert_array_equal(
+                np.float32(struct[k][field]), np.float32(q))
+
+
+@pytest.mark.parametrize("n_windows,scan", [(1, False), (3, True), (5, False)])
+@pytest.mark.parametrize("form", list(SHARE_FORMS))
+def test_plucks_that_share_a_digest_answer_as_alone(form, n_windows, scan):
+    table = _table(seed=5)
+    _assert_each_pluck_is_its_own(
+        form, lambda frag: _fold(frag, table, n_windows, scan))
+
+
+@pytest.mark.parametrize("k", [2, 4])
+@pytest.mark.parametrize("form", list(SHARE_FORMS))
+def test_merged_states_that_share_a_digest_answer_as_alone(form, k):
+    table = _table(seed=6)
+    _assert_each_pluck_is_its_own(
+        form, lambda frag: _merged(frag, table, k))
 
 
 @pytest.mark.parametrize("allow_dense", [True, False],

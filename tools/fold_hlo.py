@@ -103,8 +103,9 @@ FLAME_JOIN = (1 << 12, 1 << 20, 1 << 21)
 FLAME_WINDOWS = 2  # the windows ``-5m`` holds
 #: The ``edges`` case's shapes, as ``FLOW``'s:
 #: ``http_edges_1chip.graph_recent`` settles on 2^17 slots for its 80 k
-#: (remote_addr, pod, service) edges (three [2^17, 128] digests beside
-#: the integer carries), merged in a 2^17 bucket; three windows in range.
+#: (remote_addr, pod, service) edges (ONE [2^17, 128] digest, shared by
+#: the three plucked quantiles of the one column, beside the integer
+#: carries), merged in a 2^17 bucket; three windows in range.
 EDGES = {
     "mean+count+sum+_quantile_p50+_quantile_p90+_quantile_p99"
     "_by_remote_addr_pod_service": (1 << 17, 1 << 17, WINDOW),
