@@ -44,7 +44,7 @@ from .bridge import (  # noqa: F401  (re-exported)
 )
 from . import threadmap
 from .fragment import compile_fragment_cached as compile_fragment
-from .fragment import window_rows
+from .fragment import RowSlice, window_rows
 from .pipeline import WindowPipeline
 from .trace import Span, Tracer, clock_ns, plan_script
 from .joins import (  # noqa: F401  (re-exported)
@@ -103,6 +103,7 @@ from .stream import (  # noqa: F401  (re-exported)
     _stream_col_stats,
     _timed,
     _to_host_batch,
+    _fold_rows,
     _window_shapes,
 )
 
@@ -1157,38 +1158,58 @@ class Engine:
         )
         pend_cols, pend_lo, pend_hi = [], [], []
 
-        def note_ride(span, cols):
-            # How a keyed sorted fold carries this window's sums, and the
+        def note_ride(span, rows):
+            # How a keyed sorted fold carries these rows' sums, and the
             # words of the maxima it sorts by (an ``any``'s among them),
             # on a traced fragment's ``device.dispatch``.
             if frag.ride is not None and isinstance(span, Span):
-                way = frag.ride(window_rows(cols))
+                way = frag.ride(rows)
                 if way:
                     span.attributes["ride"] = way
                 if frag.plan.max_words:
                     span.attributes["max_words"] = frag.plan.max_words
 
+        def fold_resident(state, windows, los, his):
+            """ONE program over a run of resident windows of one shape
+            (``update`` of one, ``update_all`` of several), each with
+            rows [lo, hi) in range. The program is handed ``rows`` rows
+            of each window, a ``RowSlice`` from ``lo`` (or as far back
+            as the window's end demands): the length ``_fold_rows``
+            gives the run's longest range, so a range short against its
+            window does not pay for the padding; whole windows (a full
+            one in the run, the mesh step) go as they always went."""
+            one = len(windows) == 1
+            program = agg_step if one else frag.update_all
+            capacity = window_rows(windows[0])
+            rows = max(
+                _fold_rows(capacity, hi - lo) for lo, hi in zip(los, his)
+            ) if self.slice_windows else capacity
+            starts = [min(lo, capacity - rows) for lo in los]
+            # Host ints, not device buffers: no sync.
+            starts, los, his = (
+                np.asarray(v, dtype=np.int32)  # pxlint: disable=host-sync-hot-path
+                for v in (starts, np.subtract(los, starts),
+                          np.subtract(his, starts))
+            )
+            with _dispatch(stats, program, windows=len(windows)) as span:
+                if isinstance(span, Span):
+                    span.attributes["rows"] = rows * len(windows)
+                    span.attributes["range_rows"] = int(his.sum() - los.sum())
+                note_ride(span, rows)
+                if one:
+                    args = (windows[0], (los[0], his[0]))
+                    starts = starts[0]
+                else:
+                    args = (tuple(windows), los, his)
+                if rows < capacity:
+                    args += (RowSlice(starts, rows),)
+                state = program(state, *args)
+                _block_if(stats, state)
+            return state
+
         def flush_pending(state):
-            if not pend_cols:
-                return state
-            if len(pend_cols) == 1:
-                with _dispatch(stats, agg_step) as span:
-                    note_ride(span, pend_cols[0])
-                    state = agg_step(
-                        state, pend_cols[0], (pend_lo[0], pend_hi[0])
-                    )
-                    _block_if(stats, state)
-            else:
-                with _dispatch(stats, frag.update_all,
-                               windows=len(pend_cols)) as span:
-                    note_ride(span, pend_cols[0])
-                    state = frag.update_all(
-                        state, tuple(pend_cols),
-                        # Host int lists, not device buffers — no sync.
-                        np.asarray(pend_lo, dtype=np.int32),  # pxlint: disable=host-sync-hot-path
-                        np.asarray(pend_hi, dtype=np.int32),  # pxlint: disable=host-sync-hot-path
-                    )
-                    _block_if(stats, state)
+            if pend_cols:
+                state = fold_resident(state, pend_cols, pend_lo, pend_hi)
             pend_cols.clear()
             pend_lo.clear()
             pend_hi.clear()
@@ -1197,9 +1218,10 @@ class Engine:
         pipe = self._window_pipeline(stream, stats)
         try:
             for cols, valid in pipe:
+                resident = isinstance(valid, tuple)
                 batchable = (
                     chunk_w > 1
-                    and isinstance(valid, tuple)
+                    and resident
                     and (
                         not pend_cols
                         or _window_shapes(cols) == _window_shapes(pend_cols[0])
@@ -1209,14 +1231,19 @@ class Engine:
                 # runs for it yet); each enqueue is one device.dispatch.
                 if batchable:
                     pend_cols.append(cols)
-                    pend_lo.append(valid[0])
-                    pend_hi.append(valid[1])
+                    pend_lo.append(int(valid[0]))
+                    pend_hi.append(int(valid[1]))
                     if len(pend_cols) >= chunk_w:
                         state = flush_pending(state)
+                elif resident:
+                    state = fold_resident(
+                        flush_pending(state), [cols],
+                        [int(valid[0])], [int(valid[1])],
+                    )
                 else:
                     state = flush_pending(state)
                     with _dispatch(stats, agg_step) as span:
-                        note_ride(span, cols)
+                        note_ride(span, window_rows(cols))
                         state = agg_step(state, cols, valid)
                         _block_if(stats, state)
                 if stats is not None:
@@ -1459,6 +1486,11 @@ class Engine:
     # TPU scan-fold window batching (update_all); DistributedEngine turns
     # it off for the same reason — update_all is not a distributed step.
     scan_fold = True
+    # Whether a fold program is handed the rows in range of a resident
+    # window (``fragment.RowSlice``) and not its padded capacity;
+    # DistributedEngine turns it off: its windows are row-sharded over
+    # the mesh, and a slice at a dynamic offset would reshard them.
+    slice_windows = True
     # The joint-key sketch before a keyed aggregate's first fold
     # (_sized_agg_fragment): a plain jit a window, which DistributedEngine
     # turns off too (its windows are row-sharded over the mesh).

@@ -363,6 +363,44 @@ def window_rows(cols) -> int:
     return next(p for c, p in cols.items() if c != "__side__")[0].shape[0]
 
 
+@jax.tree_util.register_pytree_node_class
+class RowSlice:
+    """Where a fold program cuts a resident window's planes before it
+    folds them: ``rows`` rows of each plane from row ``start`` (a scalar;
+    a vector, one a window, under ``update_all``). ``start`` is an
+    argument of the program (no retrace an offset); ``rows`` is part of
+    the tree's structure, so a program is traced once a length. The
+    (lo, hi) pair beside it counts from ``start``. The engine names the
+    slice (``exec/engine.py`` ``_fold_agg_state``): the rows in range of
+    a window padded to its capacity."""
+
+    __slots__ = ("start", "rows")
+
+    def __init__(self, start, rows: int):
+        self.start, self.rows = start, rows
+
+    def tree_flatten(self):
+        return (self.start,), self.rows
+
+    @classmethod
+    def tree_unflatten(cls, rows, children):
+        return cls(children[0], rows)
+
+
+def _sliced(cols, at):
+    """``cols`` with every window plane cut to the rows ``at`` names
+    (a ``RowSlice``; None = whole), inside the program that folds them."""
+    if at is None:
+        return cols
+    return {
+        c: planes if c == "__side__" else tuple(
+            jax.lax.dynamic_slice_in_dim(p, at.start, at.rows)
+            for p in planes
+        )
+        for c, planes in cols.items()
+    }
+
+
 def _range_valid(cols, valid):
     """Materialize ``valid`` when it arrives as a (lo, hi) row-range pair
     (device-resident windows carry no mask; rather than a separate
@@ -1457,31 +1495,36 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
     # ``update``, ``update_all``, ``group_sketch`` and ``finalize`` become
     # programs at the end, once every expression of the chain is bound
     # and the operand tables they read are known (``_program``).
-    def update(state, cols, valid):
+    def update(state, cols, valid, at=None):
+        cols = _sliced(cols, at)
         valid = _range_valid(cols, valid)
         cols, valid = apply_pre(cols, valid)
         if fold.absorb is not None:
             return fold.absorb(state, cols, valid)
         return fold.merge(state, fold.window(cols, valid))
 
-    def update_all(state, cols_list, los, his):
+    def update_all(state, cols_list, los, his, at=None):
         """Fold MANY equal-capacity windows in ONE program: stack the
         per-window planes on device and lax.scan the window fold. One
         dispatch replaces W of them; XLA overlaps the scan iterations'
         memory traffic.
 
         ``cols_list`` is a tuple of per-window cols dicts; ``los``/``his``
-        are i32[W] row-range bounds (the mask builds in-program).
+        are i32[W] row-range bounds (the mask builds in-program); ``at``
+        (a ``RowSlice`` of i32[W] starts) cuts every window to one length
+        before the stack, so the stack and the scan carry that many rows.
         Query-constant side inputs (``__side__``, the fused-lookup-join
         build tables) are identical across windows and must NOT be
         stacked W times — they lift out and rejoin inside the scan body.
         """
         side = None
         stripped = []
-        for c in cols_list:
+        for w, c in enumerate(cols_list):
             c = dict(c)
             s = c.pop("__side__", None)
             side = side if side is not None else s
+            if at is not None:
+                c = _sliced(c, RowSlice(at.start[w], at.rows))
             stripped.append(c)
         stacked = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *stripped

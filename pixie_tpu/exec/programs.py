@@ -495,7 +495,9 @@ class ProgramRegistry:
         with self._lock:
             rec = self._records.get((key, sig))
             if rec is None:
-                pid = f"{hash((key, sig)) & (2**64 - 1):016x}"
+                # (A treedef's hash leaves out its nodes' static data,
+                # the length of a fold's ``RowSlice``; its text holds it.)
+                pid = f"{hash((key, sig, str(sig[0]))) & (2**64 - 1):016x}"
                 rec = ProgramRecord(
                     pid, prog._kind, prog._label, _sig_repr(sig),
                 )

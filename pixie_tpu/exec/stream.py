@@ -299,6 +299,20 @@ def _window_shapes(cols) -> tuple:
     )
 
 
+def _fold_rows(capacity: int, rows: int) -> int:
+    """The length a fold program is handed for ``rows`` rows in range of
+    a resident window padded to ``capacity``: the least of a quarter, a
+    half and the whole that holds them. At most three lengths (three
+    traces) a program whatever the ranges asked for, the whole being the
+    program of a full window; a sort, a gather or a kernel over the
+    window then pays for at most twice the rows in range, not for the
+    padding (PERF.md section 6, PR 44)."""
+    for length in (capacity // 4, capacity // 2):
+        if rows <= length:
+            return length
+    return capacity
+
+
 #: What the stage helpers return without stats (reusable, reentrant).
 _NO_STATS = contextlib.nullcontext()
 

@@ -49,7 +49,11 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   aggregates that read them, ``digest_slots``:
   groups x centroids of one, and ``digest_bins``: the width B its
   windows' rows are binned at, 2^32 where a sort orders the values
-  themselves, ``ops/routes.py`` ``digest_bins``); child of its fragment
+  themselves, ``ops/routes.py`` ``digest_bins``; where its windows came
+  resident with a row range, ``rows``: the rows of them the program
+  folded, a power-of-two slice around the range or the whole capacity,
+  summed over a run's windows, ``exec/stream.py`` ``_fold_rows``, and
+  ``range_rows``: the rows in range among them); child of its fragment
 - ``rebucket``            one per re-fold after a group-capacity overflow
   (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
   rows, ``kelvin`` the merge of states): the compile at twice the slots

@@ -149,9 +149,12 @@ class DistributedEngine(Engine):
     # single-device CPU thread-parallel fold nor the TPU scan-fold
     # batching (update_all — a single-logical-device jit) may bypass
     # the distributed steps; nor may the joint-key sketch's plain jit.
+    # The steps fold whole windows: a slice of a row-sharded window at
+    # an offset known only at run time would move rows between chips.
     cpu_parallel_fold = False
     scan_fold = False
     probe_group_keys = False
+    slice_windows = False
 
     def __init__(self, registry=None, window_rows: int | None = None,
                  mesh: Mesh | None = None, n_agents: int | None = None,

@@ -510,3 +510,36 @@ def test_asking_imports_no_kernel():
         "bad = [m for m in sys.modules if 'pallas' in m]; assert not bad, bad"
     )
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+# (cell, rows in range of its fold's window, slots g, key words, sum
+#  planes, the window's ride, whether the window folds WITH the state).
+SLICED_CELLS = [
+    ("flow_recent_pairs", 614_366, 1 << 17, 1, 2, "payload", False),
+    ("flow_recent_addrs", 614_366, 1 << 13, 1, 0, "", False),
+    ("sql_recent", 790_568, 1 << 17, 3, 1, "payload", False),
+    ("flame_recent_first", 432_374, 1 << 20, 4, 1, "index", True),
+    ("flame_recent_last", 582_178, 1 << 20, 4, 1, "index", True),
+    ("graph_recent", 1_428_589, 1 << 17, 1, 2, "payload", False),
+]
+
+
+@pytest.mark.parametrize("cell,rows,g,key_words,planes,ride,absorbs",
+                         SLICED_CELLS, ids=[c[0] for c in SLICED_CELLS])
+def test_a_sliced_window_takes_the_whole_windows_routes_at_the_cells_sizes(
+        cell, rows, g, key_words, planes, ride, absorbs):
+    """A fold handed a power-of-two slice around its range (PR 44) sees a
+    shorter n, as under a smaller ``window_rows``: at the cells' sizes the
+    routes that read n (``sorted_fold_ride``, ``_front`` / ``absorb`` at
+    n >= 4 g) choose as they chose for the 2^21-row window."""
+    from pixie_tpu.exec.stream import _fold_rows
+    from pixie_tpu.ops.routes import sorted_fold_ride
+
+    window = 1 << 21
+    n = _fold_rows(window, rows)
+    if cell.startswith("flame"):
+        n = 1 << 20  # one length a run of windows: its longest
+    assert n == (window if rows > 1 << 20 else 1 << 20)
+    for length in (n, window):
+        assert sorted_fold_ride(length, g, key_words, planes) == ride
+        assert (length < 4 * g) == absorbs

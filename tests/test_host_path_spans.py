@@ -325,6 +325,39 @@ def test_a_served_quantiles_fold_says_its_digests(rehearsed_names):
                                             "digest_bytes"}, s.name
 
 
+#: What a fold of resident windows says of the rows it was handed (PR
+#: 44): on its fold programs' ``device.dispatch``.
+RANGE_ATTRIBUTES = ("rows", "range_rows")
+
+
+def test_the_docs_and_the_docstring_name_the_range_attributes():
+    doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
+    for text, where in ((doc, "docs/OBSERVABILITY.md"),
+                        (trace_mod.__doc__, "trace.py's docstring")):
+        for word in (*RANGE_ATTRIBUTES, "_fold_rows"):
+            assert re.search(rf"\b{word}\b", text), (where, word)
+
+
+def test_a_served_fold_of_resident_windows_says_its_rows(rehearsed_names):
+    """Every PEM fold dispatch of every rehearsed cell carries ``rows``
+    (a quarter, a half or the whole of each window's 2^13-row capacity)
+    and ``range_rows`` (no more than that); the Kelvin's programs, whose
+    windows are staged batches under a mask, carry neither."""
+    for cell, spans in rehearsed_names.items():
+        folds = [s.attributes for t in spans["pem"]
+                 for s in _named(t, "device.dispatch")
+                 if "fold" in s.attributes]
+        assert folds, cell
+        for a in folds:
+            assert a["rows"] in {a["windows"] << k for k in (11, 12, 13)}, (
+                cell, a)
+            assert 0 < a["range_rows"] <= a["rows"], (cell, a)
+        for t in spans["kelvin"]:
+            for s in _named(t, "device.dispatch"):
+                assert not set(s.attributes) & set(RANGE_ATTRIBUTES), (
+                    cell, s.attributes)
+
+
 def test_the_joins_pieces_are_children_of_its_span(rehearsed_names):
     kelvin = next(t for t in rehearsed_names["conn_flow_1chip.flow_recent"]
                   ["kelvin"] if _named(t, "join"))

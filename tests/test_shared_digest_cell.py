@@ -59,8 +59,12 @@ def test_one_served_requests_span_shape(window):
         "slots": slots, "digests": 1, "digest_outputs": 3,
         "digest_slots": slots * 128, "digest_bins": 1 << 32,
         "ride": "index",
+        # (PR 44) one length a run of resident windows, the rows in range.
+        "rows": fold["rows"], "range_rows": fold["range_rows"],
     }
     assert fold["windows"] in (3, 4)
+    assert 0 < fold["range_rows"] <= fold["rows"]
+    assert fold["rows"] % fold["windows"] == 0
     assert not [s for t in window["spans"]["pem"] + window["spans"]["kelvin"]
                 for s in t.spans if s.name == "rebucket"]
     assert payload.attributes == {

@@ -959,6 +959,47 @@ def test_a_sorted_window_dispatch_says_how_its_sums_ride(aggs, slots, ride):
     assert {a.get("max_words") for a in folds} == {words or None}
 
 
+RANGED_PXL = RIDE_PXL.replace(
+    "table='events'", "table='events', start_time=%d, end_time=%d")
+
+
+@pytest.mark.parametrize("lo,hi,rows,ride", [
+    (0, 4_000, 4_096, "payload"),  # over a half: the whole window
+    (500, 2_400, 2_048, "payload"),  # a half: n = 4 g still
+    (3_000, 3_900, 1_024, "index"),  # a quarter: short against 512 slots
+], ids=["whole", "half", "quarter"])
+def test_a_sliced_windows_ride_is_its_slices(lo, hi, rows, ride):
+    """The span's ``ride`` is reckoned from the rows the program is
+    handed (PR 44: a slice around the range), as the program's own trace
+    reckons it; the answer is numpy's by either way."""
+    from pixie_tpu.planner import CompilerState, compile_pxl
+
+    data = _events(5, 4_000, [f"svc-{i}" for i in range(8)], 25)
+    with routes_of("tpu"), override_flag("dense_domain_limit", 16):
+        eng = Engine(window_rows=4_096)
+        eng.append_data("events", data)
+        state = CompilerState(
+            schemas={n: t.relation for n, t in eng.tables.items()},
+            registry=eng.registry, now_ns=0, max_groups=512,
+        )
+        out = eng.execute_plan(compile_pxl(
+            RANGED_PXL % (lo, hi, "a=('lat', px.sum), b=('size', px.sum)"),
+            state).plan)
+    (fold,) = [s.attributes for s in eng.tracer.last().spans
+               if s.name == "device.dispatch" and "fold" in s.attributes]
+    assert (fold["rows"], fold["range_rows"], fold["ride"]) == (
+        rows, hi - lo, ride)
+    want = {}
+    for s, p, lat, size in zip(data["svc"][lo:hi], data["path"][lo:hi],
+                               data["lat"][lo:hi], data["size"][lo:hi]):
+        a, b = want.get((s, p), (0, 0))
+        want[s, p] = (a + int(lat), b + int(size))
+    d = out["output"].to_pydict()
+    got = {(s, p): (int(a), int(b))
+           for s, p, a, b in zip(d["svc"], d["path"], d["a"], d["b"])}
+    assert got == want
+
+
 @pytest.fixture(params=[2, 3], ids=["two_pems", "three_pems"])
 def cluster(request):
     """Broker, Kelvin and PEMs whose tables were appended as python

@@ -46,6 +46,13 @@ sum at 2^20 slots (``update_all``) and its joint-key sketch, the dense
 fold by pod, the Kelvin's two ``merge_finalize``, the single-shot device
 join of 4,096 + 2^20 rows into 2^21 output slots.
 
+``--rows N`` (PR 44) lowers the window programs alone (``update``,
+``update_all``) as the engine calls them for a range short against its
+window: handed N rows of each 2^21-row plane from a start that is an
+argument (``exec/fragment.py`` ``RowSlice``), under names that end
+``.rows<N>``. Without it every text is a whole window's, which is what a
+full window, the mesh step and every Kelvin program stay.
+
     JAX_PLATFORMS=cpu python tools/fold_hlo.py --out DIR
 
 writes one ``<case>.<program>.txt`` a program and prints one line a
@@ -232,12 +239,14 @@ def _record(lines, out_dir, name, program, fold, text, **more):
             f.write(text)
 
 
-def _lower(case, captured, topo_device, out_dir, lines):
+def _lower(case, captured, topo_device, out_dir, lines, rows=None):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from pixie_tpu.exec.fragment import OperandProgram, compile_fragment
+    from pixie_tpu.exec.fragment import (
+        OperandProgram, RowSlice, compile_fragment,
+    )
     from pixie_tpu.exec.plan import AggOp
     from pixie_tpu.types.dtypes import device_dtypes
     from pixie_tpu.udf.registry import default_registry
@@ -272,6 +281,10 @@ def _lower(case, captured, topo_device, out_dir, lines):
         on_kelvin = not allow_dense or (flow and merged_at is None)
         who = "kelvin" if on_kelvin else "pem"
         name = f"{case}.{who}.{_agg_label(ops)}.{frag.group}{frag.slots}"
+        if rows:
+            if on_kelvin or rows >= window:
+                continue  # no resident window, or the whole one
+            name += f".rows{rows}"
         if any(line["case"] == name for line in lines):
             continue  # compiled twice (the probe, then the capacity)
         state = on(jax.eval_shape(frag.init_state))
@@ -288,10 +301,14 @@ def _lower(case, captured, topo_device, out_dir, lines):
             "merge_states": lambda: jax.jit(frag.merge_states).lower(
                 state, state),
             "finalize": lambda: frag.finalize.lower(state),
+            # ``--rows``: the programs of a range short against its
+            # window, handed that many rows of each plane.
             "update": lambda: frag.update.lower(
-                state, cols, (scalar, scalar)),
+                state, cols, (scalar, scalar),
+                *([RowSlice(scalar, rows)] if rows else [])),
             "update_all": lambda: frag.update_all.lower(
-                state, (cols,) * n_windows, bounds, bounds),
+                state, (cols,) * n_windows, bounds, bounds,
+                *([RowSlice(bounds, rows)] if rows else [])),
             # (A program that takes operand tables lowers itself, with
             # the tables' shapes: ``fragment.OperandProgram``.)
             "group_sketch": lambda: (
@@ -312,6 +329,8 @@ def _lower(case, captured, topo_device, out_dir, lines):
             wanted = ("finalize", "merge_states", "update", "update_all")
         else:  # the Kelvin folds no window: it merges and finalizes
             wanted = ("finalize", "merge_states")
+        if rows:
+            wanted = [p for p in wanted if p in ("update", "update_all")]
         for program in sorted(wanted):
             lowered = programs[program]()
             more = (
@@ -449,6 +468,10 @@ def main():
     ap.add_argument("--cases", default="dense,keyed,flow",
                     help="comma-separated, of dense, keyed, flow, digest, "
                          "sql, flame, edges")
+    ap.add_argument("--rows", type=int, default=None,
+                    help="lower the window programs alone, handed this many "
+                         "rows of each 2^21-row plane (a fragment.RowSlice: "
+                         "a range short against its window)")
     args = ap.parse_args()
     if args.out:
         os.makedirs(args.out, exist_ok=True)
@@ -529,7 +552,9 @@ def main():
                                         topology_name="v5e:2x2")
     lines = []
     for case, (seen, merges) in captured.items():
-        _lower(case, seen, topo.devices[0], args.out, lines)
+        _lower(case, seen, topo.devices[0], args.out, lines, args.rows)
+        if args.rows:
+            continue
         _lower_merges(case, merges, topo.devices[0], args.out, lines)
         if case in ("flow", "flame"):
             _lower_join(case, topo.devices[0], args.out, lines)
