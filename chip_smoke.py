@@ -4,7 +4,7 @@
     python chip_smoke.py --chips 4  # four chips: kernel + DistributedEngine only
 
 One process, no child that needs the chip. A seeded ``http_events``
-replay (the bench's five-column layout, 32 B/row) goes in through the
+replay (five columns, 32 B/row) goes in through the
 table store's ingest path with device residency on; every answer is
 checked against a plain numpy replay of the same semantics. Progress is
 one JSON object per line; the LAST line is
@@ -154,7 +154,7 @@ def _programs_since(snap: dict) -> list:
 
 
 class Replay:
-    """``rows`` http_events at the bench's layout (``bench._http_replay``):
+    """``rows`` http_events of five columns:
     time_ i64, latency_ns i64, resp_status i64, service/req_path as
     dictionary codes. All of it from ``seed``."""
 

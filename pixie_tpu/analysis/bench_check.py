@@ -1,22 +1,25 @@
-"""Plan verification over the bench shapes (``run_tests.sh
---analyze``).
+"""Plan verification over the small-replay shapes (``run_tests.sh
+--analyze``), and the one place those shapes are written down.
 
-Compiles every bench shape's query (the same shipped library scripts
-``bench.py`` runs) against the bench replay schemas, with the always-on
-plan verifier active, then splits each through the DistributedPlanner
-(2 PEMs + 1 Kelvin) and runs the full distributed schema walk. Any
-diagnostic is a regression: these plans are the repo's
-performance-critical shapes and must stay statically clean.
+A replay shape is a query with the schemas of the tables it reads: the
+five shipped library scripts the deployments in ``benchmark/`` serve
+(px/http_stats, px/service_stats, px/net_flow_graph, px/sql_stats,
+px/perf_flamegraph), three N:M join queries and a selective scan of a
+mostly-cold table. ``SHAPE_SCHEMAS`` and ``_shape_query`` own them;
+``bound_check._replay_engine`` builds an engine holding synthetic rows
+of a shape, and the safety gates (this one, ``bound_check``, the
+overhead A/Bs in tests/test_profiling.py and tests/test_bus_obs.py,
+the depth equivalence in tests/test_pipeline.py) all replay these.
 
-Also reports verifier overhead relative to compile time — the pass
-rides inside the ``compile`` span, budgeted at <5% of its p50
-(ISSUE 7 acceptance; ``bench.py`` measures the span itself).
+This gate compiles every shape's query against its schemas with the
+always-on plan verifier active, then splits each through the
+DistributedPlanner (2 PEMs + 1 Kelvin) and runs the full distributed
+schema walk. Any diagnostic is a regression: these plans must stay
+statically clean, and a script whose columns drift from its schema
+fails here with an unbound-column diagnostic, which is the point.
 
-Schemas mirror the replay builders in ``bench.py`` (``_http_replay``,
-``_shape_net_flow_graph``, ``_shape_sql_stats``,
-``_shape_perf_flamegraph``, ``_shape_device_join``); a column drift
-there will fail here with an unbound-column diagnostic, which is the
-point.
+Also reports verifier overhead relative to compile time: the pass
+rides inside the ``compile`` span, budgeted at <5% of its p50.
 """
 
 from __future__ import annotations
@@ -88,8 +91,7 @@ SHAPE_SCHEMAS = {
     },
 }
 
-# bench.py's inline queries, verbatim (the shapes whose queries are not
-# shipped library scripts).
+# The shapes whose queries are not shipped library scripts.
 _DEVICE_JOIN_QUERY = """
 import px
 l = px.DataFrame(table='conn_l')
@@ -131,8 +133,8 @@ def _shape_query(shape: str) -> str:
 
 
 def check_bench_shapes(verbose: bool = True) -> int:
-    """Compile + verify every bench shape; returns the number of failing
-    shapes (0 = green)."""
+    """Compile + verify every replay shape; returns the number of
+    failing shapes (0 = green)."""
     from ..planner import CompilerState, compile_pxl
     from ..planner.distributed import DistributedPlanner
     from ..planner.distributed.distributed_state import DistributedState
@@ -196,10 +198,10 @@ def check_bench_shapes(verbose: bool = True) -> int:
 def main() -> int:
     failures = check_bench_shapes()
     if failures:
-        print(f"[analyze] {failures} bench shape(s) failed verification",
+        print(f"[analyze] {failures} replay shape(s) failed verification",
               file=sys.stderr)
         return 1
-    print(f"[analyze] all {len(SHAPE_SCHEMAS)} bench shapes verify clean",
+    print(f"[analyze] all {len(SHAPE_SCHEMAS)} replay shapes verify clean",
           file=sys.stderr)
     return 0
 

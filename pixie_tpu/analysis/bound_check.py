@@ -3,13 +3,13 @@
 
 The resource-bound pass (``analysis/bounds.py``) is load-bearing — the
 broker's admission control rejects queries on its predictions — so it
-must be FALSIFIABLE, not advisory. This gate replays every bench shape
-(the same queries ``bench.py`` times, over synthetic ingest pushed
-through the real table-store append path so the sketches exist) plus
+must be FALSIFIABLE, not advisory. This gate replays every shape of
+``bench_check.SHAPE_SCHEMAS`` (over synthetic ingest pushed through
+the real table-store append path so the sketches exist) plus
 the bundled self-monitoring scripts, and asserts for each query that
 the OBSERVED ``QueryResourceUsage`` (PR 7 telemetry: the trace's
 ``bytes_staged``/``rows_in``/``rows_out``) stays <= the PREDICTED
-bound (which already includes the ``bounds_safety`` factor). It then
+bound (which already includes the ``BOUNDS_SAFETY`` factor). It then
 proves the rejection half of the contract: an intentionally
 over-budget query fails AT COMPILE with a structured ``resource-bound``
 ``Diagnostic`` — never an OOM or a silent truncation at run time.
@@ -29,7 +29,7 @@ import numpy as np
 from .bench_check import SHAPE_SCHEMAS, _shape_query
 
 #: Rows appended per table in the replay (small: the gate checks
-#: bound SOUNDNESS, not throughput — bench.py owns the numbers).
+#: bound SOUNDNESS, not throughput).
 GATE_ROWS = 4096
 
 #: (observed usage key, predicted cost key) pairs the gate asserts on.
@@ -54,8 +54,11 @@ def _synth_column(dtype, n: int, rng, col: str):
         return rng.random(n)
     if dtype == DataType.BOOLEAN:
         return rng.integers(0, 2, n).astype(bool)
-    # STRING: a small vocabulary (realistic NDV; joins/self-joins match)
-    vocab = [f"{col}-{i}" for i in range(16)]
+    # STRING: a small vocabulary (realistic NDV), named by the column's
+    # last word so that px/net_flow_graph's join of ``remote_addr`` to
+    # ``src_addr`` matches.
+    kind = col.rsplit("_", 1)[-1]
+    vocab = [f"{kind}-{i}" for i in range(16)]
     return [vocab[int(i)] for i in rng.integers(0, len(vocab), n)]
 
 
@@ -247,7 +250,7 @@ def _check_rejection(verbose: bool) -> int:
 
 
 def check_bounds(verbose: bool = True) -> int:
-    """Replay every bench shape + the bundled self-monitoring scripts
+    """Replay every shape + the bundled self-monitoring scripts
     against pxbound's predictions; returns the failure count."""
     from ..scripts import load_script
     from ..services.telemetry import enable_self_telemetry
@@ -313,7 +316,7 @@ def main() -> int:
               file=sys.stderr)
         return 1
     print(
-        f"[bounds] all {n} bench shapes + self-monitoring scripts hold "
+        f"[bounds] all {n} replay shapes + self-monitoring scripts hold "
         "observed <= predicted; over-budget rejection verified",
         file=sys.stderr,
     )

@@ -38,8 +38,8 @@ The walk produces a :class:`PlanResourceReport` — the query's
    queries`` as predicted-vs-observed columns.
 
 Soundness contract: every bound is an inclusive UPPER bound on the
-observed counter under the ``bounds_safety`` factor, falsifiable
-against PR 7 telemetry — ``analysis/bound_check.py`` replays the bench
+observed counter under the ``BOUNDS_SAFETY`` factor, falsifiable
+against PR 7 telemetry — ``analysis/bound_check.py`` replays the small
 shapes + the bundled self-monitoring scripts and asserts observed
 ``QueryResourceUsage`` <= predicted. Two deliberate exceptions, both
 with run-time escape hatches: join output bounds are NDV *estimates*
@@ -94,6 +94,13 @@ _AGG_SLOT_BYTES = 24
 #: Device bytes per join row across the kernel's output planes
 #: (probe idx, probe take, build idx, build take + the staged key).
 _JOIN_ROW_BYTES = 40
+
+#: Multiplier on the predicted resource totals (staged bytes, rows). It
+#: covers run-time effects the plan-time walk cannot see exactly:
+#: overflow-rebucket re-folds, concurrent ingest between compile and
+#: execution, join driver re-staging. The soundness gate
+#: (analysis/bound_check.py) asserts observed <= predicted UNDER it.
+BOUNDS_SAFETY = 2.0
 
 
 def _unb(*vals):
@@ -462,7 +469,7 @@ def _node_peak_bytes(node, bound, in_bounds, window_rows) -> int | None:
 
 def plan_bounds(plan: Plan, schemas, registry, table_stats=None, *,
                 plan_name: str = "logical", bridge_rows=None,
-                bridge_relations=None, safety: float | None = None,
+                bridge_relations=None, safety: float = BOUNDS_SAFETY,
                 ) -> PlanResourceReport:
     """Abstract-interpret ``plan``: per-node bounds + predicted query
     totals. Never raises on missing statistics — sketch-less inputs
@@ -474,8 +481,6 @@ def plan_bounds(plan: Plan, schemas, registry, table_stats=None, *,
     """
     from ..config import get_flag
 
-    if safety is None:
-        safety = float(get_flag("bounds_safety"))
     window_rows = int(get_flag("window_rows"))
     max_groups_limit = int(get_flag("max_groups_limit"))
     report = PlanResourceReport(plan_name=plan_name, safety=safety)
@@ -687,8 +692,8 @@ def presize_plan_aggs(plan: Plan, report: PlanResourceReport) -> int:
 # compiler is deterministic, so two compiles of one script against one
 # schema set, registry, and STATS SNAPSHOT produce plans with identical
 # bounds (node ids included — the per-plan counter is deterministic).
-# Repeat compiles — bench warm/timed rounds, dashboard refresh traffic
-# between ingest batches — skip the walk entirely (~2µs hit), keeping
+# Repeat compiles — dashboard refresh traffic between ingest
+# batches — skip the walk entirely (~2µs hit), keeping
 # the always-on pass inside the <5%-of-compile-span budget; any ingest
 # changes the stats snapshot and naturally misses. Reports cache
 # whether clean or over-budget: check_plan_bounds re-raises from the
@@ -737,9 +742,8 @@ def apply_plan_bounds(plan: Plan, schemas, registry, table_stats=None, *,
                 plan_params,
                 # Every flag the walk or its budget checks read.
                 get_flags(
-                    "bounds_safety", "bounds_query_budget_mb",
-                    "bounds_device_budget_mb", "window_rows",
-                    "max_groups_limit", "bounds_presize",
+                    "bounds_query_budget_mb", "bounds_device_budget_mb",
+                    "window_rows", "max_groups_limit", "bounds_presize",
                 ),
             )
             hash(key)

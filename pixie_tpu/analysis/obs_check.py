@@ -6,7 +6,7 @@ against the telemetry table schemas
 (``ingest/schemas.py`` TELEMETRY_SCHEMAS) with the always-on plan
 verifier active, then splits each through the DistributedPlanner (2
 PEMs + 1 Kelvin) and runs the full distributed schema walk — the same
-contract ``bench_check.py`` enforces for the performance shapes. A
+contract ``bench_check.py`` enforces for the small-replay shapes. A
 schema drift in the TelemetryCollector's fold (services/telemetry.py)
 surfaces HERE as an unbound-column diagnostic, before any cluster
 runs it.

@@ -559,9 +559,9 @@ def check_plan(plan: Plan, schemas, registry, **kw) -> None:
 # at most in folded literal VALUES (now_ns time arithmetic), never in
 # column names, dtypes, or topology, so their verification outcome is
 # identical. Only CLEAN results cache (a failing script re-verifies to
-# rebuild its diagnostics); repeat compiles of one script — bench's
-# warm/timed/AB rounds, dashboard refresh traffic — skip the walk,
-# keeping the always-on pass inside the <5%-of-compile-span budget.
+# rebuild its diagnostics); repeat compiles of one script (dashboard
+# refresh traffic) skip the walk, keeping the always-on pass inside
+# the <5%-of-compile-span budget.
 _VERIFY_CACHE: dict = {}
 _VERIFY_CACHE_MAX = 256
 _VERIFY_CACHE_LOCK = threading.Lock()

@@ -165,12 +165,7 @@ define_flag("join_strategy", "auto",
             "host table lookup; else sketch-guided routing picks "
             "host-dict / host-hash / single-shot / windowed sorted-probe "
             "/ windowed radix by shape, backend and sketches), or force "
-            "'host', 'single', 'sorted', 'radix' for testing/bench.")
-define_flag("join_radix_bits", 8,
-            "Radix bits for the partitioned device join: build keys are "
-            "splitmix64-hashed and partitioned by the top bits, so each "
-            "probe row binary-searches ONE partition instead of the "
-            "whole build side. 0 disables the radix strategy entirely.")
+            "'host', 'single', 'sorted', 'radix' (tests do).")
 define_flag("join_capacity_safety", 2.0,
             "Multiplier on the sketch-estimated join output cardinality "
             "when sizing the initial device-join output capacity (then "
@@ -178,19 +173,11 @@ define_flag("join_capacity_safety", 2.0,
             "NDV-based mean fan-out absorbs moderate key skew; an "
             "overflow retry costs a fresh jit compile mid-query, so "
             "over-sizing is the cheaper error.")
-define_flag("join_zone_skip", True,
-            "Skip staging probe windows whose key zone map cannot "
-            "intersect the build side's key range (inner/left windowed "
-            "device joins; left windows emit their null rows host-side).")
 define_flag("device_residency", True,
             "Stage full table windows into device memory (HBM) at append "
             "time so steady-state queries run without host transfers.")
 define_flag("device_cache_bytes", 6 << 30,
             "Byte budget for device-resident table windows (LRU-evicted).")
-define_flag("device_join_min_rows", 1 << 15,
-            "Combined row count above which joins route to the device kernel.")
-define_flag("agent_heartbeat_s", 5.0, "Agent heartbeat period (seconds).")
-define_flag("agent_expiry_s", 60.0, "Tracker agent expiry after silence.")
 define_flag(
     "cpu_fold_threads", 0,
     "CPU-backend parallel window fold: thread count (0 = auto from cores, "
@@ -220,7 +207,8 @@ define_flag(
     "Skip scan windows whose per-column zone maps cannot satisfy a "
     "query's FilterOp predicate (exec/zoneskip.py) — checked BEFORE "
     "stage/decode, so selective scans over cold data never decode "
-    "dead windows. Generalizes join_zone_skip to plain table scans.",
+    "dead windows. The join drivers' key-range window skipping, "
+    "generalized to plain table scans.",
 )
 define_flag(
     "bus_secret", "",
@@ -228,7 +216,7 @@ define_flag(
     "(single-trust-domain deployments).",
 )
 
-# -- fault tolerance (services/query_broker.py, tracker.py) ------------------
+# -- fault tolerance (services/query_broker.py) ------------------------------
 define_flag(
     "dispatch_retries", 3,
     "Re-publishes of an un-acked fragment dispatch before the broker "
@@ -245,35 +233,8 @@ define_flag(
     "lost, instead of completing with partial results from the "
     "survivors (the pre-fault-tolerance fail-closed behavior).",
 )
-define_flag(
-    "agent_flap_threshold", 3,
-    "Expirations within agent_flap_window_s that quarantine an agent "
-    "out of distributed query planning.",
-)
-define_flag(
-    "agent_flap_window_s", 300.0,
-    "Sliding window (seconds) for counting agent expirations toward "
-    "the flap threshold.",
-)
-define_flag(
-    "agent_quarantine_s", 120.0,
-    "Cooldown during which a quarantined (flapping) agent is excluded "
-    "from distributed_state() planning; it may re-register and "
-    "heartbeat meanwhile.",
-)
 
 # -- broker HA (services/broker_ha.py; docs/RESILIENCE.md "Broker HA") -------
-define_flag(
-    "broker_lease_interval_s", 0.5,
-    "Cadence of the leader's broker.lease heartbeat and of each "
-    "standby's expiry check / presence announcement.",
-)
-define_flag(
-    "broker_lease_expiry_s", 2.0,
-    "Lease age past which a standby declares the leader dead and the "
-    "lowest-id standby claims the next epoch (each higher-ranked "
-    "standby waits one extra lease interval before claiming).",
-)
 define_flag(
     "broker_reconcile_wait_s", 0.5,
     "How long a freshly elected leader collects agents' answers to the "
@@ -300,11 +261,6 @@ define_flag(
 
 # -- query-lifecycle tracing (exec/trace.py) ---------------------------------
 define_flag(
-    "trace_ring_size", 128,
-    "Finished query traces kept in the engine tracer's ring buffer "
-    "(served by /debug/queryz; oldest evicted first).",
-)
-define_flag(
     "trace_window_sample", 64,
     "window.stage / window.stall spans: every interval of a stage up "
     "to N, then every Nth (1 = every window, 0 = no window spans; the "
@@ -324,15 +280,6 @@ define_flag(
 )
 
 # -- resource bounds + admission control (analysis/bounds.py) ----------------
-define_flag(
-    "bounds_safety", 2.0,
-    "Multiplier on pxbound's predicted resource totals (staged bytes, "
-    "rows). Covers run-time effects the plan-time walk cannot see "
-    "exactly: overflow-rebucket re-folds, concurrent ingest between "
-    "compile and execution, join driver re-staging. The soundness gate "
-    "(analysis/bound_check.py) asserts observed <= predicted UNDER "
-    "this factor.",
-)
 define_flag(
     "bounds_presize", True,
     "Grow AggOp.max_groups at compile time to the sketch-NDV group "
@@ -421,25 +368,7 @@ define_flag(
     "under it; deploy roles honor it at process start.",
 )
 
-# -- device-tier observability (exec/programs.py) ----------------------------
-define_flag(
-    "program_registry_size",
-    512,
-    "Compiled-program registry capacity (exec/programs.py): tracked "
-    "(program, shape-signature) records — each holding its XLA "
-    "executable, compile wall-time and cost/memory analysis — kept in "
-    "an LRU; oldest evicted (and recompiled on next use). 0 disables "
-    "tracking entirely (jit entry points run unwrapped).",
-)
-define_flag(
-    "device_memory_poll_s",
-    0.0,
-    "Background device.memory_stats() poll period for per-query peak "
-    "device-memory attribution (QueryResourceUsage.device_peak_bytes). "
-    "0 disables the poll thread; peaks then come from the query-"
-    "boundary samples alone. Gauges refresh at every /metrics scrape "
-    "regardless.",
-)
+# -- observed-cost feedback into admission (services/query_broker.py) --------
 define_flag(
     "admission_observed_floor",
     True,
@@ -491,13 +420,6 @@ define_flag(
 
 # -- self-observability (services/telemetry.py) ------------------------------
 define_flag(
-    "self_telemetry", True,
-    "Agents fold their engine's finished query traces + resource "
-    "records into the __queries__/__spans__/__agents__ tables "
-    "(PxL-queryable through the normal engine path) and publish "
-    "distributed-trace span summaries for the broker's /debug/tracez.",
-)
-define_flag(
     "telemetry_table_mb", 8,
     "Per-table byte budget (MB) for the self-telemetry tables; each "
     "table's ring expires its own oldest rows at the budget.",
@@ -531,11 +453,4 @@ define_flag(
     "and count in pixie_bus_slow_handlers_total; 0 disables the "
     "slow-handler log. The transport-tier twin of "
     "slow_query_threshold_ms.",
-)
-define_flag(
-    "profile_summary_stacks", 512,
-    "Per-profiler cap on distinct (stack, attribution) keys kept in "
-    "the cumulative folded-stack summary that heartbeats ship for "
-    "cluster merge; over the cap the coldest stacks age out "
-    "(hottest-kept eviction, counts stay monotonic for survivors).",
 )

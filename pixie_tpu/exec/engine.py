@@ -324,7 +324,7 @@ class Engine:
         # Per-query execution scratch (thread-local: each concurrent
         # execute_plan runs on its own caller thread). The ``last_*``
         # attributes below are engine-level LAST-FINISHED-QUERY
-        # snapshots for bench/tests/observability — under concurrency
+        # snapshots for tests/observability — under concurrency
         # they are last-writer-wins by design; anything correctness-
         # bearing reads the scratch, never these.
         self._tls = threading.local()
@@ -355,8 +355,8 @@ class Engine:
         self._last_table_sinks: dict = {}  # {table: rows} from TableSinkOps
         # Routing outcome of the most recent materialized JoinOp
         # (joins.JoinDecision): strategy, build-side swap, capacity,
-        # overflow retries, zone-skipped windows. Bench and tests read
-        # it; None until a query joins.
+        # overflow retries, zone-skipped windows. Tests and the ``join``
+        # span read it; None until a query joins.
         self._last_join_decision = None
         self._last_resource_report = None
         # OTel egress collection (export_otel): init here, not lazily —
@@ -392,7 +392,7 @@ class Engine:
         from .programs import default_device_monitor
 
         self.device_memory = default_device_monitor()
-        self.device_memory.start()  # no-op unless device_memory_poll_s
+        self.device_memory.start()  # no-op while DEVICE_MEMORY_POLL_S is 0
         # Local result cache (exec/result_cache.py; result_cache_mb
         # flag, 0 = off): broker-less deployments cache merged results
         # at execute_query exactly like the broker's execute path.
@@ -807,7 +807,7 @@ class Engine:
                     self.device_memory.query_end(mem_token)
                 )
             # Publish the last-finished-query snapshots (observability/
-            # bench/test seams; last-writer-wins under concurrency).
+            # test seams; last-writer-wins under concurrency).
             with self._state_lock:
                 self._inflight -= 1
                 self._last_pipeline = scratch.pipeline

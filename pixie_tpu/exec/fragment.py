@@ -333,7 +333,7 @@ def _track_fragment_programs(frag, ops, cache_key, input_dicts,
     plan's second run is a registry hit, and a fragment-cache eviction
     can still reuse the registry's executable instead of recompiling
     (the registry pins the id()-keyed objects exactly like the entry
-    above). No-op when program_registry_size is 0."""
+    above)."""
     from .programs import default_program_registry
 
     preg = default_program_registry()

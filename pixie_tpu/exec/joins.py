@@ -80,6 +80,11 @@ DEVICE_JOIN_MIN_ROWS = 1 << 15
 # partition bookkeeping costs more than the shorter binary search saves).
 RADIX_MIN_BUILD_ROWS = 1 << 16
 
+# Radix bits of the partitioned probe: build keys are splitmix64-hashed
+# and partitioned by the top bits, so each probe row binary-searches ONE
+# of 2^bits partitions instead of the whole build side.
+JOIN_RADIX_BITS = 8
+
 
 # -- sketch-backed side statistics -------------------------------------------
 @dataclass
@@ -252,9 +257,8 @@ def remember_capacity(engine, cap_key, capacity: int) -> None:
 
 def _retry_counter(engine):
     """pixie_join_capacity_retries_total: overflow-retry kernel re-runs
-    (each costs a fresh jit compile mid-query). The bench gate asserts
-    this stays 0 on the standard shapes — the sketch estimate plus the
-    learned-capacity cache should make retries exceptional."""
+    (each costs a fresh jit compile mid-query). The sketch estimate
+    plus the learned-capacity cache should make retries exceptional."""
     tracer = getattr(engine, "tracer", None)
     if tracer is not None:
         reg = tracer.registry
@@ -305,14 +309,13 @@ def estimate_join_capacity(probe_rows: int, build: JoinSideStats | None,
 @dataclass
 class JoinDecision:
     """Routing outcome, recorded on ``engine.last_join_decision`` so
-    bench and tests can see which strategy served a query."""
+    tests and the ``join`` span can see which strategy served a query."""
 
     # degenerate|host_table|host_dict|host_hash|single|sorted|radix
     strategy: str
     swap: bool = False  # probe the RIGHT side (inner only)
     capacity: int | None = None  # initial output capacity (per window)
     window_rows: int = 0  # probe rows per dispatch (windowed paths)
-    zone_skip: bool = False
     retries: int = 0  # overflow retries actually paid
     skipped_windows: int = 0
     domain: int = 0  # the lookup table's length (host_table)
@@ -336,8 +339,6 @@ def choose_join_strategy(left: HostBatch, right: HostBatch, op: JoinOp,
 
     forced = str(get_flag("join_strategy"))
     window_rows = int(get_flag("join_probe_window_rows"))
-    radix_bits = int(get_flag("join_radix_bits"))
-    zone_skip = bool(get_flag("join_zone_skip"))
     tpu = routes.routes_platform() == "tpu"
 
     if not device_only and op.how in ("inner", "left") and (
@@ -346,7 +347,7 @@ def choose_join_strategy(left: HostBatch, right: HostBatch, op: JoinOp,
         # XLA CPU sorts make the device kernels a regression there; the
         # native build+probe hash join is the CPU-backend fast path.
         return JoinDecision(
-            strategy="host_hash", zone_skip=zone_skip,
+            strategy="host_hash",
             reason="cpu backend" if forced == "auto" else "forced",
         )
 
@@ -380,13 +381,11 @@ def choose_join_strategy(left: HostBatch, right: HostBatch, op: JoinOp,
     else:
         build_rows = left.length if swap else right.length
         strategy = (
-            "radix"
-            if radix_bits > 0 and build_rows >= RADIX_MIN_BUILD_ROWS
-            else "sorted"
+            "radix" if build_rows >= RADIX_MIN_BUILD_ROWS else "sorted"
         )
     return JoinDecision(
         strategy=strategy, swap=swap and strategy != "single",
-        window_rows=window_rows, zone_skip=zone_skip,
+        window_rows=window_rows,
         reason="forced" if forced != "auto" else "auto",
     )
 
@@ -478,7 +477,7 @@ def _join_dispatch(left: HostBatch, right: HostBatch, op: JoinOp,
     if engine is not None:
         engine.last_join_decision = decision
     if decision.strategy == "host_hash":
-        return _join_host_nm(left, right, op, right_stats, decision)
+        return _join_host_nm(left, right, op, right_stats)
     return _join_device(left, right, op, engine, decision,
                         left_stats, right_stats, cap_key,
                         planned_capacity=planned_capacity)
@@ -793,15 +792,16 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
     if decision.strategy == "radix":
         from ..ops.join import radix_partition_build
 
-        radix_bits = int(get_flag("join_radix_bits"))
-        order, part_starts, steps = radix_partition_build(bkeys, radix_bits)
+        order, part_starts, steps = radix_partition_build(
+            bkeys, JOIN_RADIX_BITS
+        )
         sbk[:rb] = bkeys[order]
         sbk_dev = jax.device_put(sbk)  # staged once; reused by every window
         starts_dev = jax.device_put(part_starts)
 
         def probe_fn(cap):
             fn = _radix_probe_cache(
-                nb, wcap, cap, op.how, radix_bits, steps
+                nb, wcap, cap, op.how, JOIN_RADIX_BITS, steps
             )
             return lambda pk_dev, pv_dev: fn(
                 sbk_dev, starts_dev, pk_dev, pv_dev
@@ -829,16 +829,14 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
     window_overlap = None  # worst surviving window's zone overlap
     if n_windows > 1:
         # Per-window zones feed BOTH decisions (one cheap pass): which
-        # windows to skip (zone_skip flag), and the capacity estimate's
-        # overlap fraction — which must cover the worst WINDOW, not the
-        # probe-wide average (for clustered probes most windows miss
-        # the build range entirely while the live ones overlap it
-        # almost fully; the whole-probe fraction would understate them
-        # whether or not skipping is enabled).
+        # windows to skip, and the capacity estimate's overlap fraction
+        # — which must cover the worst WINDOW, not the probe-wide
+        # average (for clustered probes most windows miss the build
+        # range entirely while the live ones overlap it almost fully;
+        # the whole-probe fraction would understate them).
         wlo, whi = _window_zones(pkeys, window_rows)
-        if decision.zone_skip:
-            skip = (whi < build_lo) | (wlo > build_hi)
-            decision.skipped_windows = int(skip.sum())
+        skip = (whi < build_lo) | (wlo > build_hi)
+        decision.skipped_windows = int(skip.sum())
         live = ~skip
         if live.any():
             span = np.maximum(whi[live] - wlo[live] + 1, 1)
@@ -937,7 +935,7 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
                 if not bool(overflow):
                     break
                 # Estimate/learned rung was wrong: double, recompile
-                # (counted — the bench gate wants this at zero), and
+                # (counted in pixie_join_capacity_retries_total), and
                 # keep the larger capacity for every later window.
                 capacity *= 2
                 counter.inc()
@@ -1261,7 +1259,7 @@ def _join_host(left: HostBatch, right: HostBatch, op: JoinOp) -> HostBatch:
 
 
 def _join_host_nm(left: HostBatch, right: HostBatch, op: JoinOp,
-                  right_stats=None, decision=None) -> HostBatch:
+                  right_stats=None) -> HostBatch:
     """N:M inner/left equijoin on host — the CPU-backend analog of the
     device kernel (XLA CPU sorts are too slow to route big joins through
     the device path there). The native O(n) build+probe hash join
@@ -1278,10 +1276,7 @@ def _join_host_nm(left: HostBatch, right: HostBatch, op: JoinOp,
     lkeys, rkeys = lk
 
     sel_l = sel_r = None  # compressed-row -> original-row maps
-    if (
-        decision is not None and decision.zone_skip
-        and len(lkeys) and len(rkeys)
-    ):
+    if len(lkeys) and len(rkeys):
         llo, lhi = int(lkeys.min()), int(lkeys.max())
         rlo, rhi = int(rkeys.min()), int(rkeys.max())
         if op.how == "inner" and (llo < rlo or lhi > rhi):

@@ -34,7 +34,8 @@ query's fragment stats (stage ``"stall"``) — always on since the trace
 spine (``trace.py``) passes stats for every query, feeding the
 ``pixie_window_stage_seconds{stage="stall"}`` histogram and
 ``window.stall`` spans (both ends stamped here, where it stalls); engines accumulate per-query and lifetime
-totals for bench.py's overlap report and the observability gauges.
+totals (``Engine.last_pipeline``, ``pipeline_totals``) for the
+observability gauges.
 """
 
 from __future__ import annotations

@@ -51,8 +51,18 @@ from __future__ import annotations
 import threading
 import time
 
-from ..config import get_flag
 from . import threadmap
+
+#: Tracked (program, shape-signature) records a registry keeps, each
+#: holding its XLA executable, compile wall-time and cost/memory
+#: analysis, in an LRU; the oldest is evicted (and recompiled on its
+#: next use).
+PROGRAM_REGISTRY_SIZE = 512
+
+#: Period of the device-memory poll thread (seconds). 0: no thread, and
+#: a query's peak comes from its boundary samples alone; the gauges
+#: refresh at every /metrics scrape regardless.
+DEVICE_MEMORY_POLL_S = 0.0
 
 #: ``pixie_compile_seconds`` buckets: a CPU fragment compiles in
 #: ~10-100ms, a big window-fold program on the TPU in a minute or two.
@@ -276,9 +286,10 @@ class ProgramRegistry:
     runs outside the lock (a miss must not serialize unrelated
     programs behind a multi-second XLA compile)."""
 
-    def __init__(self, metrics_registry=None, size: int | None = None):
+    def __init__(self, metrics_registry=None,
+                 size: int = PROGRAM_REGISTRY_SIZE):
         self._metrics_registry = metrics_registry
-        self._size = size  # None = read program_registry_size per miss
+        self._size = int(size)  # <= 0: nothing is tracked
         self._lock = threading.Lock()
         self._records: dict = {}  # (key, sig) -> ProgramRecord
         self._seq = 0
@@ -307,21 +318,16 @@ class ProgramRegistry:
     # -- wrapping ------------------------------------------------------------
     def wrap(self, fn, kind: str, key, label: str = "", pins=None):
         """Wrap a jitted entry point; returns ``fn`` unchanged when the
-        registry is disabled (``program_registry_size`` <= 0) or ``fn``
+        registry is disabled (``size`` <= 0) or ``fn``
         is not trackable. ``pins`` are objects whose id() participates
         in ``key`` — held by the record so a key match stays valid."""
-        if fn is None or self._max_size() <= 0:
+        if fn is None or self._size <= 0:
             return fn
         if isinstance(fn, TrackedProgram):
             return fn
         if not hasattr(fn, "lower"):
             return fn  # not a jit stage: nothing to AOT-compile
         return TrackedProgram(fn, self, key, kind, label, pins=pins)
-
-    def _max_size(self) -> int:
-        if self._size is not None:
-            return int(self._size)
-        return int(get_flag("program_registry_size"))
 
     # -- metrics -------------------------------------------------------------
     def _m(self) -> dict:
@@ -540,8 +546,7 @@ class ProgramRegistry:
             rec.seq = self._seq
             self._records[(key, sig)] = rec
             evicted = 0
-            max_size = self._max_size()
-            while len(self._records) > max(max_size, 1):
+            while len(self._records) > max(self._size, 1):
                 # Evict least-recently-used by timestamp (insertion
                 # order no longer tracks recency — hits deliberately
                 # skip the pop/reinsert dict churn).
@@ -559,7 +564,7 @@ class ProgramRegistry:
                 gone.seq = self._seq
                 self._evicted[gone.program_id] = gone
                 evicted += 1
-            while len(self._evicted) > 4 * max(max_size, 1):
+            while len(self._evicted) > 4 * max(self._size, 1):
                 self._evicted.pop(next(iter(self._evicted)))
         m = self._m()
         m["misses"].inc()
@@ -680,7 +685,7 @@ class DeviceMemoryMonitor:
     CPU devices return None from ``memory_stats()`` — every consumer of
     this class sees zeros/absent gauges there, never an error (the
     None-guard contract the telemetry tests pin). A poll thread
-    (``device_memory_poll_s`` > 0) tightens per-query peak resolution;
+    (``start(poll_s)`` with a period > 0) tightens per-query peak resolution;
     without it peaks come from the query-boundary samples alone.
     """
 
@@ -765,13 +770,10 @@ class DeviceMemoryMonitor:
                 if kind in stats:
                     g.labels(device=dev, kind=kind).set(stats[kind])
 
-    def start(self, poll_s: float | None = None) -> None:
+    def start(self, poll_s: float = DEVICE_MEMORY_POLL_S) -> None:
         """Start the background poller (no-op when the period is <= 0
         or it is already running)."""
-        period = (
-            float(get_flag("device_memory_poll_s"))
-            if poll_s is None else float(poll_s)
-        )
+        period = float(poll_s)
         if period <= 0 or self._thread is not None:
             return
         self._stop.clear()

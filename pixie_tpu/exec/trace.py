@@ -194,6 +194,10 @@ logger = logging.getLogger("pixie_tpu.slow_query")
 #: million span dicts).
 MAX_SPANS_PER_TRACE = 512
 
+#: Finished query traces a ``Tracer`` keeps in its ring (served by
+#: /debug/queryz; oldest evicted first).
+TRACE_RING_SIZE = 128
+
 #: Sub-second buckets for per-window stage timings (a window stage is
 #: typically 0.1ms..1s; the prometheus defaults top out too coarse).
 STAGE_BUCKETS = (
@@ -1019,11 +1023,9 @@ class Tracer:
     in-flight set, histogram/counter recording, slow-query log, and the
     optional OTLP push. All methods are thread-safe."""
 
-    def __init__(self, registry=None, ring_size: int | None = None):
+    def __init__(self, registry=None, ring_size: int = TRACE_RING_SIZE):
         self._registry = registry  # lazy: services import at first use
-        self._ring: deque = deque(
-            maxlen=int(ring_size or get_flag("trace_ring_size"))
-        )
+        self._ring: deque = deque(maxlen=int(ring_size))
         self._inflight: dict[str, QueryTrace] = {}
         self._lock = threading.Lock()
         self._metrics: dict | None = None
@@ -1167,7 +1169,7 @@ class Tracer:
         with self._lock:
             # Ring-drop accounting (satellite): an evicted trace that
             # never made it out over OTLP is telemetry LOST — count it
-            # so operators can size trace_ring_size / wire an exporter.
+            # so operators can see the ring is short / wire an exporter.
             if (
                 self._ring.maxlen is not None
                 and len(self._ring) == self._ring.maxlen

@@ -1,12 +1,12 @@
 """Predicate-driven window skipping: zone maps vs FilterOp predicates.
 
 Generalizes the join drivers' key-range window skipping
-(``join_zone_skip``) to plain table scans: a FilterOp predicate over a
-sketched column implies a per-column value interval; any scan window
-whose ingest zone map (``table_store/sketches.py``) cannot intersect
-that interval is pruned BEFORE it is staged — and, for cold-tier
-windows, before it is *decoded* (``Table.scan`` / ``device_scan`` /
-the streaming cursor call the pruner first). PAPERS.md
+(``exec/joins.py`` ``_window_zones``) to plain table scans: a FilterOp
+predicate over a sketched column implies a per-column value interval;
+any scan window whose ingest zone map (``table_store/sketches.py``)
+cannot intersect that interval is pruned BEFORE it is staged — and,
+for cold-tier windows, before it is *decoded* (``Table.scan`` /
+``device_scan`` / the streaming cursor call the pruner first). PAPERS.md
 "Provenance-based Data Skipping" (2104.12815) is the shape.
 
 Two halves:
@@ -29,7 +29,7 @@ Two halves:
   thread-local scratch); ``QueryTrace._finalize_usage`` folds the count
   into ``usage.skipped_windows``.
 
-Disable with the ``scan_zone_skip`` flag (bench A/B, debugging).
+Disable with the ``scan_zone_skip`` flag (an A/B, debugging).
 """
 
 from __future__ import annotations

@@ -45,6 +45,12 @@ from .schemas import STACK_TRACES_RELATION, STACKS_RELATION
 #: code paths aliased into one flame box.
 TRUNCATED_MARKER = "...[truncated]"
 
+#: Per-profiler cap on distinct (stack, attribution) keys kept in the
+#: cumulative folded-stack summary that heartbeats ship for the cluster
+#: merge; over the cap the coldest stacks age out (the hottest are kept,
+#: and a survivor's count stays monotonic).
+SUMMARY_STACKS = 512
+
 #: Active connectors (registered in init(), removed in stop()) — the
 #: per-process roster :func:`profile_summary` merges for heartbeats.
 _ACTIVE: list["PerfProfilerConnector"] = []
@@ -103,7 +109,7 @@ class PerfProfilerConnector(SourceConnector):
         # (folded, qid, script_hash, tenant, phase) -> sample count.
         self._counts: dict[tuple, int] = {}
         # Cumulative since start (drained counts fold in here), bounded
-        # by the profile_summary_stacks flag — the heartbeat export.
+        # by SUMMARY_STACKS — the heartbeat export.
         self._summary: dict[tuple, int] = {}
         self._lock = threading.Lock()
 
@@ -172,9 +178,6 @@ class PerfProfilerConnector(SourceConnector):
         # the DataTable buffers until the push period fires (the BPF map
         # drain analog).
         self.sample()
-        from ..config import get_flag
-
-        cap = max(int(get_flag("profile_summary_stacks")), 16)
         with self._lock:
             if not self._counts:
                 return
@@ -182,12 +185,12 @@ class PerfProfilerConnector(SourceConnector):
             self._counts.clear()
             for key, n in items:
                 self._summary[key] = self._summary.get(key, 0) + n
-            if len(self._summary) > cap:
+            if len(self._summary) > SUMMARY_STACKS:
                 # Keep the hottest stacks; cold tails age out. Counts
                 # stay monotonic for survivors (diff-safe).
                 keep = sorted(
                     self._summary.items(), key=lambda kv: -kv[1]
-                )[:cap]
+                )[:SUMMARY_STACKS]
                 self._summary = dict(keep)
         now = time.time_ns()
         # Attributed rows -> the __stacks__ telemetry ring.

@@ -139,16 +139,13 @@ class Agent:
         # merge traces fold into this agent's __queries__/__spans__/
         # __agents__ tables (PxL-queryable, per-agent attribution) and
         # distributed span summaries flow to the broker's tracez view.
-        from ..config import get_flag
+        from .telemetry import enable_self_telemetry
 
-        if get_flag("self_telemetry"):
-            from .telemetry import enable_self_telemetry
-
-            self.telemetry = enable_self_telemetry(
-                self.engine, agent_id=self.agent_id,
-                kind="pem" if self.processes_data else "kelvin",
-                bus=self.bus,
-            )
+        self.telemetry = enable_self_telemetry(
+            self.engine, agent_id=self.agent_id,
+            kind="pem" if self.processes_data else "kelvin",
+            bus=self.bus,
+        )
         self._register()
         background.watch_gc()
         self._hb_thread = threading.Thread(target=self._heartbeat_loop, daemon=True)

@@ -7,8 +7,8 @@ point of failure for the whole serving path. This module runs N
 
 - **Leases, not consensus.** The leader publishes ``broker.lease``
   heartbeats carrying a monotonically-increasing **epoch**
-  (``broker_lease_interval_s`` cadence). Standbys watch; when the lease
-  goes silent past ``broker_lease_expiry_s`` the lowest-id live standby
+  (``LEASE_INTERVAL_S`` cadence). Standbys watch; when the lease
+  goes silent past ``LEASE_EXPIRY_S`` the lowest-id live standby
   claims ``max(seen epochs) + 1`` and publishes its own lease
   immediately. The bus is the arbiter: a split claim resolves on the
   next lease exchange (higher epoch wins; equal epochs tie-break on
@@ -55,6 +55,14 @@ TOPIC_STATE = "broker.state"          # leader -> standbys control-plane log
 TOPIC_LEADER = "broker.leader"        # request/reply: who leads?
 TOPIC_RECONCILE = "broker.reconcile"  # takeover probe -> agents answer
 
+# Cadence of the leader's broker.lease heartbeat and of each standby's
+# expiry check / presence announcement.
+LEASE_INTERVAL_S = 0.5
+# Lease age past which a standby declares the leader dead and the
+# lowest-id standby claims the next epoch (each higher-ranked standby
+# waits one extra lease interval before claiming).
+LEASE_EXPIRY_S = 2.0
+
 
 class _Mirror:
     """A standby's fold of the leader's ``broker.state`` log. Plain
@@ -80,8 +88,8 @@ class BrokerReplica:
         broker_id: str,
         registry=None,
         secret: str | None = None,
-        lease_interval_s: float | None = None,
-        lease_expiry_s: float | None = None,
+        lease_interval_s: float = LEASE_INTERVAL_S,
+        lease_expiry_s: float = LEASE_EXPIRY_S,
         tracker_kw: dict | None = None,
         leader: bool = False,
     ):
@@ -89,14 +97,8 @@ class BrokerReplica:
 
         self.bus = bus
         self.broker_id = broker_id
-        self.lease_interval_s = (
-            float(get_flag("broker_lease_interval_s"))
-            if lease_interval_s is None else float(lease_interval_s)
-        )
-        self.lease_expiry_s = (
-            float(get_flag("broker_lease_expiry_s"))
-            if lease_expiry_s is None else float(lease_expiry_s)
-        )
+        self.lease_interval_s = float(lease_interval_s)
+        self.lease_expiry_s = float(lease_expiry_s)
         self.reconcile_wait_s = float(get_flag("broker_reconcile_wait_s"))
         self.reattach_timeout_s = float(get_flag("broker_reattach_timeout_s"))
 

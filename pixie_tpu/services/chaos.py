@@ -231,9 +231,7 @@ def run_chaos_soak(
     report.threads_before = threading.active_count()
     t0 = time.perf_counter()
 
-    with override_flag("broker_lease_interval_s", 0.1), \
-            override_flag("broker_lease_expiry_s", 0.5), \
-            override_flag("broker_reconcile_wait_s", 0.4), \
+    with override_flag("broker_reconcile_wait_s", 0.4), \
             override_flag("broker_reattach_timeout_s", 8.0):
         bus = MessageBus()
         inj = FaultInjector(seed)
@@ -241,7 +239,8 @@ def run_chaos_soak(
                           flap_threshold=3, flap_window_s=60.0,
                           quarantine_s=1.0)
         replicas = [
-            BrokerReplica(bus, f"broker-{i}", tracker_kw=tracker_kw,
+            BrokerReplica(bus, f"broker-{i}", lease_interval_s=0.1,
+                          lease_expiry_s=0.5, tracker_kw=tracker_kw,
                           leader=(i == 0))
             for i in range(n_brokers)
         ]

@@ -26,6 +26,12 @@ TOPIC_QUARANTINED = "agent.quarantined"
 DEFAULT_EXPIRY_S = 60.0
 DEFAULT_CHECK_INTERVAL_S = 5.0
 
+# Flap detection (see ``AgentTracker.__init__``): expirations within the
+# sliding window that quarantine an agent, and the cooldown.
+DEFAULT_FLAP_THRESHOLD = 3
+DEFAULT_FLAP_WINDOW_S = 300.0
+DEFAULT_QUARANTINE_S = 120.0
+
 #: Bound on per-agent flap-history entries kept by the tracker: with
 #: ephemeral agent ids (pod-suffixed names churning for weeks) the
 #: bookkeeping must not grow without limit.
@@ -59,13 +65,11 @@ class AgentTracker:
         bus: MessageBus,
         expiry_s: float = DEFAULT_EXPIRY_S,
         check_interval_s: float = DEFAULT_CHECK_INTERVAL_S,
-        flap_threshold: int | None = None,
-        flap_window_s: float | None = None,
-        quarantine_s: float | None = None,
+        flap_threshold: int = DEFAULT_FLAP_THRESHOLD,
+        flap_window_s: float = DEFAULT_FLAP_WINDOW_S,
+        quarantine_s: float = DEFAULT_QUARANTINE_S,
         passive: bool = False,
     ):
-        from ..config import get_flag
-
         self.bus = bus
         # Passive (standby-mirror) mode, broker HA: observe the
         # register/heartbeat stream and keep the live-agent map warm,
@@ -80,18 +84,9 @@ class AgentTracker:
         # planning for `quarantine_s` — it may re-register and heartbeat
         # (schemas stay visible) but no new queries are scheduled to it
         # until the cooldown passes.
-        self.flap_threshold = (
-            int(get_flag("agent_flap_threshold"))
-            if flap_threshold is None else int(flap_threshold)
-        )
-        self.flap_window_s = (
-            float(get_flag("agent_flap_window_s"))
-            if flap_window_s is None else float(flap_window_s)
-        )
-        self.quarantine_s = (
-            float(get_flag("agent_quarantine_s"))
-            if quarantine_s is None else float(quarantine_s)
-        )
+        self.flap_threshold = int(flap_threshold)
+        self.flap_window_s = float(flap_window_s)
+        self.quarantine_s = float(quarantine_s)
         self._expiry_history: dict[str, deque] = {}
         self._quarantine_until: dict[str, float] = {}  # aid -> monotonic
         self._lock = threading.Lock()

@@ -21,7 +21,7 @@
 #   ./run_tests.sh --analyze           static analysis gate: pxlint over
 #                                      pixie_tpu/ (all rules, baseline
 #                                      applied) + the plan verifier over
-#                                      every bench shape's compiled
+#                                      every replay shape's compiled
 #                                      plan + the pxbound soundness
 #                                      gate (see --bounds). Non-zero
 #                                      exit on any non-baselined
@@ -31,7 +31,7 @@
 #                                      tests/test_bounds.py + the
 #                                      pxbound soundness check
 #                                      (analysis/bound_check.py):
-#                                      replays all 8 bench shapes and
+#                                      replays the 9 small shapes and
 #                                      the bundled self-monitoring
 #                                      scripts asserting observed
 #                                      QueryResourceUsage <= predicted,
@@ -119,15 +119,6 @@
 #                                      propagation; see
 #                                      docs/STORAGE.md). The file also
 #                                      runs inside the --tier1 sweep.
-#   ./run_tests.sh --bench-join        quick join gate: a small
-#                                      selectivity/skew sweep (uniform
-#                                      vs zipf keys, low/high match
-#                                      rate) through every join
-#                                      strategy, reporting the strategy
-#                                      chosen + capacity retries and
-#                                      failing on any mismatch vs the
-#                                      numpy reference join (see
-#                                      tools/bench_join.py).
 #   ./run_tests.sh --soak              chaos-soak gate: a fixed-seed
 #                                      32-agent / 2-broker soak driving
 #                                      faults x tenancy x concurrency x
@@ -199,11 +190,6 @@ case "$1" in
     exec env JAX_PLATFORMS=cpu \
       python -m pytest -q tests/test_storage_tier.py "$@"
     ;;
-  --bench-join)
-    shift
-    exec env JAX_PLATFORMS=cpu \
-      python tools/bench_join.py "$@"
-    ;;
   --soak)
     shift
     exec env JAX_PLATFORMS=cpu \
@@ -267,7 +253,7 @@ case "$1" in
     ;;
   --tier1)
     # Static-analysis gate first (fast; see --analyze): a non-baselined
-    # lint finding or a bench-shape verification failure fails tier 1.
+    # lint finding or a replay-shape verification failure fails tier 1.
     "$0" --analyze; rc_analyze=$?
     # Self-observability script gate (the pytest half of --obs already
     # runs inside the main sweep below).

@@ -1,7 +1,7 @@
 """Pipelined window executor tests (exec/pipeline.py).
 
 Covers the ISSUE 1 acceptance surface: pipelined-vs-serial bit-identical
-equivalence across all six bench shapes at pipeline_depth 1/2/4,
+equivalence across six of the small-replay shapes at pipeline_depth 1/2/4,
 mid-pipeline cancellation, prefetch-thread exception propagation (the
 original traceback, not a hang), a concurrent-queries stress test
 asserting no thread leaks, the windowed device-join driver, and the
@@ -83,27 +83,154 @@ class TestBitIdenticalEquivalence:
         _assert_no_prefetch_threads()
 
 
-class TestBenchShapeEquivalence:
-    """All six bench shapes, each numpy-cross-checked at depth 1, 2, 4
-    (the bench's own ``checked`` assertion IS the equivalence oracle)."""
+def _groups(*keys):
+    """{key tuple: row indices} over equal-length host columns."""
+    groups = {}
+    for i, k in enumerate(zip(*keys)):
+        groups.setdefault(k, []).append(i)
+    return {k: np.array(v) for k, v in groups.items()}
+
+
+def _assert_rows(got, key_cols, want):
+    """``got`` (an output's columns) holds exactly the keys of ``want``
+    ({key tuple: {column: value}}), once each, with its values: counts
+    and integer sums exactly, means and shares to an f32's precision."""
+    keys = list(zip(*(got[c] for c in key_cols)))
+    assert len(keys) == len(set(keys)) and set(keys) == set(want)
+    for i, k in enumerate(keys):
+        for c, v in want[k].items():
+            rtol = 0 if isinstance(v, (int, np.integer)) else 1e-6
+            np.testing.assert_allclose(
+                got[c][i], v, rtol=rtol, err_msg=f"{k} {c}")
+
+
+def _oracle_http_stats(got, host):
+    h = host["http_events"]
+    ok = h["resp_status"] < 400
+    lat = h["latency_ns"][ok]
+    _assert_rows(got, ("service", "req_path"), {
+        k: {"n": len(i), "lat_mean": lat[i].mean(), "lat_max": lat[i].max()}
+        for k, i in _groups(h["service"][ok], h["req_path"][ok]).items()
+    })
+
+
+def _oracle_service_stats(got, host):
+    h = host["http_events"]
+    lat, failed = h["latency_ns"], h["resp_status"] >= 400
+    groups = _groups(h["service"])
+    _assert_rows(got, ("service",), {
+        k: {"error_rate": failed[i].mean(), "throughput": len(i)}
+        for k, i in groups.items()
+    })
+    # The quantiles are a t-digest's: held to the sample's within 15 %.
+    for s, p50, p99 in zip(got["service"], got["p50"], got["p99"]):
+        r50, r99 = np.quantile(lat[groups[(s,)]], [0.5, 0.99])
+        assert abs(p50 - r50) / r50 < 0.15 and abs(p99 - r99) / r99 < 0.15
+
+
+def _oracle_net_flow_graph(got, host):
+    h = host["conn_stats"]
+    pods_of = {}  # the addrs side: the distinct (src_addr, src_pod)
+    for addr, pod in _groups(h["src_addr"], h["src_pod"]):
+        pods_of.setdefault(addr, []).append(pod)
+    want = {}
+    for (pod, remote), i in _groups(h["src_pod"], h["remote_addr"]).items():
+        for dst in pods_of.get(remote, ()):
+            row = want.setdefault(
+                (pod, dst), {"bytes_sent": 0, "bytes_recv": 0})
+            row["bytes_sent"] += h["bytes_sent"][i].sum()
+            row["bytes_recv"] += h["bytes_recv"][i].sum()
+    assert want  # the replay's addresses join
+    _assert_rows(got, ("src_pod", "src_pod_dst"), want)
+
+
+def _oracle_sql_stats(got, host):
+    from pixie_tpu.udf.builtins.sql_ops import normalize_sql
+
+    h = host["mysql_events"]
+    norm = [normalize_sql(q) for q in h["query_str"]]
+    window = h["time_"] // 1_000_000_000 * 1_000_000_000
+    lat = h["latency_ns"]
+    _assert_rows(got, ("query_norm", "window"), {
+        k: {"n": len(i), "lat_mean": lat[i].mean()}
+        for k, i in _groups(norm, window).items()
+    })
+
+
+def _oracle_perf_flamegraph(got, host):
+    h = host["stack_traces.beta"]
+    count = h["count"]
+    of_pod = {k: count[i].sum() for (k,), i in _groups(h["pod"]).items()}
+    groups = _groups(h["pod"], h["stack_trace_id"])
+    _assert_rows(got, ("pod", "stack_trace_id"), {
+        k: {"count": count[i].sum(),
+            "percent": 100.0 * count[i].sum() / of_pod[k[0]]}
+        for k, i in groups.items()
+    })
+    for pod, sid, st in zip(got["pod"], got["stack_trace_id"],
+                            got["stack_trace"]):
+        assert st in h["stack_trace"][groups[(pod, sid)]]  # px.any
+
+
+def _oracle_device_join(got, host):
+    l, r = host["conn_l"], host["conn_r"]
+    right = {k: r["v"][i] for (k,), i in _groups(r["k"]).items()}
+    want = {}
+    for k, b in zip(l["k"], l["b"]):
+        if k in right:
+            row = want.setdefault((b,), {"n": 0, "s": 0})
+            row["n"] += len(right[k])
+            row["s"] += right[k].sum()
+    _assert_rows(got, ("b",), want)
+
+
+_ORACLES = {
+    "http_stats": _oracle_http_stats,
+    "service_stats": _oracle_service_stats,
+    "net_flow_graph": _oracle_net_flow_graph,
+    "sql_stats": _oracle_sql_stats,
+    "perf_flamegraph": _oracle_perf_flamegraph,
+    "device_join": _oracle_device_join,
+}
+
+
+class TestReplayShapeEquivalence:
+    """Six of the small-replay shapes (analysis/bench_check.py), each
+    run at depth 1, 2, 4 over one engine: the depth's answer equals the
+    serial one, and the serial one is held to a numpy oracle."""
 
     @pytest.mark.parametrize("depth", [1, 2, 4])
-    @pytest.mark.parametrize("shape", [
-        "http_stats", "service_stats", "net_flow_graph",
-        "sql_stats", "perf_flamegraph", "device_join",
-    ])
-    def test_shape_checked_at_depth(self, shape, depth, monkeypatch):
-        import bench
+    @pytest.mark.parametrize("shape", list(_ORACLES))
+    def test_shape_checked_at_depth(self, shape, depth):
+        from pixie_tpu.analysis.bench_check import (
+            SHAPE_SCHEMAS, _shape_query,
+        )
+        from pixie_tpu.analysis.bound_check import _replay_engine
 
-        monkeypatch.setenv("PIXIE_TPU_BENCH_AB", "0")  # A/B covered above
-        config.set_flag("pipeline_depth", depth)
-        try:
-            fn_name, _div = bench.SHAPE_DEFS[shape]
-            res = getattr(bench, fn_name)(4000, W)
-        finally:
-            config.clear_flag("pipeline_depth")
-        assert res["checked"] is True
-        assert res["pipeline"]["depth"] == depth
+        # Small windows (the replay is ~4 of them a table), so that
+        # there is a next window to stage while one computes; the depth
+        # reaches the engine as a deployment's would, by the flag.
+        with config.override_flag("window_rows", W), \
+                config.override_flag("pipeline_depth", depth):
+            eng = _replay_engine(SHAPE_SCHEMAS[shape], rows=4000)
+        query = _shape_query(shape)
+
+        def run():
+            out = eng.execute_query(query, max_output_rows=1 << 20)
+            return out["output"].to_pydict()
+
+        got = run()
+        assert eng.last_pipeline["depth"] == depth
+        eng.pipeline_depth = 1
+        serial = run()
+        assert eng.last_pipeline["depth"] == 1
+        host = {
+            name: t.read_all().to_pydict() for name, t in eng.tables.items()
+        }
+        _ORACLES[shape](serial, host)
+        assert set(got) == set(serial)
+        for c in serial:
+            np.testing.assert_array_equal(serial[c], got[c])
         _assert_no_prefetch_threads()
 
 

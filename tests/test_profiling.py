@@ -6,7 +6,7 @@ attribution majority + cross-tenant isolation + thread-leak freedom,
 the 2-agent cluster merge through heartbeats and /debug/pprof, the
 differential-profile math, the px/query_cpu end-to-end attribution
 proof through a live broker, and the sampler overhead A/B on the
-http_stats bench shape. See docs/OBSERVABILITY.md "Profiling tier".
+http_stats replay shape. See docs/OBSERVABILITY.md "Profiling tier".
 """
 
 from __future__ import annotations
@@ -664,7 +664,7 @@ class TestQueryCpuEndToEnd:
 class TestOverheadAB:
     @pytest.mark.slow
     def test_sampler_overhead_under_five_percent(self):
-        """A/B the http_stats bench shape with and without a live
+        """A/B the http_stats replay shape with and without a live
         100Hz sampler: the measured overhead gates at <5% (the number
         in docs/OBSERVABILITY.md comes from this test's print)."""
         from pixie_tpu.analysis.bench_check import (

@@ -153,7 +153,7 @@ class TestFreshnessCounters:
 
 class TestAppendOverhead:
     """Acceptance: freshness maintenance costs < 3% on the append path
-    (http_stats bench shape rows). A/B against the same append with the
+    (http_stats replay shape rows). A/B against the same append with the
     freshness method stripped (``Table._note_append_freshness`` is the
     exact PR addition; everything else on the path predates it)."""
 

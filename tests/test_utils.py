@@ -168,3 +168,24 @@ class TestJaxCacheDir:
         assert env["XLA_FLAGS"].split() == [
             "--foo", "--xla_force_host_platform_device_count=4"
         ]
+
+
+def test_every_flag_is_read_somewhere():
+    """A flag that no code reads is not an option: every defined flag's
+    name is a string literal somewhere in the package outside config.py
+    (where ``get_flag`` / ``override_flag`` / a deploy role names it)."""
+    import ast
+    import pathlib
+
+    import pixie_tpu
+    from pixie_tpu import config
+
+    root = pathlib.Path(pixie_tpu.__file__).parent
+    literals = set()
+    for path in root.rglob("*.py"):
+        if path == root / "config.py":
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Constant) and isinstance(node.value, str):
+                literals.add(node.value)
+    assert sorted(set(config.all_flags()) - literals) == []
