@@ -1,7 +1,7 @@
 """Chip smoke: the query path, once, on the TPU it was written for.
 
     python chip_smoke.py            # one chip: kernel, engine, served, skew, flow, edges
-    python chip_smoke.py --chips 4  # four chips: kernel + DistributedEngine only
+    python chip_smoke.py --chips 4  # four chips: kernel, DistributedEngine, cluster
 
 One process, no child that needs the chip. A seeded ``http_events``
 replay (five columns, 32 B/row) goes in through the
@@ -37,6 +37,7 @@ REHEARSAL_MAX_ROWS = 1 << 20  # what a run without a TPU may be asked for
 SKEW_ROWS = 1 << 23  # the non-dense phase: four windows at ten columns
 FLOW_ROWS = 1 << 22  # the join phase: two windows at conn_stats' fifteen
 EDGES_ROWS = 1 << 22  # the keyed quantiles phase: two windows at ten columns
+CLUSTER_ROWS = 4 << 21  # the four-node phase: 2^21 rows a node on average
 
 SERVICES = [f"svc-{i}" for i in range(32)]
 PATHS = [f"/api/v1/ep{i}" for i in range(8)]
@@ -766,13 +767,87 @@ def phase_edges(seed: int, rows: int, meter: CompileMeter,
         f"a window's rows were binned: {_fold_routes(eng, 'digest_bins')}")
 
 
+def phase_cluster(seed: int, rows: int, meter: CompileMeter,
+                  on_tpu: bool) -> None:
+    """Four nodes answered as the deployment answers them: configuration
+    ``http_cluster_4chip``'s stack at ``rows`` rows over the cluster (a
+    PEM a node, each an ``Engine`` on a chip of its own holding its
+    node's rows under dictionaries of its own, one Kelvin, one broker),
+    the cell's two scripts over the whole table three times, against the
+    benchmark's plain references over the union: every number inside its
+    limit. Every PEM's programs run on its own chip, the Kelvin merges
+    four payloads a script through remaps that are not empty, and the
+    third run compiles nothing (the second's merge may: the Kelvin has
+    seen the union fit a smaller bucket than the payloads' sum)."""
+    from benchmark import harness
+    from benchmark.builders import served_http_nodes
+
+    spec = harness.load_cell("http_cluster_4chip.cluster_recent")
+    cfg = spec["config"]
+    requests = [
+        {**r, "pxl": r["pxl"].replace(", start_time='-5m'", "")}
+        for r in harness.requests_of(spec)
+    ]
+    t0 = time.perf_counter()
+    data = served_http_nodes.make_data(cfg, seed, rows)
+    stack = served_http_nodes.build(cfg, WINDOW)
+    try:
+        stack.ingest(data)
+        res = stack.resident()
+        emit(phase="cluster", step="ingest", rows=rows,
+             secs=time.perf_counter() - t0, resident=res)
+        assert (res["rows"], res["devices"]) == (rows, cfg["nodes"]), (
+            f"resident {res}, want {rows} rows on {cfg['nodes']} devices")
+        log = harness.SpanLog(stack.tracers)
+        for run in ("first", "again", "warm"):
+            for req in requests:
+                ref = harness.module("reference", req["reference"])
+                mark = meter.mark()
+                t0 = time.perf_counter()
+                got = stack.execute(req["pxl"], 600.0, cfg["t_end_ns"])
+                secs = time.perf_counter() - t0
+                assert not got["partial"], f"{req['name']}: partial"
+                numbers = ref.numbers(ref.rows(got["rows"]),
+                                      ref.answer(data, None))
+                compiled = meter.since(mark)
+                time.sleep(0.1)  # the agents' traces close after eos
+                spans = log.cut()
+                merge = [s.attributes for t in spans["kelvin"]
+                         for s in t.spans if s.name == "device.dispatch"]
+                devices = sorted({
+                    s.attributes["device"] for k, traces in spans.items()
+                    if k.startswith("pem") for t in traces for s in t.spans
+                    if s.name == "device.dispatch"})
+                emit(phase="cluster", query=req["name"], run=run, rows=rows,
+                     secs=secs, answer_rows=len(next(iter(
+                         got["rows"].values()))),
+                     pem_devices=devices, merge=merge, compile=compiled,
+                     numbers=numbers)
+                over = sorted(k for k, v in numbers.items()
+                              if v > ref.LIMITS[k])
+                assert not over, (
+                    f"{req['name']} ({run}): over its limit: {over}")
+                assert len(devices) == cfg["nodes"], (
+                    f"the PEMs' programs ran on devices {devices}")
+                assert merge and merge[-1]["payloads"] == cfg["nodes"], merge
+                assert merge[-1].get("remap_entries", 0) > 0, (
+                    "the nodes' dictionaries were equal")
+                if run == "warm":
+                    assert compiled["programs"] == 0, (
+                        f"{req['name']}: third run compiled "
+                        f"{compiled['programs']} program(s)")
+    finally:
+        stack.close()
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=16 << 20,
                     help="http_events rows (default 16 Mi = 512 MiB)")
     ap.add_argument("--seed", type=int, default=22)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: the DistributedEngine phase and nothing else")
+                    help="4: the DistributedEngine phase and the four-node "
+                         "cluster phase, and nothing else")
     args = ap.parse_args(argv)
 
     from pixie_tpu import native
@@ -814,6 +889,8 @@ def main(argv=None) -> int:
                              args.chips)
             if args.chips == 4:
                 phase_distributed(rp, meter, on_tpu, 4)
+                phase_cluster(args.seed, min(args.rows, CLUSTER_ROWS),
+                              meter, on_tpu)
             else:
                 phase_engine(rp, meter, on_tpu)
                 phase_served(rp, meter, on_tpu)

@@ -422,7 +422,7 @@ def _prepare_merge(engine, payloads, tail, slots: int, key) -> _PreparedMerge:
             # empty dictionary (an agent with no rows) maps all to null.
             padded = np.full(bucket_capacity(len(ids)), NULL_ID, np.int32)
             padded[:len(ids)] = ids
-            remap[pi] = jax.device_put(padded)
+            remap[pi] = engine._put(padded)
     apply_tail, meta, _rel = _bind_post_stage(
         post,
         [
@@ -586,8 +586,25 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge,
                 ]
             with _dispatch(stats, rec.program, "finalize") as span:
                 if span is not None:
-                    span.attributes.update(prepared=prepared, slots=g)
-                out = rec.program(jax.device_put(states), rec.remaps)
+                    # What k agents cost the merge beyond one: the
+                    # states uploaded, the merges folded, the padded
+                    # entries of the remaps read (none where the
+                    # payloads' dictionaries are equal).
+                    span.attributes.update(
+                        prepared=prepared, slots=g,
+                        payloads=len(payloads), merges=len(payloads) - 1,
+                        upload_bytes=sum(
+                            int(a.nbytes)
+                            for a in jax.tree_util.tree_leaves(states)
+                        ),
+                    )
+                    remapped = sum(
+                        int(t.shape[0])
+                        for remap in rec.remaps for t in remap.values()
+                    )
+                    if remapped:  # absent without a remap, as a fold's
+                        span.attributes["remap_entries"] = remapped
+                out = rec.program(engine._put(states), rec.remaps)
             with _device_wait(stats) as wait:
                 out = jax.device_get(out)
                 _note_fetched(wait, jax.tree_util.tree_leaves(out))

@@ -42,7 +42,7 @@ from .bridge import (  # noqa: F401  (re-exported)
     bridge_payload,
     merge_agg_bridge,
 )
-from . import threadmap
+from . import placement, threadmap
 from .fragment import compile_fragment_cached as compile_fragment
 from .fragment import RowSlice, window_rows
 from .pipeline import WindowPipeline
@@ -311,11 +311,23 @@ class Engine:
 
     def __init__(self, registry: Registry | None = None,
                  window_rows: int | None = None,
-                 pipeline_depth: int | None = None):
+                 pipeline_depth: int | None = None,
+                 device=None):
         from ..config import get_flag
         from ..table_store import TableStore
 
         self.registry = registry or default_registry()
+        # The one device this engine lives on (``exec/placement.py``): a
+        # PEM a node on a chip of its own beside other engines of the
+        # process. Its tables stage there, its programs run there, its
+        # results are fetched from there. None: wherever JAX puts
+        # things, as an engine always did.
+        self.device = device
+        self._stage_sharding = None
+        if device is not None:
+            import jax
+
+            self._stage_sharding = jax.sharding.SingleDeviceSharding(device)
         self.table_store = TableStore()
         self.window_rows = window_rows or get_flag("window_rows")
         # Window-executor prefetch depth (pipeline.py): staging of window
@@ -345,6 +357,9 @@ class Engine:
         # execute_plan gets a trace (spans + stats spine, ring-buffered,
         # /debug/queryz). Cheap: timestamps only, no device sync.
         self.tracer = Tracer()
+        # Its spans name the engine's device; one given none learns
+        # where JAX puts its work at its first request (``_device_id``).
+        self.tracer.device_id = None if device is None else device.id
         # Engine-STATE mutation guard. Queries no longer serialize on it
         # (per-query state lives on ``_QueryScratch``); it remains for
         # subclasses that mutate engine-scoped execution state around
@@ -475,8 +490,11 @@ class Engine:
                      max_bytes: int = -1):
         t = self.table_store.add_table(name, relation, max_bytes=max_bytes)
         # Tables created through an engine stage device windows at the
-        # engine's streaming size from the first append on.
+        # engine's streaming size from the first append on, on the
+        # engine's device.
         t.device_window_rows = self.window_rows
+        if self._stage_sharding is not None:
+            t.stage_sharding = self._stage_sharding
         return t
 
     def append_data(self, name: str, data, time_cols=("time_",)):
@@ -484,9 +502,11 @@ class Engine:
         # Atomic get-or-create at THIS engine's streaming window size so
         # first appends stage device windows correctly (and concurrent
         # first appends never replace each other's table).
-        self.table_store.ensure_table(
+        t = self.table_store.ensure_table(
             name, device_window_rows=self.window_rows
         )
+        if self._stage_sharding is not None:
+            t.stage_sharding = self._stage_sharding
         return self.table_store.append_data(name, data, time_cols=time_cols)
 
     # -- execution -----------------------------------------------------------
@@ -737,9 +757,10 @@ class Engine:
             trace = self.tracer.begin_query(
                 script=plan_script(plan), analyze=analyze
             )
+        self._name_device()
         status, error = "ok", ""
         try:
-            with trace.annotation():
+            with trace.annotation(), self._on_device():
                 return self._execute_plan_scoped(
                     plan, bridge_inputs, analyze, materialize, cancel, trace
                 )
@@ -1399,7 +1420,7 @@ class Engine:
         carries = {}
         k = 0
         for out_name, treedef, n_leaves in treedefs:
-            leaves = [jnp.asarray(outs[k + i][:g]) for i in range(n_leaves)]
+            leaves = [self._put(outs[k + i][:g]) for i in range(n_leaves)]
             carries[out_name] = jax.tree_util.tree_unflatten(treedef, leaves)
             k += n_leaves
         for out_name, init, _j, w, mw in digests:
@@ -1413,16 +1434,16 @@ class Engine:
             means = np.where(w2 > 0, mw.reshape(g, b) / np.maximum(w2, 1e-30),
                              0.0).astype(np.float32)
             carries[out_name] = _compress(
-                jnp.asarray(means), jnp.asarray(w2), kk
+                self._put(means), self._put(w2), kk
             )
         count_out = next(
             o for (op, _dt, _a), o in zip(specs, outs) if op == 0
         )
         return {
             "keys": (),
-            "valid": jnp.asarray(count_out[:g] > 0),
+            "valid": self._put(count_out[:g] > 0),
             "carries": carries,
-            "overflow": jnp.asarray(oob_any),
+            "overflow": self._put(np.bool_(oob_any)),
         }
 
     # -- internals -----------------------------------------------------------
@@ -1501,7 +1522,7 @@ class Engine:
 
     def _stage(self, hb: HostBatch, capacity: int):
         """Pad a host window to capacity and place it on device."""
-        db = hb.to_device(capacity)
+        db = hb.to_device(capacity, sharding=self._stage_sharding)
         return db.cols, db.valid
 
     def _check_cancel(self) -> None:
@@ -1575,15 +1596,35 @@ class Engine:
                 d["stage_secs"] += c["stage_secs"]
                 d["stall_secs"] += c["stall_secs"]
 
-    def _put_side(self, v):
-        """Stage one fused-join side table (DistributedEngine replicates
-        over its mesh instead)."""
+    def _on_device(self):
+        """The scope a request of this engine runs in: the thread's
+        uncommitted values and programs go to the engine's device
+        (``exec/placement.py``); no-op on an engine given none."""
+        return placement.scope(self.device)
+
+    def _device_id(self) -> int:
+        """The id of the device this engine's work runs on: its own, or
+        where JAX puts the work of an engine given none."""
         import jax
 
-        return jax.device_put(v)
+        return (self.device or placement.current() or jax.devices()[0]).id
+
+    def _name_device(self) -> None:
+        """Before a request's trace begins its work: the tracer learns
+        the device its ``device.*`` spans name, once."""
+        if self.tracer.device_id is None:
+            self.tracer.device_id = self._device_id()
+
+    def _put(self, v):
+        """THE way a host value (an array or a tree of them) this engine
+        makes on a request's path goes to a device: its own, committed
+        (a fused join's side table, a merge's remaps and shipped states,
+        the native fold's carries). DistributedEngine replicates over
+        its mesh instead."""
+        return placement.put(v, self.device)
 
     def _staged_windows_with_side(self, stream: "_Stream", stats=None):
-        side = {k: self._put_side(v) for k, v in stream.side.items()}
+        side = {k: self._put(v) for k, v in stream.side.items()}
         for cols, valid in self._staged_windows_inner(stream, stats):
             yield {**cols, "__side__": side}, valid
 

@@ -35,6 +35,7 @@ from ..types.dtypes import DataType
 from ..types.strings import NULL_ID, StringDictionary
 from ..udf.registry import Registry
 from ..udf.udf import Executor, apply_cast
+from . import placement
 from . import trace as _trace
 from .plan import ColumnRef, Expr, FuncCall, Literal
 
@@ -66,26 +67,30 @@ _operands = threading.local()
 
 class Operand:
     """One operand table: the host array (padded to its bucket) and its
-    copy on the device, made at the first dispatch that needs it and
-    kept. Remembered on the image it was made from
-    (``DictImage.derived``), so every fragment over one image shares the
-    one copy."""
+    copy on a device, made at the first dispatch there that needs it and
+    kept: one a device, for a fragment is shared through the process's
+    fragment cache by every engine whose dictionaries read alike, and
+    each engine's programs read the copy on ITS device (the scope the
+    call runs in: ``exec/placement.py``). Remembered on the image it was
+    made from (``DictImage.derived``), so every fragment over one image
+    shares the copies."""
 
-    __slots__ = ("host", "_device", "_lock")
+    __slots__ = ("host", "_copies", "_lock")
 
     def __init__(self, host: np.ndarray):
         self.host = host
-        self._device = None
+        self._copies: dict = {}  # device (None: JAX's own) -> array
         self._lock = threading.Lock()
 
     def device(self):
-        if self._device is None:
-            import jax
-
+        where = placement.current()
+        copy = self._copies.get(where)
+        if copy is None:
             with self._lock:
-                if self._device is None:
-                    self._device = jax.device_put(self.host)
-        return self._device
+                copy = self._copies.get(where)
+                if copy is None:
+                    copy = self._copies[where] = placement.put(self.host)
+        return copy
 
 
 @contextlib.contextmanager

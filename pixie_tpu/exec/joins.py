@@ -745,14 +745,16 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
     them outright, left emits their null rows host-side); ``decision``
     may swap probe/build for inner joins.
     """
-    import jax
-
     from ..config import get_flag
+    from . import placement
     from .pipeline import WindowPipeline
     from .stream import _block_if, _device_wait, _dispatch, _timed
 
     if decision is None:
         decision = JoinDecision(strategy="sorted", window_rows=window_rows)
+    # Where the build side and the probe windows go: the engine's device
+    # (the prefetch thread that stages the windows is outside its scope).
+    put = getattr(engine, "_put", placement.put)
     # Under analyze, the join gets its own stage breakdown (stage /
     # compute / stall) like every other window consumer.
     qstats = getattr(engine, "_query_stats", None) if engine is not None \
@@ -796,8 +798,8 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
             bkeys, JOIN_RADIX_BITS
         )
         sbk[:rb] = bkeys[order]
-        sbk_dev = jax.device_put(sbk)  # staged once; reused by every window
-        starts_dev = jax.device_put(part_starts)
+        sbk_dev = put(sbk)  # staged once; reused by every window
+        starts_dev = put(part_starts)
 
         def probe_fn(cap):
             fn = _radix_probe_cache(
@@ -809,7 +811,7 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
     else:
         order = np.argsort(bkeys, kind="stable")
         sbk[:rb] = bkeys[order]
-        sbk_dev = jax.device_put(sbk)
+        sbk_dev = put(sbk)
         rb_s = np.int32(rb)
 
         def probe_fn(cap):
@@ -854,7 +856,7 @@ def _join_device_windowed(left: HostBatch, right: HostBatch, op: JoinOp,
         pk[:m] = pkeys[off:off + m]
         pv = np.zeros(wcap, dtype=bool)
         pv[:m] = True
-        return m, jax.device_put(pk), jax.device_put(pv)
+        return m, put(pk), put(pv)
 
     def staged_probe_windows():
         for w in range(n_windows):

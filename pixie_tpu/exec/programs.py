@@ -51,7 +51,7 @@ from __future__ import annotations
 import threading
 import time
 
-from . import threadmap
+from . import placement, threadmap
 
 #: Tracked (program, shape-signature) records a registry keeps, each
 #: holding its XLA executable, compile wall-time and cost/memory
@@ -80,10 +80,14 @@ _MEM_KINDS = (
 
 def shape_signature(args) -> tuple:
     """Hashable signature of a call's input pytree: treedef + per-leaf
-    (shape, dtype-or-type, sharding). Exactly the distinctions XLA
-    compiles separate programs for — two calls with equal signatures
-    may share one executable. ~7µs per call (hot-path budget: one per
-    tracked dispatch, i.e. per window)."""
+    (shape, dtype-or-type, sharding), and the device of the scope the
+    call runs in (``exec/placement.py``; None outside one). Exactly the
+    distinctions XLA compiles separate programs for — two calls with
+    equal signatures may share one executable. A leaf committed to a
+    device says so in its sharding; a call whose leaves are host arrays
+    and scalars runs where its scope says, and an executable compiled
+    for one engine's device must not serve another's. ~7µs per call
+    (hot-path budget: one per tracked dispatch, i.e. per window)."""
     from jax import tree_util
 
     leaves, treedef = tree_util.tree_flatten(args)
@@ -94,7 +98,7 @@ def shape_signature(args) -> tuple:
             getattr(leaf, "sharding", None),
         )
         for leaf in leaves
-    ))
+    ), placement.current())
 
 
 class ProgramRecord:
@@ -662,7 +666,7 @@ def _sig_repr(sig) -> str:
     """Compact human form of a shape signature for programz/telemetry:
     the distinct leaf shapes with multiplicities, e.g.
     '3x[131072]float32,[scalar]int32'."""
-    _treedef, leaves = sig
+    _treedef, leaves = sig[:2]
     counts: dict = {}
     for shape, dtype, _sharding in leaves:
         name = getattr(dtype, "name", None) or getattr(

@@ -199,6 +199,7 @@ class StreamingQuery:
         # last so earlier __init__ raises can't leak an in-flight trace.
         from .trace import plan_script
 
+        engine._name_device()
         self.trace = engine.tracer.begin_query(
             script=script or plan_script(plan), kind="stream"
         )
@@ -433,7 +434,8 @@ class StreamingQuery:
     def poll(self) -> int:
         """Fold new rows; emit updates. Returns rows consumed."""
         self._note_freshness()
-        return self._poll_inner()
+        with self.engine._on_device():  # the engine's device: placement.py
+            return self._poll_inner()
 
     def _poll_inner(self) -> int:
         frag = self._frag

@@ -31,7 +31,10 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   carry windows, rows in/out and the per-stage second totals
 - ``device.dispatch``     one per program enqueued: the host's enqueue
   call (attributes ``program`` as ``ProgramRegistry`` names its kind,
-  ``windows``, and on a window-fold program ``fold``: ``pallas_int``,
+  ``windows``, ``device``: the id of the device the engine lives on
+  (``Engine(device=...)``, ``exec/placement.py``; an engine given none
+  names the device JAX puts its work on, a mesh engine its mesh's
+  first), and on a window-fold program ``fold``: ``pallas_int``,
   ``pallas_f32``, ``sorted_digest``, ``keyed_digest``, ``xla``,
   ``mixed:...`` or ``sorted_int``, as ``CompiledFragment.fold``
   decided at compile time, with ``group``: ``dense`` / ``sorted`` /
@@ -53,7 +56,14 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   resident with a row range, ``rows``: the rows of them the program
   folded, a power-of-two slice around the range or the whole capacity,
   summed over a run's windows, ``exec/stream.py`` ``_fold_rows``, and
-  ``range_rows``: the rows in range among them); child of its fragment
+  ``range_rows``: the rows in range among them; on the Kelvin's
+  ``merge_finalize`` ``prepared``: ``hit`` / ``miss``, ``slots``, and
+  what k agents cost it: ``payloads`` (k), ``merges`` (k - 1),
+  ``remap_entries`` (the padded entries of the key-id remaps it reads,
+  absent where the agents' dictionaries are equal) and ``upload_bytes`` (the
+  compacted states put on its device), counted in
+  ``usage.merge_payloads`` / ``merge_remap_entries`` /
+  ``merge_upload_bytes``); child of its fragment
 - ``rebucket``            one per re-fold after a group-capacity overflow
   (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
   rows, ``kelvin`` the merge of states): the compile at twice the slots
@@ -76,7 +86,8 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   plane) to the last leaf on the host: one batched get
   (``stream._fetch_tree``) of copies started where the program was
   enqueued (``stream._start_fetch``), so only what is still in flight
-  is waited for here (attributes ``leaves``, ``bytes``). Counted in
+  is waited for here (attributes ``leaves``, ``bytes`` and ``device``,
+  as on ``device.dispatch``). Counted in
   ``usage.bytes_fetched`` / ``usage.fetches``
 - ``plan.walk``           child of the root: the plan's loop between the
   ops that run work (sources found, chains extended), one span a stretch
@@ -136,7 +147,8 @@ Kelvin's root: merge installed until the last bridge payload is in) and
 bus). The binder's: ``dict_udf`` (``dict_udf_span`` below). The
 broker's stages on its ``distributed`` trace
 (``services/query_broker.py``): ``snapshot``, ``compile``, ``plan``,
-``admit``, ``register``, ``dispatch`` (``dispatch.retry`` a re-publish),
+``admit``, ``register``, ``dispatch`` (``agents``: how many agents the
+request fans out to; ``dispatch.retry`` a re-publish),
 ``await`` > ``await.results`` / ``await.stats``, ``finish``, and
 ``failover`` where an agent was lost.
 
@@ -336,6 +348,16 @@ class QueryResourceUsage:
       content, or built it (``exec/bridge.py`` ``_PreparedMerge``; the
       ``prepared`` attr of a ``merge_finalize`` dispatch). A warm script
       reads one hit a request
+    - ``merge_payloads`` / ``merge_remap_entries`` / ``merge_upload_bytes``
+      what k agents cost the Kelvin's merges beyond one: the partial-agg
+      states they folded (k a merge: a fold of k - 1 merges), the padded
+      entries of the key-id remaps their programs read (one a payload
+      and string key column whose dictionary is not the canonical one's
+      prefix; 0 where the agents' dictionaries are equal), and the bytes
+      of the compacted states uploaded to the Kelvin's device: the
+      ``payloads``, ``remap_entries`` and ``upload_bytes`` of the
+      ``merge_finalize`` dispatches (a re-fold after an overflow counts
+      again: it uploads again)
     - ``join_rows_in`` / ``join_rows_out`` rows the query's joins took
       in (build + probe) and gave out: the ``join`` spans' ``build_rows``
       + ``probe_rows`` and ``rows_out`` (a span around every ``JoinOp``)
@@ -382,6 +404,9 @@ class QueryResourceUsage:
     rebuckets: int = 0
     merge_prepared_hits: int = 0
     merge_prepared_misses: int = 0
+    merge_payloads: int = 0
+    merge_remap_entries: int = 0
+    merge_upload_bytes: int = 0
     join_rows_in: int = 0
     join_rows_out: int = 0
     dict_udf_strings: int = 0
@@ -413,6 +438,7 @@ class QueryResourceUsage:
             "bytes_restaged", "bytes_fetched", "fetches", "wire_bytes",
             "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
+            "merge_payloads", "merge_remap_entries", "merge_upload_bytes",
             "join_rows_in", "join_rows_out", "dict_udf_strings",
             "answer_rows", "string_bytes_out", "digest_bytes",
             "skipped_windows",
@@ -608,11 +634,20 @@ class TracedFragment(FragmentStats):
         """A named span under the fragment's, or under ``parent``: a
         span of this fragment (a ``device.fetch`` inside its
         ``device.wait``)."""
+        if name == "device.fetch":
+            self._name_device(attrs)
         return _FragmentSpanCtx(self, name, attrs, parent=parent)
+
+    def _name_device(self, attrs: dict) -> None:
+        """``device``: the id of the device the engine lives on."""
+        device_id = getattr(self.trace.tracer, "device_id", None)
+        if device_id is not None:
+            attrs["device"] = device_id
 
     def dispatch(self, program: str, stage: str = "compute",
                  windows: int = 1) -> _FragmentSpanCtx:
         attrs = {"program": program, "windows": int(windows)}
+        self._name_device(attrs)
         if self.fold and stage == "compute":  # a window-fold program
             attrs["fold"] = self.fold
             if self.group:
@@ -902,10 +937,15 @@ class QueryTrace:
                 # both.
                 u.bytes_fetched += s.attributes["bytes"]
                 u.fetches += 1
-            elif s.attributes.get("prepared") == "hit":
-                u.merge_prepared_hits += 1
-            elif s.attributes.get("prepared") == "miss":
-                u.merge_prepared_misses += 1
+            elif "prepared" in s.attributes:  # a ``merge_finalize``
+                a = s.attributes
+                if a["prepared"] == "hit":
+                    u.merge_prepared_hits += 1
+                elif a["prepared"] == "miss":
+                    u.merge_prepared_misses += 1
+                u.merge_payloads += a.get("payloads", 0)
+                u.merge_remap_entries += a.get("remap_entries", 0)
+                u.merge_upload_bytes += a.get("upload_bytes", 0)
         compile_span = next(
             (s for s in self.spans if s.name == "compile"), None
         )
@@ -1037,6 +1077,10 @@ class Tracer:
         # must never fail or slow the query that produced the trace.
         self._listeners: list = []
         self._closed = False
+        # The id of the device the tracer's engine lives on (the engine
+        # sets it; None on a tracer of no engine, the broker's): the
+        # ``device`` of its traces' ``device.dispatch`` / ``device.fetch``.
+        self.device_id = None
 
     def add_listener(self, fn) -> None:
         """Register ``fn(trace)`` to run on every finished trace."""

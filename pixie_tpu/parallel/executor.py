@@ -254,10 +254,14 @@ class DistributedEngine(Engine):
         db = hb.to_device(capacity, sharding=row_sharding(self.mesh))
         return db.cols, db.valid
 
-    def _put_side(self, v):
+    def _put(self, v):
         """Fused-join side tables replicate over the mesh (the steps'
         P() in_spec); a device-0-committed array would conflict."""
         return jax.device_put(v, jax.sharding.NamedSharding(self.mesh, P()))
+
+    def _device_id(self) -> int:
+        """A mesh engine's spans name its mesh's first device."""
+        return self._base_mesh.devices.flat[0].id
 
     def _dist_step(self, frag, range_valid: bool, agg: bool):
         """Per-(fragment, mesh, valid-form) compiled step — fresh jits

@@ -1670,6 +1670,10 @@ class QueryBroker:
                 sp.attributes.update({
                     "data_agents": ",".join(data_agents),
                     "merge_agent": merge_agent,
+                    # How many agents the request fans out to: its data
+                    # agents and, where it merges, the merge agent.
+                    "agents": len(set(data_agents) | (
+                        {merge_agent} if merge_agent else set())),
                 })
                 # Trace stitching: every dispatch carries the dispatch
                 # span's context envelope, so each agent's fragment/merge

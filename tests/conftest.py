@@ -184,6 +184,24 @@ _SUPERSEDED = {
         )
         for platform, states in (("tpu", 3), ("cpu", 12))
     },
+    "tests/benchmark/test_fold_fill.py::"
+    "test_the_metric_is_filed_under_the_engine": (
+        "asserts fold_fill_pct is the LAST per-layer metric; PR 46 appended "
+        "its cell's five after it (new entries go last): the entry is held, "
+        "by name, by tests/benchmark/test_http_cluster.py::"
+        "test_fold_fill_pct_is_as_it_was_filed, the order by ::"
+        "test_what_was_filed_is_a_prefix_of_the_list"
+    ),
+    # PR 46: a second four-chip cell (http_cluster_4chip.cluster_recent:
+    # four PEMs, a chip each, behind one broker).
+    "tests/benchmark/test_span_readers.py::"
+    "test_benchmark_json_has_the_span_metrics_and_the_four_chip_cell": (
+        "asserts len(cells4) == 1; PR 46 appended a second four-chip cell "
+        "(of nine: the limit is four): every other assertion of this test, "
+        "and len(cells4) == 2 <= len(workloads) // 2, is held by "
+        "tests/benchmark/test_http_cluster.py::"
+        "test_benchmark_json_has_the_span_metrics_and_two_four_chip_cells"
+    ),
 }
 
 

@@ -232,7 +232,8 @@ def rehearsed():
     for cell in ("http_pem_1chip.dash_recent", "conn_flow_1chip.flow_recent",
                  "sql_stats_1chip.sql_recent",
                  "stack_flame_1chip.flame_recent",
-                 "http_edges_1chip.graph_recent"):
+                 "http_edges_1chip.graph_recent",
+                 "http_cluster_4chip.cluster_recent"):
         spec = harness.load_cell(cell)
         cfg, traffic = spec["config"], spec["traffic"]
         builder = harness.module("builders", cfg["builder"])
@@ -323,6 +324,93 @@ def test_a_served_quantiles_fold_says_its_digests(rehearsed_names):
         for s in t.spans:
             assert not set(s.attributes) & {*DIGEST_ATTRIBUTES,
                                             "digest_bytes"}, s.name
+
+
+#: What the Kelvin's ``merge_finalize`` dispatch says of its k payloads
+#: (PR 46), the usage record's counters of them, and the device every
+#: ``device.dispatch`` and ``device.fetch`` names.
+MERGE_ATTRIBUTES = ("payloads", "merges", "remap_entries", "upload_bytes")
+MERGE_COUNTERS = ("merge_payloads", "merge_remap_entries",
+                  "merge_upload_bytes")
+CLUSTER = "http_cluster_4chip.cluster_recent"
+
+
+def test_the_docs_and_the_docstring_name_the_device_and_the_merges_cost():
+    doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
+    for text, where in ((doc, "docs/OBSERVABILITY.md"),
+                        (trace_mod.__doc__, "trace.py's docstring")):
+        for word in (*MERGE_ATTRIBUTES, *MERGE_COUNTERS, "device", "agents",
+                     "placement"):
+            assert re.search(rf"\b{word}\b", text), (where, word)
+    usage = trace_mod.QueryResourceUsage()
+    for counter in MERGE_COUNTERS:
+        assert counter in trace_mod.QueryResourceUsage.__doc__
+        assert getattr(usage, counter) == 0
+    other = trace_mod.QueryResourceUsage(merge_payloads=4,
+                                         merge_upload_bytes=10)
+    usage.merge(other)
+    usage.merge(other.to_dict())
+    assert (usage.merge_payloads, usage.merge_upload_bytes) == (8, 20)
+
+
+def test_every_served_dispatch_and_fetch_names_its_device(rehearsed_names):
+    """An engine's spans name its device: the one it was given (a PEM a
+    node on a device of its own in the cluster's cell, the Kelvin beside
+    node 0's) or, given none, the device JAX puts its work on. The
+    broker's spans name none."""
+    for cell, spans in rehearsed_names.items():
+        for tracer, traces in spans.items():
+            want = ({"pem.1": 1, "pem.2": 2, "pem.3": 3}.get(tracer, 0)
+                    if cell == CLUSTER else 0)
+            named = [s for t in traces for s in t.spans
+                     if s.name in ("device.dispatch", "device.fetch")]
+            assert bool(named) == (tracer != "broker"), (cell, tracer)
+            assert all(s.attributes["device"] == want for s in named), (
+                cell, tracer)
+            assert not any("device" in s.attributes for t in traces
+                           for s in t.spans if s not in named), (cell, tracer)
+    (dispatch,) = _named(rehearsed_names[CLUSTER]["broker"][-1], "dispatch")
+    assert dispatch.attributes["agents"] == 4 + 1
+    (dispatch,) = _named(
+        rehearsed_names["http_pem_1chip.dash_recent"]["broker"][-1],
+        "dispatch")
+    assert dispatch.attributes["agents"] == 1 + 1
+
+
+def test_a_served_merge_says_what_its_payloads_cost(rehearsed_names):
+    """One PEM: one payload, no merge folded, no remap read. Four PEMs
+    with dictionaries of their own: four payloads, three merges, a remap
+    a payload and string key column; the usage record sums them, and no
+    other span carries them."""
+    for cell, spans in rehearsed_names.items():
+        k = 4 if cell == CLUSTER else 1
+        merges = [(t, s) for t in spans["kelvin"]
+                  for s in _named(t, "device.dispatch")
+                  if s.attributes["program"] == "merge_finalize"]
+        assert merges, cell
+        for t, s in merges:
+            a = s.attributes
+            assert (a["payloads"], a["merges"]) == (k, k - 1), (cell, a)
+            # (Absent without a remap, as a fold program's.)
+            assert (a.get("remap_entries", 0) > 0) == (k > 1) == (
+                "remap_entries" in a), (cell, a)
+            assert a["upload_bytes"] > 0
+        for t in spans["kelvin"]:
+            mine = [s.attributes for _t, s in merges if _t is t]
+            for counter, attr in zip(MERGE_COUNTERS, (
+                    "payloads", "remap_entries", "upload_bytes")):
+                assert getattr(t.usage, counter) == sum(
+                    a.get(attr, 0) for a in mine), (cell, counter)
+        for tracer, traces in spans.items():
+            for t in traces:
+                if tracer.startswith("pem"):  # (the broker's sums them)
+                    assert not any(getattr(t.usage, c, 0)
+                                   for c in MERGE_COUNTERS), (cell, tracer)
+                for s in t.spans:
+                    if s.attributes.get("program") != "merge_finalize":
+                        assert not set(s.attributes) & {
+                            "payloads", "merges", "upload_bytes"} or (
+                            s.name == "merge.compact"), (cell, s.name)
 
 
 #: What a fold of resident windows says of the rows it was handed (PR

@@ -55,6 +55,7 @@ def test_one_served_requests_span_shape(window):
     slots = fold["slots"]
     assert fold == {
         "program": "fragment_scan_fold", "windows": fold["windows"],
+        "device": 0,  # (PR 46) an engine's spans name its device
         "fold": "mixed:sorted_int=3,keyed_digest=3", "group": "sorted",
         "slots": slots, "digests": 1, "digest_outputs": 3,
         "digest_slots": slots * 128, "digest_bins": 1 << 32,

@@ -46,6 +46,17 @@ sum at 2^20 slots (``update_all``) and its joint-key sketch, the dense
 fold by pod, the Kelvin's two ``merge_finalize``, the single-shot device
 join of 4,096 + 2^20 rows into 2^21 output slots.
 
+The ``cluster`` case (PR 46) is ``http_cluster_4chip.cluster_recent``:
+its two scripts served by FOUR PEMs, a node's rows and dictionaries each
+(``benchmark/builders/served_http_nodes.py``), so the Kelvin's prepared
+merges hold four payloads and remaps that are not empty. What a cold run
+of the cell compiles, lowered AND compiled at the cell's shapes
+(``CLUSTER``): a PEM's keyed folds of one window (``update``; with
+``--rows 1048576`` the slice the cell's five minutes take) at 2^15 and
+2^16 slots with their joint-key sketches, and ``merge_finalize`` of four
+states at the bucket of their sum (2^17, 2^18) and at the one the union
+was seen to fit (2^16); the line carries ``remap_entries``.
+
 ``--rows N`` (PR 44) lowers the window programs alone (``update``,
 ``update_all``) as the engine calls them for a range short against its
 window: handed N rows of each 2^21-row plane from a start that is an
@@ -117,6 +128,20 @@ EDGES = {
     "mean+count+sum+_quantile_p50+_quantile_p90+_quantile_p99"
     "_by_remote_addr_pod_service": (1 << 17, 1 << 17, WINDOW),
 }
+#: The ``cluster`` case's shapes, as ``FLOW``'s, with FOUR payloads a
+#: merge: a PEM of ``http_cluster_4chip.cluster_recent`` settles on 2^15
+#: slots for its node's 18.8 k live edges and on 2^16 for its 47-48 k
+#: (service, req_path) groups, and folds one sliced window a script (run
+#: with ``--rows 1048576``); the Kelvin merges four states of that bucket
+#: each at the bucket of the live groups' SUM (2^17 for 75 k edges,
+#: disjoint; 2^18 for 190 k groups) and, once it has seen the union fit a
+#: smaller one, there (``CLUSTER_KNOWN``: 63 k groups in 2^16).
+CLUSTER = {
+    "mean+count+sum+_quantile_p50+_quantile_p90+_quantile_p99"
+    "_by_remote_addr_pod_service": (1 << 15, 1 << 15, WINDOW),
+    "count+mean+max_by_service_req_path": (1 << 16, 1 << 16, WINDOW),
+}
+CLUSTER_KNOWN = {"count+mean+max_by_service_req_path": 1 << 16}
 
 
 def _sized(case):
@@ -128,13 +153,17 @@ def _sized(case):
         return FLAME
     if case.startswith("edges"):
         return EDGES
+    if case.startswith("cluster"):
+        return CLUSTER
     return SQL if case.startswith("sql") else None
 
 
 def _capture(batches, table="http_events",
-             scripts=("px/http_stats", "px/service_stats")):
+             scripts=("px/http_stats", "px/service_stats"), more_pems=()):
     """Every (who, ops, relation, dicts, allow_dense, col_stats) of an
-    aggregate fragment compiled while the scripts are served."""
+    aggregate fragment compiled while the scripts are served.
+    ``more_pems``: the batches of each further PEM (a node a PEM, with
+    dictionaries of its own: the Kelvin then merges k payloads)."""
     from pixie_tpu.exec import fragment
     from pixie_tpu.exec.engine import Engine
     from pixie_tpu.scripts import load_script
@@ -167,11 +196,17 @@ def _capture(batches, table="http_events",
     pem = PEMAgent(bus, "pem-0", heartbeat_interval_s=0.05,
                    engine=Engine(window_rows=SMALL_WINDOW)).start()
     kelvin = KelvinAgent(bus, "kelvin-0", heartbeat_interval_s=0.05).start()
+    pems = [pem] + [
+        PEMAgent(bus, f"pem-{n}", heartbeat_interval_s=0.05,
+                 engine=Engine(window_rows=SMALL_WINDOW)).start()
+        for n in range(1, 1 + len(more_pems))
+    ]
     try:
-        for hb in batches:
-            pem.append_data(table, hb)
-        pem._register()
-        _wait_for_table(tracker, table)
+        for agent, its in zip(pems, (batches, *more_pems)):
+            for hb in its:
+                agent.append_data(table, hb)
+            agent._register()
+        _wait_for_table(tracker, table, len(pems))
         broker = QueryBroker(bus, tracker)
         for script in scripts:
             # A bundled script's name, or the text of a traffic's own.
@@ -182,18 +217,19 @@ def _capture(batches, table="http_events",
     finally:
         fragment.compile_fragment = real
         bridge._prepare_merge = real_prepare
-        pem.stop()
+        for agent in pems:
+            agent.stop()
         kelvin.stop()
         tracker.close()
         bus.close()
     return seen, merges
 
 
-def _wait_for_table(tracker, table):
+def _wait_for_table(tracker, table, pems=1):
     import time
 
     deadline = time.time() + 30
-    while not tracker.distributed_state().pems_with_table(table):
+    while len(tracker.distributed_state().pems_with_table(table)) < pems:
         assert time.time() < deadline, "the PEM's schema did not reach the tracker"
         time.sleep(0.01)
 
@@ -275,8 +311,13 @@ def _lower(case, captured, topo_device, out_dir, lines, rows=None):
         if slots is not None:
             ops = [dataclasses.replace(op, max_groups=slots)
                    if isinstance(op, AggOp) else op for op in ops]
+        # (A node's dictionaries hold the strings of its rows alone: at
+        # the served run's size their product is a dense domain, at the
+        # cell's 6.6 k addresses x 1,024 pods, 65 k paths x 32 services,
+        # it is not: the cluster's PEM chains are compiled keyed.)
         frag = compile_fragment(ops, relation, dicts, default_registry(),
-                                allow_dense, col_stats=col_stats)
+                                allow_dense and case != "cluster",
+                                col_stats=col_stats)
         # The flow case's unmerged chain is the Kelvin's re-aggregation.
         on_kelvin = not allow_dense or (flow and merged_at is None)
         who = "kelvin" if on_kelvin else "pem"
@@ -431,8 +472,11 @@ def _lower_merges(case, merges, topo_device, out_dir, lines):
     from pixie_tpu.types.batch import bucket_capacity
     from pixie_tpu.udf.registry import default_registry
 
+    from pixie_tpu.exec import placement
+
     chip = SingleDeviceSharding(topo_device)
-    engine = types.SimpleNamespace(registry=default_registry())
+    engine = types.SimpleNamespace(registry=default_registry(),
+                                   _put=placement.put)
     for payloads, tail in merges:
         slots = [bridge._live_slots(p.state) for p in payloads]
         caps = [cap for _idx, _live, cap in slots]
@@ -441,25 +485,36 @@ def _lower_merges(case, merges, topo_device, out_dir, lines):
         elif _sized(case) is not None:
             caps = [_sized(case)[_agg_label(payloads[0].chain)][1]] * len(
                 payloads)
-        g = bucket_capacity(sum(caps))
-        rec = bridge._prepare_merge(engine, payloads, tail, g, None)
-        name = (f"{case}.kelvin.{_agg_label(payloads[0].chain)}"
-                f".k{len(payloads)}.{rec.frag.group}{g}")
-        if any(line["case"] == name for line in lines):
-            continue
-        states = [
-            jax.tree_util.tree_map(
-                lambda a, have=have, cap=cap: jax.ShapeDtypeStruct(
-                    (cap,) + a.shape[1:] if a.ndim and a.shape[0] == have
-                    else a.shape, a.dtype, sharding=chip),
-                bridge._explicit_state(p, idx, rec.key_types),
-            )
-            for p, (idx, _live, have), cap in zip(payloads, slots, caps)
-        ]
-        lowered = rec.program.lower(states, rec.remaps)
-        _record(lines, out_dir, name, "merge_finalize", rec.frag.fold,
-                _without_kernel_locations(lowered.as_text()),
-                compile_s=_compile_s(lowered))
+        buckets = [bucket_capacity(sum(caps))]
+        if case == "cluster":  # and the bucket the union was seen to fit
+            known = CLUSTER_KNOWN.get(_agg_label(payloads[0].chain))
+            buckets += [known] if known else []
+        for g in buckets:
+            rec = bridge._prepare_merge(engine, payloads, tail, g, None)
+            name = (f"{case}.kelvin.{_agg_label(payloads[0].chain)}"
+                    f".k{len(payloads)}.{rec.frag.group}{g}")
+            if any(line["case"] == name for line in lines):
+                continue
+            states = [
+                jax.tree_util.tree_map(
+                    lambda a, have=have, cap=cap: jax.ShapeDtypeStruct(
+                        (cap,) + a.shape[1:] if a.ndim and a.shape[0] == have
+                        else a.shape, a.dtype, sharding=chip),
+                    bridge._explicit_state(p, idx, rec.key_types),
+                )
+                for p, (idx, _live, have), cap in zip(payloads, slots, caps)
+            ]
+            # (The remaps are arrays of the host's backend here: their
+            # shapes, on the described device.)
+            remaps = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=chip), rec.remaps)
+            lowered = rec.program.lower(states, remaps)
+            _record(lines, out_dir, name, "merge_finalize", rec.frag.fold,
+                    _without_kernel_locations(lowered.as_text()),
+                    compile_s=_compile_s(lowered),
+                    remap_entries=sum(int(t.shape[0]) for remap in rec.remaps
+                                      for t in remap.values()))
 
 
 def main():
@@ -467,7 +522,7 @@ def main():
     ap.add_argument("--out", default=None, help="directory for the texts")
     ap.add_argument("--cases", default="dense,keyed,flow",
                     help="comma-separated, of dense, keyed, flow, digest, "
-                         "sql, flame, edges")
+                         "sql, flame, edges, cluster")
     ap.add_argument("--rows", type=int, default=None,
                     help="lower the window programs alone, handed this many "
                          "rows of each 2^21-row plane (a fragment.RowSlice: "
@@ -480,8 +535,8 @@ def main():
 
     import pixie_tpu  # noqa: F401
     from benchmark.builders import (
-        served_conn, served_http_edges, served_http_skew, served_sql,
-        served_stacks,
+        served_conn, served_http_edges, served_http_nodes, served_http_skew,
+        served_sql, served_stacks,
     )
     from pixie_tpu.ingest.replay import gen_http_events
 
@@ -526,13 +581,26 @@ def main():
             list(served_http_edges.batches(data, SMALL_WINDOW, 0, SQL_ROWS)),
             scripts=(pxl,))
 
+    def cluster():
+        cfg = {**config("http_cluster_4chip"), "requires": {}}
+        data = served_http_nodes.make_data(cfg, 4_600_000_019, SQL_ROWS)
+        pxl = []
+        for name in ("service_graph", "http_stats"):
+            with open(os.path.join(ROOT, "benchmark", "traffic",
+                                   "cluster_recent", f"{name}.pxl")) as f:
+                pxl.append(f.read())
+        first, *rest = (
+            list(served_http_skew.batches(part, SMALL_WINDOW))
+            for part in data["parts"])
+        return _capture(first, scripts=tuple(pxl), more_pems=rest)
+
     cases = {
         "dense": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "keyed": keyed, "flow": flow,
         "digest": lambda: _capture(list(gen_http_events(ROWS, seed=3))),
         "sql": lambda: sql(3_400_000_019),
         "sql2": lambda: sql(3_400_000_023),
-        "flame": flame, "edges": edges,
+        "flame": flame, "edges": edges, "cluster": cluster,
     }
     wanted = args.cases.split(",")
     if "sql" in wanted:
