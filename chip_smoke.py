@@ -814,6 +814,11 @@ def phase_cluster(seed: int, rows: int, meter: CompileMeter,
                 spans = log.cut()
                 merge = [s.attributes for t in spans["kelvin"]
                          for s in t.spans if s.name == "device.dispatch"]
+                # (PR 47) what the k-way fold joined, on the merge's wait.
+                joined = [
+                    {k: s.attributes[k] for k in ("contended_slots", "rebins")}
+                    for t in spans["kelvin"] for s in t.spans
+                    if s.name == "device.wait" and "rebins" in s.attributes]
                 devices = sorted({
                     s.attributes["device"] for k, traces in spans.items()
                     if k.startswith("pem") for t in traces for s in t.spans
@@ -821,8 +826,8 @@ def phase_cluster(seed: int, rows: int, meter: CompileMeter,
                 emit(phase="cluster", query=req["name"], run=run, rows=rows,
                      secs=secs, answer_rows=len(next(iter(
                          got["rows"].values()))),
-                     pem_devices=devices, merge=merge, compile=compiled,
-                     numbers=numbers)
+                     pem_devices=devices, merge=merge, joined=joined,
+                     compile=compiled, numbers=numbers)
                 over = sorted(k for k, v in numbers.items()
                               if v > ref.LIMITS[k])
                 assert not over, (
@@ -832,6 +837,12 @@ def phase_cluster(seed: int, rows: int, meter: CompileMeter,
                 assert merge and merge[-1]["payloads"] == cfg["nodes"], merge
                 assert merge[-1].get("remap_entries", 0) > 0, (
                     "the nodes' dictionaries were equal")
+                # Four keyed payloads fold ONCE; the graph's edges are
+                # disjoint by node (nothing re-binned), the (service,
+                # req_path) groups overlap and hold no digest.
+                assert joined and joined[-1]["rebins"] == 0, joined
+                assert (joined[-1]["contended_slots"] > 0) == (
+                    req["name"] == "px/http_stats"), (req["name"], joined)
                 if run == "warm":
                     assert compiled["programs"] == 0, (
                         f"{req['name']}: third run compiled "

@@ -268,7 +268,9 @@ def bind_bridge(payloads):
 # that pads, remaps, folds, finalizes and applies them. One path for
 # every number of payloads and every layout; what it does follows what
 # it observes: equal ``content_key``s, no remap; live counts, the
-# capacity; k payloads, a fold of k - 1 merges.
+# capacity; k >= 2 payloads of a keyed sort fold, ONE k-way fold at their
+# own sizes (``fragment.py`` ``merge_many``), of another fold k - 1
+# merges.
 
 #: Prepared merges an engine keeps (a Kelvin serves a handful of
 #: scripts; a record pins a fragment, a program and its dictionaries).
@@ -453,23 +455,30 @@ def _prepare_merge(engine, payloads, tail, slots: int, key) -> _PreparedMerge:
 
 def _merge_program(frag, apply_tail, meta):
     """The one program of a prepared merge: each state as it arrived
-    (compacted, explicit keys) padded into ``frag``'s neutral slots,
-    string key ids remapped where there is a remap, the k - 1 merges
-    folded, the finalize, the plan's ops after it. Returns the planes
-    the host batch reads, their validity, the overflow flag and the
-    union's live groups."""
+    (compacted, explicit keys), string key ids remapped where there is a
+    remap; k >= 2 states folded ONCE at their own sizes where the merge
+    fragment's fold offers that (``frag.merge_many``: the keyed sort
+    fold's), else padded into ``frag``'s neutral slots and the k - 1
+    merges folded (nothing to fold for one); the finalize, the plan's
+    ops after it. Returns the planes the host batch reads, their
+    validity, the overflow flag and the union's live groups; after a
+    k-way fold also its ``contended_slots`` and ``rebins``."""
     import jax
     import jax.numpy as jnp
 
+    def cast(a, i):
+        return jnp.asarray(a, i.dtype)
+
     def pad(a, i):
-        a = jnp.asarray(a, i.dtype)
+        a = cast(a, i)
         if a.ndim == 0 or a.shape[0] >= i.shape[0]:
             return a
         return jnp.concatenate([a, i[a.shape[0]:]])
 
     def merge_finalize(states, remaps):
         init = frag.init_state()
-        padded = []
+        many = len(states) > 1 and frag.merge_many is not None
+        arrived = []
         for s, remap in zip(states, remaps):
             keys = list(s["keys"])
             for pi, table in remap.items():
@@ -478,19 +487,27 @@ def _merge_program(frag, apply_tail, meta):
                     ids >= 0, table[jnp.clip(ids, 0, table.shape[0] - 1)],
                     NULL_ID,
                 ).astype(jnp.int32)
-            padded.append(jax.tree_util.tree_map(
-                pad, {**s, "keys": tuple(keys)}, init
+            arrived.append(jax.tree_util.tree_map(
+                cast if many else pad, {**s, "keys": tuple(keys)}, init
             ))
-        # The fold of the k - 1 merges as a scan over the stacked
-        # states: one merge body in the program whatever k is (a keyed
-        # merge takes the chip's compiler most of a minute), and an
-        # empty scan for one payload.
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
-        acc, _ = jax.lax.scan(
-            lambda acc, s: (frag.merge_states(acc, s), None),
-            jax.tree_util.tree_map(lambda x: x[0], stacked),
-            jax.tree_util.tree_map(lambda x: x[1:], stacked),
-        )
+        counts = ()
+        if many:
+            # One fold of the states' N slots as they came: no pad to the
+            # union's capacity, no stack, no merge a state.
+            acc, told = frag.merge_many(arrived)
+            counts = (told["contended_slots"], told["rebins"])
+        else:
+            # The fold of the k - 1 merges as a scan over the stacked
+            # states: one merge body in the program whatever k is (a
+            # keyed merge takes the chip's compiler most of a minute),
+            # and an empty scan for one payload.
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *arrived)
+            acc, _ = jax.lax.scan(
+                lambda acc, s: (frag.merge_states(acc, s), None),
+                jax.tree_util.tree_map(lambda x: x[0], stacked),
+                jax.tree_util.tree_map(lambda x: x[1:], stacked),
+            )
         live = jnp.sum(acc["valid"], dtype=jnp.int32)
         cols, valid, overflow = frag.finalize_state(acc)
         cols, valid = apply_tail(cols, valid)
@@ -506,7 +523,7 @@ def _merge_program(frag, apply_tail, meta):
             )
             for m in meta
         }
-        return planes, valid, overflow, live
+        return (planes, valid, overflow, live, *counts)
 
     return jax.jit(merge_finalize)
 
@@ -608,7 +625,15 @@ def merge_agg_bridge(engine, pending: _PendingAggBridge,
             with _device_wait(stats) as wait:
                 out = jax.device_get(out)
                 _note_fetched(wait, jax.tree_util.tree_leaves(out))
-                cols, valid, overflowed, live = out
+                cols, valid, overflowed, live, *folded = out
+                if wait is not None and folded:
+                    # What the k-way fold saw (they ride the one fetch):
+                    # the merged slots two or more states filled, and
+                    # the ``merge_ordered`` runs their digests took.
+                    wait.attributes.update(
+                        contended_slots=int(folded[0]),
+                        rebins=int(folded[1]),
+                    )
         if not overflowed:
             break
         if g * 2 > get_flag("max_groups_limit"):

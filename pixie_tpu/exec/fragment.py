@@ -45,6 +45,7 @@ from ..ops.groupby import (
 from ..ops.tdigest import (
     digest_merge,
     digest_quantile,
+    merge_ordered,
     ordered_batch_to_digest,
 )
 from ..types.dtypes import DataType, device_dtypes, pad_values
@@ -91,6 +92,10 @@ class CompiledFragment:
     window_state: object = None  # (cols, valid) -> per-window group state
     merge_states: object = None  # (state_a, state_b) -> merged state
     finalize_state: object = None  # ``finalize`` before jit (the merge tier)
+    # [k >= 2 states as they arrived] -> (merged state, {counts}) in one
+    # fold (the keyed sort fold's ``merge_many``); None: the merge tier
+    # folds ``merge_states`` over them.
+    merge_many: object = None
     # Dense fragments whose aggregates are all count/sum/mean/min/max
     # expose the native-fold seam: {"inputs_jit": (cols, valid) ->
     # (gids, an argument a carry, oob), "plan": ((out_name, uda_name,
@@ -762,6 +767,10 @@ class _Fold(NamedTuple):
     # (state, cols, valid), pre-stage applied -> the state with the window
     # folded in; None: ``merge(state, window(cols, valid))``.
     absorb: object = None
+    # [k >= 2 states, each at its own length] -> (merged state, {counts}):
+    # the Kelvin's k-way fold (``exec/bridge.py``); None: ``merge`` folded
+    # over the states padded to g.
+    merge_many: object = None
 
 
 def _dense_slot_ids(plan, rel1, key_plane_index, cols, valid):
@@ -1092,11 +1101,12 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
             at += k
         return planes
 
-    def _sorted_state(key_planes, valid, leaves):
+    def _counted_state(key_planes, valid, leaves):
         """``sorted_group_fold`` over N partial groups. ``leaves`` maps an
         aggregate's out_name to its statistic planes in carry order, each
-        ("sum" | "max" | "min" | "rows", int64[N] or None): the new [g]
-        state. ``rows`` is a window's count (every valid row counts one)."""
+        ("sum" | "max" | "min" | "rows", int64[N] or None): (the new [g]
+        state, int32[g] the partial groups each slot folded). ``rows`` is
+        a window's count (every valid row counts one)."""
         sums, maxes = [], []
         for kinds in leaves.values():
             for kind, v in kinds:
@@ -1131,7 +1141,12 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
             "valid": valid_g,
             "carries": carries,
             "overflow": n_groups > g,
-        }
+        }, rows
+
+    def _sorted_state(key_planes, valid, leaves):
+        """``_counted_state``'s [g] state alone: a window's, a pairwise
+        merge's."""
+        return _counted_state(key_planes, valid, leaves)[0]
 
     # A window's statistic planes by aggregate, each (kind, which distinct
     # argument expression): static, so the span can say how they ride.
@@ -1270,6 +1285,111 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
         )
         return merged
 
+    def moved_digests(states, key_planes, valid, contended):
+        """({out_name: merged digest}, ``merge_ordered`` runs) of a k-way
+        fold: ONE ``sorted_slot_ids`` over the N partial groups says where
+        each goes. A merged slot one state fills takes that state's [K]
+        rows as they came, bit for bit: one move of N rows. A slot two or
+        more fill (``contended``, bool[g]) is re-binned by
+        ``merge_ordered`` folded over the states that fill it, in their
+        order, and the program runs that fold only when such a slot
+        exists: the rule is a slot's own."""
+        with jax.named_scope("digest_slots"):
+            dest = sorted_slot_ids(_key_words(key_planes), valid, g, folded)
+        n = dest.shape[0]
+        iota = jnp.arange(n, dtype=jnp.int32)
+
+        def sources(lo, hi):
+            """The row of [lo, hi) that fills each merged slot (n, the
+            empty row below, where none does)."""
+            return jnp.full(g + 1, n, jnp.int32).at[dest[lo:hi]].set(
+                iota[lo:hi])[:g]
+
+        rows = {
+            ae.out_name: tuple(
+                jnp.concatenate([*planes, jnp.zeros_like(planes[0][:1])])
+                for planes in zip(*(s["carries"][ae.out_name] for s in states))
+            )
+            for ae, _uda, _b, _c in digest_aggs
+        }
+
+        def placed(src):
+            return {out: tuple(jnp.take(p, src, axis=0) for p in carry)
+                    for out, carry in rows.items()}
+
+        def rebinned(moved):
+            ends = [0]  # where each state's rows lie among the N
+            for s in states:
+                ends.append(ends[-1] + s["valid"].shape[0])
+            src = jnp.stack(
+                [sources(lo, hi) for lo, hi in zip(ends, ends[1:])])
+
+            def fold(acc, src_b):
+                b, out = placed(src_b), {}
+                for name, (ma, wa) in acc.items():
+                    mb, wb = b[name]
+                    has_a = jnp.any(wa > 0, axis=-1, keepdims=True)
+                    has_b = jnp.any(wb > 0, axis=-1, keepdims=True)
+                    with jax.named_scope("digest_merge"):
+                        merged = merge_ordered((ma, wa), (mb, wb))
+                    out[name] = tuple(
+                        jnp.where(has_a & has_b, m, jnp.where(has_b, pb, pa))
+                        for m, pa, pb in zip(merged, (ma, wa), (mb, wb))
+                    )
+                return out, None
+
+            acc, _ = jax.lax.scan(fold, placed(src[0]), src[1:])
+            return {
+                name: tuple(jnp.where(contended[:, None], a, m)
+                            for a, m in zip(acc[name], moved[name]))
+                for name in moved
+            }
+
+        any_contended = jnp.any(contended)
+        carries = jax.lax.cond(
+            any_contended, rebinned, lambda moved: moved, placed(sources(0, n)))
+        runs = (len(states) - 1) * len(rows)
+        return carries, jnp.where(any_contended, runs, 0).astype(jnp.int32)
+
+    def merge_many(states):
+        """(merged state, {``contended_slots``, ``rebins``}) of k >= 2
+        states, each at its own length, in ONE fold: their slots are N
+        partial groups, concatenated as they came and folded by one set
+        of sorts into the g slots (no pad to g, no k - 1 pairwise merges
+        at 2 g); the digests follow by ``moved_digests``. What the
+        pairwise ``merge`` folded over the states gives, but for a digest
+        no other state joins, which is the one that was shipped."""
+        def cat(*xs):
+            return jnp.concatenate([jnp.asarray(x) for x in xs])
+
+        leaves = {}
+        for ae, _uda, _b, _c in int_aggs:
+            cs = [s["carries"][ae.out_name] for s in states]
+            if ae.uda_name == "mean":
+                leaves[ae.out_name] = (
+                    ("sum", cat(*(c[0] for c in cs))),
+                    ("sum", cat(*(c[1] for c in cs))),
+                )
+            else:
+                (kind, _fkey), = spec[ae.out_name]
+                kind = kind if kind in ("max", "min") else "sum"
+                leaves[ae.out_name] = ((kind, cat(*cs)),)
+        key_planes = [cat(*ks) for ks in zip(*(s["keys"] for s in states))]
+        valid = cat(*(s["valid"] for s in states))
+        merged, filled = _counted_state(key_planes, valid, leaves)
+        contended = filled > 1
+        rebins = jnp.zeros((), jnp.int32)
+        if digest_aggs:
+            digests, rebins = moved_digests(
+                states, key_planes, valid, contended)
+            merged["carries"].update(digests)
+        for s in states:
+            merged["overflow"] = merged["overflow"] | s["overflow"]
+        return merged, {
+            "contended_slots": jnp.sum(contended, dtype=jnp.int32),
+            "rebins": rebins,
+        }
+
     def absorb(state, cols, valid):
         """The state with a window folded in. A window long against the
         slots folds alone and merges (``_front``'s rule, n >= 4 g). One
@@ -1304,6 +1424,7 @@ def _sorted_fold(plan, aggs_bound, rel1, key_plane_index) -> _Fold:
     return _Fold(
         _keyed_init_keys(g, rel1, key_plane_index), window, merge,
         lambda state: state["keys"], ride, None if digest_aggs else absorb,
+        merge_many,
     )
 
 
@@ -1724,6 +1845,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         limit=limit,
         window_state=window_state,
         merge_states=merge_states,
+        merge_many=fold.merge_many,
         native_fold=native_fold,
         apply_rows=apply_pre,
         key_plane_index=tuple(key_plane_index),

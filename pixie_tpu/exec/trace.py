@@ -63,7 +63,11 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   absent where the agents' dictionaries are equal) and ``upload_bytes`` (the
   compacted states put on its device), counted in
   ``usage.merge_payloads`` / ``merge_remap_entries`` /
-  ``merge_upload_bytes``); child of its fragment
+  ``merge_upload_bytes``; the k - 1 states beyond the first are folded
+  in by ONE k-way fold at the states' own sizes where the merge's fold
+  is the keyed sort fold's, ``exec/fragment.py`` ``merge_many``, by
+  k - 1 pairwise merges at the union's capacity otherwise); child of
+  its fragment
 - ``rebucket``            one per re-fold after a group-capacity overflow
   (attributes ``from``, ``to`` slots, ``where``: ``pem`` the fold of
   rows, ``kelvin`` the merge of states): the compile at twice the slots
@@ -80,7 +84,14 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
 - ``device.wait``         the host asks for a result until the bytes are
   on the host, at the sync the path has anyway (where the path's one
   batched ``jax.device_get`` is also its sync the span carries
-  ``leaves`` and ``bytes`` itself)
+  ``leaves`` and ``bytes`` itself; the wait of a ``merge_finalize``
+  that folded k >= 2 keyed states in one k-way fold also says what the
+  fold saw, two scalars beside its answer: ``contended_slots``, the
+  merged slots that two or more states filled, and ``rebins``, the
+  ``merge_ordered`` runs its digests took: k - 1 a digest carry where a
+  slot was contended, 0 where every slot's digest moved as it was
+  shipped, and for a chain that holds none; ``rebins`` is counted in
+  ``usage.merge_rebins``)
 - ``device.fetch``        child of its ``device.wait``: from the instant
   the path's own sync has returned (an overflow scalar, a validity
   plane) to the last leaf on the host: one batched get
@@ -358,6 +369,11 @@ class QueryResourceUsage:
       ``payloads``, ``remap_entries`` and ``upload_bytes`` of the
       ``merge_finalize`` dispatches (a re-fold after an overflow counts
       again: it uploads again)
+    - ``merge_rebins`` the ``merge_ordered`` runs of the Kelvin's k-way
+      folds of k >= 2 keyed states (0 where the agents' groups are
+      disjoint: a digest nothing joins moves to its slot as it was
+      shipped): the ``rebins`` of the merges' ``device.wait`` spans
+      (absent, and 0 here, for one payload and for a pairwise fold)
     - ``join_rows_in`` / ``join_rows_out`` rows the query's joins took
       in (build + probe) and gave out: the ``join`` spans' ``build_rows``
       + ``probe_rows`` and ``rows_out`` (a span around every ``JoinOp``)
@@ -407,6 +423,7 @@ class QueryResourceUsage:
     merge_payloads: int = 0
     merge_remap_entries: int = 0
     merge_upload_bytes: int = 0
+    merge_rebins: int = 0
     join_rows_in: int = 0
     join_rows_out: int = 0
     dict_udf_strings: int = 0
@@ -439,6 +456,7 @@ class QueryResourceUsage:
             "retries", "rebuckets",
             "merge_prepared_hits", "merge_prepared_misses",
             "merge_payloads", "merge_remap_entries", "merge_upload_bytes",
+            "merge_rebins",
             "join_rows_in", "join_rows_out", "dict_udf_strings",
             "answer_rows", "string_bytes_out", "digest_bytes",
             "skipped_windows",
@@ -937,6 +955,8 @@ class QueryTrace:
                 # both.
                 u.bytes_fetched += s.attributes["bytes"]
                 u.fetches += 1
+                # (A k-way merge's wait also says what its fold joined.)
+                u.merge_rebins += s.attributes.get("rebins", 0)
             elif "prepared" in s.attributes:  # a ``merge_finalize``
                 a = s.attributes
                 if a["prepared"] == "hit":

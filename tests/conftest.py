@@ -202,6 +202,23 @@ _SUPERSEDED = {
         "tests/benchmark/test_http_cluster.py::"
         "test_benchmark_json_has_the_span_metrics_and_two_four_chip_cells"
     ),
+    # PR 47: the k-way merge's counter as a per-layer metric.
+    "tests/benchmark/test_http_cluster.py::"
+    "test_what_was_filed_is_a_prefix_of_the_list[per_layer]": (
+        "asserts the per-layer list ends with PR 46's five; PR 47 appended "
+        "merge_rebins after them (new entries go last): that what was "
+        "filed is a prefix of the list is held, relatively, by "
+        "tests/benchmark/test_merge_rebins.py::"
+        "test_the_metric_is_filed_under_the_engine_after_what_was_there"
+    ),
+    "tests/benchmark/test_http_cluster.py::"
+    "test_nothing_the_benchmark_had_lists_the_new_cell": (
+        "asserts that PR 46's five per-layer metrics alone list the "
+        "four-node cell; PR 47's merge_rebins lists it too (a new entry, "
+        "no accepted one edited): held, with that one more name, by "
+        "tests/benchmark/test_merge_rebins.py::"
+        "test_nothing_that_was_filed_before_lists_the_four_node_cell"
+    ),
 }
 
 

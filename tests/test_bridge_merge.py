@@ -22,6 +22,7 @@ from pixie_tpu.exec.plan import (
     ResultSinkOp,
 )
 from pixie_tpu.planner.distributed.splitter import Splitter
+from pixie_tpu.types.batch import bucket_capacity
 from pixie_tpu.types.strings import StringDictionary
 
 KS = (1, 2, 4)
@@ -45,6 +46,25 @@ def _compile_meter():
 
     jax.monitoring.register_event_duration_secs_listener(_on_compile)
     yield
+    _drop_programs()
+
+
+def _drop_programs():
+    """Let go of every executable this process holds. An executable of
+    the CPU backend maps its code, some 300 mappings a keyed merge, and
+    the process's caches pin them: this file's cases (each compiles a
+    merge of its own, by design) would walk an xdist worker into
+    ``vm.max_map_count`` (65,530) and the compiler into a segfault."""
+    from pixie_tpu.exec import fragment, programs
+
+    programs.default_program_registry().clear()
+    fragment._FRAGMENT_CACHE.clear()
+
+
+@pytest.fixture
+def fresh_programs():
+    yield
+    _drop_programs()
 
 
 @contextlib.contextmanager
@@ -525,3 +545,325 @@ def test_served_refresh_span_shape():
     assert read("device_wait_ms", ctx) + read("dispatch_ms", ctx) <= interval
     assert read("merge_ms", ctx) < tail
     assert read("broker_self_ms", ctx) < head + tail
+
+
+# -- the k-way fold (PR 47) ---------------------------------------------------
+# k >= 2 payloads of a keyed sort fold merge in ONE fold at their own
+# sizes (``exec/fragment.py`` ``merge_many``): on the chip's routes, which
+# these cases run under. The cases above run the CPU's, whose merge
+# fragment is the id-form fold: the scan of pairwise merges.
+
+MANY_KS = (2, 3, 4)
+KEY_SETS = ("disjoint", "overlapping", "mixed")
+BUCKETS = ("equal", "differ", "empty")
+MANY_DICTS = ("equal", "differ")
+SVCS = [f"svc-{i}" for i in range(6)]
+
+
+def _many_rows(a: int, key_set: str, size: str, dicts: str) -> dict:
+    """Agent ``a``'s rows: groups by (svc, code), a handful of rows each.
+    ``key_set``: the agents' groups are their own, the same, or half and
+    half; ``size``: ``small`` (72 live groups: the least bucket, 1,024),
+    ``large`` (1,560: the next one) or ``empty`` (no row); ``dicts``:
+    every agent learns the services in one order, or in an order of its
+    own with a service of its own ahead of them."""
+    if size == "empty":
+        return {"time_": np.empty(0, np.int64), "svc": np.empty(0, dtype=str),
+                "code": np.empty(0, np.int64), "lat": np.empty(0, np.int64),
+                "tag": np.empty(0, np.int64)}
+    rng = np.random.default_rng(4700 + a)
+    codes = 260 if size == "large" else 12
+    shared = {"disjoint": 0, "overlapping": codes, "mixed": codes // 2}[key_set]
+    code = np.array([10**9 * (0 if j < shared else a + 1) + 7 * j
+                     for j in range(codes)])
+    names = SVCS if dicts == "equal" else (
+        [f"only-a{a}"] + SVCS[a:] + SVCS[:a])
+    first = [(s, c) for s in names for c in code
+             if not s.startswith("only")]
+    if key_set != "overlapping" and dicts == "differ":
+        first = [(names[0], int(code[-1]))] + first
+    elif dicts == "differ":  # (its own service learnt, no group under it)
+        names = names[1:]
+        first = [(s, c) for s in names for c in code]
+    more = [first[i] for i in rng.integers(0, len(first), 4 * len(first))]
+    groups = first + more
+    return {
+        "time_": np.arange(len(groups), dtype=np.int64),
+        "svc": [s for s, _c in groups],
+        "code": np.array([c for _s, c in groups], dtype=np.int64),
+        "lat": rng.integers(1, 10**9, len(groups)).astype(np.int64),
+        # One value a group, so that ``any`` has one answer.
+        "tag": np.array([c % 1000 + len(s) for s, c in groups],
+                        dtype=np.int64),
+    }
+
+
+def _many_split():
+    aggs = (AggExpr("n", "count", (C("lat"),)),
+            AggExpr("total", "sum", (C("lat"),)),
+            AggExpr("worst", "max", (C("lat"),)),
+            AggExpr("some", "any", (C("tag"),)),
+            AggExpr("p50", "_quantile_p50", (C("lat"),)))
+    p = Plan()
+    src = p.add(MemorySourceOp(table="t"))
+    agg = p.add(AggOp(("svc", "code"), aggs), [src])
+    out = p.add(MapOp(exprs=tuple(
+        [("service", C("svc")), ("code", C("code"))]
+        + [(a.out_name, C(a.out_name)) for a in aggs]
+    )), [agg])
+    p.add(ResultSinkOp("output"), [out])
+    return Splitter().split(p)
+
+
+_many_payloads: dict = {}
+
+
+def _many_payload(a, key_set, size, dicts):
+    """(rows, payload) of one agent, made once a module run: the same
+    agent serves every k."""
+    key = (a, key_set, size, dicts)
+    if key not in _many_payloads:
+        rows = _many_rows(a, key_set, size, dicts)
+        (payload,) = _payloads(_many_split(), [_agent(rows)])
+        _many_payloads[key] = (rows, payload)
+    return _many_payloads[key]
+
+
+def _sizes(k: int, buckets: str) -> list:
+    """``equal``: every agent's live groups share a bucket; ``differ``:
+    agent 1's take the next one; ``empty``: the last agent has no row."""
+    sizes = ["small"] * k
+    if buckets == "differ":
+        sizes[1] = "large"
+    elif buckets == "empty":
+        sizes[-1] = "empty"
+    return sizes
+
+
+def _many_reference(all_rows) -> dict:
+    groups: dict = {}
+    for a, rows in enumerate(all_rows):
+        for s, c, lat, tag in zip(rows["svc"], rows["code"].tolist(),
+                                  rows["lat"].tolist(), rows["tag"].tolist()):
+            g = groups.setdefault((s, c), {"lat": [], "tag": set(),
+                                           "agents": set()})
+            g["lat"].append(lat)
+            g["tag"].add(tag)
+            g["agents"].add(a)
+    return groups
+
+
+def _arrived(rec, payloads) -> list:
+    """The states as ``merge_finalize`` hands them to the fold: compacted,
+    explicit keys, string ids remapped into the canonical dictionary."""
+    from pixie_tpu.exec import bridge
+    from pixie_tpu.types.strings import NULL_ID
+
+    states = []
+    for p, remap in zip(payloads, rec.remaps):
+        idx, _live, _cap = bridge._live_slots(p.state)
+        s = bridge._explicit_state(p, idx, rec.key_types)
+        keys = list(s["keys"])
+        for pi, table in remap.items():
+            table = np.asarray(table)
+            ids = np.asarray(keys[pi])
+            keys[pi] = np.where(
+                ids >= 0, table[np.clip(ids, 0, len(table) - 1)], NULL_ID
+            ).astype(np.int32)
+        states.append({**s, "keys": tuple(keys)})
+    return states
+
+
+def _bits(plane):
+    return np.ascontiguousarray(plane, dtype=np.float32).view(np.uint32)
+
+
+def _assert_digests_moved_or_rebinned(rec, payloads, contended_want: int):
+    """The k-way fold's digests, slot for slot: the one state's row bit
+    for bit where one state fills the slot; ``merge_ordered`` folded over
+    the states that fill it, in payload order, where several do."""
+    import jax
+
+    from pixie_tpu.ops.tdigest import merge_ordered
+
+    states = _arrived(rec, payloads)
+    merged, told = jax.jit(rec.frag.merge_many)(states)
+    merged = jax.tree_util.tree_map(np.asarray, merged)
+    fills: dict = {}  # key -> [(state, slot)], in payload order
+    for j, s in enumerate(states):
+        for slot in np.nonzero(np.asarray(s["valid"]))[0]:
+            fills.setdefault(
+                tuple(int(k[slot]) for k in s["keys"]), []
+            ).append((j, int(slot)))
+    live = np.nonzero(merged["valid"])[0]
+    assert len(live) == len(fills)
+    assert int(told["contended_slots"]) == contended_want == sum(
+        len(f) > 1 for f in fills.values())
+    by_pattern: dict = {}  # the states that fill a slot -> its slots
+    for slot in live:
+        key = tuple(int(k[slot]) for k in merged["keys"])
+        by_pattern.setdefault(
+            tuple(j for j, _s in fills[key]), []
+        ).append((int(slot), [s for _j, s in fills[key]]))
+    got = merged["carries"]["p50"]
+    for pattern, slots in by_pattern.items():
+        at = np.array([slot for slot, _src in slots])
+        sides = [
+            tuple(np.asarray(states[j]["carries"]["p50"][plane])[
+                np.array([src[n] for _slot, src in slots])]
+                for plane in (0, 1))
+            for n, j in enumerate(pattern)
+        ]
+        want = sides[0]
+        for side in sides[1:]:
+            want = jax.jit(merge_ordered)(want, side)
+        for plane in (0, 1):
+            if len(pattern) == 1:  # as it was shipped, bit for bit
+                np.testing.assert_array_equal(
+                    _bits(got[plane][at]), _bits(want[plane]))
+            else:
+                np.testing.assert_allclose(
+                    got[plane][at], np.asarray(want[plane]), rtol=1e-6)
+    # A slot no state fills holds the empty digest.
+    dead = ~merged["valid"]
+    assert not got[0][dead].any() and not got[1][dead].any()
+
+
+@pytest.mark.parametrize("dicts", MANY_DICTS)
+@pytest.mark.parametrize("buckets", BUCKETS)
+@pytest.mark.parametrize("key_set", KEY_SETS)
+@pytest.mark.parametrize("k", MANY_KS)
+def test_k_payloads_fold_once(k, key_set, buckets, dicts, fresh_programs):
+    """k >= 2 keyed payloads under the chip's routes: keys, counts, sums,
+    ``max`` and ``any`` equal the union-and-reduce reference exactly; a
+    digest no other payload joins is the shipped one bit for bit, a
+    joined one ``merge_ordered`` over its contributors; the wait says
+    what the fold joined."""
+    with routes_of("tpu"):
+        made = [_many_payload(a, key_set, size, dicts)
+                for a, size in enumerate(_sizes(k, buckets))]
+        all_rows = [rows for rows, _p in made]
+        payloads = [p for _rows, p in made]
+        caps = [_cap(p) for p in payloads]
+        assert (len(set(caps)) > 1) == (buckets == "differ"), caps
+        assert payloads[-1].state["valid"].any() == (buckets != "empty")
+        full = k - (buckets == "empty")  # (an empty agent learnt nothing)
+        same = len({p.input_dicts["svc"].content_key()
+                    for p in payloads[:full]}) == 1
+        assert same == (dicts == "equal" or full < 2)
+        kelvin = Engine()
+        out = kelvin.execute_plan(
+            _many_split().after_blocking, bridge_inputs={0: payloads}
+        )["output"].to_pydict()
+        trace = kelvin.tracer.last()
+        (rec,) = kelvin._prepared_merges.values()
+        want = _many_reference(all_rows)
+        got = {(s, int(c)): i for i, (s, c) in
+               enumerate(zip(out["service"], out["code"]))}
+        assert set(got) == set(want) and len(got) == len(out["service"])
+        for key, w in want.items():
+            i = got[key]
+            assert (int(out["n"][i]), int(out["total"][i]),
+                    int(out["worst"][i])) == (
+                len(w["lat"]), sum(w["lat"]), max(w["lat"])), key
+            assert {int(out["some"][i])} == w["tag"], key
+        contended = sum(len(w["agents"]) > 1 for w in want.values())
+        assert (contended == 0) == (key_set == "disjoint" or full < 2)
+        assert rec.frag.merge_many is not None
+        assert bool(any(rec.remaps[:full])) == (not same)
+        _assert_prepared(trace, "miss")
+        (wait,) = [s for s in trace.spans if s.name == "device.wait"]
+        rebins = (k - 1) if contended else 0
+        assert (wait.attributes["contended_slots"],
+                wait.attributes["rebins"]) == (contended, rebins)
+        assert trace.usage.merge_rebins == rebins
+        (dispatch,) = _dispatches(trace)
+        assert (dispatch.attributes["payloads"],
+                dispatch.attributes["merges"]) == (k, k - 1)
+        assert dispatch.attributes["slots"] == max(
+            bucket_capacity(sum(_live(p) for p in payloads)), 1024)
+        _assert_digests_moved_or_rebinned(rec, payloads, contended)
+
+
+def _live(p) -> int:
+    return int(np.count_nonzero(p.state["valid"]))
+
+
+def _cap(p) -> int:
+    from pixie_tpu.exec import bridge
+
+    return bridge._live_slots(p.state)[2]
+
+
+def test_a_k_way_union_that_overflows_refolds_once():
+    """The k-way fold under a remembered capacity the union outgrows:
+    two agents with the same 1,000 (svc, code) groups teach the Kelvin
+    1,024 slots; four with groups of their own start there (the largest
+    payload's bucket), overflow, double ONCE (one ``rebucket`` span) and
+    are right; the next request starts at what the climb settled on."""
+    def wide(a: int, shared: bool) -> dict:
+        return {"time_": np.arange(1000, dtype=np.int64),
+                "svc": [SVCS[i % 6] for i in range(1000)],
+                "code": np.arange(1000, dtype=np.int64) * 7 + (
+                    0 if shared else 10**9 * (a + 1)),
+                "lat": np.arange(1, 1001, dtype=np.int64) * (a + 1),
+                "tag": np.zeros(1000, np.int64)}
+
+    def merged(kelvin, all_rows):
+        payloads = _payloads(split, map(_agent, all_rows))
+        out = kelvin.execute_plan(
+            split.after_blocking, bridge_inputs={0: payloads}
+        )["output"].to_pydict()
+        want = _many_reference(all_rows)
+        assert len(out["service"]) == len(want)
+        for s, c, n, total in zip(out["service"], out["code"], out["n"],
+                                  out["total"]):
+            w = want[s, int(c)]
+            assert (int(n), int(total)) == (len(w["lat"]), sum(w["lat"]))
+        return kelvin.tracer.last()
+
+    with routes_of("tpu"):
+        split = _many_split()
+        kelvin = Engine()
+        trace = merged(kelvin, [wide(a, True) for a in range(2)])
+        assert _dispatches(trace)[0].attributes["slots"] == 2048
+        apart = [wide(a, False) for a in range(4)]
+        trace = merged(kelvin, apart)
+        _assert_prepared(trace, "miss", rebuckets=2)
+        assert [s.attributes["slots"] for s in _dispatches(trace)] == [
+            1024, 2048, 4096]
+        assert [(s.attributes["from"], s.attributes["to"])
+                for s in trace.spans if s.name == "rebucket"] == [
+            (1024, 2048), (2048, 4096)]
+        trace = merged(kelvin, apart)
+        _assert_prepared(trace, "hit")
+        assert _dispatches(trace)[0].attributes["slots"] == 4096
+        (wait,) = [s for s in trace.spans if s.name == "device.wait"]
+        assert (wait.attributes["contended_slots"],
+                wait.attributes["rebins"]) == (0, 0)
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_one_payload_folds_nothing(platform):
+    """One payload pads, finalizes and applies the tail: its program
+    holds no sort, no scatter, no conditional and no loop on either
+    platform's routes (``tools/fold_hlo.py`` holds its text to the
+    parent's byte for byte), and its wait says nothing of a fold."""
+    with routes_of(platform):
+        split = _many_split()
+        rows, payload = _many_payload(0, "disjoint", "small", "equal")
+        kelvin = Engine()
+        out = kelvin.execute_plan(
+            split.after_blocking, bridge_inputs={0: [payload]}
+        )["output"].to_pydict()
+        assert len(out["service"]) == len(_many_reference([rows]))
+        trace = kelvin.tracer.last()
+        (wait,) = [s for s in trace.spans if s.name == "device.wait"]
+        assert not {"rebins", "contended_slots"} & set(wait.attributes)
+        assert trace.usage.merge_rebins == 0
+        (rec,) = kelvin._prepared_merges.values()
+        (state,) = _arrived(rec, [payload])
+        text = rec.program.fn.lower([state], rec.remaps).as_text()
+        for op in ("stablehlo.sort", "stablehlo.scatter", "stablehlo.case",
+                   "stablehlo.while"):
+            assert op not in text, op
