@@ -1133,6 +1133,8 @@ class Engine:
         Equal-capacity device-resident window runs fold through
         ``update_all`` — ONE scan program per chunk of windows instead of
         one dispatch per window."""
+        import jax
+
         from ..config import get_flag
 
         init_state, agg_step, _ = self._compile_steps(frag)
@@ -1151,10 +1153,17 @@ class Engine:
             state = self._fold_agg_state_native(stream, frag, stats)
             if state is not None:
                 return state
-        # The fold's empty state: a handful of eager array constructions
-        # on the device, before the first window's program has work.
-        with _subspan(stats, "state.init"):
+        # The fold's empty state: ONE program of no argument, made with
+        # the fragment and enqueued here (``frag.init_program``; the mesh's
+        # replicates its output), before the first window's program has
+        # work. Made anew every request and never kept: a state is as
+        # large as 134 MB, and the mesh step donates it.
+        with _subspan(stats, "state.init", programs=1) as span:
             state = init_state()
+            if isinstance(span, Span):
+                span.attributes["leaves"] = len(
+                    jax.tree_util.tree_leaves(state)
+                )
         if stats is not None:
             # Onto its device.dispatch spans.
             stats.fold, stats.group, stats.slots = (
@@ -1711,9 +1720,10 @@ class Engine:
             yield cols, valid
 
     def _compile_steps(self, frag):
-        """(init_state_fn, agg_step, rows_step) for a compiled fragment."""
+        """(init_state program, agg_step, rows_step) for a compiled
+        fragment."""
         if frag.is_agg:
-            return frag.init_state, frag.update, None
+            return frag.init_program, frag.update, None
         return None, None, frag.update
 
     def _materialize(self, res) -> HostBatch:

@@ -86,6 +86,11 @@ class CompiledFragment:
     update_all: object = None  # jitted scan-fold over stacked windows (agg)
     finalize: object = None  # jitted (agg only)
     init_state: object = None  # callable -> state pytree (agg only)
+    # ``init_state`` as ONE program of no argument (jitted; agg only):
+    # what a fold starts from, made anew every request. The plain
+    # function above stays for what calls it inside a trace (the merge
+    # tier's ``merge_finalize``, the mesh step) or reads its shapes.
+    init_program: object = None
     limit: Optional[int] = None  # host-enforced row cap (non-agg chains)
     # Unjitted building blocks, traceable inside shard_map (the distributed
     # partial-agg path, ``pixie_tpu.parallel``):
@@ -356,6 +361,8 @@ def _track_fragment_programs(frag, ops, cache_key, input_dicts,
     frag.update_all = wrap(frag.update_all, "fragment_scan_fold",
                            "update_all")
     frag.finalize = wrap(frag.finalize, "fragment_finalize", "finalize")
+    frag.init_program = wrap(frag.init_program, "fragment_init_state",
+                             "init_state")
     if frag.native_fold is not None:
         frag.native_fold["inputs_jit"] = wrap(
             frag.native_fold["inputs_jit"], "native_fold_inputs",
@@ -1842,6 +1849,7 @@ def _compile_agg(agg: AggOp, post, limit, apply_pre, rel1, dicts1, registry,
         finalize=_program(finalize, operands),
         finalize_state=finalize,
         init_state=init_state,
+        init_program=_program(init_state, {}),
         limit=limit,
         window_state=window_state,
         merge_states=merge_states,

@@ -12,8 +12,9 @@ One rule, reached two ways:
   ``put(value, engine.device)``: the value committed to the device.
 - Code below the engine has none in hand: a fragment (shared between
   engines through the process's fragment cache) and its operand tables,
-  ``jnp`` constructors made eagerly (a fold's empty state) or inside a
-  program's trace, a program called with host arrays alone. It follows
+  a program called with host arrays alone or with no argument at all (a
+  fold's empty state, ``fragment.init_program``), ``jnp`` constructors
+  inside a program's trace or made eagerly. It follows
   ``scope(device)``, which ``Engine._on_device()`` enters around every
   request: JAX's own default device for the thread, so uncommitted
   values land there, ``current()`` names it, and ``put(value)`` commits

@@ -360,7 +360,7 @@ class StreamingQuery:
                     self._state = None
                     break
         if self._state is None:
-            self._state = frag.init_state()
+            self._state = frag.init_program()
             # Restart folds everything from the source's start.
             for t in self.tablets:
                 be = getattr(t, "_backend", None)

@@ -113,8 +113,11 @@ Span hierarchy (one trace per ``Engine.execute_plan`` /
   skip, the resident window found or staged), stamped on the thread
   that stages; ``skipped``: windows the zone maps pruned on the way
 - ``state.init``          child of its fragment: a fold's empty group
-  state made (eager array constructions on the device, before the
-  first window's program)
+  state made, before the first window's program: ONE program of no
+  argument enqueued (``fragment_init_state``, the fragment's
+  ``init_program``; the mesh's replicates its output), outside
+  ``device.dispatch`` and made anew every request; ``programs``: 1,
+  ``leaves``: the state's
 - ``merge.compact``       child of the root, on the Kelvin: the shipped
   states' live slots found and taken (``payloads``, ``slots``: the
   bucket they are merged at)

@@ -263,21 +263,39 @@ class DistributedEngine(Engine):
         """A mesh engine's spans name its mesh's first device."""
         return self._base_mesh.devices.flat[0].id
 
-    def _dist_step(self, frag, range_valid: bool, agg: bool):
-        """Per-(fragment, mesh, valid-form) compiled step — fresh jits
-        per query would recompile the same program every execute."""
-        key = (id(frag), self.mesh, range_valid, agg)
+    def _cached_step(self, key, build):
+        """Per-(fragment, mesh, ...) compiled program — fresh jits per
+        query would recompile the same program every execute."""
         fn = self._step_cache.get(key)
         if fn is None:
-            fn = (
-                distributed_agg_step(frag, self.mesh, range_valid)
-                if agg
-                else distributed_rows_step(frag, self.mesh, range_valid)
-            )
+            fn = build()
             if len(self._step_cache) > 128:
                 self._step_cache.clear()
             self._step_cache[key] = fn
         return fn
+
+    def _dist_step(self, frag, range_valid: bool, agg: bool):
+        """The shard_map step of a fragment for one valid-form."""
+        return self._cached_step(
+            (id(frag), self.mesh, range_valid, agg),
+            lambda: (
+                distributed_agg_step(frag, self.mesh, range_valid)
+                if agg
+                else distributed_rows_step(frag, self.mesh, range_valid)
+            ),
+        )
+
+    def _init_program(self, frag):
+        """The fragment's empty group state as ONE program whose output
+        is replicated over the mesh (the steps' P() in_spec). The agg
+        step donates its state, so every request runs it anew."""
+        return self._cached_step(
+            (id(frag), self.mesh, "init_state"),
+            lambda: jax.jit(
+                frag.init_state,
+                out_shardings=jax.sharding.NamedSharding(self.mesh, P()),
+            ),
+        )
 
     @staticmethod
     def _split_side(cols):
@@ -288,10 +306,7 @@ class DistributedEngine(Engine):
 
     def _compile_steps(self, frag):
         if frag.is_agg:
-            def init_state():
-                return jax.device_put(
-                    frag.init_state(), jax.sharding.NamedSharding(self.mesh, P())
-                )
+            init_state = self._init_program(frag)
 
             def agg_step(state, cols, valid):
                 cols, side = self._split_side(cols)

@@ -446,6 +446,55 @@ def test_a_served_fold_of_resident_windows_says_its_rows(rehearsed_names):
                     cell, s.attributes)
 
 
+#: What a fold's ``state.init`` says of the one program it holds (PR 48).
+STATE_INIT_ATTRIBUTES = ("programs", "leaves")
+
+
+def test_the_docs_and_the_docstring_say_what_state_init_holds():
+    doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
+    for text, where in ((doc, "docs/OBSERVABILITY.md"),
+                        (trace_mod.__doc__, "trace.py's docstring")):
+        # The span's entry: from its name to the next entry's.
+        (line,) = re.findall(
+            r"(?:- ``|├── )state\.init(?:``)?\s(.*?)\n(?:- ``|│   ├── )",
+            text, re.S)
+        for word in (*STATE_INIT_ATTRIBUTES, "fragment_init_state"):
+            assert re.search(rf"\b{word}\b", line), (where, word)
+        line = " ".join(line.replace("│", " ").split())
+        assert "ONE program" in line, where
+        assert "eager" not in line, where
+
+
+def test_every_served_fold_makes_its_state_by_one_program(rehearsed_names):
+    """Every ``state.init`` of every rehearsed cell (a PEM fragment with
+    an AggOp, the Kelvin's re-aggregation) says one program and the
+    state's leaves, ends before its fragment's first ``device.dispatch``
+    and lies inside none; no other span carries the two attributes."""
+    for cell, spans in rehearsed_names.items():
+        seen = 0
+        for tracer, traces in spans.items():
+            for t in traces:
+                dispatches = _named(t, "device.dispatch")
+                for s in t.spans:
+                    if s.name != "state.init":
+                        assert not set(s.attributes) & {"programs"}, (
+                            cell, s.name)
+                        continue
+                    seen += 1
+                    assert tracer != "broker"
+                    assert set(s.attributes) == set(STATE_INIT_ATTRIBUTES)
+                    assert s.attributes["programs"] == 1
+                    assert s.attributes["leaves"] >= 3, (cell, s.attributes)
+                    mine = [d for d in dispatches
+                            if d.parent_id == s.parent_id]
+                    assert mine and s.end_ns <= min(
+                        d.start_ns for d in mine), cell
+                    assert not any(
+                        d.start_ns < s.end_ns and s.start_ns < d.end_ns
+                        for d in dispatches), cell
+        assert seen, cell
+
+
 def test_the_joins_pieces_are_children_of_its_span(rehearsed_names):
     kelvin = next(t for t in rehearsed_names["conn_flow_1chip.flow_recent"]
                   ["kelvin"] if _named(t, "join"))

@@ -442,6 +442,33 @@ def test_keyed_state_programs_at_the_full_cells_capacity(one_chip, program):
         assert " sort(" in text
 
 
+@pytest.mark.parametrize("where", ["one_chip", "mesh"])
+def test_a_folds_empty_state_is_one_program_of_fills(topo, one_chip, where):
+    """A fold's empty state (PR 48: ``CompiledFragment.init_program``, the
+    mesh engine's ``_init_program``) for the described chip, and
+    replicated over the 2x2's four: a program of no argument whose text
+    holds no literal of the state's size (every plane a broadcast fill)
+    and whose outputs are the state's, at the keyed state's 2^17 slots."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pixie_tpu.parallel.mesh import agent_mesh
+
+    frag = _http_stats_keyed_fragment(1 << 17)
+    sharding = one_chip if where == "one_chip" else NamedSharding(
+        agent_mesh(4, devices=topo.devices), P())
+    compiled = jax.jit(frag.init_state, out_shardings=sharding).lower(
+    ).compile()
+    state = jax.eval_shape(frag.init_state)
+    leaves = jax.tree_util.tree_leaves(state)
+    assert len(jax.tree_util.tree_leaves(compiled.out_info)) == len(leaves)
+    assert len(compiled.as_text()) < 64 * 1024
+    want = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert want > 4 << 20
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == 0
+    assert want <= memory.output_size_in_bytes < want + (1 << 16)
+
+
 @pytest.mark.slow
 def test_keyed_fold_step_under_shard_map(topo):
     """``DistributedEngine``'s step for a keyed chain on the four chips

@@ -119,10 +119,11 @@ def engines():
     return out
 
 
-def _run(eng, form, lo, hi, flags=()):
-    """(the answer's rows, sorted; the fold dispatches' attributes)."""
+def _run(eng, form, lo, hi, flags=(), platform="tpu"):
+    """(the answer's rows, sorted; the fold dispatches' attributes),
+    under ``platform``'s routes."""
     body, dense_limit, slots, _fold = FORMS[form]
-    with routes_of("tpu"), override_flag("dense_domain_limit", dense_limit):
+    with routes_of(platform), override_flag("dense_domain_limit", dense_limit):
         state = CompilerState(
             schemas={n: t.relation for n, t in eng.tables.items()},
             registry=eng.registry, now_ns=0, max_groups=slots,

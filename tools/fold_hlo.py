@@ -9,7 +9,8 @@ data (32 services x 64 paths: 2,145 slots) and over ``http_full_1chip``'s
 PEM and the Kelvin compile. Phase 2 compiles the same chains again as the
 chip would see them (the backend answers ``tpu``, the devices are a
 described ``v5e:2x2``'s) and lowers ``update``, ``update_all`` (three
-windows), ``merge_states`` and ``finalize`` at the benchmark's 2^21-row
+windows), ``merge_states``, ``finalize`` and (PR 48) ``init_state``, the
+one program of a fold's empty state, at the benchmark's 2^21-row
 window; a keyed chain at the 131,072 slots ``http_full_1chip`` settles on.
 It also prepares the Kelvin's merge of each script as a request does
 (``exec/bridge.py`` ``_prepare_merge``, from the payloads the served run
@@ -357,6 +358,11 @@ def _lower(case, captured, topo_device, out_dir, lines, rows=None):
                 if isinstance(frag.group_sketch, OperandProgram)
                 else jax.jit(frag.group_sketch)
             ).lower(on(jax.eval_shape(frag.init_sketch)), cols, valid),
+            # The fold's empty state (PR 48: ``frag.init_program``, one
+            # program of no argument; here its function with the
+            # described device named, where the engine's scope names it).
+            "init_state": lambda: jax.jit(
+                frag.init_state, out_shardings=chip).lower(),
         }
         if case in ("flame", "edges"):  # windows in range: one scan program
             wanted = ("update_all",) + (
@@ -372,6 +378,8 @@ def _lower(case, captured, topo_device, out_dir, lines, rows=None):
             wanted = ("finalize", "merge_states")
         if rows:
             wanted = [p for p in wanted if p in ("update", "update_all")]
+        elif {"update", "update_all"} & set(wanted):
+            wanted = (*wanted, "init_state")  # whoever folds windows
         for program in sorted(wanted):
             lowered = programs[program]()
             more = (
